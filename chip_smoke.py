@@ -1,0 +1,520 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py        # from the root of a checkout, on a TPU host
+
+Drives the main path once, in ONE process, through the entry points a user
+calls, at the full width of the models the repo supports, with seeded random
+weights, frames and prompts:
+
+- ``kernels``      every Pallas kernel compiled by Mosaic (never interpret
+                   mode) at the shapes the pipelines feed it, checked against
+                   a plain-XLA reference, ``tpu_custom_call`` in its lowering;
+- ``label_host``   the README label pipeline (MobileNetV2 1.0 at 224²) through
+  ``label_device`` ``parse_launch`` + ``PipelineRunner``, host decode and
+                   ``device=true`` decode, long enough for the compiled
+                   steady-state window to arm and run; classes agree with a
+                   direct ``jax.jit`` of the model on the same frames;
+- ``llm_xla``      ``appsrc ! tensor_llm ! tensor_sink`` on a store-registered
+  ``llm_pallas``   transformer (d_model 1024, 8 heads × 128, 4 layers, vocab
+                   512, bf16), ``paged_kernel=xla`` then ``pallas``: every
+                   request answered, identical greedy tokens, the pallas arm's
+                   invokes all counted under ``pallas``;
+- ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
+                   four distinct chips, ``tensor_llm shards=4`` equal to
+                   ``shards=1``, ring prefill through the Pallas block kernel.
+
+There is no CPU mode, no interpret mode and no smaller size off the chip: the
+script exits non-zero at once unless ``jax.devices()[0].platform == "tpu"``.
+It exits non-zero if any leg failed, and only a full pass prints the last
+line, one JSON object ``{"ok": true, "device": {...}}``.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import time
+import traceback
+
+N_FRAMES = 48               # label legs: enough for the window to arm twice
+FRAME_SEED = 7
+NORMALIZE = "typecast:float32,add:-127.5,div:127.5"
+LLM = dict(d_model=1024, n_heads=8, n_layers=4, vocab=512)   # bench.py's
+LLM_REQUESTS = 4                                             # widest
+LLM_NEW_TOKENS = 8
+
+
+def _bf16_ulp(x: float) -> float:
+    """Spacing of bfloat16 (8 significand bits) at magnitude `x`."""
+    import math
+
+    return 2.0 ** (math.floor(math.log2(max(abs(x), 1e-30))) - 7)
+
+
+# -- kernels -----------------------------------------------------------------
+
+def leg_kernels() -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from nnstreamer_tpu.backends import pallas_ops as po
+    from nnstreamer_tpu.backends import pallas_paged as pp
+    from nnstreamer_tpu.parallel.ring_attention import ring_attention
+
+    assert not po._interpret(), "Pallas kernels would run in interpret mode"
+    rng = np.random.default_rng(0)
+    errs = {}
+
+    def check(name, fn, args, ref, tol):
+        """Compile `fn` for the chip, require a Mosaic custom call in its
+        lowering, run it, compare every output with `ref(*args)`."""
+        jitted = jax.jit(fn)
+        assert "tpu_custom_call" in jitted.lower(*args).as_text(), \
+            f"{name}: no tpu_custom_call in the lowering"
+        got = [np.asarray(g, np.float32)
+               for g in jax.tree_util.tree_leaves(jitted(*args))]
+        want = [np.asarray(w, np.float32)
+                for w in jax.tree_util.tree_leaves(ref(*args))]
+        assert len(got) == len(want), name
+        err = 0.0
+        for g, w in zip(got, want):
+            assert g.shape == w.shape, (name, g.shape, w.shape)
+            assert np.isfinite(g).all(), f"{name}: non-finite output"
+            err = max(err, float(np.abs(g - w).max()))
+        assert err <= tol, f"{name}: max abs err {err} > {tol}"
+        errs[name] = err
+
+    def normal(shape, dtype=jnp.float32):
+        # bf16-exact values: the MXU's default-precision pass rounds its
+        # operands to bf16, so these inputs lose nothing on the way in
+        return jnp.asarray(rng.normal(0, 1, shape),
+                           jnp.bfloat16).astype(dtype)
+
+    # ingest kernels at the frame shape the pipeline feeds them (4-D)
+    frame = rng.integers(0, 256, (1, 224, 224, 3), np.uint8)
+    check("normalize_u8", po.normalize_u8, (frame,),
+          lambda x: (x.astype(np.float32) - 127.5) / 127.5, 1e-6)
+    xf = rng.normal(0, 2, (1, 224, 224, 3)).astype(np.float32)
+    check("clamp_scale", lambda a: po.clamp_scale(a, -1.0, 1.0, 2.0, 0.5),
+          (xf,), lambda a: np.clip(a, -1, 1) * 2 + 0.5, 1e-6)
+    for m in (16, 4):                       # 4: the zero-padded-rows path
+        check(f"quantize_rows_m{m}", po.quantize_rows,
+              (normal((m, 1024), jnp.bfloat16),), po._quantize_rows_xla, 1.0)
+
+    def attn_ref(q, k, v, causal, q0=0):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                       k.astype(jnp.float32),
+                       precision="highest") * q.shape[-1] ** -0.5
+        if causal:
+            rows = q0 + jnp.arange(s.shape[-2])[:, None]
+            s = jnp.where(rows >= jnp.arange(s.shape[-1])[None, :],
+                          s, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                          v.astype(jnp.float32), precision="highest")
+
+    q, k, v = (normal((1, 2048, 8, 128), jnp.bfloat16) for _ in range(3))
+    for causal in (False, True):
+        check(f"flash_attention_causal{int(causal)}",
+              lambda q, k, v, c=causal: po.flash_attention(q, k, v, causal=c),
+              (q, k, v), lambda q, k, v, c=causal: attn_ref(q, k, v, c), 3e-2)
+    # the K-grid streaming path (a head's K+V past the VMEM budget);
+    # checked on the last 128 query rows
+    q, k, v = (normal((1, 32768, 1, 128), jnp.bfloat16) for _ in range(3))
+    check("flash_attention_kgrid",
+          lambda q, k, v: po.flash_attention(q, k, v, causal=True)[:, -128:],
+          (q, k, v),
+          lambda q, k, v: attn_ref(q[:, -128:], k, v, True, q0=32768 - 128),
+          3e-2)
+    # ring attention picks the Pallas block kernel by itself on a TPU
+    # backend: flash_block_update inside shard_map (one-device ring here;
+    # the multichip leg rotates it over four)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    q, k, v = (normal((1, 256, 8, 128)) for _ in range(3))
+    check("ring_attention_block_kernel",
+          lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=True),
+          (q, k, v), lambda q, k, v: attn_ref(q, k, v, True), 3e-2)
+
+    # paged kernels at the LLM legs' geometry, MHA and GQA
+    hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64
+    nh = LLM["n_heads"]
+
+    def gathered(pool, table, g):
+        flat = pool[table].reshape(table.shape[:-1] + (-1,) + pool.shape[2:])
+        return jnp.repeat(flat, g, axis=-2).astype(jnp.float32)
+
+    for n_kv in (nh, 2):
+        kp, vp = (normal((nb, bs, n_kv, hd)) for _ in range(2))
+        tables = jnp.asarray(
+            1 + rng.permutation(nb - 1)[:32].reshape(4, 8), jnp.int32)
+        pos = jnp.asarray([3, 17, 64, 127], jnp.int32)
+
+        def decode_ref(q, kp, vp, tables, pos, g=nh // n_kv):
+            kc, vc = gathered(kp, tables, g), gathered(vp, tables, g)
+            s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), kc,
+                           precision="highest") * hd ** -0.5
+            s = jnp.where(jnp.arange(kc.shape[1])[None, None, :]
+                          <= pos[:, None, None], s, -1e30)
+            return jnp.einsum("bhk,bkhd->bhd", jax.nn.softmax(s, -1), vc,
+                              precision="highest")
+
+        check(f"paged_decode_attn_kv{n_kv}", pp.paged_decode_attn,
+              (normal((4, nh, hd), jnp.bfloat16), kp, vp, tables, pos),
+              decode_ref, 3e-2)
+
+        def prefill_ref(q, kp, vp, table, pos0, g=nh // n_kv):
+            kc, vc = gathered(kp, table, g), gathered(vp, table, g)
+            s = jnp.einsum("hqd,khd->hqk", q.astype(jnp.float32), kc,
+                           precision="highest") * hd ** -0.5
+            qpos = pos0 + jnp.arange(q.shape[1])
+            s = jnp.where(jnp.arange(kc.shape[0])[None, None, :]
+                          <= qpos[None, :, None], s, -1e30)
+            return jnp.einsum("hqk,khd->hqd", jax.nn.softmax(s, -1), vc,
+                              precision="highest")
+
+        for s_c in (16, 128):
+            check(f"paged_prefill_attn_kv{n_kv}_c{s_c}",
+                  pp.paged_prefill_attn,
+                  (normal((nh, s_c, hd), jnp.bfloat16), kp, vp, tables[0],
+                   jnp.int32(32)), prefill_ref, 3e-2)
+    return {"kernels": len(errs), "max_abs_err": round(max(errs.values()), 5)}
+
+
+# -- label pipeline ----------------------------------------------------------
+
+def _label_reference():
+    """Reference logits per frame: a direct jit of the zoo model on the
+    seeded frames `videotestsrc pattern=random` emits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.models.zoo import build_model
+
+    rng = np.random.default_rng(FRAME_SEED)
+    frames = [rng.integers(0, 256, size=(224, 224, 3), dtype=np.uint8)
+              for _ in range(N_FRAMES)]
+    bundle = build_model("mobilenet_v2")
+    direct = jax.jit(lambda p, x: bundle.fn(
+        p, (x.astype(jnp.float32) + -127.5) / 127.5))
+    logits = [np.asarray(direct(bundle.params, f[None]))[0] for f in frames]
+    for l in logits:
+        assert l.shape == (1001,) and np.isfinite(l).all(), l.shape
+    return logits
+
+
+def _run_label(decoder: str, filter_props: str = ""):
+    """Run the README label pipeline to EOS; returns (sink buffers, the
+    filter's stats row, runner)."""
+    import nnstreamer_tpu as nns
+
+    pipe = nns.parse_launch(
+        f"videotestsrc pattern=random seed={FRAME_SEED} "
+        f"num-buffers={N_FRAMES} ! tensor_converter ! "
+        f"tensor_transform mode=arithmetic option={NORMALIZE} ! "
+        f"tensor_filter name=f model=zoo://mobilenet_v2 {filter_props} ! "
+        f"tensor_decoder mode=image_labeling {decoder} ! "
+        f"tensor_sink name=out")
+    runner = nns.PipelineRunner(pipe)
+    try:
+        runner.start()
+        runner.wait(600)
+    finally:
+        runner.stop()
+    assert runner._error is None, runner._error
+    return pipe.get("out").results, runner.stats()["f"], runner
+
+
+def _check_classes(bufs, logits, index_of, score_of=None) -> int:
+    """Every frame accounted for, in order, and the class the pipeline
+    chose is the reference's best within bf16 rounding (the zoo model
+    computes in bf16: its top logits tie or sit one step apart, and a
+    differently fused program may break such a tie the other way).
+    Returns how many classes were exactly the reference argmax."""
+    assert len(bufs) == N_FRAMES, f"{len(bufs)} of {N_FRAMES} frames out"
+    pts = [b.pts for b in bufs]
+    assert pts == sorted(pts) and len(set(pts)) == N_FRAMES, pts
+    exact = 0
+    for i, (b, ref) in enumerate(zip(bufs, logits)):
+        idx = index_of(b)
+        tol = 4 * _bf16_ulp(float(abs(ref).max()))
+        assert 0 <= idx < ref.size, (i, idx)
+        assert ref[idx] >= ref.max() - tol, \
+            f"frame {i}: class {idx} scores {ref[idx]}, best {ref.max()}"
+        if score_of is not None:
+            assert abs(score_of(b) - ref[idx]) <= tol, \
+                f"frame {i}: score {score_of(b)} vs reference {ref[idx]}"
+        exact += int(idx == int(ref.argmax()))
+    return exact
+
+
+def _check_window(stats: dict) -> dict:
+    """The compiled steady-state window armed, ran, and never errored."""
+    assert stats["buffers"] == N_FRAMES, stats["buffers"]
+    assert stats["loop_entries"] > 0, stats
+    assert stats["loop_bails"].get("error", 0) == 0, stats["loop_bails"]
+    return {"loop_entries": stats["loop_entries"],
+            "compiled_steps": stats["compiled_steps"],
+            "loop_bails": stats["loop_bails"]}
+
+
+def leg_label_host(ref) -> dict:
+    bufs, stats, _ = _run_label("")
+    exact = _check_classes(
+        bufs, ref, lambda b: int(b.meta["label_index"]),
+        lambda b: float(b.meta["score"]))
+    return {"frames": len(bufs), "argmax_exact": exact,
+            **_check_window(stats)}
+
+
+def leg_label_device(ref) -> dict:
+    import numpy as np
+
+    bufs, stats, _ = _run_label("device=true")
+    for b in bufs:
+        t = np.asarray(b.tensors[0])
+        assert t.shape == (1,) and t.dtype == np.int32, (t.shape, t.dtype)
+    exact = _check_classes(
+        bufs, ref, lambda b: int(np.asarray(b.tensors[0])[0]))
+    return {"frames": len(bufs), "argmax_exact": exact,
+            **_check_window(stats)}
+
+
+# -- tensor_llm --------------------------------------------------------------
+
+def _register_llm() -> str:
+    """Seeded bf16 transformer at the smoke width, in the model store."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.models import transformer as T
+    from nnstreamer_tpu.serving.store import get_store
+
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), T.init_params(seed=0, **LLM))
+    get_store().register("chip_smoke_llm",
+                         ModelBundle(fn=None, params=params))
+    return "store://chip_smoke_llm"
+
+
+def _prompts(n: int, lo: int, hi: int):
+    import numpy as np
+
+    rng = np.random.default_rng(1)
+    return [rng.integers(0, LLM["vocab"], size=int(rng.integers(lo, hi + 1)))
+            .astype(np.int32) for _ in range(n)]
+
+
+def _run_llm(model: str, prompts, trace: bool = False, **llm_props):
+    """appsrc ! tensor_llm ! tensor_sink (the shape of `python -m
+    nnstreamer_tpu llm`): every request pushed, run to EOS. Returns
+    ({request: tokens}, the element's stats; with `trace` also its
+    kernel-tagged backend span counts under "kernel_spans")."""
+    import numpy as np
+
+    import nnstreamer_tpu as nns
+    from nnstreamer_tpu.elements import AppSrc, TensorLLM, TensorSink
+    from nnstreamer_tpu.tensor.buffer import TensorBuffer
+    from nnstreamer_tpu.tensor.info import TensorFormat, TensorsSpec
+
+    src = AppSrc(name="src", spec=TensorsSpec(
+        tensors=(), format=TensorFormat.FLEXIBLE))
+    props = dict(n_heads=LLM["n_heads"], dtype="bfloat16", max_batch=8,
+                 num_blocks=64, block_size=16, max_len=128,
+                 max_new_tokens=LLM_NEW_TOKENS,
+                 # all requests land in the first serving step, so both
+                 # arms decode the same batch composition every step
+                 admit_window_ms=50.0)
+    props.update(llm_props)
+    llm = TensorLLM(name="llm", model=model, **props)
+    sink = TensorSink(name="sink")
+    pipe = nns.Pipeline()
+    for e in (src, llm, sink):
+        pipe.add(e)
+    pipe.link(src, llm)
+    pipe.link(llm, sink)
+    for i, p in enumerate(prompts):
+        src.push(TensorBuffer(tensors=(p,), pts=i,
+                              meta={"llm": {"request_id": f"req{i}"}}))
+    src.end()
+    runner = nns.PipelineRunner(pipe, trace=trace)
+    try:
+        runner.start()
+        runner.wait(600)
+    finally:
+        runner.stop()
+    assert runner._error is None, runner._error
+    tokens, done = {}, set()
+    for b in sink.results:
+        m = b.meta["llm"]
+        tokens.setdefault(m["request_id"], []).extend(
+            int(t) for t in np.asarray(b.tensors[0]))
+        if m["done"]:
+            done.add(m["request_id"])
+    want = {f"req{i}" for i in range(len(prompts))}
+    assert done == want, f"requests finished: {sorted(done)}"
+    for rid, toks in tokens.items():
+        assert len(toks) == props["max_new_tokens"], (rid, toks)
+        assert all(0 <= t < LLM["vocab"] for t in toks), (rid, toks)
+    stats = llm.extra_stats()
+    assert stats["requests_in"] == stats["finished"] == len(prompts), stats
+    if trace:
+        stats["kernel_spans"] = {
+            kernel: n for (_, kernel), n in
+            runner.tracer.kernel_spans().items()}
+    return tokens, stats
+
+
+def leg_llm(model: str, kernel: str, reference=None):
+    toks, stats = _run_llm(model, _prompts(LLM_REQUESTS, 9, 16),
+                           paged_kernel=kernel)
+    ex = stats["executor"]
+    other = "xla" if kernel == "pallas" else "pallas"
+    assert ex["paged_kernel"] == kernel, ex
+    assert ex["kernel_invokes"][kernel] > 0, ex
+    # xla's whole-prompt prefill and every decode step count under xla;
+    # pallas routes prefill through the chunk family, so nothing in that
+    # arm may have been served by XLA
+    assert ex["kernel_invokes"][other] == 0, ex["kernel_invokes"]
+    assert ex["decode_steps"] >= LLM_NEW_TOKENS - 1, ex
+    if reference is not None:
+        assert toks == reference, \
+            f"greedy tokens differ: {kernel} {toks} vs {reference}"
+    return toks, {"requests": len(toks), "tokens": stats["tokens_out"],
+                  "kernel_invokes": ex["kernel_invokes"],
+                  "compiles": ex["compile_count"]}
+
+
+# -- four chips --------------------------------------------------------------
+
+def leg_multichip(model: str, ref) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from nnstreamer_tpu.parallel.ring_attention import (
+        reference_attention, ring_attention)
+
+    devs = jax.devices()[:4]
+    ids = [d.id for d in devs]
+    assert len(set(ids)) == 4, ids
+    before = [d.memory_stats()["bytes_in_use"] for d in devs]
+
+    # devices=4: one replica per chip, every chip serves frames
+    bufs, stats, _ = _run_label("device=true", "devices=4")
+    assert "replica_decline" not in stats, stats["replica_decline"]
+    assert stats["replica_devices"] == 4 and stats["replica_live"] == 4
+    rows = stats["replicas"]
+    served = {devs[r["device"]].id: r["invokes"] for r in rows}
+    assert sorted(served) == sorted(ids), served
+    assert all(n > 0 for n in served.values()), served
+    assert sum(served.values()) == N_FRAMES and stats["replica_errors"] == 0
+    exact = _check_classes(
+        bufs, ref, lambda b: int(np.asarray(b.tensors[0])[0]))
+    # the weights went to four chips, not four times to chip 0: the
+    # three chips that held nothing before this leg each grew by a model
+    grew = [d.memory_stats()["peak_bytes_in_use"] - b
+            for d, b in zip(devs[1:], before[1:])]
+    assert all(g > 4 << 20 for g in grew), f"HBM growth on chips 1-3 {grew}"
+
+    # shards=4 == shards=1, token for token (canonical blocking)
+    prompts = _prompts(2, 9, 16)
+    one, _ = _run_llm(model, prompts, shards=1)
+    four, st4 = _run_llm(model, prompts, shards=4)
+    assert st4["executor"]["shards"] == 4
+    assert len(set(st4["executor"]["shard_chips"])) == 4
+    assert four == one, f"shards=4 {four} vs shards=1 {one}"
+
+    # ring prefill: a 512-token prompt over four chips is 128 rows a
+    # chip, where ring_attention takes the Pallas block kernel by itself
+    mesh = Mesh(np.array(devs), ("sp",))
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.normal(0, 1, (1, 512, 8, 128)), jnp.bfloat16)
+               .astype(jnp.float32) for _ in range(3))
+    ring = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh=mesh,
+                                                  causal=True))
+    assert "tpu_custom_call" in ring.lower(q, k, v).as_text()
+    err = float(jnp.abs(ring(q, k, v)
+                        - reference_attention(q, k, v, causal=True)).max())
+    assert err <= 3e-2, f"ring attention over 4 chips: max abs err {err}"
+    long_prompt = [np.random.default_rng(3).integers(
+        0, LLM["vocab"], size=500).astype(np.int32)]
+    _, st_ring = _run_llm(model, long_prompt, trace=True, shards=4,
+                          ring_prefill_min=256, max_len=640, num_blocks=96)
+    assert st_ring["kernel_spans"].get("ring", 0) > 0, st_ring["kernel_spans"]
+    return {"replica_invokes": served, "argmax_exact": exact,
+            "hbm_growth_mib": [g >> 20 for g in grew],
+            "shards4_equals_shards1": True,
+            "ring_attention_err": round(err, 5)}
+
+
+# -- driver ------------------------------------------------------------------
+
+def main() -> int:
+    import jax
+
+    devices = jax.devices()
+    dev = {"platform": devices[0].platform,
+           "kind": devices[0].device_kind, "count": len(devices)}
+    if dev["platform"] != "tpu":
+        print(f"chip_smoke: needs a TPU, found platform "
+              f"{dev['platform']!r} ({dev['kind']}, {dev['count']} "
+              f"device(s)); there is no CPU mode", file=sys.stderr)
+        return 2
+
+    from importlib.metadata import version
+
+    import jaxlib
+
+    from nnstreamer_tpu.serving.compile_cache import enable_compile_cache
+
+    print(f"platform {dev['platform']}  device_kind {dev['kind']}  "
+          f"devices {dev['count']}")
+    print(f"jax {jax.__version__}  jaxlib {jaxlib.__version__}  "
+          f"libtpu {version('libtpu')}")
+    print(f"compile cache {enable_compile_cache()}", flush=True)
+
+    failed = []
+
+    def leg(name, fn, *args):
+        t0 = time.perf_counter()
+        try:
+            out = fn(*args)
+        except Exception:
+            failed.append(name)
+            traceback.print_exc()
+            print(f"leg {name}: FAILED after "
+                  f"{time.perf_counter() - t0:.1f}s", flush=True)
+            return None
+        info = out[1] if isinstance(out, tuple) else out
+        print(f"leg {name}: ok ({time.perf_counter() - t0:.1f}s) "
+              f"{json.dumps(info)}", flush=True)
+        return out
+
+    leg("kernels", leg_kernels)
+    ref = leg("label_reference",
+              lambda: (_label_reference(), {"frames": N_FRAMES}))
+    ref = ref and ref[0]            # the logits; None if the leg failed
+    if ref:
+        leg("label_host", leg_label_host, ref)
+        leg("label_device", leg_label_device, ref)
+    model = _register_llm()
+    xla = leg("llm_xla", leg_llm, model, "xla")
+    leg("llm_pallas", leg_llm, model, "pallas", xla and xla[0])
+    if dev["count"] >= 4 and ref:
+        leg("multichip", leg_multichip, model, ref)
+    else:
+        print(f"multichip: not run ({dev['count']} device)")
+
+    if failed:
+        print(f"chip_smoke: FAILED legs: {', '.join(failed)}", flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

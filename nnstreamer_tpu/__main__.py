@@ -276,13 +276,25 @@ def _serve_main(argv) -> int:
     --pipeline (a mid-pipeline description, e.g. 'tensor_filter
     framework=xla model=store://m'); without --pipeline the workers
     echo after --service-ms, which gives a known-capacity pool for
-    drills and demos."""
+    drills and demos.
+
+    One process per chip: the supervisor never initialises a JAX
+    backend (it holds no chip and measures no device), and workers that
+    run a pipeline each own their chips — one worker without --chips
+    (it sees the whole host), or --chips split evenly across --workers,
+    each worker narrowing itself to its share before it imports jax.
+    More device workers than chips is refused at start
+    (ChipLeaseError)."""
     ap = argparse.ArgumentParser(
         prog="nnstreamer_tpu serve",
         description="supervised multi-process serving pool "
                     "(docs/robustness.md)")
     ap.add_argument("--workers", type=int, default=2,
                     help="worker processes (pipeline copies)")
+    ap.add_argument("--chips", default="", metavar="I,J,…",
+                    help="chip ordinals to lease, split evenly across "
+                         "the workers (each sees only its own); a "
+                         "pipeline pool without it runs one worker")
     ap.add_argument("--pipeline", default=None,
                     help="mid-pipeline each worker runs between appsrc "
                          "and tensor_sink (default: echo)")
@@ -335,7 +347,7 @@ def _serve_main(argv) -> int:
                          "(docs/observability.md): forensic bundles "
                          "dumped into DIR on SLO breach / conservation "
                          "mismatch / worker fence / watchdog (also "
-                         "turns on the pool tracer + device profiler)")
+                         "turns on the pool tracer)")
     ap.add_argument("--join", default=None, metavar="HOST:PORT",
                     help="register this pool as a host of a mesh "
                          "router (python -m nnstreamer_tpu mesh "
@@ -348,6 +360,7 @@ def _serve_main(argv) -> int:
                     help="locality zone advertised to the router")
     args = ap.parse_args(argv)
 
+    from nnstreamer_tpu.core.errors import ChipLeaseError
     from nnstreamer_tpu.serving.pool import PooledQueryServer
     from nnstreamer_tpu.serving.worker import WorkerSpec
 
@@ -356,11 +369,6 @@ def _serve_main(argv) -> int:
         from nnstreamer_tpu.runtime.tracing import Tracer
 
         tracer = Tracer()
-    prof = None
-    if args.metrics_port is not None or args.flight_dir:
-        from nnstreamer_tpu.runtime import devprof
-
-        prof = devprof.get().enable()
     table = None
     if args.tenants:
         from nnstreamer_tpu.serving.tenancy import TenantTable
@@ -376,11 +384,17 @@ def _serve_main(argv) -> int:
     else:
         spec = WorkerSpec(kind="echo", service_ms=args.service_ms,
                           dims=args.dims, types=args.types)
-    pqs = PooledQueryServer(
-        spec, workers=args.workers, sid=args.id, host=args.host,
-        port=args.port, max_pending=args.max_pending,
-        max_inflight=args.max_inflight, shed_policy=args.shed_policy,
-        tenants=table, tracer=tracer)
+    try:
+        pqs = PooledQueryServer(
+            spec, workers=args.workers, sid=args.id, host=args.host,
+            port=args.port, max_pending=args.max_pending,
+            max_inflight=args.max_inflight, shed_policy=args.shed_policy,
+            tenants=table, tracer=tracer,
+            chips=[int(c) for c in args.chips.split(",") if c.strip()]
+            or None)
+    except (ChipLeaseError, ValueError) as e:
+        print(f"serve: {e}", file=sys.stderr)
+        return 2
     pqs.install_signal_handlers()
     tuner = None
     if args.slo:
@@ -409,8 +423,7 @@ def _serve_main(argv) -> int:
         s = pqs.stats()
         return metrics_snapshot(
             tracer=tracer, admission=s.pop("admission"), pool=s,
-            autotune=tuner.stats() if tuner is not None else None,
-            devprof=prof.stats() if prof is not None else None)
+            autotune=tuner.stats() if tuner is not None else None)
 
     msrv = None
     if args.metrics_port is not None:
@@ -428,8 +441,7 @@ def _serve_main(argv) -> int:
 
         def _flight_env():
             return {"cmd": "serve", "argv": list(argv),
-                    "workers": args.workers, "port": pqs.port,
-                    "devprof": prof.stats() if prof is not None else None}
+                    "workers": args.workers, "port": pqs.port}
 
         flight = FlightRecorder(args.flight_dir).attach(
             tracer=tracer, autotune=tuner,

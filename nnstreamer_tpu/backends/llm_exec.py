@@ -15,7 +15,7 @@ head-sharded, the KV pools are sharded along the kv-head axis next to
 them, and the jit namespace becomes ``("tp", N, version)`` — same
 per-bucket compile accounting, same swap protocol, one SPMD executable
 per bucket. The sharded path is XLA-only and float-only (Pallas and
-W8A8 refuse loudly); prompts at or past ``ring_prefill_min`` prefill
+W8A8 are typed refusals); prompts at or past ``ring_prefill_min`` prefill
 through the sequence-parallel ring-attention twin instead of the
 blocked path (allclose-, not bit-, equivalent — decode from ring KV is
 still the blocked bit-exact program).
@@ -34,10 +34,11 @@ Kernel selection (``paged_kernel`` prop / ``NNS_PAGED_KERNEL`` env,
 default ``xla``): the attention inner loop is either the XLA reference
 (`llm/paged_model.py` — the bit-parity path against
 `transformer.generate`) or the paged Pallas flash kernels
-(`backends/pallas_paged.py` — the r05 9.2–165x path). The kernel is
-part of the jit key, invocations are counted per kernel, and a Pallas
-path that cannot build here becomes a *counted* XLA fallback
-(`kernel_fallback`), never an error.
+(`backends/pallas_paged.py`). The kernel is part of the jit key and
+invocations are counted per kernel. The kernel that was asked for is
+the kernel that runs: a Pallas kernel the compiler refuses raises
+`BackendError` from the call that needed it, and `paged_kernel=pallas`
+with `shards>0` is refused at construction.
 
 Weights are passed as jit *arguments* (not closed over), so a same-
 shape hot swap is served by the already-compiled executable — the
@@ -106,7 +107,6 @@ class PagedLLMExecutor:
         self.tracer = tracer
         self.shards = int(shards)
         self.ring_prefill_min = int(ring_prefill_min)
-        self.kernel_fallback = 0
         self.kernel_invokes: Dict[str, int] = {"pallas": 0, "xla": 0}
         kern = (paged_kernel or os.environ.get("NNS_PAGED_KERNEL")
                 or "xla").strip().lower()
@@ -114,23 +114,10 @@ class PagedLLMExecutor:
             raise BackendError(
                 f"paged_kernel must be 'pallas' or 'xla', got {kern!r}")
         if kern == "pallas" and self.shards > 0:
-            log.warning(
-                "llm %s: paged_kernel=pallas is single-chip; shards=%d "
-                "serves on the sharded XLA path (counted fallback)",
-                name, self.shards)
-            self.kernel_fallback += 1
-            kern = "xla"
-        if kern == "pallas":
-            from nnstreamer_tpu.backends import pallas_paged
-
-            if not pallas_paged.available():
-                log.warning(
-                    "llm %s: paged_kernel=pallas requested but the "
-                    "Pallas paged kernels are unavailable here — "
-                    "serving on the XLA reference (counted fallback)",
-                    name)
-                self.kernel_fallback += 1
-                kern = "xla"
+            raise BackendError(
+                f"llm {name}: paged_kernel=pallas is single-chip and "
+                f"shards={self.shards} serves on the sharded XLA path; "
+                f"drop one of the two")
         self.paged_kernel = kern
         self.n_heads = int(n_heads)
         self.dtype = jnp.dtype(dtype) if dtype is not None \
@@ -392,16 +379,21 @@ class PagedLLMExecutor:
         self._jits[key] = jitted
         return jitted, True
 
-    def _kernel_fallback_to_xla(self, kind: str, exc: Exception) -> None:
-        """A fresh Pallas compile failed at serve time: flip the whole
-        executor to the XLA reference (sticky — one flip, not one per
-        call), count it, and keep serving. Never an error."""
-        log.warning(
-            "llm %s: pallas %s kernel failed to build (%s: %s) — "
-            "falling back to the XLA reference", self.name, kind,
-            type(exc).__name__, exc)
-        self.kernel_fallback += 1
-        self.paged_kernel = "xla"
+    def _run_kernel(self, kind: str, run):
+        """Call `run()` (get the bucket's jit and invoke it). On the
+        Pallas path a failure is the kernel's: the compiler refused it
+        or the program faulted, and the caller asked for that kernel —
+        raise it typed, naming the kind, never serve another kernel in
+        its place."""
+        if self._kind_kernel(kind) != "pallas":
+            return run()
+        try:
+            return run()
+        except Exception as e:
+            raise BackendError(
+                f"llm {self.name}: paged_kernel=pallas {kind} failed "
+                f"({type(e).__name__}: {e}); set paged_kernel=xla to "
+                f"serve on the XLA reference") from e
 
     def _span(self, kind: str, t0: float, t1: float, **args) -> None:
         if self.tracer.active:
@@ -544,13 +536,7 @@ class PagedLLMExecutor:
         if prof.enabled:
             prof.note_dispatch(self.name, f"chunk:{c_b}")
         t0 = time.perf_counter()
-        try:
-            logits, fresh = _run()
-        except Exception as e:
-            if self.paged_kernel != "pallas":
-                raise
-            self._kernel_fallback_to_xla("chunk", e)
-            logits, fresh = _run()
+        logits, fresh = self._run_kernel("chunk", _run)
         kernel = self._kind_kernel("chunk")
         out = np.asarray(device_sync(
             logits, tracer=self.tracer,
@@ -607,13 +593,7 @@ class PagedLLMExecutor:
         if prof.enabled:
             prof.note_dispatch(self.name, f"decode:{b_b}")
         t0 = time.perf_counter()
-        try:
-            logits, fresh = _run()
-        except Exception as e:
-            if self.paged_kernel != "pallas":
-                raise
-            self._kernel_fallback_to_xla("decode", e)
-            logits, fresh = _run()
+        logits, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
         out = np.asarray(device_sync(
             logits, tracer=self.tracer,
@@ -719,13 +699,7 @@ class PagedLLMExecutor:
         if prof.enabled:
             prof.note_dispatch(self.name, f"decmulti:{b_b}x{steps}")
         t0 = time.perf_counter()
-        try:
-            toks, fresh = _run()
-        except Exception as e:
-            if self.paged_kernel != "pallas":
-                raise
-            self._kernel_fallback_to_xla("decode", e)
-            toks, fresh = _run()
+        toks, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
         out = np.asarray(device_sync(
             toks, tracer=self.tracer,
@@ -927,7 +901,6 @@ class PagedLLMExecutor:
             "swap_count": self.swap_count,
             "paged_kernel": self.paged_kernel,
             "kernel_invokes": dict(self.kernel_invokes),
-            "kernel_fallback": self.kernel_fallback,
         }
         if self.shards:
             out["shards"] = self.shards

@@ -57,13 +57,6 @@ from nnstreamer_tpu.backends.pallas_ops import (
     _interpret, _online_softmax_update)
 
 
-def available() -> bool:
-    """Whether the paged Pallas kernels can run here (compiled on TPU,
-    interpret mode elsewhere). Split out so llm_exec can probe it once
-    and count a fallback instead of raising mid-serve."""
-    return hasattr(pltpu, "PrefetchScalarGridSpec")
-
-
 # -- paged flash decode ------------------------------------------------------
 
 def _paged_decode_kernel(scale: float, bs: int, n_kv: int, n_heads: int,
@@ -193,8 +186,8 @@ def _paged_prefill_kernel(scale: float, bs: int, bq: int,
     @pl.when((j * bs) <= (q_lo + bq - 1))
     def _step():
         q = q_ref[0].astype(jnp.float32)            # (bq, hd)
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)   # (bs, hd)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        k_blk = k_ref[0].astype(jnp.float32)        # (bs, hd)
+        v_blk = v_ref[0].astype(jnp.float32)
         rows = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
         m, l, acc = _online_softmax_update(
@@ -228,24 +221,28 @@ def paged_prefill_attn(q, k_pool_l, v_pool_l, table, pos0):
     (n_heads, S_c, hd) f32.
 
     Head ``h`` fetches KV head ``h // group`` straight from the narrow
-    pool in its index map — GQA without a group-expanded copy.
+    pool in its index map — GQA without a group-expanded copy. The pool
+    is viewed as (num_blocks, block_size, n_kv*hd) so that one KV head
+    of one block is a (block_size, hd) tile on the lane axis: Mosaic
+    takes a block whose last two dims are (8k, 128k) or the whole
+    array's, and a (1, hd) slice of (n_kv, hd) is neither. With
+    n_kv > 1 that needs hd % 128 == 0.
     """
     n_heads, s_c, hd = q.shape
-    _, bs, n_kv, _ = k_pool_l.shape
+    nb, bs, n_kv, _ = k_pool_l.shape
     mb = table.shape[0]
     g = n_heads // n_kv
     bq = _auto_bq(s_c)
     scale = hd ** -0.5
     kern = functools.partial(_paged_prefill_kernel, scale, bs, bq)
+    kv_blk = pl.BlockSpec(
+        (1, bs, hd), lambda h, i, j, tab, p0: (tab[j], 0, h // g))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_heads, s_c // bq, mb),
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda h, i, j, tab, p0: (h, i, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda h, i, j, tab, p0: (tab[j], 0, h // g, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda h, i, j, tab, p0: (tab[j], 0, h // g, 0)),
+            kv_blk, kv_blk,
         ],
         out_specs=pl.BlockSpec((1, bq, hd),
                                lambda h, i, j, tab, p0: (h, i, 0)),
@@ -261,7 +258,8 @@ def paged_prefill_attn(q, k_pool_l, v_pool_l, table, pos0):
         out_shape=jax.ShapeDtypeStruct((n_heads, s_c, hd), jnp.float32),
         interpret=_interpret(),
     )(table.astype(jnp.int32), jnp.asarray(pos0, jnp.int32).reshape(1),
-      q, k_pool_l, v_pool_l)
+      q, k_pool_l.reshape(nb, bs, n_kv * hd),
+      v_pool_l.reshape(nb, bs, n_kv * hd))
 
 
 # -- full layer-stack twins (jitted by llm_exec) -----------------------------

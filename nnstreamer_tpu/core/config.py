@@ -68,9 +68,9 @@ _DEFAULTS: Dict[str, Dict[str, str]] = {
     },
     "serving": {
         # persistent XLA compile cache + bucket manifest for store://
-        # models (serving/compile_cache.py); opt-in
+        # models; opt-in. serving/compile_cache.py decides where it
+        # lives ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
         "compile_cache": "0",
-        "compile_cache_dir": "~/.cache/nnstreamer_tpu/xla",
     },
 }
 

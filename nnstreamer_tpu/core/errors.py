@@ -70,6 +70,21 @@ class SegmentStageError(BackendError):
         self.member = member
 
 
+class WindowBuildError(BackendError):
+    """The compiled steady-state window (K frames through one
+    ``lax.scan``) could not be traced or compiled for the device. Not
+    an element error on a frame: the scheduler lets it through instead
+    of counting a ``bail("error")`` and re-running per-frame, which
+    would hide a window that can never run."""
+
+
+class ChipLeaseError(BackendError):
+    """A worker pool was asked for more device workers than it has
+    chips to lease. A chip belongs to one process at a time, so the
+    surplus workers could only fail or hang at backend start-up (and
+    the supervisor would restart them forever) — refused up front."""
+
+
 class StreamError(NNStreamerTPUError):
     """Runtime dataflow failure (the GST_FLOW_ERROR analog)."""
 

@@ -57,9 +57,9 @@ class TensorLLM(Element):
     - eos_id: stop token (-1 disables); max_new_tokens: token budget.
     - paged_kernel: "pallas" (paged flash attention, backends/
       pallas_paged.py) or "xla" (the bit-reference, llm/paged_model.py);
-      "" defers to $NNS_PAGED_KERNEL then defaults to xla. An
-      unavailable Pallas path serves on XLA and counts a
-      kernel_fallback — never an error.
+      "" defers to $NNS_PAGED_KERNEL then defaults to xla. The kernel
+      asked for is the kernel that runs: a Pallas kernel the compiler
+      refuses is a BackendError, never a switch to XLA.
     - prefill_chunk: prompts longer than this prefill in N-token chunks
       interleaved with decode steps (0 = whole-prompt prefill), so a
       long prompt does not head-of-line block the batch's inter-token
@@ -68,7 +68,7 @@ class TensorLLM(Element):
       one mesh-sharded backend over N leased chips with head-sharded
       projections and KV pools (docs/sharded_serving.md); bit-identical
       to shards=1 by the canonical-blocking construction. Exclusive
-      with prefill_chunk and pallas.
+      with prefill_chunk and paged_kernel=pallas (typed refusals).
     - ring_prefill_min: with shards>0, prompts at least this long
       prefill through sequence-parallel ring attention over the same
       chips (allclose-, not bit-, equivalent; decode stays bit-exact).
@@ -166,6 +166,11 @@ class TensorLLM(Element):
                     "prefill_chunk and shards are exclusive — sharded "
                     "long prompts use ring_prefill_min (sequence-"
                     "parallel ring prefill), not chunking")
+            if kern == "pallas":
+                self.fail_negotiation(
+                    "paged_kernel=pallas and shards are exclusive — the "
+                    "paged Pallas kernels are single-chip, the sharded "
+                    "path is XLA-only")
         elif int(self.props["ring_prefill_min"]) > 0:
             self.fail_negotiation(
                 "ring_prefill_min needs shards>0 (ring prefill runs "

@@ -94,8 +94,7 @@ class BatchCore:
         # completion stage: device results queue here and a second
         # thread performs the host readback + future resolution, so the
         # batcher can dispatch batch N+1 while batch N's D2H is still in
-        # flight (the readback dominates on remote/tunneled hosts —
-        # overlapping it measured ~4x offload throughput)
+        # flight
         import queue as _q
 
         self._done_q: "_q.Queue" = _q.Queue(maxsize=4)

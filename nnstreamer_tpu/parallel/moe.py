@@ -30,10 +30,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from nnstreamer_tpu.parallel._compat import shard_map
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,

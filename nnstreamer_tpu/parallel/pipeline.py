@@ -30,10 +30,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from nnstreamer_tpu.parallel._compat import shard_map
 
 
 def stack_stage_params(per_stage_params) -> Any:

@@ -22,10 +22,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nnstreamer_tpu.parallel._compat import shard_map
 
 NEG_INF = -1e30
 

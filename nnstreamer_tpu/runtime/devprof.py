@@ -109,12 +109,26 @@ def peak_for(device_kind: str) -> Tuple[float, float]:
     return 0.0, 0.0
 
 
+def require_peak(device_kind: str) -> Tuple[float, float]:
+    """`peak_for`, for callers that print an MFU or a roofline share: a
+    device that is not in the table is an error, not a zero (or another
+    chip's) denominator."""
+    tflops, gbps = peak_for(device_kind)
+    if not tflops or not gbps:
+        raise ValueError(
+            f"no declared peak for device_kind {device_kind!r}: an MFU or "
+            f"roofline figure needs PEAK_TFLOPS / PEAK_HBM_GBPS entries "
+            f"(runtime/devprof.py) with their source")
+    return tflops, gbps
+
+
 class DeviceProfiler:
     """Process-wide cost registry + invoke reservoirs + HBM ledger.
 
     Off by default: every hot-path hook starts with an ``enabled``
     check, so the plane costs one attribute read until something
-    (serve --metrics-port, bench's devprof arm, a test) turns it on.
+    (bench's devprof arm, a test) turns it on in the process that owns
+    the chip.
     Thread model: registry and reservoirs are dict/deque appends under
     one lock taken only on compile events and sync samples (both
     orders of magnitude rarer than frames); the dispatch stamp is
@@ -254,22 +268,16 @@ class DeviceProfiler:
 
     def _device_meta(self) -> Dict[str, Any]:
         """Platform/device-kind/count, cached after first read (device
-        topology does not change mid-process)."""
-        if self._device_info is not None:
-            return self._device_info
-        info = {"platform": "none", "device_kind": "none", "devices": 0}
-        try:
+        topology does not change mid-process). A backend that cannot
+        initialise raises: the profile names the device it measured."""
+        if self._device_info is None:
             import jax
 
             devs = jax.devices()
-            if devs:
-                info = {"platform": devs[0].platform,
-                        "device_kind": devs[0].device_kind,
-                        "devices": len(devs)}
-        except Exception:
-            pass
-        self._device_info = info
-        return info
+            self._device_info = {"platform": devs[0].platform,
+                                 "device_kind": devs[0].device_kind,
+                                 "devices": len(devs)}
+        return self._device_info
 
     def hbm_rows(self) -> List[Dict[str, Any]]:
         """Per-device memory ledger rows {device, kind, bytes} from

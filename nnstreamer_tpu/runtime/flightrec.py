@@ -21,11 +21,10 @@ disk flood):
   scan's worth of slack absorbs the benign mid-flight read races the
   conservation tests allow
 - ``worker_fence``   — a worker/host kill|fence lifecycle event
-- ``kernel_fallback``— a requested Pallas path served on XLA
 - ``watchdog``       — a watchdog incident recorded by the tracer
 - ``manual``         — operator-requested dump (CLI / tests)
 
-Counter-derived triggers (fence, fallback, watchdog) are watermarked:
+Counter-derived triggers (fence, watchdog) are watermarked:
 the first observation of a source only sets the baseline, so attaching
 the recorder to a system with historical faults does not dump.
 
@@ -50,8 +49,7 @@ log = get_logger("runtime.flightrec")
 #: the trigger kinds a recorder can fire (fixed taxonomy; cause.json
 #: carries the evidence)
 TRIGGERS = ("slo_breach", "conservation", "worker_fence",
-            "kernel_fallback", "watchdog", "manual",
-            "scenario_violation")
+            "watchdog", "manual", "scenario_violation")
 
 DEFAULT_COOLDOWN_S = 60.0
 
@@ -130,8 +128,7 @@ class FlightRecorder:
              p99_budget_ms: Optional[float] = None,
              admission: Optional[dict] = None,
              worker_counts: Optional[dict] = None,
-             watchdog_counts: Optional[dict] = None,
-             kernel_fallbacks: Optional[float] = None) -> List[str]:
+             watchdog_counts: Optional[dict] = None) -> List[str]:
         """Evaluate every predicate against one round of signals and
         dump for each that fires; returns the kinds that dumped."""
         fired: List[str] = []
@@ -173,13 +170,9 @@ class FlightRecorder:
                     hit(kind, {"count": total, "events": {
                         k: (dict(v) if isinstance(v, dict) else v)
                         for k, v in counts.items()}})
-        if kernel_fallbacks is not None \
-                and self._rose("kernel_fallback", float(kernel_fallbacks)):
-            hit("kernel_fallback", {"count": float(kernel_fallbacks)})
         return fired
 
-    def poll(self, *, admission: Optional[dict] = None,
-             llm: Optional[Dict[str, dict]] = None) -> List[str]:
+    def poll(self, *, admission: Optional[dict] = None) -> List[str]:
         """One recorder pass over attached + passed sources: snapshot
         tick, then scan.  The serve loop's poller calls this."""
         self.tick()
@@ -191,10 +184,6 @@ class FlightRecorder:
                     if k in ("kill", "fence", "fenced", "killed")}
                 for n, kinds in tr.worker_counts().items()}
             kw["watchdog_counts"] = tr.watchdog_counts()
-        if llm:
-            kw["kernel_fallbacks"] = sum(
-                float(st.get("executor", st).get("kernel_fallback", 0))
-                for st in llm.values())
         return self.scan(**kw)
 
     def _rose(self, key: str, total: float) -> bool:

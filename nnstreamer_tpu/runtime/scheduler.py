@@ -65,7 +65,8 @@ from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from nnstreamer_tpu.core.config import get_config
-from nnstreamer_tpu.core.errors import PipelineError, StreamError
+from nnstreamer_tpu.core.errors import (
+    PipelineError, StreamError, WindowBuildError)
 from nnstreamer_tpu.core.log import get_logger
 from nnstreamer_tpu.graph.pipeline import Element, Link, Pipeline, SourceElement
 from nnstreamer_tpu.runtime.channel import CLOSED, TIMED_OUT, Channel
@@ -1144,7 +1145,8 @@ class PipelineRunner:
         K buffers of dt/K each (plus per-frame queue waits and tracer
         process spans), and an errored window re-runs its frames
         per-frame so the error policy lands on the precise frame that
-        faulted.
+        faulted. A window that cannot be *built* (`WindowBuildError`)
+        is not an element error on a frame and fails the pipeline.
         """
         now = time.perf_counter()
         # entry bails: state the jitted window must not bake in. Both
@@ -1214,6 +1216,11 @@ class PipelineRunner:
         self._inflight[elem.name] = time.monotonic()
         try:
             emissions = elem.process_window(pad, [m[1] for m in batch])
+        except WindowBuildError:
+            # the window itself cannot be traced/compiled for the
+            # device: re-running per-frame would carry the whole run on
+            # the path the window was meant to replace — surface it
+            raise
         except Exception:
             # re-run every frame through the per-frame path so the
             # error (and its fail-fast policy) lands on the precise

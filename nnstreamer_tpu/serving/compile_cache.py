@@ -2,10 +2,8 @@
 
 Two cooperating layers (docs/serving.md):
 
-- **XLA executable cache**: ``jax_compilation_cache_dir`` pointed at a
-  persistent directory, so the *compilations* themselves survive
-  process restarts (the same mechanism bench.py uses across family
-  subprocesses).
+- **XLA executable cache**: jax's persistent compilation cache, so the
+  *compilations* themselves survive process restarts.
 - **Bucket manifest**: XLA's cache is keyed by HLO — it can only hit
   once something asks to compile. The manifest records *what to ask
   for*: every (model name, version) → the compile-bucket set it has
@@ -15,10 +13,19 @@ Two cooperating layers (docs/serving.md):
   hot path — against a warm XLA disk cache those are fast loads, not
   recompiles.
 
-Configured via the ``[serving]`` group in core/config.py (opt-in:
-``compile_cache=1``; env ``NNSTREAMER_TPU_SERVING_COMPILE_CACHE=1``).
-Every disk write is best-effort — the cache is an optimization, never
-a gate.
+This module is the one place that decides where the cache lives
+(`resolve_dir`): the directory ``JAX_COMPILATION_CACHE_DIR`` names —
+jax reads that variable itself, so nothing here sets a directory —
+else ``<checkout>/.jax_cache``, derived from this package's own
+location. The path is part of the cache's key; one that moved with
+the home directory, a pid or the time would never hit.
+
+`enable_compile_cache()` turns it on (bench.py, chip_smoke.py);
+`maybe_enable_compile_cache()` does so when the ``[serving]`` config
+group opts in (``compile_cache=1``; env
+``NNSTREAMER_TPU_SERVING_COMPILE_CACHE=1``), which is how ``store://``
+filters reach it. Manifest writes are best-effort — the cache is an
+optimization, never a gate.
 """
 
 from __future__ import annotations
@@ -26,7 +33,7 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from nnstreamer_tpu.core.config import get_config
 from nnstreamer_tpu.core.log import get_logger
@@ -34,54 +41,68 @@ from nnstreamer_tpu.core.log import get_logger
 log = get_logger("serving.cache")
 
 _lock = threading.Lock()
-_enabled: Optional[bool] = None     # memoized maybe_enable verdict
-_dir: Optional[str] = None
+_dir: Optional[str] = None          # set once the cache is on
+
+#: <checkout>/.jax_cache (git-ignored): this file is
+#: <checkout>/nnstreamer_tpu/serving/compile_cache.py
+_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def reset() -> None:
-    """Forget the memoized enable verdict (tests re-point the config)."""
-    global _enabled, _dir
+    """Forget that the cache was enabled (tests re-point it)."""
+    global _dir
     with _lock:
-        _enabled = None
         _dir = None
 
 
 def cache_dir() -> Optional[str]:
-    return _dir if _enabled else None
+    """The directory in use, None while the cache is off."""
+    return _dir
+
+
+def resolve_dir() -> Tuple[str, bool]:
+    """(directory, from_env): where the cache and its manifest go, and
+    whether ``JAX_COMPILATION_CACHE_DIR`` chose it."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return (env, True) if env else (_CHECKOUT_DIR, False)
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its
+    directory. Idempotent. Raises OSError when the directory cannot be
+    created."""
+    global _dir
+    with _lock:
+        if _dir is not None:
+            return _dir
+        import jax
+
+        d, from_env = resolve_dir()
+        os.makedirs(d, exist_ok=True)
+        if not from_env:
+            jax.config.update("jax_compilation_cache_dir", d)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        _dir = d
+        log.info("persistent compile cache at %s", d)
+        return d
 
 
 def maybe_enable_compile_cache() -> bool:
-    """Wire jax's persistent compilation cache per the ``[serving]``
-    config group. Idempotent; returns whether the cache is active."""
-    global _enabled, _dir
-    with _lock:
-        if _enabled is not None:
-            return _enabled
-        cfg = get_config()
-        if not cfg.get_bool("serving", "compile_cache", False):
-            _enabled = False
-            return False
-        d = os.path.expanduser(
-            cfg.get("serving", "compile_cache_dir")
-            or "~/.cache/nnstreamer_tpu/xla")
-        try:
-            os.makedirs(d, exist_ok=True)
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", d)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-            except Exception:
-                pass             # older jax: keep its default threshold
-        except Exception as e:
-            log.warning("compile cache disabled: %s", e)
-            _enabled = False
-            return False
-        _dir = d
-        _enabled = True
-        log.info("persistent compile cache at %s", d)
+    """Enable the cache when the ``[serving]`` config group opts in.
+    Returns whether the cache is active."""
+    if _dir is not None:
         return True
+    if not get_config().get_bool("serving", "compile_cache", False):
+        return False
+    try:
+        enable_compile_cache()
+    except OSError as e:
+        log.warning("compile cache disabled: %s", e)
+        return False
+    return True
 
 
 # -- bucket manifest ---------------------------------------------------------

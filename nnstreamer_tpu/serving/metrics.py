@@ -543,12 +543,6 @@ def metrics_snapshot(tracer=None, admission: Optional[dict] = None,
              for k, v in sorted(ex.get("kernel_invokes", {}).items())]
             or [({"element": "none", "kernel": "none"}, 0.0)]))
         out.append(_series(
-            f"{ns}_llm_kernel_fallback_total", "counter",
-            "requested Pallas paths served on XLA instead (kernel "
-            "unavailable or failed to build — counted, never an error)",
-            [({"element": el}, float(ex.get("kernel_fallback", 0)))
-             for el, _, ex in rows]))
-        out.append(_series(
             f"{ns}_llm_paged_kernel_info", "gauge",
             "1 for the attention kernel currently selected",
             [({"element": el,

@@ -61,12 +61,13 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence
 
-from nnstreamer_tpu.core.errors import StreamError
+from nnstreamer_tpu.core.errors import ChipLeaseError, StreamError
 from nnstreamer_tpu.core.log import get_logger
 from nnstreamer_tpu.edge.query import QueryServer
 from nnstreamer_tpu.edge.wire import encode_buffer
 from nnstreamer_tpu.runtime.tracing import NULL_TRACER, get_trace_ctx
-from nnstreamer_tpu.serving.worker import RID_META, WorkerSpec, worker_main
+from nnstreamer_tpu.serving.worker import (
+    CHIP_BOUNDS, RID_META, WorkerSpec, worker_main)
 from nnstreamer_tpu.tensor.info import TensorsSpec
 
 log = get_logger("serving.pool")
@@ -174,6 +175,14 @@ class _Slot:
         return now - max(self.last_hb, self.started_t)
 
 
+def _host_platform_only() -> bool:
+    """Whether this process tree is pinned to JAX's host platform
+    (``JAX_PLATFORMS=cpu``, inherited by every spawned worker): then
+    no worker takes a chip and any number may run. Read from the
+    environment — the supervisor never initialises a JAX backend."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
 class WorkerPool:
     """Supervised pool of worker processes behind one QueryServer
     (module docstring). Use `PooledQueryServer` unless you already own
@@ -206,11 +215,28 @@ class WorkerPool:
         # as K capacity slots (capacity_slots / slot_weights).
         self.chip_table = None
         self._chips_per_slot = 0
+        if spec.kind != "echo" and not _host_platform_only() \
+                and workers > (len(chips) if chips else 1):
+            # pipeline / multiplex workers each open a JAX backend, and
+            # a chip belongs to one process at a time. Without a lease
+            # the one worker owns every chip of the host; with one,
+            # each worker narrows itself to its own chips (worker.py).
+            raise ChipLeaseError(
+                f"pool {name}: {workers} device workers but "
+                f"{len(chips) if chips else 'no'} chip(s) leased"
+                f"{'' if chips else ' (an unleased pool runs one)'}: "
+                f"lease one chip per worker (serve --chips 0,1,…), "
+                f"lower --workers, or set JAX_PLATFORMS=cpu to serve "
+                f"from the host platform")
         if chips:
             if len(chips) % workers != 0:
                 raise ValueError(
                     f"chips ({len(chips)}) must divide evenly across "
                     f"workers ({workers})")
+            if len(chips) // workers not in CHIP_BOUNDS:
+                raise ValueError(
+                    f"{len(chips) // workers} chips per worker: a worker "
+                    f"narrows itself to {sorted(CHIP_BOUNDS)} chips")
             from nnstreamer_tpu.serving.placement import ChipLeaseTable
 
             self.chip_table = ChipLeaseTable(chips)
@@ -1251,8 +1277,10 @@ class PooledQueryServer:
                     spec, tenants=tenants.to_dict())
         if tracer is not None:
             self.qs.tracer = tracer
-        self.qs.start(host, port)
+        # built before the server binds: a refused pool (ChipLeaseError)
+        # leaves no listening socket behind
         self.pool = WorkerPool(self.qs, spec, workers, **pool_kwargs)
+        self.qs.start(host, port)
         if tenants is not None:
             self.pool.set_tenants(tenants)
         self.pool.start(ready_timeout_s=ready_timeout_s)

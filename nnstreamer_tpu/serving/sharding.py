@@ -100,10 +100,14 @@ def shard_devices(indices: Sequence[int]) -> list:
     devs = visible_devices()
     for i in indices:
         if not 0 <= int(i) < len(devs):
+            found = (f"{len(devs)} {devs[0].platform} device(s) "
+                     f"({devs[0].device_kind})")
+            hint = ("; to emulate more on the host platform run under "
+                    "XLA_FLAGS=--xla_force_host_platform_device_count=N"
+                    if devs[0].platform == "cpu" else
+                    "; lower shards/devices to what this host has")
             raise BackendError(
-                f"shard group wants device {i} but only {len(devs)} "
-                f"visible; run under "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count=N")
+                f"shard group wants device {i} but found {found}{hint}")
     return [devs[int(i)] for i in indices]
 
 
@@ -285,7 +289,7 @@ class ShardedBackend:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from nnstreamer_tpu.parallel._compat import shard_map
+        from jax import shard_map
 
         key = (version,) + tuple(sig)
         with self._lock:
@@ -663,7 +667,7 @@ def make_llm_fns(mesh, param_spec_tree, mesh_devices=None):
     `replicate_params` — and is allclose-, not bit-, equivalent."""
     from jax.sharding import PartitionSpec as P
 
-    from nnstreamer_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     pool = kv_pool_specs()
 

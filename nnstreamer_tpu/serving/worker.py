@@ -109,9 +109,10 @@ class WorkerSpec:
     shm_req: str = ""                     # parent→child payload ring
     shm_res: str = ""                     # child→parent payload ring
     # chip ownership (serving/placement.ChipLeaseTable): device ordinals
-    # this worker is leased. Informational to the child (it pins its
-    # own placement from these); authoritative to the SUPERVISOR, which
-    # fences the chips when the worker dies and re-leases them to the
+    # this worker is leased. The child narrows itself to exactly these
+    # chips before it first imports jax (`_narrow_to_chips`), so N
+    # workers on one host never ask for the same chip; the SUPERVISOR
+    # fences them when the worker dies and re-leases them to the
     # replacement — a K-chip worker counts as K slots of capacity in
     # the scaler (tenancy.ScalingController).
     chips: tuple = ()
@@ -127,6 +128,31 @@ class WorkerSpec:
         if self.kind == "multiplex" and not self.tenants:
             raise ValueError("WorkerSpec(kind='multiplex') needs a "
                              "tenants table (TenantTable.to_dict())")
+
+
+#: libtpu's per-process topology for a K-chip slice of one host
+CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def _narrow_to_chips(chips: tuple) -> None:
+    """Make this process see only its leased chips. libtpu reads these
+    variables when the backend initialises, so this runs first thing in
+    the child, before anything imports jax. A chip belongs to one
+    process at a time: un-narrowed, every worker on a host would open
+    the same chips and all but the first would fail or hang. On the
+    host platform (JAX_PLATFORMS=cpu) the variables are inert."""
+    if not chips:
+        return
+    bounds = CHIP_BOUNDS.get(len(chips))
+    if bounds is None:
+        raise ValueError(
+            f"a worker can be leased {sorted(CHIP_BOUNDS)} chips, "
+            f"got {len(chips)}: {chips}")
+    os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(int(c)) for c in chips)
+    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+    os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    # several processes of one host each load libtpu for their own chips
+    os.environ["ALLOW_MULTIPLE_LIBTPU_LOAD"] = "1"
 
 
 def _pickle_exc(exc: BaseException) -> bytes:
@@ -534,6 +560,7 @@ def worker_main(conn, spec: WorkerSpec, wid: int = 0) -> None:
     The loop is deliberately sequential per worker — concurrency comes
     from the POOL running N of these processes, which is the whole
     point: one wedged/GIL-bound worker never slows its siblings."""
+    _narrow_to_chips(spec.chips)
     send_lock = threading.Lock()
 
     # same-host shm lane: attach the supervisor's rings, or silently

@@ -22,7 +22,7 @@ import pytest
 import nnstreamer_tpu as nns
 from nnstreamer_tpu.elements.sinks import TensorSink
 from nnstreamer_tpu.elements.sources import AppSrc
-from nnstreamer_tpu.core.errors import StreamError
+from nnstreamer_tpu.core.errors import StreamError, WindowBuildError
 from nnstreamer_tpu.graph.pipeline import Element
 from nnstreamer_tpu.runtime.compiled_loop import (
     BAIL_CAUSES, LoopStats, SteadyStateDetector, frame_signature)
@@ -291,6 +291,44 @@ class TestBailMatrix:
         pf = [c[1] for c in dbl.calls if c[0] == "pf"]
         assert pf[:5] == [0, 1, 2, 3, 4]
 
+    def test_window_build_failure_surfaces(self):
+        """A window that cannot be BUILT is not an element error on a
+        frame: no counted bail, no per-frame re-run carrying the run —
+        the pipeline fails with the typed error."""
+        class Unbuildable(Doubler):
+            def process_window(self, pad, bufs):
+                self.calls.append(("win", [b.pts for b in bufs]))
+                raise WindowBuildError("scan does not compile here")
+
+        pipe = nns.Pipeline("cl_unbuildable")
+        frames = _frames(10)
+        spec = TensorsSpec.of(TensorInfo(
+            frames[0].shape, DType.from_name(frames[0].dtype.name)))
+        src = AppSrc(spec=spec, name="src")
+        dbl = Unbuildable(name="d")
+        sink = TensorSink(name="out")
+        for e in (src, dbl, sink):
+            pipe.add(e)
+        pipe.link(src, dbl)
+        pipe.link(dbl, sink)
+        for i, x in enumerate(frames):
+            src.push(TensorBuffer.of(x, pts=i))
+        src.end()
+        r = nns.PipelineRunner(pipe, compiled_loop=True,
+                               compiled_loop_arm=2,
+                               compiled_loop_window=4,
+                               queue_capacity=16)
+        r.start()
+        with pytest.raises(StreamError):
+            r.wait(60)
+        assert isinstance(r._error.__cause__ or r._error,
+                          WindowBuildError)
+        st = r.stats()["d"]
+        assert st["loop_bails"].get("error", 0) == 0
+        assert st["loop_entries"] == 0
+        # nothing re-ran per-frame behind the failed window
+        assert dbl.calls == [("pf", 0), ("win", [1, 2, 3, 4])]
+
     def test_swap_pending_is_a_transient_bail(self):
         res, dbl, st = _run(_frames(10), arm=2, window=4, swap_bails=1)
         # the first armed attempt bails (swap adoption happens
@@ -366,6 +404,35 @@ class TestBackendWindowParity:
             assert be.window_frames >= 4
         finally:
             filt.stop()
+
+    def test_window_that_cannot_compile_raises_build_error(
+            self, monkeypatch):
+        """The backend builds the K-frame scan ahead of running it and
+        names a failure for what it is (the scheduler lets this type
+        through instead of bailing to per-frame)."""
+        import jax
+
+        from nnstreamer_tpu.backends.xla import ModelBundle, XLABackend
+
+        be = XLABackend()
+        be.open({"model": ModelBundle(
+            fn=lambda params, x: (x * 2.0,), params=None, name="m")})
+        try:
+            x = np.ones((2, 3), np.float32)
+
+            def refuse(*a, **k):
+                raise NotImplementedError("scan refused by the compiler")
+
+            with monkeypatch.context() as mp:
+                mp.setattr(jax.lax, "scan", refuse)
+                with pytest.raises(WindowBuildError, match="2-frame"):
+                    be.invoke_window([(x,), (x,)])
+            assert be.window_invokes == 0
+            # the failed bucket was not cached: a later build succeeds
+            out = be.invoke_window([(x,), (x,)])
+            assert np.asarray(out[1][0])[0, 0] == 2.0
+        finally:
+            be.close()
 
 
 # -- paged-LLM decode window --------------------------------------------------

@@ -369,9 +369,11 @@ class TestFlightRecorder:
         # a RISE past the watermark does
         assert rec.scan(worker_counts={"pool": {"kill": 4}}) == \
             ["worker_fence"]
-        # same for kernel fallbacks
-        assert rec.scan(kernel_fallbacks=2.0) == []
-        assert rec.scan(kernel_fallbacks=3.0) == ["kernel_fallback"]
+        # same for watchdog incidents
+        wd = {"el": {"stall": 2}}
+        assert rec.scan(watchdog_counts=wd) == []
+        assert rec.scan(watchdog_counts={"el": {"stall": 3}}) == \
+            ["watchdog"]
 
     def test_bundle_is_complete_and_atomic(self, tmp_path):
         rec, _ = self._rec(tmp_path)

@@ -594,6 +594,8 @@ _ERR_INSTANCES = [
     errors_mod.PipelineError("unbalanced tee"),
     errors_mod.BackendError("xla open failed"),
     errors_mod.SegmentStageError("conv0", ValueError("bad trace")),
+    errors_mod.WindowBuildError("4-frame window could not be built"),
+    errors_mod.ChipLeaseError("2 device workers but 1 chip(s) leased"),
     errors_mod.StreamError("flow error"),
     errors_mod.ServerBusyError(
         "server busy", queue_depth=17, retry_after_ms=12.5,

@@ -630,7 +630,7 @@ def test_nnl012_blessed_in_parallel_and_sharding():
     assert_silent("NNL012", {
         "nnstreamer_tpu/serving/sharding.py": BAD_SHARDING,
         "nnstreamer_tpu/parallel/ring_attention.py": BAD_SHARDING,
-        "nnstreamer_tpu/parallel/_compat.py": BAD_SHARDING,
+        "nnstreamer_tpu/parallel/moe.py": BAD_SHARDING,
     })
 
 

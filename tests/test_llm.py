@@ -364,7 +364,7 @@ def test_tensor_llm_pallas_chunked_matches_generate(params):
     ex = stats["executor"]
     assert ex["paged_kernel"] == "pallas"
     assert ex["kernel_invokes"]["pallas"] > 0
-    assert ex["kernel_fallback"] == 0
+    assert ex["kernel_invokes"]["xla"] == 0
     reset_store()
 
 

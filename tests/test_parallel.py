@@ -368,16 +368,16 @@ def test_mesh_spec_resolve_edge_cases(eight_cpu_devices):
         MeshSpec(dp=4, tp=4).resolve(8)
 
 
-def test_compat_shard_map_is_the_single_source():
-    """Satellite guard: every shard_map consumer goes through the
-    `parallel/_compat` shim (one copy of the jax-version import dance),
-    and the shim accepts the modern `check_vma` keyword."""
-    from nnstreamer_tpu.parallel import _compat, moe, pipeline, ring_attention
+def test_shard_map_is_jax_shard_map():
+    """Every shard_map consumer in parallel/ uses `jax.shard_map` itself
+    (the `check_vma` spelling) — no experimental import, no shim."""
+    import jax
 
-    assert moe.shard_map is _compat.shard_map
-    assert pipeline.shard_map is _compat.shard_map
-    assert ring_attention.shard_map is _compat.shard_map
-    assert callable(_compat.shard_map)
+    from nnstreamer_tpu.parallel import moe, pipeline, ring_attention
+
+    assert moe.shard_map is jax.shard_map
+    assert pipeline.shard_map is jax.shard_map
+    assert ring_attention.shard_map is jax.shard_map
 
 
 def test_block_attn_streaming_accumulator_matches_reference(

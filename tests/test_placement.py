@@ -20,6 +20,7 @@ with Σ per-chip invokes equal to the filter's invoke count.
 """
 
 import itertools
+import os
 import time
 
 import numpy as np
@@ -467,6 +468,49 @@ class TestPoolChips:
         with pytest.raises(ValueError, match="divide"):
             WorkerPool(QueryServer.get(next(_sid)),
                        WorkerSpec(kind="echo"), 2, chips=[0, 1, 2])
+
+    def test_more_device_workers_than_chips_refused(self, monkeypatch):
+        """A chip belongs to one process at a time: a pipeline pool with
+        more workers than leased chips is a typed error at construction,
+        not N processes asking for the same chip. Pinned to the host
+        platform there is no chip to fight over."""
+        from nnstreamer_tpu.core.errors import ChipLeaseError
+
+        spec = WorkerSpec(kind="pipeline",
+                          pipeline="tensor_transform mode=typecast "
+                                   "option=float32")
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(ChipLeaseError, match="2 device workers"):
+            WorkerPool(QueryServer.get(next(_sid)), spec, 2)
+        with pytest.raises(ChipLeaseError, match="1 chip"):
+            WorkerPool(QueryServer.get(next(_sid)), spec, 2, chips=[0])
+        # one unleased worker owns the host; leased, one chip each
+        WorkerPool(QueryServer.get(next(_sid)), spec, 1)
+        WorkerPool(QueryServer.get(next(_sid)), spec, 2, chips=[0, 1])
+        # echo workers never open a backend
+        WorkerPool(QueryServer.get(next(_sid)), WorkerSpec(kind="echo"), 4)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        WorkerPool(QueryServer.get(next(_sid)), spec, 2)
+
+    def test_worker_narrows_itself_to_leased_chips(self, monkeypatch):
+        """What the child does first, before anything imports jax."""
+        from nnstreamer_tpu.serving.worker import _narrow_to_chips
+
+        names = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                 "TPU_PROCESS_BOUNDS", "ALLOW_MULTIPLE_LIBTPU_LOAD")
+        for n in names:                  # restored by monkeypatch
+            monkeypatch.delenv(n, raising=False)
+        _narrow_to_chips(())
+        assert not any(n in os.environ for n in names)
+        _narrow_to_chips((2, 3))
+        assert os.environ["TPU_VISIBLE_CHIPS"] == "2,3"
+        assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+        assert os.environ["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        with pytest.raises(ValueError, match="chips"):
+            _narrow_to_chips((0, 1, 2))
+        with pytest.raises(ValueError, match="per worker"):
+            WorkerPool(QueryServer.get(next(_sid)),
+                       WorkerSpec(kind="echo"), 2, chips=list(range(6)))
 
     def test_partition_weights_and_stats(self):
         pqs = PooledQueryServer.echo(

@@ -11,8 +11,14 @@ marker so a constrained CI lane can deselect them (`-m 'not chaos'`).
 """
 
 import itertools
+import os
+import re
+import signal
+import subprocess
+import sys
 import threading
 import time
+import urllib.request
 
 import numpy as np
 import pytest
@@ -386,3 +392,40 @@ class TestPoolTracing:
                 for _, _, _, hops, _ in tr.requests())
         finally:
             pqs.close()
+
+
+# -- serve CLI: one process per chip -----------------------------------------
+
+def test_serve_supervisor_never_loads_jax(tmp_path):
+    """`serve --metrics-port --flight-dir`: the supervisor answers a
+    scrape and drains without ever loading jaxlib — it initialises no
+    backend, so it holds no chip for its workers to fight over."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "nnstreamer_tpu", "serve", "--workers", "1",
+         "--metrics-port", "0", "--flight-dir", str(tmp_path / "flight")],
+        stderr=subprocess.PIPE, text=True, cwd=root)
+    watchdog = threading.Timer(60.0, proc.kill)
+    watchdog.start()
+    try:
+        port, seen = None, []
+        for line in proc.stderr:
+            seen.append(line)
+            m = re.search(r"metrics on http://[\d.]+:(\d+)/metrics", line)
+            if m:
+                port = int(m.group(1))
+            if "pool serving on" in line:
+                break
+        assert port is not None, "".join(seen)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/metrics", timeout=10) as r:
+            assert r.status == 200 and b"nns_" in r.read()
+        with open(f"/proc/{proc.pid}/maps") as f:
+            maps = f.read()
+        assert "jaxlib" not in maps and "libtpu" not in maps
+        proc.send_signal(signal.SIGTERM)
+        assert proc.wait(30) == 0
+    finally:
+        watchdog.cancel()
+        if proc.poll() is None:
+            proc.kill()

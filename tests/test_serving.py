@@ -435,15 +435,20 @@ class TestCanary:
 class TestCompileCache:
     def test_manifest_roundtrip_and_warm_start(self, _fresh_store,
                                                tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set the directory is jax's to
+        read: enabling the cache leaves jax.config untouched and the
+        manifest lands in that same directory."""
         monkeypatch.setenv("NNSTREAMER_TPU_SERVING_COMPILE_CACHE", "1")
-        monkeypatch.setenv("NNSTREAMER_TPU_SERVING_COMPILE_CACHE_DIR",
-                           str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         compile_cache.reset()
         import jax
+        before = jax.config.jax_compilation_cache_dir
         try:
             store = _fresh_store
             store.register("det", _v1)
             b = _open_backend("store://det")
+            assert compile_cache.cache_dir() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
             b.invoke_batched((np.ones((3, 4), np.float32),), 3,
                              keepdims=(False,))
             b.invoke((np.ones(4, np.float32),))
@@ -467,7 +472,21 @@ class TestCompileCache:
             b2.close()
         finally:
             compile_cache.reset()
-            jax.config.update("jax_compilation_cache_dir", None)
+
+    def test_unset_resolves_to_checkout(self, tmp_path, monkeypatch):
+        """Without the variable the cache is <checkout>/.jax_cache,
+        whatever HOME and the working directory are."""
+        import nnstreamer_tpu
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("HOME", str(tmp_path / "home"))
+        monkeypatch.chdir(tmp_path)
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(nnstreamer_tpu.__file__)))
+        assert compile_cache.resolve_dir() == (
+            os.path.join(checkout, ".jax_cache"), False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert compile_cache.resolve_dir() == ("/some/dir", True)
 
     def test_disabled_by_default(self, _fresh_store):
         assert compile_cache.maybe_enable_compile_cache() is False

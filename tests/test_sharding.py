@@ -337,17 +337,12 @@ class TestShardedExclusions:
                       num_blocks=16, max_len=64, shards=2,
                       prefill_chunk=8)
 
-    def test_pallas_falls_back_counted(self, eight_cpu_devices,
-                                       llm_params):
-        ex = PagedLLMExecutor(dict(llm_params), n_heads=8, block_size=8,
-                              num_blocks=16, max_len=64, shards=2,
-                              paged_kernel="pallas", name="pk")
-        try:
-            st = ex.stats()
-            assert st["paged_kernel"] == "xla"
-            assert st["kernel_fallback"] >= 1
-        finally:
-            ex.close()
+    def test_pallas_with_shards_refused(self, eight_cpu_devices,
+                                        llm_params):
+        with pytest.raises(BackendError, match="single-chip"):
+            PagedLLMExecutor(dict(llm_params), n_heads=8, block_size=8,
+                             num_blocks=16, max_len=64, shards=2,
+                             paged_kernel="pallas", name="pk")
 
     def test_quantized_params_refused_float_only(
             self, eight_cpu_devices, llm_params):
