@@ -63,6 +63,16 @@ from nnstreamer_tpu.runtime.tracing import NULL_TRACER
 
 log = get_logger("backends.llm")
 
+#: jax.monitoring duration events of a first call -> the label of the
+#: child span they become under the call's `compile` span
+_FIRST_CALL_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "jax_backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "jax_cache_retrieval",
+}
+
 
 def _derive_dims(params: dict, n_heads: int) -> dict:
     """Model dims from the transformer params pytree itself (the only
@@ -196,6 +206,16 @@ class PagedLLMExecutor:
         # decode steps served through a window
         self.decode_windows = 0
         self.window_steps = 0
+        # first-call anatomy: a list from a jit miss (_get_jit) to its
+        # `compile` span, holding jax's own duration events in between
+        # as (label, t0, t1). Listened to only by a traced executor.
+        self._first_call: Optional[list] = None
+        self._listening = bool(tracer.active)
+        if self._listening:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_jax_duration)
 
     # -- store integration -------------------------------------------------
     def _vkey(self, version: Optional[int] = None):
@@ -342,6 +362,7 @@ class PagedLLMExecutor:
             self.cache_hits += 1
             return jitted, False
         self.cache_misses += 1
+        self._first_call = []
         if self.shards:
             if kind == "chunk":
                 raise BackendError(
@@ -396,8 +417,59 @@ class PagedLLMExecutor:
                 f"serve on the XLA reference") from e
 
     def _span(self, kind: str, t0: float, t1: float, **args) -> None:
+        if kind == "compile":
+            self._first_call_children()
         if self.tracer.active:
             self.tracer.backend_span(self.name, kind, t0, t1, **args)
+
+    def _on_jax_duration(self, event: str, duration: float, **_) -> None:
+        label = _FIRST_CALL_EVENTS.get(event)
+        if label is not None and self._first_call is not None:
+            t = time.perf_counter()
+            self._first_call.append((label, t - duration, t))
+
+    def _first_call_children(self) -> None:
+        """The first call just made, as jax timed it: children of its
+        `compile` span, the outermost event of each label only (tracing
+        reports every nested jit, hundreds a layer). Events arrive in
+        the order they end, so one that starts no earlier than a later
+        one of its label lies inside it."""
+        events, self._first_call = self._first_call or (), None
+        first: Dict[str, float] = {}
+        for label, t0, t1 in reversed(events):
+            if t0 < first.get(label, float("inf")):
+                first[label] = t0
+                self.tracer.span("backend", self.name, label, t0, t1)
+
+    def _resolve(self, dev, sync: bool, kind: str, bucket: int,
+                 t_in: float, t0: float):
+        """The end of a call whose jit has just returned: with `sync`,
+        wait for `dev` and read it back. An active tracer gets the
+        call's children in order, disjoint: `prep` [t_in, t0) (building
+        the host arrays), `dispatch` (t0 to the jit's return), `wait`
+        (the device_sync alone) and `readback` (the np.asarray) — the
+        last three divide the enclosing invoke/compile span [t0, t1)
+        into launch, device wait and D2H. Returns (result, t1)."""
+        tr = self.tracer
+        on = tr.active
+        t_d = t_w = time.perf_counter() if on else 0.0
+        out = dev
+        if sync:
+            device_sync(dev, tracer=tr, name=f"{self.name}:{kind}")
+            if on:
+                t_w = time.perf_counter()
+            out = np.asarray(dev)  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
+        t1 = time.perf_counter()
+        if on:
+            what = f"llm_{kind}"
+            tr.span("backend", self.name, "prep", t_in, t0, what=what)
+            tr.span("backend", self.name, "dispatch", t0, t_d, what=what,
+                    bucket=bucket)
+            if sync:
+                tr.span("backend", self.name, "wait", t_d, t_w, what=what)
+                tr.span("backend", self.name, "readback", t_w, t1,
+                        what=what, bytes=int(out.nbytes))
+        return out, t1
 
     # -- device performance plane (runtime/devprof.py) ---------------------
     def resident_bytes(self) -> int:
@@ -434,7 +506,7 @@ class PagedLLMExecutor:
 
     # -- prefill -----------------------------------------------------------
     def prefill(self, prompt: np.ndarray, block_table: List[int],
-                *, sync: bool = True):
+                *, sync: bool = True, req: Optional[str] = None):
         """One whole prompt; its KV lands in the pool blocks of
         `block_table`. Dispatches between the full-sequence
         `apply_seq_kv` path and the chunk family (`_prefill_kind` —
@@ -442,14 +514,15 @@ class PagedLLMExecutor:
         chunk covering the prompt). Returns last-token logits: a host
         (vocab,) f32 array when `sync`, else the device array so the
         engine can batch one `device_sync` over a whole step's
-        admissions."""
+        admissions. `req` only labels the call's `invoke` span."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
+        t_in = time.perf_counter() if self.tracer.active else 0.0
         plen = int(prompt.shape[0])
         if self._prefill_kind() == "chunk":
             return self.prefill_chunk(
                 prompt, 0, block_table,
-                bucket=_next_pow2(plen, 8), sync=sync)
+                bucket=_next_pow2(plen, 8), sync=sync, req=req)
         kind = "prefill"
         if self.shards and 0 < self.ring_prefill_min <= plen:
             kind = "ring"    # sequence-parallel long-context cutover
@@ -471,10 +544,7 @@ class PagedLLMExecutor:
             sp, ids, blk_idx, blk_off, self.cache.k,
             self.cache.v, np.int32(plen - 1), n_heads=self.n_heads,
             dtype=self.dtype)
-        out = np.asarray(device_sync(
-            logits, tracer=self.tracer,
-            name=f"{self.name}:prefill")) if sync else logits
-        t1 = time.perf_counter()
+        out, t1 = self._resolve(logits, sync, "prefill", s_b, t_in, t0)
         kernel = "ring" if kind == "ring" else "xla"
         if fresh:
             self.compile_count += 1
@@ -489,14 +559,14 @@ class PagedLLMExecutor:
                 {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill", bucket=s_b,
-                       plen=plen, kernel=kernel)
+                       plen=plen, kernel=kernel, req=req)
         self.prefills += 1
         self.kernel_invokes["xla"] += 1
         return out
 
     def prefill_chunk(self, chunk: np.ndarray, pos0: int,
                       block_table: List[int], *, bucket: int = 0,
-                      sync: bool = True):
+                      sync: bool = True, req: Optional[str] = None):
         """One prompt chunk starting at absolute position `pos0`,
         scattered into `block_table`'s blocks and attending the whole
         prefix written so far. `bucket` pins the pad width so every
@@ -511,6 +581,7 @@ class PagedLLMExecutor:
                 f"llm {self.name}: chunked prefill is not supported with "
                 f"shards={self.shards}; long prompts go through the "
                 f"sequence-parallel ring prefill (ring_prefill_min)")
+        t_in = time.perf_counter() if self.tracer.active else 0.0
         clen = int(chunk.shape[0])
         c_b = max(int(bucket) or 0, _next_pow2(clen, 8))
         bs = self.cache.block_size
@@ -538,10 +609,8 @@ class PagedLLMExecutor:
         t0 = time.perf_counter()
         logits, fresh = self._run_kernel("chunk", _run)
         kernel = self._kind_kernel("chunk")
-        out = np.asarray(device_sync(
-            logits, tracer=self.tracer,
-            name=f"{self.name}:prefill_chunk")) if sync else logits
-        t1 = time.perf_counter()
+        out, t1 = self._resolve(logits, sync, "prefill_chunk", c_b,
+                                t_in, t0)
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_prefill_chunk",
@@ -555,7 +624,7 @@ class PagedLLMExecutor:
                 {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill_chunk",
-                       bucket=c_b, clen=clen, kernel=kernel)
+                       bucket=c_b, clen=clen, kernel=kernel, req=req)
         self.chunk_prefills += 1
         self.kernel_invokes[kernel] += 1
         return out
@@ -571,6 +640,7 @@ class PagedLLMExecutor:
         `device_sync` (caller slices [:n] after syncing)."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
+        t_in = time.perf_counter() if self.tracer.active else 0.0
         n = len(cur)
         b_b = _next_pow2(n, 1)
         cur_a = np.zeros((b_b,), np.int32)
@@ -595,10 +665,9 @@ class PagedLLMExecutor:
         t0 = time.perf_counter()
         logits, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
-        out = np.asarray(device_sync(
-            logits, tracer=self.tracer,
-            name=f"{self.name}:decode"))[:n] if sync else logits
-        t1 = time.perf_counter()
+        out, t1 = self._resolve(logits, sync, "decode", b_b, t_in, t0)
+        if sync:
+            out = out[:n]
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -610,9 +679,12 @@ class PagedLLMExecutor:
                 (self._exec_params("decode"), cur_a, tab_a, pos_a,
                  self.cache.k, self.cache.v),
                 {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
-        else:
+        elif self.tracer.active:
+            # kv_tokens: the context this step attends, its own tokens
+            # included
             self._span("invoke", t0, t1, what="llm_decode", bucket=b_b,
-                       rows=n, kernel=kernel)
+                       rows=n, kernel=kernel,
+                       kv_tokens=int(sum(pos)) + n)
         self.decode_steps += 1
         self.kernel_invokes[kernel] += 1
         return out
@@ -633,6 +705,7 @@ class PagedLLMExecutor:
             self.cache_hits += 1
             return jitted, False
         self.cache_misses += 1
+        self._first_call = []
         if kernel == "pallas":
             from nnstreamer_tpu.backends.pallas_paged import (
                 paged_flash_decode_step)
@@ -676,6 +749,7 @@ class PagedLLMExecutor:
         in blocks the row still owned when the window ran."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
+        t_in = time.perf_counter() if self.tracer.active else 0.0
         n = len(cur)
         steps = int(steps)
         b_b = _next_pow2(n, 1)
@@ -701,10 +775,9 @@ class PagedLLMExecutor:
         t0 = time.perf_counter()
         toks, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
-        out = np.asarray(device_sync(
-            toks, tracer=self.tracer,
-            name=f"{self.name}:decmulti"))[:, :n].T
-        t1 = time.perf_counter()
+        out, t1 = self._resolve(toks, True, "decode_multi", b_b,
+                                t_in, t0)
+        out = out[:, :n].T
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode_multi",
@@ -883,6 +956,12 @@ class PagedLLMExecutor:
                 self._entry.detach(self)
             except Exception:
                 pass
+        if self._listening:
+            import jax.monitoring
+
+            self._listening = False
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_jax_duration)
         self._jits.clear()
         self._sparams.clear()
         self._rparams.clear()

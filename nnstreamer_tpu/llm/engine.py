@@ -242,6 +242,35 @@ class LLMEngine:
         return events
 
     def _admit(self, pending: List[tuple]) -> None:
+        """Admission, and under an active tracer one span over the whole
+        call (prefill launches included) whose label is the outcome:
+        `admit` (at least one request admitted), `admit_blocked` (the
+        head of the queue is short of KV blocks), `admit_none_queued`
+        (a row is free and nothing is queued here: whatever waits is
+        still upstream of the engine) or `admit_full` (no row to give:
+        all live, or a static batch still running)."""
+        tr = self.tracer
+        if not tr.active:
+            return self._admit_queue(pending)
+        t0 = time.perf_counter()
+        rows = len(self.active) + len(self.prefilling) + len(pending)
+        queued, blocked = len(self.queue), self.admission_blocked
+        self._admit_queue(pending)
+        admitted = queued - len(self.queue)
+        if admitted:
+            label = "admit"
+        elif self.admission_blocked > blocked:
+            label = "admit_blocked"
+        elif not queued and rows < self.max_batch:
+            label = "admit_none_queued"
+        else:
+            label = "admit_full"
+        tr.span("llm", self.name, label, t0, time.perf_counter(),
+                step=self.steps, rows=rows, queued=queued,
+                admitted=admitted,
+                blocks_free=self.cache.allocator.free)
+
+    def _admit_queue(self, pending: List[tuple]) -> None:
         # static A/B mode: the batch forms only from empty, no top-up
         if self.static and (self.active or self.prefilling):
             return
@@ -260,6 +289,9 @@ class LLMEngine:
                 self.admission_blocked += 1
                 return
             self.queue.popleft()
+            if self.tracer.active:
+                self.tracer.span("llm", self.name, "queued", req.t_submit,
+                                 time.perf_counter(), req=req.req_id)
             req.block_table = blocks
             if self.prefill_chunk > 0 and plen > self.prefill_chunk:
                 # long prompt: prefill one chunk per step alongside the
@@ -270,7 +302,7 @@ class LLMEngine:
                 continue
             req.state = "active"
             logits = self.executor.prefill(req.prompt, blocks,
-                                           sync=False)
+                                           sync=False, req=req.req_id)
             req.pos = plen
             pending.append((req, logits))
 
@@ -288,7 +320,8 @@ class LLMEngine:
 
         logits = self.executor.prefill_chunk(
             chunk, req.pos, req.block_table,
-            bucket=_next_pow2(self.prefill_chunk, 8), sync=False)
+            bucket=_next_pow2(self.prefill_chunk, 8), sync=False,
+            req=req.req_id)
         req.pos += int(chunk.shape[0])
         if req.pos >= plen:
             self.prefilling.pop(0)
@@ -303,15 +336,22 @@ class LLMEngine:
         decode batch."""
         if not pending:
             return
+        tr = self.tracer
+        t0 = time.perf_counter() if tr.active else 0.0
         arrays = device_sync(
-            [lg for _, lg in pending], tracer=self.tracer,
+            [lg for _, lg in pending], tracer=tr,
             name=f"{self.name}:prefill_batch")
+        t1 = time.perf_counter() if tr.active else 0.0
         for (req, _), lg in zip(pending, arrays):
             tok = self._sample(req, np.asarray(lg))
             self._record_token(req, tok)
             self.active.append(req)
             done = self._maybe_finish(req, tok)
             events.append(TokenEvent(req, [tok], done))
+        if tr.active:
+            tr.span("backend", self.name, "wait", t0, t1,
+                    what="llm_prefill_batch")
+            self._sample_span(t1, len(pending))
 
     def _window_len(self, live: List[LLMRequest]) -> int:
         """How many decode steps may run as one compiled window right
@@ -346,6 +386,7 @@ class LLMEngine:
                 [r.block_table for r in live],
                 [r.pos for r in live], k)
             self.decode_windows += 1
+            t0 = time.perf_counter() if self.tracer.active else 0.0
             for j in range(k):
                 for i, req in enumerate(live):
                     if req.state != "active":
@@ -356,19 +397,30 @@ class LLMEngine:
                     done = self._maybe_finish(req, tok)
                     events.append(TokenEvent(req, [tok], done))
                     self.window_tokens += 1
+            if self.tracer.active:
+                self._sample_span(t0, len(live))
             return
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
             [r.block_table for r in live],
             [r.pos for r in live])
+        t0 = time.perf_counter() if self.tracer.active else 0.0
         for i, req in enumerate(live):
             req.pos += 1
             tok = self._sample(req, logits[i])
             self._record_token(req, tok)
             done = self._maybe_finish(req, tok)
             events.append(TokenEvent(req, [tok], done))
+        if self.tracer.active:
+            self._sample_span(t0, len(live))
 
     # -- helpers -----------------------------------------------------------
+    def _sample_span(self, t0: float, rows: int) -> None:
+        """The host's loop over a step's rows (sampling, token
+        bookkeeping, retirement with its block frees), as one span."""
+        self.tracer.span("llm", self.name, "sample", t0,
+                         time.perf_counter(), step=self.steps, rows=rows)
+
     def _sample(self, req: LLMRequest, logits: np.ndarray) -> int:
         if req.temperature <= 0.0:
             return int(np.argmax(logits))

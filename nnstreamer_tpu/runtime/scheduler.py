@@ -1313,11 +1313,25 @@ class PipelineRunner:
                     now = time.perf_counter()
                     if now >= deadline:
                         stats.timer_fires += 1
-                        for sp, b in elem.on_timer():
+                        if not tr.active:
+                            for sp, b in elem.on_timer():
+                                self._emit(elem, sp, b)
+                            continue
+                        # input_depth: messages this fire leaves unread
+                        # (a deadline already past fires again before
+                        # the channel is read); len() needs no lock
+                        depth = ch.qsize()
+                        out = elem.on_timer()
+                        t_ret = time.perf_counter()
+                        for sp, b in out:
                             self._emit(elem, sp, b)
-                        if tr.active:
-                            tr.record_timer(elem.name, now,
-                                            time.perf_counter())
+                        t1 = time.perf_counter()
+                        if out:
+                            # the hop downstream: blocking puts included
+                            tr.span("element", elem.name, "emit", t_ret,
+                                    t1, n=len(out))
+                        tr.record_timer(elem.name, now, t1,
+                                        input_depth=depth)
                         continue
                 if pending:
                     # bailed-window frames: already dequeued (and

@@ -61,5 +61,5 @@ def device_sync(tensors, tracer=None, name=None, forced=True):
         with _lock:
             _forced += 1
         if tracer is not None and getattr(tracer, "active", False):
-            tracer.record_forced_sync(name or "?", time.monotonic())
+            tracer.record_forced_sync(name or "?", time.perf_counter())
     return tensors

@@ -261,7 +261,7 @@ class NullTracer:
     def record_process(self, name, buf, t0, t1):
         pass
 
-    def record_timer(self, name, t0, t1):
+    def record_timer(self, name, t0, t1, **args):
         pass
 
     def record_flush(self, name, t0, t1):
@@ -288,11 +288,11 @@ class NullTracer:
     def flight_dumps(self):
         return []
 
-    def record_device_counter(self, name, value, t):
-        pass
-
     def worker_counts(self):
         return {}
+
+    def span(self, cat, name, label, t0, t1, **args):
+        pass
 
     def backend_span(self, name, kind, t0, t1, **args):
         pass
@@ -380,9 +380,10 @@ class Tracer:
         # just ring events) so report() can render every swap even after
         # the event ring wraps
         self._swaps: List[Tuple[str, float, dict]] = []
-        # retired LLM requests (llm/engine.py): same keep-whole
-        # rationale as swaps
+        # retired LLM requests (llm/engine.py): kept whole so serving
+        # latency survives ring wrap, bounded FIFO like _requests
         self._llm_requests: List[Tuple[str, str, float, dict]] = []
+        self._llm_requests_dropped = 0
         # element name -> count of forced host syncs (runtime/sync.py)
         self._forced: Dict[str, int] = {}
         # (element, kernel) -> backend spans tagged with a kernel=
@@ -477,8 +478,10 @@ class Tracer:
                 if len(s) < self._max_latency_samples:
                     s.append(t1 - src_ts)
 
-    def record_timer(self, name: str, t0: float, t1: float) -> None:
-        self._append("X", "element", name, "timer", t0, t1 - t0, None)
+    def record_timer(self, name: str, t0: float, t1: float,
+                     **args) -> None:
+        self._append("X", "element", name, "timer", t0, t1 - t0,
+                     args or None)
 
     def record_flush(self, name: str, t0: float, t1: float) -> None:
         self._append("X", "element", name, "flush", t0, t1 - t0, None)
@@ -513,6 +516,15 @@ class Tracer:
         """Per-element watchdog-kind totals (wrap-proof)."""
         return {name: dict(c) for name, c in self._watchdogs.items()}
 
+    def span(self, cat: str, name: str, label: str, t0: float,
+             t1: float, **args) -> None:
+        """One "X" span ``cat:name:label`` over [t0, t1) on `name`'s
+        track, both ends `time.perf_counter()` seconds. The one generic
+        call: a new site calls this behind ``if tracer.active:`` and
+        tells its outcomes apart by `label` (docs/observability.md
+        lists the labels metrics read)."""
+        self._append("X", cat, name, label, t0, t1 - t0, args or None)
+
     def backend_span(self, name: str, kind: str, t0: float, t1: float,
                      **args) -> None:
         """Backend-side span (compile/invoke) attributed to the owning
@@ -520,11 +532,11 @@ class Tracer:
         ``kernel=`` arg (the LLM executor's pallas/xla attribution) is
         additionally counted per (element, kernel) — wrap-proof, read
         back via `kernel_spans()`."""
-        kern = (args or {}).get("kernel")
+        kern = args.get("kernel")
         if kern is not None:
             key = (name, str(kern))
             self._kernel_spans[key] = self._kernel_spans.get(key, 0) + 1
-        self._append("X", "backend", name, kind, t0, t1 - t0, args or None)
+        self.span("backend", name, kind, t0, t1, **args)
 
     def kernel_spans(self) -> Dict[Tuple[str, str], int]:
         """(element, kernel) -> count of kernel-tagged backend spans."""
@@ -554,8 +566,11 @@ class Tracer:
                            **args) -> None:
         """One retired LLM request (llm/engine.py); args carry the
         request summary: prompt_len/n_tokens/first_token_ms/itl_p50_ms/
-        finish_reason. Kept whole like swaps so per-request serving
-        latency survives ring wrap."""
+        finish_reason. Kept whole (bounded FIFO) so per-request
+        serving latency survives ring wrap."""
+        if len(self._llm_requests) >= self._max_requests:
+            del self._llm_requests[:self._max_requests // 4]
+            self._llm_requests_dropped += self._max_requests // 4
         self._llm_requests.append((name, req_id, t, dict(args)))
         self._append("i", "llm", name, "llm_request", t, 0.0,
                      dict(args, req_id=req_id))
@@ -681,13 +696,6 @@ class Tracer:
 
     def flight_dumps(self) -> List[Tuple[str, float, dict]]:
         return list(self._flights)
-
-    def record_device_counter(self, name: str, value: float,
-                              t: float) -> None:
-        """Device-plane counter sample (runtime/devprof.py): MFU per
-        bucket and HBM per device, rendered as Chrome-trace counter
-        tracks alongside queue depth and in-flight windows."""
-        self._append("C", "devprof", name, "devprof", t, 0.0, value)
 
     def autotune_events(self) -> List[Tuple[str, str, float, dict]]:
         return list(self._autotune)
@@ -1056,7 +1064,9 @@ class Tracer:
             "events": len(self._events),
             "events_dropped": self.events_dropped,
             "swaps": len(self._swaps),
-            "llm_requests": len(self._llm_requests),
+            "llm_requests": (len(self._llm_requests)
+                             + self._llm_requests_dropped),
+            "llm_requests_dropped": self._llm_requests_dropped,
             "forced_syncs": dict(self._forced),
             "inflight": self.inflight_gauges(),
             "sheds": self.shed_counts(),
@@ -1107,20 +1117,11 @@ class Tracer:
                     if args:
                         ev["args"] = dict(args)
                 elif ph == "C":
-                    if cat == "devprof":
-                        # device-plane counter tracks: name already
-                        # carries the mfu:/hbm: prefix, value is the
-                        # sampled counter value (not a queue depth)
-                        ev = {"ph": "C", "cat": cat, "name": name,
-                              "pid": pid, "tid": 0, "ts": us,
-                              "args": {"value": args}}
-                    else:
-                        track = ("inflight" if cat == "inflight"
-                                 else "queue")
-                        ev = {"ph": "C", "cat": cat,
-                              "name": f"{track}:{name}",
-                              "pid": pid, "tid": 0, "ts": us,
-                              "args": {"depth": args}}
+                    track = "inflight" if cat == "inflight" else "queue"
+                    ev = {"ph": "C", "cat": cat,
+                          "name": f"{track}:{name}",
+                          "pid": pid, "tid": 0, "ts": us,
+                          "args": {"depth": args}}
                 else:  # "i" instant, scoped to the element's track
                     ev = {"ph": "i", "cat": cat, "name": label,
                           "pid": pid, "tid": tid_of(pid, name),

@@ -465,7 +465,6 @@ class TestTracerHooks:
         # flightrec + devprof call these unguarded on whatever tracer
         # is wired; the null twin must absorb every one
         NULL_TRACER.record_flight("manual", 0.0, path="/x")
-        NULL_TRACER.record_device_counter("mfu:f/b", 0.5, 0.0)
         NULL_TRACER.record_watchdog("el", "stall", 0.0)
         assert NULL_TRACER.flight_dumps() == []
         assert NULL_TRACER.watchdog_counts() == {}
@@ -477,19 +476,6 @@ class TestTracerHooks:
             tr.record_watchdog("el", "stall", 0.0)
         tr.record_watchdog("el", "queue", 0.0)
         assert tr.watchdog_counts() == {"el": {"stall": 10, "queue": 1}}
-
-    def test_devprof_counter_track_in_chrome_trace(self):
-        tr = Tracer()
-        tr.record_device_counter("mfu:f/b", 0.5, 0.0)
-        tr.record_inflight("el", 3, 0.0)
-        evs = tr.to_chrome_trace("t")["traceEvents"]
-        c = [e for e in evs if e.get("ph") == "C"]
-        dev = [e for e in c if e.get("cat") == "devprof"]
-        assert dev and dev[0]["name"] == "mfu:f/b"
-        assert dev[0]["args"] == {"value": 0.5}
-        # the existing depth-track rendering is untouched
-        infl = [e for e in c if e.get("cat") == "inflight"]
-        assert infl and infl[0]["args"] == {"depth": 3}
 
     def test_record_flight_instant_event(self):
         tr = Tracer()

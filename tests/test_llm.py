@@ -398,3 +398,218 @@ def test_tensor_llm_open_loop_arrivals(params):
     assert stats["cache"]["blocks_high_water"] <= \
         stats["cache"]["blocks_total"]
     reset_store()
+
+
+# -- the serving thread's own spans (docs/observability.md) ------------------
+
+def _spans(tr, cat=None, prefix=""):
+    """The tracer's "X" spans as (label, t0, t1, args), in time order."""
+    return sorted(
+        ((label, ts, ts + dur, args or {})
+         for ph, c, _n, label, ts, dur, args in tr.events()
+         if ph == "X" and (cat is None or c == cat)
+         and label.startswith(prefix)), key=lambda s: s[1])
+
+
+def _inside(span, outer):
+    return outer[1] <= span[1] and span[2] <= outer[2]
+
+
+def _serve_traced(params, requests, tracer, **llm_props):
+    """Push `requests` ({rid: (prompt, budget)}) through the element and
+    end the stream only after every one is answered, so that the engine
+    steps on the timer path, as it does when serving."""
+    pipe, src, llm, sink = _llm_pipeline(params, **llm_props)
+    runner = nns.PipelineRunner(pipe, trace=tracer)
+    runner.start()
+    try:
+        for rid, (prompt, budget) in requests.items():
+            src.push(TensorBuffer(
+                tensors=(np.asarray(prompt, np.int32),), pts=0,
+                meta={"llm": {"request_id": rid,
+                              "max_new_tokens": budget}}))
+        deadline = time.perf_counter() + 120
+        while llm.extra_stats().get("finished", 0) < len(requests):
+            assert time.perf_counter() < deadline, "requests unanswered"
+            time.sleep(0.01)
+        src.end()
+        runner.wait(60)
+    finally:
+        runner.stop()
+    reset_store()
+    return sink
+
+
+def test_step_spans_nest_and_order_on_the_serving_thread(params):
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tr = Tracer()
+    _serve_traced(params, {"a": ([1, 2, 3], 5), "b": ([4, 5], 3)}, tr)
+    timers = _spans(tr, "element", "timer")
+    backend = _spans(tr, "backend")
+    steps = [t for t in timers
+             if any(_inside(b, t) for b in backend)]
+    assert len(steps) >= 4
+    admits = _spans(tr, "llm", "admit")
+    for t in steps:
+        # exactly one admission a step, and its label is an outcome
+        mine = [a for a in admits if _inside(a, t)]
+        assert len(mine) == 1, (t, mine)
+        assert mine[0][0] in ("admit", "admit_none_queued",
+                              "admit_blocked", "admit_full")
+        assert "input_depth" in t[3]
+    assert all(any(_inside(a, t) for t in steps) for a in admits)
+    # every synced call: prep, dispatch, wait, readback in that order,
+    # disjoint, and the last three inside the call's invoke/compile
+    outers = [b for b in backend if b[0] in ("invoke", "compile")
+              and b[3]["what"] == "llm_decode"]
+    assert outers
+    for o in outers:
+        prep = [b for b in backend if b[0] == "prep" and b[2] == o[1]]
+        assert len(prep) == 1
+        kids = [b for b in backend if b[0] in ("dispatch", "wait",
+                                               "readback")
+                and _inside(b, o)]
+        assert [k[0] for k in kids] == ["dispatch", "wait", "readback"]
+        chain = prep + kids
+        for a, b in zip(chain, chain[1:]):
+            assert a[2] <= b[1]
+        assert kids[0][1] == o[1] and kids[-1][2] == o[2]
+        assert kids[-1][3]["bytes"] > 0
+        assert all("kernel" not in k[3] for k in chain)
+    # the children are not counted as kernel spans a second time
+    assert sum(tr.kernel_spans().values()) == len(
+        [b for b in backend if b[0] in ("invoke", "compile")
+         and "kernel" in b[3]])
+    # one sample span a decode, after its readback; emission is timed
+    # from on_timer's return, inside the same timer span
+    samples = _spans(tr, "llm", "sample")
+    emits = _spans(tr, "element", "emit")
+    assert len(samples) >= len(outers) and emits
+    for e in emits:
+        assert e[3]["n"] >= 1 and any(_inside(e, t) for t in steps)
+    # every event of the ring is on the one clock
+    t_lo, t_hi = timers[0][1] - 60, timers[-1][2] + 60
+    assert all(t_lo < ev[4] < t_hi for ev in tr.events())
+
+
+def test_request_spans_share_an_identifier(params):
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tr = Tracer()
+    _serve_traced(params, {"r7": ([9, 8, 7, 6], 3)}, tr)
+    queued = [s for s in _spans(tr, "llm", "queued")]
+    assert len(queued) == 1 and queued[0][3] == {"req": "r7"}
+    prefill = [b for b in _spans(tr, "backend")
+               if b[0] in ("invoke", "compile")
+               and b[3]["what"] == "llm_prefill"]
+    assert len(prefill) == 1
+    inst = {label: args for ph, _c, _n, label, _t, _d, args in tr.events()
+            if ph == "i" and label in ("first_token", "llm_request")}
+    assert inst["first_token"]["req"] == "r7"
+    assert inst["llm_request"]["req_id"] == "r7"
+    # queued ends at admission, where the prefill starts
+    assert queued[0][2] <= prefill[0][1]
+    if prefill[0][0] == "invoke":
+        assert prefill[0][3]["req"] == "r7"
+
+
+@pytest.mark.parametrize("label", ["admit", "admit_none_queued",
+                                   "admit_blocked", "admit_full"])
+def test_admit_label_is_the_outcome(params, label):
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tr = Tracer()
+    rows = 16 if label == "admit_full" else 4
+    # 7 usable blocks of 4 slots: one request of 3 + 17 takes 5
+    blocks = 8 if label == "admit_blocked" else 96
+    eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=blocks,
+                    max_batch=rows, max_len=64, tracer=tr, name="e")
+    n = {"admit": 1, "admit_none_queued": 1, "admit_blocked": 2,
+         "admit_full": 17}[label]
+    for _ in range(n):
+        eng.submit(np.array([1, 2, 3], np.int32), max_new_tokens=17)
+    eng.step()
+    first = _spans(tr, "llm", "admit")[-1]
+    want_first = {"admit": 1, "admit_none_queued": 1, "admit_blocked": 1,
+                  "admit_full": 16}[label]
+    assert first[0] == "admit" and first[3]["admitted"] == want_first
+    assert first[3]["rows"] == 0 and first[3]["queued"] == n
+    eng.step()
+    second = _spans(tr, "llm", "admit")[-1]
+    if label == "admit":
+        return
+    assert second[0] == label and second[3]["admitted"] == 0
+    assert second[3]["step"] == 1 and second[3]["rows"] == want_first
+    assert second[3]["queued"] == n - want_first
+    assert second[3]["blocks_free"] == eng.cache.allocator.free
+    if label == "admit_blocked":
+        assert eng.admission_blocked >= 1
+    if label == "admit_full":
+        assert len(eng.active) == 16 == eng.max_batch
+
+
+def test_decode_invoke_carries_the_context_it_attends(params):
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tr = Tracer()
+    eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=32,
+                    max_batch=4, max_len=64, tracer=tr, name="e")
+    eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=6)
+    eng.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=6)
+    eng.step()                      # compiles; its span is `compile`
+    want = []
+    for _ in range(3):
+        live = [r for r in eng.active if r.state == "active"]
+        want.append(sum(r.pos for r in live) + len(live))
+        eng.step()
+    got = [b[3]["kv_tokens"] for b in _spans(tr, "backend", "invoke")
+           if b[3]["what"] == "llm_decode"]
+    # prompts of 5 and 8 and one decoded token each before the first
+    # `invoke`: (6 + 9) held + 2 written, then two more a step
+    assert got == want == [17, 19, 21]
+    eng.executor.close()
+
+
+def test_first_call_children_lie_inside_the_compile_span():
+    """jax's own duration events of a first call become children of its
+    `compile` span, the outermost of each label only."""
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tr = Tracer()
+    # widths no other test uses, so that jax has nothing traced for them
+    fresh = init_params(vocab=59, d_model=40, n_layers=1, n_heads=4,
+                        n_kv_heads=2, seed=1)
+    eng = LLMEngine(fresh, n_heads=4, block_size=4, num_blocks=32,
+                    max_batch=2, max_len=64, tracer=tr, name="fc")
+    eng.submit(np.array([5, 4, 3], np.int32), max_new_tokens=2)
+    eng.drain()
+    eng.executor.close()
+    eng.executor.close()            # closing twice is harmless
+    backend = _spans(tr, "backend")
+    compiles = [b for b in backend if b[0] == "compile"]
+    kids = [b for b in backend if b[0].startswith("jax_")]
+    assert len(compiles) == 2       # one prefill, one decode bucket
+    for c in compiles:
+        mine = [k for k in kids if _inside(k, c)]
+        assert {k[0] for k in mine} >= {"jax_trace", "jax_lower",
+                                        "jax_backend_compile"}
+        assert len(mine) <= 8       # not one a nested jit
+    assert all(any(_inside(k, c) for c in compiles) for k in kids)
+
+
+class _RaisingTracer:
+    """Inactive, and any hook called on it is a test failure: with
+    tracing off a guarded site may load `.active` and nothing else."""
+    active = False
+
+    def __getattr__(self, name):
+        raise AssertionError(f"tracer.{name} touched with tracing off")
+
+
+def test_tracing_off_touches_no_tracer_hook(params):
+    sink = _serve_traced(params, {"x": ([3, 1, 4, 1, 5], 6),
+                                  "y": ([2, 7], 4)}, _RaisingTracer())
+    done = {b.meta["llm"]["request_id"]: b.meta["llm"]["n_tokens"]
+            for b in sink.results if b.meta["llm"]["done"]}
+    assert done == {"x": 6, "y": 4}

@@ -61,6 +61,51 @@ class TestTracerCore:
         # a NullTracer records nothing and has no ring to inspect
         assert isinstance(runner.tracer, NullTracer)
 
+    def test_span_is_the_one_generic_call(self):
+        tr = Tracer()
+        tr.span("llm", "e", "admit_full", 1.0, 1.5, step=3, rows=16)
+        tr.span("backend", "e", "wait", 2.0, 2.25)
+        tr.backend_span("e", "invoke", 3.0, 3.5, what="x", kernel="xla")
+        tr.record_timer("e", 0.5, 4.0, input_depth=2)
+        assert tr.events() == [
+            ("X", "llm", "e", "admit_full", 1.0, 0.5,
+             {"step": 3, "rows": 16}),
+            ("X", "backend", "e", "wait", 2.0, 0.25, None),
+            ("X", "backend", "e", "invoke", 3.0, 0.5,
+             {"what": "x", "kernel": "xla"}),
+            ("X", "element", "e", "timer", 0.5, 3.5, {"input_depth": 2})]
+        # only a kernel= span is counted per kernel
+        assert tr.kernel_spans() == {("e", "xla"): 1}
+        ev = [e for e in tr.to_chrome_trace()["traceEvents"]
+              if e.get("name") == "admit_full"][0]
+        assert ev["cat"] == "llm" and ev["args"] == {"step": 3, "rows": 16}
+        assert NULL_TRACER.span("llm", "e", "admit", 0.0, 1.0, k=1) is None
+        assert NULL_TRACER.record_timer("e", 0.0, 1.0, input_depth=0) \
+            is None
+
+    def test_llm_requests_are_bounded_fifo(self):
+        tr = Tracer()
+        tr._max_requests = 8
+        for i in range(11):
+            tr.record_llm_request("e", f"r{i}", float(i), n_tokens=i)
+        kept = [r[1] for r in tr.llm_requests()]
+        # at the cap the oldest quarter goes, never the newest
+        assert kept == [f"r{i}" for i in range(4, 11)]
+        s = tr.summary()
+        assert s["llm_requests"] == 11 and s["llm_requests_dropped"] == 4
+
+    def test_forced_sync_is_stamped_on_the_ring_clock(self):
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.runtime.sync import device_sync
+
+        tr = Tracer()
+        t0 = time.perf_counter()
+        device_sync(jnp.ones((2,)), tracer=tr, name="e:decode")
+        t1 = time.perf_counter()
+        (ev,) = [e for e in tr.events() if e[3] == "forced_sync"]
+        assert t0 <= ev[4] <= t1
+
     def test_percentile_nearest_rank(self):
         vals = sorted(float(i) for i in range(1, 101))
         assert percentile(vals, 50) == 50.0
