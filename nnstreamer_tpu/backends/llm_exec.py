@@ -206,6 +206,11 @@ class PagedLLMExecutor:
         # decode steps served through a window
         self.decode_windows = 0
         self.window_steps = 0
+        # decode attention's extent, kept tracer on or off: context
+        # tokens the steps attended, and pool slots a layer read for
+        # them (their ratio is the live share of what was read)
+        self.kv_tokens_attended = 0
+        self.kv_slots_read = 0
         # first-call anatomy: a list from a jit miss (_get_jit) to its
         # `compile` span, holding jax's own duration events in between
         # as (label, t0, t1). Listened to only by a traced executor.
@@ -668,6 +673,9 @@ class PagedLLMExecutor:
         out, t1 = self._resolve(logits, sync, "decode", b_b, t_in, t0)
         if sync:
             out = out[:n]
+        # kv_tokens: the context this step attends, its own tokens
+        # included; kv_slots: the pool slots a layer read for it
+        kv_tokens, kv_slots = self._note_kv(pos_a, n)
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -679,15 +687,35 @@ class PagedLLMExecutor:
                 (self._exec_params("decode"), cur_a, tab_a, pos_a,
                  self.cache.k, self.cache.v),
                 {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
-        elif self.tracer.active:
-            # kv_tokens: the context this step attends, its own tokens
-            # included
+        else:
             self._span("invoke", t0, t1, what="llm_decode", bucket=b_b,
-                       rows=n, kernel=kernel,
-                       kv_tokens=int(sum(pos)) + n)
+                       rows=n, kernel=kernel, kv_tokens=kv_tokens,
+                       kv_slots=kv_slots)
         self.decode_steps += 1
         self.kernel_invokes[kernel] += 1
         return out
+
+    def _note_kv(self, pos_a: np.ndarray, n: int, steps: int = 1) -> tuple:
+        """Count what `steps` decode steps from the bucket's positions
+        `pos_a` (`n` live rows first) attend and read: (kv_tokens, the
+        live rows' context with the steps' own tokens; kv_slots, the
+        pool slots one layer gathers, padding rows and the walk's
+        rounding included). Only the XLA single-chip step walks live
+        blocks; the Pallas grid and the sharded step cover every table
+        entry."""
+        from nnstreamer_tpu.llm.paged_model import walk_slots
+
+        walks = not self.shards and self.paged_kernel == "xla"
+        bs = self.cache.block_size
+        tokens = slots = 0
+        for s in range(steps):
+            tokens += int(pos_a[:n].sum()) + n * (s + 1)
+            slots += walk_slots(pos_a + s, bs, self.n_kv, self.head_dim,
+                                self.max_blocks) if walks \
+                else len(pos_a) * self.max_blocks * bs
+        self.kv_tokens_attended += tokens
+        self.kv_slots_read += slots
+        return tokens, slots
 
     def _get_multi_jit(self, bucket: int, steps: int, version=None):
         """Jitted K-step greedy decode window: ``jax.lax.scan`` whose
@@ -778,6 +806,7 @@ class PagedLLMExecutor:
         out, t1 = self._resolve(toks, True, "decode_multi", b_b,
                                 t_in, t0)
         out = out[:, :n].T
+        kv_tokens, kv_slots = self._note_kv(pos_a, n, steps)
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode_multi",
@@ -785,7 +814,8 @@ class PagedLLMExecutor:
             self._note_bucket(("llmw", b_b, steps))
         else:
             self._span("invoke", t0, t1, what="llm_decode_multi",
-                       bucket=b_b, steps=steps, rows=n, kernel=kernel)
+                       bucket=b_b, steps=steps, rows=n, kernel=kernel,
+                       kv_tokens=kv_tokens, kv_slots=kv_slots)
         # the ledger counts the same decode steps whether or not the
         # window path served them — parity with per-step mode
         self.decode_steps += steps
@@ -977,6 +1007,8 @@ class PagedLLMExecutor:
             "decode_steps": self.decode_steps,
             "decode_windows": self.decode_windows,
             "window_steps": self.window_steps,
+            "kv_tokens_attended": self.kv_tokens_attended,
+            "kv_slots_read": self.kv_slots_read,
             "swap_count": self.swap_count,
             "paged_kernel": self.paged_kernel,
             "kernel_invokes": dict(self.kernel_invokes),

@@ -2,14 +2,33 @@
 
 Parity contract (the acceptance gate): at temperature 0 the paged
 engine's tokens must equal `transformer.generate`'s token-for-token.
-Both functions here therefore mirror `transformer._step_impl`'s cached
-attention exactly — the same f32 einsum pair, the same -1e30 additive
-mask, softmax in f32 — over a *gathered* KV axis instead of a
-contiguous ring. Masked positions (padding, unwritten or stale block
-slots) contribute exp(-1e30-…) = exactly 0.0 attention weight, and a
-0.0 weight times any finite stale value is exactly 0.0 in the value
-contraction, so gathering `max_blocks * block_size` slots instead of a
-dense `max_len` window changes no bits of the surviving terms.
+The functions here keep `transformer._step_impl`'s cached attention —
+f32 scores, the inclusive window `slot <= pos`, softmax in f32 — over
+KV read through block tables instead of a contiguous ring.
+
+Which slots are read. The prefill functions gather a table's whole
+`max_blocks * block_size` slots behind a -1e30 additive mask. The decode
+step reads each row's *live* blocks and no others: row r holds
+`pos[r] // C + 1` chunks of C slots (C a whole number of blocks), the
+chunks of all rows form one work list made on the device from `pos` and
+`tables`, and a loop whose trip count is a value of that list (not a
+shape: one program per decode bucket, whatever the contexts) attends T
+chunks at a time and merges them into the rows' online-softmax state
+(m, l, acc), f32, as the flash kernels do. Slots past `pos[r]` inside a
+row's last chunk, and the scratch block that the list's tail past its
+total points at, are read and masked.
+
+Why masked and unread slots give the same bits of nothing. A masked
+slot's score is replaced by -1e30 before the maximum and its weight by
+0.0 after the exponential, and 0.0 times any finite stale value is 0.0
+in the value contraction; a chunk that holds no live slot of its row is
+never read, which adds the same 0.0 to the same sums. An item past the
+total has no owner in the (T, B) relation that merges items into rows,
+so its zeros reach no row, and a padding row's chunk (pos 0, a table of
+scratch) reaches its own row only, whose logits the host slices off.
+What differs from the dense softmax is the order of the f32 sums (per
+chunk, then across chunks, rescaled by exp(m_chunk - m_row)), as it
+already does between this path and the Pallas twin (parity 1e-5).
 
 Shapes:
 - k/v pool: (L, num_blocks, block_size, n_kv, hd)  — PagedKVCache
@@ -20,6 +39,8 @@ Shapes:
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +109,135 @@ def paged_prefill(params, ids, blk_idx, blk_off, k_pool, v_pool, last_idx,
     return logits[0, last_idx], k_pool, v_pool
 
 
+# One K (or V) chunk of the walk is at most this many bytes of pool, one
+# iteration gathers at most this many bytes of K: the extents of
+# `_walk_plan`, set from chip runs (PERF.md section 6, PR 26).
+_CHUNK_BYTES = 256 << 10
+_ITER_BYTES = 16 << 20
+
+
+def _walk_plan(block_size, n_kv, hd, b, max_blocks):
+    """The decode walk's constants for one pool geometry and bucket:
+    (blocks a chunk, chunks a full table holds, items an iteration).
+    A chunk is a whole number of blocks, so C = blocks * block_size
+    slots; T items of C slots are gathered and attended at a time."""
+    block_bytes = block_size * n_kv * hd * 4     # the pool is float32
+    nb_c = max(1, min(max_blocks, _CHUNK_BYTES // block_bytes))
+    n_chunks = -(-max_blocks // nb_c)
+    t = max(1, min(b * n_chunks, _ITER_BYTES // (nb_c * block_bytes)))
+    return nb_c, n_chunks, t
+
+
+def walk_slots(pos, block_size, n_kv, hd, max_blocks):
+    """Pool slots one layer of a decode step gathers for the bucket's
+    positions `pos` (padding rows included): whole iterations of T
+    chunks of C slots. Host arithmetic, for the executor's counters."""
+    nb_c, _, t = _walk_plan(block_size, n_kv, hd, len(pos), max_blocks)
+    c = nb_c * block_size
+    items = sum(int(p) // c + 1 for p in pos)
+    return -(-items // t) * t * c
+
+
+def _live_items(tables, pos, block_size, nb_c, n_chunks, t):
+    """The step's work list, made on the device from `pos` and
+    `tables`: item i is one chunk of one row's live blocks, rows in
+    order, row r holding pos[r] // C + 1 of them. Returns per item its
+    row, its pool blocks (nb_c,), the last live slot inside its chunk
+    (-1 for the items past the total, which read the scratch block and
+    count for nothing) and the number of T-item iterations."""
+    b, max_blocks = tables.shape
+    c = nb_c * block_size
+    n_items = -(-(b * n_chunks) // t) * t
+    chunks = pos // c + 1                                    # (B,)
+    ends = jnp.cumsum(chunks)
+    i = jnp.arange(n_items, dtype=pos.dtype)
+    valid = i < ends[-1]
+    row = jnp.minimum(
+        jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
+    chunk = jnp.where(valid, i - (ends - chunks)[row], 0)
+    # a table's tail past max_blocks, like an item past the total,
+    # reads block 0: the scratch block
+    tab = jnp.pad(tables, ((0, 0), (0, n_chunks * nb_c - max_blocks)))
+    blocks = tab[row[:, None], chunk[:, None] * nb_c + jnp.arange(nb_c)]
+    blocks = jnp.where(valid[:, None], blocks, 0)
+    last = jnp.where(valid, pos[row] - chunk * c, -1)
+    return row, blocks, last, (ends[-1] + t - 1) // t
+
+
+def _attend_live(q, k_pool, v_pool, li, items, t, n_heads):
+    """Layer `li`'s attention of q (B, H, hd) f32 over each row's live
+    slots: a loop over the work list, T items at a time, with the
+    online-softmax state (m, l, acc) per row and head in f32. Several
+    items of one iteration may belong to one row; they merge through
+    the (T, B) relation `own`."""
+    row, blocks, last, n_iter = items
+    b, _, hd = q.shape
+    block_size, n_kv = k_pool.shape[2], k_pool.shape[3]
+    c = blocks.shape[1] * block_size
+    hi = jax.lax.Precision.HIGHEST
+
+    def body(j, state):
+        m, l, acc = state
+        r = jax.lax.dynamic_slice_in_dim(row, j * t, t)
+        bl = jax.lax.dynamic_slice_in_dim(blocks, j * t, t)
+        la = jax.lax.dynamic_slice_in_dim(last, j * t, t)
+        kc = k_pool[li, bl].reshape(t, c, n_kv, hd)
+        vc = v_pool[li, bl].reshape(t, c, n_kv, hd)
+        kcx = _expand_kv(kc, n_heads).astype(jnp.float32)
+        s = jnp.einsum("thd,tchd->thc", q[r], kcx) * hd ** -0.5
+        # the same inclusive window as _step_impl's `<= p`
+        live = (jnp.arange(c)[None, :] <= la[:, None])[:, None, :]
+        s = jnp.where(live, s, -1e30)
+        mi = jnp.max(s, axis=-1)                             # (T, H)
+        p = jnp.where(live, jnp.exp(s - mi[..., None]), 0.0)
+        vcx = _expand_kv(vc, n_heads).astype(jnp.float32)
+        ai = jnp.einsum("thc,tchd->thd", p, vcx)
+        own = (r[:, None] == jnp.arange(b)[None, :]) & (la >= 0)[:, None]
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(own[:, :, None], mi[:, None, :], -1e30), axis=0))
+        w = jnp.exp(mi - m_new[r])                           # (T, H)
+        old = jnp.exp(m - m_new)
+        ownf = own.astype(jnp.float32)
+        l = l * old + jnp.einsum(
+            "tb,th->bh", ownf, jnp.sum(p, axis=-1) * w, precision=hi)
+        acc = acc * old[..., None] + jnp.einsum(
+            "tb,thd->bhd", ownf, ai * w[..., None], precision=hi)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(0, n_iter, body, (
+        jnp.full((b, n_heads), -1e30, jnp.float32),
+        jnp.zeros((b, n_heads), jnp.float32),
+        jnp.zeros((b, n_heads, hd), jnp.float32)))
+    return acc / l[..., None]
+
+
+@functools.partial(jax.jit, static_argnames=("t", "n_heads", "dtype"))
+def _decode_layer(blk, x, li, pos, write_blk, write_off, items,
+                  k_pool, v_pool, *, t, n_heads, dtype):
+    """Layer `li` of a decode step: write the step's K/V to the pool,
+    attend the live context, MLP. Jitted with `li` an argument, so a
+    step traces and lowers one layer, not one per layer of the model
+    (XLA inlines the calls: the compiled program is the same)."""
+    b, _, d = x.shape
+    n_kv, hd = k_pool.shape[3], k_pool.shape[4]
+    kv_dim = n_kv * hd
+    h = rmsnorm(x, blk["ln1"].astype(dtype))
+    qkv = _proj(blk, "wqkv", h, dtype)
+    q = qkv[..., :d].reshape(b, 1, n_heads, hd)
+    k = qkv[..., d:d + kv_dim].reshape(b, 1, n_kv, hd)
+    v = qkv[..., d + kv_dim:].reshape(b, 1, n_kv, hd)
+    q, k = _rope_rows(q, pos), _rope_rows(k, pos)
+    k_pool = k_pool.at[li, write_blk, write_off].set(
+        k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[li, write_blk, write_off].set(
+        v[:, 0].astype(v_pool.dtype))
+    attn = _attend_live(q[:, 0].astype(jnp.float32), k_pool, v_pool,
+                        li, items, t, n_heads).astype(dtype)
+    x = x + _proj(blk, "wo", attn.reshape(b, 1, -1), dtype)
+    h = rmsnorm(x, blk["ln2"].astype(dtype))
+    return x + _mlp_paged(blk, h, dtype), k_pool, v_pool
+
+
 def paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
                       *, n_heads=4, dtype=jnp.float32):
     """One decode step for a bucketed batch over the paged pool.
@@ -97,51 +247,23 @@ def paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
     Returns (logits (B_b, vocab) f32, k_pool, v_pool).
 
     Mirrors `transformer._step_impl` with three serving deltas: the
-    cache axis is gathered through the block tables, positions are
-    per-row (sequences at different depths share one step), and there
-    is no ring wrap — admission enforces prompt+new <= table capacity.
+    cache axis is read through the block tables, each row's live
+    blocks only; positions are per-row (sequences at different depths
+    share one step), and there is no ring wrap — admission enforces
+    prompt+new <= table capacity.
     """
     b = cur.shape[0]
-    n_layers, _, block_size, _, _ = k_pool.shape
-    max_blocks = tables.shape[1]
-    kv_len = max_blocks * block_size
-    rows = jnp.arange(b)
-    write_blk = tables[rows, pos // block_size]      # (B,)
+    _, _, block_size, n_kv, hd = k_pool.shape
+    nb_c, n_chunks, t = _walk_plan(block_size, n_kv, hd, b,
+                                   tables.shape[1])
+    write_blk = tables[jnp.arange(b), pos // block_size]      # (B,)
     write_off = pos % block_size
+    items = _live_items(tables, pos, block_size, nb_c, n_chunks, t)
     x = params["embed"][cur][:, None, :].astype(dtype)   # (B,1,D)
-    # attend over positions <= pos[b] (same inclusive window as
-    # _step_impl's `arange(max_len) <= p`)
-    mask = (jnp.arange(kv_len)[None, None, None, :] <=
-            pos[:, None, None, None])
     for li, blk in enumerate(params["blocks"]):
-        h = rmsnorm(x, blk["ln1"].astype(dtype))
-        d = x.shape[-1]
-        hd = d // n_heads
-        qkv = _proj(blk, "wqkv", h, dtype)
-        kv_dim = (qkv.shape[-1] - d) // 2
-        n_kv = kv_dim // hd
-        q = qkv[..., :d].reshape(b, 1, n_heads, hd)
-        k = qkv[..., d:d + kv_dim].reshape(b, 1, n_kv, hd)
-        v = qkv[..., d + kv_dim:].reshape(b, 1, n_kv, hd)
-        q, k = _rope_rows(q, pos), _rope_rows(k, pos)
-        k_pool = k_pool.at[li, write_blk, write_off].set(
-            k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[li, write_blk, write_off].set(
-            v[:, 0].astype(v_pool.dtype))
-        # gather this batch's KV through the block tables:
-        # (B, max_blocks, block_size, n_kv, hd) → (B, kv_len, n_kv, hd)
-        kc = k_pool[li][tables].reshape(b, kv_len, n_kv, hd)
-        vc = v_pool[li][tables].reshape(b, kv_len, n_kv, hd)
-        kcx = _expand_kv(kc, n_heads).astype(jnp.float32)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       kcx) * hd ** -0.5                # (B,H,1,kv_len)
-        s = jnp.where(mask, s, -1e30)
-        pattn = jax.nn.softmax(s, axis=-1)
-        vcx = _expand_kv(vc, n_heads).astype(jnp.float32)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vcx).astype(dtype)
-        x = x + _proj(blk, "wo", attn.reshape(b, 1, -1), dtype)
-        h = rmsnorm(x, blk["ln2"].astype(dtype))
-        x = x + _mlp_paged(blk, h, dtype)
+        x, k_pool, v_pool = _decode_layer(
+            blk, x, li, pos, write_blk, write_off, items, k_pool, v_pool,
+            t=t, n_heads=n_heads, dtype=dtype)
     x = rmsnorm(x, params["ln_f"].astype(dtype))
     logits = _proj(params, "head", x[:, 0], dtype).astype(jnp.float32)
     return logits, k_pool, v_pool

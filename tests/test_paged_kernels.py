@@ -111,6 +111,202 @@ def test_decode_parity_row_at_block_boundary(params):
     assert float(jnp.max(jnp.abs(ref - fl))) <= TOL
 
 
+# -- the live-block walk vs the dense gather it replaced ----------------------
+
+WL, WNB, WBS, WMB = 2, 40, 8, 8            # walk geometry: max_len 64
+WBLOCK_BYTES = WBS * NKV * HD * 4
+
+
+def _dense_decode_step(params, cur, tables, pos, k_pool, v_pool, *,
+                       n_heads=4):
+    """The gather `paged_decode_step` had before it walked live blocks:
+    every slot of every table behind a -1e30 mask, float32."""
+    import jax
+
+    from nnstreamer_tpu.llm.paged_model import (
+        _mlp_paged, _proj, _rope_rows)
+    from nnstreamer_tpu.models.transformer import _expand_kv, rmsnorm
+
+    b, f32 = cur.shape[0], jnp.float32
+    bs, n_kv, hd = k_pool.shape[2:]
+    kv_len = tables.shape[1] * bs
+    wb, wo = tables[jnp.arange(b), pos // bs], pos % bs
+    x = params["embed"][cur][:, None, :]
+    mask = jnp.arange(kv_len)[None, None, None, :] \
+        <= pos[:, None, None, None]
+    for li, blk in enumerate(params["blocks"]):
+        qkv = _proj(blk, "wqkv", rmsnorm(x, blk["ln1"]), f32)
+        d = x.shape[-1]
+        kvd = n_kv * hd
+        q = _rope_rows(qkv[..., :d].reshape(b, 1, n_heads, hd), pos)
+        k = _rope_rows(qkv[..., d:d + kvd].reshape(b, 1, n_kv, hd), pos)
+        v = qkv[..., d + kvd:].reshape(b, 1, n_kv, hd)
+        k_pool = k_pool.at[li, wb, wo].set(k[:, 0])
+        v_pool = v_pool.at[li, wb, wo].set(v[:, 0])
+        kc = _expand_kv(k_pool[li][tables].reshape(b, kv_len, n_kv, hd),
+                        n_heads)
+        vc = _expand_kv(v_pool[li][tables].reshape(b, kv_len, n_kv, hd),
+                        n_heads)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kc) * hd ** -0.5
+        pattn = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vc)
+        x = x + _proj(blk, "wo", attn.reshape(b, 1, -1), f32)
+        x = x + _mlp_paged(blk, rmsnorm(x, blk["ln2"]), f32)
+    x = rmsnorm(x, params["ln_f"])
+    return _proj(params, "head", x[:, 0], f32), k_pool, v_pool
+
+
+def _walk_consts(monkeypatch, chunk_blocks, items):
+    """Steer the walk's constants from the test: C = chunk_blocks
+    blocks a chunk, T = items an iteration (None: as shipped, where a
+    table of this size is one chunk)."""
+    from nnstreamer_tpu.llm import paged_model as pm
+
+    if chunk_blocks is not None:
+        monkeypatch.setattr(pm, "_CHUNK_BYTES",
+                            chunk_blocks * WBLOCK_BYTES)
+        monkeypatch.setattr(pm, "_ITER_BYTES",
+                            items * chunk_blocks * WBLOCK_BYTES)
+
+
+def _walk_batch(pos, live, seed):
+    """Pools full of stale garbage, hole-y tables of distinct blocks
+    for the `live` first rows, all-scratch tables for the padding rows
+    behind them."""
+    rng = np.random.default_rng(seed)
+    shape = (WL, WNB, WBS, NKV, HD)
+    kp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    free = list(rng.permutation(np.arange(1, WNB)))
+    tabs = np.zeros((len(pos), WMB), np.int32)
+    for r in range(live):
+        for j in range(pos[r] // WBS + 1):
+            tabs[r, j] = free.pop()
+    cur = jnp.asarray(rng.integers(1, 60, len(pos)), jnp.int32)
+    return cur, jnp.asarray(tabs), jnp.asarray(pos, jnp.int32), kp, vp
+
+
+# (pos of the bucket's rows, live rows, blocks a chunk, items an iteration)
+WALK_CASES = {
+    # C = 16 slots: rows 3, 1, 2 and 4 chunks deep, 10 items in 4
+    # iterations of 3, the last one a third full
+    "staggered_multichunk": ([37, 5, 20, 50], 4, 2, 3),
+    # a chunk's last slot, the next one's first, twice
+    "chunk_edges": ([15, 16, 31, 32], 4, 2, 4),
+    # 5 items against T = 4: a second iteration with one item
+    "last_iteration_part_empty": ([40, 17, 3, 7], 4, 2, 4),
+    # one item an iteration: every merge crosses iterations
+    "one_item_an_iteration": ([33, 9, 63, 24], 4, 2, 1),
+    # more items an iteration than the bucket has: one row's chunks
+    # merge inside an iteration
+    "row_merges_inside_iteration": ([63, 47], 2, 1, 16),
+    # two padding rows share the scratch block with each other
+    "padding_rows_share_scratch": ([29, 18, 0, 0], 2, 2, 3),
+    "bucket_of_one": ([45], 1, 2, 2),
+    "bucket_of_one_first_slot": ([0], 1, 1, 2),
+    # the constants as shipped: the whole table is one chunk
+    "shipped_constants": ([37, 5, 20, 63], 4, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_decode_walk_matches_dense_gather(params, monkeypatch, case):
+    """The walk over live blocks gives the dense gather's logits to
+    1e-5 and its pool writes: every slot the step did not write keeps
+    its bits, and the written ones (which below layer 0 follow a
+    reassociated attention sum, and in every layer a jitted projection
+    against the reference's eager one) agree to 1e-5."""
+    pos, live, chunk_blocks, items = WALK_CASES[case]
+    _walk_consts(monkeypatch, chunk_blocks, items)
+    cur, tabs, pos_a, kp, vp = _walk_batch(pos, live, seed=len(case))
+    ref, kr, vr = _dense_decode_step(params, cur, tabs, pos_a, kp, vp)
+    out, kw, vw = paged_decode_step(params, cur, tabs, pos_a, kp, vp)
+    assert float(jnp.max(jnp.abs(ref[:live] - out[:live]))) <= TOL
+    written = np.zeros((WNB, WBS), bool)
+    written[np.asarray(tabs)[np.arange(len(pos)), np.asarray(pos) // WBS],
+            np.asarray(pos) % WBS] = True
+    for new, old, dense in ((kw, kp, kr), (vw, vp, vr)):
+        new, old, dense = map(np.asarray, (new, old, dense))
+        assert np.array_equal(new[:, ~written], old[:, ~written])
+        assert np.max(np.abs(new - dense)) <= TOL
+
+
+@pytest.mark.parametrize("chunk_blocks,items", [(2, 3), (None, None)])
+def test_decode_walk_reads_nothing_past_pos(params, monkeypatch,
+                                            chunk_blocks, items):
+    """A huge finite value planted in the live blocks' slots past `pos`
+    and all over the scratch block changes no bit of the live rows'
+    logits: masked slots weigh exactly 0.0."""
+    _walk_consts(monkeypatch, chunk_blocks, items)
+    pos, live = [37, 5, 20, 0], 3
+    cur, tabs, pos_a, kp, vp = _walk_batch(pos, live, seed=9)
+    clean = paged_decode_step(params, cur, tabs, pos_a, kp, vp)[0]
+    dirty = np.zeros((WNB, WBS), bool)
+    dirty[0] = True                              # the scratch block
+    for r in range(live):
+        for j in range(pos[r] // WBS + 1):
+            lo = max(0, pos[r] + 1 - j * WBS)
+            dirty[int(tabs[r, j]), lo:] = True
+    kd = jnp.where(jnp.asarray(dirty)[None, :, :, None, None], 1e30, kp)
+    vd = jnp.where(jnp.asarray(dirty)[None, :, :, None, None], -1e30, vp)
+    out = paged_decode_step(params, cur, tabs, pos_a, kd, vd)[0]
+    assert np.array_equal(np.asarray(out[:live]), np.asarray(clean[:live]))
+
+
+def test_decode_multi_over_chunk_boundary_equals_single_steps(
+        params, monkeypatch):
+    """A compiled decode window whose positions advance on the device
+    across a chunk's edge (C = 16: from slot 13 to slot 20) serves the
+    tokens of as many single steps."""
+    from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
+
+    _walk_consts(monkeypatch, 2, 3)
+    prompts = [_prompt(13, 40), _prompt(29, 41), _prompt(6, 42)]
+    served = []
+    for window in (False, True):
+        ex = PagedLLMExecutor(dict(params), n_heads=4, block_size=WBS,
+                              num_blocks=WNB, max_len=WBS * WMB)
+        tables = [ex.cache.allocator.alloc(WMB) for _ in prompts]
+        cur = [int(np.argmax(ex.prefill(p, t)))
+               for p, t in zip(prompts, tables)]
+        pos = [len(p) for p in prompts]
+        if window:
+            toks = ex.decode_multi(cur, tables, pos, 8)
+        else:
+            toks = np.zeros((len(prompts), 8), np.int32)
+            for s in range(8):
+                logits = ex.decode(cur, tables, [p + s for p in pos])
+                cur = [int(t) for t in np.argmax(logits, axis=-1)]
+                toks[:, s] = cur
+        served.append(np.asarray(toks))
+        assert ex.stats()["kv_tokens_attended"] == sum(
+            sum(pos) + len(pos) * (s + 1) for s in range(8))
+    assert np.array_equal(served[0], served[1])
+
+
+def test_decode_bucket_compiles_once_over_all_contexts(params):
+    """One decode bucket is one program whatever the rows' depths: the
+    walk's extent is a value read from `pos`, not a shape, so contexts
+    from 1 to max_len add no compile (what holds `compiles_in_window`
+    at 0 behind the benchmark's short warm-up)."""
+    from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
+
+    ex = PagedLLMExecutor(dict(params), n_heads=4, block_size=WBS,
+                          num_blocks=WNB, max_len=WBS * WMB)
+    tables = [ex.cache.allocator.alloc(WMB) for _ in range(3)]
+    ex.decode([5, 6, 7], tables, [0, 0, 0])
+    first = ex.compile_count
+    assert first == 1
+    traces = ex._jits[(ex._ns(), "decode", 4, "xla")]._cache_size()
+    for p in range(0, WBS * WMB, 3):
+        ex.decode([5, 6, 7], tables,
+                  [p, (p * 7) % (WBS * WMB), WBS * WMB - 1 - p])
+    assert ex.compile_count == first
+    assert ex._jits[(ex._ns(), "decode", 4, "xla")]._cache_size() == traces
+    st = ex.stats()
+    assert 0 < st["kv_tokens_attended"] <= st["kv_slots_read"]
+
+
 # -- prefill / chunk parity --------------------------------------------------
 
 def test_chunk_matches_full_prefill_reference(params):
