@@ -19,6 +19,11 @@ weights, frames and prompts:
                    512, bf16), ``paged_kernel=xla`` then ``pallas``: every
                    request answered, identical greedy tokens, the pallas arm's
                    invokes all counted under ``pallas``;
+- ``llm_sparse_moe`` the sparse-expert family (llm/sparse_moe.py) at a tiny
+                   size (hidden 64, 8 q / 2 kv heads of 16, 8 experts top-2,
+                   indexer 2 x 8 top-8, float32): chunked prefill and decode
+                   through the same element on the chip serve the tokens the
+                   same engine serves on this host's CPU device;
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -387,6 +392,98 @@ def leg_llm(model: str, kernel: str, reference=None):
                   "compiles": ex["compile_count"]}
 
 
+# -- the sparse-expert family ---------------------------------------------------
+
+SPARSE = dict(d=64, heads=8, kv=2, hd=16, experts=8, per_tok=2, width=32,
+              idx_heads=2, idx_dim=8, topk=8, layers=2, vocab=256)
+
+
+def _sparse_bundle(device):
+    """Seeded float32 weights of the tiny sparse-expert model on
+    `device`, in the family's hand-over layout, with its description."""
+    import jax
+    import numpy as np
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.llm.spec import SPARSE_MOE, LMSpec
+
+    c = SPARSE
+    rng = np.random.default_rng(11)
+
+    def w(*shape):
+        lim = (6.0 / (shape[-2] + shape[-1])) ** 0.5
+        return rng.uniform(-lim, lim, shape).astype(np.float32)
+
+    ones = lambda n: np.ones((n,), np.float32)          # noqa: E731
+    qw, kw = c["heads"] * c["hd"], c["kv"] * c["hd"]
+    blocks = [{
+        "ln1": ones(c["d"]), "wqkv": w(c["d"], qw + 2 * kw),
+        "q_norm": ones(c["hd"]), "k_norm": ones(c["hd"]),
+        "wo": w(qw, c["d"]),
+        "widx": w(c["d"], c["idx_heads"] * c["idx_dim"] + c["idx_dim"]
+                  + c["idx_heads"]),
+        "ln2": ones(c["d"]), "router": w(c["d"], c["experts"]),
+        "ewi": w(c["experts"], c["d"], 2 * c["width"]),
+        "ewd": w(c["experts"], c["width"], c["d"]),
+    } for _ in range(c["layers"])]
+    params = {"embed": w(c["vocab"], c["d"]), "blocks": blocks,
+              "ln_f": ones(c["d"]), "head": w(c["d"], c["vocab"])}
+    spec = LMSpec(family=SPARSE_MOE, n_heads=c["heads"], n_kv=c["kv"],
+                  head_dim=c["hd"], rope_theta=1e7, qk_norm=True,
+                  idx_heads=c["idx_heads"], idx_dim=c["idx_dim"],
+                  topk=c["topk"], n_experts=c["experts"],
+                  experts_per_tok=c["per_tok"], expert_width=c["width"])
+    return ModelBundle(fn=None, lm=spec, params=jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, device), params))
+
+
+def leg_llm_sparse_moe() -> dict:
+    """The family through the element on the default device (the chip)
+    against the same engine on this host's CPU device. Float32 products
+    at `highest` on both, so that greedy tokens of random weights do not
+    hang on the matrix unit's bfloat16 rounding."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.llm.engine import LLMEngine
+    from nnstreamer_tpu.serving.store import get_store
+
+    rng = np.random.default_rng(5)
+    # prompts on both sides of topk (8) and of the chunk (8)
+    prompts = [rng.integers(0, SPARSE["vocab"], size=n).astype(np.int32)
+               for n in (5, 12, 21, 33)]
+    serving = dict(block_size=8, num_blocks=64, max_len=64, prefill_chunk=8)
+    cpu = jax.devices("cpu")[0]
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        with jax.default_device(cpu):
+            eng = LLMEngine(_sparse_bundle(cpu), dtype=jnp.float32,
+                            max_batch=8, **serving)
+            reqs = [eng.submit(p, req_id=f"req{i}",
+                               max_new_tokens=LLM_NEW_TOKENS)
+                    for i, p in enumerate(prompts)]
+            eng.drain()
+            want = {r.req_id: list(r.tokens) for r in reqs}
+            eng.executor.close()
+        get_store().register("chip_smoke_sparse_moe",
+                             _sparse_bundle(jax.devices()[0]))
+        toks, stats = _run_llm("store://chip_smoke_sparse_moe", prompts,
+                               dtype="float32", paged_kernel="xla",
+                               **serving)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+    ex = stats["executor"]
+    assert ex["family"] == "sparse_moe" and ex["chunk_prefills"] >= 10, ex
+    assert ex["kv_tokens_selected"] > 0 and ex["expert_tokens"] > 0, ex
+    assert stats["cache"]["pools"] == 3, stats["cache"]
+    assert toks == want, f"greedy tokens differ: chip {toks} vs cpu {want}"
+    return {"requests": len(toks), "tokens": stats["tokens_out"],
+            "chunk_prefills": ex["chunk_prefills"],
+            "experts_touched_sum": ex["experts_touched_sum"]}
+
+
 # -- four chips --------------------------------------------------------------
 
 def leg_multichip(model: str, ref) -> dict:
@@ -504,6 +601,7 @@ def main() -> int:
     model = _register_llm()
     xla = leg("llm_xla", leg_llm, model, "xla")
     leg("llm_pallas", leg_llm, model, "pallas", xla and xla[0])
+    leg("llm_sparse_moe", leg_llm_sparse_moe)
     if dev["count"] >= 4 and ref:
         leg("multichip", leg_multichip, model, ref)
     else:

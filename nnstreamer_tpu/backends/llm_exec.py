@@ -74,6 +74,23 @@ _FIRST_CALL_EVENTS = {
 }
 
 
+def _model_dims(params: dict, n_heads: int, spec=None) -> dict:
+    """Model dims: from the bundle's own description (`ModelBundle.lm`,
+    llm/spec.LMSpec) when it has one, else from the params' shapes and
+    the element's `n_heads`, as a dense bundle always was read."""
+    if spec is None:
+        return _derive_dims(params, n_heads)
+    try:
+        return {"d_model": int(params["embed"].shape[1]),
+                "vocab": int(params["head"].shape[1]),
+                "n_layers": len(params["blocks"]),
+                "head_dim": int(spec.head_dim), "n_kv": int(spec.n_kv)}
+    except (KeyError, IndexError, AttributeError, TypeError) as e:
+        raise BackendError(
+            f"tensor_llm needs an embed/blocks/ln_f/head params pytree; "
+            f"could not read dims: {e}") from e
+
+
 def _derive_dims(params: dict, n_heads: int) -> dict:
     """Model dims from the transformer params pytree itself (the only
     honest source — a store version may differ from element props)."""
@@ -152,15 +169,23 @@ class PagedLLMExecutor:
             else:
                 cur, epoch = self._entry.state
                 self._version, self.adopted_epoch = cur, epoch
-            self.params = self._entry.bundle(self._version).params
+            bundle = self._entry.bundle(self._version)
+            self.params, self.spec = bundle.params, bundle.lm
             self._entry.attach(self)
         elif isinstance(model, dict):
-            self.params = model
+            self.params, self.spec = model, None
+        elif hasattr(model, "params") and hasattr(model, "lm"):
+            # a ModelBundle in hand: its params and its description
+            self.params, self.spec = model.params, model.lm
         else:
             raise BackendError(
-                f"tensor_llm model must be a store:// ref or a params "
-                f"dict, got {type(model).__name__}")
-        dims = _derive_dims(self.params, self.n_heads)
+                f"tensor_llm model must be a store:// ref, a ModelBundle "
+                f"or a params dict, got {type(model).__name__}")
+        self.sparse = self._is_sparse(self.spec)
+        if self.sparse:
+            self._refuse_sparse_combinations()
+            self.n_heads = int(self.spec.n_heads)
+        dims = _model_dims(self.params, self.n_heads, self.spec)
         self.__dict__.update(dims)
         self._mesh = None
         self._shard_chips: tuple = ()
@@ -193,7 +218,9 @@ class PagedLLMExecutor:
         self.cache = PagedKVCache(
             num_blocks=int(num_blocks), block_size=bs,
             n_layers=self.n_layers, n_kv=self.n_kv,
-            head_dim=self.head_dim, placer=placer)
+            head_dim=self.head_dim,
+            idx_dim=int(self.spec.idx_dim) if self.sparse else 0,
+            placer=placer)
         #: (ns, kind, bucket) → jitted callable
         self._jits: Dict[tuple, Any] = {}
         self.compile_count = 0
@@ -211,6 +238,25 @@ class PagedLLMExecutor:
         # them (their ratio is the live share of what was read)
         self.kv_tokens_attended = 0
         self.kv_slots_read = 0
+        # the sparse-expert family's extents (llm/sparse_moe.py), kept
+        # tracer on or off. Decode steps: context slots the indexer
+        # scored / slots selected and attended / indexer-pool slots a
+        # layer read (kv_slots_read then counts the selected slots'
+        # gathers); (layer, step) pairs and the distinct experts that
+        # got a token in them. Every call: (token, expert) pairs routed.
+        # Chunks: tokens at the busiest expert, summed over the chunks
+        # whose counts have been read back (expert_load_chunks).
+        self.kv_tokens_scored = 0
+        self.kv_tokens_selected = 0
+        self.idx_slots_read = 0
+        self.expert_tokens = 0
+        self.expert_steps_layers = 0
+        self.experts_touched_sum = 0
+        self.expert_load_max_sum = 0
+        self.expert_load_chunks = 0
+        #: chunks launched with sync=False whose expert counts are still
+        #: on the device: (req, pos0, clen, counts)
+        self._chunk_counts: List[tuple] = []
         # first-call anatomy: a list from a jit miss (_get_jit) to its
         # `compile` span, holding jax's own duration events in between
         # as (label, t0, t1). Listened to only by a traced executor.
@@ -237,6 +283,132 @@ class PagedLLMExecutor:
             return ("v", version if version is not None
                     else self._version)
         return ("g", 0)
+
+    # -- the sparse-expert family (llm/sparse_moe.py) ----------------------
+    #: the longest prompt the one-chunk whole-prompt prefill takes: past
+    #: it a chunk's (heads, C, tile) temporaries outgrow what the pool
+    #: leaves free, and the engine has to chunk (prefill_chunk)
+    SPARSE_WHOLE_PROMPT_MAX = 4096
+
+    @staticmethod
+    def _is_sparse(spec) -> bool:
+        from nnstreamer_tpu.llm.spec import SPARSE_MOE
+
+        return spec is not None and spec.family == SPARSE_MOE
+
+    def _refuse_sparse_combinations(self) -> None:
+        """What the sparse-expert family cannot yet be combined with,
+        refused typed at construction (ROADMAP C2)."""
+        why = None
+        if self.shards > 0:
+            why = (f"shards={self.shards}: its experts and indexer pool "
+                   f"have no sharding rule yet (ROADMAP B2)")
+        elif self.paged_kernel == "pallas":
+            why = ("paged_kernel=pallas: it has no Pallas twin yet "
+                   "(ROADMAP B2); set paged_kernel=xla")
+        elif any(k.endswith("_scale") for k in self.params["blocks"][0]):
+            why = ("a W8A8 store version: its grouped expert products "
+                   "are float only")
+        if why is not None:
+            raise BackendError(
+                f"llm {self.name}: the sparse_moe family cannot be served "
+                f"with {why}")
+
+    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
+        """Refuse at submission a prompt this executor can never
+        prefill: the sparse family prefills through its chunk program
+        only, and one chunk holds at most SPARSE_WHOLE_PROMPT_MAX."""
+        if self.sparse and plen > self.SPARSE_WHOLE_PROMPT_MAX and not (
+                0 < prefill_chunk < plen):
+            raise BackendError(
+                f"llm {self.name}: a prompt of {plen} tokens needs "
+                f"chunked prefill in the sparse_moe family (one chunk "
+                f"holds at most {self.SPARSE_WHOLE_PROMPT_MAX}); set "
+                f"prefill_chunk (it is {prefill_chunk})")
+
+    def _kw(self) -> dict:
+        """The static arguments of this family's jits."""
+        if self.sparse:
+            return {"spec": self.spec, "dtype": self.dtype}
+        return {"n_heads": self.n_heads, "dtype": self.dtype}
+
+    def _chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab,
+                    last) -> tuple:
+        return (params, ids, pos0, blk_idx, blk_off, tab,
+                *self.cache.pools(), last)
+
+    def _decode_args(self, params, cur, tab, pos, n: int) -> tuple:
+        if self.sparse:
+            return (params, cur, tab, pos, np.int32(n), *self.cache.pools())
+        return (params, cur, tab, pos, *self.cache.pools())
+
+    def _chunk_kw(self, pos0: int, bucket: int) -> dict:
+        """Static arguments of a chunk call. The sparse family writes
+        whole blocks at once where the chunk lies on them: every chunk
+        of a prompt does when block_size divides prefill_chunk, so the
+        bucket stays one program."""
+        kw = self._kw()
+        if self.sparse:
+            bs = self.cache.block_size
+            kw["by_block"] = int(pos0) % bs == 0 and bucket % bs == 0
+        return kw
+
+    def _take(self, out: tuple):
+        """Split a jit's result into (logits, expert counts or None) and
+        keep the pools it returns."""
+        logits, *rest = out
+        counts = rest.pop(0) if self.sparse else None
+        self.cache.set_pools(rest)
+        return logits, counts
+
+    def _resolve_counts(self, logits, counts, sync: bool, kind: str,
+                        bucket: int, t_in: float, t0: float):
+        """`_resolve` for a call that may carry expert counts: with
+        `sync` they ride the logits' read-back, and the chunks launched
+        before the call are done too. Returns (result, host counts or
+        None, t1)."""
+        if not sync or counts is None:
+            out, t1 = self._resolve(logits, sync, kind, bucket, t_in, t0)
+            return out, None, t1
+        (out, counts), t1 = self._resolve((logits, counts), sync, kind,
+                                          bucket, t_in, t0)
+        self._drain_chunk_counts(wait=True)
+        return out, counts, t1
+
+    def _note_experts(self, counts: np.ndarray, decode: bool) -> tuple:
+        """Account one call's (layers, experts) token counts; returns
+        (distinct experts with a token, summed over layers; tokens at
+        the busiest expert, largest over layers)."""
+        touched = int((counts > 0).sum())
+        load_max = int(counts.max())
+        self.expert_tokens += int(counts.sum())
+        if decode:
+            self.expert_steps_layers += counts.shape[0]
+            self.experts_touched_sum += touched
+        else:
+            self.expert_load_max_sum += load_max
+            self.expert_load_chunks += 1
+        return touched, load_max
+
+    def _drain_chunk_counts(self, wait: bool = False) -> None:
+        """Read back the expert counts of chunks launched with
+        sync=False, once the device has them (all of them after a
+        sync: the device runs in order), and put them on a `resolve`
+        span under the chunk's own `req`, `pos0` and `clen`."""
+        while self._chunk_counts:
+            req, pos0, clen, dev = self._chunk_counts[0]
+            if not (wait or dev.is_ready()):
+                return
+            self._chunk_counts.pop(0)
+            t0 = time.perf_counter()
+            counts = np.asarray(dev)  # nnlint: disable=NNL002 ready, or behind the caller's device_sync
+            touched, load_max = self._note_experts(counts, decode=False)
+            if self.tracer.active:
+                self.tracer.span(
+                    "backend", self.name, "resolve", t0,
+                    time.perf_counter(), what="llm_prefill_chunk",
+                    req=req, pos0=pos0, clen=clen,
+                    experts_touched=touched, expert_load_max=load_max)
 
     # -- sharded serving (serving/sharding.py) -----------------------------
     def _shard_fns(self):
@@ -287,16 +459,19 @@ class PagedLLMExecutor:
         if epoch == self.adopted_epoch:
             return
         old = self._version
-        self.params = self._entry.bundle(cur).params
-        dims = _derive_dims(self.params, self.n_heads)
+        bundle = self._entry.bundle(cur)
+        dims = _model_dims(bundle.params, self.n_heads, bundle.lm)
         if dims["n_layers"] != self.n_layers or dims["n_kv"] != self.n_kv \
-                or dims["head_dim"] != self.head_dim:
+                or dims["head_dim"] != self.head_dim \
+                or bundle.lm != self.spec:
             # pool-incompatible geometry cannot serve in-flight
             # sequences; refuse the adoption loudly rather than corrupt
             raise BackendError(
                 f"store swap {self._entry.name}@{old} → @{cur} changes "
-                f"cache geometry (layers/kv-heads/head-dim); restart the "
-                f"tensor_llm element to serve it")
+                f"cache geometry (layers/kv-heads/head-dim) or the "
+                f"model's description; restart the tensor_llm element "
+                f"to serve it")
+        self.params = bundle.params
         self.__dict__.update(dims)
         keep = {cur, self._pinned}
         if self.shards:
@@ -345,7 +520,8 @@ class PagedLLMExecutor:
             # sharded init already refused pallas and quantized params;
             # the ring cutover is decided per prompt in prefill()
             return "prefill"
-        if self.paged_kernel == "pallas":
+        if self.paged_kernel == "pallas" or self.sparse:
+            # the sparse-expert family has one prefill program: its chunk
             return "chunk"
         try:
             if "wqkv_scale" in self.params["blocks"][0]:
@@ -380,6 +556,18 @@ class PagedLLMExecutor:
             jitted = jax.jit(self._shard_fns()[kind],
                              static_argnames=("n_heads", "dtype"),
                              donate_argnums=(4, 5))
+            self._jits[key] = jitted
+            return jitted, True
+        if self.sparse:
+            from nnstreamer_tpu.llm.sparse_moe import (
+                sparse_moe_decode_step, sparse_moe_prefill_chunk)
+
+            fn, donate = (sparse_moe_prefill_chunk, (6, 7, 8)) \
+                if kind == "chunk" else (sparse_moe_decode_step, (5, 6, 7))
+            static = ("spec", "dtype", "by_block") if kind == "chunk" \
+                else ("spec", "dtype")
+            jitted = jax.jit(fn, static_argnames=static,
+                             donate_argnums=donate)
             self._jits[key] = jitted
             return jitted, True
         if kind == "prefill":
@@ -463,7 +651,13 @@ class PagedLLMExecutor:
             device_sync(dev, tracer=tr, name=f"{self.name}:{kind}")
             if on:
                 t_w = time.perf_counter()
-            out = np.asarray(dev)  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
+            if isinstance(dev, tuple):
+                # logits and what rides their read-back (expert counts)
+                out = tuple(np.asarray(d) for d in dev)  # nnlint: disable=NNL002 synced by the device_sync above
+                nbytes = sum(int(o.nbytes) for o in out)
+            else:
+                out = np.asarray(dev)  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
+                nbytes = int(out.nbytes)
         t1 = time.perf_counter()
         if on:
             what = f"llm_{kind}"
@@ -473,7 +667,7 @@ class PagedLLMExecutor:
             if sync:
                 tr.span("backend", self.name, "wait", t_d, t_w, what=what)
                 tr.span("backend", self.name, "readback", t_w, t1,
-                        what=what, bytes=int(out.nbytes))
+                        what=what, bytes=nbytes)
         return out, t1
 
     # -- device performance plane (runtime/devprof.py) ---------------------
@@ -493,9 +687,7 @@ class PagedLLMExecutor:
         else:
             n = sum(getattr(a, "nbytes", 0)
                     for a in jax.tree_util.tree_leaves(self.params))
-        for a in (self.cache.k, self.cache.v):
-            n += getattr(a, "nbytes", 0)
-        return n
+        return n + self.cache.resident_bytes()
 
     def _prof_capture(self, bucket: str, jitted, args: tuple,
                       kwargs: dict, seconds: float) -> None:
@@ -525,6 +717,7 @@ class PagedLLMExecutor:
         t_in = time.perf_counter() if self.tracer.active else 0.0
         plen = int(prompt.shape[0])
         if self._prefill_kind() == "chunk":
+            self.check_prompt(plen, 0)
             return self.prefill_chunk(
                 prompt, 0, block_table,
                 bucket=_next_pow2(plen, 8), sync=sync, req=req)
@@ -598,24 +791,37 @@ class PagedLLMExecutor:
         blk_off = ((int(pos0) + np.arange(c_b)) % bs).astype(np.int32)
         tab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
         tab[:len(block_table)] = block_table
-        args = (ids, blk_idx, blk_off, tab, np.int32(clen - 1))
+        args = (ids, np.int32(pos0), blk_idx, blk_off, tab,
+                np.int32(clen - 1))
+
+        kw = self._chunk_kw(pos0, c_b)
 
         def _run():
             jitted, fresh = self._get_jit("chunk", c_b)
-            logits, self.cache.k, self.cache.v = jitted(
-                self.params, args[0], np.int32(pos0), args[1], args[2],
-                args[3], self.cache.k, self.cache.v, args[4],
-                n_heads=self.n_heads, dtype=self.dtype)
-            return logits, fresh
+            logits, counts = self._take(jitted(
+                *self._chunk_args(self.params, *args), **kw))
+            return logits, counts, fresh
 
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"chunk:{c_b}")
         t0 = time.perf_counter()
-        logits, fresh = self._run_kernel("chunk", _run)
+        logits, counts, fresh = self._run_kernel("chunk", _run)
         kernel = self._kind_kernel("chunk")
-        out, t1 = self._resolve(logits, sync, "prefill_chunk", c_b,
-                                t_in, t0)
+        out, host_counts, t1 = self._resolve_counts(
+            logits, counts, sync, "prefill_chunk", c_b, t_in, t0)
+        extra = {}
+        if self.sparse:
+            # this family's chunk span also says where the chunk starts
+            # and, once its counts are on the host, how its tokens
+            # spread over the experts
+            extra["pos0"] = int(pos0)
+            if host_counts is not None:
+                extra["experts_touched"], extra["expert_load_max"] = \
+                    self._note_experts(host_counts, decode=False)
+            else:
+                self._drain_chunk_counts()
+                self._chunk_counts.append((req, int(pos0), clen, counts))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_prefill_chunk",
@@ -624,12 +830,11 @@ class PagedLLMExecutor:
             jitted, _ = self._get_jit("chunk", c_b)
             self._prof_capture(
                 f"chunk:{c_b}", jitted,
-                (self.params, args[0], np.int32(pos0), args[1], args[2],
-                 args[3], self.cache.k, self.cache.v, args[4]),
-                {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
+                self._chunk_args(self.params, *args), kw, t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill_chunk",
-                       bucket=c_b, clen=clen, kernel=kernel, req=req)
+                       bucket=c_b, clen=clen, kernel=kernel, req=req,
+                       **extra)
         self.chunk_prefills += 1
         self.kernel_invokes[kernel] += 1
         return out
@@ -658,24 +863,33 @@ class PagedLLMExecutor:
 
         def _run():
             jitted, fresh = self._get_jit("decode", b_b)
-            logits, self.cache.k, self.cache.v = jitted(
-                self._exec_params("decode"), cur_a, tab_a, pos_a,
-                self.cache.k, self.cache.v, n_heads=self.n_heads,
-                dtype=self.dtype)
-            return logits, fresh
+            logits, counts = self._take(jitted(*self._decode_args(
+                self._exec_params("decode"), cur_a, tab_a, pos_a, n),
+                **self._kw()))
+            return logits, counts, fresh
 
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"decode:{b_b}")
         t0 = time.perf_counter()
-        logits, fresh = self._run_kernel("decode", _run)
+        logits, counts, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
-        out, t1 = self._resolve(logits, sync, "decode", b_b, t_in, t0)
+        out, host_counts, t1 = self._resolve_counts(
+            logits, counts, sync, "decode", b_b, t_in, t0)
+        extra = {}
+        if host_counts is not None:
+            extra["experts_touched"], _ = self._note_experts(
+                host_counts, decode=True)
         if sync:
             out = out[:n]
-        # kv_tokens: the context this step attends, its own tokens
-        # included; kv_slots: the pool slots a layer read for it
-        kv_tokens, kv_slots = self._note_kv(pos_a, n)
+        # kv_tokens: the context this step attends (sparse family:
+        # scores), its own tokens included; kv_slots: the pool slots a
+        # layer read for it (sparse family: the selected slots' gathers)
+        if self.sparse:
+            kv_tokens, kv_slots, extra["kv_selected"], \
+                extra["idx_slots"] = self._note_kv_sparse(pos_a, n)
+        else:
+            kv_tokens, kv_slots = self._note_kv(pos_a, n)
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -683,14 +897,13 @@ class PagedLLMExecutor:
             self._note_bucket(("llmd", b_b))
             jitted, _ = self._get_jit("decode", b_b)
             self._prof_capture(
-                f"decode:{b_b}", jitted,
-                (self._exec_params("decode"), cur_a, tab_a, pos_a,
-                 self.cache.k, self.cache.v),
-                {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
+                f"decode:{b_b}", jitted, self._decode_args(
+                    self._exec_params("decode"), cur_a, tab_a, pos_a, n),
+                self._kw(), t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_decode", bucket=b_b,
                        rows=n, kernel=kernel, kv_tokens=kv_tokens,
-                       kv_slots=kv_slots)
+                       kv_slots=kv_slots, **extra)
         self.decode_steps += 1
         self.kernel_invokes[kernel] += 1
         return out
@@ -716,6 +929,27 @@ class PagedLLMExecutor:
         self.kv_tokens_attended += tokens
         self.kv_slots_read += slots
         return tokens, slots
+
+    def _note_kv_sparse(self, pos_a: np.ndarray, n: int) -> tuple:
+        """One decode step of the sparse-expert family, counted: the
+        indexer scores each live row's context (kv_tokens_scored)
+        reading the bucket's whole tables of the indexer pool
+        (idx_slots_read); the step attends min(topk, pos + 1) slots a
+        row (kv_tokens_selected, also kv_tokens_attended) and gathers
+        `topk` slots of K and V for every row of the bucket
+        (kv_slots_read). Returns the span's (kv_tokens, kv_slots,
+        kv_selected, idx_slots)."""
+        s_max = self.max_blocks * self.cache.block_size
+        k = min(int(self.spec.topk), s_max)
+        scored = int(pos_a[:n].sum()) + n
+        selected = int(np.minimum(k, pos_a[:n] + 1).sum())
+        slots, idx_slots = len(pos_a) * k, len(pos_a) * s_max
+        self.kv_tokens_scored += scored
+        self.kv_tokens_selected += selected
+        self.kv_tokens_attended += selected
+        self.idx_slots_read += idx_slots
+        self.kv_slots_read += slots
+        return scored, slots, selected, idx_slots
 
     def _get_multi_jit(self, bucket: int, steps: int, version=None):
         """Jitted K-step greedy decode window: ``jax.lax.scan`` whose
@@ -777,6 +1011,10 @@ class PagedLLMExecutor:
         in blocks the row still owned when the window ran."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
+        if self.sparse:
+            raise BackendError(
+                f"llm {self.name}: the sparse_moe family has no compiled "
+                f"decode window; set decode_window=0")
         t_in = time.perf_counter() if self.tracer.active else 0.0
         n = len(cur)
         steps = int(steps)
@@ -847,6 +1085,7 @@ class PagedLLMExecutor:
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"{kind}:{bucket}")
+        kw = self._kw()
         t0 = time.perf_counter()
         if kind in ("prefill", "ring"):
             ids = np.zeros((1, bucket), np.int32)
@@ -864,30 +1103,26 @@ class PagedLLMExecutor:
             off = (np.arange(bucket)
                    % self.cache.block_size).astype(np.int32)
             tab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
-            logits, self.cache.k, self.cache.v = jitted(
-                params, ids, np.int32(0), blk, off, tab, self.cache.k,
-                self.cache.v, np.int32(0), n_heads=self.n_heads,
-                dtype=self.dtype)
-            largs = (params, ids, np.int32(0), blk, off, tab,
-                     self.cache.k, self.cache.v, np.int32(0))
+            cargs = (params, ids, np.int32(0), blk, off, tab, np.int32(0))
+            kw = self._chunk_kw(0, bucket)
+            logits, _ = self._take(jitted(*self._chunk_args(*cargs), **kw))
+            largs = self._chunk_args(*cargs)
         else:
             cur = np.zeros((bucket,), np.int32)
             tab = np.full((bucket, self.max_blocks), SCRATCH_BLOCK,
                           np.int32)
             pos = np.zeros((bucket,), np.int32)
-            logits, self.cache.k, self.cache.v = jitted(
-                params, cur, tab, pos, self.cache.k, self.cache.v,
-                n_heads=self.n_heads, dtype=self.dtype)
-            largs = (params, cur, tab, pos, self.cache.k, self.cache.v)
+            # no live row: a sparse step's padding rows reach no expert
+            logits, _ = self._take(jitted(
+                *self._decode_args(params, cur, tab, pos, 0), **kw))
+            largs = self._decode_args(params, cur, tab, pos, 0)
         device_sync(logits, tracer=self.tracer,
                     name=f"{self.name}:warm_{kind}")
         self.compile_count += 1
         t1 = time.perf_counter()
         self._span("compile", t0, t1, what=f"llm_{kind}_warm",
                    bucket=bucket)
-        self._prof_capture(f"{kind}:{bucket}", jitted, largs,
-                           {"n_heads": self.n_heads, "dtype": self.dtype},
-                           t1 - t0)
+        self._prof_capture(f"{kind}:{bucket}", jitted, largs, kw, t1 - t0)
         return True
 
     def prewarm_buckets(self, *, max_batch: int, max_prompt: int,
@@ -955,9 +1190,10 @@ class PagedLLMExecutor:
         incoming version's executables for every bucket this executor
         has served, before the epoch flips."""
         params = getattr(bundle, "params", bundle)
-        dims = _derive_dims(params, self.n_heads)
+        spec = getattr(bundle, "lm", None)
+        dims = _model_dims(params, self.n_heads, spec)
         if dims["n_layers"] != self.n_layers or dims["n_kv"] != self.n_kv \
-                or dims["head_dim"] != self.head_dim:
+                or dims["head_dim"] != self.head_dim or spec != self.spec:
             raise BackendError(
                 f"incoming {self._entry.name}@{version} changes cache "
                 f"geometry; tensor_llm cannot hot-swap it over live "
@@ -1013,6 +1249,17 @@ class PagedLLMExecutor:
             "paged_kernel": self.paged_kernel,
             "kernel_invokes": dict(self.kernel_invokes),
         }
+        if self.sparse:
+            out.update(
+                family=self.spec.family,
+                kv_tokens_scored=self.kv_tokens_scored,
+                kv_tokens_selected=self.kv_tokens_selected,
+                idx_slots_read=self.idx_slots_read,
+                expert_tokens=self.expert_tokens,
+                expert_steps_layers=self.expert_steps_layers,
+                experts_touched_sum=self.experts_touched_sum,
+                expert_load_max_sum=self.expert_load_max_sum,
+                expert_load_chunks=self.expert_load_chunks)
         if self.shards:
             out["shards"] = self.shards
             out["shard_chips"] = list(self._shard_chips)

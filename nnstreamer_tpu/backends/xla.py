@@ -63,6 +63,10 @@ class ModelBundle:
     #: DecodeWav entry: RIFF header decode happens here, PCM samples
     #: enter the XLA program)
     host_pre: Optional[Callable[[tuple], tuple]] = None
+    #: for a language model served by tensor_llm: its family and the
+    #: sizes its params do not spell out (llm/spec.LMSpec); None = the
+    #: dense family, dims read from the params' shapes
+    lm: Any = None
 
 
 @dataclass

@@ -142,6 +142,10 @@ class LLMEngine:
             paged_kernel=paged_kernel, shards=shards,
             shard_chips=shard_chips, ring_prefill_min=ring_prefill_min,
             tracer=tracer, name=name)
+        if self.executor.sparse and self.decode_window > 0:
+            raise BackendError(
+                f"llm {name}: the sparse_moe family has no compiled decode "
+                f"window; set decode_window=0")
         self.cache = self.executor.cache
         self.queue: deque = deque()
         self.active: List[LLMRequest] = []
@@ -174,6 +178,7 @@ class LLMEngine:
             raise BackendError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
         ex = self.executor
+        ex.check_prompt(int(prompt.shape[0]), self.prefill_chunk)
         total = int(prompt.shape[0]) + max_new_tokens
         seq_cap = ex.max_blocks * self.cache.block_size
         if total > seq_cap:

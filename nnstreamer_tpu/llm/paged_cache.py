@@ -102,17 +102,38 @@ class BlockAllocator:
         }
 
 
+def idx_pack(block_size: int, idx_dim: int) -> int:
+    """Slots of the indexer pool that share one row: as many as fit 128
+    values and divide the block."""
+    if idx_dim <= 0:
+        return 1
+    p = max(1, min(int(block_size), 128 // int(idx_dim)))
+    while block_size % p:
+        p -= 1
+    return p
+
+
 class PagedKVCache:
     """The device-resident block pool + its allocator.
 
     k/v pools: (n_layers, num_blocks, block_size, n_kv, head_dim).
+    A model with an indexer (llm/sparse_moe.py) keeps a third kind of
+    state in the same blocks, under the same tables and allocator: the
+    indexer's keys, `idx`: block_size slots of idx_dim values a block
+    and layer, held as (n_layers, num_blocks, block_size // idx_pack,
+    idx_pack * idx_dim) with `idx_pack` neighbouring slots side by side
+    in one row of up to 128 values (a 64-wide minor dimension made
+    XLA:TPU re-lay the whole pool for every layer of a step; slot s of
+    a block is row s // idx_pack, values (s % idx_pack) * idx_dim
+    onward). `idx_dim=0` (every dense model) makes no third pool.
     The pools live here as plain jax arrays and are threaded through the
     executor's donated jit calls (write-in-place on device); this class
     only owns layout and accounting, never math.
     """
 
     def __init__(self, *, num_blocks: int, block_size: int, n_layers: int,
-                 n_kv: int, head_dim: int, dtype=None, placer=None):
+                 n_kv: int, head_dim: int, idx_dim: int = 0, dtype=None,
+                 placer=None):
         import jax.numpy as jnp
 
         self.num_blocks = int(num_blocks)
@@ -125,6 +146,13 @@ class PagedKVCache:
                  self.n_kv, self.head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
+        self.idx_dim = int(idx_dim)
+        self.idx_pack = idx_pack(self.block_size, self.idx_dim)
+        self.idx = jnp.zeros(
+            (self.n_layers, self.num_blocks,
+             self.block_size // self.idx_pack,
+             self.idx_pack * self.idx_dim), self.dtype) \
+            if self.idx_dim else None
         if placer is not None:
             # sharded serving hands us a device-placement closure (pool
             # sharded along the kv-head axis next to the projections —
@@ -142,8 +170,34 @@ class PagedKVCache:
     def tokens_capacity(self) -> int:
         return self.allocator.total * self.block_size
 
+    def pools(self) -> tuple:
+        """The pools there are: (k, v), and the indexer's keys after
+        them where the model has an indexer."""
+        return (self.k, self.v) if self.idx is None \
+            else (self.k, self.v, self.idx)
+
+    def set_pools(self, pools) -> None:
+        """Keep the pools a donating jit handed back, in `pools()` order."""
+        self.k, self.v, *rest = pools
+        if rest:
+            self.idx, = rest
+
+    @property
+    def block_bytes(self) -> int:
+        """Bytes one block holds over all layers and all pools."""
+        import jax.numpy as jnp
+
+        per_slot = 2 * self.n_kv * self.head_dim + self.idx_dim
+        return (self.n_layers * self.block_size * per_slot
+                * jnp.dtype(self.dtype).itemsize)
+
+    def resident_bytes(self) -> int:
+        return sum(int(p.nbytes) for p in self.pools())
+
     def stats(self) -> dict:
         out = self.allocator.stats()
         out["block_size"] = self.block_size
         out["tokens_capacity"] = self.tokens_capacity
+        out["pools"] = 2 if self.idx is None else 3
+        out["block_bytes"] = self.block_bytes
         return out

@@ -72,14 +72,14 @@ def _mlp_paged(blk, x, dtype):
     return _proj(blk, "wd", jax.nn.silu(gate) * up, dtype)
 
 
-def _rope_rows(x, pos):
+def _rope_rows(x, pos, base=10000.0):
     """Rotary embedding with a PER-ROW position: x (B, 1, H, D),
     pos (B,). Same f32 angle math as `transformer.rope`, broadcast over
     the batch instead of the sequence axis — row b's values are bit-
-    identical to rope(x[b:b+1], pos[b:b+1])."""
+    identical to rope(x[b:b+1], pos[b:b+1], base)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]   # (B, half)
     cos = jnp.cos(ang)[:, None, None, :]
     sin = jnp.sin(ang)[:, None, None, :]

@@ -34,11 +34,12 @@ def rmsnorm(x, w, eps=1e-6):
     return (x32 * scale).astype(x.dtype) * w
 
 
-def rope(x, pos):
-    """Rotary embedding. x: (B, S, H, D); pos: (S,) absolute positions."""
+def rope(x, pos, base=10000.0):
+    """Rotary embedding. x: (B, S, H, D); pos: (S,) absolute positions;
+    `base` travels with the model (10000.0 is the dense family's)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]   # (S, half)
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]

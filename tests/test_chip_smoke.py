@@ -28,3 +28,14 @@ def test_refuses_off_chip_and_names_the_platform():
         except (ValueError, AttributeError):
             pass
     assert "leg " not in proc.stdout          # no leg even started
+
+
+def test_the_sparse_moe_leg_runs_where_both_devices_are_the_cpu():
+    """The leg's own logic (the pipeline against the bare engine, the
+    bundle's layout, its assertions) on the only device there is here."""
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    out = chip_smoke.leg_llm_sparse_moe()
+    assert out["requests"] == 4 and out["tokens"] == 32
+    assert out["chunk_prefills"] >= 10
