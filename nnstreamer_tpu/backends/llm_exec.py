@@ -30,6 +30,14 @@ Buckets:
   chunk of a chunked prefill, including the short final one, pads to
   the same bucket so the whole family is one executable.
 
+Greedy ids stay on the device (llm/next_ids.py): a decode launched with
+``sync=False`` leaves each row's `argmax` in ``last_ids``, where the
+next launch finds it, and ``resolve`` reads `rows x 4` bytes a step
+later. The three small programs that do it are first called where their
+bucket's program is first built (one shape a decode bucket; the
+prefills' one shape in all), so a bucket's first call holds theirs and
+`compile_count` stays a count of buckets.
+
 Kernel selection (``paged_kernel`` prop / ``NNS_PAGED_KERNEL`` env,
 default ``xla``): the attention inner loop is either the XLA reference
 (`llm/paged_model.py` — the bit-parity path against
@@ -50,6 +58,7 @@ from __future__ import annotations
 
 import os
 import time
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -72,6 +81,20 @@ _FIRST_CALL_EVENTS = {
     "/jax/compilation_cache/cache_retrieval_time_sec":
         "jax_cache_retrieval",
 }
+
+
+@dataclass
+class DecodeLaunch:
+    """A decode step launched with ``sync=False``, until `resolve` reads
+    it: the rows' ids and (sparse family) expert counts, still on the
+    device, and what its `invoke` span says (None for a first call,
+    whose `compile` span is already written)."""
+
+    ids: Any
+    counts: Any
+    rows: int
+    t0: float
+    span: Optional[dict]
 
 
 def _model_dims(params: dict, n_heads: int, spec=None) -> dict:
@@ -221,6 +244,10 @@ class PagedLLMExecutor:
             head_dim=self.head_dim,
             idx_dim=int(self.spec.idx_dim) if self.sparse else 0,
             placer=placer)
+        #: each live sequence's last token, on the device, at the index
+        #: of its table's first block (llm/next_ids.py); single-chip only
+        self.last_ids = None if self.shards else jnp.zeros(
+            (self.cache.num_blocks,), jnp.int32)
         #: (ns, kind, bucket) → jitted callable
         self._jits: Dict[tuple, Any] = {}
         self.compile_count = 0
@@ -448,16 +475,19 @@ class PagedLLMExecutor:
     def tracks_store_epoch(self) -> bool:
         return self._entry is not None and self._pinned is None
 
+    def swap_due(self) -> bool:
+        """Whether the next `maybe_adopt` will adopt a flipped epoch."""
+        return self.tracks_store_epoch \
+            and self._entry.state[1] != self.adopted_epoch
+
     def maybe_adopt(self) -> None:
         """Adopt a flipped store epoch at a step boundary. In-flight
         sequences keep their old-version KV (documented serving
         tradeoff, docs/llm_serving.md) — retiring them instead would
         turn every swap into a latency spike for every live request."""
-        if not self.tracks_store_epoch:
+        if not self.swap_due():
             return
         cur, epoch = self._entry.state        # one read = consistent
-        if epoch == self.adopted_epoch:
-            return
         old = self._version
         bundle = self._entry.bundle(cur)
         dims = _model_dims(bundle.params, self.n_heads, bundle.lm)
@@ -543,6 +573,7 @@ class PagedLLMExecutor:
             self.cache_hits += 1
             return jitted, False
         self.cache_misses += 1
+        self._warm_ids(kind, bucket)
         self._first_call = []
         if self.shards:
             if kind == "chunk":
@@ -592,6 +623,30 @@ class PagedLLMExecutor:
                          donate_argnums=donate)
         self._jits[key] = jitted
         return jitted, True
+
+    def _warm_ids(self, kind: str, bucket: int) -> None:
+        """First call of the id programs (llm/next_ids.py) that serve
+        beside a bucket's program, made where that program is first
+        built, so that a bucket's first call holds theirs: the two of a
+        decode bucket, or the one every prefill shares. Every write goes
+        to the scratch block's entry."""
+        if self.shards:
+            return
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.llm import next_ids
+
+        if kind != "decode":
+            _, self.last_ids = next_ids.llm_pick_first(
+                jnp.zeros((self.vocab,), jnp.float32), self.last_ids,
+                np.int32(SCRATCH_BLOCK))
+            return
+        tab = jnp.full((bucket, self.max_blocks), SCRATCH_BLOCK, jnp.int32)
+        next_ids.llm_last_ids(self.last_ids, tab,
+                              np.zeros((bucket,), np.int32))
+        _, self.last_ids = next_ids.llm_pick_rows(
+            jnp.zeros((bucket, self.vocab), jnp.float32), self.last_ids,
+            tab)
 
     def _run_kernel(self, kind: str, run):
         """Call `run()` (get the bucket's jit and invoke it). On the
@@ -840,21 +895,33 @@ class PagedLLMExecutor:
         return out
 
     # -- decode ------------------------------------------------------------
-    def decode(self, cur: List[int], tables: List[List[int]],
+    def decode(self, cur: List[Optional[int]], tables: List[List[int]],
                pos: List[int], *, sync: bool = True):
         """One decode step for `len(cur)` live rows (bucketed to pow2;
-        padding rows write to the scratch block). With `sync` (default)
-        returns host logits (n, vocab) f32 for the live rows only; with
-        sync=False returns the padded device array (b_b, vocab) so the
-        engine can fold this step's decode into its single whole-step
-        `device_sync` (caller slices [:n] after syncing)."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        padding rows write to the scratch block). `cur[i]` is row i's
+        last token, or None where the host has not read it: the step
+        then takes it from `last_ids`, where the row's unsynced launch
+        or its prefill's `pick_first` left it. With `sync` (default)
+        returns host logits (n, vocab) f32 for the live rows. With
+        sync=False nothing is waited for: the rows' greedy ids are
+        taken on the device, kept in `last_ids` for the next launch, and
+        the returned `DecodeLaunch` is read by `resolve` (single-chip
+        only)."""
+        import jax
 
+        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.llm import next_ids
+
+        if self.shards and not sync:
+            raise BackendError(
+                f"llm {self.name}: an unsynced decode keeps its ids on "
+                f"one chip; shards={self.shards} decodes with sync=True")
         t_in = time.perf_counter() if self.tracer.active else 0.0
         n = len(cur)
         b_b = _next_pow2(n, 1)
         cur_a = np.zeros((b_b,), np.int32)
-        cur_a[:n] = cur
+        # -1: on the device, at the first block of the row's table
+        cur_a[:n] = [-1 if c is None else c for c in cur]
         tab_a = np.full((b_b, self.max_blocks), SCRATCH_BLOCK, np.int32)
         for i, t in enumerate(tables):
             tab_a[i, :len(t)] = t
@@ -863,16 +930,30 @@ class PagedLLMExecutor:
 
         def _run():
             jitted, fresh = self._get_jit("decode", b_b)
+            cur_d, tab_d, ids = cur_a, tab_a, None
+            if not self.shards:
+                # the tables go up once for the step and the two id
+                # programs beside it; `cur` is a device array whether
+                # the ids came from the host or not: one kind of
+                # argument, one executable a bucket
+                tab_d = jax.device_put(tab_a)
+                cur_d = next_ids.llm_last_ids(self.last_ids, tab_d, cur_a)
             logits, counts = self._take(jitted(*self._decode_args(
-                self._exec_params("decode"), cur_a, tab_a, pos_a, n),
+                self._exec_params("decode"), cur_d, tab_d, pos_a, n),
                 **self._kw()))
-            return logits, counts, fresh
+            if not sync:
+                ids, self.last_ids = next_ids.llm_pick_rows(
+                    logits, self.last_ids, tab_d)
+                # on their way while the next step is prepared
+                for dev in (ids,) if counts is None else (ids, counts):
+                    dev.copy_to_host_async()
+            return logits, counts, ids, fresh
 
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"decode:{b_b}")
         t0 = time.perf_counter()
-        logits, counts, fresh = self._run_kernel("decode", _run)
+        logits, counts, ids, fresh = self._run_kernel("decode", _run)
         kernel = self._kind_kernel("decode")
         out, host_counts, t1 = self._resolve_counts(
             logits, counts, sync, "decode", b_b, t_in, t0)
@@ -880,8 +961,6 @@ class PagedLLMExecutor:
         if host_counts is not None:
             extra["experts_touched"], _ = self._note_experts(
                 host_counts, decode=True)
-        if sync:
-            out = out[:n]
         # kv_tokens: the context this step attends (sparse family:
         # scores), its own tokens included; kv_slots: the pool slots a
         # layer read for it (sparse family: the selected slots' gathers)
@@ -890,6 +969,8 @@ class PagedLLMExecutor:
                 extra["idx_slots"] = self._note_kv_sparse(pos_a, n)
         else:
             kv_tokens, kv_slots = self._note_kv(pos_a, n)
+        span = dict(what="llm_decode", bucket=b_b, rows=n, kernel=kernel,
+                    kv_tokens=kv_tokens, kv_slots=kv_slots, **extra)
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -900,13 +981,69 @@ class PagedLLMExecutor:
                 f"decode:{b_b}", jitted, self._decode_args(
                     self._exec_params("decode"), cur_a, tab_a, pos_a, n),
                 self._kw(), t1 - t0)
-        else:
-            self._span("invoke", t0, t1, what="llm_decode", bucket=b_b,
-                       rows=n, kernel=kernel, kv_tokens=kv_tokens,
-                       kv_slots=kv_slots, **extra)
+        elif sync:
+            self._span("invoke", t0, t1, **span)
         self.decode_steps += 1
         self.kernel_invokes[kernel] += 1
-        return out
+        if sync:
+            return out[:n]
+        return DecodeLaunch(ids, counts, n, t0, None if fresh else span)
+
+    def pick_first(self, logits, block_table: List[int]):
+        """The greedy first token of a prefill launched with sync=False,
+        taken on the device from its `logits` and kept in `last_ids` for
+        the sequence's first decode launch. Returns the device id, which
+        `resolve` reads back."""
+        from nnstreamer_tpu.llm import next_ids
+
+        on = self.tracer.active
+        t0 = time.perf_counter() if on else 0.0
+        tok, self.last_ids = next_ids.llm_pick_first(
+            logits, self.last_ids, np.int32(block_table[0]))
+        tok.copy_to_host_async()
+        if on:
+            self.tracer.span("backend", self.name, "dispatch", t0,
+                             time.perf_counter(), what="llm_first")
+        return tok
+
+    def resolve(self, launch: Optional[DecodeLaunch], firsts=()) -> tuple:
+        """Wait for an unsynced decode launch (None: there was none) and
+        for the first ids picked beside it, and read them back: one
+        `device_sync`, one `wait` and one `readback` span. Returns (the
+        launch's ids (rows,) int32 or None, the first ids). The launch's
+        `invoke` span is written here, from the launch to the end of the
+        wait, with what only the read-back tells (the sparse family's
+        `experts_touched`)."""
+        tr = self.tracer
+        on = tr.active
+        dev = list(firsts)
+        if launch is not None:
+            dev.append(launch.ids)
+            if launch.counts is not None:
+                dev.append(launch.counts)
+        t_w0 = time.perf_counter() if on else 0.0
+        device_sync(dev, tracer=tr, name=f"{self.name}:decode")
+        t_w = time.perf_counter() if on else 0.0
+        host = [np.asarray(d) for d in dev]  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
+        if on:
+            tr.span("backend", self.name, "wait", t_w0, t_w,
+                    what="llm_decode")
+            tr.span("backend", self.name, "readback", t_w,
+                    time.perf_counter(), what="llm_decode",
+                    bytes=sum(int(h.nbytes) for h in host))
+        first_ids = [int(h) for h in host[:len(firsts)]]
+        if launch is None:
+            return None, first_ids
+        span = launch.span
+        if launch.counts is not None:
+            touched, _ = self._note_experts(host[-1], decode=True)
+            # the chunks launched before it are done too
+            self._drain_chunk_counts()
+            if span is not None:
+                span["experts_touched"] = touched
+        if span is not None:
+            self._span("invoke", launch.t0, t_w, **span)
+        return host[len(firsts)][:launch.rows], first_ids
 
     def _note_kv(self, pos_a: np.ndarray, n: int, steps: int = 1) -> tuple:
         """Count what `steps` decode steps from the bucket's positions
@@ -1108,10 +1245,17 @@ class PagedLLMExecutor:
             logits, _ = self._take(jitted(*self._chunk_args(*cargs), **kw))
             largs = self._chunk_args(*cargs)
         else:
-            cur = np.zeros((bucket,), np.int32)
+            cur = pos = np.zeros((bucket,), np.int32)
             tab = np.full((bucket, self.max_blocks), SCRATCH_BLOCK,
                           np.int32)
-            pos = np.zeros((bucket,), np.int32)
+            if not self.shards:
+                # on the device, as `decode` passes them
+                import jax
+
+                from nnstreamer_tpu.llm import next_ids
+
+                tab = jax.device_put(tab)
+                cur = next_ids.llm_last_ids(self.last_ids, tab, cur)
             # no live row: a sparse step's padding rows reach no expert
             logits, _ = self._take(jitted(
                 *self._decode_args(params, cur, tab, pos, 0), **kw))

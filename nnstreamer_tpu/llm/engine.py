@@ -18,17 +18,32 @@ advances one N-token chunk per step — through the executor's one
 s8192 prompt stops being head-of-line for every live sequence's
 inter-token latency. The final chunk's logits yield the first token.
 
-Sampling is host-side on the step's (vocab,) f32 logits: temperature 0
-is `np.argmax`, which shares first-occurrence tie-breaking with the
-`jnp.argmax` inside `transformer.generate`'s fused decode — a parity
-requirement, not a convenience. Temperature > 0 uses a per-request
-seeded Generator so a request's tokens don't depend on its batchmates.
+One step runs one launch ahead of its read-back. While every live row is
+greedy (temperature 0) on a single chip, a step admits, launches this
+step's decode, and only then reads the *previous* launch: each row's
+token is the `argmax` of its logits taken on the device
+(llm/next_ids.py: the lowest index among equal maxima, as `np.argmax`
+and the `jnp.argmax` inside `transformer.generate`'s fused decode — a
+parity requirement, not a convenience), it stays there to feed the next
+launch, and the host reads `rows x 4` bytes of ids while the device is
+already in the next step. The host knows every row's token count without
+the values, so a row whose budget ends with the token in flight is not
+launched again; a row that stops on `eos_id` was, and that one token is
+discarded (`lookahead_discarded`). Tokens reach `step()`'s caller one
+step after their launch.
+
+A step that holds a row with temperature > 0 resolves first and samples
+on the host from that step's (vocab,) f32 logits, as does every step
+under `shards` or a `decode_window`: the sampled token comes from a
+per-request seeded Generator (so a request's tokens don't depend on its
+batchmates) and must be known before the next launch. Which of the two
+orders a step takes is read from its rows, never set.
 
 Host syncs are batched: every prefill/chunk launched in a step returns
-*device* logits, and one `runtime.sync.device_sync` over the whole
-pending set resolves them together — one forced sync for all of a
-step's admissions plus one for the decode batch, instead of one per
-admitted request.
+*device* logits, and one `runtime.sync.device_sync` resolves a launch's
+ids together with the first ids of the prefills launched beside it (or,
+in a sampled step, one over the prefills' logits and one over the
+decode batch's).
 """
 
 from __future__ import annotations
@@ -70,6 +85,7 @@ class LLMRequest:
     t_first: Optional[float] = None
     t_last: float = 0.0
     itl_ms: List[float] = field(default_factory=list)
+    ahead: int = 0                      # tokens launched, not yet read back
     _rng: Any = None
 
     @property
@@ -98,6 +114,28 @@ class TokenEvent:
     request: LLMRequest
     tokens: List[int]
     done: bool
+
+
+class _Picked:
+    """Stands where a row's logits stood when their argmax was taken on
+    the device: the `token`, and the logits' `shape`. `_sample` stays
+    the one place every token of every row passes."""
+
+    __slots__ = ("token", "shape")
+
+    def __init__(self, token: int, vocab: int):
+        self.token, self.shape = token, (vocab,)
+
+
+@dataclass
+class _Ahead:
+    """What a step launched and left for the next step to read: the
+    first ids of its prefills, its decode launch (None: no row had a
+    token left to decode), and that launch's rows in order."""
+
+    firsts: List[tuple]                 # (request, device id)
+    launch: Any
+    rows: List[LLMRequest]
 
 
 class LLMEngine:
@@ -158,6 +196,12 @@ class LLMEngine:
         self.admission_blocked = 0
         self.decode_windows = 0
         self.window_tokens = 0
+        #: the launch the next step reads (module docstring)
+        self._ahead: Optional[_Ahead] = None
+        # decode launches made while the one before was still unread,
+        # and tokens computed for a row that had stopped on its eos_id
+        self.lookahead_steps = 0
+        self.lookahead_discarded = 0
         self._first_ms: List[float] = []
         self._itl_ms: List[float] = []
 
@@ -214,22 +258,34 @@ class LLMEngine:
     # -- the serving quantum ----------------------------------------------
     @property
     def has_work(self) -> bool:
-        return bool(self.queue or self.active or self.prefilling)
+        return bool(self.queue or self.active or self.prefilling
+                    or self._ahead)
 
     def step(self) -> List[TokenEvent]:
-        """Admit, prefill (whole or one chunk of a long prompt), one
-        decode step, retire. Returns this step's token events (freshly
-        admitted requests contribute their prefill token AND their first
-        decode token; chunk-prefilling requests emit nothing until their
-        final chunk lands)."""
-        self.executor.maybe_adopt()
+        """Admit, prefill (whole or one chunk of a long prompt), launch
+        one decode step, read the launch before it, retire. Returns the
+        token events of what was read: a request's prefill token and
+        its first decode token come together, a step after its
+        admission (in a sampled step: at once, as every other token);
+        chunk-prefilling requests emit nothing until their final chunk
+        lands."""
         events: List[TokenEvent] = []
+        if self._ahead is not None and self.executor.swap_due():
+            # a swap lands between two whole steps of one version
+            self._resolve_ahead(events)
+        self.executor.maybe_adopt()
         #: (req, device logits) for every prefill completed this step
         pending: List[tuple] = []
         self._admit(pending)
         self._prefill_chunks(pending)
-        self._finish_pending(pending, events)
-        self._decode(events)
+        if self._runs_ahead(pending):
+            ahead = self._launch_ahead(pending)
+            self._resolve_ahead(events)
+            self._ahead = ahead
+        else:
+            self._resolve_ahead(events)
+            self._finish_pending(pending, events)
+            self._decode(events)
         self.steps += 1
         return events
 
@@ -358,6 +414,77 @@ class LLMEngine:
                     what="llm_prefill_batch")
             self._sample_span(t1, len(pending))
 
+    def _runs_ahead(self, pending: List[tuple]) -> bool:
+        """Whether this step may launch before it reads: every row it
+        holds is greedy, on one chip, with no compiled window set."""
+        if self.executor.shards or self.decode_window >= 2:
+            return False
+        rows = self.active + [r for r, _ in pending]
+        return not any(r.temperature > 0.0 for r in rows)
+
+    def _launch_ahead(self, pending: List[tuple]) -> Optional[_Ahead]:
+        """Take each finished prefill's first token on the device, then
+        launch one decode step for every row that has a token left once
+        those in flight are counted. Nothing is waited for."""
+        ex = self.executor
+        firsts = []
+        for req, logits in pending:
+            firsts.append((req, ex.pick_first(logits, req.block_table)))
+            req.ahead += 1
+            self.active.append(req)
+        rows = [r for r in self.active
+                if len(r.tokens) + r.ahead < r.max_new_tokens]
+        launch = None
+        if rows:
+            launch = ex.decode(
+                [None if r.ahead else r.tokens[-1] for r in rows],
+                [r.block_table for r in rows], [r.pos for r in rows],
+                sync=False)
+            if self._ahead is not None and self._ahead.launch is not None:
+                self.lookahead_steps += 1
+            for r in rows:
+                r.pos += 1
+                r.ahead += 1
+        # a row whose budget ends with a token in flight is read by no
+        # later launch: its row and blocks go back now, for the next
+        # step's admission as in the synchronous order (whatever is
+        # prefilled into them runs after the launches already made)
+        for r in [r for r in self.active
+                  if len(r.tokens) + r.ahead >= r.max_new_tokens]:
+            self._release(r)
+        if launch is None and not firsts:
+            return None
+        return _Ahead(firsts, launch, rows)
+
+    def _resolve_ahead(self, events: List[TokenEvent]) -> None:
+        """Read the outstanding launch, if there is one: its prefills'
+        first tokens, then its rows' tokens. A row that stopped on its
+        eos_id after the launch was made has its token discarded; its
+        write went to a block it still owned, and the device runs a
+        later prefill into that block after it."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        ids, first_ids = self.executor.resolve(
+            ahead.launch, [dev for _, dev in ahead.firsts])
+        t0 = time.perf_counter() if self.tracer.active else 0.0
+        taken = [(req, tok) for (req, _), tok
+                 in zip(ahead.firsts, first_ids)]
+        if ids is not None:
+            taken.extend(zip(ahead.rows, ids))
+        vocab = self.executor.vocab
+        for req, tok in taken:
+            req.ahead -= 1
+            if req.state != "active":
+                self.lookahead_discarded += 1
+                continue
+            tok = self._sample(req, _Picked(int(tok), vocab))
+            self._record_token(req, tok)
+            done = self._maybe_finish(req, tok)
+            events.append(TokenEvent(req, [tok], done))
+        if self.tracer.active:
+            self._sample_span(t0, len(taken))
+
     def _window_len(self, live: List[LLMRequest]) -> int:
         """How many decode steps may run as one compiled window right
         now. 1 means per-step mode; >= 2 enters decode_multi. The
@@ -426,7 +553,9 @@ class LLMEngine:
         self.tracer.span("llm", self.name, "sample", t0,
                          time.perf_counter(), step=self.steps, rows=rows)
 
-    def _sample(self, req: LLMRequest, logits: np.ndarray) -> int:
+    def _sample(self, req: LLMRequest, logits) -> int:
+        if isinstance(logits, _Picked):
+            return logits.token
         if req.temperature <= 0.0:
             return int(np.argmax(logits))
         lg = logits.astype(np.float64) / req.temperature
@@ -465,10 +594,8 @@ class LLMEngine:
         else:
             return False
         req.state = "done"
-        self.cache.allocator.free_blocks(req.block_table)
-        req.block_table = []
-        if req in self.active:
-            self.active.remove(req)
+        if req.block_table:
+            self._release(req)
         self.finished += 1
         if self.tracer.active:
             self.tracer.record_llm_request(
@@ -476,6 +603,13 @@ class LLMEngine:
                 **{k: v for k, v in req.summary().items()
                    if k != "req_id"})
         return True
+
+    def _release(self, req: LLMRequest) -> None:
+        """Give a request's row and blocks back: when it finishes, or
+        ahead of that once its last token is in flight."""
+        self.cache.allocator.free_blocks(req.block_table)
+        req.block_table = []
+        self.active.remove(req)
 
     def stats(self) -> dict:
         first = sorted(self._first_ms)
@@ -494,6 +628,8 @@ class LLMEngine:
             "decode_window": self.decode_window,
             "decode_windows": self.decode_windows,
             "window_tokens": self.window_tokens,
+            "lookahead_steps": self.lookahead_steps,
+            "lookahead_discarded": self.lookahead_discarded,
             "cache": self.cache.stats(),
             "executor": self.executor.stats(),
         }

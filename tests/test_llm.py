@@ -103,21 +103,45 @@ def test_llm_bucket_manifest_roundtrip():
 
 # -- decode parity vs transformer.generate -----------------------------------
 
-def test_paged_parity_single_request(engine, params):
+@pytest.fixture(params=["ahead", "resolved"])
+def order(request, monkeypatch):
+    """The two orders of a step. `ahead`: launch, then read the launch
+    before (what greedy rows on one chip get). `resolved`: read, sample
+    on the host, then launch (what a step with a sampled row gets),
+    here by steering the engine's own choice. The served tokens are the
+    same in both, and generate()'s."""
+    if request.param == "resolved":
+        monkeypatch.setattr(LLMEngine, "_runs_ahead",
+                            lambda self, pending: False)
+    return request.param
+
+
+def _ahead_since(eng, before, order):
+    """Launches made ahead of a read since `before`: all but the first
+    of a drained burst under `ahead`, none under `resolved`."""
+    made = eng.lookahead_steps - before
+    return made > 0 if order == "ahead" else made == 0
+
+
+def test_paged_parity_single_request(engine, params, order):
     prompt = np.array([5, 17, 3], np.int32)
+    before = engine.lookahead_steps
     req = engine.submit(prompt, max_new_tokens=8)
     engine.drain()
     assert req.finish_reason == "length"
     assert np.array_equal(np.array(req.tokens), _ref(params, prompt, 8))
+    assert _ahead_since(engine, before, order)
+    assert engine.lookahead_discarded == 0
 
 
-def test_paged_parity_interleaved_lengths(engine, params):
+def test_paged_parity_interleaved_lengths(engine, params, order):
     """Concurrent requests with different prompt lengths interleave in
     one continuous batch; each stream must still match its own
     single-sequence generate() bit-for-bit."""
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, 61, size=n).astype(np.int32)
                for n in (1, 4, 7, 11)]
+    before = engine.lookahead_steps
     reqs = [engine.submit(p, max_new_tokens=6) for p in prompts]
     engine.drain()
     for p, r in zip(prompts, reqs):
@@ -125,11 +149,13 @@ def test_paged_parity_interleaved_lengths(engine, params):
             f"plen={len(p)}"
     # every retirement returned its blocks
     assert engine.cache.allocator.used == 0
+    assert _ahead_since(engine, before, order)
 
 
-def test_paged_parity_staggered_admission(engine, params):
+def test_paged_parity_staggered_admission(engine, params, order):
     """A request admitted mid-flight (merged into a running decode
     batch) produces the same tokens as one served alone."""
+    before = engine.lookahead_steps
     a = engine.submit(np.array([9, 2, 40, 11], np.int32),
                       max_new_tokens=10)
     engine.step()                    # a is prefilled + decoding
@@ -139,11 +165,12 @@ def test_paged_parity_staggered_admission(engine, params):
                           _ref(params, a.prompt, 10))
     assert np.array_equal(np.array(b.tokens),
                           _ref(params, b.prompt, 5))
+    assert _ahead_since(engine, before, order)
 
 
 # -- admission / retirement --------------------------------------------------
 
-def test_admission_queues_when_pool_full(params):
+def test_admission_queues_when_pool_full(params, order):
     """More requests than the pool can hold: latecomers queue (never
     crash) and complete as retirements free blocks."""
     eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=8,
@@ -192,6 +219,100 @@ def test_eos_retires_and_frees_blocks(engine, params):
     assert engine.cache.allocator.used == 0
 
 
+def test_eos_with_the_next_step_in_flight_discards_one_token(params):
+    """A row stops on its eos_id when the launch after is already made:
+    that launch's token for it is discarded and counted, its blocks go
+    back once, and the request that takes them is served right."""
+    # 6 usable blocks of 4 slots; a request of 2 + 8 takes 3: two fit
+    eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=7,
+                    max_batch=4, max_len=16)
+    prompt = np.array([12, 30], np.int32)
+    probe = _ref(params, prompt, 8)
+    eos = int(probe[3])
+    stop = [int(t) for t in probe].index(eos)
+    a = eng.submit(prompt, max_new_tokens=8, eos_id=eos)
+    b = eng.submit(np.array([7, 19], np.int32), max_new_tokens=8)
+    c = eng.submit(np.array([41, 5], np.int32), max_new_tokens=8)
+    eng.step()
+    a_blocks = set(a.block_table)
+    assert len(a_blocks) == 3 and c.state == "queued"
+    while a.state != "done":
+        eng.step()
+    # a's stop was read after the next launch was made, with a in it
+    assert a.finish_reason == "eos" and len(a.tokens) == stop + 1
+    assert a.ahead == 1 and eng.lookahead_discarded == 0
+    eng.step()                       # reads that launch; admits c
+    assert a.ahead == 0 and eng.lookahead_discarded == 1
+    assert set(c.block_table) == a_blocks
+    eng.drain()
+    assert [int(t) for t in a.tokens] == [int(t) for t in probe[:stop + 1]]
+    for r in (b, c):
+        assert np.array_equal(
+            np.array(r.tokens),
+            np.asarray(generate(params, r.prompt[None, :], 8,
+                                n_heads=4, max_len=16))[0, 2:])
+    st = eng.stats()
+    assert st["lookahead_discarded"] == 1
+    # a discarded row is a launch's row, not a token
+    assert st["tokens_out"] == sum(len(r.tokens) for r in (a, b, c))
+    assert st["cache"]["blocks_used"] == 0 and eng.admission_blocked > 0
+
+
+def test_a_sampled_row_puts_its_steps_in_the_resolved_order(params):
+    """While a row with temperature > 0 is live every step resolves
+    before it launches, and the row's tokens are those of the same seed
+    served alone (every step resolved); greedy rows beside it, before it
+    and after it are generate()'s."""
+    kw = dict(max_new_tokens=5, temperature=0.8, top_k=5, seed=11)
+    prompt = np.array([3, 8, 21], np.int32)
+    alone = LLMEngine(params, n_heads=4, block_size=4, num_blocks=32,
+                      max_batch=4, max_len=64)
+    want = alone.submit(prompt, **kw)
+    alone.drain()
+    assert alone.lookahead_steps == 0 and len(want.tokens) == 5
+
+    eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=32,
+                    max_batch=4, max_len=64)
+    g1 = eng.submit(np.array([9, 2, 40, 11], np.int32), max_new_tokens=14)
+    eng.step()
+    eng.step()
+    assert eng._ahead is not None and eng.lookahead_steps == 1
+    s = eng.submit(prompt, **kw)
+    eng.step()                       # reads g1's launch, then resolves
+    assert eng._ahead is None and len(s.tokens) == 2
+    g2 = eng.submit(np.array([33, 1], np.int32), max_new_tokens=6)
+    while s.state != "done":
+        eng.step()
+        assert eng._ahead is None and g1.ahead == 0
+    assert eng.lookahead_steps == 1
+    eng.drain()                      # greedy rows only: ahead again
+    assert eng.lookahead_steps > 1
+    assert s.tokens == want.tokens
+    assert np.array_equal(np.array(g1.tokens), _ref(params, g1.prompt, 14))
+    assert np.array_equal(np.array(g2.tokens), _ref(params, g2.prompt, 6))
+    st = eng.stats()
+    assert 0 < st["lookahead_steps"] < st["executor"]["decode_steps"]
+    assert st["lookahead_discarded"] == 0
+
+
+def test_drain_reads_the_launch_left_in_flight(params):
+    """A request of two tokens is launched whole in its first step and
+    its row is given back with its last token in flight: nothing is
+    queued, live or prefilling, and there is still work."""
+    eng = LLMEngine(params, n_heads=4, block_size=4, num_blocks=32,
+                    max_batch=4, max_len=64)
+    req = eng.submit(np.array([5, 17, 3], np.int32), max_new_tokens=2)
+    assert eng.step() == []
+    assert not (eng.queue or eng.active or eng.prefilling)
+    assert eng.cache.allocator.used == 0 and req.tokens == []
+    assert eng.has_work and eng.stats()["executor"]["decode_steps"] == 1
+    events = eng.drain()
+    assert [e.tokens for e in events] == [[t] for t in req.tokens]
+    assert events[-1].done and not eng.has_work and eng._ahead is None
+    assert np.array_equal(np.array(req.tokens),
+                          _ref(params, req.prompt, 2))
+
+
 def test_static_batching_runs_to_completion(params):
     """static mode: nothing is admitted while a batch is in flight; the
     tokens still match generate()."""
@@ -234,6 +355,61 @@ def test_store_hot_swap_adopts_new_weights(params):
         eng.drain()
         assert eng.executor.swap_count == 1
         assert np.array_equal(np.array(r2.tokens), _ref(p2, prompt, 5))
+    finally:
+        reset_store()
+
+
+def _swap_with_a_request_in_flight(params, name):
+    """Two steps of one request, a swap, one more step, then the rest:
+    (the engine and the request after that step, the labels of the
+    step's waits, swap and launches in order, the whole stream)."""
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    store = get_store()
+    p2 = init_params(vocab=61, d_model=32, n_layers=2, n_heads=4,
+                     n_kv_heads=2, seed=9)
+    store.register(name, ModelBundle(fn=None, params=params))
+    tr = Tracer()
+    eng = LLMEngine(f"store://{name}", n_heads=4, block_size=4,
+                    num_blocks=32, max_batch=4, max_len=64, tracer=tr,
+                    name="e")
+    req = eng.submit(np.array([3, 44, 8], np.int32), max_new_tokens=7)
+    eng.step()
+    eng.step()
+    store.register(name, ModelBundle(fn=None, params=p2))
+    store.update(name)
+    mark = len(tr.events())
+    eng.step()
+    assert eng.executor.swap_count == 1
+    labels = [ev[3] for ev in tr.events()[mark:]
+              if ev[3] in ("wait", "model_swap", "dispatch")]
+    seen = (len(req.tokens), req.ahead)
+    eng.drain()
+    return labels, seen, list(req.tokens)
+
+
+def test_hot_swap_reads_the_launch_in_flight_first(params, monkeypatch):
+    """A swap that lands with a launch unread: the step that adopts it
+    reads that launch (the old version's last) before it adopts and
+    launches, so a request in flight changes version at the token the
+    resolved order changes it at."""
+    reset_store()
+    try:
+        labels, seen, ahead = _swap_with_a_request_in_flight(
+            params, "llm_swap_a")
+        assert labels == ["wait", "model_swap", "dispatch"]
+        assert seen == (3, 1)       # three read, the new version's first out
+        monkeypatch.setattr(LLMEngine, "_runs_ahead",
+                            lambda self, pending: False)
+        labels, seen, resolved = _swap_with_a_request_in_flight(
+            params, "llm_swap_r")
+        assert labels == ["model_swap", "dispatch", "wait"]
+        assert seen == (4, 0)
+        # three tokens of the old version, then the new one's over the
+        # old one's KV: the same stream in both orders
+        assert ahead[:3] == [int(t) for t in _ref(params, [3, 44, 8], 3)]
+        assert ahead == resolved and len(ahead) == 7
     finally:
         reset_store()
 
@@ -459,33 +635,59 @@ def test_step_spans_nest_and_order_on_the_serving_thread(params):
                               "admit_blocked", "admit_full")
         assert "input_depth" in t[3]
     assert all(any(_inside(a, t) for t in steps) for a in admits)
-    # every synced call: prep, dispatch, wait, readback in that order,
-    # disjoint, and the last three inside the call's invoke/compile
+
+    def step_of(span):
+        mine = [i for i, t in enumerate(steps) if _inside(span, t)]
+        assert len(mine) == 1, span
+        return mine[0]
+
+    def decode(label):
+        return [b for b in backend if b[0] == label
+                and b[3].get("what") == "llm_decode"]
+
+    # a launch: prep then dispatch inside one step, nothing waited for
+    launches = decode("dispatch")
+    assert len(launches) >= 4
+    for d in launches:
+        prep = [b for b in decode("prep") if b[2] == d[1]]
+        assert len(prep) == 1 and step_of(prep[0]) == step_of(d)
+        assert "kernel" not in d[3] and "kernel" not in prep[0][3]
+    # a read: wait then readback of a few ids, after the step's own
+    # launch where it made one, and one sample span behind it
+    reads = decode("wait")
+    samples = _spans(tr, "llm", "sample")
+    assert len(reads) == len(samples)
+    for w in reads:
+        back = [b for b in decode("readback") if b[1] == w[2]]
+        assert len(back) == 1 and step_of(back[0]) == step_of(w)
+        assert 0 < back[0][3]["bytes"] <= 4 * 8     # ids, never logits
+        ahead = [d for d in launches if step_of(d) == step_of(w)]
+        assert len(ahead) <= 1 and all(d[2] <= w[1] for d in ahead)
+        mine = [s for s in samples if back[0][2] <= s[1]
+                and step_of(s) == step_of(w)]
+        assert len(mine) == 1
+    # a launch's invoke (compile, a first call) span is its own: from
+    # its dispatch to the end of the wait that read it, a step later
     outers = [b for b in backend if b[0] in ("invoke", "compile")
               and b[3]["what"] == "llm_decode"]
-    assert outers
+    assert len(outers) == len(launches)
     for o in outers:
-        prep = [b for b in backend if b[0] == "prep" and b[2] == o[1]]
-        assert len(prep) == 1
-        kids = [b for b in backend if b[0] in ("dispatch", "wait",
-                                               "readback")
-                and _inside(b, o)]
-        assert [k[0] for k in kids] == ["dispatch", "wait", "readback"]
-        chain = prep + kids
-        for a, b in zip(chain, chain[1:]):
-            assert a[2] <= b[1]
-        assert kids[0][1] == o[1] and kids[-1][2] == o[2]
-        assert kids[-1][3]["bytes"] > 0
-        assert all("kernel" not in k[3] for k in chain)
+        d = [d for d in launches if d[1] == o[1]]
+        assert len(d) == 1
+        if o[0] == "compile":
+            assert _inside(d[0], o) and step_of(o) == step_of(d[0])
+            continue
+        assert o[3]["rows"] >= 1 and o[3]["kv_tokens"] >= o[3]["rows"]
+        w = [w for w in reads if w[2] == o[2]]
+        assert len(w) == 1 and step_of(w[0]) == step_of(d[0]) + 1
     # the children are not counted as kernel spans a second time
     assert sum(tr.kernel_spans().values()) == len(
         [b for b in backend if b[0] in ("invoke", "compile")
          and "kernel" in b[3]])
-    # one sample span a decode, after its readback; emission is timed
-    # from on_timer's return, inside the same timer span
-    samples = _spans(tr, "llm", "sample")
+    # emission is timed from on_timer's return, inside the same timer
+    # span
     emits = _spans(tr, "element", "emit")
-    assert len(samples) >= len(outers) and emits
+    assert emits
     for e in emits:
         assert e[3]["n"] >= 1 and any(_inside(e, t) for t in steps)
     # every event of the ring is on the one clock
@@ -563,10 +765,12 @@ def test_decode_invoke_carries_the_context_it_attends(params):
         live = [r for r in eng.active if r.state == "active"]
         want.append(sum(r.pos for r in live) + len(live))
         eng.step()
+    eng.step()                      # reads the third launch
     got = [b[3]["kv_tokens"] for b in _spans(tr, "backend", "invoke")
            if b[3]["what"] == "llm_decode"]
     # prompts of 5 and 8 and one decoded token each before the first
-    # `invoke`: (6 + 9) held + 2 written, then two more a step
+    # `invoke`: (6 + 9) held + 2 written, then two more a step; the
+    # fourth step's launch is not read yet and has no span
     assert got == want == [17, 19, 21]
     eng.executor.close()
 

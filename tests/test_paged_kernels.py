@@ -436,7 +436,8 @@ def test_engine_chunked_prefill_interleaves_decode(params):
                     num_blocks=64, max_batch=4, max_len=128,
                     prefill_chunk=8)
     short = eng.submit(_prompt(4, 16), max_new_tokens=32)
-    eng.step()                       # short admits + first token
+    eng.step()                       # short admits; its tokens in flight
+    eng.step()                       # and read a step after their launch
     assert len(short.tokens) >= 1
     long_req = eng.submit(_prompt(48, 17), max_new_tokens=4)
     grew = []
@@ -478,9 +479,11 @@ def test_pallas_build_failure_raises(params, monkeypatch, kernel_fn):
 
 
 def test_step_batches_prefill_syncs(params):
-    """Satellite fix: a step admitting many requests resolves their
-    logits with ONE forced device_sync (plus one for the decode batch),
-    not one per admission."""
+    """A step admitting many requests reads their first ids and the
+    decode batch's ids with ONE forced device_sync, a step after it
+    launched them (and waits for nothing in the step that launches);
+    with a sampled row among them it resolves in the step itself, one
+    sync for all the admissions and one for the decode batch."""
     from nnstreamer_tpu.runtime.sync import forced_sync_count
 
     eng = LLMEngine(dict(params), n_heads=4, block_size=8,
@@ -490,11 +493,22 @@ def test_step_batches_prefill_syncs(params):
     # absorb compile-time warm syncs by pre-compiling the buckets
     eng.prewarm(16)
     n0 = forced_sync_count()
-    eng.step()                       # 4 admissions + 1 decode batch
+    eng.step()                       # 4 admissions + 1 decode launch
+    assert forced_sync_count() - n0 == 0
+    eng.step()                       # next launch, then the read
+    assert forced_sync_count() - n0 == 1
+    eng.step()                       # steady state
     assert forced_sync_count() - n0 == 2
+    eng.drain()
+    for i in range(4):
+        eng.submit(_prompt(5 + i, 20 + i), max_new_tokens=4,
+                   temperature=0.7 if i == 2 else 0.0, seed=3)
     n1 = forced_sync_count()
+    eng.step()                       # 4 admissions + 1 decode batch
+    assert forced_sync_count() - n1 == 2
+    n2 = forced_sync_count()
     eng.step()                       # steady state: decode only
-    assert forced_sync_count() - n1 == 1
+    assert forced_sync_count() - n2 == 1
     eng.drain()
 
 

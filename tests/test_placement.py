@@ -548,7 +548,8 @@ class TestPoolChips:
                         st["chips"]["counts"]["leased"] == 8:
                     break
                 time.sleep(0.05)
-            st = pool.stats()
+            # judged on the reading that ended the wait: the workers go
+            # on crashing every 0.3 s, and a later one may fall in a gap
             assert pool.chip_table.fences_total >= 4
             assert st["chips"]["counts"]["leased"] == 8
             after = {w["wid"]: tuple(w["chips"]) for w in st["workers"]}

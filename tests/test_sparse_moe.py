@@ -330,3 +330,74 @@ def test_the_engine_serves_the_family_with_chunked_prefill(params, ids,
     assert short.tokens[0] == int(np.argmax(want[0][4]))
     st = eng.stats()["executor"]
     assert st["chunk_prefills"] >= 5 and st["family"] == "sparse_moe"
+
+
+# -- the launch-ahead step (llm/engine.py) through this family ---------------
+
+def _engine(params, **kw):
+    kw.setdefault("max_batch", 2)
+    return LLMEngine(ModelBundle(fn=None, params=params, lm=SPEC),
+                     dtype=jnp.float32, block_size=8, num_blocks=40,
+                     max_len=64, prefill_chunk=8, **kw)
+
+
+def _resolved_order(monkeypatch):
+    """Every step resolves before it launches, as with a sampled row."""
+    monkeypatch.setattr(LLMEngine, "_runs_ahead",
+                        lambda self, pending: False)
+
+
+def test_eos_with_the_next_step_in_flight_in_this_family(params, ids,
+                                                         monkeypatch):
+    """A row stops on its eos_id with the next launch made: one token
+    discarded and counted, the streams those of the resolved order, and
+    the expert counts of every launch read, the discarded row's too."""
+    def serve(eos):
+        eng = _engine(params)
+        a = eng.submit(ids[:13], max_new_tokens=8, eos_id=eos)
+        b = eng.submit(ids[:5], max_new_tokens=10)
+        eng.drain()
+        return eng, list(a.tokens), list(b.tokens)
+
+    _, probe, _ = serve(None)
+    eos = probe[3]
+    stop = probe.index(eos)
+    eng, a, b = serve(eos)
+    st = eng.stats()
+    assert a == probe[:stop + 1] and len(b) == 10
+    assert st["lookahead_discarded"] == 1 and st["lookahead_steps"] > 0
+    assert st["cache"]["blocks_used"] == 0
+    ex = st["executor"]
+    assert ex["expert_steps_layers"] == 2 * ex["decode_steps"]
+    assert st["tokens_out"] == len(a) + len(b)
+    _resolved_order(monkeypatch)
+    eng, a_r, b_r = serve(eos)
+    assert (a_r, b_r) == (a, b) and eng.lookahead_steps == 0
+    # the discarded token was one more row of a launch b needed anyway
+    assert eng.stats()["executor"]["decode_steps"] == ex["decode_steps"]
+
+
+def test_a_sampled_row_in_this_family(params, ids, monkeypatch):
+    """A row with temperature > 0 beside a greedy one: its tokens are
+    those of its seed served alone, the greedy row's those of the
+    resolved order, and the steps after it run ahead again."""
+    kw = dict(max_new_tokens=4, temperature=0.7, top_k=4, seed=5)
+    alone = _engine(params)
+    want = alone.submit(ids[:13], **kw)
+    alone.drain()
+    assert alone.lookahead_steps == 0
+    eng = _engine(params)
+    g = eng.submit(ids[:5], max_new_tokens=12)
+    s = eng.submit(ids[:13], **kw)
+    while s.state != "done":
+        eng.step()
+    held = eng.lookahead_steps
+    eng.drain()
+    assert s.tokens == want.tokens and len(s.tokens) == 4
+    assert eng.lookahead_steps > held
+    assert eng.lookahead_steps < eng.stats()["executor"]["decode_steps"]
+    _resolved_order(monkeypatch)
+    ref_eng = _engine(params)
+    g_r = ref_eng.submit(ids[:5], max_new_tokens=12)
+    ref_eng.drain()
+    assert g.tokens == g_r.tokens and ref_eng.lookahead_steps == 0
