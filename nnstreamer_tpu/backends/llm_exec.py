@@ -38,6 +38,13 @@ bucket's program is first built (one shape a decode bucket; the
 prefills' one shape in all), so a bucket's first call holds theirs and
 `compile_count` stays a count of buckets.
 
+Which programs a model is served by, with which arguments, refusing what
+and counted how is one object's to say: the family's program set
+(`llm/families.py`, chosen once at construction from the bundle's
+`LMSpec.family`). This file asks it and names no family: it keeps the
+buckets, the jit cache and its keys, versions and hot swap, warm-up,
+`last_ids`, launch and resolve, spans and the `devprof` hooks.
+
 Kernel selection (``paged_kernel`` prop / ``NNS_PAGED_KERNEL`` env,
 default ``xla``): the attention inner loop is either the XLA reference
 (`llm/paged_model.py` — the bit-parity path against
@@ -65,6 +72,7 @@ import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
 from nnstreamer_tpu.core.log import get_logger
+from nnstreamer_tpu.llm.families import program_set
 from nnstreamer_tpu.llm.paged_cache import SCRATCH_BLOCK, PagedKVCache
 from nnstreamer_tpu.runtime import devprof
 from nnstreamer_tpu.runtime.sync import device_sync
@@ -86,12 +94,13 @@ _FIRST_CALL_EVENTS = {
 @dataclass
 class DecodeLaunch:
     """A decode step launched with ``sync=False``, until `resolve` reads
-    it: the rows' ids and (sparse family) expert counts, still on the
-    device, and what its `invoke` span says (None for a first call,
-    whose `compile` span is already written)."""
+    it: the rows' ids and what the program returned beside its logits
+    (llm/families.py), still on the device, and what its `invoke` span
+    says (None for a first call, whose `compile` span is already
+    written)."""
 
     ids: Any
-    counts: Any
+    beside: tuple
     rows: int
     t0: float
     span: Optional[dict]
@@ -204,12 +213,19 @@ class PagedLLMExecutor:
             raise BackendError(
                 f"tensor_llm model must be a store:// ref, a ModelBundle "
                 f"or a params dict, got {type(model).__name__}")
-        self.sparse = self._is_sparse(self.spec)
-        if self.sparse:
-            self._refuse_sparse_combinations()
+        if self.spec is not None:
             self.n_heads = int(self.spec.n_heads)
         dims = _model_dims(self.params, self.n_heads, self.spec)
         self.__dict__.update(dims)
+        bs = int(block_size)
+        self.max_blocks = max(1, -(-self.max_len // bs))
+        #: the family's programs, layouts, refusals and counters, chosen
+        #: once (llm/families.py); refuses here what it cannot serve
+        self.programs = program_set(
+            self.spec, name=name, params=self.params, dtype=self.dtype,
+            n_heads=self.n_heads, n_kv=self.n_kv, head_dim=self.head_dim,
+            block_size=bs, max_blocks=self.max_blocks, kernel=kern,
+            shards=self.shards, shard_fns=self._shard_fns)
         self._mesh = None
         self._shard_chips: tuple = ()
         self._sparams: Dict[Any, Any] = {}   # vkey → blocked+placed tree
@@ -236,13 +252,10 @@ class PagedLLMExecutor:
                 self.params, self._mesh, n_heads=self.n_heads)
             self._sparams[self._vkey()] = placed
             placer = shg.kv_pool_placer(self._mesh)
-        bs = int(block_size)
-        self.max_blocks = max(1, -(-self.max_len // bs))
         self.cache = PagedKVCache(
             num_blocks=int(num_blocks), block_size=bs,
             n_layers=self.n_layers, n_kv=self.n_kv,
-            head_dim=self.head_dim,
-            idx_dim=int(self.spec.idx_dim) if self.sparse else 0,
+            head_dim=self.head_dim, idx_dim=self.programs.idx_dim,
             placer=placer)
         #: each live sequence's last token, on the device, at the index
         #: of its table's first block (llm/next_ids.py); single-chip only
@@ -256,34 +269,9 @@ class PagedLLMExecutor:
         self.prefills = 0
         self.chunk_prefills = 0
         self.decode_steps = 0
-        # compiled decode windows (decode_multi): windows dispatched /
-        # decode steps served through a window
-        self.decode_windows = 0
-        self.window_steps = 0
-        # decode attention's extent, kept tracer on or off: context
-        # tokens the steps attended, and pool slots a layer read for
-        # them (their ratio is the live share of what was read)
-        self.kv_tokens_attended = 0
-        self.kv_slots_read = 0
-        # the sparse-expert family's extents (llm/sparse_moe.py), kept
-        # tracer on or off. Decode steps: context slots the indexer
-        # scored / slots selected and attended / indexer-pool slots a
-        # layer read (kv_slots_read then counts the selected slots'
-        # gathers); (layer, step) pairs and the distinct experts that
-        # got a token in them. Every call: (token, expert) pairs routed.
-        # Chunks: tokens at the busiest expert, summed over the chunks
-        # whose counts have been read back (expert_load_chunks).
-        self.kv_tokens_scored = 0
-        self.kv_tokens_selected = 0
-        self.idx_slots_read = 0
-        self.expert_tokens = 0
-        self.expert_steps_layers = 0
-        self.experts_touched_sum = 0
-        self.expert_load_max_sum = 0
-        self.expert_load_chunks = 0
-        #: chunks launched with sync=False whose expert counts are still
-        #: on the device: (req, pos0, clen, counts)
-        self._chunk_counts: List[tuple] = []
+        #: chunks launched with sync=False whose values beside the
+        #: logits are still on the device: (req, pos0, clen, values)
+        self._chunk_beside: List[tuple] = []
         # first-call anatomy: a list from a jit miss (_get_jit) to its
         # `compile` span, holding jax's own duration events in between
         # as (label, t0, t1). Listened to only by a traced executor.
@@ -310,132 +298,6 @@ class PagedLLMExecutor:
             return ("v", version if version is not None
                     else self._version)
         return ("g", 0)
-
-    # -- the sparse-expert family (llm/sparse_moe.py) ----------------------
-    #: the longest prompt the one-chunk whole-prompt prefill takes: past
-    #: it a chunk's (heads, C, tile) temporaries outgrow what the pool
-    #: leaves free, and the engine has to chunk (prefill_chunk)
-    SPARSE_WHOLE_PROMPT_MAX = 4096
-
-    @staticmethod
-    def _is_sparse(spec) -> bool:
-        from nnstreamer_tpu.llm.spec import SPARSE_MOE
-
-        return spec is not None and spec.family == SPARSE_MOE
-
-    def _refuse_sparse_combinations(self) -> None:
-        """What the sparse-expert family cannot yet be combined with,
-        refused typed at construction (ROADMAP C2)."""
-        why = None
-        if self.shards > 0:
-            why = (f"shards={self.shards}: its experts and indexer pool "
-                   f"have no sharding rule yet (ROADMAP B2)")
-        elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: it has no Pallas twin yet "
-                   "(ROADMAP B2); set paged_kernel=xla")
-        elif any(k.endswith("_scale") for k in self.params["blocks"][0]):
-            why = ("a W8A8 store version: its grouped expert products "
-                   "are float only")
-        if why is not None:
-            raise BackendError(
-                f"llm {self.name}: the sparse_moe family cannot be served "
-                f"with {why}")
-
-    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
-        """Refuse at submission a prompt this executor can never
-        prefill: the sparse family prefills through its chunk program
-        only, and one chunk holds at most SPARSE_WHOLE_PROMPT_MAX."""
-        if self.sparse and plen > self.SPARSE_WHOLE_PROMPT_MAX and not (
-                0 < prefill_chunk < plen):
-            raise BackendError(
-                f"llm {self.name}: a prompt of {plen} tokens needs "
-                f"chunked prefill in the sparse_moe family (one chunk "
-                f"holds at most {self.SPARSE_WHOLE_PROMPT_MAX}); set "
-                f"prefill_chunk (it is {prefill_chunk})")
-
-    def _kw(self) -> dict:
-        """The static arguments of this family's jits."""
-        if self.sparse:
-            return {"spec": self.spec, "dtype": self.dtype}
-        return {"n_heads": self.n_heads, "dtype": self.dtype}
-
-    def _chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab,
-                    last) -> tuple:
-        return (params, ids, pos0, blk_idx, blk_off, tab,
-                *self.cache.pools(), last)
-
-    def _decode_args(self, params, cur, tab, pos, n: int) -> tuple:
-        if self.sparse:
-            return (params, cur, tab, pos, np.int32(n), *self.cache.pools())
-        return (params, cur, tab, pos, *self.cache.pools())
-
-    def _chunk_kw(self, pos0: int, bucket: int) -> dict:
-        """Static arguments of a chunk call. The sparse family writes
-        whole blocks at once where the chunk lies on them: every chunk
-        of a prompt does when block_size divides prefill_chunk, so the
-        bucket stays one program."""
-        kw = self._kw()
-        if self.sparse:
-            bs = self.cache.block_size
-            kw["by_block"] = int(pos0) % bs == 0 and bucket % bs == 0
-        return kw
-
-    def _take(self, out: tuple):
-        """Split a jit's result into (logits, expert counts or None) and
-        keep the pools it returns."""
-        logits, *rest = out
-        counts = rest.pop(0) if self.sparse else None
-        self.cache.set_pools(rest)
-        return logits, counts
-
-    def _resolve_counts(self, logits, counts, sync: bool, kind: str,
-                        bucket: int, t_in: float, t0: float):
-        """`_resolve` for a call that may carry expert counts: with
-        `sync` they ride the logits' read-back, and the chunks launched
-        before the call are done too. Returns (result, host counts or
-        None, t1)."""
-        if not sync or counts is None:
-            out, t1 = self._resolve(logits, sync, kind, bucket, t_in, t0)
-            return out, None, t1
-        (out, counts), t1 = self._resolve((logits, counts), sync, kind,
-                                          bucket, t_in, t0)
-        self._drain_chunk_counts(wait=True)
-        return out, counts, t1
-
-    def _note_experts(self, counts: np.ndarray, decode: bool) -> tuple:
-        """Account one call's (layers, experts) token counts; returns
-        (distinct experts with a token, summed over layers; tokens at
-        the busiest expert, largest over layers)."""
-        touched = int((counts > 0).sum())
-        load_max = int(counts.max())
-        self.expert_tokens += int(counts.sum())
-        if decode:
-            self.expert_steps_layers += counts.shape[0]
-            self.experts_touched_sum += touched
-        else:
-            self.expert_load_max_sum += load_max
-            self.expert_load_chunks += 1
-        return touched, load_max
-
-    def _drain_chunk_counts(self, wait: bool = False) -> None:
-        """Read back the expert counts of chunks launched with
-        sync=False, once the device has them (all of them after a
-        sync: the device runs in order), and put them on a `resolve`
-        span under the chunk's own `req`, `pos0` and `clen`."""
-        while self._chunk_counts:
-            req, pos0, clen, dev = self._chunk_counts[0]
-            if not (wait or dev.is_ready()):
-                return
-            self._chunk_counts.pop(0)
-            t0 = time.perf_counter()
-            counts = np.asarray(dev)  # nnlint: disable=NNL002 ready, or behind the caller's device_sync
-            touched, load_max = self._note_experts(counts, decode=False)
-            if self.tracer.active:
-                self.tracer.span(
-                    "backend", self.name, "resolve", t0,
-                    time.perf_counter(), what="llm_prefill_chunk",
-                    req=req, pos0=pos0, clen=clen,
-                    experts_touched=touched, expert_load_max=load_max)
 
     # -- sharded serving (serving/sharding.py) -----------------------------
     def _shard_fns(self):
@@ -532,95 +394,20 @@ class PagedLLMExecutor:
             self._entry.note_bucket(self._version, bucket_key)
 
     # -- jit cache ---------------------------------------------------------
-    def _kind_kernel(self, kind: str) -> str:
-        """Which attention kernel serves `kind`. The full-sequence
-        prefill is always the XLA `apply_seq_kv` path (it is the bit-
-        parity anchor against `transformer.generate`); chunk and decode
-        follow the selected kernel."""
-        return "xla" if kind == "prefill" else self.paged_kernel
-
-    def _prefill_kind(self) -> str:
-        """Whole-prompt prefills route through the chunk family (one
-        chunk covering the prompt) when the selected kernel is Pallas or
-        the bound params are W8A8-quantized — `apply_seq_kv` is float-
-        only and kernel-fixed; the chunk path is quant-aware and
-        kernel-selectable. Float + xla keeps the original path, so the
-        token-for-token `generate` parity contract is untouched there."""
-        if self.shards:
-            # sharded init already refused pallas and quantized params;
-            # the ring cutover is decided per prompt in prefill()
-            return "prefill"
-        if self.paged_kernel == "pallas" or self.sparse:
-            # the sparse-expert family has one prefill program: its chunk
-            return "chunk"
-        try:
-            if "wqkv_scale" in self.params["blocks"][0]:
-                return "chunk"
-        except (KeyError, IndexError, TypeError):
-            pass
-        return "prefill"
-
     def _get_jit(self, kind: str, bucket: int, version=None):
         import jax
 
-        from nnstreamer_tpu.llm.paged_model import (
-            paged_decode_step, paged_prefill, paged_prefill_chunk)
-
-        kernel = self._kind_kernel(kind)
-        key = (self._ns(version), kind, bucket, kernel)
+        key = (self._ns(version), kind, bucket, self.programs.kernel(kind))
         jitted = self._jits.get(key)
         if jitted is not None:
             self.cache_hits += 1
             return jitted, False
         self.cache_misses += 1
+        prog = self.programs.program(kind)
         self._warm_ids(kind, bucket)
         self._first_call = []
-        if self.shards:
-            if kind == "chunk":
-                raise BackendError(
-                    f"llm {self.name}: chunked prefill is not supported "
-                    f"with shards={self.shards}; long prompts go through "
-                    f"the sequence-parallel ring prefill "
-                    f"(ring_prefill_min)")
-            # one SPMD executable per bucket under ("tp", N, version) —
-            # same donate/static discipline as the single-chip jits
-            jitted = jax.jit(self._shard_fns()[kind],
-                             static_argnames=("n_heads", "dtype"),
-                             donate_argnums=(4, 5))
-            self._jits[key] = jitted
-            return jitted, True
-        if self.sparse:
-            from nnstreamer_tpu.llm.sparse_moe import (
-                sparse_moe_decode_step, sparse_moe_prefill_chunk)
-
-            fn, donate = (sparse_moe_prefill_chunk, (6, 7, 8)) \
-                if kind == "chunk" else (sparse_moe_decode_step, (5, 6, 7))
-            static = ("spec", "dtype", "by_block") if kind == "chunk" \
-                else ("spec", "dtype")
-            jitted = jax.jit(fn, static_argnames=static,
-                             donate_argnums=donate)
-            self._jits[key] = jitted
-            return jitted, True
-        if kind == "prefill":
-            fn, donate = paged_prefill, (4, 5)
-        elif kind == "chunk":
-            if kernel == "pallas":
-                from nnstreamer_tpu.backends.pallas_paged import (
-                    paged_flash_prefill_chunk)
-                fn = paged_flash_prefill_chunk
-            else:
-                fn = paged_prefill_chunk
-            donate = (6, 7)
-        else:
-            if kernel == "pallas":
-                from nnstreamer_tpu.backends.pallas_paged import (
-                    paged_flash_decode_step)
-                fn = paged_flash_decode_step
-            else:
-                fn = paged_decode_step
-            donate = (4, 5)
-        jitted = jax.jit(fn, static_argnames=("n_heads", "dtype"),
-                         donate_argnums=donate)
+        jitted = jax.jit(prog.fn, static_argnames=prog.static,
+                         donate_argnums=prog.donate)
         self._jits[key] = jitted
         return jitted, True
 
@@ -654,7 +441,7 @@ class PagedLLMExecutor:
         or the program faulted, and the caller asked for that kernel —
         raise it typed, naming the kind, never serve another kernel in
         its place."""
-        if self._kind_kernel(kind) != "pallas":
+        if self.programs.kernel(kind) != "pallas":
             return run()
         try:
             return run()
@@ -689,30 +476,29 @@ class PagedLLMExecutor:
                 first[label] = t0
                 self.tracer.span("backend", self.name, label, t0, t1)
 
-    def _resolve(self, dev, sync: bool, kind: str, bucket: int,
-                 t_in: float, t0: float):
+    def _resolve(self, dev, beside: tuple, sync: bool, kind: str,
+                 bucket: int, t_in: float, t0: float):
         """The end of a call whose jit has just returned: with `sync`,
-        wait for `dev` and read it back. An active tracer gets the
-        call's children in order, disjoint: `prep` [t_in, t0) (building
-        the host arrays), `dispatch` (t0 to the jit's return), `wait`
-        (the device_sync alone) and `readback` (the np.asarray) — the
-        last three divide the enclosing invoke/compile span [t0, t1)
-        into launch, device wait and D2H. Returns (result, t1)."""
+        wait for `dev` and for what the program returned `beside` it,
+        and read them back (the chunks launched before the call are
+        done too). An active tracer gets the call's children in order,
+        disjoint: `prep` [t_in, t0) (building the host arrays),
+        `dispatch` (t0 to the jit's return), `wait` (the device_sync
+        alone) and `readback` (the np.asarray) — the last three divide
+        the enclosing invoke/compile span [t0, t1) into launch, device
+        wait and D2H. Returns (result, host `beside` or None, t1)."""
         tr = self.tracer
         on = tr.active
         t_d = t_w = time.perf_counter() if on else 0.0
-        out = dev
+        out, host = dev, None
         if sync:
-            device_sync(dev, tracer=tr, name=f"{self.name}:{kind}")
+            device_sync((dev, *beside), tracer=tr,
+                        name=f"{self.name}:{kind}")
             if on:
                 t_w = time.perf_counter()
-            if isinstance(dev, tuple):
-                # logits and what rides their read-back (expert counts)
-                out = tuple(np.asarray(d) for d in dev)  # nnlint: disable=NNL002 synced by the device_sync above
-                nbytes = sum(int(o.nbytes) for o in out)
-            else:
-                out = np.asarray(dev)  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
-                nbytes = int(out.nbytes)
+            out = np.asarray(dev)  # nnlint: disable=NNL002 synced by the device_sync above; timed apart from it as readback
+            host = [np.asarray(d) for d in beside]  # nnlint: disable=NNL002 synced by the device_sync above
+            nbytes = int(out.nbytes) + sum(int(h.nbytes) for h in host)
         t1 = time.perf_counter()
         if on:
             what = f"llm_{kind}"
@@ -723,7 +509,29 @@ class PagedLLMExecutor:
                 tr.span("backend", self.name, "wait", t_d, t_w, what=what)
                 tr.span("backend", self.name, "readback", t_w, t1,
                         what=what, bytes=nbytes)
-        return out, t1
+        if host:
+            self._drain_chunks(wait=True)
+        return out, host, t1
+
+    def _drain_chunks(self, wait: bool = False) -> None:
+        """Read back what chunks launched with sync=False returned
+        beside their logits, once the device has it (all of it after a
+        sync: the device runs in order), hand it to the family's
+        accounting and put what that says on a `resolve` span under the
+        chunk's own `req`, `pos0` and `clen`."""
+        while self._chunk_beside:
+            req, pos0, clen, dev = self._chunk_beside[0]
+            if not (wait or all(d.is_ready() for d in dev)):
+                return
+            self._chunk_beside.pop(0)
+            t0 = time.perf_counter()
+            host = [np.asarray(d) for d in dev]  # nnlint: disable=NNL002 ready, or behind the caller's device_sync
+            said = self.programs.note_beside("chunk", host)
+            if self.tracer.active:
+                self.tracer.span(
+                    "backend", self.name, "resolve", t0,
+                    time.perf_counter(), what="llm_prefill_chunk",
+                    req=req, pos0=pos0, clen=clen, **said)
 
     # -- device performance plane (runtime/devprof.py) ---------------------
     def resident_bytes(self) -> int:
@@ -761,18 +569,19 @@ class PagedLLMExecutor:
                 *, sync: bool = True, req: Optional[str] = None):
         """One whole prompt; its KV lands in the pool blocks of
         `block_table`. Dispatches between the full-sequence
-        `apply_seq_kv` path and the chunk family (`_prefill_kind` —
-        pallas / quantized stores go through the chunk path, as one
-        chunk covering the prompt). Returns last-token logits: a host
-        (vocab,) f32 array when `sync`, else the device array so the
-        engine can batch one `device_sync` over a whole step's
-        admissions. `req` only labels the call's `invoke` span."""
+        `apply_seq_kv` path and the chunk family (the program set's
+        `prefill_kind` — pallas / quantized stores go through the chunk
+        path, as one chunk covering the prompt). Returns last-token
+        logits: a host (vocab,) f32 array when `sync`, else the device
+        array so the engine can batch one `device_sync` over a whole
+        step's admissions. `req` only labels the call's `invoke` span."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
         plen = int(prompt.shape[0])
-        if self._prefill_kind() == "chunk":
-            self.check_prompt(plen, 0)
+        ps = self.programs
+        if ps.prefill_kind(self.params) == "chunk":
+            ps.check_prompt(plen, 0)
             return self.prefill_chunk(
                 prompt, 0, block_table,
                 bucket=_next_pow2(plen, 8), sync=sync, req=req)
@@ -788,16 +597,17 @@ class PagedLLMExecutor:
         blk_idx[:plen] = np.asarray(block_table, np.int32)[pos // bs]
         blk_off = (np.arange(s_b) % bs).astype(np.int32)
         jitted, fresh = self._get_jit(kind, s_b)
-        sp = self._exec_params(kind)
+        args = (self._exec_params(kind), ids, blk_idx, blk_off,
+                np.int32(plen - 1))
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"{kind}:{s_b}")
         t0 = time.perf_counter()
-        logits, self.cache.k, self.cache.v = jitted(
-            sp, ids, blk_idx, blk_off, self.cache.k,
-            self.cache.v, np.int32(plen - 1), n_heads=self.n_heads,
-            dtype=self.dtype)
-        out, t1 = self._resolve(logits, sync, "prefill", s_b, t_in, t0)
+        logits, _, pools = ps.split(jitted(
+            *ps.prefill_args(*args, self.cache.pools()), **ps.kw))
+        self.cache.set_pools(pools)
+        out, _, t1 = self._resolve(logits, (), sync, "prefill", s_b, t_in,
+                                   t0)
         kernel = "ring" if kind == "ring" else "xla"
         if fresh:
             self.compile_count += 1
@@ -807,9 +617,7 @@ class PagedLLMExecutor:
                 ("llmr" if kind == "ring" else "llmp", s_b))
             self._prof_capture(
                 f"{kind}:{s_b}", jitted,
-                (sp, ids, blk_idx, blk_off, self.cache.k,
-                 self.cache.v, np.int32(plen - 1)),
-                {"n_heads": self.n_heads, "dtype": self.dtype}, t1 - t0)
+                ps.prefill_args(*args, self.cache.pools()), ps.kw, t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill", bucket=s_b,
                        plen=plen, kernel=kernel, req=req)
@@ -829,11 +637,6 @@ class PagedLLMExecutor:
         the final chunk's value is meaningful to sampling."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
-        if self.shards:
-            raise BackendError(
-                f"llm {self.name}: chunked prefill is not supported with "
-                f"shards={self.shards}; long prompts go through the "
-                f"sequence-parallel ring prefill (ring_prefill_min)")
         t_in = time.perf_counter() if self.tracer.active else 0.0
         clen = int(chunk.shape[0])
         c_b = max(int(bucket) or 0, _next_pow2(clen, 8))
@@ -846,37 +649,37 @@ class PagedLLMExecutor:
         blk_off = ((int(pos0) + np.arange(c_b)) % bs).astype(np.int32)
         tab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
         tab[:len(block_table)] = block_table
-        args = (ids, np.int32(pos0), blk_idx, blk_off, tab,
+        args = (self.params, ids, np.int32(pos0), blk_idx, blk_off, tab,
                 np.int32(clen - 1))
-
-        kw = self._chunk_kw(pos0, c_b)
+        ps = self.programs
+        kw = ps.chunk_kw(pos0, c_b)
 
         def _run():
             jitted, fresh = self._get_jit("chunk", c_b)
-            logits, counts = self._take(jitted(
-                *self._chunk_args(self.params, *args), **kw))
-            return logits, counts, fresh
+            logits, beside, pools = ps.split(jitted(
+                *ps.chunk_args(*args, self.cache.pools()), **kw))
+            self.cache.set_pools(pools)
+            return logits, beside, fresh
 
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"chunk:{c_b}")
         t0 = time.perf_counter()
-        logits, counts, fresh = self._run_kernel("chunk", _run)
-        kernel = self._kind_kernel("chunk")
-        out, host_counts, t1 = self._resolve_counts(
-            logits, counts, sync, "prefill_chunk", c_b, t_in, t0)
+        logits, beside, fresh = self._run_kernel("chunk", _run)
+        kernel = ps.kernel("chunk")
+        out, host, t1 = self._resolve(
+            logits, beside, sync, "prefill_chunk", c_b, t_in, t0)
         extra = {}
-        if self.sparse:
-            # this family's chunk span also says where the chunk starts
-            # and, once its counts are on the host, how its tokens
-            # spread over the experts
+        if beside:
+            # the span also says where the chunk starts and, once what
+            # came beside the logits is on the host, what the family
+            # reads from it; until then a `resolve` span will
             extra["pos0"] = int(pos0)
-            if host_counts is not None:
-                extra["experts_touched"], extra["expert_load_max"] = \
-                    self._note_experts(host_counts, decode=False)
+            if host:
+                extra.update(ps.note_beside("chunk", host))
             else:
-                self._drain_chunk_counts()
-                self._chunk_counts.append((req, int(pos0), clen, counts))
+                self._drain_chunks()
+                self._chunk_beside.append((req, int(pos0), clen, beside))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_prefill_chunk",
@@ -885,7 +688,7 @@ class PagedLLMExecutor:
             jitted, _ = self._get_jit("chunk", c_b)
             self._prof_capture(
                 f"chunk:{c_b}", jitted,
-                self._chunk_args(self.params, *args), kw, t1 - t0)
+                ps.chunk_args(*args, self.cache.pools()), kw, t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill_chunk",
                        bucket=c_b, clen=clen, kernel=kernel, req=req,
@@ -927,6 +730,7 @@ class PagedLLMExecutor:
             tab_a[i, :len(t)] = t
         pos_a = np.zeros((b_b,), np.int32)
         pos_a[:n] = pos
+        ps = self.programs
 
         def _run():
             jitted, fresh = self._get_jit("decode", b_b)
@@ -938,39 +742,33 @@ class PagedLLMExecutor:
                 # argument, one executable a bucket
                 tab_d = jax.device_put(tab_a)
                 cur_d = next_ids.llm_last_ids(self.last_ids, tab_d, cur_a)
-            logits, counts = self._take(jitted(*self._decode_args(
-                self._exec_params("decode"), cur_d, tab_d, pos_a, n),
-                **self._kw()))
+            logits, beside, pools = ps.split(jitted(*ps.decode_args(
+                self._exec_params("decode"), cur_d, tab_d, pos_a, n,
+                self.cache.pools()), **ps.kw))
+            self.cache.set_pools(pools)
             if not sync:
                 ids, self.last_ids = next_ids.llm_pick_rows(
                     logits, self.last_ids, tab_d)
                 # on their way while the next step is prepared
-                for dev in (ids,) if counts is None else (ids, counts):
+                for dev in (ids, *beside):
                     dev.copy_to_host_async()
-            return logits, counts, ids, fresh
+            return logits, beside, ids, fresh
 
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"decode:{b_b}")
         t0 = time.perf_counter()
-        logits, counts, ids, fresh = self._run_kernel("decode", _run)
-        kernel = self._kind_kernel("decode")
-        out, host_counts, t1 = self._resolve_counts(
-            logits, counts, sync, "decode", b_b, t_in, t0)
-        extra = {}
-        if host_counts is not None:
-            extra["experts_touched"], _ = self._note_experts(
-                host_counts, decode=True)
-        # kv_tokens: the context this step attends (sparse family:
-        # scores), its own tokens included; kv_slots: the pool slots a
-        # layer read for it (sparse family: the selected slots' gathers)
-        if self.sparse:
-            kv_tokens, kv_slots, extra["kv_selected"], \
-                extra["idx_slots"] = self._note_kv_sparse(pos_a, n)
-        else:
-            kv_tokens, kv_slots = self._note_kv(pos_a, n)
+        logits, beside, ids, fresh = self._run_kernel("decode", _run)
+        kernel = ps.kernel("decode")
+        out, host, t1 = self._resolve(
+            logits, beside, sync, "decode", b_b, t_in, t0)
+        # the family's count of what the step attended and read
+        # (kv_tokens, kv_slots, ...) and, where it is on the host, of
+        # what the step returned beside its logits
         span = dict(what="llm_decode", bucket=b_b, rows=n, kernel=kernel,
-                    kv_tokens=kv_tokens, kv_slots=kv_slots, **extra)
+                    **ps.note_decode(pos_a, n))
+        if host:
+            span.update(ps.note_beside("decode", host))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -978,16 +776,16 @@ class PagedLLMExecutor:
             self._note_bucket(("llmd", b_b))
             jitted, _ = self._get_jit("decode", b_b)
             self._prof_capture(
-                f"decode:{b_b}", jitted, self._decode_args(
-                    self._exec_params("decode"), cur_a, tab_a, pos_a, n),
-                self._kw(), t1 - t0)
+                f"decode:{b_b}", jitted, ps.decode_args(
+                    self._exec_params("decode"), cur_a, tab_a, pos_a, n,
+                    self.cache.pools()), ps.kw, t1 - t0)
         elif sync:
             self._span("invoke", t0, t1, **span)
         self.decode_steps += 1
         self.kernel_invokes[kernel] += 1
         if sync:
             return out[:n]
-        return DecodeLaunch(ids, counts, n, t0, None if fresh else span)
+        return DecodeLaunch(ids, beside, n, t0, None if fresh else span)
 
     def pick_first(self, logits, block_table: List[int]):
         """The greedy first token of a prefill launched with sync=False,
@@ -1012,15 +810,14 @@ class PagedLLMExecutor:
         `device_sync`, one `wait` and one `readback` span. Returns (the
         launch's ids (rows,) int32 or None, the first ids). The launch's
         `invoke` span is written here, from the launch to the end of the
-        wait, with what only the read-back tells (the sparse family's
-        `experts_touched`)."""
+        wait, with what only the read-back tells: the family's reading
+        of what the step returned beside its logits."""
         tr = self.tracer
         on = tr.active
         dev = list(firsts)
         if launch is not None:
             dev.append(launch.ids)
-            if launch.counts is not None:
-                dev.append(launch.counts)
+            dev.extend(launch.beside)
         t_w0 = time.perf_counter() if on else 0.0
         device_sync(dev, tracer=tr, name=f"{self.name}:decode")
         t_w = time.perf_counter() if on else 0.0
@@ -1031,173 +828,20 @@ class PagedLLMExecutor:
             tr.span("backend", self.name, "readback", t_w,
                     time.perf_counter(), what="llm_decode",
                     bytes=sum(int(h.nbytes) for h in host))
-        first_ids = [int(h) for h in host[:len(firsts)]]
+        nf = len(firsts)
+        first_ids = [int(h) for h in host[:nf]]
         if launch is None:
             return None, first_ids
         span = launch.span
-        if launch.counts is not None:
-            touched, _ = self._note_experts(host[-1], decode=True)
+        if launch.beside:
+            said = self.programs.note_beside("decode", host[nf + 1:])
             # the chunks launched before it are done too
-            self._drain_chunk_counts()
+            self._drain_chunks()
             if span is not None:
-                span["experts_touched"] = touched
+                span.update(said)
         if span is not None:
             self._span("invoke", launch.t0, t_w, **span)
-        return host[len(firsts)][:launch.rows], first_ids
-
-    def _note_kv(self, pos_a: np.ndarray, n: int, steps: int = 1) -> tuple:
-        """Count what `steps` decode steps from the bucket's positions
-        `pos_a` (`n` live rows first) attend and read: (kv_tokens, the
-        live rows' context with the steps' own tokens; kv_slots, the
-        pool slots one layer gathers, padding rows and the walk's
-        rounding included). Only the XLA single-chip step walks live
-        blocks; the Pallas grid and the sharded step cover every table
-        entry."""
-        from nnstreamer_tpu.llm.paged_model import walk_slots
-
-        walks = not self.shards and self.paged_kernel == "xla"
-        bs = self.cache.block_size
-        tokens = slots = 0
-        for s in range(steps):
-            tokens += int(pos_a[:n].sum()) + n * (s + 1)
-            slots += walk_slots(pos_a + s, bs, self.n_kv, self.head_dim,
-                                self.max_blocks) if walks \
-                else len(pos_a) * self.max_blocks * bs
-        self.kv_tokens_attended += tokens
-        self.kv_slots_read += slots
-        return tokens, slots
-
-    def _note_kv_sparse(self, pos_a: np.ndarray, n: int) -> tuple:
-        """One decode step of the sparse-expert family, counted: the
-        indexer scores each live row's context (kv_tokens_scored)
-        reading the bucket's whole tables of the indexer pool
-        (idx_slots_read); the step attends min(topk, pos + 1) slots a
-        row (kv_tokens_selected, also kv_tokens_attended) and gathers
-        `topk` slots of K and V for every row of the bucket
-        (kv_slots_read). Returns the span's (kv_tokens, kv_slots,
-        kv_selected, idx_slots)."""
-        s_max = self.max_blocks * self.cache.block_size
-        k = min(int(self.spec.topk), s_max)
-        scored = int(pos_a[:n].sum()) + n
-        selected = int(np.minimum(k, pos_a[:n] + 1).sum())
-        slots, idx_slots = len(pos_a) * k, len(pos_a) * s_max
-        self.kv_tokens_scored += scored
-        self.kv_tokens_selected += selected
-        self.kv_tokens_attended += selected
-        self.idx_slots_read += idx_slots
-        self.kv_slots_read += slots
-        return scored, slots, selected, idx_slots
-
-    def _get_multi_jit(self, bucket: int, steps: int, version=None):
-        """Jitted K-step greedy decode window: ``jax.lax.scan`` whose
-        body is exactly the per-step decode kernel plus an on-device
-        ``jnp.argmax`` feeding the next step. One cache entry per
-        (bucket, steps) pair — the engine rounds `steps` down to a
-        power of two so the cache stays O(log K) per bucket."""
-        import jax
-        import jax.numpy as jnp
-
-        kernel = self._kind_kernel("decode")
-        key = (self._ns(version), "decmulti", bucket, steps, kernel)
-        jitted = self._jits.get(key)
-        if jitted is not None:
-            self.cache_hits += 1
-            return jitted, False
-        self.cache_misses += 1
-        self._first_call = []
-        if kernel == "pallas":
-            from nnstreamer_tpu.backends.pallas_paged import (
-                paged_flash_decode_step)
-            step_fn = paged_flash_decode_step
-        else:
-            from nnstreamer_tpu.llm.paged_model import paged_decode_step
-            step_fn = paged_decode_step
-
-        def multi(params, cur, tab, pos, kc, vc, *, n_heads, dtype):
-            def body(carry, _):
-                cur_, pos_, kc_, vc_ = carry
-                logits, kc2, vc2 = step_fn(
-                    params, cur_, tab, pos_, kc_, vc_,
-                    n_heads=n_heads, dtype=dtype)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (nxt, pos_ + 1, kc2, vc2), nxt
-            (_, _, kc_f, vc_f), toks = jax.lax.scan(
-                body, (cur, pos, kc, vc), None, length=steps)
-            return toks, kc_f, vc_f
-
-        jitted = jax.jit(multi, static_argnames=("n_heads", "dtype"),
-                         donate_argnums=(4, 5))
-        self._jits[key] = jitted
-        return jitted, True
-
-    def decode_multi(self, cur: List[int], tables: List[List[int]],
-                     pos: List[int], steps: int) -> np.ndarray:
-        """`steps` greedy decode steps for `len(cur)` live rows as ONE
-        compiled dispatch (the engine's `decode_window` fast path): the
-        sampled token feeds the next step on-device, so the host pays
-        one Python dispatch and one sync per window instead of one per
-        token. Returns a host (n, steps) int32 token matrix.
-
-        The caller guarantees the window invariants (llm/engine.py
-        `_window_len`): every row is greedy (temperature<=0, matching
-        the host argmax tie-breaking bit for bit), `steps` never
-        exceeds any row's remaining token budget (block tables are
-        fully pre-allocated at admission, so position pos+steps-1 is
-        always backed), and rows that hit EOS mid-window have their
-        trailing tokens discarded host-side — the extra KV writes land
-        in blocks the row still owned when the window ran."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
-
-        if self.sparse:
-            raise BackendError(
-                f"llm {self.name}: the sparse_moe family has no compiled "
-                f"decode window; set decode_window=0")
-        t_in = time.perf_counter() if self.tracer.active else 0.0
-        n = len(cur)
-        steps = int(steps)
-        b_b = _next_pow2(n, 1)
-        cur_a = np.zeros((b_b,), np.int32)
-        cur_a[:n] = cur
-        tab_a = np.full((b_b, self.max_blocks), SCRATCH_BLOCK, np.int32)
-        for i, t in enumerate(tables):
-            tab_a[i, :len(t)] = t
-        pos_a = np.zeros((b_b,), np.int32)
-        pos_a[:n] = pos
-
-        def _run():
-            jitted, fresh = self._get_multi_jit(b_b, steps)
-            toks, self.cache.k, self.cache.v = jitted(
-                self._exec_params("decode"), cur_a, tab_a, pos_a,
-                self.cache.k, self.cache.v, n_heads=self.n_heads,
-                dtype=self.dtype)
-            return toks, fresh
-
-        prof = devprof.get()
-        if prof.enabled:
-            prof.note_dispatch(self.name, f"decmulti:{b_b}x{steps}")
-        t0 = time.perf_counter()
-        toks, fresh = self._run_kernel("decode", _run)
-        kernel = self._kind_kernel("decode")
-        out, t1 = self._resolve(toks, True, "decode_multi", b_b,
-                                t_in, t0)
-        out = out[:, :n].T
-        kv_tokens, kv_slots = self._note_kv(pos_a, n, steps)
-        if fresh:
-            self.compile_count += 1
-            self._span("compile", t0, t1, what="llm_decode_multi",
-                       bucket=b_b, steps=steps, kernel=kernel)
-            self._note_bucket(("llmw", b_b, steps))
-        else:
-            self._span("invoke", t0, t1, what="llm_decode_multi",
-                       bucket=b_b, steps=steps, rows=n, kernel=kernel,
-                       kv_tokens=kv_tokens, kv_slots=kv_slots)
-        # the ledger counts the same decode steps whether or not the
-        # window path served them — parity with per-step mode
-        self.decode_steps += steps
-        self.kernel_invokes[kernel] += steps
-        self.decode_windows += 1
-        self.window_steps += steps
-        return out
+        return host[nf][:launch.rows], first_ids
 
     # -- warm paths --------------------------------------------------------
     def _warm_compile(self, kind: str, bucket: int, version=None,
@@ -1209,7 +853,8 @@ class PagedLLMExecutor:
         populates the jit's dispatch cache, so the first *served*
         request is a cache hit, not a second compile. Returns whether a
         fresh executable was built."""
-        key = (self._ns(version), kind, bucket, self._kind_kernel(kind))
+        ps = self.programs
+        key = (self._ns(version), kind, bucket, ps.kernel(kind))
         if key in self._jits:
             return False
         jitted, _ = self._get_jit(kind, bucket, version)
@@ -1222,29 +867,10 @@ class PagedLLMExecutor:
         prof = devprof.get()
         if prof.enabled:
             prof.note_dispatch(self.name, f"{kind}:{bucket}")
-        kw = self._kw()
+        kw = ps.kw
+        pools = self.cache.pools
         t0 = time.perf_counter()
-        if kind in ("prefill", "ring"):
-            ids = np.zeros((1, bucket), np.int32)
-            blk = np.full((bucket,), SCRATCH_BLOCK, np.int32)
-            off = (np.arange(bucket)
-                   % self.cache.block_size).astype(np.int32)
-            logits, self.cache.k, self.cache.v = jitted(
-                params, ids, blk, off, self.cache.k, self.cache.v,
-                np.int32(0), n_heads=self.n_heads, dtype=self.dtype)
-            largs = (params, ids, blk, off, self.cache.k, self.cache.v,
-                     np.int32(0))
-        elif kind == "chunk":
-            ids = np.zeros((1, bucket), np.int32)
-            blk = np.full((bucket,), SCRATCH_BLOCK, np.int32)
-            off = (np.arange(bucket)
-                   % self.cache.block_size).astype(np.int32)
-            tab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
-            cargs = (params, ids, np.int32(0), blk, off, tab, np.int32(0))
-            kw = self._chunk_kw(0, bucket)
-            logits, _ = self._take(jitted(*self._chunk_args(*cargs), **kw))
-            largs = self._chunk_args(*cargs)
-        else:
+        if kind == "decode":
             cur = pos = np.zeros((bucket,), np.int32)
             tab = np.full((bucket, self.max_blocks), SCRATCH_BLOCK,
                           np.int32)
@@ -1256,17 +882,36 @@ class PagedLLMExecutor:
 
                 tab = jax.device_put(tab)
                 cur = next_ids.llm_last_ids(self.last_ids, tab, cur)
-            # no live row: a sparse step's padding rows reach no expert
-            logits, _ = self._take(jitted(
-                *self._decode_args(params, cur, tab, pos, 0), **kw))
-            largs = self._decode_args(params, cur, tab, pos, 0)
+
+            def layout():       # no live row
+                return ps.decode_args(params, cur, tab, pos, 0, pools())
+        else:
+            ids = np.zeros((1, bucket), np.int32)
+            blk = np.full((bucket,), SCRATCH_BLOCK, np.int32)
+            off = (np.arange(bucket)
+                   % self.cache.block_size).astype(np.int32)
+            zero = np.int32(0)
+            if kind == "chunk":
+                kw = ps.chunk_kw(0, bucket)
+                tab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
+
+                def layout():
+                    return ps.chunk_args(params, ids, zero, blk, off, tab,
+                                         zero, pools())
+            else:
+                def layout():
+                    return ps.prefill_args(params, ids, blk, off, zero,
+                                           pools())
+        logits, _, kept = ps.split(jitted(*layout(), **kw))
+        self.cache.set_pools(kept)
         device_sync(logits, tracer=self.tracer,
                     name=f"{self.name}:warm_{kind}")
         self.compile_count += 1
         t1 = time.perf_counter()
         self._span("compile", t0, t1, what=f"llm_{kind}_warm",
                    bucket=bucket)
-        self._prof_capture(f"{kind}:{bucket}", jitted, largs, kw, t1 - t0)
+        self._prof_capture(f"{kind}:{bucket}", jitted, layout(), kw,
+                           t1 - t0)
         return True
 
     def prewarm_buckets(self, *, max_batch: int, max_prompt: int,
@@ -1286,7 +931,7 @@ class PagedLLMExecutor:
         if chunk > 0:
             compiled += int(self._warm_compile(
                 "chunk", _next_pow2(chunk, 8)))
-        if self._prefill_kind() == "chunk":
+        if self.programs.prefill_kind(self.params) == "chunk":
             # whole-prompt prefills route through the chunk family too
             s, top_s = 8, _next_pow2(
                 min(max(1, max_prompt), self.max_len), 8)
@@ -1385,25 +1030,11 @@ class PagedLLMExecutor:
             "prefills": self.prefills,
             "chunk_prefills": self.chunk_prefills,
             "decode_steps": self.decode_steps,
-            "decode_windows": self.decode_windows,
-            "window_steps": self.window_steps,
-            "kv_tokens_attended": self.kv_tokens_attended,
-            "kv_slots_read": self.kv_slots_read,
             "swap_count": self.swap_count,
             "paged_kernel": self.paged_kernel,
             "kernel_invokes": dict(self.kernel_invokes),
+            **self.programs.stats(),
         }
-        if self.sparse:
-            out.update(
-                family=self.spec.family,
-                kv_tokens_scored=self.kv_tokens_scored,
-                kv_tokens_selected=self.kv_tokens_selected,
-                idx_slots_read=self.idx_slots_read,
-                expert_tokens=self.expert_tokens,
-                expert_steps_layers=self.expert_steps_layers,
-                experts_touched_sum=self.experts_touched_sum,
-                expert_load_max_sum=self.expert_load_max_sum,
-                expert_load_chunks=self.expert_load_chunks)
         if self.shards:
             out["shards"] = self.shards
             out["shard_chips"] = list(self._shard_chips)

@@ -7,6 +7,8 @@ The prefill/decode split and the streaming transformer
   slot count (not max_len × batch) bounds HBM.
 - `paged_model`  — prefill/decode math over the paged pool, formulated
   for token-for-token parity with `transformer.generate`.
+- `families`     — one program set a model family (`spec.LMSpec.family`):
+  which programs serve it, their arguments, refusals and counters.
 - `engine`       — the continuous-batching scheduler loop: admit,
   prefill (pow2-bucketed), merge into the in-flight decode batch,
   retire; plus the static-batching A/B mode the bench compares against.

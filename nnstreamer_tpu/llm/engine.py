@@ -34,7 +34,7 @@ step after their launch.
 
 A step that holds a row with temperature > 0 resolves first and samples
 on the host from that step's (vocab,) f32 logits, as does every step
-under `shards` or a `decode_window`: the sampled token comes from a
+under `shards`: the sampled token comes from a
 per-request seeded Generator (so a request's tokens don't depend on its
 batchmates) and must be known before the next launch. Which of the two
 orders a step takes is read from its rows, never set.
@@ -147,7 +147,6 @@ class LLMEngine:
                  static_batching: bool = False, prefill_chunk: int = 0,
                  paged_kernel: Optional[str] = None, shards: int = 0,
                  shard_chips=None, ring_prefill_min: int = 0,
-                 decode_window: int = 0,
                  tracer=NULL_TRACER, name: str = "llm"):
         from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
 
@@ -156,16 +155,6 @@ class LLMEngine:
         self.max_batch = int(max_batch)
         self.static = bool(static_batching)
         self.prefill_chunk = int(prefill_chunk)
-        # compiled decode window (executor.decode_multi): when the
-        # batch is in steady state — nothing queued or prefilling, all
-        # live rows greedy — run up to this many decode steps as ONE
-        # jitted lax.scan dispatch. 0 disables. Tokens arrive in
-        # window-sized bursts (ITL percentiles reflect that); greedy
-        # on-device argmax matches the host sampler bit for bit.
-        self.decode_window = int(decode_window)
-        if self.decode_window < 0:
-            raise BackendError(
-                f"decode_window must be >= 0, got {self.decode_window}")
         if self.prefill_chunk < 0:
             raise BackendError(
                 f"prefill_chunk must be >= 0, got {self.prefill_chunk}")
@@ -180,10 +169,6 @@ class LLMEngine:
             paged_kernel=paged_kernel, shards=shards,
             shard_chips=shard_chips, ring_prefill_min=ring_prefill_min,
             tracer=tracer, name=name)
-        if self.executor.sparse and self.decode_window > 0:
-            raise BackendError(
-                f"llm {name}: the sparse_moe family has no compiled decode "
-                f"window; set decode_window=0")
         self.cache = self.executor.cache
         self.queue: deque = deque()
         self.active: List[LLMRequest] = []
@@ -194,8 +179,6 @@ class LLMEngine:
         self.tokens_out = 0
         self.steps = 0
         self.admission_blocked = 0
-        self.decode_windows = 0
-        self.window_tokens = 0
         #: the launch the next step reads (module docstring)
         self._ahead: Optional[_Ahead] = None
         # decode launches made while the one before was still unread,
@@ -222,7 +205,7 @@ class LLMEngine:
             raise BackendError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
         ex = self.executor
-        ex.check_prompt(int(prompt.shape[0]), self.prefill_chunk)
+        ex.programs.check_prompt(int(prompt.shape[0]), self.prefill_chunk)
         total = int(prompt.shape[0]) + max_new_tokens
         seq_cap = ex.max_blocks * self.cache.block_size
         if total > seq_cap:
@@ -416,8 +399,8 @@ class LLMEngine:
 
     def _runs_ahead(self, pending: List[tuple]) -> bool:
         """Whether this step may launch before it reads: every row it
-        holds is greedy, on one chip, with no compiled window set."""
-        if self.executor.shards or self.decode_window >= 2:
+        holds is greedy, on one chip."""
+        if self.executor.shards:
             return False
         rows = self.active + [r for r, _ in pending]
         return not any(r.temperature > 0.0 for r in rows)
@@ -485,52 +468,9 @@ class LLMEngine:
         if self.tracer.active:
             self._sample_span(t0, len(taken))
 
-    def _window_len(self, live: List[LLMRequest]) -> int:
-        """How many decode steps may run as one compiled window right
-        now. 1 means per-step mode; >= 2 enters decode_multi. The
-        guards are the LLM analog of the scheduler's bail matrix:
-        pending admissions / prefills need per-step batch re-forming
-        (cause "shape"), a sampled row needs host RNG per token, and
-        the window never outruns any row's remaining budget (rows that
-        hit EOS early have their trailing tokens discarded host-side).
-        Rounded down to a power of two so the jit cache stays
-        O(log window) per batch bucket."""
-        if self.decode_window < 2 or self.queue or self.prefilling:
-            return 1
-        if self.executor.shards:
-            return 1       # sharded decode stays on the per-step path
-        if any(r.temperature > 0.0 for r in live):
-            return 1
-        k = min(self.decode_window,
-                min(r.max_new_tokens - len(r.tokens) for r in live))
-        if k < 2:
-            return 1
-        return 1 << (k.bit_length() - 1)
-
     def _decode(self, events: List[TokenEvent]) -> None:
         live = [r for r in self.active if r.state == "active"]
         if not live:
-            return
-        k = self._window_len(live)
-        if k >= 2:
-            toks = self.executor.decode_multi(
-                [r.tokens[-1] for r in live],
-                [r.block_table for r in live],
-                [r.pos for r in live], k)
-            self.decode_windows += 1
-            t0 = time.perf_counter() if self.tracer.active else 0.0
-            for j in range(k):
-                for i, req in enumerate(live):
-                    if req.state != "active":
-                        continue   # retired mid-window: discard tail
-                    req.pos += 1
-                    tok = int(toks[i, j])
-                    self._record_token(req, tok)
-                    done = self._maybe_finish(req, tok)
-                    events.append(TokenEvent(req, [tok], done))
-                    self.window_tokens += 1
-            if self.tracer.active:
-                self._sample_span(t0, len(live))
             return
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
@@ -625,9 +565,6 @@ class LLMEngine:
             "admission_blocked": self.admission_blocked,
             "scheduling": "static" if self.static else "continuous",
             "prefill_chunk": self.prefill_chunk,
-            "decode_window": self.decode_window,
-            "decode_windows": self.decode_windows,
-            "window_tokens": self.window_tokens,
             "lookahead_steps": self.lookahead_steps,
             "lookahead_discarded": self.lookahead_discarded,
             "cache": self.cache.stats(),

@@ -3,9 +3,10 @@
 `ModelBundle.lm` holds an `LMSpec` when the parameters alone cannot say
 what they are: the family, and the sizes a params pytree does not spell
 out (a query width that is not the hidden size, a rope base, an indexer,
-experts). `PagedLLMExecutor` reads its dims from the spec when the bundle
-has one and from the parameters' shapes, as it always did, when it has
-none; `n_heads` stays the dense family's element property.
+experts). `PagedLLMExecutor` reads its dims and `n_heads` from the spec
+when the bundle has one, and from the parameters' shapes and the
+element's `n_heads` property, as it always did, when it has none.
+`family` picks the model's program set from `llm/families.py`'s table.
 
 Frozen and hashable: the sparse-expert programs take it as a static
 argument of their jits.

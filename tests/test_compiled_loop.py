@@ -433,47 +433,3 @@ class TestBackendWindowParity:
             assert np.asarray(out[1][0])[0, 0] == 2.0
         finally:
             be.close()
-
-
-# -- paged-LLM decode window --------------------------------------------------
-
-class TestLLMDecodeWindowParity:
-    def _engine(self, window):
-        from nnstreamer_tpu.llm.engine import LLMEngine
-        from nnstreamer_tpu.models.transformer import init_params
-
-        params = init_params(vocab=61, d_model=32, n_layers=2,
-                             n_heads=4, n_kv_heads=2, seed=0)
-        return LLMEngine(params, n_heads=4, block_size=4, num_blocks=32,
-                         max_batch=4, max_len=64, decode_window=window)
-
-    def test_token_parity_mixed_budgets(self):
-        rng = np.random.default_rng(3)
-        prompts = [rng.integers(1, 60, size=s).tolist()
-                   for s in (5, 9, 3)]
-        n_new = [12, 7, 10]
-
-        def run(window):
-            eng = self._engine(window)
-            reqs = [eng.submit(p, max_new_tokens=m, eos_id=None)
-                    for p, m in zip(prompts, n_new)]
-            eng.drain()
-            return [list(r.tokens) for r in reqs], eng.stats()
-
-        toks_win, st_win = run(8)
-        toks_ref, st_ref = run(0)
-        assert st_win["decode_windows"] > 0
-        assert st_ref["decode_windows"] == 0
-        assert toks_win == toks_ref
-        assert [len(t) for t in toks_win] == n_new
-
-    def test_eos_mid_window_truncates_identically(self):
-        rng = np.random.default_rng(3)
-        prompt = rng.integers(1, 60, size=5).tolist()
-        outs = {}
-        for window in (8, 0):
-            eng = self._engine(window)
-            r = eng.submit(prompt, max_new_tokens=12, eos_id=7)
-            eng.drain()
-            outs[window] = (list(r.tokens), r.finish_reason)
-        assert outs[8] == outs[0]

@@ -253,35 +253,32 @@ def test_decode_walk_reads_nothing_past_pos(params, monkeypatch,
     assert np.array_equal(np.asarray(out[:live]), np.asarray(clean[:live]))
 
 
-def test_decode_multi_over_chunk_boundary_equals_single_steps(
+def test_launch_ahead_over_chunk_boundary_equals_single_synced_steps(
         params, monkeypatch):
-    """A compiled decode window whose positions advance on the device
-    across a chunk's edge (C = 16: from slot 13 to slot 20) serves the
-    tokens of as many single steps."""
-    from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
-
+    """Eight launches made ahead of their reads, whose positions cross a
+    chunk's edge (C = 16: from slot 13 to slot 20, from 29 to 36), serve
+    the tokens of as many single synced steps and count the same
+    context."""
     _walk_consts(monkeypatch, 2, 3)
     prompts = [_prompt(13, 40), _prompt(29, 41), _prompt(6, 42)]
+    pos = [len(p) for p in prompts]
     served = []
-    for window in (False, True):
-        ex = PagedLLMExecutor(dict(params), n_heads=4, block_size=WBS,
-                              num_blocks=WNB, max_len=WBS * WMB)
-        tables = [ex.cache.allocator.alloc(WMB) for _ in prompts]
-        cur = [int(np.argmax(ex.prefill(p, t)))
-               for p, t in zip(prompts, tables)]
-        pos = [len(p) for p in prompts]
-        if window:
-            toks = ex.decode_multi(cur, tables, pos, 8)
-        else:
-            toks = np.zeros((len(prompts), 8), np.int32)
-            for s in range(8):
-                logits = ex.decode(cur, tables, [p + s for p in pos])
-                cur = [int(t) for t in np.argmax(logits, axis=-1)]
-                toks[:, s] = cur
-        served.append(np.asarray(toks))
-        assert ex.stats()["kv_tokens_attended"] == sum(
+    for ahead in (True, False):
+        with monkeypatch.context() as mp:
+            if not ahead:
+                mp.setattr(LLMEngine, "_runs_ahead",
+                           lambda self, pending: False)
+            eng = LLMEngine(dict(params), n_heads=4, block_size=WBS,
+                            num_blocks=WNB, max_len=WBS * WMB, max_batch=4)
+            reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+            eng.drain()
+        served.append([list(r.tokens) for r in reqs])
+        st = eng.stats()
+        assert (st["lookahead_steps"] > 0) == ahead
+        assert st["executor"]["decode_steps"] == 8
+        assert st["executor"]["kv_tokens_attended"] == sum(
             sum(pos) + len(pos) * (s + 1) for s in range(8))
-    assert np.array_equal(served[0], served[1])
+    assert served[0] == served[1] and [len(t) for t in served[0]] == [9] * 3
 
 
 def test_decode_bucket_compiles_once_over_all_contexts(params):

@@ -24,6 +24,7 @@ from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
 from nnstreamer_tpu.llm import sparse_moe                       # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
+from nnstreamer_tpu.llm.families import SparseMoESet            # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import sparse_moe_lm as ref           # noqa: E402
 from perfbench.runners.sparse_moe_llm import lm_spec            # noqa: E402
@@ -287,7 +288,6 @@ def _refused(params, match, **kw):
 REFUSALS = {
     "shards": ({"shards": 2}, "shards=2"),
     "pallas": ({"paged_kernel": "pallas"}, "paged_kernel=pallas"),
-    "decode_window": ({"decode_window": 4}, "decode window"),
 }
 
 
@@ -305,7 +305,7 @@ def test_typed_refusal_of_a_w8a8_store_version(params):
 
 def test_typed_refusal_of_a_long_prompt_without_prefill_chunk(params,
                                                               monkeypatch):
-    monkeypatch.setattr(PagedLLMExecutor, "SPARSE_WHOLE_PROMPT_MAX", 16)
+    monkeypatch.setattr(SparseMoESet, "WHOLE_PROMPT_MAX", 16)
     bundle = ModelBundle(fn=None, params=params, lm=SPEC)
     kw = dict(dtype=jnp.float32, block_size=8, num_blocks=40, max_len=64)
     eng = LLMEngine(bundle, **kw)
