@@ -256,7 +256,10 @@ class PagedLLMExecutor:
             num_blocks=int(num_blocks), block_size=bs,
             n_layers=self.n_layers, n_kv=self.n_kv,
             head_dim=self.head_dim, idx_dim=self.programs.idx_dim,
-            placer=placer)
+            dtype=self.dtype, placer=placer)
+        #: bytes of a value in the pools, which keep K, V and the indexer's
+        #: keys in the type they are computed in (llm/paged_cache.py)
+        self.kv_pool_itemsize = int(self.cache.dtype.itemsize)
         #: each live sequence's last token, on the device, at the index
         #: of its table's first block (llm/next_ids.py); single-chip only
         self.last_ids = None if self.shards else jnp.zeros(
@@ -764,8 +767,10 @@ class PagedLLMExecutor:
             logits, beside, sync, "decode", b_b, t_in, t0)
         # the family's count of what the step attended and read
         # (kv_tokens, kv_slots, ...) and, where it is on the host, of
-        # what the step returned beside its logits
+        # what the step returned beside its logits; a slot of kv_slots
+        # is n_kv x head_dim values of kv_pool_itemsize bytes, K and V
         span = dict(what="llm_decode", bucket=b_b, rows=n, kernel=kernel,
+                    kv_pool_itemsize=self.kv_pool_itemsize,
                     **ps.note_decode(pos_a, n))
         if host:
             span.update(ps.note_beside("decode", host))
@@ -1033,6 +1038,7 @@ class PagedLLMExecutor:
             "swap_count": self.swap_count,
             "paged_kernel": self.paged_kernel,
             "kernel_invokes": dict(self.kernel_invokes),
+            "kv_pool_itemsize": self.kv_pool_itemsize,
             **self.programs.stats(),
         }
         if self.shards:

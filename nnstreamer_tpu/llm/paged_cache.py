@@ -126,6 +126,12 @@ class PagedKVCache:
     XLA:TPU re-lay the whole pool for every layer of a step; slot s of
     a block is row s // idx_pack, values (s % idx_pack) * idx_dim
     onward). `idx_dim=0` (every dense model) makes no third pool.
+
+    `dtype` is the type the model computes K, V and the indexer's keys
+    in, and the type the pools keep them in: every value a step writes
+    is a `dtype` value already, so a wider pool would hold zeros beside
+    it and every read would move them.
+
     The pools live here as plain jax arrays and are threaded through the
     executor's donated jit calls (write-in-place on device); this class
     only owns layout and accounting, never math.
@@ -141,7 +147,7 @@ class PagedKVCache:
         self.n_layers = int(n_layers)
         self.n_kv = int(n_kv)
         self.head_dim = int(head_dim)
-        self.dtype = dtype or jnp.float32
+        self.dtype = jnp.dtype(jnp.float32 if dtype is None else dtype)
         shape = (self.n_layers, self.num_blocks, self.block_size,
                  self.n_kv, self.head_dim)
         self.k = jnp.zeros(shape, self.dtype)
@@ -185,11 +191,9 @@ class PagedKVCache:
     @property
     def block_bytes(self) -> int:
         """Bytes one block holds over all layers and all pools."""
-        import jax.numpy as jnp
-
         per_slot = 2 * self.n_kv * self.head_dim + self.idx_dim
         return (self.n_layers * self.block_size * per_slot
-                * jnp.dtype(self.dtype).itemsize)
+                * self.dtype.itemsize)
 
     def resident_bytes(self) -> int:
         return sum(int(p.nbytes) for p in self.pools())

@@ -109,10 +109,15 @@ def paged_prefill(params, ids, blk_idx, blk_off, k_pool, v_pool, last_idx,
     return logits[0, last_idx], k_pool, v_pool
 
 
-# One K (or V) chunk of the walk is at most this many bytes of pool, one
-# iteration gathers at most this many bytes of K: the extents of
-# `_walk_plan`, set from chip runs (PERF.md section 6, PR 26).
-_CHUNK_BYTES = 256 << 10
+# The extents of `_walk_plan`, set from chip runs (PERF.md section 6,
+# PR 26 and PR 30). They are bytes of the float32 tile the products
+# read, not of the pool: a narrower pool is widened after its gather,
+# inside the loop, and it is the widened K and V tiles of an iteration
+# that have to stay in fast memory (at twice these slots an iteration a
+# bfloat16 pool's step was slower than a float32 pool's at these). One K
+# (or V) chunk is at most `_CHUNK_BYTES` of it, one iteration's chunks
+# together at most `_ITER_BYTES`.
+_CHUNK_BYTES = 128 << 10
 _ITER_BYTES = 16 << 20
 
 
@@ -121,7 +126,7 @@ def _walk_plan(block_size, n_kv, hd, b, max_blocks):
     (blocks a chunk, chunks a full table holds, items an iteration).
     A chunk is a whole number of blocks, so C = blocks * block_size
     slots; T items of C slots are gathered and attended at a time."""
-    block_bytes = block_size * n_kv * hd * 4     # the pool is float32
+    block_bytes = block_size * n_kv * hd * 4     # attended as float32
     nb_c = max(1, min(max_blocks, _CHUNK_BYTES // block_bytes))
     n_chunks = -(-max_blocks // nb_c)
     t = max(1, min(b * n_chunks, _ITER_BYTES // (nb_c * block_bytes)))

@@ -27,7 +27,12 @@ The layer, for input x at position t (`LMSpec` gives the sizes):
 State: K and V pools as the dense family's, and a third pool of indexer
 keys in the same blocks under the same tables (PagedKVCache.idx):
 ``(L, num_blocks, block_size // pack, pack * di)``, `pack` neighbouring
-slots side by side in a row of up to 128 values.
+slots side by side in a row of up to 128 values. All three hold the
+compute type's values in the compute type: a read's ``astype(dtype)`` is
+the identity. (Under a wider pool it is a cast in front of the matrix
+unit, which XLA:TPU moves ahead of the gather and out of the loop over
+context tiles, converting the whole pool once a layer: compiled text,
+PR 27 and PR 30. The step functions take such pools, for the tests.)
 
 How each program reads it.
 
@@ -173,21 +178,6 @@ def _idx_scores(qi, w, rows, di, shared: bool):
     return jnp.stack(per, axis=-1).reshape(qi.shape[0], -1)
 
 
-def _narrow(x, dtype):
-    """Pool values `x` in the compute type. The pool is float32 and was
-    written from `dtype` values, so for bfloat16 the upper 16 bits are
-    the value, exactly. Taken as bits, not as a cast: a cast in front of
-    the matrix unit XLA:TPU moves ahead of the gather and out of the
-    loop over context tiles, where it converts the whole pool once a
-    layer (8 GB of traffic at the published sizes; compiled text, PR 27;
-    a barrier or a conditional around the gather does not hold it)."""
-    if x.dtype == _F32 and dtype == jnp.bfloat16:
-        bits = jax.lax.bitcast_convert_type(x, _U32) >> 16
-        return jax.lax.bitcast_convert_type(bits.astype(jnp.uint16),
-                                            jnp.bfloat16)
-    return x.astype(dtype)
-
-
 def _expert_layer(blk, g, live, spec: LMSpec, dtype):
     """The dropless expert layer for tokens g (N, D), `live` (N,) bool
     marking the real ones. Returns (y (N, D) in `dtype`, tokens each
@@ -250,14 +240,13 @@ def _decode_layer(blk, x, li, pos, live, write_blk, write_off, tables,
     k_pool = k_pool.at[li, write_blk, write_off].set(k.astype(k_pool.dtype))
     v_pool = v_pool.at[li, write_blk, write_off].set(v.astype(v_pool.dtype))
     i_pool = _idx_write(i_pool, li, write_blk, write_off, ki)
-    # pool values were written from `dtype` values: narrowing is exact
-    rows = _narrow(i_pool[li, tables], dtype)    # (B, MB, bs/pack, pack*di)
+    rows = i_pool[li, tables].astype(dtype)      # (B, MB, bs/pack, pack*di)
     scores = _idx_scores(qi, w, rows.reshape(b, -1, rows.shape[-1]),
                          spec.idx_dim, shared=False)            # (B, S)
     sel, valid = select_rows(scores, pos, spec.topk)
     blk_of = jnp.take_along_axis(tables, sel // bs, axis=1)     # (B, K)
-    kc = _narrow(k_pool[li, blk_of, sel % bs], dtype)     # (B, K, Hkv, hd)
-    vc = _narrow(v_pool[li, blk_of, sel % bs], dtype)
+    kc = k_pool[li, blk_of, sel % bs].astype(dtype)       # (B, K, Hkv, hd)
+    vc = v_pool[li, blk_of, sel % bs].astype(dtype)
     qg = q.reshape(b, nkv, grp, hd)
     sc = jnp.einsum("bgrd,bkgd->bgrk", qg, kc,
                     preferred_element_type=_F32) * hd ** -0.5
@@ -390,7 +379,7 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
         return jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
 
     def score_tile(j, keys):
-        rows = _narrow(i_pool[li, tile_blocks(j)], dtype)
+        rows = i_pool[li, tile_blocks(j)].astype(dtype)
         sc = _idx_scores(qi, w, rows.reshape(-1, rows.shape[-1]),
                          spec.idx_dim, shared=True)             # (C, tile)
         may = (j * tile + slot)[None, :] <= pos[:, None]
@@ -406,9 +395,8 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
     def attend_tile(j, state):
         m, l, acc = state
         bl = tile_blocks(j)
-        # pool values were written from `dtype` values: the casts are exact
-        kt = _narrow(k_pool[li, bl], dtype).reshape(tile, nkv, hd)
-        vt = _narrow(v_pool[li, bl], dtype).reshape(tile, nkv, hd)
+        kt = k_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
+        vt = v_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
         key_t = jax.lax.dynamic_slice_in_dim(keys, j * tile,
                                              tile, 1)
         sel = (key_t > t[:, None]) | ((key_t == t[:, None]) & (

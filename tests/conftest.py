@@ -43,6 +43,17 @@ def eight_cpu_devices():
     return devs
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _trace_dir_of_this_worker(worker_id):
+    """The benchmark's tests profile into one fixed directory, which
+    `TraceWindow.launch` empties: two test files that trace at the same
+    time under xdist deleted each other's trace. Each worker gets a
+    directory of its own under it."""
+    from perfbench import harness
+
+    harness.TRACE_DIR = os.path.join(harness.TRACE_DIR, worker_id)
+
+
 def free_port() -> int:
     """Ephemeral TCP port for loopback test servers (shared helper)."""
     import socket

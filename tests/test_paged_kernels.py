@@ -10,10 +10,15 @@ previously written pool blocks. The compiled run of the same kernels
 is `chip_smoke.py`, on the chip.
 """
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
 jnp = pytest.importorskip("jax.numpy")
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "perfbench"))
 
 from nnstreamer_tpu.backends.pallas_paged import (  # noqa: E402
     paged_flash_decode_step, paged_flash_prefill_chunk)
@@ -543,3 +548,166 @@ def test_tracer_kernel_spans(params):
     eng.drain()
     spans = tr.kernel_spans()
     assert spans.get(("llm", "pallas"), 0) > 0
+
+
+# -- the pools keep their values in the type they were computed in ------------
+#
+# Under a bfloat16 model every value a step writes to a pool is a
+# bfloat16 value already, so a float32 pool holds 16 bits of value and 16
+# of zeros, and the same run on bfloat16 pools attends the same bits. The
+# step functions take the pools as arguments: both arms run here, through
+# the executor's own calls, on every path it serves.
+
+POOL_PATHS = {
+    "dense_xla": dict(paged_kernel="xla"),
+    "dense_pallas": dict(paged_kernel="pallas"),
+    "shards2_ring": dict(shards=2, ring_prefill_min=16),
+    "sparse": dict(),
+}
+PROMPT_LEN, DECODE_STEPS = 32, 24
+
+
+def _pool_executor(path, dtype=jnp.bfloat16, **kw):
+    from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
+
+    geom = dict(block_size=8, num_blocks=16, max_len=64, dtype=dtype,
+                name=f"pool_{path}")
+    if path == "sparse":
+        import tiny_sparse_moe as tiny
+        from nnstreamer_tpu.backends.xla import ModelBundle
+        from perfbench.references import sparse_moe_lm
+        from perfbench.runners.sparse_moe_llm import lm_spec
+
+        model = ModelBundle(
+            fn=None, params=sparse_moe_lm.make_params(tiny.CONFIG, 77),
+            lm=lm_spec(tiny.CONFIG))
+    else:
+        # the %8 geometry a sharded executor needs, for all three
+        model = init_params(d_model=64, n_heads=8, n_layers=2, vocab=256,
+                            seed=5)
+        geom["n_heads"] = 8
+    return PagedLLMExecutor(model, **geom, **POOL_PATHS[path], **kw)
+
+
+def _pool_dtypes(ex):
+    return {str(p.dtype) for p in ex.cache.pools()}
+
+
+def _pool_run(ex, widen):
+    """A 32-token prompt's prefill and 24 greedy decode steps. `widen`:
+    on float32 pools in place of the executor's own. Returns (the 25
+    logits, the pools as float32 numpy)."""
+    if widen:
+        ex.cache.set_pools([p.astype(jnp.float32)
+                            for p in ex.cache.pools()])
+    prompt = np.random.default_rng(2).integers(
+        1, 200, PROMPT_LEN).astype(np.int32)
+    table = ex.cache.allocator.alloc(
+        ex.cache.blocks_for(PROMPT_LEN + DECODE_STEPS))
+    logits = [np.asarray(ex.prefill(prompt, table))]
+    for step in range(DECODE_STEPS):
+        tok = int(np.argmax(logits[-1]))
+        logits.append(np.asarray(
+            ex.decode([tok], [table], [PROMPT_LEN + step])[0]))
+    return np.stack(logits), [np.asarray(p.astype(jnp.float32))
+                              for p in ex.cache.pools()]
+
+
+@pytest.mark.parametrize("path", sorted(POOL_PATHS))
+def test_bfloat16_pools_serve_a_bfloat16_model_bit_for_bit(
+        path, eight_cpu_devices):
+    narrow, wide = _pool_executor(path), _pool_executor(path)
+    try:
+        assert _pool_dtypes(narrow) == {"bfloat16"}
+        lg_n, pools_n = _pool_run(narrow, widen=False)
+        lg_w, pools_w = _pool_run(wide, widen=True)
+        assert _pool_dtypes(wide) == {"float32"}
+    finally:
+        narrow.close()
+        wide.close()
+    assert len(pools_w) == (3 if path == "sparse" else 2)
+    # (a) the float32 pools hold nothing bfloat16 does not
+    for pool in pools_w:
+        assert np.any(pool != 0.0)
+        assert np.array_equal(
+            pool, np.asarray(jnp.asarray(pool).astype(jnp.bfloat16)
+                             .astype(jnp.float32)))
+    # (b) the same logits at every step, the same pools after widening
+    assert np.array_equal(lg_n, lg_w)
+    for got, want in zip(pools_n, pools_w):
+        assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("path", sorted(POOL_PATHS))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_executor_allocates_the_pools_in_its_dtype(path, dtype,
+                                                   eight_cpu_devices):
+    from nnstreamer_tpu.runtime.tracing import Tracer
+
+    tracer = Tracer(max_events=4096)
+    ex = _pool_executor(path, dtype=dtype, tracer=tracer)
+    try:
+        cache, size = ex.cache, jnp.dtype(dtype).itemsize
+        assert _pool_dtypes(ex) == {jnp.dtype(dtype).name}
+        assert len(cache.pools()) == (3 if path == "sparse" else 2)
+        per_slot = 2 * ex.n_kv * ex.head_dim + cache.idx_dim
+        assert cache.stats()["block_bytes"] == cache.block_bytes \
+            == ex.n_layers * 8 * per_slot * size
+        assert cache.resident_bytes() == 16 * cache.block_bytes
+        assert ex.stats()["kv_pool_itemsize"] == size
+        table = cache.allocator.alloc(2)
+        prompt = np.arange(1, 10, dtype=np.int32)
+        for _ in range(2):          # the second decode is not a compile
+            ex.prefill(prompt, table)
+            ex.decode([3], [table], [9])
+        spans = [args for ph, cat, _, label, _, _, args in tracer.events()
+                 if ph == "X" and cat == "backend" and label == "invoke"
+                 and args.get("what") == "llm_decode"]
+        assert spans and all(
+            s["kv_pool_itemsize"] == size and s["kv_slots"] > 0
+            for s in spans)
+    finally:
+        ex.close()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["itemsize2", "itemsize4"])
+def test_walk_slots_equal_what_the_device_loop_gathers(params, monkeypatch,
+                                                       dtype):
+    """The executor's `kv_slots_read` is host arithmetic: it has to be
+    the plan the step traces for the pool it is given. The extents are
+    those of the float32 tile the products read, so the pool's
+    itemsize does not move them."""
+    from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
+    from nnstreamer_tpu.llm import paged_model as pm
+
+    monkeypatch.setattr(pm, "_CHUNK_BYTES", 2 * WBLOCK_BYTES)
+    monkeypatch.setattr(pm, "_ITER_BYTES", 6 * WBLOCK_BYTES)
+    plans = []
+    live_items = pm._live_items
+
+    def recording(tables, pos, block_size, nb_c, n_chunks, t):
+        plans.append((nb_c, n_chunks, t))
+        return live_items(tables, pos, block_size, nb_c, n_chunks, t)
+
+    monkeypatch.setattr(pm, "_live_items", recording)
+    ex = PagedLLMExecutor(dict(params), n_heads=4, dtype=dtype,
+                          block_size=WBS, num_blocks=WNB, max_len=WMB * WBS,
+                          name="walk")
+    try:
+        rows = [37, 5, 20]
+        tables = [ex.cache.allocator.alloc(p // WBS + 1) for p in rows]
+        ex.decode([3, 4, 5], tables, rows)
+        (nb_c, n_chunks, t), = plans        # what the step was traced with
+        assert ex.cache.k.dtype.itemsize == jnp.dtype(dtype).itemsize
+        assert (nb_c * WBS, t) == (16, 3)
+        # a bucket of 4: the padding row's one chunk is gathered too. The
+        # loop's trip count is a value of the step: compute it as it does
+        pos = np.asarray(rows + [0], np.int32)
+        n_iter = live_items(jnp.zeros((4, WMB), jnp.int32), jnp.asarray(pos),
+                            WBS, nb_c, n_chunks, t)[3]
+        assert int(n_iter) * t * nb_c * WBS == ex.stats()["kv_slots_read"] \
+            == pm.walk_slots(pos, WBS, NKV, HD, WMB)
+    finally:
+        ex.close()
