@@ -24,6 +24,13 @@ weights, frames and prompts:
                    indexer 2 x 8 top-8, float32): chunked prefill and decode
                    through the same element on the chip serve the tokens the
                    same engine serves on this host's CPU device;
+- ``llm_hybrid``   the hybrid family (llm/hybrid_lm.py) at a tiny size (hidden
+                   64, two sparse layers of 4 q / 2 kv heads of 16 attending
+                   3 blocks of 8 tokens, two linear layers of 4 heads with a
+                   carried state, float32): chunked prefill and decode over
+                   blocks and state slots through the same element on the
+                   chip serve the tokens the same engine serves on this
+                   host's CPU device;
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -484,6 +491,101 @@ def leg_llm_sparse_moe() -> dict:
             "experts_touched_sum": ex["experts_touched_sum"]}
 
 
+# -- the hybrid family ----------------------------------------------------------
+
+HYBRID = dict(d=64, heads=4, kv=2, hd=16, width=128, vocab=256,
+              kinds=("sparse", "linear", "linear", "sparse"))
+
+
+def _hybrid_bundle(device):
+    """Seeded float32 weights of the tiny hybrid model on `device`, in
+    the family's hand-over layout, with its description."""
+    import jax
+    import numpy as np
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.llm.spec import HYBRID as FAMILY
+    from nnstreamer_tpu.llm.spec import LMSpec
+
+    c = HYBRID
+    rng = np.random.default_rng(13)
+
+    def w(*shape):
+        lim = (6.0 / (shape[-2] + shape[-1])) ** 0.5
+        return rng.uniform(-lim, lim, shape).astype(np.float32)
+
+    ones = lambda n: np.ones((n,), np.float32)          # noqa: E731
+    aw, kw = c["heads"] * c["hd"], c["kv"] * c["hd"]
+
+    def layer(kind):
+        out = {"ln1": ones(c["d"]), "q_norm": ones(c["hd"]),
+               "k_norm": ones(c["hd"]), "wg": w(c["d"], aw),
+               "wo": w(aw, c["d"]), "ln2": ones(c["d"]),
+               "wi": w(c["d"], 2 * c["width"]), "wd": w(c["width"], c["d"]),
+               "wqkv": w(c["d"], 3 * aw if kind == "linear"
+                         else aw + 2 * kw)}
+        if kind == "linear":
+            out["o_norm"] = ones(aw)
+        return out
+
+    params = {"embed": w(c["vocab"], c["d"]),
+              "blocks": [layer(k) for k in c["kinds"]],
+              "ln_f": ones(c["d"]), "head": w(c["d"], c["vocab"])}
+    spec = LMSpec(family=FAMILY, n_heads=c["heads"], n_kv=c["kv"],
+                  head_dim=c["hd"], qk_norm=True, layer_kinds=c["kinds"],
+                  lin_heads=c["heads"], ck_kernel=8, ck_stride=4,
+                  sel_block=8, sel_topk=3, sel_window=8, sel_init=1,
+                  emb_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+                  logit_div=4.0)
+    return ModelBundle(fn=None, lm=spec, params=jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, device), params))
+
+
+def leg_llm_hybrid() -> dict:
+    """As `leg_llm_sparse_moe`, for the family that keeps a state slot a
+    sequence beside its blocks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.llm.engine import LLMEngine
+    from nnstreamer_tpu.serving.store import get_store
+
+    rng = np.random.default_rng(6)
+    # prompts on both sides of three selection blocks (24) and the chunk
+    prompts = [rng.integers(0, HYBRID["vocab"], size=n).astype(np.int32)
+               for n in (5, 12, 29, 41)]
+    serving = dict(block_size=4, num_blocks=96, max_len=64, prefill_chunk=8)
+    cpu = jax.devices("cpu")[0]
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        with jax.default_device(cpu):
+            eng = LLMEngine(_hybrid_bundle(cpu), dtype=jnp.float32,
+                            max_batch=8, **serving)
+            reqs = [eng.submit(p, req_id=f"req{i}",
+                               max_new_tokens=LLM_NEW_TOKENS)
+                    for i, p in enumerate(prompts)]
+            eng.drain()
+            want = {r.req_id: list(r.tokens) for r in reqs}
+            eng.executor.close()
+        get_store().register("chip_smoke_hybrid",
+                             _hybrid_bundle(jax.devices()[0]))
+        toks, stats = _run_llm("store://chip_smoke_hybrid", prompts,
+                               dtype="float32", paged_kernel="xla",
+                               **serving)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+    ex, cache = stats["executor"], stats["cache"]
+    assert ex["family"] == "hybrid" and ex["chunk_prefills"] >= 10, ex
+    assert ex["kv_tokens_selected"] > 0 and ex["state_bytes_rw"] > 0, ex
+    assert cache["pools"] == 4 and cache["state_slots_used"] == 0, cache
+    assert toks == want, f"greedy tokens differ: chip {toks} vs cpu {want}"
+    return {"requests": len(toks), "tokens": stats["tokens_out"],
+            "chunk_prefills": ex["chunk_prefills"],
+            "state_bytes_rw": ex["state_bytes_rw"]}
+
+
 # -- four chips --------------------------------------------------------------
 
 def leg_multichip(model: str, ref) -> dict:
@@ -602,6 +704,7 @@ def main() -> int:
     xla = leg("llm_xla", leg_llm, model, "xla")
     leg("llm_pallas", leg_llm, model, "pallas", xla and xla[0])
     leg("llm_sparse_moe", leg_llm_sparse_moe)
+    leg("llm_hybrid", leg_llm_hybrid)
     if dev["count"] >= 4 and ref:
         leg("multichip", leg_multichip, model, ref)
     else:

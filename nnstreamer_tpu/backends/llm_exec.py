@@ -158,7 +158,7 @@ class PagedLLMExecutor:
                  dtype=None, block_size: int = 16, num_blocks: int = 64,
                  max_len: int = 128, paged_kernel: Optional[str] = None,
                  shards: int = 0, shard_chips=None,
-                 ring_prefill_min: int = 0,
+                 ring_prefill_min: int = 0, state_slots: int = 0,
                  tracer=NULL_TRACER, name: str = "llm"):
         import jax.numpy as jnp
 
@@ -252,11 +252,13 @@ class PagedLLMExecutor:
                 self.params, self._mesh, n_heads=self.n_heads)
             self._sparams[self._vkey()] = placed
             placer = shg.kv_pool_placer(self._mesh)
+        # `state_slots`: sequences that may hold a state at once, where
+        # the family keeps one a sequence (the engine passes max_batch)
         self.cache = PagedKVCache(
             num_blocks=int(num_blocks), block_size=bs,
-            n_layers=self.n_layers, n_kv=self.n_kv,
             head_dim=self.head_dim, idx_dim=self.programs.idx_dim,
-            dtype=self.dtype, placer=placer)
+            dtype=self.dtype, placer=placer, state_slots=int(state_slots),
+            **self.programs.cache_kw(self.n_layers))
         #: bytes of a value in the pools, which keep K, V and the indexer's
         #: keys in the type they are computed in (llm/paged_cache.py)
         self.kv_pool_itemsize = int(self.cache.dtype.itemsize)
@@ -347,7 +349,8 @@ class PagedLLMExecutor:
 
     def maybe_adopt(self) -> None:
         """Adopt a flipped store epoch at a step boundary. In-flight
-        sequences keep their old-version KV (documented serving
+        sequences keep their old-version KV and, where the family keeps
+        one, their old-version state in their slot (documented serving
         tradeoff, docs/llm_serving.md) — retiring them instead would
         turn every swap into a latency spike for every live request."""
         if not self.swap_due():
@@ -569,7 +572,8 @@ class PagedLLMExecutor:
 
     # -- prefill -----------------------------------------------------------
     def prefill(self, prompt: np.ndarray, block_table: List[int],
-                *, sync: bool = True, req: Optional[str] = None):
+                *, sync: bool = True, req: Optional[str] = None,
+                state_slot: Optional[int] = None):
         """One whole prompt; its KV lands in the pool blocks of
         `block_table`. Dispatches between the full-sequence
         `apply_seq_kv` path and the chunk family (the program set's
@@ -577,7 +581,9 @@ class PagedLLMExecutor:
         path, as one chunk covering the prompt). Returns last-token
         logits: a host (vocab,) f32 array when `sync`, else the device
         array so the engine can batch one `device_sync` over a whole
-        step's admissions. `req` only labels the call's `invoke` span."""
+        step's admissions. `req` only labels the call's `invoke` span;
+        `state_slot` is the sequence's slot of the state pool, where the
+        family keeps a state a sequence."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
@@ -586,8 +592,8 @@ class PagedLLMExecutor:
         if ps.prefill_kind(self.params) == "chunk":
             ps.check_prompt(plen, 0)
             return self.prefill_chunk(
-                prompt, 0, block_table,
-                bucket=_next_pow2(plen, 8), sync=sync, req=req)
+                prompt, 0, block_table, bucket=_next_pow2(plen, 8),
+                sync=sync, req=req, state_slot=state_slot)
         kind = "prefill"
         if self.shards and 0 < self.ring_prefill_min <= plen:
             kind = "ring"    # sequence-parallel long-context cutover
@@ -630,7 +636,8 @@ class PagedLLMExecutor:
 
     def prefill_chunk(self, chunk: np.ndarray, pos0: int,
                       block_table: List[int], *, bucket: int = 0,
-                      sync: bool = True, req: Optional[str] = None):
+                      sync: bool = True, req: Optional[str] = None,
+                      state_slot: Optional[int] = None):
         """One prompt chunk starting at absolute position `pos0`,
         scattered into `block_table`'s blocks and attending the whole
         prefix written so far. `bucket` pins the pad width so every
@@ -656,11 +663,12 @@ class PagedLLMExecutor:
                 np.int32(clen - 1))
         ps = self.programs
         kw = ps.chunk_kw(pos0, c_b)
+        slot = np.int32(state_slot or 0)        # none: the scratch slot
 
         def _run():
             jitted, fresh = self._get_jit("chunk", c_b)
             logits, beside, pools = ps.split(jitted(
-                *ps.chunk_args(*args, self.cache.pools()), **kw))
+                *ps.chunk_args(*args, self.cache.pools(), slot), **kw))
             self.cache.set_pools(pools)
             return logits, beside, fresh
 
@@ -672,7 +680,8 @@ class PagedLLMExecutor:
         kernel = ps.kernel("chunk")
         out, host, t1 = self._resolve(
             logits, beside, sync, "prefill_chunk", c_b, t_in, t0)
-        extra = {}
+        # what the family counts of the chunk from where it starts
+        extra = ps.note_chunk(int(pos0), clen)
         if beside:
             # the span also says where the chunk starts and, once what
             # came beside the logits is on the host, what the family
@@ -691,7 +700,8 @@ class PagedLLMExecutor:
             jitted, _ = self._get_jit("chunk", c_b)
             self._prof_capture(
                 f"chunk:{c_b}", jitted,
-                ps.chunk_args(*args, self.cache.pools()), kw, t1 - t0)
+                ps.chunk_args(*args, self.cache.pools(), slot), kw,
+                t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill_chunk",
                        bucket=c_b, clen=clen, kernel=kernel, req=req,
@@ -702,7 +712,8 @@ class PagedLLMExecutor:
 
     # -- decode ------------------------------------------------------------
     def decode(self, cur: List[Optional[int]], tables: List[List[int]],
-               pos: List[int], *, sync: bool = True):
+               pos: List[int], *, sync: bool = True,
+               state_slots: Optional[List[int]] = None):
         """One decode step for `len(cur)` live rows (bucketed to pow2;
         padding rows write to the scratch block). `cur[i]` is row i's
         last token, or None where the host has not read it: the step
@@ -712,7 +723,9 @@ class PagedLLMExecutor:
         sync=False nothing is waited for: the rows' greedy ids are
         taken on the device, kept in `last_ids` for the next launch, and
         the returned `DecodeLaunch` is read by `resolve` (single-chip
-        only)."""
+        only). `state_slots[i]` is row i's slot of the state pool, where
+        the family keeps a state a sequence (padding rows take the
+        scratch slot)."""
         import jax
 
         from nnstreamer_tpu.backends.xla import _next_pow2
@@ -733,6 +746,10 @@ class PagedLLMExecutor:
             tab_a[i, :len(t)] = t
         pos_a = np.zeros((b_b,), np.int32)
         pos_a[:n] = pos
+        slot_a = None
+        if state_slots is not None:
+            slot_a = np.zeros((b_b,), np.int32)
+            slot_a[:n] = state_slots
         ps = self.programs
 
         def _run():
@@ -747,7 +764,7 @@ class PagedLLMExecutor:
                 cur_d = next_ids.llm_last_ids(self.last_ids, tab_d, cur_a)
             logits, beside, pools = ps.split(jitted(*ps.decode_args(
                 self._exec_params("decode"), cur_d, tab_d, pos_a, n,
-                self.cache.pools()), **ps.kw))
+                self.cache.pools(), slot_a), **ps.kw))
             self.cache.set_pools(pools)
             if not sync:
                 ids, self.last_ids = next_ids.llm_pick_rows(
@@ -783,7 +800,7 @@ class PagedLLMExecutor:
             self._prof_capture(
                 f"decode:{b_b}", jitted, ps.decode_args(
                     self._exec_params("decode"), cur_a, tab_a, pos_a, n,
-                    self.cache.pools()), ps.kw, t1 - t0)
+                    self.cache.pools(), slot_a), ps.kw, t1 - t0)
         elif sync:
             self._span("invoke", t0, t1, **span)
         self.decode_steps += 1
@@ -888,8 +905,9 @@ class PagedLLMExecutor:
                 tab = jax.device_put(tab)
                 cur = next_ids.llm_last_ids(self.last_ids, tab, cur)
 
-            def layout():       # no live row
-                return ps.decode_args(params, cur, tab, pos, 0, pools())
+            def layout():       # no live row, every state the scratch's
+                return ps.decode_args(params, cur, tab, pos, 0, pools(),
+                                      np.zeros((bucket,), np.int32))
         else:
             ids = np.zeros((1, bucket), np.int32)
             blk = np.full((bucket,), SCRATCH_BLOCK, np.int32)
@@ -902,7 +920,7 @@ class PagedLLMExecutor:
 
                 def layout():
                     return ps.chunk_args(params, ids, zero, blk, off, tab,
-                                         zero, pools())
+                                         zero, pools(), zero)
             else:
                 def layout():
                     return ps.prefill_args(params, ids, blk, off, zero,

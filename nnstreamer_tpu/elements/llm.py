@@ -64,6 +64,9 @@ class TensorLLM(Element):
       interleaved with decode steps (0 = whole-prompt prefill), so a
       long prompt does not head-of-line block the batch's inter-token
       latency.
+    - chunk_every: while rows decode, a chunk rides every N-th step
+      and the steps between are the decode batch alone (1 = a chunk
+      every step); with no row decoding a chunk rides every step.
     - shards: tensor-parallel shard count (2/4/8) — the executor opens
       one mesh-sharded backend over N leased chips with head-sharded
       projections and KV pools (docs/sharded_serving.md); bit-identical
@@ -108,6 +111,9 @@ class TensorLLM(Element):
         "prefill_chunk": PropDef(
             int, 0, "chunked-prefill chunk size in tokens "
                     "(0 = whole-prompt prefill)"),
+        "chunk_every": PropDef(
+            int, 1, "while rows decode, a prefill chunk rides every "
+                    "N-th step (1 = every step)"),
         "shards": PropDef(
             int, 0, "tensor-parallel shard count (0 = single chip; "
                     "2/4/8 serve one mesh-sharded backend whose chips "
@@ -153,6 +159,10 @@ class TensorLLM(Element):
             self.fail_negotiation(
                 f"prefill_chunk must be >= 0, got "
                 f"{self.props['prefill_chunk']}")
+        if int(self.props["chunk_every"]) < 1:
+            self.fail_negotiation(
+                f"chunk_every must be >= 1, got "
+                f"{self.props['chunk_every']}")
         shards = int(self.props["shards"])
         if shards > 0:
             from nnstreamer_tpu.serving.sharding import SUPPORTED_SHARDS
@@ -214,6 +224,7 @@ class TensorLLM(Element):
             max_len=int(self.props["max_len"]),
             static_batching=self.props["scheduling"] == "static",
             prefill_chunk=int(self.props["prefill_chunk"]),
+            chunk_every=int(self.props["chunk_every"]),
             paged_kernel=str(self.props["paged_kernel"]) or None,
             shards=shards, shard_chips=chips,
             ring_prefill_min=int(self.props["ring_prefill_min"]),

@@ -17,6 +17,13 @@ advances one N-token chunk per step — through the executor's one
 ``llmp_chunk`` bucket — while the decode batch keeps stepping, so an
 s8192 prompt stops being head-of-line for every live sequence's
 inter-token latency. The final chunk's logits yield the first token.
+``chunk_every=K`` spaces the chunks: while rows decode, a chunk rides
+every K-th step and the steps between are the decode batch alone, so a
+row's token rate is K tokens a (chunk + K decode steps) and not one a
+chunk; with no row decoding a chunk rides every step. Where a chunk
+costs many decode steps (a 2048-token chunk of a large model beside a
+decode step bound by the weights' read) K = 1 holds every live row to
+the chunk's pace for as long as any prompt prefills.
 
 One step runs one launch ahead of its read-back. While every live row is
 greedy (temperature 0) on a single chip, a step admits, launches this
@@ -80,6 +87,7 @@ class LLMRequest:
     state: str = "queued"          # queued | prefilling | active | done
     finish_reason: Optional[str] = None  # eos | length
     block_table: List[int] = field(default_factory=list)
+    state_slot: Optional[int] = None    # where the model keeps a state
     pos: int = 0                        # next cache write position
     t_submit: float = 0.0
     t_first: Optional[float] = None
@@ -145,6 +153,7 @@ class LLMEngine:
                  dtype=None, block_size: int = 16, num_blocks: int = 64,
                  max_batch: int = 8, max_len: int = 128,
                  static_batching: bool = False, prefill_chunk: int = 0,
+                 chunk_every: int = 1,
                  paged_kernel: Optional[str] = None, shards: int = 0,
                  shard_chips=None, ring_prefill_min: int = 0,
                  tracer=NULL_TRACER, name: str = "llm"):
@@ -158,6 +167,12 @@ class LLMEngine:
         if self.prefill_chunk < 0:
             raise BackendError(
                 f"prefill_chunk must be >= 0, got {self.prefill_chunk}")
+        self.chunk_every = int(chunk_every)
+        if self.chunk_every < 1:
+            raise BackendError(
+                f"chunk_every must be >= 1, got {self.chunk_every}")
+        #: steps since a chunk last rode one
+        self._since_chunk = 0
         if int(shards) > 0 and self.prefill_chunk > 0:
             raise BackendError(
                 f"llm {name}: prefill_chunk and shards are exclusive — "
@@ -168,7 +183,8 @@ class LLMEngine:
             num_blocks=num_blocks, max_len=max_len,
             paged_kernel=paged_kernel, shards=shards,
             shard_chips=shard_chips, ring_prefill_min=ring_prefill_min,
-            tracer=tracer, name=name)
+            # a model that keeps a state a sequence: a slot for each row
+            state_slots=self.max_batch, tracer=tracer, name=name)
         self.cache = self.executor.cache
         self.queue: deque = deque()
         self.active: List[LLMRequest] = []
@@ -178,7 +194,9 @@ class LLMEngine:
         self.finished = 0
         self.tokens_out = 0
         self.steps = 0
+        # admissions that waited: short of KV blocks, short of a state slot
         self.admission_blocked = 0
+        self.admission_blocked_state = 0
         #: the launch the next step reads (module docstring)
         self._ahead: Optional[_Ahead] = None
         # decode launches made while the one before was still unread,
@@ -289,7 +307,8 @@ class LLMEngine:
         """Admission, and under an active tracer one span over the whole
         call (prefill launches included) whose label is the outcome:
         `admit` (at least one request admitted), `admit_blocked` (the
-        head of the queue is short of KV blocks), `admit_none_queued`
+        head of the queue is short of KV blocks), `admit_blocked_state`
+        (it is short of a state slot), `admit_none_queued`
         (a row is free and nothing is queued here: whatever waits is
         still upstream of the engine) or `admit_full` (no row to give:
         all live, or a static batch still running)."""
@@ -299,26 +318,31 @@ class LLMEngine:
         t0 = time.perf_counter()
         rows = len(self.active) + len(self.prefilling) + len(pending)
         queued, blocked = len(self.queue), self.admission_blocked
+        blocked_state = self.admission_blocked_state
         self._admit_queue(pending)
         admitted = queued - len(self.queue)
         if admitted:
             label = "admit"
         elif self.admission_blocked > blocked:
             label = "admit_blocked"
+        elif self.admission_blocked_state > blocked_state:
+            label = "admit_blocked_state"
         elif not queued and rows < self.max_batch:
             label = "admit_none_queued"
         else:
             label = "admit_full"
+        args = {}
+        if self.cache.state_alloc is not None:
+            args["state_free"] = self.cache.state_alloc.free
         tr.span("llm", self.name, label, t0, time.perf_counter(),
                 step=self.steps, rows=rows, queued=queued,
                 admitted=admitted,
-                blocks_free=self.cache.allocator.free)
+                blocks_free=self.cache.allocator.free, **args)
 
     def _admit_queue(self, pending: List[tuple]) -> None:
         # static A/B mode: the batch forms only from empty, no top-up
         if self.static and (self.active or self.prefilling):
             return
-        alloc = self.cache.allocator
         # pending holds this step's already-admitted prefills (they only
         # join active in _finish_pending) — count them against the cap
         while self.queue and (len(self.active) + len(self.prefilling)
@@ -326,12 +350,16 @@ class LLMEngine:
             req = self.queue[0]
             plen = int(req.prompt.shape[0])
             need = self.cache.blocks_for(plen + req.max_new_tokens)
-            blocks = alloc.alloc(need, owner=req.req_id)
-            if blocks is None:
+            got = self.cache.reserve(need, owner=req.req_id)
+            if isinstance(got, str):
                 # head-of-line waits for retirements; admitting a
                 # smaller later request instead would starve it
-                self.admission_blocked += 1
+                if got == "state":
+                    self.admission_blocked_state += 1
+                else:
+                    self.admission_blocked += 1
                 return
+            blocks, req.state_slot = got
             self.queue.popleft()
             if self.tracer.active:
                 self.tracer.span("llm", self.name, "queued", req.t_submit,
@@ -345,8 +373,9 @@ class LLMEngine:
                 self.prefilling.append(req)
                 continue
             req.state = "active"
-            logits = self.executor.prefill(req.prompt, blocks,
-                                           sync=False, req=req.req_id)
+            logits = self.executor.prefill(
+                req.prompt, blocks, sync=False, req=req.req_id,
+                state_slot=req.state_slot)
             req.pos = plen
             pending.append((req, logits))
 
@@ -354,9 +383,14 @@ class LLMEngine:
         """Advance the oldest chunk-prefilling prompt by ONE chunk (the
         per-step prefill compute budget that keeps decode stepping);
         when its final chunk lands, its logits join this step's pending
-        batch and the request enters the decode batch."""
+        batch and the request enters the decode batch. While rows
+        decode, only every `chunk_every`-th step carries one."""
         if not self.prefilling:
             return
+        self._since_chunk += 1
+        if self.active and self._since_chunk < self.chunk_every:
+            return
+        self._since_chunk = 0
         req = self.prefilling[0]
         plen = int(req.prompt.shape[0])
         chunk = req.prompt[req.pos:req.pos + self.prefill_chunk]
@@ -365,7 +399,7 @@ class LLMEngine:
         logits = self.executor.prefill_chunk(
             chunk, req.pos, req.block_table,
             bucket=_next_pow2(self.prefill_chunk, 8), sync=False,
-            req=req.req_id)
+            req=req.req_id, state_slot=req.state_slot)
         req.pos += int(chunk.shape[0])
         if req.pos >= plen:
             self.prefilling.pop(0)
@@ -422,7 +456,7 @@ class LLMEngine:
             launch = ex.decode(
                 [None if r.ahead else r.tokens[-1] for r in rows],
                 [r.block_table for r in rows], [r.pos for r in rows],
-                sync=False)
+                sync=False, state_slots=self._state_slots(rows))
             if self._ahead is not None and self._ahead.launch is not None:
                 self.lookahead_steps += 1
             for r in rows:
@@ -475,7 +509,7 @@ class LLMEngine:
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
             [r.block_table for r in live],
-            [r.pos for r in live])
+            [r.pos for r in live], state_slots=self._state_slots(live))
         t0 = time.perf_counter() if self.tracer.active else 0.0
         for i, req in enumerate(live):
             req.pos += 1
@@ -544,11 +578,20 @@ class LLMEngine:
                    if k != "req_id"})
         return True
 
+    def _state_slots(self, rows: List[LLMRequest]) -> Optional[List[int]]:
+        """The rows' state slots, where the model keeps a state a
+        sequence."""
+        if self.cache.state_alloc is None:
+            return None
+        return [r.state_slot for r in rows]
+
     def _release(self, req: LLMRequest) -> None:
-        """Give a request's row and blocks back: when it finishes, or
-        ahead of that once its last token is in flight."""
-        self.cache.allocator.free_blocks(req.block_table)
-        req.block_table = []
+        """Give a request's row, blocks and state slot back: when it
+        finishes, or ahead of that once its last token is in flight
+        (what is prefilled into them runs after the launches already
+        made, the state's first chunk starting from zero)."""
+        self.cache.release(req.block_table, req.state_slot)
+        req.block_table, req.state_slot = [], None
         self.active.remove(req)
 
     def stats(self) -> dict:
@@ -563,8 +606,10 @@ class LLMEngine:
             "tokens_out": self.tokens_out,
             "steps": self.steps,
             "admission_blocked": self.admission_blocked,
+            "admission_blocked_state": self.admission_blocked_state,
             "scheduling": "static" if self.static else "continuous",
             "prefill_chunk": self.prefill_chunk,
+            "chunk_every": self.chunk_every,
             "lookahead_steps": self.lookahead_steps,
             "lookahead_discarded": self.lookahead_discarded,
             "cache": self.cache.stats(),

@@ -21,10 +21,14 @@ family, a kernel and a kind of call about:
   through its chunk;
 - what it refuses: at construction (`__init__`) and at submission
   (`check_prompt`);
-- `idx_dim`: the width a token needs of the pool's blocks beyond K and V;
-- its accounting: `note_decode` and `note_beside` count what a call
-  attended and read and return what its span says of it; `stats()` is
-  the family's part of the executor's.
+- `idx_dim`: the width a token needs of the pool's blocks beyond K and V,
+  and `cache_kw(n_layers)`: which layers keep K and V, and the pools the
+  family adds to `PagedKVCache` by slot (a fixed-size state and
+  compressed keys a sequence, whose slot the executor hands `chunk_args`
+  and `decode_args` last);
+- its accounting: `note_decode`, `note_chunk` and `note_beside` count what
+  a call attended and read and return what its span says of it; `stats()`
+  is the family's part of the executor's.
 
 A new family is a subclass and an entry of `FAMILIES`; the executor, the
 engine and the element are not edited (docs/llm_serving.md).
@@ -32,12 +36,13 @@ engine and the element are not edited (docs/llm_serving.md).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
-from nnstreamer_tpu.llm.spec import DENSE, SPARSE_MOE
+from nnstreamer_tpu.llm.spec import DENSE, HYBRID, LINEAR, SPARSE, SPARSE_MOE
 
 
 class Program(NamedTuple):
@@ -143,11 +148,17 @@ class DenseSet:
         return (params, ids, blk_idx, blk_off, *pools, last)
 
     def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
-                   pools) -> tuple:
+                   pools, slot=None) -> tuple:
         return (params, ids, pos0, blk_idx, blk_off, tab, *pools, last)
 
-    def decode_args(self, params, cur, tab, pos, n: int, pools) -> tuple:
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None) -> tuple:
         return (params, cur, tab, pos, *pools)
+
+    def cache_kw(self, n_layers: int) -> dict:
+        """What `PagedKVCache` is built with beyond the pool's geometry:
+        every layer keeps K and V, and there is no other state."""
+        return {"n_layers": n_layers, "n_kv": self.n_kv}
 
     def split(self, out: tuple) -> tuple:
         """A program's result as (logits, the device values it returns
@@ -176,6 +187,11 @@ class DenseSet:
         self.counters["kv_slots_read"] += slots
         return {"kv_tokens": tokens, "kv_slots": slots}
 
+    def note_chunk(self, pos0: int, clen: int) -> dict:
+        """Count what a chunk of `clen` tokens at `pos0` reads that the
+        host can tell from those two, and return its span's part."""
+        return {}
+
     def note_beside(self, kind: str, host: list) -> dict:
         """Account what a `chunk` or a `decode` returned beside its
         logits, now on the host, and return its span's part."""
@@ -185,17 +201,47 @@ class DenseSet:
         return dict(self.counters)
 
 
-class SparseMoESet(DenseSet):
+class ChunkOnlySet(DenseSet):
+    """What the families share that have one prefill program, their
+    chunk: whole prompts go through it, up to a length."""
+
+    #: the longest prompt the one-chunk whole-prompt prefill takes: past
+    #: it a chunk's temporaries outgrow what the pool leaves free, and
+    #: the engine has to chunk (prefill_chunk)
+    WHOLE_PROMPT_MAX = 4096
+
+    def prefill_kind(self, params: dict) -> str:
+        return "chunk"
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        """Whole blocks are written at once where the chunk lies on
+        them: every chunk of a prompt does when block_size divides
+        prefill_chunk, so the bucket stays one program."""
+        bs = self.block_size
+        return dict(self.kw,
+                    by_block=int(pos0) % bs == 0 and bucket % bs == 0)
+
+    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
+        """The family prefills through its chunk program only, and one
+        chunk holds at most WHOLE_PROMPT_MAX."""
+        if plen > self.WHOLE_PROMPT_MAX and not 0 < prefill_chunk < plen:
+            raise BackendError(
+                f"llm {self.name}: a prompt of {plen} tokens needs "
+                f"chunked prefill in the {self.family} family (one chunk "
+                f"holds at most {self.WHOLE_PROMPT_MAX}); set "
+                f"prefill_chunk (it is {prefill_chunk})")
+
+    def stats(self) -> dict:
+        return dict(self.counters, family=self.family)
+
+
+class SparseMoESet(ChunkOnlySet):
     """The sparse-expert decoder whose attention a learned indexer
     chooses (llm/sparse_moe.py): one prefill program, its chunk; a third
     pool of indexer keys; each call returns the tokens an expert got,
     (layers, experts), beside its logits."""
 
     family = SPARSE_MOE
-    #: the longest prompt the one-chunk whole-prompt prefill takes: past
-    #: it a chunk's (heads, C, tile) temporaries outgrow what the pool
-    #: leaves free, and the engine has to chunk (prefill_chunk)
-    WHOLE_PROMPT_MAX = 4096
 
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
@@ -228,9 +274,6 @@ class SparseMoESet(DenseSet):
             "expert_tokens", "expert_steps_layers", "experts_touched_sum",
             "expert_load_max_sum", "expert_load_chunks"), 0))
 
-    def prefill_kind(self, params: dict) -> str:
-        return "chunk"
-
     def program(self, kind: str) -> Program:
         from nnstreamer_tpu.llm import sparse_moe
 
@@ -240,31 +283,14 @@ class SparseMoESet(DenseSet):
         return Program(sparse_moe.sparse_moe_decode_step,
                        ("spec", "dtype"), (5, 6, 7))
 
-    def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        """Whole blocks are written at once where the chunk lies on
-        them: every chunk of a prompt does when block_size divides
-        prefill_chunk, so the bucket stays one program."""
-        bs = self.block_size
-        return dict(self.kw,
-                    by_block=int(pos0) % bs == 0 and bucket % bs == 0)
-
-    def decode_args(self, params, cur, tab, pos, n: int, pools) -> tuple:
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None) -> tuple:
         # n live rows: a step's padding rows reach no expert
         return (params, cur, tab, pos, np.int32(n), *pools)
 
     def split(self, out: tuple) -> tuple:
         logits, counts, *pools = out
         return logits, (counts,), pools
-
-    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
-        """The family prefills through its chunk program only, and one
-        chunk holds at most WHOLE_PROMPT_MAX."""
-        if plen > self.WHOLE_PROMPT_MAX and not 0 < prefill_chunk < plen:
-            raise BackendError(
-                f"llm {self.name}: a prompt of {plen} tokens needs "
-                f"chunked prefill in the sparse_moe family (one chunk "
-                f"holds at most {self.WHOLE_PROMPT_MAX}); set "
-                f"prefill_chunk (it is {prefill_chunk})")
 
     def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
         """The indexer scores each live row's context (kv_tokens_scored)
@@ -304,12 +330,147 @@ class SparseMoESet(DenseSet):
         c["expert_load_chunks"] += 1
         return {"experts_touched": touched, "expert_load_max": load_max}
 
-    def stats(self) -> dict:
-        return dict(self.counters, family=self.family)
+
+class HybridSet(ChunkOnlySet):
+    """The decoder whose layers are linear attention with a carried
+    state or block-sparse attention over paged KV (llm/hybrid_lm.py):
+    one prefill program, its chunk; K and V for the sparse layers only,
+    and a slot a sequence for the linear layers' states and the sparse
+    layers' compressed keys."""
+
+    family = HYBRID
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        # what the family cannot yet be combined with (ROADMAP C2)
+        why = None
+        if self.shards > 0:
+            why = (f"shards={self.shards}: its state and compressed-key "
+                   f"pools have no sharding rule yet (ROADMAP B5)")
+        elif self.paged_kernel == "pallas":
+            why = ("paged_kernel=pallas: it has no Pallas twin yet "
+                   "(ROADMAP B5); set paged_kernel=xla")
+        elif any(k.endswith("_scale") for k in params["blocks"][0]):
+            why = ("a W8A8 store version: its state update and gathered "
+                   "attention are float only")
+        elif self.block_size != spec.ck_stride:
+            why = (f"block_size={self.block_size}: a sequence keeps one "
+                   f"compressed key a block of its table, so block_size "
+                   f"has to be the keys' stride, {spec.ck_stride}")
+        elif len(spec.layer_kinds) != len(params["blocks"]):
+            why = (f"a bundle of {len(params['blocks'])} layers under a "
+                   f"spec that names {len(spec.layer_kinds)}")
+        if why is not None:
+            raise BackendError(
+                f"llm {self.name}: the hybrid family cannot be served "
+                f"with {why}")
+        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
+        self.n_linear = spec.layer_kinds.count(LINEAR)
+        self.n_sparse = spec.layer_kinds.count(SPARSE)
+        #: bytes of one sequence's state, all linear layers
+        self.state_bytes = (self.n_linear * spec.lin_heads
+                            * spec.head_dim * spec.head_dim * 4)
+        # kept tracer on or off; one sparse layer's worth each, a KV
+        # head's where heads select apart. Decode steps and chunks:
+        # state bytes read and written (all linear layers), compressed
+        # keys scored, selection blocks and tokens attended, pool slots
+        # gathered for them (padding rows and whole blocks included)
+        self.counters.update(dict.fromkeys((
+            "state_bytes_rw", "ckeys_scored", "kv_blocks_selected",
+            "kv_tokens_selected"), 0))
+
+    def cache_kw(self, n_layers: int) -> dict:
+        # K and V of the sparse layers only, a KV head a pool layer
+        # (llm/hybrid_lm.py says why); by slot, the linear layers' states
+        # and a compressed key a block of the longest table
+        s = self.spec
+        heads = self.n_sparse * self.n_kv
+        return {"n_layers": heads, "n_kv": 1,
+                "state_shape": (self.n_linear, s.lin_heads, s.head_dim,
+                                s.head_dim),
+                "ckey_shape": (heads, self.max_blocks, s.head_dim)}
+
+    def program(self, kind: str) -> Program:
+        from nnstreamer_tpu.llm import hybrid_lm
+
+        if kind == "chunk":
+            return Program(hybrid_lm.hybrid_prefill_chunk,
+                           ("spec", "dtype", "by_block"), (7, 8, 9, 10))
+        return Program(hybrid_lm.hybrid_decode_step, ("spec", "dtype"),
+                       (5, 6, 7, 8))
+
+    def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
+                   pools, slot=None) -> tuple:
+        return (params, ids, pos0, blk_idx, blk_off, tab, slot, *pools,
+                last)
+
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None) -> tuple:
+        return (params, cur, tab, pos, slots, *pools)
+
+    def _count(self, said: dict) -> dict:
+        for counter, key in (("ckeys_scored", "ckeys_scored"),
+                             ("kv_blocks_selected", "blocks_selected"),
+                             ("kv_tokens_selected", "kv_selected"),
+                             ("kv_tokens_attended", "kv_selected"),
+                             ("kv_slots_read", "kv_slots")):
+            self.counters[counter] += said[key]
+        return said
+
+    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
+        """Each live row's state is read and written once a linear
+        layer (state_rows, state_bytes_rw); in a sparse layer it scores
+        the compressed keys complete by its position, selects blocks and
+        attends their tokens up to its own; every row of the bucket
+        gathers sel_topk blocks (`hybrid_lm.sparse_attend_rows`)."""
+        s = self.spec
+        self.counters["state_bytes_rw"] += 2 * n * self.state_bytes
+        slots = len(pos_a) * min(s.sel_topk * s.sel_block,
+                                 self.max_blocks * self.block_size)
+        return {"state_rows": n, "kv_tokens": int(pos_a[:n].sum()) + n,
+                **self._count(_sparse_reads(
+                    s, pos_a[:n].astype(np.int64), slots))}
+
+    def note_chunk(self, pos0: int, clen: int) -> dict:
+        self.counters["state_bytes_rw"] += 2 * self.state_bytes
+        return {"pos0": pos0, "state_rows": 1,
+                **self._count(_chunk_reads(self.spec, pos0, clen))}
+
+
+def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
+    """What the queries at positions `qpos` score, select and attend in
+    one sparse layer of the hybrid family, a KV head; `slots` pool slots
+    are gathered for them."""
+    scored = int(np.maximum(
+        (qpos + 1 - spec.ck_kernel) // spec.ck_stride + 1, 0).sum())
+    blocks = np.minimum(spec.sel_topk, qpos // spec.sel_block + 1)
+    # the query's own block holds qpos % sel_block + 1 tokens for it
+    tokens = int(((blocks - 1) * spec.sel_block + qpos % spec.sel_block
+                  + 1).sum())
+    return {"ckeys_scored": scored, "blocks_selected": int(blocks.sum()),
+            "kv_selected": tokens, "kv_slots": int(slots)}
+
+
+@functools.lru_cache(maxsize=256)
+def _chunk_reads(spec, pos0: int, clen: int) -> dict:
+    """A chunk's reads, which only its place and length decide, so each
+    is reckoned once. The slots are what `hybrid_lm.sparse_attend_tile`
+    gathers: once a tile of queries the forced run (the first blocks,
+    the window and the tile's own), and for each query the blocks it
+    chose."""
+    from nnstreamer_tpu.llm.hybrid_lm import _Q_TILE
+
+    tile = min(_Q_TILE, clen)
+    forced = spec.sel_init + spec.sel_window // spec.sel_block
+    run = forced + (tile - 1) // spec.sel_block + 1
+    slots = spec.sel_block * (-(-clen // tile) * run
+                              + clen * max(spec.sel_topk - forced, 0))
+    return _sparse_reads(spec, pos0 + np.arange(clen, dtype=np.int64), slots)
 
 
 #: `LMSpec.family` -> its program set
-FAMILIES: Dict[str, type] = {DENSE: DenseSet, SPARSE_MOE: SparseMoESet}
+FAMILIES: Dict[str, type] = {DENSE: DenseSet, SPARSE_MOE: SparseMoESet,
+                             HYBRID: HybridSet}
 
 
 def program_set(spec, *, name: str, **given):

@@ -14,6 +14,15 @@ sequence — the table, not adjacency, provides ordering).
 Block 0 is reserved as the scratch block: padding rows of a bucketed
 decode batch and the padded tail of a bucketed prefill write there, so
 pow2 padding never corrupts a live sequence's cache.
+
+A model may keep a second kind of state, which is not paged: a *slot*
+a sequence, under an allocator of its own, of a pool of fixed-size
+states (llm/hybrid_lm.py's linear layers) and of a pool of compressed
+keys, one a block of the sequence's table (its sparse layers; read
+whole and in order by every query, so kept in a row, not gathered by
+block). A sequence is admitted with its blocks and its slot or with
+neither (`PagedKVCache.reserve`); slot 0 is the scratch slot, as block 0
+is the scratch block.
 """
 
 from __future__ import annotations
@@ -127,6 +136,17 @@ class PagedKVCache:
     a block is row s // idx_pack, values (s % idx_pack) * idx_dim
     onward). `idx_dim=0` (every dense model) makes no third pool.
 
+    `n_layers` counts the layers that keep K and V (all of a dense
+    model's; the sparse layers' KV heads of llm/hybrid_lm.py). Two more
+    pools serve that family, both indexed by a sequence's *slot*, one of
+    `state_slots` from `state_alloc` (slot 0 is scratch: padding rows
+    and warm-up calls write there). `state_shape` (layers, heads, dk,
+    dv): the state pool (layers, state_slots + 1, heads, dk, dv),
+    float32 whatever `dtype` is. `ckey_shape` (layers, entries, width):
+    the compressed keys `ck` (layers, state_slots + 1, entries, width)
+    in `dtype`, entry m of a slot belonging to block m of its
+    sequence's table.
+
     `dtype` is the type the model computes K, V and the indexer's keys
     in, and the type the pools keep them in: every value a step writes
     is a `dtype` value already, so a wider pool would hold zeros beside
@@ -139,7 +159,8 @@ class PagedKVCache:
 
     def __init__(self, *, num_blocks: int, block_size: int, n_layers: int,
                  n_kv: int, head_dim: int, idx_dim: int = 0, dtype=None,
-                 placer=None):
+                 placer=None, state_shape: tuple = (),
+                 ckey_shape: tuple = (), state_slots: int = 0):
         import jax.numpy as jnp
 
         self.num_blocks = int(num_blocks)
@@ -159,6 +180,18 @@ class PagedKVCache:
              self.block_size // self.idx_pack,
              self.idx_pack * self.idx_dim), self.dtype) \
             if self.idx_dim else None
+        self.ck = self.state = self.state_alloc = None
+        if state_shape or ckey_shape:
+            self.state_alloc = BlockAllocator(int(state_slots) + 1)
+
+        def by_slot(shape, dtype):
+            layers, *rest = (int(n) for n in shape)
+            return jnp.zeros((layers, int(state_slots) + 1, *rest), dtype)
+
+        if state_shape:
+            self.state = by_slot(state_shape, jnp.float32)
+        if ckey_shape:
+            self.ck = by_slot(ckey_shape, self.dtype)
         if placer is not None:
             # sharded serving hands us a device-placement closure (pool
             # sharded along the kv-head axis next to the projections —
@@ -176,24 +209,58 @@ class PagedKVCache:
     def tokens_capacity(self) -> int:
         return self.allocator.total * self.block_size
 
+    _EXTRA = ("idx", "ck", "state")
+
     def pools(self) -> tuple:
-        """The pools there are: (k, v), and the indexer's keys after
-        them where the model has an indexer."""
-        return (self.k, self.v) if self.idx is None \
-            else (self.k, self.v, self.idx)
+        """The pools there are: (k, v), then those the model adds, in
+        the order indexer keys, compressed keys, state."""
+        return (self.k, self.v) + tuple(
+            p for p in (getattr(self, n) for n in self._EXTRA)
+            if p is not None)
 
     def set_pools(self, pools) -> None:
         """Keep the pools a donating jit handed back, in `pools()` order."""
         self.k, self.v, *rest = pools
-        if rest:
-            self.idx, = rest
+        for name in self._EXTRA:
+            if getattr(self, name) is not None:
+                setattr(self, name, rest.pop(0))
+
+    # -- admission over both kinds of state --------------------------------
+    def reserve(self, n_blocks: int, owner: object = None):
+        """`n_blocks` blocks and, where the model keeps a state a
+        sequence, a state slot: both or neither. Returns (blocks, slot),
+        slot None for a model without state, or the name of what it was
+        short of: "blocks" or "state"."""
+        if self.state_alloc is not None and not self.state_alloc.can_alloc(1):
+            self.state_alloc.failed_allocs += 1
+            return "state"
+        blocks = self.allocator.alloc(n_blocks, owner=owner)
+        if blocks is None:
+            return "blocks"
+        if self.state_alloc is None:
+            return blocks, None
+        return blocks, self.state_alloc.alloc(1, owner=owner)[0]
+
+    def release(self, blocks: List[int], slot: Optional[int]) -> None:
+        """Give back what `reserve` granted."""
+        self.allocator.free_blocks(blocks)
+        if slot is not None:
+            self.state_alloc.free_blocks([slot])
 
     @property
     def block_bytes(self) -> int:
-        """Bytes one block holds over all layers and all pools."""
+        """Bytes one block holds over all layers and all pools that are
+        paged (what a sequence holds by slot: `state_slot_bytes`)."""
         per_slot = 2 * self.n_kv * self.head_dim + self.idx_dim
         return (self.n_layers * self.block_size * per_slot
                 * self.dtype.itemsize)
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes one slot holds: a sequence's state over all layers that
+        keep one, and its compressed keys."""
+        return sum(int(p.nbytes) // p.shape[1]
+                   for p in (self.state, self.ck) if p is not None)
 
     def resident_bytes(self) -> int:
         return sum(int(p.nbytes) for p in self.pools())
@@ -202,6 +269,12 @@ class PagedKVCache:
         out = self.allocator.stats()
         out["block_size"] = self.block_size
         out["tokens_capacity"] = self.tokens_capacity
-        out["pools"] = 2 if self.idx is None else 3
+        out["pools"] = len(self.pools())
         out["block_bytes"] = self.block_bytes
+        if self.state_alloc is not None:
+            out["state_slots"] = self.state_alloc.total
+            out["state_slots_used"] = self.state_alloc.used
+            out["state_bytes"] = self.state_slot_bytes * (
+                self.state_alloc.total + 1)
+            out["state_slot_bytes"] = self.state_slot_bytes
         return out
