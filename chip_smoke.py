@@ -148,6 +148,28 @@ def leg_kernels() -> dict:
           lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=True),
           (q, k, v), lambda q, k, v: attn_ref(q, k, v, True), 3e-2)
 
+    # the sparse-expert chunk's fused tile update at the Keye cell's head
+    # geometry (8 query heads a KV head of 128), against its plain twin:
+    # tile 1 of 2, a tie at every fourth slot cut at a query's own place,
+    # a carry that arrives filled
+    from nnstreamer_tpu.llm import sparse_moe
+
+    c, tile, nkv, grp = 512, 1024, 2, 8
+    keys = jnp.asarray(rng.integers(1, 9, (c, 2 * tile)), jnp.uint32) << 28
+    keys = keys.at[:, ::4].set(jnp.uint32(5 << 28))
+    t = jnp.full((c,), 5 << 28, jnp.uint32)
+    cut = jnp.asarray(tile + rng.integers(-8, tile + 8, (c,)), jnp.int32)
+    carry = (normal((nkv, grp, c)), 1.0 + jnp.abs(normal((nkv, grp, c))),
+             normal((nkv, grp, c, 128)))
+    check("selected_block_update",
+          lambda qg, kt, vt, m, l, a: po.selected_block_update(
+              qg.transpose(1, 2, 0, 3), kt, vt, keys, t, cut, 1, m, l, a),
+          (normal((c, nkv, grp, 128), jnp.bfloat16),
+           normal((tile, nkv, 128), jnp.bfloat16),
+           normal((tile, nkv, 128), jnp.bfloat16), *carry),
+          lambda qg, kt, vt, m, l, a: sparse_moe.attend_plain(
+              qg, kt, vt, keys[:, tile:], t, cut, tile, (m, l, a)), 3e-2)
+
     # paged kernels at the LLM legs' geometry, MHA and GQA
     hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64
     nh = LLM["n_heads"]

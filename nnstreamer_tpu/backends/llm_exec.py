@@ -53,7 +53,13 @@ default ``xla``): the attention inner loop is either the XLA reference
 invocations are counted per kernel. The kernel that was asked for is
 the kernel that runs: a Pallas kernel the compiler refuses raises
 `BackendError` from the call that needed it, and `paged_kernel=pallas`
-with `shards>0` is refused at construction.
+with `shards>0` is refused at construction. ``paged_kernel`` chooses
+who reads the pool through the block table, and nothing else: what a
+family does with a tile once XLA has gathered it is the family's own
+(the sparse-expert chunk updates its attention's carry in a Pallas
+kernel on a TPU under ``paged_kernel=xla``, chosen by
+`llm/sparse_moe.fused_attend` from backend and shapes, and says which
+on its spans: `attend`).
 
 Weights are passed as jit *arguments* (not closed over), so a same-
 shape hot swap is served by the already-compiled executable — the
@@ -524,9 +530,10 @@ class PagedLLMExecutor:
         beside their logits, once the device has it (all of it after a
         sync: the device runs in order), hand it to the family's
         accounting and put what that says on a `resolve` span under the
-        chunk's own `req`, `pos0` and `clen`."""
+        chunk's own `req` and `clen` and what its `invoke` said of where
+        it starts (`pos0` and the family's `note_chunk`)."""
         while self._chunk_beside:
-            req, pos0, clen, dev = self._chunk_beside[0]
+            req, clen, extra, dev = self._chunk_beside[0]
             if not (wait or all(d.is_ready() for d in dev)):
                 return
             self._chunk_beside.pop(0)
@@ -537,7 +544,7 @@ class PagedLLMExecutor:
                 self.tracer.span(
                     "backend", self.name, "resolve", t0,
                     time.perf_counter(), what="llm_prefill_chunk",
-                    req=req, pos0=pos0, clen=clen, **said)
+                    req=req, clen=clen, **extra, **said)
 
     # -- device performance plane (runtime/devprof.py) ---------------------
     def resident_bytes(self) -> int:
@@ -681,7 +688,7 @@ class PagedLLMExecutor:
         out, host, t1 = self._resolve(
             logits, beside, sync, "prefill_chunk", c_b, t_in, t0)
         # what the family counts of the chunk from where it starts
-        extra = ps.note_chunk(int(pos0), clen)
+        extra = ps.note_chunk(int(pos0), clen, c_b)
         if beside:
             # the span also says where the chunk starts and, once what
             # came beside the logits is on the host, what the family
@@ -691,7 +698,7 @@ class PagedLLMExecutor:
                 extra.update(ps.note_beside("chunk", host))
             else:
                 self._drain_chunks()
-                self._chunk_beside.append((req, int(pos0), clen, beside))
+                self._chunk_beside.append((req, clen, extra, beside))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_prefill_chunk",

@@ -468,6 +468,87 @@ def flash_block_update(q, k_blk, v_blk, m, l, acc, *, q_offset, k_offset,
     )(*scalars, q, k_blk, v_blk, m, l, acc)
 
 
+def _selected_block_kernel(scale: float, tile_ref,
+                           q_ref, k_ref, v_ref, key_ref, t_ref, cut_ref,
+                           m_ref, l_ref, a_ref, mo_ref, lo_ref, ao_ref):
+    """Selected-attention tile update: `flash_block_update`'s carry
+    under the chunk selection's mask, for one KV head and one block of
+    queries with all the query heads of the group. The mask is one for
+    the group (``key > T or (key == T and position <= P)``), built once
+    and handed to each head's `_online_softmax_update`; scores and
+    probabilities never leave VMEM. The carry's m / l block is
+    (group, bq): a head a sublane, no replication needed."""
+    grp, bq = q_ref.shape[1], q_ref.shape[2]
+    sk = k_ref.shape[0]
+
+    def ordered(u):
+        # uint32 order as int32 order (Mosaic compares signed vectors)
+        return jax.lax.bitcast_convert_type(u, jnp.int32) ^ jnp.int32(-2**31)
+
+    key, t = ordered(key_ref[...]), ordered(t_ref[...])   # (bq, sk), (bq, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 1)
+    cut = cut_ref[...] - tile_ref[0] * sk                 # tile-relative
+    mask = (key > t) | ((key == t) & (col <= cut))
+    for r in range(grp):
+        mo_ref[0, r], lo_ref[0, r], ao_ref[0, r] = _online_softmax_update(
+            q_ref[0, r], k_ref[...], v_ref[...],
+            m_ref[0, r], l_ref[0, r], a_ref[0, r], scale, mask)
+
+
+def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
+                          *, block_q: int = 128, interpret=None):
+    """One context tile of the sparse-expert chunk's attention walk
+    (llm/sparse_moe.py) as a Pallas kernel. q (Hkv, G, C, D): C queries,
+    G query heads a KV head; k_blk, v_blk (Sk, Hkv, D): the tile's keys
+    and values; keys (C, S) uint32: every query's selection key of every
+    context slot, of which this is tile number `tile` (a traced scalar)
+    of Sk slots; t (C,) uint32 and cut (C,) int32: query c attends slot
+    s where ``keys[c, s] > t[c]``, or ``keys[c, s] == t[c]`` and
+    ``s <= cut[c]``. Updates the flash carry m, l (Hkv, G, C) f32 and
+    acc (Hkv, G, C, D) f32 in place; a query the tile selects nothing
+    for keeps its carry. Needs D a multiple of 128 (a KV head is a lane
+    tile of the (Sk, Hkv * D) view) unless Hkv is 1.
+
+    A program takes `block_q` queries against the whole tile: on the
+    v5e, at Sk 1024 and 8 heads of 128 a group, 128 queries read 0.40 ms
+    a tile of 2,048 queries, 256 0.50, 512 0.47; the tile in two key
+    blocks of 512 read 0.55-0.78 (PERF.md, PR 32)."""
+    nkv, grp, c, d = q.shape
+    sk = k_blk.shape[0]
+    bq = min(block_q, c)
+    if c % bq or keys.shape[1] % sk:
+        raise ValueError(
+            f"selected_block_update needs C={c} divisible by {bq} and "
+            f"S={keys.shape[1]} by Sk={sk}")
+    kern = functools.partial(_selected_block_kernel, d ** -0.5)
+    blk_q = pl.BlockSpec((1, grp, bq, d), lambda g, i, j: (g, 0, i, 0))
+    blk_kv = pl.BlockSpec((sk, d), lambda g, i, j: (0, g))
+    blk_c = pl.BlockSpec((bq, 1), lambda g, i, j: (i, 0))
+    blk_m = pl.BlockSpec((1, grp, bq), lambda g, i, j: (g, 0, i))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nkv, c // bq),
+        in_specs=[blk_q, blk_kv, blk_kv,
+                  pl.BlockSpec((bq, sk), lambda g, i, j: (i, j[0])),
+                  blk_c, blk_c, blk_m, blk_m, blk_q],
+        out_specs=[blk_m, blk_m, blk_q])
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(m.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(l.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(acc.shape, jnp.float32)],
+        # the carry is updated in place, as flash_block_update's
+        input_output_aliases={7: 0, 8: 1, 9: 2},
+        name="selected_block_update",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=_interpret() if interpret is None else interpret,
+    )(jnp.asarray(tile, jnp.int32).reshape(1), q,
+      k_blk.reshape(sk, nkv * d), v_blk.reshape(sk, nkv * d), keys,
+      t[:, None], cut[:, None], m, l, acc)
+
+
 def flash_carry_init(bh: int, sq: int, d: int):
     """Fresh (m, l, acc) carry for flash_block_update — m/l in the
     (BH, 8, Sq) sublane-replicated layout the kernel requires."""

@@ -187,9 +187,10 @@ class DenseSet:
         self.counters["kv_slots_read"] += slots
         return {"kv_tokens": tokens, "kv_slots": slots}
 
-    def note_chunk(self, pos0: int, clen: int) -> dict:
-        """Count what a chunk of `clen` tokens at `pos0` reads that the
-        host can tell from those two, and return its span's part."""
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """Count what a chunk of `clen` tokens at `pos0`, padded to
+        `bucket`, reads that the host can tell from those three, and
+        return its span's part."""
         return {}
 
     def note_beside(self, kind: str, host: list) -> dict:
@@ -268,20 +269,34 @@ class SparseMoESet(ChunkOnlySet):
         # gathers); (layer, step) pairs and the distinct experts that
         # got a token in them. Every call: (token, expert) pairs routed.
         # Chunks: tokens at the busiest expert, summed over the chunks
-        # whose counts have been read back (expert_load_chunks).
+        # whose counts have been read back (expert_load_chunks); context
+        # tiles a layer's walks covered, and those of them the attention
+        # walk updated in one kernel (`sparse_moe.fused_attend`).
         self.counters.update(dict.fromkeys((
             "kv_tokens_scored", "kv_tokens_selected", "idx_slots_read",
             "expert_tokens", "expert_steps_layers", "experts_touched_sum",
-            "expert_load_max_sum", "expert_load_chunks"), 0))
+            "expert_load_max_sum", "expert_load_chunks",
+            "chunk_tiles_attended", "chunk_tiles_fused"), 0))
 
     def program(self, kind: str) -> Program:
         from nnstreamer_tpu.llm import sparse_moe
 
         if kind == "chunk":
             return Program(sparse_moe.sparse_moe_prefill_chunk,
-                           ("spec", "dtype", "by_block"), (6, 7, 8))
+                           ("spec", "dtype", "by_block", "fused"),
+                           (6, 7, 8))
         return Program(sparse_moe.sparse_moe_decode_step,
                        ("spec", "dtype"), (5, 6, 7))
+
+    def _fused(self, bucket: int) -> bool:
+        from nnstreamer_tpu.llm import sparse_moe
+
+        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
+                                       self.head_dim)
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        return dict(super().chunk_kw(pos0, bucket),
+                    fused=self._fused(bucket))
 
     def decode_args(self, params, cur, tab, pos, n: int, pools,
                     slots=None) -> tuple:
@@ -312,6 +327,20 @@ class SparseMoESet(ChunkOnlySet):
         c["kv_slots_read"] += slots
         return {"kv_tokens": scored, "kv_slots": slots,
                 "kv_selected": selected, "idx_slots": idx_slots}
+
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """The context tiles each of a layer's three walks covers (the
+        program's own count, `sparse_moe_prefill_chunk`'s `n_tiles`),
+        and which update the attention walk made them with."""
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+
+        tiles = min(-(-(pos0 + bucket) // _CTX_TILE),
+                    -(-(self.max_blocks * self.block_size) // _CTX_TILE))
+        fused = self._fused(bucket)
+        self.counters["chunk_tiles_attended"] += tiles
+        self.counters["chunk_tiles_fused"] += tiles * fused
+        return {"attend": "fused" if fused else "plain",
+                "ctx_tiles": tiles}
 
     def note_beside(self, kind: str, host: list) -> dict:
         """One call's (layers, experts) token counts: distinct experts
@@ -431,7 +460,7 @@ class HybridSet(ChunkOnlySet):
                 **self._count(_sparse_reads(
                     s, pos_a[:n].astype(np.int64), slots))}
 
-    def note_chunk(self, pos0: int, clen: int) -> dict:
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         self.counters["state_bytes_rw"] += 2 * self.state_bytes
         return {"pos0": pos0, "state_rows": 1,
                 **self._count(_chunk_reads(self.spec, pos0, clen))}

@@ -52,8 +52,17 @@ How each program reads it.
   positions by a second search, run only when a tie straddles the cut),
   and attention walks the same tiles with an online softmax under the
   mask ``key > T or (key == T and position <= P)``. No
-  ``(heads, chunk, max_len)`` score tensor exists; a tile's is
-  ``(heads, C, _CTX_TILE)``.
+  ``(heads, chunk, max_len)`` score tensor exists. How a tile updates
+  the softmax's carry is chosen by `fused_attend` from the backend and
+  the shapes: on a TPU, where the head width is a whole lane tile, one
+  kernel a tile (`pallas_ops.selected_block_update`: scores, mask,
+  running maximum and sum, exponentials and the value product of a
+  block of queries stay in fast memory, and nothing of shape
+  ``(heads, C, _CTX_TILE)`` is written); elsewhere `attend_plain`, whose
+  tile of float32 scores passes through memory three times. The two
+  attend the same slots and differ in the order float32 sums are added
+  inside a tile. XLA still reads the pool through the table for both:
+  ``paged_kernel`` stays ``xla``.
 - Expert layer (both): (token, expert) pairs sorted by expert, two
   grouped products (`jax.lax.ragged_dot`) over the experts that have
   tokens, combined by the renormalised weights in f32. Experts without a
@@ -69,14 +78,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.backends import pallas_ops
 from nnstreamer_tpu.llm.paged_model import _proj, _rope_rows
 from nnstreamer_tpu.llm.spec import LMSpec
 from nnstreamer_tpu.models.transformer import rmsnorm
 
 # Context slots one iteration of the chunk program's walks covers (a
-# whole number of blocks): the (heads, C, tile) f32 scores of a tile are
-# the program's largest temporary, 268 MB at 32 heads and C 2048.
+# whole number of blocks). The plain attention update keeps a tile's
+# (heads, C, tile) f32 scores in memory, 268 MB at 32 heads and C 2048;
+# the fused one keeps a block of them in fast memory, and the program's
+# largest temporary is then the (C, max_len) integer keys.
 _CTX_TILE = 1024
+
+# Queries a program of the fused update takes at once, against a whole
+# tile (`pallas_ops.selected_block_update`; read on the chip, PERF.md
+# PR 32).
+_FUSED_Q_BLOCK = 128
 
 _F32 = jnp.float32
 _U32 = jnp.uint32
@@ -357,11 +374,47 @@ def select_cut(keys, n_tiles, tile, k_eff):
     return t, p
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "by_block", "spec",
-                                             "dtype"))
+def fused_attend(c: int, tile: int, hd: int) -> bool:
+    """Whether a chunk of `c` queries walks its context tiles of `tile`
+    slots with the fused update (`pallas_ops.selected_block_update`) or
+    the plain one (`attend_plain`): from the backend and the shapes
+    alone. The kernel takes a KV head as a lane tile (hd and the tile
+    multiples of 128) and whole blocks of queries."""
+    return (jax.default_backend() == "tpu" and hd % 128 == 0
+            and tile % 128 == 0 and c % min(_FUSED_Q_BLOCK, c) == 0)
+
+
+def attend_plain(qg, kt, vt, key_t, t, cut, first, state):
+    """One context tile of the chunk's attention walk in plain XLA: qg
+    (C, Hkv, G, hd); kt, vt (tile, Hkv, hd), the slots from `first` on;
+    key_t (C, tile) their selection keys; query c attends the slots with
+    ``key > t[c]`` and those with ``key == t[c]`` at positions
+    ``<= cut[c]``. state: the online softmax's m, l (Hkv, G, C) and acc
+    (Hkv, G, C, hd), f32. The scores of the tile, (Hkv, G, C, tile) f32,
+    pass through memory three times."""
+    m, l, acc = state
+    tile, hd = kt.shape[0], kt.shape[2]
+    sel = (key_t > t[:, None]) | ((key_t == t[:, None]) & (
+        (first + jnp.arange(tile))[None, :] <= cut[:, None]))
+    sel = sel[None, None]                         # (1, 1, C, tile)
+    s = jnp.einsum("cgrd,sgd->grcs", qg, kt,
+                   preferred_element_type=_F32) * hd ** -0.5
+    s = jnp.where(sel, s, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    p = jnp.where(sel, jnp.exp(s - m_new[..., None]), 0.0)
+    old = jnp.exp(m - m_new)
+    l = l * old + jnp.sum(p, axis=-1)
+    acc = acc * old[..., None] + jnp.einsum(
+        "grcs,sgd->grcd", p.astype(vt.dtype), vt,
+        preferred_element_type=_F32)
+    return m_new, l, acc
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "by_block", "fused",
+                                             "spec", "dtype"))
 def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
-                 k_pool, v_pool, i_pool, *, tile, by_block, spec: LMSpec,
-                 dtype):
+                 k_pool, v_pool, i_pool, *, tile, by_block, fused,
+                 spec: LMSpec, dtype):
     """Layer `li` of a chunk: x (C, 1, D), the chunk's tokens as rows
     (jitted with `li` an argument, as `_decode_layer`)."""
     c = x.shape[0]
@@ -391,28 +444,18 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
     k_eff = jnp.minimum(min(int(spec.topk), s_pad), pos + 1)
     t, cut = select_cut(keys, n_tiles, tile, k_eff)
     qg = q.reshape(c, nkv, grp, hd)
+    # the kernel's layout, a head's queries side by side: made once
+    qh = qg.transpose(1, 2, 0, 3) if fused else None
 
     def attend_tile(j, state):
-        m, l, acc = state
         bl = tile_blocks(j)
         kt = k_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
         vt = v_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
-        key_t = jax.lax.dynamic_slice_in_dim(keys, j * tile,
-                                             tile, 1)
-        sel = (key_t > t[:, None]) | ((key_t == t[:, None]) & (
-            (j * tile + slot)[None, :] <= cut[:, None]))
-        sel = sel[None, None]                         # (1, 1, C, tile)
-        s = jnp.einsum("cgrd,sgd->grcs", qg, kt,
-                       preferred_element_type=_F32) * hd ** -0.5
-        s = jnp.where(sel, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(sel, jnp.exp(s - m_new[..., None]), 0.0)
-        old = jnp.exp(m - m_new)
-        l = l * old + jnp.sum(p, axis=-1)
-        acc = acc * old[..., None] + jnp.einsum(
-            "grcs,sgd->grcd", p.astype(dtype), vt,
-            preferred_element_type=_F32)
-        return m_new, l, acc
+        if fused:
+            return pallas_ops.selected_block_update(
+                qh, kt, vt, keys, t, cut, j, *state, block_q=_FUSED_Q_BLOCK)
+        key_t = jax.lax.dynamic_slice_in_dim(keys, j * tile, tile, 1)
+        return attend_plain(qg, kt, vt, key_t, t, cut, j * tile, state)
 
     m, l, acc = jax.lax.fori_loop(0, n_tiles, attend_tile, (
         jnp.full((nkv, grp, c), -1e30, _F32),
@@ -428,11 +471,13 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
 def sparse_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
                              k_pool, v_pool, i_pool, last_idx,
                              *, spec: LMSpec, dtype=jnp.float32,
-                             by_block: bool = False):
+                             by_block: bool = False, fused: bool = False):
     """One prompt chunk of one sequence; the arguments of
     `paged_prefill_chunk` with the indexer's pool after K and V.
     `by_block` (static): the caller vouches that `pos0` and the chunk's
-    width are multiples of the block size (`_write_chunk`).
+    width are multiples of the block size (`_write_chunk`). `fused`
+    (static): the attention walk updates a tile in one kernel; the
+    caller asks `fused_attend` whether it may.
     Returns (last real token's logits (vocab,) f32, tokens an expert
     (L, E) int32 over the chunk's real tokens, k_pool, v_pool, i_pool).
     """
@@ -454,8 +499,8 @@ def sparse_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
     for li, blk in enumerate(params["blocks"]):
         x, counts, k_pool, v_pool, i_pool = _chunk_layer(
             blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
-            k_pool, v_pool, i_pool, tile=tile, by_block=by_block, spec=spec,
-            dtype=dtype)
+            k_pool, v_pool, i_pool, tile=tile, by_block=by_block,
+            fused=fused, spec=spec, dtype=dtype)
         load.append(counts)
     logits = _finish(params, x[last_idx, 0][None, :], dtype)[0]
     return logits, jnp.stack(load), k_pool, v_pool, i_pool

@@ -352,7 +352,7 @@ def test_counts_of_a_step_are_the_references(bundle, params):
         assert said["blocks_selected"] == min(3, t // 8 + 1)
     assert ps.counters["state_bytes_rw"] == 4 * 2 * ps.state_bytes
     read = ps.counters["kv_slots_read"]
-    chunk = ps.note_chunk(16, 8)
+    chunk = ps.note_chunk(16, 8, 8)
     assert chunk["kv_selected"] == taps["attended"][0, 16:24, 0].sum()
     assert chunk["pos0"] == 16 and chunk["state_rows"] == 1
     # one tile of 8 queries: the forced run once (the first block, the
@@ -360,7 +360,7 @@ def test_counts_of_a_step_are_the_references(bundle, params):
     # each query chose
     assert chunk["kv_slots"] == 8 * 3 + 8 * 8 * 1
     assert ps.counters["kv_slots_read"] == read + chunk["kv_slots"]
-    assert ps.note_chunk(16, 8) == chunk            # reckoned once
+    assert ps.note_chunk(16, 8, 8) == chunk            # reckoned once
 
 
 def test_admission_short_of_a_slot_is_counted_apart(bundle):
