@@ -170,6 +170,31 @@ def leg_kernels() -> dict:
           lambda qg, kt, vt, m, l, a: sparse_moe.attend_plain(
               qg, kt, vt, keys[:, tile:], t, cut, tile, (m, l, a)), 3e-2)
 
+    # the hybrid chunk's walk at the SALA cell's head geometry (2 KV heads
+    # of 16 query heads of 128, blocks of 64 tokens, a pool block 16): the
+    # same kernel a call a KV head, against the walk by the plain update;
+    # queries across the second tile's first slots, every fourth block on
+    from nnstreamer_tpu.llm import hybrid_lm
+    from nnstreamer_tpu.llm.spec import HYBRID, LMSpec
+
+    hy = LMSpec(family=HYBRID, n_heads=32, n_kv=2, head_dim=128,
+                sel_block=64)
+    c, blocks = 256, 2 * tile // 16
+    tab = jnp.asarray(1 + rng.permutation(255)[:blocks], jnp.int32)
+    qpos = jnp.arange(tile - 128, tile - 128 + c)
+    on = jnp.asarray(rng.integers(0, 4, (c, 2, 2 * tile // 64)) == 0)
+    on = on.at[:, :, 0].set(True)
+    check("hybrid_walk_fused",
+          lambda q, kp, vp: hybrid_lm.sparse_attend_walk(
+              q, qpos, on, tab, 2, 1, kp, vp, spec=hy, dtype=jnp.bfloat16,
+              fused=True, tile=tile),
+          (normal((c, 32, 128), jnp.bfloat16),
+           normal((4, 256, 16, 1, 128), jnp.bfloat16),
+           normal((4, 256, 16, 1, 128), jnp.bfloat16)),
+          lambda q, kp, vp: hybrid_lm.sparse_attend_walk(
+              q, qpos, on, tab, 2, 1, kp, vp, spec=hy, dtype=jnp.bfloat16,
+              fused=False, tile=tile), 3e-2)
+
     # paged kernels at the LLM legs' geometry, MHA and GQA
     hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64
     nh = LLM["n_heads"]

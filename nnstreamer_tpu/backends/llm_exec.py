@@ -944,6 +944,13 @@ class PagedLLMExecutor:
                            t1 - t0)
         return True
 
+    def warm_decode(self, rows: int) -> bool:
+        """Build the decode bucket that holds `rows` rows, unless it is
+        built. Returns whether it was built now."""
+        from nnstreamer_tpu.backends.xla import _next_pow2
+
+        return self._warm_compile("decode", _next_pow2(rows, 1))
+
     def prewarm_buckets(self, *, max_batch: int, max_prompt: int,
                         chunk: int = 0) -> int:
         """Eagerly compile every bucket a serving run can hit: decode

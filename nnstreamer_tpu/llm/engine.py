@@ -278,6 +278,12 @@ class LLMEngine:
         #: (req, device logits) for every prefill completed this step
         pending: List[tuple] = []
         self._admit(pending)
+        if self.prefilling:
+            # rows that hold a place and join chunks from now: the decode
+            # bucket of all the rows admitted is built here, where fewer
+            # rows wait on it than at the step the batch is largest
+            self.executor.warm_decode(len(self.active) + len(pending)
+                                      + len(self.prefilling))
         self._prefill_chunks(pending)
         if self._runs_ahead(pending):
             ahead = self._launch_ahead(pending)

@@ -214,12 +214,20 @@ class ChunkOnlySet(DenseSet):
     def prefill_kind(self, params: dict) -> str:
         return "chunk"
 
+    def _fused(self, bucket: int) -> bool:
+        """Whether the chunk's attention walk updates a context tile in
+        one kernel (`sparse_moe.fused_attend`)."""
+        from nnstreamer_tpu.llm import sparse_moe
+
+        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
+                                       self.head_dim)
+
     def chunk_kw(self, pos0: int, bucket: int) -> dict:
         """Whole blocks are written at once where the chunk lies on
         them: every chunk of a prompt does when block_size divides
         prefill_chunk, so the bucket stays one program."""
         bs = self.block_size
-        return dict(self.kw,
+        return dict(self.kw, fused=self._fused(bucket),
                     by_block=int(pos0) % bs == 0 and bucket % bs == 0)
 
     def check_prompt(self, plen: int, prefill_chunk: int) -> None:
@@ -287,16 +295,6 @@ class SparseMoESet(ChunkOnlySet):
                            (6, 7, 8))
         return Program(sparse_moe.sparse_moe_decode_step,
                        ("spec", "dtype"), (5, 6, 7))
-
-    def _fused(self, bucket: int) -> bool:
-        from nnstreamer_tpu.llm import sparse_moe
-
-        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
-                                       self.head_dim)
-
-    def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        return dict(super().chunk_kw(pos0, bucket),
-                    fused=self._fused(bucket))
 
     def decode_args(self, params, cur, tab, pos, n: int, pools,
                     slots=None) -> tuple:
@@ -403,10 +401,11 @@ class HybridSet(ChunkOnlySet):
         # head's where heads select apart. Decode steps and chunks:
         # state bytes read and written (all linear layers), compressed
         # keys scored, selection blocks and tokens attended, pool slots
-        # gathered for them (padding rows and whole blocks included)
+        # gathered for them (padding rows and whole blocks included).
+        # Chunks: the context tiles a sparse layer's walk covered
         self.counters.update(dict.fromkeys((
             "state_bytes_rw", "ckeys_scored", "kv_blocks_selected",
-            "kv_tokens_selected"), 0))
+            "kv_tokens_selected", "chunk_tiles_attended"), 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         # K and V of the sparse layers only, a KV head a pool layer
@@ -424,7 +423,8 @@ class HybridSet(ChunkOnlySet):
 
         if kind == "chunk":
             return Program(hybrid_lm.hybrid_prefill_chunk,
-                           ("spec", "dtype", "by_block"), (7, 8, 9, 10))
+                           ("spec", "dtype", "by_block", "fused", "tile"),
+                           (7, 8, 9, 10))
         return Program(hybrid_lm.hybrid_decode_step, ("spec", "dtype"),
                        (5, 6, 7, 8))
 
@@ -460,10 +460,25 @@ class HybridSet(ChunkOnlySet):
                 **self._count(_sparse_reads(
                     s, pos_a[:n].astype(np.int64), slots))}
 
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+
+        return dict(super().chunk_kw(pos0, bucket), tile=_CTX_TILE)
+
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """The context tiles a sparse layer's walk covers (the program's
+        own trip count, `hybrid_lm.live_tiles` of the tile `chunk_kw`
+        hands it) and the slots it gathers for them, a KV head."""
+        from nnstreamer_tpu.llm import hybrid_lm
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+
+        tiles = hybrid_lm.live_tiles(
+            pos0, bucket, self.max_blocks * self.block_size, _CTX_TILE)
         self.counters["state_bytes_rw"] += 2 * self.state_bytes
-        return {"pos0": pos0, "state_rows": 1,
-                **self._count(_chunk_reads(self.spec, pos0, clen))}
+        self.counters["chunk_tiles_attended"] += tiles
+        return {"pos0": pos0, "state_rows": 1, "ctx_tiles": tiles,
+                **self._count(_chunk_reads(self.spec, pos0, clen,
+                                           tiles * _CTX_TILE))}
 
 
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
@@ -481,19 +496,11 @@ def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
 
 
 @functools.lru_cache(maxsize=256)
-def _chunk_reads(spec, pos0: int, clen: int) -> dict:
-    """A chunk's reads, which only its place and length decide, so each
-    is reckoned once. The slots are what `hybrid_lm.sparse_attend_tile`
-    gathers: once a tile of queries the forced run (the first blocks,
-    the window and the tile's own), and for each query the blocks it
-    chose."""
-    from nnstreamer_tpu.llm.hybrid_lm import _Q_TILE
-
-    tile = min(_Q_TILE, clen)
-    forced = spec.sel_init + spec.sel_window // spec.sel_block
-    run = forced + (tile - 1) // spec.sel_block + 1
-    slots = spec.sel_block * (-(-clen // tile) * run
-                              + clen * max(spec.sel_topk - forced, 0))
+def _chunk_reads(spec, pos0: int, clen: int, slots: int) -> dict:
+    """A chunk's reads, which only its place, its length and the `slots`
+    of its live context tiles decide, so each is reckoned once: those
+    slots are what `hybrid_lm.sparse_attend_walk` gathers, a KV head,
+    once for all of the chunk's queries."""
     return _sparse_reads(spec, pos0 + np.arange(clen, dtype=np.int64), slots)
 
 

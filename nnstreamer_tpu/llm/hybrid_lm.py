@@ -47,17 +47,25 @@ block of ``sel_block`` tokens takes the largest score of the compressed keys
 whose tokens overlap it, the first ``sel_init`` blocks and the ``sel_window
 / sel_block`` blocks ending in the query's own are forced, and the query
 attends the ``sel_topk`` highest blocks, ties to the lower index, causally.
-K and V are gathered for selected blocks only, a KV head at a time, whatever
-the context's length; no ``(queries, max_len)`` score over tokens exists,
-the scores over compressed keys are ``(heads, queries, max_len / stride)``.
+No ``(queries, max_len)`` score over tokens exists; the scores over
+compressed keys are ``(heads, queries, max_len / stride)``, a tile of
+`_Q_TILE` queries at a time in a chunk.
 
 - Decode: `jax.lax.top_k` over a row's block scores; all ``sel_topk``
   blocks gathered, a row and KV head.
-- Chunk, in tiles of `_Q_TILE` queries: the forced blocks of a tile's
-  queries are one run of the table, read once and attended densely behind
-  each query's own mask; the blocks a query chose by score (at most
-  ``sel_topk - sel_init - window blocks``, found without a sort:
-  `select_chosen`) are gathered for it; one softmax over both parts.
+- Chunk: the selection of every query is kept as a mask over blocks,
+  forced and chosen as one set (`attended_mask`; the chosen ones found
+  without a sort, `chosen_mask`), and attention walks the live context a
+  tile of `sparse_moe._CTX_TILE` slots at a time, a loop whose trip count
+  comes from ``pos0`` (`sparse_attend_walk`): a tile's K and V are read
+  once through the table for all of the chunk's queries, a query's block
+  bits are spread over the tile's slots behind its position, and an
+  online softmax's carry is updated under that mask, on a TPU in one
+  kernel a tile and KV head (`pallas_ops.selected_block_update`, the
+  sparse-expert family's; elsewhere `sparse_moe.attend_plain`). Nothing
+  a query wide is gathered: a context of C tokens is read once, not once
+  for each of the queries that chose from it. The walk's time grows with
+  the context, 0.39 ms a tile and layer on the v5e (PERF.md, PR 36).
 
 ``y = Wo (sigmoid(h Wg) * o)``.
 
@@ -72,6 +80,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.backends import pallas_ops
+from nnstreamer_tpu.llm import sparse_moe
 from nnstreamer_tpu.llm.paged_model import _mlp_paged, _proj, _rope_rows
 from nnstreamer_tpu.llm.spec import LINEAR, LMSpec
 from nnstreamer_tpu.models.transformer import rmsnorm
@@ -79,8 +89,9 @@ from nnstreamer_tpu.models.transformer import rmsnorm
 # Tokens of a chunk the linear layer takes at a time: the (heads, run,
 # run) float32 decay and scores of a run are 8.4 MB each at 32 heads.
 _SCAN = 256
-# Queries of a chunk the sparse layer selects and gathers for at a time:
-# a tile's gathered K (or V) is tile x n_kv x topk x sel_block x hd values.
+# Queries of a chunk the sparse layer scores and selects for at a time: a
+# tile's scores over the compressed keys are tile x heads x max_blocks
+# float32.
 _Q_TILE = 128
 
 _F32 = jnp.float32
@@ -209,35 +220,42 @@ def select_blocks(score, qpos, spec: LMSpec):
     return blocks, top > -jnp.inf
 
 
-def select_chosen(score, qpos, spec: LMSpec):
-    """The same selection without its forced blocks, for a chunk's many
-    queries: of the blocks a query is not forced to, the sel_topk -
-    sel_init - window blocks of largest score, ties to the lower index,
-    as (blocks (N, G, J) int32, valid (N, G, J)). No sort: the J-th
-    largest score of each row is found by the sparse-expert family's
-    search over the bits of order-preserving keys (`select_cut`), and
-    the blocks at or above it are counted into place."""
-    from nnstreamer_tpu.llm.sparse_moe import _sort_keys, select_cut
+def _n_chosen(spec: LMSpec) -> int:
+    """The blocks a query chooses by score: sel_topk less the forced."""
+    return spec.sel_topk - spec.sel_init - spec.sel_window // spec.sel_block
 
+
+def chosen_mask(score, qpos, spec: LMSpec):
+    """The same selection without its forced blocks, for a chunk's many
+    queries, as a mask: of the blocks a query is not forced to, the
+    sel_topk - sel_init - window blocks of largest score, ties to the
+    lower index. Returns (chosen (N, G, NB) bool, take (N,): how many a
+    query chose). No sort: the J-th largest score of each row is found
+    by the sparse-expert family's search over the bits of order-
+    preserving keys (`select_cut`)."""
     n, g, nb = score.shape
-    wb = spec.sel_window // spec.sel_block
-    j = spec.sel_topk - spec.sel_init - wb
     forced, own = _forced(nb, qpos, spec)
     free = ~forced & (jnp.arange(nb)[None, :] <= own)            # (N, NB)
-    take = jnp.minimum(j, jnp.sum(free, axis=1))                 # (N,)
-    keys = jnp.where(free[:, None, :], _sort_keys(score), jnp.uint32(0))
+    take = jnp.minimum(_n_chosen(spec), jnp.sum(free, axis=1))   # (N,)
+    keys = jnp.where(free[:, None, :], sparse_moe._sort_keys(score),
+                     jnp.uint32(0))
     keys = keys.reshape(n * g, nb)
-    take = jnp.repeat(take, g)
-    t, cut = select_cut(keys, 1, nb, take)
+    rows = jnp.repeat(take, g)
+    t, cut = sparse_moe.select_cut(keys, 1, nb, rows)
     sel = (keys > t[:, None]) | ((keys == t[:, None]) & (
         jnp.arange(nb)[None, :] <= cut[:, None]))
-    sel = sel & (take > 0)[:, None]
-    # block b is the rank-th chosen of its row: one-hot into place
-    rank = jnp.cumsum(sel, axis=1) - 1
-    at = sel[:, :, None] & (rank[:, :, None] == jnp.arange(j))
-    blocks = jnp.sum(jnp.where(at, jnp.arange(nb)[None, :, None], 0), axis=1)
-    valid = jnp.arange(j)[None, :] < take[:, None]
-    return blocks.reshape(n, g, j), valid.reshape(n, g, j)
+    sel = sel & (rows > 0)[:, None]
+    return sel.reshape(n, g, nb), take
+
+
+def attended_mask(score, qpos, spec: LMSpec):
+    """(N, G, NB) bool: the selection blocks a query attends, forced and
+    chosen as one set (blocks past its own among them: the position
+    cuts those, slot by slot)."""
+    forced, _ = _forced(score.shape[-1], qpos, spec)
+    if _n_chosen(spec) <= 0:
+        return jnp.broadcast_to(forced[:, None, :], score.shape)
+    return forced[:, None, :] | chosen_mask(score, qpos, spec)[0]
 
 
 def _head_layers(li, spec: LMSpec):
@@ -262,17 +280,11 @@ def _score_blocks(qg, qpos, ck, spec: LMSpec):
     return _block_scores(p, spec)
 
 
-def _softmax_over(parts, dtype):
-    """One softmax over several (scores (..., T_i) f32, allowed) parts
-    laid side by side: each part's weights in `dtype`, 0 where not
-    allowed."""
-    sc = jnp.concatenate([jnp.where(may, s, -1e30) for s, may in parts], -1)
-    may = jnp.concatenate([jnp.broadcast_to(m, s.shape) for s, m in parts],
-                          -1)
-    w = jnp.where(may, jax.nn.softmax(sc, axis=-1), 0.0).astype(dtype)
-    widths = [s.shape[-1] for s, _ in parts]
-    return jnp.split(w, [sum(widths[:i]) for i in range(1, len(widths))],
-                     axis=-1)
+def _softmax_over(sc, may, dtype):
+    """Softmax of scores sc (..., T) f32 over the places `may` allows:
+    weights in `dtype`, 0 elsewhere."""
+    w = jax.nn.softmax(jnp.where(may, sc, -1e30), axis=-1)
+    return jnp.where(may, w, 0.0).astype(dtype)
 
 
 def _gathered(pool, head, pool_blk, dtype):
@@ -308,72 +320,78 @@ def sparse_attend_rows(q, qpos, tables, slots, li, k_pool, v_pool, c_pool,
     may = jnp.repeat(valid, bs, axis=-1) & (tok <= qpos[:, None, None])
     att = jnp.einsum("ngrd,ngtd->ngrt", qg, kc,
                      preferred_element_type=_F32) * hd ** -0.5
-    w, = _softmax_over([(att, may[:, :, None, :])], dtype)
+    w = _softmax_over(att, may[:, :, None, :], dtype)
     o = jnp.einsum("ngrt,ngtd->ngrd", w, vc, preferred_element_type=_F32)
     return o.reshape(n, nh, hd).astype(dtype)
 
 
-def sparse_attend_tile(q, qpos, tab, ck, li, k_pool, v_pool,
-                       *, spec: LMSpec, dtype):
-    """Layer `li`'s attention of a tile of a chunk: queries q (N, H, hd)
-    at consecutive positions qpos (N,) of one sequence, through its
-    table `tab` (MB,) and its compressed keys ck (G, MB, hd). The blocks
-    every query is forced to (the first
-    `sel_init` and its window) lie in a row of the table that the
-    tile's queries share but for its ends: they are read once for the
-    tile and attended densely behind each query's own mask; only the
-    blocks a query chose by score (`select_chosen`) are gathered for
-    it. Returns o (N, H, hd) in `dtype`."""
-    n, nh, hd = q.shape
-    g = spec.n_kv
+def live_tiles(pos0, c: int, slots: int, tile: int):
+    """Context tiles of `tile` slots that hold what a chunk of `c`
+    queries at `pos0` may attend, under a table of `slots` slots: the
+    walk's trip count, the smaller of the two in arithmetic that the
+    host's ints and the program's traced `pos0` both take."""
+    n, cap = -(-(pos0 + c) // tile), -(-slots // tile)
+    return n - (n > cap) * (n - cap)
+
+
+def sparse_attend_walk(q, qpos, mask, tab, n_tiles, li, k_pool, v_pool,
+                       *, spec: LMSpec, dtype, fused: bool, tile: int):
+    """Layer `li`'s attention of a whole chunk: queries q (C, H, hd) at
+    positions qpos (C,) of one sequence, each attending the selection
+    blocks mask (C, G, NB) names (`attended_mask`), causally. The live
+    context is walked a tile of `tile` slots at a time, `n_tiles` of
+    them (a traced count): a tile's K and V are read once
+    through the table `tab` (MB,) for all queries, a KV head from its
+    own pool layer, the tile's block bits of a query are spread over its
+    slots behind the query's position, and an online softmax's carry is
+    updated under that mask: by `pallas_ops.selected_block_update` where
+    `fused` (`sparse_moe.fused_attend`), a call a KV head since heads
+    select apart, else by `sparse_moe.attend_plain`. Nothing a query
+    wide is gathered. Returns o (C, H, hd) in `dtype`."""
+    c, nh, hd = q.shape
+    g, sb = spec.n_kv, spec.sel_block
+    grp = nh // g
     bs = k_pool.shape[2]
-    sb = spec.sel_block
-    per = sb // bs
-    wb = spec.sel_window // sb
-    qg = q.reshape(n, g, nh // g, hd)
+    if tile % bs or tile % sb:
+        raise ValueError(f"block_size {bs} and sel_block {sb} have to "
+                         f"divide the context tile of {tile} slots")
+    nb_t, sb_t = tile // bs, tile // sb
+    max_tiles = -(-tab.shape[0] // nb_t)
+    # the table's tail past max_blocks reads block 0: the scratch block
+    tab = jnp.pad(tab, (0, max_tiles * nb_t - tab.shape[0]))
+    mask = jnp.pad(mask.transpose(1, 0, 2),
+                   ((0, 0), (0, 0), (0, max_tiles * sb_t - mask.shape[2])))
     head = _head_layers(li, spec)
-    score = _score_blocks(qg, qpos, ck, spec)                   # (N, G, NB)
-    # the table by selection block, with room for the window's slice
-    width = wb + (n - 1) // sb + 1
-    rows = -(-tab.shape[0] // per)
-    tab_sb = jnp.pad(tab, (0, (rows + width) * per - tab.shape[0])) \
-        .reshape(rows + width, per)
-    own = qpos // sb
-    first = jnp.maximum(own[0] - (wb - 1), 0)
-    shared_blk = jnp.concatenate([
-        tab_sb[:spec.sel_init].reshape(-1),
-        jax.lax.dynamic_slice_in_dim(tab_sb, first, width).reshape(-1)])
-    tok = jnp.concatenate([jnp.arange(spec.sel_init * sb),
-                           first * sb + jnp.arange(width * sb)])
-    in_window = (tok // sb)[None, :] > (own - wb)[:, None]       # (N, T)
-    # an early block inside the window counts once, as the window's
-    forced = jnp.where(jnp.arange(tok.shape[0]) < spec.sel_init * sb,
-                       ~in_window, in_window)
-    may = forced & (tok[None, :] <= qpos[:, None])
-    ks = k_pool[head[:, None], shared_blk[None, :]].astype(dtype)
-    vs = v_pool[head[:, None], shared_blk[None, :]].astype(dtype)
-    ks, vs = ks.reshape(g, -1, hd), vs.reshape(g, -1, hd)
-    parts = [(jnp.einsum("ngrd,gtd->ngrt", qg, ks,
-                         preferred_element_type=_F32) * hd ** -0.5,
-              may[:, None, None, :])]
-    if spec.sel_topk > spec.sel_init + wb:
-        chosen, cvalid = select_chosen(score, qpos, spec)
-        pool_blk = jnp.where(cvalid[..., None], tab_sb[chosen], 0)
-        pool_blk = pool_blk.reshape(n, g, -1)
-        kc = _gathered(k_pool, head, pool_blk, dtype)
-        vc = _gathered(v_pool, head, pool_blk, dtype)
-        ctok = (chosen[..., None] * sb + jnp.arange(sb)).reshape(n, g, -1)
-        cmay = jnp.repeat(cvalid, sb, axis=-1) & (
-            ctok <= qpos[:, None, None])
-        parts.append((jnp.einsum("ngrd,ngtd->ngrt", qg, kc,
-                                 preferred_element_type=_F32) * hd ** -0.5,
-                      cmay[:, :, None, :]))
-    w = _softmax_over(parts, dtype)
-    o = jnp.einsum("ngrt,gtd->ngrd", w[0], vs, preferred_element_type=_F32)
-    if len(w) > 1:
-        o = o + jnp.einsum("ngrt,ngtd->ngrd", w[1], vc,
-                           preferred_element_type=_F32)
-    return o.reshape(n, nh, hd).astype(dtype)
+    qg = q.reshape(c, g, 1, grp, hd)
+    # a KV head's queries as its update takes them: made once
+    qs = [qg[:, i].transpose(1, 2, 0, 3) if fused else qg[:, i]
+          for i in range(g)]
+    slot = jnp.arange(tile)
+    # selection keys of 1 and 0 under a threshold of 0 with no tie taken
+    none, no_tie = jnp.zeros((c,), jnp.uint32), jnp.full((c,), -1, jnp.int32)
+
+    def attend_tile(j, state):
+        bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
+        kt = k_pool[head[:, None], bl[None, :]].astype(dtype)
+        vt = v_pool[head[:, None], bl[None, :]].astype(dtype)
+        kt, vt = kt.reshape(g, tile, 1, hd), vt.reshape(g, tile, 1, hd)
+        on = jax.lax.dynamic_slice_in_dim(mask, j * sb_t, sb_t, 2)
+        on = jnp.repeat(on, sb, axis=2) & (
+            (j * tile + slot)[None, :] <= qpos[:, None])[None]
+        keys = on.astype(jnp.uint32)                        # (G, C, tile)
+        if fused:
+            return tuple(pallas_ops.selected_block_update(
+                qs[i], kt[i], vt[i], keys[i], none, no_tie, 0, *state[i],
+                block_q=sparse_moe._FUSED_Q_BLOCK) for i in range(g))
+        return tuple(sparse_moe.attend_plain(
+            qs[i], kt[i], vt[i], keys[i], none, no_tie, 0, state[i])
+            for i in range(g))
+
+    state = jax.lax.fori_loop(0, n_tiles, attend_tile, tuple(
+        (jnp.full((1, grp, c), -1e30, _F32), jnp.zeros((1, grp, c), _F32),
+         jnp.zeros((1, grp, c, hd), _F32)) for _ in range(g)))
+    att = jnp.concatenate([acc / l[..., None] for _, l, acc in state])
+    return att.transpose(2, 0, 1, 3).reshape(c, nh, hd).astype(dtype)
 
 
 def _mlp(blk, x, spec: LMSpec, dtype):
@@ -503,9 +521,11 @@ def _write_chunk(pool, head, blk_idx, blk_off, x, by_block: bool):
             p, x[i], (head[i % g], first[i // g], 0, 0, 0))), pool)
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "dtype", "by_block"))
+@functools.partial(jax.jit, static_argnames=("spec", "dtype", "by_block",
+                                             "fused", "tile"))
 def _chunk_sparse(blk, x, li, pos, last_pos, blk_idx, blk_off, tab, slot,
-                  k_pool, v_pool, c_pool, *, spec, dtype, by_block):
+                  k_pool, v_pool, c_pool, *, spec, dtype, by_block, fused,
+                  tile):
     c = x.shape[0]
     bs = k_pool.shape[2]
     h = rmsnorm(x, blk["ln1"].astype(dtype))
@@ -531,15 +551,21 @@ def _chunk_sparse(blk, x, li, pos, last_pos, blk_idx, blk_off, tab, slot,
                        entry[None, :]].set(ckey.astype(c_pool.dtype),
                                            mode="drop")
     ck = c_pool[head, slot].astype(dtype)                     # (G, MB, hd)
-    tile = min(_Q_TILE, c)
+    n_q = min(_Q_TILE, c)
 
-    def attend(xs):
+    # the scoring and the cut `n_q` queries at a time (their scores over
+    # the compressed keys are (n_q, G, R, MB) float32)
+    def select(xs):
         qt, pt = xs
-        return sparse_attend_tile(qt, pt, tab, ck, li, k_pool, v_pool,
-                                  spec=spec, dtype=dtype)
+        qg = qt.reshape(n_q, spec.n_kv, -1, qt.shape[-1])
+        return attended_mask(_score_blocks(qg, pt, ck, spec), pt, spec)
 
-    o = jax.lax.map(attend, (q.reshape((c // tile, tile) + q.shape[1:]),
-                             pos.reshape(c // tile, tile)))
+    mask = jax.lax.map(select, (q.reshape((c // n_q, n_q) + q.shape[1:]),
+                                pos.reshape(c // n_q, n_q)))
+    o = sparse_attend_walk(
+        q, pos, mask.reshape((c,) + mask.shape[2:]), tab,
+        live_tiles(pos[0], c, tab.shape[0] * bs, tile), li, k_pool, v_pool,
+        spec=spec, dtype=dtype, fused=fused, tile=tile)
     x = _gated_out(blk, x, h, o, spec, dtype)
     return _mlp(blk, x, spec, dtype), k_pool, v_pool, c_pool
 
@@ -547,13 +573,17 @@ def _chunk_sparse(blk, x, li, pos, last_pos, blk_idx, blk_off, tab, slot,
 def hybrid_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table, slot,
                          k_pool, v_pool, c_pool, s_pool, last_idx,
                          *, spec: LMSpec, dtype=jnp.float32,
-                         by_block: bool = False):
+                         by_block: bool = False, fused: bool = False,
+                         tile: int = sparse_moe._CTX_TILE):
     """One prompt chunk of one sequence: the arguments of
     `paged_prefill_chunk` with the sequence's state slot after its table
     and the compressed-key and state pools after K and V. A chunk at
     ``pos0 == 0`` starts the sequence's states from zero. `by_block`
     (static): the caller vouches that `pos0` and the chunk's width are
-    multiples of the block size (`_write_chunk`). Returns (last real
+    multiples of the block size (`_write_chunk`). `tile` (static): the
+    context slots the sparse layers' walk covers an iteration; `fused`
+    (static): it updates a tile in one kernel, and the caller asks
+    `sparse_moe.fused_attend` whether it may. Returns (last real
     token's logits (vocab,) f32, k_pool, v_pool, c_pool, s_pool)."""
     c = ids.shape[1]
     pos = pos0 + jnp.arange(c)
@@ -568,6 +598,6 @@ def hybrid_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table, slot,
             x, k_pool, v_pool, c_pool = _chunk_sparse(
                 blk, x, li, pos, pos0 + last_idx, blk_idx, blk_off, table,
                 slot, k_pool, v_pool, c_pool, spec=spec, dtype=dtype,
-                by_block=by_block)
+                by_block=by_block, fused=fused, tile=tile)
     logits = _finish(params, x[last_idx, 0][None, :], spec, dtype)[0]
     return logits, k_pool, v_pool, c_pool, s_pool

@@ -16,7 +16,7 @@ import tiny_hybrid as tiny                                      # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
-from nnstreamer_tpu.llm import hybrid_lm                        # noqa: E402
+from nnstreamer_tpu.llm import hybrid_lm, sparse_moe            # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.paged_cache import (                    # noqa: E402
     BlockAllocator, PagedKVCache)
@@ -331,6 +331,31 @@ def test_chunks_ride_every_nth_step_beside_live_rows(bundle, params, every):
     assert eng.stats()["chunk_every"] == every
 
 
+def test_the_decode_bucket_of_the_rows_admitted_is_built_at_admission(
+        bundle, params):
+    """Four prompts of three chunks each and three tokens out, a chunk
+    every fourth step: admitted together, never more than two live
+    together. The bucket of four rows is built at the step that admits
+    them, before any has joined; the tokens are the reference's."""
+    eng = _engine(bundle, max_batch=4, chunk_every=4)
+    prompts = [_prompt(21, seed=20 + i) for i in range(4)]
+    reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+
+    def built():
+        return {key[2] for key in eng.executor._jits if key[1] == "decode"}
+
+    eng.step()
+    assert len(eng.prefilling) == 4 and not eng.active
+    assert built() == {4}
+    live = 0
+    while eng.has_work:
+        eng.step()
+        live = max(live, len(eng.active))
+    assert live < 3 and built() == {1, 2, 4}
+    for r, p in zip(reqs, prompts):
+        assert list(r.tokens) == _greedy(params, p, 3)
+
+
 def test_chunk_every_below_one_is_refused(bundle):
     with pytest.raises(BackendError, match="chunk_every"):
         _engine(bundle, chunk_every=0)
@@ -338,7 +363,8 @@ def test_chunk_every_below_one_is_refused(bundle):
 
 def test_counts_of_a_step_are_the_references(bundle, params):
     """What a decode step's span says it attended is what the reference
-    attends at that position."""
+    attends at that position; a chunk's too, and its `kv_slots` are what
+    its walk reads."""
     taps = {}
     ids = _prompt(41, seed=8)
     ref.forward_logits(params, CFG, ids, q_block=8, taps=taps)
@@ -355,10 +381,10 @@ def test_counts_of_a_step_are_the_references(bundle, params):
     chunk = ps.note_chunk(16, 8, 8)
     assert chunk["kv_selected"] == taps["attended"][0, 16:24, 0].sum()
     assert chunk["pos0"] == 16 and chunk["state_rows"] == 1
-    # one tile of 8 queries: the forced run once (the first block, the
-    # window's one and the tile's own: 3 blocks of 8), and the one block
-    # each query chose
-    assert chunk["kv_slots"] == 8 * 3 + 8 * 8 * 1
+    # the one live context tile, read once for all eight queries (the
+    # table's 64 slots and the scratch block past them)
+    assert chunk["ctx_tiles"] == 1
+    assert chunk["kv_slots"] == sparse_moe._CTX_TILE
     assert ps.counters["kv_slots_read"] == read + chunk["kv_slots"]
     assert ps.note_chunk(16, 8, 8) == chunk            # reckoned once
 

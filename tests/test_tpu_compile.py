@@ -8,13 +8,18 @@ of several loads the TPU's library; docs in the on-chip-measurement
 guide), and every such compile lives in this one file.
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from nnstreamer_tpu.backends import pallas_ops
-from nnstreamer_tpu.llm import sparse_moe
+from nnstreamer_tpu.llm import hybrid_lm, sparse_moe
+from perfbench.references import hybrid_lm as ref
+from perfbench.runners.hybrid_llm import lm_spec
 
 
 @pytest.fixture(scope="module")
@@ -29,10 +34,15 @@ def one_chip():
 
 
 # the Keye cell's chunk (keye-vl-2.0-30b-a3b-6l: 4 KV heads of 8 query
-# heads of 128, chunks of 2,048, a context of 33 tiles) and a short bucket
-@pytest.mark.parametrize("c", [2048, 64])
-def test_selected_block_update_compiles_at_the_cells_sizes(one_chip, c):
-    nkv, grp, hd, tile, s_pad = 4, 8, 128, sparse_moe._CTX_TILE, 33792
+# heads of 128, chunks of 2,048, a context of 33 tiles) and a short bucket;
+# the SALA cell's (minicpm-sala-8l: a call a KV head of 16 query heads, the
+# keys one tile wide: `hybrid_lm.sparse_attend_walk`)
+@pytest.mark.parametrize("nkv,grp,c,s_pad", [
+    (4, 8, 2048, 33792), (4, 8, 64, 33792), (1, 16, 2048, 1024),
+    (1, 16, 64, 1024)], ids=["keye-2048", "keye-64", "sala-2048", "sala-64"])
+def test_selected_block_update_compiles_at_the_cells_sizes(one_chip, nkv,
+                                                           grp, c, s_pad):
+    hd, tile = 128, sparse_moe._CTX_TILE
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -55,3 +65,45 @@ def test_selected_block_update_compiles_at_the_cells_sizes(one_chip, c):
     # size, (heads, C, tile), is kept outside the kernel
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < nkv * grp * c * tile
+
+
+def test_the_hybrid_chunks_walk_gathers_nothing_a_query_wide(one_chip,
+                                                             monkeypatch):
+    """One sparse layer of the SALA cell's chunk (minicpm-sala-8l: 2,048
+    queries, 32 query / 2 KV heads of 128, 64 blocks of 64 a query, a
+    table of 4,160 blocks of 16) holds no per-query gathered K or V (a
+    query tile's 31 chosen blocks were (128, 2, 31 x 64, 128) before the
+    walk) and no score over tokens, and updates a tile in the kernel, a
+    call a KV head."""
+    # the kernel for Mosaic, not interpreted (the backend here is the CPU)
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "minicpm-sala-8l.json")) as f:
+        cfg = json.load(f)
+    spec = lm_spec(cfg)
+    c, mb, bs, nblk, bf = 2048, 4160, 16, 8320, jnp.bfloat16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = jax.eval_shape(lambda: ref.make_params(cfg, 1, dtype=bf))
+    blk = jax.tree.map(lambda x: arg(x.shape, x.dtype), blk["blocks"][0])
+    pool = arg((2 * spec.n_kv, nblk, bs, 1, 128), bf)
+    i32 = jnp.int32
+
+    def layer(*a):
+        return hybrid_lm._chunk_sparse(*a, spec=spec, dtype=bf,
+                                       by_block=True, fused=True,
+                                       tile=sparse_moe._CTX_TILE)
+
+    text = jax.jit(layer, donate_argnums=(9, 10, 11)).lower(
+        blk, arg((c, 1, 4096), bf), arg((), i32), arg((c,), i32),
+        arg((), i32), arg((c,), i32), arg((c,), i32), arg((mb,), i32),
+        arg((), i32), pool, pool, arg((4, 33, mb, 128), bf)
+    ).compile().as_text()
+    assert "1984,128]" not in text
+    assert f"[{c},{mb * bs}]" not in text
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == spec.n_kv
