@@ -51,6 +51,22 @@ Host syncs are batched: every prefill/chunk launched in a step returns
 ids together with the first ids of the prefills launched beside it (or,
 in a sampled step, one over the prefills' logits and one over the
 decode batch's).
+
+Every decode launch is accounted for row by row (`stats()["rows"]`,
+always on, integers only): of the `max_batch` rows a launch could have
+carried, each stood under exactly one state: `decode` (in the launch),
+`prefilling` (held by an admitted request whose prompt is not through),
+`retiring` (held at this step's admission, not carried: a last token in
+flight, or released since), or free under the one cause this step's
+admission found: `blocked` (the queue's head is short of KV blocks),
+`blocked_state` (short of a state slot), `unfed` (the engine's queue is
+empty) or `other` (a static batch still running). The states sum to
+`total`, and `total` is `max_batch` x the executor's `decode_steps`.
+A request's stages (`queued_ms`, `prefill_wait_ms`, `prefill_ms`, their
+sum `first_token_ms`, and `inter_token_ms`) are kept for the last
+`STAGE_WINDOW` samples each. Under an active tracer the same account is
+one `llm:<name>:rows` counter event a launch and one `prefill_wait` and
+one `prefill` span a request (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -68,6 +84,21 @@ from nnstreamer_tpu.runtime.sync import device_sync
 from nnstreamer_tpu.runtime.tracing import NULL_TRACER, percentile
 
 log = get_logger("llm.engine")
+
+#: the states of a decode launch's rows (module docstring); the last
+#: four are the causes a free row stands under
+ROW_STATES = ("decode", "prefilling", "retiring",
+              "blocked", "blocked_state", "unfed", "other")
+
+#: a request's stages, as `stats()` names their percentiles (ms)
+STAGES = ("queued_ms", "prefill_wait_ms", "prefill_ms", "first_token_ms",
+          "inter_token_ms")
+
+#: samples a stage keeps: the latest, as the tracer's interlatency
+#: reservoirs. Not the tracer's `_Hist`: its bounds end at 10 s and lie
+#: a factor of 2.15 apart, where a long prompt waits tens of seconds for
+#: its chunk turn and a step's 10 % is what is looked for.
+STAGE_WINDOW = 8192
 
 
 @dataclass
@@ -90,10 +121,15 @@ class LLMRequest:
     state_slot: Optional[int] = None    # where the model keeps a state
     pos: int = 0                        # next cache write position
     t_submit: float = 0.0
+    t_admit: float = 0.0                # rows, blocks (and slot) granted
+    t_prefill0: float = 0.0             # launch of its first chunk / prefill
     t_first: Optional[float] = None
     t_last: float = 0.0
     itl_ms: List[float] = field(default_factory=list)
     ahead: int = 0                      # tokens launched, not yet read back
+    # (engine steps, executor chunk launches) at admission, then at the
+    # first launch: what the stage spans say of `steps` and `chunks`
+    mark: tuple = (0, 0)
     _rng: Any = None
 
     @property
@@ -203,8 +239,14 @@ class LLMEngine:
         # and tokens computed for a row that had stopped on its eos_id
         self.lookahead_steps = 0
         self.lookahead_discarded = 0
-        self._first_ms: List[float] = []
-        self._itl_ms: List[float] = []
+        #: row-steps by state, cumulative (module docstring)
+        self.rows = dict.fromkeys(ROW_STATES + ("total",), 0)
+        # what this step's admission left free, and why
+        self._free_rows = 0
+        self._free_cause = "unfed"
+        # steps in which a prompt waited and `chunk_every` held its chunk
+        self.chunk_deferred_steps = 0
+        self._stage = {k: deque(maxlen=STAGE_WINDOW) for k in STAGES}
 
     # -- submission --------------------------------------------------------
     def submit(self, prompt, *, req_id: Optional[str] = None,
@@ -278,6 +320,8 @@ class LLMEngine:
         #: (req, device logits) for every prefill completed this step
         pending: List[tuple] = []
         self._admit(pending)
+        self._free_rows = (self.max_batch - len(self.active)
+                           - len(self.prefilling) - len(pending))
         if self.prefilling:
             # rows that hold a place and join chunks from now: the decode
             # bucket of all the rows admitted is built here, where fewer
@@ -322,7 +366,8 @@ class LLMEngine:
         if not tr.active:
             return self._admit_queue(pending)
         t0 = time.perf_counter()
-        rows = len(self.active) + len(self.prefilling) + len(pending)
+        prefilling = len(self.prefilling)
+        rows = len(self.active) + prefilling + len(pending)
         queued, blocked = len(self.queue), self.admission_blocked
         blocked_state = self.admission_blocked_state
         self._admit_queue(pending)
@@ -341,14 +386,20 @@ class LLMEngine:
         if self.cache.state_alloc is not None:
             args["state_free"] = self.cache.state_alloc.free
         tr.span("llm", self.name, label, t0, time.perf_counter(),
-                step=self.steps, rows=rows, queued=queued,
-                admitted=admitted,
+                step=self.steps, rows=rows, prefilling=prefilling,
+                queued=queued, admitted=admitted,
                 blocks_free=self.cache.allocator.free, **args)
 
     def _admit_queue(self, pending: List[tuple]) -> None:
+        """Admit from the head of the queue while rows, blocks and
+        state slots allow, and leave in `_free_cause` why a row it
+        left free stands empty."""
         # static A/B mode: the batch forms only from empty, no top-up
         if self.static and (self.active or self.prefilling):
+            self._free_cause = "other"
             return
+        # the loop ends with the queue empty, or with no row left free
+        self._free_cause = "unfed"
         # pending holds this step's already-admitted prefills (they only
         # join active in _finish_pending) — count them against the cap
         while self.queue and (len(self.active) + len(self.prefilling)
@@ -362,14 +413,18 @@ class LLMEngine:
                 # smaller later request instead would starve it
                 if got == "state":
                     self.admission_blocked_state += 1
+                    self._free_cause = "blocked_state"
                 else:
                     self.admission_blocked += 1
+                    self._free_cause = "blocked"
                 return
             blocks, req.state_slot = got
             self.queue.popleft()
+            req.t_admit = time.perf_counter()
+            req.mark = (self.steps, self.executor.chunk_prefills)
             if self.tracer.active:
                 self.tracer.span("llm", self.name, "queued", req.t_submit,
-                                 time.perf_counter(), req=req.req_id)
+                                 req.t_admit, req=req.req_id)
             req.block_table = blocks
             if self.prefill_chunk > 0 and plen > self.prefill_chunk:
                 # long prompt: prefill one chunk per step alongside the
@@ -379,6 +434,8 @@ class LLMEngine:
                 self.prefilling.append(req)
                 continue
             req.state = "active"
+            # a whole prefill is launched where it is admitted
+            self._first_launch(req, req.t_admit)
             logits = self.executor.prefill(
                 req.prompt, blocks, sync=False, req=req.req_id,
                 state_slot=req.state_slot)
@@ -395,10 +452,13 @@ class LLMEngine:
             return
         self._since_chunk += 1
         if self.active and self._since_chunk < self.chunk_every:
+            self.chunk_deferred_steps += 1
             return
         self._since_chunk = 0
         req = self.prefilling[0]
         plen = int(req.prompt.shape[0])
+        if req.pos == 0:
+            self._first_launch(req, time.perf_counter())
         chunk = req.prompt[req.pos:req.pos + self.prefill_chunk]
         from nnstreamer_tpu.backends.xla import _next_pow2
 
@@ -459,6 +519,7 @@ class LLMEngine:
                 if len(r.tokens) + r.ahead < r.max_new_tokens]
         launch = None
         if rows:
+            self._account_rows(len(rows))
             launch = ex.decode(
                 [None if r.ahead else r.tokens[-1] for r in rows],
                 [r.block_table for r in rows], [r.pos for r in rows],
@@ -512,6 +573,7 @@ class LLMEngine:
         live = [r for r in self.active if r.state == "active"]
         if not live:
             return
+        self._account_rows(len(live))
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
             [r.block_table for r in live],
@@ -527,6 +589,40 @@ class LLMEngine:
             self._sample_span(t0, len(live))
 
     # -- helpers -----------------------------------------------------------
+    def _account_rows(self, decode: int) -> None:
+        """One decode launch of `decode` rows: `max_batch` row-steps,
+        each under one state (module docstring). What admission found
+        free stands under its cause; what it found held and the launch
+        does not carry is prefilling or retiring."""
+        acc = self.rows
+        prefilling = len(self.prefilling)
+        free = self._free_rows
+        retiring = self.max_batch - decode - prefilling - free
+        acc["decode"] += decode
+        acc["prefilling"] += prefilling
+        acc["retiring"] += retiring
+        acc[self._free_cause] += free
+        acc["total"] += self.max_batch
+        if self.tracer.active:
+            self.tracer.counter(
+                "llm", self.name, "rows", time.perf_counter(),
+                dict(decode=decode, prefilling=prefilling,
+                     retiring=retiring, free=free),
+                cause=self._free_cause if free else None,
+                queued=len(self.queue), step=self.steps)
+
+    def _first_launch(self, req: LLMRequest, t: float) -> None:
+        """The launch of a request's whole prefill or of its prompt's
+        first chunk, at `t`: the wait for a chunk turn ends here."""
+        req.t_prefill0 = t
+        steps, chunks = req.mark
+        req.mark = (self.steps, self.executor.chunk_prefills)
+        if self.tracer.active:
+            self.tracer.span(
+                "llm", self.name, "prefill_wait", req.t_admit, t,
+                req=req.req_id, steps=self.steps - steps,
+                chunks=self.executor.chunk_prefills - chunks)
+
     def _sample_span(self, t0: float, rows: int) -> None:
         """The host's loop over a step's rows (sampling, token
         bookkeeping, retirement with its block frees), as one span."""
@@ -553,15 +649,25 @@ class LLMEngine:
         now = time.perf_counter()
         if req.t_first is None:
             req.t_first = now
-            self._first_ms.append(req.first_token_ms)
+            stage = self._stage
+            stage["queued_ms"].append((req.t_admit - req.t_submit) * 1e3)
+            stage["prefill_wait_ms"].append(
+                (req.t_prefill0 - req.t_admit) * 1e3)
+            stage["prefill_ms"].append((now - req.t_prefill0) * 1e3)
+            stage["first_token_ms"].append(req.first_token_ms)
             if self.tracer.active:
+                plen, chunk = int(req.prompt.shape[0]), self.prefill_chunk
+                self.tracer.span(
+                    "llm", self.name, "prefill", req.t_prefill0, now,
+                    req=req.req_id, steps=self.steps - req.mark[0],
+                    chunks=-(-plen // chunk) if 0 < chunk < plen else 1)
                 self.tracer.instant(
                     self.name, "first_token", t=now, req=req.req_id,
                     ms=round(req.first_token_ms, 3))
         else:
             itl = (now - req.t_last) * 1e3
             req.itl_ms.append(itl)
-            self._itl_ms.append(itl)
+            self._stage["inter_token_ms"].append(itl)
         req.t_last = now
         req.tokens.append(tok)
         self.tokens_out += 1
@@ -601,8 +707,6 @@ class LLMEngine:
         self.active.remove(req)
 
     def stats(self) -> dict:
-        first = sorted(self._first_ms)
-        itl = sorted(self._itl_ms)
         out = {
             "submitted": self.submitted,
             "finished": self.finished,
@@ -613,6 +717,8 @@ class LLMEngine:
             "steps": self.steps,
             "admission_blocked": self.admission_blocked,
             "admission_blocked_state": self.admission_blocked_state,
+            "rows": dict(self.rows),
+            "chunk_deferred_steps": self.chunk_deferred_steps,
             "scheduling": "static" if self.static else "continuous",
             "prefill_chunk": self.prefill_chunk,
             "chunk_every": self.chunk_every,
@@ -621,14 +727,9 @@ class LLMEngine:
             "cache": self.cache.stats(),
             "executor": self.executor.stats(),
         }
-        if first:
-            out["first_token_ms"] = {
-                "p50": round(percentile(first, 50), 3),
-                "p95": round(percentile(first, 95), 3),
-                "p99": round(percentile(first, 99), 3)}
-        if itl:
-            out["inter_token_ms"] = {
-                "p50": round(percentile(itl, 50), 3),
-                "p95": round(percentile(itl, 95), 3),
-                "p99": round(percentile(itl, 99), 3)}
+        for key, window in self._stage.items():
+            if window:
+                vals = sorted(window)
+                out[key] = {f"p{p}": round(percentile(vals, p), 3)
+                            for p in (50, 95, 99)}
         return out

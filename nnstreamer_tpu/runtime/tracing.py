@@ -294,6 +294,9 @@ class NullTracer:
     def span(self, cat, name, label, t0, t1, **args):
         pass
 
+    def counter(self, cat, name, label, t, values, **args):
+        pass
+
     def backend_span(self, name, kind, t0, t1, **args):
         pass
 
@@ -525,6 +528,15 @@ class Tracer:
         lists the labels metrics read)."""
         self._append("X", cat, name, label, t0, t1 - t0, args or None)
 
+    def counter(self, cat: str, name: str, label: str, t: float,
+                values: Dict[str, float], **args) -> None:
+        """One "C" sample ``cat:name:label`` at `t` whose value is a
+        dict: `values` are the series of one counter track (a Chrome
+        trace stacks them), `args` ride beside them in the ring only.
+        `span`'s sibling, behind ``if tracer.active:`` like it."""
+        self._append("C", cat, name, label, t, 0.0,
+                     dict(args, values=values))
+
     def backend_span(self, name: str, kind: str, t0: float, t1: float,
                      **args) -> None:
         """Backend-side span (compile/invoke) attributed to the owning
@@ -573,7 +585,7 @@ class Tracer:
             self._llm_requests_dropped += self._max_requests // 4
         self._llm_requests.append((name, req_id, t, dict(args)))
         self._append("i", "llm", name, "llm_request", t, 0.0,
-                     dict(args, req_id=req_id))
+                     dict(args, req_id=req_id, req=req_id))
 
     def llm_requests(self) -> List[Tuple[str, str, float, dict]]:
         return list(self._llm_requests)
@@ -1116,6 +1128,12 @@ class Tracer:
                           "ts": us, "dur": round(dur * 1e6, 3)}
                     if args:
                         ev["args"] = dict(args)
+                elif ph == "C" and isinstance(args, dict):
+                    # `counter`: a track of its own, one series a value
+                    ev = {"ph": "C", "cat": cat,
+                          "name": f"{label}:{name}",
+                          "pid": pid, "tid": 0, "ts": us,
+                          "args": dict(args["values"])}
                 elif ph == "C":
                     track = "inflight" if cat == "inflight" else "queue"
                     ev = {"ph": "C", "cat": cat,

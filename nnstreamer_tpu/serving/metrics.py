@@ -568,6 +568,23 @@ def metrics_snapshot(tracer=None, admission: Optional[dict] = None,
             "requests mid chunked-prefill right now",
             [({"element": el}, float(st.get("prefilling", 0)))
              for el, st, _ in rows]))
+        out.append(_series(
+            f"{ns}_llm_row_steps_total", "counter",
+            "rows of max_batch over every decode launch, by what each "
+            "did: decode, prefilling or retiring, or free and blocked "
+            "(short of KV blocks), blocked_state, unfed (nothing "
+            "queued) or other; the states' rates sum to max_batch x "
+            "the launch rate",
+            [({"element": el, "state": k}, float(v))
+             for el, st, _ in rows
+             for k, v in st.get("rows", {}).items() if k != "total"]
+            or [({"element": "none", "state": "none"}, 0.0)]))
+        out.append(_series(
+            f"{ns}_llm_chunk_deferred_steps_total", "counter",
+            "steps in which a prompt waited and chunk_every held its "
+            "chunk back",
+            [({"element": el}, float(st.get("chunk_deferred_steps", 0)))
+             for el, st, _ in rows]))
 
     if devprof:
         jit = devprof.get("jit", [])
@@ -882,6 +899,9 @@ _TOP_KEY_FAMILIES = (
     # rate = which attention path is hot, prefilling = admission wave
     "nns_llm_tokens_total", "nns_llm_kernel_invokes_total",
     "nns_llm_prefilling",
+    # rows of every decode launch by state: the decode rate over the
+    # sum is the batch's fill, the rest says why rows stood empty
+    "nns_llm_row_steps_total",
     # device performance plane (runtime/devprof.py): MFU and HBM
     # headroom answer "how close to the hardware" at a glance
     "nns_invoke_mfu", "nns_invoke_seconds_total",

@@ -322,6 +322,62 @@ class TestLoopAndShmExposition:
         assert by_cause == {"eos": 1.0, "shape": 2.0}
 
 
+class TestLLMRowExposition:
+    """ISSUE 37: the engine's account of every decode launch's rows, as
+    `extra_stats()` carries it, is one counter family by state."""
+
+    @staticmethod
+    def _llm(decode=620, unfed=100):
+        return {"llm": {
+            "tokens_out": 700, "finished": 9, "prefilling": 2,
+            "chunk_deferred_steps": 33,
+            "rows": {"decode": decode, "prefilling": 240, "retiring": 1,
+                     "blocked": 30, "blocked_state": 9, "unfed": unfed,
+                     "other": 0, "total": decode + unfed + 280},
+            "executor": {"kernel_invokes": {"xla": 125}, "paged_kernel":
+                         "xla", "chunk_prefills": 11}}}
+
+    def test_row_steps_round_trip_by_state(self):
+        llm = self._llm()
+        parsed = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=llm)))
+        fam = parsed["nns_llm_row_steps_total"]
+        assert fam["type"] == "counter" and fam.get("help")
+        by_state = {re.search(r'state="([^"]+)"', k).group(1): v
+                    for k, v in fam["samples"].items()}
+        rows = llm["llm"]["rows"]
+        assert by_state == {k: float(v) for k, v in rows.items()
+                            if k != "total"}
+        assert all('element="llm"' in k for k in fam["samples"])
+        # the states are the whole of it: no series for the total
+        assert sum(by_state.values()) == rows["total"]
+        held = parsed["nns_llm_chunk_deferred_steps_total"]
+        assert held["type"] == "counter"
+        assert held["samples"][
+            'nns_llm_chunk_deferred_steps_total{element="llm"}'] == 33.0
+
+    def test_an_engine_without_the_account_exports_the_family_empty(self):
+        old = self._llm()
+        del old["llm"]["rows"], old["llm"]["chunk_deferred_steps"]
+        parsed = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=old)))
+        assert list(parsed["nns_llm_row_steps_total"]["samples"].values()
+                    ) == [0.0]
+        assert parsed["nns_llm_chunk_deferred_steps_total"]["samples"][
+            'nns_llm_chunk_deferred_steps_total{element="llm"}'] == 0.0
+
+    def test_the_top_view_rates_the_states(self):
+        prev = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=self._llm())))
+        cur = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=self._llm(decode=1240, unfed=120))))
+        lines = top_table(prev, cur, 2.0)
+        mine = [ln for ln in lines if "nns_llm_row_steps_total" in ln]
+        assert len(mine) == 7
+        decode = next(ln for ln in mine if 'state="decode"' in ln)
+        assert decode.split()[-1] == "310.0"
+
+
 def _sharded_replicas(invokes=(6, 4), fenced=None):
     """Synthetic ShardedReplicaSet.stats() — the shape placement's
     ReplicaSet emits plus the shard-group keys sharding.py adds."""
