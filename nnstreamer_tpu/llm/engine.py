@@ -5,7 +5,24 @@ and batch slots allow (each admission runs a bucketed prefill and
 yields its first token), then run ONE decode step for every in-flight
 sequence — freshly admitted requests merge into the same decode batch
 that step, and finished sequences retire immediately, returning their
-blocks to the pool. Contrast `static_batching=True`, the A/B baseline:
+blocks to the pool.
+
+A sequence holds the blocks of what it has written, not of its whole
+life: those of its prompt and first decode write at admission, one more
+before the launch whose write position reaches the end of its table
+(`_grow`). Admission looks ahead instead of at the free list: every row
+of a launch advances one position, and a row's remaining launches are
+known (its budget less its tokens out and in flight; an `eos_id` only
+shortens them), so the blocks the admitted rows will hold at each later
+launch are known too, a prompt still chunk-prefilling counted at its
+whole life. The head of the queue is admitted iff the peak of that
+demand, with it included, fits the pool (`paged_cache.peak_demand`). A
+growth grant therefore cannot fail (the cache raises if one does), no
+row is ever preempted, and since no term exceeds the row's whole life
+no request waits longer than under whole-life reservation. FIFO and
+head-of-line order are unchanged.
+
+Contrast `static_batching=True`, the A/B baseline:
 a batch admits only while the engine is empty and runs to full
 completion, so one long request holds the whole batch hostage (exactly
 the head-of-line blocking continuous batching removes — bench family
@@ -80,6 +97,7 @@ import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
 from nnstreamer_tpu.core.log import get_logger
+from nnstreamer_tpu.llm.paged_cache import peak_demand
 from nnstreamer_tpu.runtime.sync import device_sync
 from nnstreamer_tpu.runtime.tracing import NULL_TRACER, percentile
 
@@ -255,8 +273,9 @@ class LLMEngine:
                eos_id: Optional[int] = None,
                pts: Optional[int] = None) -> LLMRequest:
         """Queue a request. Rejects (raises) only what can NEVER be
-        served — a prompt+budget exceeding per-sequence table capacity;
-        a merely-full pool queues instead."""
+        served — a prompt+budget exceeding per-sequence table capacity,
+        or the pool alone; a pool whose admitted rows' peak demand
+        leaves no room yet queues instead."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.shape[0] == 0:
             raise BackendError("llm request needs a non-empty prompt")
@@ -406,8 +425,11 @@ class LLMEngine:
                               + len(pending) < self.max_batch):
             req = self.queue[0]
             plen = int(req.prompt.shape[0])
-            need = self.cache.blocks_for(plen + req.max_new_tokens)
-            got = self.cache.reserve(need, owner=req.req_id)
+            # the prompt's blocks and the first decode write's; the rest
+            # as it grows, which the peak says the pool can give
+            got = self.cache.reserve(
+                self.cache.blocks_for(plen + 1), owner=req.req_id,
+                peak=self._peak_with(req, [r for r, _ in pending]))
             if isinstance(got, str):
                 # head-of-line waits for retirements; admitting a
                 # smaller later request instead would starve it
@@ -426,7 +448,7 @@ class LLMEngine:
                 self.tracer.span("llm", self.name, "queued", req.t_submit,
                                  req.t_admit, req=req.req_id)
             req.block_table = blocks
-            if self.prefill_chunk > 0 and plen > self.prefill_chunk:
+            if self._chunked(plen):
                 # long prompt: prefill one chunk per step alongside the
                 # decode batch instead of head-of-line blocking it
                 req.state = "prefilling"
@@ -441,6 +463,37 @@ class LLMEngine:
                 state_slot=req.state_slot)
             req.pos = plen
             pending.append((req, logits))
+
+    def _peak_with(self, req: LLMRequest, pending: List[LLMRequest]) -> int:
+        """The most blocks the admitted rows and `req` will hold
+        together (module docstring). A row's launches left count its
+        tokens in flight; a row this step admitted, like `req`, has its
+        first token still to come from its prefill and is counted one
+        launch long. A prompt that is not through, `req`'s too if it
+        will chunk, holds its whole life throughout."""
+        rows = [(r.pos, r.max_new_tokens - len(r.tokens) - r.ahead)
+                for r in self.active + pending]
+        whole = list(self.prefilling)
+        plen = int(req.prompt.shape[0])
+        if self._chunked(plen):
+            whole.append(req)
+        else:
+            rows.append((plen, req.max_new_tokens))
+        return peak_demand(rows, self.cache.block_size, held=sum(
+            self.cache.blocks_for(int(r.prompt.shape[0]) + r.max_new_tokens)
+            for r in whole))
+
+    def _chunked(self, plen: int) -> bool:
+        """Whether a prompt of `plen` tokens prefills in chunks."""
+        return 0 < self.prefill_chunk < plen
+
+    def _grow(self, rows: List[LLMRequest]) -> None:
+        """Before a decode launch: a block more for every row of it
+        whose write position has reached the end of its table."""
+        bs = self.cache.block_size
+        for r in rows:
+            if r.pos // bs == len(r.block_table):
+                self.cache.grow(r.block_table, owner=r.req_id)
 
     def _prefill_chunks(self, pending: List[tuple]) -> None:
         """Advance the oldest chunk-prefilling prompt by ONE chunk (the
@@ -520,6 +573,7 @@ class LLMEngine:
         launch = None
         if rows:
             self._account_rows(len(rows))
+            self._grow(rows)
             launch = ex.decode(
                 [None if r.ahead else r.tokens[-1] for r in rows],
                 [r.block_table for r in rows], [r.pos for r in rows],
@@ -574,6 +628,7 @@ class LLMEngine:
         if not live:
             return
         self._account_rows(len(live))
+        self._grow(live)
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
             [r.block_table for r in live],

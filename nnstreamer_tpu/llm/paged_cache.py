@@ -7,9 +7,19 @@ typical occupancy is a fraction of that. Paging (vLLM's PagedAttention
 idea, PAPERS.md) decouples the two: the pool holds `num_blocks` blocks
 of `block_size` token slots each, and every sequence owns an ordered
 per-sequence *block table* mapping its positions onto pool blocks.
-Memory is bounded by the pool, admission is bounded by free blocks, and
-fragmentation is impossible by construction (any free block serves any
-sequence — the table, not adjacency, provides ordering).
+Memory is bounded by the pool, and fragmentation is impossible by
+construction (any free block serves any sequence — the table, not
+adjacency, provides ordering).
+
+A sequence holds the blocks its *written* context needs: those of its
+prompt and of its first decode write at admission (`reserve`), one more
+each time its write position reaches the end of its table (`grow`).
+What bounds admission is therefore not the free list but the future:
+`peak_demand` is the most blocks the admitted sequences will ever hold
+together, each growing a position a decode launch until its budget ends,
+and a sequence is admitted only if that peak, with it included, fits the
+pool. So a growth grant cannot fail, and nothing is ever evicted,
+stalled or recomputed for want of a block.
 
 Block 0 is reserved as the scratch block: padding rows of a bucketed
 decode batch and the padded tail of a bucketed prefill write there, so
@@ -29,6 +39,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from nnstreamer_tpu.core.errors import BackendError
 from nnstreamer_tpu.core.log import get_logger
 
 log = get_logger("llm.cache")
@@ -40,9 +51,13 @@ SCRATCH_BLOCK = 0
 class BlockAllocator:
     """Free-list allocator over the pool's block indices.
 
-    All-or-nothing `alloc(n)`: a request either gets its whole block
-    set or stays queued (None) — partial grants would deadlock two
-    half-admitted requests against each other. Single-threaded by
+    All-or-nothing `alloc(n)`: a grant is whole or refused (None).
+    Sequences are granted their blocks piece by piece as they grow, and
+    two half-grown sequences would deadlock against each other over the
+    last free blocks if admission looked at the free list alone; what
+    prevents it is `PagedKVCache.reserve`, which admits a sequence only
+    if the admitted set's `peak_demand` fits the pool, so every growth
+    grant it will ask for is there when asked. Single-threaded by
     design: the engine owns it from one scheduler thread.
     """
 
@@ -109,6 +124,23 @@ class BlockAllocator:
             "alloc_calls": self.alloc_calls,
             "failed_allocs": self.failed_allocs,
         }
+
+
+def peak_demand(rows, block_size: int, held: int = 0) -> int:
+    """The most blocks a set of admitted sequences will hold together.
+    `rows`: a (pos, k) pair a decoding sequence, its next write position
+    and the decode launches it has left; each advances one position a
+    launch, so at the j-th launch from now it holds the blocks of pos + j
+    slots, and after its k-th it holds none. `held`: blocks counted at
+    every launch, for sequences whose first launch is an unknown number
+    of steps away (their whole lives). The demand only falls where a
+    sequence ends, so the peak is at one of the rows' k."""
+    bs = int(block_size)
+    peak = 0
+    for j in {k for _, k in rows}:
+        peak = max(peak, sum(-(-(pos + j) // bs)
+                             for pos, k in rows if k >= j))
+    return int(held) + peak
 
 
 def idx_pack(block_size: int, idx_dim: int) -> int:
@@ -200,6 +232,9 @@ class PagedKVCache:
             self.k = placer(self.k)
             self.v = placer(self.v)
         self.allocator = BlockAllocator(self.num_blocks)
+        # growth grants, and the largest peak an admission was accepted at
+        self.blocks_grown = 0
+        self.admit_peak = 0
 
     def blocks_for(self, n_tokens: int) -> int:
         """Blocks needed to hold `n_tokens` token slots."""
@@ -226,23 +261,44 @@ class PagedKVCache:
                 setattr(self, name, rest.pop(0))
 
     # -- admission over both kinds of state --------------------------------
-    def reserve(self, n_blocks: int, owner: object = None):
+    def reserve(self, n_blocks: int, owner: object = None, peak: int = 0):
         """`n_blocks` blocks and, where the model keeps a state a
-        sequence, a state slot: both or neither. Returns (blocks, slot),
-        slot None for a model without state, or the name of what it was
-        short of: "blocks" or "state"."""
+        sequence, a state slot: both or neither. `peak` is the
+        `peak_demand` of the admitted sequences with this one among
+        them; the pool is short of blocks if it cannot hold that, however
+        many are free now. Returns (blocks, slot), slot None for a model
+        without state, or the name of what it was short of: "blocks" or
+        "state"."""
         if self.state_alloc is not None and not self.state_alloc.can_alloc(1):
             self.state_alloc.failed_allocs += 1
             return "state"
-        blocks = self.allocator.alloc(n_blocks, owner=owner)
-        if blocks is None:
+        alloc = self.allocator
+        peak = max(int(peak), alloc.used + n_blocks)
+        if peak > alloc.total:
+            alloc.failed_allocs += 1
             return "blocks"
+        self.admit_peak = max(self.admit_peak, peak)
+        blocks = alloc.alloc(n_blocks, owner=owner)
         if self.state_alloc is None:
             return blocks, None
         return blocks, self.state_alloc.alloc(1, owner=owner)[0]
 
+    def grow(self, table: List[int], owner: object = None) -> None:
+        """One more block at the end of a sequence's `table`, for the
+        write position that has reached it. `reserve` admitted the
+        sequence against the peak of such grants, so a refusal here is a
+        fault of that account, not a full pool to wait out."""
+        got = self.allocator.alloc(1, owner=owner)
+        if got is None:
+            raise BackendError(
+                f"paged pool: no block left to grow {owner!r} into, with "
+                f"{self.allocator.used} of {self.allocator.total} live; "
+                f"admission by peak demand should have kept one free")
+        table.extend(got)
+        self.blocks_grown += 1
+
     def release(self, blocks: List[int], slot: Optional[int]) -> None:
-        """Give back what `reserve` granted."""
+        """Give back what `reserve` and `grow` granted."""
         self.allocator.free_blocks(blocks)
         if slot is not None:
             self.state_alloc.free_blocks([slot])
@@ -271,6 +327,9 @@ class PagedKVCache:
         out["tokens_capacity"] = self.tokens_capacity
         out["pools"] = len(self.pools())
         out["block_bytes"] = self.block_bytes
+        out["blocks_grown"] = self.blocks_grown
+        out["admit_peak_blocks"] = self.admit_peak
+        out["blocks_live_high_water"] = self.allocator.high_water
         if self.state_alloc is not None:
             out["state_slots"] = self.state_alloc.total
             out["state_slots_used"] = self.state_alloc.used

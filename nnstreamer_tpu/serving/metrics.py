@@ -579,6 +579,28 @@ def metrics_snapshot(tracer=None, admission: Optional[dict] = None,
              for el, st, _ in rows
              for k, v in st.get("rows", {}).items() if k != "total"]
             or [({"element": "none", "state": "none"}, 0.0)]))
+        # the KV pool's grants (llm/paged_cache.py): a sequence holds
+        # the blocks it has written, admitted against the admitted
+        # set's peak demand
+        caches = [(el, st.get("cache", {})) for el, st, _ in rows]
+        out.append(_series(
+            f"{ns}_llm_blocks_grown_total", "counter",
+            "KV blocks granted to rows as their write position reached "
+            "the end of their tables (beside those of admission)",
+            [({"element": el}, float(c.get("blocks_grown", 0)))
+             for el, c in caches]))
+        out.append(_series(
+            f"{ns}_llm_admit_peak_blocks", "gauge",
+            "the largest peak future demand, in KV blocks, an admission "
+            "was accepted at; the pool's blocks_total bounds it",
+            [({"element": el}, float(c.get("admit_peak_blocks", 0)))
+             for el, c in caches]))
+        out.append(_series(
+            f"{ns}_llm_blocks_live_high_water", "gauge",
+            "the most KV blocks ever live at once (written context, not "
+            "reserved lives)",
+            [({"element": el}, float(c.get("blocks_live_high_water", 0)))
+             for el, c in caches]))
         out.append(_series(
             f"{ns}_llm_chunk_deferred_steps_total", "counter",
             "steps in which a prompt waited and chunk_every held its "

@@ -170,17 +170,24 @@ def _only(rows, **want):
 
 
 def test_a_pool_too_small_reads_blocked(dense):
-    """Seven usable blocks, two a request: three live of four rows, the
-    fourth free behind a queue's head that is short of blocks. Five
-    launches carry the first three requests (one token of six is the
-    prefill's); the other three are admitted into the blocks they give
-    back and leave a row free with nothing queued."""
+    """Seven usable blocks, two a request at its longest: three live of
+    four rows, the fourth free behind a queue's head whose admission
+    would bring the peak to eight. At the fourth step the three have two
+    launches left and the head needs one block until they are gone
+    (peak seven): it is admitted, three steps blocked. The first three
+    end with the fifth launch; the last two requests are admitted into
+    what they give back, and rows stand free with nothing queued in the
+    launches that carry three requests (three) and two (two)."""
     eng = _dense_engine(dense, num_blocks=8, max_len=16)
     for i in range(6):
         eng.submit(np.array([i + 1, i + 2], np.int32), max_new_tokens=6)
     eng.drain()
-    _only(_check_identities(eng), decode=30, blocked=5, unfed=5)
+    _only(_check_identities(eng), decode=30, blocked=3, unfed=7)
     assert eng.executor.stats()["decode_steps"] == 10
+    st = eng.stats()["cache"]
+    assert st["admit_peak_blocks"] == st["blocks_live_high_water"] == 7
+    # a request of 2 + 6 is admitted with one block and grows one
+    assert st["blocks_grown"] == 6
 
 
 def test_one_state_slot_short_reads_blocked_state(hybrid):

@@ -366,6 +366,26 @@ class TestLLMRowExposition:
         assert parsed["nns_llm_chunk_deferred_steps_total"]["samples"][
             'nns_llm_chunk_deferred_steps_total{element="llm"}'] == 0.0
 
+    def test_the_pools_grants_round_trip(self):
+        """ISSUE 38: growth grants as a counter, the largest admitted
+        peak and the most blocks live as gauges; an engine without them
+        exports zeros."""
+        llm = self._llm()
+        llm["llm"]["cache"] = {"blocks_grown": 3911, "admit_peak_blocks":
+                               612, "blocks_live_high_water": 607}
+        parsed = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=llm)))
+        for fam, typ, want in (
+                ("nns_llm_blocks_grown_total", "counter", 3911.0),
+                ("nns_llm_admit_peak_blocks", "gauge", 612.0),
+                ("nns_llm_blocks_live_high_water", "gauge", 607.0)):
+            assert parsed[fam]["type"] == typ and parsed[fam].get("help")
+            assert parsed[fam]["samples"] == {fam + '{element="llm"}': want}
+        old = parse_prometheus(render_prometheus(metrics_snapshot(
+            llm=self._llm())))
+        assert old["nns_llm_blocks_grown_total"]["samples"] == {
+            'nns_llm_blocks_grown_total{element="llm"}': 0.0}
+
     def test_the_top_view_rates_the_states(self):
         prev = parse_prometheus(render_prometheus(metrics_snapshot(
             llm=self._llm())))
