@@ -31,6 +31,15 @@ weights, frames and prompts:
                    blocks and state slots through the same element on the
                    chip serve the tokens the same engine serves on this
                    host's CPU device;
+- ``llm_window_moe`` the window family (llm/window_moe.py) at a tiny size
+                   (hidden 64, a window layer, a full layer and a window
+                   layer of 8 q / 2 kv heads of 16, a window of 8 over
+                   blocks of 4, the first layer dense, then a shared expert
+                   beside 4 held of 8 routed experts top-2 with sigmoid
+                   scores, float32): chunked prefill and decode over two
+                   tables a sequence through the same element on the chip
+                   serve the tokens the same engine serves on this host's
+                   CPU device, with window blocks given back and regranted;
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -194,6 +203,28 @@ def leg_kernels() -> dict:
           lambda q, kp, vp: hybrid_lm.sparse_attend_walk(
               q, qpos, on, tab, 2, 1, kp, vp, spec=hy, dtype=jnp.bfloat16,
               fused=False, tile=tile), 3e-2)
+
+    # the window family's chunk walk at the Trinity cell's head geometry
+    # (8 KV heads of 6 query heads of 128, pool blocks of 64): the same
+    # kernel a tile behind the causal edge and a window of 1,536, tiles 1
+    # to 3 of a table of 4, against the walk by the plain update
+    from nnstreamer_tpu.llm import window_moe
+
+    c = 512
+    tab = jnp.asarray(1 + rng.permutation(95)[:4 * tile // 64], jnp.int32)
+    qpos = jnp.arange(3 * tile - c, 3 * tile)
+    span = window_moe.tile_span(3 * tile - c, c, 4 * tile, tile, 1536)
+    assert span == (1, 3), span
+    check("window_walk_fused",
+          lambda q, kp, vp: window_moe.attend_tiles(
+              q, qpos, tab, span, 1, kp, vp, window=1536, fused=True,
+              tile=tile, dtype=jnp.bfloat16),
+          (normal((c, 48, 128), jnp.bfloat16),
+           normal((2, 96, 64, 8, 128), jnp.bfloat16),
+           normal((2, 96, 64, 8, 128), jnp.bfloat16)),
+          lambda q, kp, vp: window_moe.attend_tiles(
+              q, qpos, tab, span, 1, kp, vp, window=1536, fused=False,
+              tile=tile, dtype=jnp.bfloat16), 3e-2)
 
     # paged kernels at the LLM legs' geometry, MHA and GQA
     hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64
@@ -633,6 +664,111 @@ def leg_llm_hybrid() -> dict:
             "state_bytes_rw": ex["state_bytes_rw"]}
 
 
+# -- the window family ------------------------------------------------------------
+
+WINDOWED = dict(d=64, heads=8, kv=2, hd=16, dense_width=160, width=32,
+                experts=8, first=2, held=4, per_tok=2, vocab=256, window=8,
+                kinds=("window", "full", "window"))
+
+
+def _window_bundle(device):
+    """Seeded float32 weights of the tiny window-family model on
+    `device`, in the family's hand-over layout, with its description."""
+    import jax
+    import numpy as np
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.llm.spec import WINDOW_MOE, LMSpec
+
+    c = WINDOWED
+    rng = np.random.default_rng(17)
+
+    def w(*shape):
+        lim = (6.0 / (shape[-2] + shape[-1])) ** 0.5
+        return rng.uniform(-lim, lim, shape).astype(np.float32)
+
+    ones = lambda n: np.ones((n,), np.float32)          # noqa: E731
+    qw, kw = c["heads"] * c["hd"], c["kv"] * c["hd"]
+
+    def layer(dense):
+        out = {f"ln{i}": ones(c["d"]) for i in (1, 2, 3, 4)}
+        out.update(wqkv=w(c["d"], qw + 2 * kw), q_norm=ones(c["hd"]),
+                   k_norm=ones(c["hd"]), wg=w(c["d"], qw), wo=w(qw, c["d"]))
+        if dense:
+            out.update(wi=w(c["d"], 2 * c["dense_width"]),
+                       wd=w(c["dense_width"], c["d"]))
+            return out
+        out.update(router=w(c["d"], c["experts"]),
+                   router_bias=rng.uniform(-0.02, 0.02, c["experts"])
+                   .astype(np.float32),
+                   ewi=w(c["held"], c["d"], 2 * c["width"]),
+                   ewd=w(c["held"], c["width"], c["d"]),
+                   swi=w(c["d"], 2 * c["width"]), swd=w(c["width"], c["d"]))
+        return out
+
+    params = {"embed": w(c["vocab"], c["d"]),
+              "blocks": [layer(i == 0) for i in range(len(c["kinds"]))],
+              "ln_f": ones(c["d"]), "head": w(c["d"], c["vocab"])}
+    spec = LMSpec(family=WINDOW_MOE, n_heads=c["heads"], n_kv=c["kv"],
+                  head_dim=c["hd"], layer_kinds=c["kinds"],
+                  window=c["window"], dense_layers=1,
+                  dense_width=c["dense_width"],
+                  shared_width=c["width"], n_experts=c["experts"],
+                  experts_per_tok=c["per_tok"], expert_width=c["width"],
+                  score_fn="sigmoid", route_scale=2.448,
+                  experts_first=c["first"], experts_held=c["held"],
+                  emb_scale=8.0, norm_eps=1e-5)
+    return ModelBundle(fn=None, lm=spec, params=jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, device), params))
+
+
+def leg_llm_window_moe() -> dict:
+    """As `leg_llm_sparse_moe`, for the family that keeps two tables a
+    sequence: the full layers' and the window layers', whose blocks
+    behind the window are given back and granted again."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.llm.engine import LLMEngine
+    from nnstreamer_tpu.serving.store import get_store
+
+    rng = np.random.default_rng(7)
+    # prompts inside the window (8), across it and across the chunk (8)
+    prompts = [rng.integers(0, WINDOWED["vocab"], size=n).astype(np.int32)
+               for n in (5, 12, 29, 41)]
+    serving = dict(block_size=4, num_blocks=96, max_len=64, prefill_chunk=8)
+    cpu = jax.devices("cpu")[0]
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        with jax.default_device(cpu):
+            eng = LLMEngine(_window_bundle(cpu), dtype=jnp.float32,
+                            max_batch=8, **serving)
+            reqs = [eng.submit(p, req_id=f"req{i}",
+                               max_new_tokens=LLM_NEW_TOKENS)
+                    for i, p in enumerate(prompts)]
+            eng.drain()
+            want = {r.req_id: list(r.tokens) for r in reqs}
+            eng.executor.close()
+        get_store().register("chip_smoke_window_moe",
+                             _window_bundle(jax.devices()[0]))
+        toks, stats = _run_llm("store://chip_smoke_window_moe", prompts,
+                               dtype="float32", paged_kernel="xla",
+                               **serving)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+    ex, cache = stats["executor"], stats["cache"]
+    assert ex["family"] == "window_moe" and ex["chunk_prefills"] >= 10, ex
+    assert ex["expert_pairs_held"] > 0 and ex["expert_pairs_away"] > 0, ex
+    assert cache["pools"] == 4 and cache["window_blocks_freed"] > 0, cache
+    assert cache["window"]["blocks_used"] == 0, cache
+    assert toks == want, f"greedy tokens differ: chip {toks} vs cpu {want}"
+    return {"requests": len(toks), "tokens": stats["tokens_out"],
+            "chunk_prefills": ex["chunk_prefills"],
+            "window_blocks_freed": cache["window_blocks_freed"]}
+
+
 # -- four chips --------------------------------------------------------------
 
 def leg_multichip(model: str, ref) -> dict:
@@ -752,6 +888,7 @@ def main() -> int:
     leg("llm_pallas", leg_llm, model, "pallas", xla and xla[0])
     leg("llm_sparse_moe", leg_llm_sparse_moe)
     leg("llm_hybrid", leg_llm_hybrid)
+    leg("llm_window_moe", leg_llm_window_moe)
     if dev["count"] >= 4 and ref:
         leg("multichip", leg_multichip, model, ref)
     else:

@@ -165,7 +165,8 @@ class PagedLLMExecutor:
                  max_len: int = 128, paged_kernel: Optional[str] = None,
                  shards: int = 0, shard_chips=None,
                  ring_prefill_min: int = 0, state_slots: int = 0,
-                 tracer=NULL_TRACER, name: str = "llm"):
+                 prefill_chunk: int = 0, tracer=NULL_TRACER,
+                 name: str = "llm"):
         import jax.numpy as jnp
 
         self.name = name
@@ -231,7 +232,10 @@ class PagedLLMExecutor:
             self.spec, name=name, params=self.params, dtype=self.dtype,
             n_heads=self.n_heads, n_kv=self.n_kv, head_dim=self.head_dim,
             block_size=bs, max_blocks=self.max_blocks, kernel=kern,
-            shards=self.shards, shard_fns=self._shard_fns)
+            shards=self.shards, shard_fns=self._shard_fns,
+            # what a family sizes its own pools by: the engine's rows
+            # (`state_slots`) and its prompt chunk
+            rows=int(state_slots), chunk=int(prefill_chunk))
         self._mesh = None
         self._shard_chips: tuple = ()
         self._sparams: Dict[Any, Any] = {}   # vkey → blocked+placed tree
@@ -580,7 +584,8 @@ class PagedLLMExecutor:
     # -- prefill -----------------------------------------------------------
     def prefill(self, prompt: np.ndarray, block_table: List[int],
                 *, sync: bool = True, req: Optional[str] = None,
-                state_slot: Optional[int] = None):
+                state_slot: Optional[int] = None,
+                window_table: Optional[List[int]] = None):
         """One whole prompt; its KV lands in the pool blocks of
         `block_table`. Dispatches between the full-sequence
         `apply_seq_kv` path and the chunk family (the program set's
@@ -590,7 +595,8 @@ class PagedLLMExecutor:
         array so the engine can batch one `device_sync` over a whole
         step's admissions. `req` only labels the call's `invoke` span;
         `state_slot` is the sequence's slot of the state pool, where the
-        family keeps a state a sequence."""
+        family keeps a state a sequence; `window_table` its table of the
+        window layers' pools, where it keeps those."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
@@ -600,7 +606,8 @@ class PagedLLMExecutor:
             ps.check_prompt(plen, 0)
             return self.prefill_chunk(
                 prompt, 0, block_table, bucket=_next_pow2(plen, 8),
-                sync=sync, req=req, state_slot=state_slot)
+                sync=sync, req=req, state_slot=state_slot,
+                window_table=window_table)
         kind = "prefill"
         if self.shards and 0 < self.ring_prefill_min <= plen:
             kind = "ring"    # sequence-parallel long-context cutover
@@ -644,14 +651,17 @@ class PagedLLMExecutor:
     def prefill_chunk(self, chunk: np.ndarray, pos0: int,
                       block_table: List[int], *, bucket: int = 0,
                       sync: bool = True, req: Optional[str] = None,
-                      state_slot: Optional[int] = None):
+                      state_slot: Optional[int] = None,
+                      window_table: Optional[List[int]] = None):
         """One prompt chunk starting at absolute position `pos0`,
         scattered into `block_table`'s blocks and attending the whole
         prefix written so far. `bucket` pins the pad width so every
         chunk of a prompt (the short final one included) hits one
         executable; 0 = pow2 of this chunk. Returns the chunk's
         last-token logits (host when `sync`, device otherwise) — only
-        the final chunk's value is meaningful to sampling."""
+        the final chunk's value is meaningful to sampling. A family
+        with window pools writes the chunk through `window_table` too,
+        whose entries behind the window read the scratch block."""
         from nnstreamer_tpu.backends.xla import _next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
@@ -671,11 +681,20 @@ class PagedLLMExecutor:
         ps = self.programs
         kw = ps.chunk_kw(pos0, c_b)
         slot = np.int32(state_slot or 0)        # none: the scratch slot
+        window = None
+        if self.cache.window_alloc is not None:
+            # the same layout through the window layers' table
+            wtab = np.full((self.max_blocks,), SCRATCH_BLOCK, np.int32)
+            wtab[:len(window_table)] = window_table
+            wblk_idx = np.full((c_b,), SCRATCH_BLOCK, np.int32)
+            wblk_idx[:clen] = wtab[pos // bs]
+            window = (wblk_idx, wtab)
 
         def _run():
             jitted, fresh = self._get_jit("chunk", c_b)
             logits, beside, pools = ps.split(jitted(
-                *ps.chunk_args(*args, self.cache.pools(), slot), **kw))
+                *ps.chunk_args(*args, self.cache.pools(), slot, window),
+                **kw))
             self.cache.set_pools(pools)
             return logits, beside, fresh
 
@@ -707,7 +726,7 @@ class PagedLLMExecutor:
             jitted, _ = self._get_jit("chunk", c_b)
             self._prof_capture(
                 f"chunk:{c_b}", jitted,
-                ps.chunk_args(*args, self.cache.pools(), slot), kw,
+                ps.chunk_args(*args, self.cache.pools(), slot, window), kw,
                 t1 - t0)
         else:
             self._span("invoke", t0, t1, what="llm_prefill_chunk",
@@ -720,7 +739,8 @@ class PagedLLMExecutor:
     # -- decode ------------------------------------------------------------
     def decode(self, cur: List[Optional[int]], tables: List[List[int]],
                pos: List[int], *, sync: bool = True,
-               state_slots: Optional[List[int]] = None):
+               state_slots: Optional[List[int]] = None,
+               window_tables: Optional[List[List[int]]] = None):
         """One decode step for `len(cur)` live rows (bucketed to pow2;
         padding rows write to the scratch block). `cur[i]` is row i's
         last token, or None where the host has not read it: the step
@@ -732,7 +752,8 @@ class PagedLLMExecutor:
         the returned `DecodeLaunch` is read by `resolve` (single-chip
         only). `state_slots[i]` is row i's slot of the state pool, where
         the family keeps a state a sequence (padding rows take the
-        scratch slot)."""
+        scratch slot); `window_tables[i]` its table of the window
+        layers' pools, where it keeps those."""
         import jax
 
         from nnstreamer_tpu.backends.xla import _next_pow2
@@ -757,6 +778,11 @@ class PagedLLMExecutor:
         if state_slots is not None:
             slot_a = np.zeros((b_b,), np.int32)
             slot_a[:n] = state_slots
+        wtab_a = None
+        if window_tables is not None:
+            wtab_a = np.full((b_b, self.max_blocks), SCRATCH_BLOCK, np.int32)
+            for i, t in enumerate(window_tables):
+                wtab_a[i, :len(t)] = t
         ps = self.programs
 
         def _run():
@@ -771,7 +797,7 @@ class PagedLLMExecutor:
                 cur_d = next_ids.llm_last_ids(self.last_ids, tab_d, cur_a)
             logits, beside, pools = ps.split(jitted(*ps.decode_args(
                 self._exec_params("decode"), cur_d, tab_d, pos_a, n,
-                self.cache.pools(), slot_a), **ps.kw))
+                self.cache.pools(), slot_a, wtab_a), **ps.kw))
             self.cache.set_pools(pools)
             if not sync:
                 ids, self.last_ids = next_ids.llm_pick_rows(
@@ -807,7 +833,7 @@ class PagedLLMExecutor:
             self._prof_capture(
                 f"decode:{b_b}", jitted, ps.decode_args(
                     self._exec_params("decode"), cur_a, tab_a, pos_a, n,
-                    self.cache.pools(), slot_a), ps.kw, t1 - t0)
+                    self.cache.pools(), slot_a, wtab_a), ps.kw, t1 - t0)
         elif sync:
             self._span("invoke", t0, t1, **span)
         self.decode_steps += 1
@@ -914,7 +940,7 @@ class PagedLLMExecutor:
 
             def layout():       # no live row, every state the scratch's
                 return ps.decode_args(params, cur, tab, pos, 0, pools(),
-                                      np.zeros((bucket,), np.int32))
+                                      np.zeros((bucket,), np.int32), tab)
         else:
             ids = np.zeros((1, bucket), np.int32)
             blk = np.full((bucket,), SCRATCH_BLOCK, np.int32)
@@ -927,7 +953,7 @@ class PagedLLMExecutor:
 
                 def layout():
                     return ps.chunk_args(params, ids, zero, blk, off, tab,
-                                         zero, pools(), zero)
+                                         zero, pools(), zero, (blk, tab))
             else:
                 def layout():
                     return ps.prefill_args(params, ids, blk, off, zero,

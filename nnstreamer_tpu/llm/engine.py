@@ -22,6 +22,17 @@ row is ever preempted, and since no term exceeds the row's whole life
 no request waits longer than under whole-life reservation. FIFO and
 head-of-line order are unchanged.
 
+A model whose window layers keep pools of their own (llm/window_moe.py)
+gives a request a second table, of those pools, and everything above
+holds in each pool apart: admission is by the admitted rows' peak demand
+in both (a window table's demand capped: `PagedKVCache.window_cap`), a
+table grows before the launch that writes past its end, and the window
+table's blocks wholly behind the window are given back (`_trim`) right
+after the launch that last needed them is dispatched: launches run in
+order on the device, so whoever is granted such a block next writes it
+after that launch has read it. A family with one table takes none of
+these branches.
+
 Contrast `static_batching=True`, the A/B baseline:
 a batch admits only while the engine is empty and runs to full
 completion, so one long request holds the whole batch hostage (exactly
@@ -76,8 +87,8 @@ carried, each stood under exactly one state: `decode` (in the launch),
 `retiring` (held at this step's admission, not carried: a last token in
 flight, or released since), or free under the one cause this step's
 admission found: `blocked` (the queue's head is short of KV blocks),
-`blocked_state` (short of a state slot), `unfed` (the engine's queue is
-empty) or `other` (a static batch still running). The states sum to
+`blocked_state` (short of a state slot), `blocked_window` (short of
+window blocks), `unfed` (the engine's queue is empty) or `other` (a static batch still running). The states sum to
 `total`, and `total` is `max_batch` x the executor's `decode_steps`.
 A request's stages (`queued_ms`, `prefill_wait_ms`, `prefill_ms`, their
 sum `first_token_ms`, and `inter_token_ms`) are kept for the last
@@ -104,9 +115,9 @@ from nnstreamer_tpu.runtime.tracing import NULL_TRACER, percentile
 log = get_logger("llm.engine")
 
 #: the states of a decode launch's rows (module docstring); the last
-#: four are the causes a free row stands under
+#: five are the causes a free row stands under
 ROW_STATES = ("decode", "prefilling", "retiring",
-              "blocked", "blocked_state", "unfed", "other")
+              "blocked", "blocked_state", "blocked_window", "unfed", "other")
 
 #: a request's stages, as `stats()` names their percentiles (ms)
 STAGES = ("queued_ms", "prefill_wait_ms", "prefill_ms", "first_token_ms",
@@ -137,6 +148,11 @@ class LLMRequest:
     finish_reason: Optional[str] = None  # eos | length
     block_table: List[int] = field(default_factory=list)
     state_slot: Optional[int] = None    # where the model keeps a state
+    # the table of the window layers' pools, where the model keeps those,
+    # by a position's block as `block_table`; the entries before
+    # `window_first` are given back and read the scratch block
+    window_table: List[int] = field(default_factory=list)
+    window_first: int = 0
     pos: int = 0                        # next cache write position
     t_submit: float = 0.0
     t_admit: float = 0.0                # rows, blocks (and slot) granted
@@ -237,8 +253,10 @@ class LLMEngine:
             num_blocks=num_blocks, max_len=max_len,
             paged_kernel=paged_kernel, shards=shards,
             shard_chips=shard_chips, ring_prefill_min=ring_prefill_min,
-            # a model that keeps a state a sequence: a slot for each row
-            state_slots=self.max_batch, tracer=tracer, name=name)
+            # a model that keeps a state a sequence: a slot for each row;
+            # one with window pools sizes them by the rows and the chunk
+            state_slots=self.max_batch, prefill_chunk=self.prefill_chunk,
+            tracer=tracer, name=name)
         self.cache = self.executor.cache
         self.queue: deque = deque()
         self.active: List[LLMRequest] = []
@@ -248,9 +266,11 @@ class LLMEngine:
         self.finished = 0
         self.tokens_out = 0
         self.steps = 0
-        # admissions that waited: short of KV blocks, short of a state slot
+        # admissions that waited: short of KV blocks, short of a state
+        # slot, short of window blocks
         self.admission_blocked = 0
         self.admission_blocked_state = 0
+        self.admission_blocked_window = 0
         #: the launch the next step reads (module docstring)
         self._ahead: Optional[_Ahead] = None
         # decode launches made while the one before was still unread,
@@ -296,6 +316,13 @@ class LLMEngine:
             raise BackendError(
                 f"request needs {self.cache.blocks_for(total)} blocks but "
                 f"the pool only has {self.cache.allocator.total}")
+        if self._windowed and not self._chunked(int(prompt.shape[0])) \
+                and self.cache.blocks_for(int(prompt.shape[0]) + 1) \
+                > self.cache.window_alloc.total:
+            raise BackendError(
+                f"a whole prompt of {prompt.shape[0]} tokens needs more "
+                f"window blocks than the {self.cache.window_alloc.total} "
+                f"there are; set prefill_chunk")
         if req_id is None:
             self._seq += 1
             req_id = f"{self.name}-{self._seq}"
@@ -377,7 +404,8 @@ class LLMEngine:
         call (prefill launches included) whose label is the outcome:
         `admit` (at least one request admitted), `admit_blocked` (the
         head of the queue is short of KV blocks), `admit_blocked_state`
-        (it is short of a state slot), `admit_none_queued`
+        (it is short of a state slot), `admit_blocked_window` (of window
+        blocks), `admit_none_queued`
         (a row is free and nothing is queued here: whatever waits is
         still upstream of the engine) or `admit_full` (no row to give:
         all live, or a static batch still running)."""
@@ -389,6 +417,7 @@ class LLMEngine:
         rows = len(self.active) + prefilling + len(pending)
         queued, blocked = len(self.queue), self.admission_blocked
         blocked_state = self.admission_blocked_state
+        blocked_window = self.admission_blocked_window
         self._admit_queue(pending)
         admitted = queued - len(self.queue)
         if admitted:
@@ -397,6 +426,8 @@ class LLMEngine:
             label = "admit_blocked"
         elif self.admission_blocked_state > blocked_state:
             label = "admit_blocked_state"
+        elif self.admission_blocked_window > blocked_window:
+            label = "admit_blocked_window"
         elif not queued and rows < self.max_batch:
             label = "admit_none_queued"
         else:
@@ -404,6 +435,8 @@ class LLMEngine:
         args = {}
         if self.cache.state_alloc is not None:
             args["state_free"] = self.cache.state_alloc.free
+        if self._windowed:
+            args["window_free"] = self.cache.window_alloc.free
         tr.span("llm", self.name, label, t0, time.perf_counter(),
                 step=self.steps, rows=rows, prefilling=prefilling,
                 queued=queued, admitted=admitted,
@@ -427,20 +460,27 @@ class LLMEngine:
             plen = int(req.prompt.shape[0])
             # the prompt's blocks and the first decode write's; the rest
             # as it grows, which the peak says the pool can give
+            joined = [r for r, _ in pending]
+            window = self._window_ask(req, joined) if self._windowed else {}
             got = self.cache.reserve(
                 self.cache.blocks_for(plen + 1), owner=req.req_id,
-                peak=self._peak_with(req, [r for r, _ in pending]))
+                peak=self._peak_with(req, joined), **window)
             if isinstance(got, str):
                 # head-of-line waits for retirements; admitting a
                 # smaller later request instead would starve it
                 if got == "state":
                     self.admission_blocked_state += 1
                     self._free_cause = "blocked_state"
+                elif got == "window":
+                    self.admission_blocked_window += 1
+                    self._free_cause = "blocked_window"
                 else:
                     self.admission_blocked += 1
                     self._free_cause = "blocked"
                 return
-            blocks, req.state_slot = got
+            blocks, req.state_slot, *rest = got
+            if rest:
+                req.window_table, req.window_first = rest[0], 0
             self.queue.popleft()
             req.t_admit = time.perf_counter()
             req.mark = (self.steps, self.executor.chunk_prefills)
@@ -460,8 +500,9 @@ class LLMEngine:
             self._first_launch(req, req.t_admit)
             logits = self.executor.prefill(
                 req.prompt, blocks, sync=False, req=req.req_id,
-                state_slot=req.state_slot)
+                state_slot=req.state_slot, **self._window_of(req))
             req.pos = plen
+            self._trim(req)
             pending.append((req, logits))
 
     def _peak_with(self, req: LLMRequest, pending: List[LLMRequest]) -> int:
@@ -483,6 +524,52 @@ class LLMEngine:
             self.cache.blocks_for(int(r.prompt.shape[0]) + r.max_new_tokens)
             for r in whole))
 
+    @property
+    def _windowed(self) -> bool:
+        """Whether the model keeps window pools: a second table a row."""
+        return self.cache.window_alloc is not None
+
+    def _window_ask(self, req: LLMRequest, pending: List[LLMRequest]) -> dict:
+        """What admitting `req` asks of the window pools, as `reserve`'s
+        keywords: the blocks of a whole prompt and its first decode
+        write now (a prompt that will chunk grows into its own before
+        each chunk), and the most window blocks the admitted rows and
+        `req` will hold together: `_peak_with`'s account with every
+        table capped at a decoding row's cap, a prompt not yet through
+        at the least of its whole life and that cap, and once, for the
+        one prompt whose chunk is being computed, what a chunk's cap
+        adds."""
+        cache = self.cache
+        cap = cache.window_cap(1)
+        rows = [(r.pos, r.max_new_tokens - len(r.tokens) - r.ahead)
+                for r in self.active + pending]
+        whole = list(self.prefilling)
+        plen = int(req.prompt.shape[0])
+        ask = 0
+        if self._chunked(plen):
+            whole.append(req)
+        else:
+            rows.append((plen, req.max_new_tokens))
+            ask = cache.blocks_for(plen + 1)
+        held = sum(min(cap, cache.blocks_for(
+            int(r.prompt.shape[0]) + r.max_new_tokens)) for r in whole)
+        if whole:
+            held += cache.window_cap(self.prefill_chunk) - cap
+        return {"window": ask, "window_peak": peak_demand(
+            rows, cache.block_size, held=held, cap=cap)}
+
+    def _window_of(self, req: LLMRequest) -> dict:
+        """A request's window table, as the executor's keyword."""
+        return {"window_table": req.window_table} if self._windowed else {}
+
+    def _trim(self, req: LLMRequest) -> None:
+        """After the dispatch of the launch that wrote up to `req.pos`:
+        the next launch queries from `req.pos` on, and the window blocks
+        wholly behind its window go back."""
+        if self._windowed:
+            req.window_first = self.cache.trim(
+                req.window_table, req.pos, req.window_first)
+
     def _chunked(self, plen: int) -> bool:
         """Whether a prompt of `plen` tokens prefills in chunks."""
         return 0 < self.prefill_chunk < plen
@@ -491,9 +578,12 @@ class LLMEngine:
         """Before a decode launch: a block more for every row of it
         whose write position has reached the end of its table."""
         bs = self.cache.block_size
+        windowed = self._windowed
         for r in rows:
             if r.pos // bs == len(r.block_table):
                 self.cache.grow(r.block_table, owner=r.req_id)
+            if windowed and r.pos // bs == len(r.window_table):
+                self.cache.grow(r.window_table, owner=r.req_id, window=True)
 
     def _prefill_chunks(self, pending: List[tuple]) -> None:
         """Advance the oldest chunk-prefilling prompt by ONE chunk (the
@@ -515,11 +605,20 @@ class LLMEngine:
         chunk = req.prompt[req.pos:req.pos + self.prefill_chunk]
         from nnstreamer_tpu.backends.xla import _next_pow2
 
+        if self._windowed:
+            # the window table grows into the chunk (`_grow` sees to the
+            # first decode write's block)
+            upto = req.pos + int(chunk.shape[0])
+            while len(req.window_table) < self.cache.blocks_for(upto):
+                self.cache.grow(req.window_table, owner=req.req_id,
+                                window=True)
         logits = self.executor.prefill_chunk(
             chunk, req.pos, req.block_table,
             bucket=_next_pow2(self.prefill_chunk, 8), sync=False,
-            req=req.req_id, state_slot=req.state_slot)
+            req=req.req_id, state_slot=req.state_slot,
+            **self._window_of(req))
         req.pos += int(chunk.shape[0])
+        self._trim(req)
         if req.pos >= plen:
             self.prefilling.pop(0)
             req.state = "active"
@@ -577,12 +676,14 @@ class LLMEngine:
             launch = ex.decode(
                 [None if r.ahead else r.tokens[-1] for r in rows],
                 [r.block_table for r in rows], [r.pos for r in rows],
-                sync=False, state_slots=self._state_slots(rows))
+                sync=False, state_slots=self._state_slots(rows),
+                **self._window_tables(rows))
             if self._ahead is not None and self._ahead.launch is not None:
                 self.lookahead_steps += 1
             for r in rows:
                 r.pos += 1
                 r.ahead += 1
+                self._trim(r)
         # a row whose budget ends with a token in flight is read by no
         # later launch: its row and blocks go back now, for the next
         # step's admission as in the synchronous order (whatever is
@@ -632,10 +733,12 @@ class LLMEngine:
         logits = self.executor.decode(
             [r.tokens[-1] for r in live],
             [r.block_table for r in live],
-            [r.pos for r in live], state_slots=self._state_slots(live))
+            [r.pos for r in live], state_slots=self._state_slots(live),
+            **self._window_tables(live))
         t0 = time.perf_counter() if self.tracer.active else 0.0
         for i, req in enumerate(live):
             req.pos += 1
+            self._trim(req)
             tok = self._sample(req, logits[i])
             self._record_token(req, tok)
             done = self._maybe_finish(req, tok)
@@ -752,12 +855,24 @@ class LLMEngine:
             return None
         return [r.state_slot for r in rows]
 
+    def _window_tables(self, rows: List[LLMRequest]) -> dict:
+        """The rows' window tables, as the executor's keyword, where the
+        model keeps window pools."""
+        if not self._windowed:
+            return {}
+        return {"window_tables": [r.window_table for r in rows]}
+
     def _release(self, req: LLMRequest) -> None:
         """Give a request's row, blocks and state slot back: when it
         finishes, or ahead of that once its last token is in flight
         (what is prefilled into them runs after the launches already
         made, the state's first chunk starting from zero)."""
-        self.cache.release(req.block_table, req.state_slot)
+        if self._windowed:
+            self.cache.release(req.block_table, req.state_slot,
+                               req.window_table[req.window_first:])
+            req.window_table, req.window_first = [], 0
+        else:
+            self.cache.release(req.block_table, req.state_slot)
         req.block_table, req.state_slot = [], None
         self.active.remove(req)
 
@@ -772,6 +887,7 @@ class LLMEngine:
             "steps": self.steps,
             "admission_blocked": self.admission_blocked,
             "admission_blocked_state": self.admission_blocked_state,
+            "admission_blocked_window": self.admission_blocked_window,
             "rows": dict(self.rows),
             "chunk_deferred_steps": self.chunk_deferred_steps,
             "scheduling": "static" if self.static else "continuous",

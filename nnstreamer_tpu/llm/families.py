@@ -22,10 +22,11 @@ family, a kernel and a kind of call about:
 - what it refuses: at construction (`__init__`) and at submission
   (`check_prompt`);
 - `idx_dim`: the width a token needs of the pool's blocks beyond K and V,
-  and `cache_kw(n_layers)`: which layers keep K and V, and the pools the
+  and `cache_kw(n_layers)`: which layers keep K and V, the pools the
   family adds to `PagedKVCache` by slot (a fixed-size state and
   compressed keys a sequence, whose slot the executor hands `chunk_args`
-  and `decode_args` last);
+  and `decode_args` as `slot` / `slots`), and the window layers' pools
+  under a table a sequence of their own (handed as `window`, last);
 - its accounting: `note_decode`, `note_chunk` and `note_beside` count what
   a call attended and read and return what its span says of it; `stats()`
   is the family's part of the executor's.
@@ -42,7 +43,8 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
-from nnstreamer_tpu.llm.spec import DENSE, HYBRID, LINEAR, SPARSE, SPARSE_MOE
+from nnstreamer_tpu.llm.spec import (
+    DENSE, FULL, HYBRID, LINEAR, SPARSE, SPARSE_MOE, WINDOW, WINDOW_MOE)
 
 
 class Program(NamedTuple):
@@ -63,8 +65,12 @@ class DenseSet:
     def __init__(self, spec, *, name: str, params: dict, dtype,
                  n_heads: int, n_kv: int, head_dim: int, block_size: int,
                  max_blocks: int, kernel: str, shards: int = 0,
-                 shard_fns: Callable[[], dict] = None):
+                 shard_fns: Callable[[], dict] = None, rows: int = 0,
+                 chunk: int = 0):
         self.spec, self.name = spec, name
+        #: the engine's rows and its prompt chunk (0: whole prompts), for
+        #: a family whose pools are sized by them
+        self.rows, self.chunk = int(rows), int(chunk)
         self.paged_kernel, self.shards = kernel, shards
         self._shard_fns = shard_fns
         self.n_kv, self.head_dim = n_kv, head_dim
@@ -148,11 +154,11 @@ class DenseSet:
         return (params, ids, blk_idx, blk_off, *pools, last)
 
     def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
-                   pools, slot=None) -> tuple:
+                   pools, slot=None, window=None) -> tuple:
         return (params, ids, pos0, blk_idx, blk_off, tab, *pools, last)
 
     def decode_args(self, params, cur, tab, pos, n: int, pools,
-                    slots=None) -> tuple:
+                    slots=None, window=None) -> tuple:
         return (params, cur, tab, pos, *pools)
 
     def cache_kw(self, n_layers: int) -> dict:
@@ -297,7 +303,7 @@ class SparseMoESet(ChunkOnlySet):
                        ("spec", "dtype"), (5, 6, 7))
 
     def decode_args(self, params, cur, tab, pos, n: int, pools,
-                    slots=None) -> tuple:
+                    slots=None, window=None) -> tuple:
         # n live rows: a step's padding rows reach no expert
         return (params, cur, tab, pos, np.int32(n), *pools)
 
@@ -429,12 +435,12 @@ class HybridSet(ChunkOnlySet):
                        (5, 6, 7, 8))
 
     def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
-                   pools, slot=None) -> tuple:
+                   pools, slot=None, window=None) -> tuple:
         return (params, ids, pos0, blk_idx, blk_off, tab, slot, *pools,
                 last)
 
     def decode_args(self, params, cur, tab, pos, n: int, pools,
-                    slots=None) -> tuple:
+                    slots=None, window=None) -> tuple:
         return (params, cur, tab, pos, slots, *pools)
 
     def _count(self, said: dict) -> dict:
@@ -481,6 +487,181 @@ class HybridSet(ChunkOnlySet):
                                            tiles * _CTX_TILE))}
 
 
+class WindowMoESet(ChunkOnlySet):
+    """The decoder whose layers attend a window of the newest positions
+    or the whole context (llm/window_moe.py): one prefill program, its
+    chunk; a pair of K and V pools a layer kind, each under a table a
+    sequence of its own (`window`: the window layers' table, or for a
+    chunk its write targets and table); each call returns, an expert
+    layer, the tokens each held expert got and the pairs routed to
+    experts that are not held, beside its logits."""
+
+    family = WINDOW_MOE
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        kinds = spec.layer_kinds
+        # what the family cannot yet be combined with (ROADMAP B3)
+        why = None
+        if self.shards > 0:
+            why = (f"shards={self.shards}: its two pairs of pools and its "
+                   f"share of the experts have no sharding rule yet "
+                   f"(ROADMAP B3)")
+        elif self.paged_kernel == "pallas":
+            why = ("paged_kernel=pallas: it has no windowed Pallas twin yet "
+                   "(ROADMAP B3); set paged_kernel=xla")
+        elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
+            why = ("a W8A8 store version: its grouped expert products "
+                   "are float only")
+        elif len(kinds) != len(params["blocks"]):
+            why = (f"a bundle of {len(params['blocks'])} layers under a "
+                   f"spec that names {len(kinds)}")
+        elif set(kinds) != {WINDOW, FULL} or spec.window < 1:
+            why = (f"layer kinds {sorted(set(kinds))} and a window of "
+                   f"{spec.window}: it serves layers of both kinds, "
+                   f"'{WINDOW}' and '{FULL}', and a window >= 1")
+        elif not 0 <= spec.dense_layers < len(kinds):
+            why = (f"dense_layers={spec.dense_layers} of {len(kinds)}: at "
+                   f"least one layer has to be an expert layer")
+        if why is not None:
+            raise BackendError(
+                f"llm {self.name}: the window_moe family cannot be served "
+                f"with {why}")
+        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
+        self.n_window, self.n_full = kinds.count(WINDOW), kinds.count(FULL)
+        self.held = spec.experts_held or spec.n_experts
+        from nnstreamer_tpu.llm.paged_model import _walk_plan
+
+        self._walk_plan = _walk_plan
+        # kept tracer on or off. Decode steps: live context a FULL layer
+        # and a WINDOW layer attended, pool slots all layers gathered for
+        # it (whole iterations, padding rows included; a slot is n_kv x
+        # head_dim values, K and V); (layer, step) pairs and the distinct
+        # held experts that got a token in them. Every call: (token,
+        # expert) pairs of real tokens routed to held experts and away.
+        # Chunks: context tiles a FULL and a WINDOW layer's walk covered;
+        # tokens at the busiest held expert, summed over the chunks whose
+        # counts have been read back (expert_load_chunks)
+        self.counters.update(dict.fromkeys((
+            "kv_tokens_full", "kv_tokens_window", "expert_pairs_held",
+            "expert_pairs_away", "expert_steps_layers",
+            "experts_touched_sum", "expert_load_max_sum",
+            "expert_load_chunks", "ctx_tiles_full", "ctx_tiles_window"), 0))
+
+    def cache_kw(self, n_layers: int) -> dict:
+        """The FULL layers' pools under the pool's geometry as given;
+        the WINDOW layers' sized by the rows, not by the context: every
+        row at its decode cap, the one prompt whose chunk is computed at
+        its chunk's cap, and the scratch block."""
+        from nnstreamer_tpu.llm.paged_cache import window_cap
+
+        w, bs = self.spec.window, self.block_size
+        span = min(self.chunk or self.WHOLE_PROMPT_MAX,
+                   self.max_blocks * bs)
+        blocks = (max(self.rows, 1) * window_cap(w, bs, 1)
+                  + window_cap(w, bs, span) - window_cap(w, bs, 1) + 1)
+        return {"n_layers": self.n_full, "n_kv": self.n_kv,
+                "window_layers": self.n_window, "window": w,
+                "window_blocks": blocks}
+
+    def program(self, kind: str) -> Program:
+        from nnstreamer_tpu.llm import window_moe
+
+        if kind == "chunk":
+            return Program(window_moe.window_moe_prefill_chunk,
+                           ("spec", "dtype", "by_block", "fused", "tile"),
+                           (8, 9, 10, 11))
+        return Program(window_moe.window_moe_decode_step,
+                       ("spec", "dtype"), (6, 7, 8, 9))
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+
+        return dict(super().chunk_kw(pos0, bucket), tile=_CTX_TILE)
+
+    def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
+                   pools, slot=None, window=None) -> tuple:
+        wblk_idx, wtab = window
+        return (params, ids, pos0, blk_idx, blk_off, tab, wblk_idx, wtab,
+                *pools, last)
+
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None, window=None) -> tuple:
+        # n live rows: a step's padding rows reach no expert
+        return (params, cur, tab, window, pos, np.int32(n), *pools)
+
+    def split(self, out: tuple) -> tuple:
+        logits, load, *pools = out
+        return logits, (load,), pools
+
+    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
+        """A FULL layer attends each live row's whole context, a WINDOW
+        layer its newest `window` positions; each kind's work list is
+        walked in whole iterations of T chunks of C slots
+        (`paged_model._walk_plan`), every row of the bucket in it."""
+        nb_c, _, t = self._walk_plan(self.block_size, self.n_kv,
+                                     self.head_dim, len(pos_a),
+                                     self.max_blocks)
+        c = nb_c * self.block_size
+        pos = pos_a.astype(np.int64)
+        lo = np.maximum(pos - (self.spec.window - 1), 0)
+
+        def slots(items: int) -> int:
+            return -(-items // t) * t * c
+
+        said = {"kv_tokens_full": int(pos[:n].sum()) + n,
+                "kv_tokens_window": int((pos[:n] - lo[:n]).sum()) + n,
+                "kv_slots": self.n_full * slots(int((pos // c + 1).sum()))
+                + self.n_window * slots(int((pos // c - lo // c + 1).sum()))}
+        count = self.counters
+        count["kv_tokens_full"] += said["kv_tokens_full"]
+        count["kv_tokens_window"] += said["kv_tokens_window"]
+        count["kv_tokens_attended"] += (
+            self.n_full * said["kv_tokens_full"]
+            + self.n_window * said["kv_tokens_window"])
+        count["kv_slots_read"] += said["kv_slots"]
+        return said
+
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """The context tiles a FULL and a WINDOW layer's walk covers (the
+        program's own trip counts, `window_moe.tile_span`)."""
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+        from nnstreamer_tpu.llm.window_moe import tile_span
+
+        slots = self.max_blocks * self.block_size
+        _, full = tile_span(pos0, bucket, slots, _CTX_TILE)
+        first, end = tile_span(pos0, bucket, slots, _CTX_TILE,
+                               self.spec.window)
+        self.counters["ctx_tiles_full"] += full
+        self.counters["ctx_tiles_window"] += end - first
+        return {"pos0": pos0,
+                "attend": "fused" if self._fused(bucket) else "plain",
+                "ctx_tiles_full": int(full),
+                "ctx_tiles_window": int(end - first)}
+
+    def note_beside(self, kind: str, host: list) -> dict:
+        """One call's (expert layers, held + 1) counts: the real tokens'
+        pairs at held experts and away, the distinct held experts with a
+        token summed over layers, and for a chunk the tokens at the
+        busiest held expert, largest over layers."""
+        load, = host
+        counts, away = load[:, :-1], int(load[:, -1].sum())
+        touched, held = int((counts > 0).sum()), int(counts.sum())
+        c = self.counters
+        c["expert_pairs_held"] += held
+        c["expert_pairs_away"] += away
+        if kind == "decode":
+            c["expert_steps_layers"] += counts.shape[0]
+            c["experts_touched_sum"] += touched
+            return {"experts_touched": touched, "expert_pairs_held": held,
+                    "expert_pairs_away": away}
+        load_max = int(counts.max())
+        c["expert_load_max_sum"] += load_max
+        c["expert_load_chunks"] += 1
+        return {"experts_touched": touched, "expert_load_max": load_max,
+                "expert_pairs_held": held, "expert_pairs_away": away}
+
+
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
     """What the queries at positions `qpos` score, select and attend in
     one sparse layer of the hybrid family, a KV head; `slots` pool slots
@@ -506,14 +687,14 @@ def _chunk_reads(spec, pos0: int, clen: int, slots: int) -> dict:
 
 #: `LMSpec.family` -> its program set
 FAMILIES: Dict[str, type] = {DENSE: DenseSet, SPARSE_MOE: SparseMoESet,
-                             HYBRID: HybridSet}
+                             HYBRID: HybridSet, WINDOW_MOE: WindowMoESet}
 
 
 def program_set(spec, *, name: str, **given):
     """The set serving a bundle's `spec` (None: a dense bundle that
     describes nothing), built from what the executor knows: `params`,
-    `dtype`, `n_heads`, the pool's geometry, `kernel`, `shards` and
-    `shard_fns`."""
+    `dtype`, `n_heads`, the pool's geometry, `kernel`, `shards`,
+    `shard_fns`, and the engine's `rows` and prompt `chunk`."""
     family = DENSE if spec is None else spec.family
     cls = FAMILIES.get(family)
     if cls is None:

@@ -33,6 +33,20 @@ whole and in order by every query, so kept in a row, not gathered by
 block). A sequence is admitted with its blocks and its slot or with
 neither (`PagedKVCache.reserve`); slot 0 is the scratch slot, as block 0
 is the scratch block.
+
+A model whose layers are of two kinds, some attending a window of the
+newest positions and some the whole context (llm/window_moe.py), keeps a
+second pair of K and V pools for its window layers, under an allocator
+and a table a sequence of their own. Both tables are indexed by a
+position's block; the window table's blocks wholly behind the window are
+given back as the sequence advances (`trim`, after the launch that last
+needed them was dispatched: the device runs its programs in order, so
+whoever is granted the block next writes it after that launch has read
+it) and their entries read the scratch block from then on. A sequence
+therefore holds at most `window_cap(1)` window blocks while it decodes and
+`window_cap(chunk)` while a chunk of its prompt is prefilled, whatever its
+context, and `peak_demand` counts that cap. `reserve` admits against the
+peak in both pools and grants both tables or neither.
 """
 
 from __future__ import annotations
@@ -126,7 +140,7 @@ class BlockAllocator:
         }
 
 
-def peak_demand(rows, block_size: int, held: int = 0) -> int:
+def peak_demand(rows, block_size: int, held: int = 0, cap: int = 0) -> int:
     """The most blocks a set of admitted sequences will hold together.
     `rows`: a (pos, k) pair a decoding sequence, its next write position
     and the decode launches it has left; each advances one position a
@@ -134,13 +148,25 @@ def peak_demand(rows, block_size: int, held: int = 0) -> int:
     slots, and after its k-th it holds none. `held`: blocks counted at
     every launch, for sequences whose first launch is an unknown number
     of steps away (their whole lives). The demand only falls where a
-    sequence ends, so the peak is at one of the rows' k."""
+    sequence ends, so the peak is at one of the rows' k. `cap` > 0: no
+    sequence holds more than `cap` blocks (a window table's, whose blocks
+    behind the window are given back); a capped sequence's holding stops
+    growing but still only falls where it ends."""
     bs = int(block_size)
+    cap = int(cap) or (1 << 62)
     peak = 0
     for j in {k for _, k in rows}:
-        peak = max(peak, sum(-(-(pos + j) // bs)
+        peak = max(peak, sum(min(-(-(pos + j) // bs), cap)
                              for pos, k in rows if k >= j))
     return int(held) + peak
+
+
+def window_cap(window: int, block_size: int, span: int) -> int:
+    """The most window blocks a sequence holds while `span` consecutive
+    queries of it are computed at once (1: a decode step; a prompt
+    chunk's width): those of the `window - 1` positions behind the first
+    query and of the `span` queries, a block more where they straddle."""
+    return -(-(int(window) - 1 + int(span)) // int(block_size)) + 1
 
 
 def idx_pack(block_size: int, idx_dim: int) -> int:
@@ -179,6 +205,13 @@ class PagedKVCache:
     in `dtype`, entry m of a slot belonging to block m of its
     sequence's table.
 
+    `window_layers` > 0 (llm/window_moe.py): a second pair of pools, `wk`
+    and `wv`, (window_layers, window_blocks, block_size, n_kv, head_dim),
+    for the layers that attend the newest `window` positions only, under
+    `window_alloc` and a table a sequence of their own; `n_layers` then
+    counts the layers that attend the whole context. Block 0 of these
+    pools is their scratch block.
+
     `dtype` is the type the model computes K, V and the indexer's keys
     in, and the type the pools keep them in: every value a step writes
     is a `dtype` value already, so a wider pool would hold zeros beside
@@ -192,7 +225,9 @@ class PagedKVCache:
     def __init__(self, *, num_blocks: int, block_size: int, n_layers: int,
                  n_kv: int, head_dim: int, idx_dim: int = 0, dtype=None,
                  placer=None, state_shape: tuple = (),
-                 ckey_shape: tuple = (), state_slots: int = 0):
+                 ckey_shape: tuple = (), state_slots: int = 0,
+                 window_layers: int = 0, window_blocks: int = 0,
+                 window: int = 0):
         import jax.numpy as jnp
 
         self.num_blocks = int(num_blocks)
@@ -224,6 +259,23 @@ class PagedKVCache:
             self.state = by_slot(state_shape, jnp.float32)
         if ckey_shape:
             self.ck = by_slot(ckey_shape, self.dtype)
+        self.wk = self.wv = self.window_alloc = None
+        self.window = int(window)
+        if window_layers:
+            if self.window < 1 or window_blocks < 2:
+                raise ValueError(
+                    f"window pools need a window >= 1 and >= 2 blocks, got "
+                    f"window={window}, window_blocks={window_blocks}")
+            wshape = (int(window_layers), int(window_blocks),
+                      self.block_size, self.n_kv, self.head_dim)
+            self.wk = jnp.zeros(wshape, self.dtype)
+            self.wv = jnp.zeros(wshape, self.dtype)
+            self.window_alloc = BlockAllocator(int(window_blocks))
+        # window blocks given back behind the window, granted as rows
+        # grew, and the largest window peak an admission was accepted at
+        self.window_blocks_freed = 0
+        self.window_blocks_grown = 0
+        self.window_admit_peak = 0
         if placer is not None:
             # sharded serving hands us a device-placement closure (pool
             # sharded along the kv-head axis next to the projections —
@@ -244,11 +296,12 @@ class PagedKVCache:
     def tokens_capacity(self) -> int:
         return self.allocator.total * self.block_size
 
-    _EXTRA = ("idx", "ck", "state")
+    _EXTRA = ("idx", "ck", "state", "wk", "wv")
 
     def pools(self) -> tuple:
         """The pools there are: (k, v), then those the model adds, in
-        the order indexer keys, compressed keys, state."""
+        the order indexer keys, compressed keys, state, the window
+        layers' K and V."""
         return (self.k, self.v) + tuple(
             p for p in (getattr(self, n) for n in self._EXTRA)
             if p is not None)
@@ -261,55 +314,107 @@ class PagedKVCache:
                 setattr(self, name, rest.pop(0))
 
     # -- admission over both kinds of state --------------------------------
-    def reserve(self, n_blocks: int, owner: object = None, peak: int = 0):
+    def window_cap(self, span: int) -> int:
+        """`window_cap` of this cache's window and block size."""
+        return window_cap(self.window, self.block_size, span)
+
+    def reserve(self, n_blocks: int, owner: object = None, peak: int = 0,
+                window: int = 0, window_peak: int = 0):
         """`n_blocks` blocks and, where the model keeps a state a
-        sequence, a state slot: both or neither. `peak` is the
+        sequence, a state slot, and, where it keeps window pools,
+        `window` blocks of those: all or none. `peak` is the
         `peak_demand` of the admitted sequences with this one among
         them; the pool is short of blocks if it cannot hold that, however
-        many are free now. Returns (blocks, slot), slot None for a model
-        without state, or the name of what it was short of: "blocks" or
-        "state"."""
+        many are free now; `window_peak` likewise for the window pools.
+        Returns (blocks, slot), slot None for a model without state
+        (with window pools: (blocks, slot, window blocks)), or the name
+        of what it was short of: "blocks", "state" or "window"."""
         if self.state_alloc is not None and not self.state_alloc.can_alloc(1):
             self.state_alloc.failed_allocs += 1
             return "state"
-        alloc = self.allocator
+        alloc, walloc = self.allocator, self.window_alloc
         peak = max(int(peak), alloc.used + n_blocks)
         if peak > alloc.total:
             alloc.failed_allocs += 1
             return "blocks"
+        if walloc is not None:
+            window_peak = max(int(window_peak), walloc.used + window)
+            if window_peak > walloc.total:
+                walloc.failed_allocs += 1
+                return "window"
+            self.window_admit_peak = max(self.window_admit_peak, window_peak)
         self.admit_peak = max(self.admit_peak, peak)
         blocks = alloc.alloc(n_blocks, owner=owner)
-        if self.state_alloc is None:
-            return blocks, None
-        return blocks, self.state_alloc.alloc(1, owner=owner)[0]
+        slot = None if self.state_alloc is None \
+            else self.state_alloc.alloc(1, owner=owner)[0]
+        if walloc is None:
+            return blocks, slot
+        return blocks, slot, walloc.alloc(window, owner=owner)
 
-    def grow(self, table: List[int], owner: object = None) -> None:
-        """One more block at the end of a sequence's `table`, for the
-        write position that has reached it. `reserve` admitted the
-        sequence against the peak of such grants, so a refusal here is a
-        fault of that account, not a full pool to wait out."""
-        got = self.allocator.alloc(1, owner=owner)
+    def grow(self, table: List[int], owner: object = None,
+             window: bool = False) -> None:
+        """One more block at the end of a sequence's `table` (`window`:
+        its window table, from the window pools), for the write position
+        that has reached it. `reserve` admitted the sequence against the
+        peak of such grants, so a refusal here is a fault of that
+        account, not a full pool to wait out."""
+        alloc = self.window_alloc if window else self.allocator
+        got = alloc.alloc(1, owner=owner)
         if got is None:
             raise BackendError(
-                f"paged pool: no block left to grow {owner!r} into, with "
-                f"{self.allocator.used} of {self.allocator.total} live; "
-                f"admission by peak demand should have kept one free")
+                f"paged pool: no {'window ' if window else ''}block left to "
+                f"grow {owner!r} into, with {alloc.used} of {alloc.total} "
+                f"live; admission by peak demand should have kept one free")
         table.extend(got)
-        self.blocks_grown += 1
+        if window:
+            self.window_blocks_grown += 1
+        else:
+            self.blocks_grown += 1
 
-    def release(self, blocks: List[int], slot: Optional[int]) -> None:
+    def trim(self, table: List[int], pos: int, first: int = 0) -> int:
+        """Give back the blocks of a sequence's window `table` that lie
+        wholly behind the window of a query at `pos` (the lowest position
+        any launch still to be dispatched will query): block b goes iff
+        its last slot, (b + 1) * block_size - 1, is below pos - (window -
+        1). Their entries read the scratch block from now on. Call it
+        after the launch that last needed them was dispatched. `first`:
+        the table's first entry not yet given back, as the call before
+        returned it; returns the new one."""
+        behind = min(max(int(pos) - (self.window - 1), 0) // self.block_size,
+                     len(table))
+        if behind <= first:
+            return first
+        self.window_alloc.free_blocks(table[first:behind])
+        table[first:behind] = [SCRATCH_BLOCK] * (behind - first)
+        self.window_blocks_freed += behind - first
+        return behind
+
+    def release(self, blocks: List[int], slot: Optional[int],
+                window: Optional[List[int]] = None) -> None:
         """Give back what `reserve` and `grow` granted."""
         self.allocator.free_blocks(blocks)
         if slot is not None:
             self.state_alloc.free_blocks([slot])
+        if window:
+            self.window_alloc.free_blocks(
+                [b for b in window if b != SCRATCH_BLOCK])
 
     @property
     def block_bytes(self) -> int:
         """Bytes one block holds over all layers and all pools that are
-        paged (what a sequence holds by slot: `state_slot_bytes`)."""
+        paged under the one table (what a sequence holds by slot:
+        `state_slot_bytes`; a window block: `window_block_bytes`)."""
         per_slot = 2 * self.n_kv * self.head_dim + self.idx_dim
         return (self.n_layers * self.block_size * per_slot
                 * self.dtype.itemsize)
+
+    @property
+    def window_block_bytes(self) -> int:
+        """Bytes one block of the window pools holds, K and V over the
+        window layers."""
+        if self.wk is None:
+            return 0
+        return 2 * int(self.wk.nbytes) // self.wk.shape[1]
 
     @property
     def state_slot_bytes(self) -> int:
@@ -336,4 +441,14 @@ class PagedKVCache:
             out["state_bytes"] = self.state_slot_bytes * (
                 self.state_alloc.total + 1)
             out["state_slot_bytes"] = self.state_slot_bytes
+        if self.window_alloc is not None:
+            w = self.window_alloc
+            out["window"] = {
+                "blocks_total": w.total, "blocks_used": w.used,
+                "blocks_live_high_water": w.high_water,
+                "block_bytes": self.window_block_bytes,
+                "admit_peak_blocks": self.window_admit_peak,
+                "blocks_grown": self.window_blocks_grown,
+                "failed_allocs": w.failed_allocs}
+            out["window_blocks_freed"] = self.window_blocks_freed
         return out

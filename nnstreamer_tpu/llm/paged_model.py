@@ -143,30 +143,43 @@ def walk_slots(pos, block_size, n_kv, hd, max_blocks):
     return -(-items // t) * t * c
 
 
-def _live_items(tables, pos, block_size, nb_c, n_chunks, t):
+def _live_items(tables, pos, block_size, nb_c, n_chunks, t, lo=None):
     """The step's work list, made on the device from `pos` and
     `tables`: item i is one chunk of one row's live blocks, rows in
     order, row r holding pos[r] // C + 1 of them. Returns per item its
     row, its pool blocks (nb_c,), the last live slot inside its chunk
     (-1 for the items past the total, which read the scratch block and
-    count for nothing) and the number of T-item iterations."""
+    count for nothing) and the number of T-item iterations.
+
+    `lo` (B,), where given, is each row's first live position (a window's
+    lower edge): row r then holds the chunks from lo[r] // C on, and a
+    fifth value is returned, the first live slot inside each item's
+    chunk (at or below 0: the whole chunk is behind the edge)."""
     b, max_blocks = tables.shape
     c = nb_c * block_size
     n_items = -(-(b * n_chunks) // t) * t
     chunks = pos // c + 1                                    # (B,)
+    if lo is not None:
+        chunk0 = lo // c
+        chunks = chunks - chunk0
     ends = jnp.cumsum(chunks)
     i = jnp.arange(n_items, dtype=pos.dtype)
     valid = i < ends[-1]
     row = jnp.minimum(
         jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
     chunk = jnp.where(valid, i - (ends - chunks)[row], 0)
+    if lo is not None:
+        chunk = chunk + jnp.where(valid, chunk0[row], 0)
     # a table's tail past max_blocks, like an item past the total,
     # reads block 0: the scratch block
     tab = jnp.pad(tables, ((0, 0), (0, n_chunks * nb_c - max_blocks)))
     blocks = tab[row[:, None], chunk[:, None] * nb_c + jnp.arange(nb_c)]
     blocks = jnp.where(valid[:, None], blocks, 0)
     last = jnp.where(valid, pos[row] - chunk * c, -1)
-    return row, blocks, last, (ends[-1] + t - 1) // t
+    n_iter = (ends[-1] + t - 1) // t
+    if lo is None:
+        return row, blocks, last, n_iter
+    return row, blocks, last, n_iter, lo[row] - chunk * c
 
 
 def _attend_live(q, k_pool, v_pool, li, items, t, n_heads):

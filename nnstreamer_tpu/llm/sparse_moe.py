@@ -67,8 +67,9 @@ How each program reads it.
   grouped products (`jax.lax.ragged_dot`) over the experts that have
   tokens, combined by the renormalised weights in f32. Experts without a
   token are not read. Padding rows are routed past the last expert and
-  count for nothing. Returns the tokens each expert got, which rides the
-  step's read-back.
+  count for nothing; the pair rows are filled up to a multiple of 8
+  (`_GROUPED_ROWS`) the same way. Returns the tokens each expert got,
+  which rides the step's read-back.
 """
 
 from __future__ import annotations
@@ -94,6 +95,12 @@ _CTX_TILE = 1024
 # tile (`pallas_ops.selected_block_update`; read on the chip, PERF.md
 # PR 32).
 _FUSED_Q_BLOCK = 128
+
+# Pair rows of a grouped product the TPU compiler hands to its kernel:
+# a multiple of this. Any other count it expands to one dense product
+# over all groups (compiled for a described v5e, and read on the chip:
+# PERF.md, PR 39).
+_GROUPED_ROWS = 8
 
 _F32 = jnp.float32
 _U32 = jnp.uint32
@@ -144,6 +151,19 @@ def _idx_write(i_pool, li, blk, off, ki):
         mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
+def _put_blocks(pool, li, first, x):
+    """Whole blocks x (values of len(first) blocks, in order) into layer
+    `li` of `pool` at the blocks `first` (nb,): one in-place update a
+    block, in a loop (as one scatter of whole blocks XLA:TPU re-lays the
+    whole pool for it, 2.6 GB)."""
+    nb = first.shape[0]
+    x = x.astype(pool.dtype).reshape((nb, 1, 1) + pool.shape[2:])
+    zeros = (0,) * (pool.ndim - 2)
+    return jax.lax.fori_loop(0, nb, lambda i, p: (
+        jax.lax.dynamic_update_slice(p, x[i], (li, first[i]) + zeros)),
+        pool)
+
+
 def _write_chunk(pools, li, blk_idx, blk_off, k, v, ki, by_block: bool):
     """A chunk's keys, values and indexer keys (C rows, consecutive
     positions) into the three pools. `by_block`: the chunk starts on a
@@ -159,19 +179,10 @@ def _write_chunk(pools, li, blk_idx, blk_off, k, v, ki, by_block: bool):
         return (k_pool.at[li, blk_idx, blk_off].set(k.astype(k_pool.dtype)),
                 v_pool.at[li, blk_idx, blk_off].set(v.astype(v_pool.dtype)),
                 _idx_write(i_pool, li, blk_idx, blk_off, ki))
-    nb = k.shape[0] // bs
-    first = blk_idx.reshape(nb, bs)[:, 0]
-
-    def put(pool, x):
-        # one in-place update a block, in a loop: as one scatter of
-        # whole blocks XLA:TPU re-lays the whole pool for it (2.6 GB)
-        x = x.astype(pool.dtype).reshape((nb, 1, 1) + pool.shape[2:])
-        zeros = (0,) * (pool.ndim - 2)
-        return jax.lax.fori_loop(0, nb, lambda i, p: (
-            jax.lax.dynamic_update_slice(p, x[i], (li, first[i]) + zeros)),
-            pool)
-
-    return put(k_pool, k), put(v_pool, v), put(i_pool, ki)
+    first = blk_idx.reshape(k.shape[0] // bs, bs)[:, 0]
+    return (_put_blocks(k_pool, li, first, k),
+            _put_blocks(v_pool, li, first, v),
+            _put_blocks(i_pool, li, first, ki))
 
 
 def _idx_scores(qi, w, rows, di, shared: bool):
@@ -195,31 +206,64 @@ def _idx_scores(qi, w, rows, di, shared: bool):
     return jnp.stack(per, axis=-1).reshape(qi.shape[0], -1)
 
 
-def _expert_layer(blk, g, live, spec: LMSpec, dtype):
-    """The dropless expert layer for tokens g (N, D), `live` (N,) bool
-    marking the real ones. Returns (y (N, D) in `dtype`, tokens each
-    expert got (E,) int32)."""
-    n, d = g.shape
-    ne, k, f = spec.n_experts, spec.experts_per_tok, spec.expert_width
+def _route(blk, g, spec: LMSpec, dtype):
+    """The router for tokens g (N, D): (weights (N, k) f32, experts
+    (N, k) int32 among all `n_experts`). Softmax scores: the k largest,
+    renormalised. Sigmoid scores: the k of largest score + bias (ties to
+    the lower index), weighted by their scores alone, renormalised and
+    multiplied by `route_scale`."""
+    k = spec.experts_per_tok
     logits = jnp.dot(g, blk["router"].astype(dtype),
                      preferred_element_type=_F32)
-    p, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    if spec.score_fn == "softmax":
+        p, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return p / jnp.sum(p, axis=-1, keepdims=True), e
+    s = jax.nn.sigmoid(logits)
+    _, e = jax.lax.top_k(s + blk["router_bias"].astype(_F32), k)
+    p = jnp.take_along_axis(s, e, axis=-1)
+    return spec.route_scale * p / (
+        jnp.sum(p, axis=-1, keepdims=True) + 1e-20), e
+
+
+def _expert_layer(blk, g, live, spec: LMSpec, dtype):
+    """The dropless expert layer for tokens g (N, D), `live` (N,) bool
+    marking the real ones. The router scores all `n_experts`; `ewi` and
+    `ewd` carry the experts held here, `experts_held` from
+    `experts_first` on (all of them where `experts_held` is 0), and a
+    pair routed to an expert that is not held costs nothing and adds
+    nothing. Returns (y (N, D) in `dtype`, tokens each held expert got
+    (held,) int32, the real tokens' pairs routed away () int32)."""
+    n, d = g.shape
+    k, f = spec.experts_per_tok, spec.expert_width
+    ne = spec.experts_held or spec.n_experts
+    p, e = _route(blk, g, spec, dtype)
     # a padding row's pairs sort past the last expert and belong to no
-    # group: they cost no expert's weights and are not counted
-    e = jnp.where(live[:, None], e, ne).reshape(-1)
+    # group: they cost no expert's weights and are not counted; so do
+    # the pairs of an expert that is not held here
+    mine = live[:, None]
+    if spec.experts_held:
+        e = e - spec.experts_first
+        mine = mine & (e >= 0) & (e < ne)
+    e = jnp.where(mine, e, ne).reshape(-1)
     order = jnp.argsort(e, stable=True)
     counts = jnp.sum(e[:, None] == jnp.arange(ne)[None, :], axis=0,
                      dtype=jnp.int32)
     xs = g[order // k]
+    # a count of pair rows the compiler would expand (one decode row of
+    # 4 a token) reads every held expert, and reads wrong in float32 at
+    # `highest` on the chip: rows of zeros past the last group, which
+    # belong to no expert, take it to the kernel
+    if n * k % _GROUPED_ROWS:
+        xs = jnp.pad(xs, ((0, -(n * k) % _GROUPED_ROWS), (0, 0)))
     gu = jax.lax.ragged_dot(xs, blk["ewi"].astype(dtype), counts)
     mid = jax.nn.silu(gu[:, :f]) * gu[:, f:]
     out = jax.lax.ragged_dot(mid, blk["ewd"].astype(dtype), counts)
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
     out = out[inv].reshape(n, k, d).astype(_F32)
-    y = jnp.sum(jnp.where(live[:, None, None], out * p[..., None], 0.0),
+    y = jnp.sum(jnp.where(mine[..., None], out * p[..., None], 0.0),
                 axis=1)
-    return y.astype(dtype), counts
+    away = jnp.sum(live[:, None] & ~mine, dtype=jnp.int32)
+    return y.astype(dtype), counts, away
 
 
 def _finish(params, x, dtype):
@@ -274,7 +318,7 @@ def _decode_layer(blk, x, li, pos, live, write_blk, write_off, tables,
                      preferred_element_type=_F32).astype(dtype)
     x = x + _proj(blk, "wo", att.reshape(b, 1, -1), dtype)
     g = rmsnorm(x, blk["ln2"].astype(dtype))
-    y, counts = _expert_layer(blk, g[:, 0], live, spec, dtype)
+    y, counts, _ = _expert_layer(blk, g[:, 0], live, spec, dtype)
     return x + y[:, None, :], counts, k_pool, v_pool, i_pool
 
 
@@ -464,7 +508,7 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, n_tiles,
     att = (acc / l[..., None]).transpose(2, 0, 1, 3).astype(dtype)
     x = x + _proj(blk, "wo", att.reshape(c, 1, -1), dtype)
     g = rmsnorm(x, blk["ln2"].astype(dtype))
-    y, counts = _expert_layer(blk, g[:, 0], live, spec, dtype)
+    y, counts, _ = _expert_layer(blk, g[:, 0], live, spec, dtype)
     return x + y[:, None, :], counts, k_pool, v_pool, i_pool
 
 

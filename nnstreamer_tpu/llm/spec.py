@@ -3,7 +3,8 @@
 `ModelBundle.lm` holds an `LMSpec` when the parameters alone cannot say
 what they are: the family, and the sizes a params pytree does not spell
 out (a query width that is not the hidden size, a rope base, an indexer,
-experts, the kinds of its layers). `PagedLLMExecutor` reads its dims
+experts and which of them are held here, the kinds of its layers, a
+window). `PagedLLMExecutor` reads its dims
 and `n_heads` from the spec when the bundle has one, and from the
 parameters' shapes and the element's `n_heads` property, as it always
 did, when it has none.
@@ -26,8 +27,15 @@ SPARSE_MOE = "sparse_moe"
 #: the decoder whose layers are linear attention with a carried state or
 #: block-sparse attention over paged KV (llm/hybrid_lm.py)
 HYBRID = "hybrid"
-#: the kinds of layer `LMSpec.layer_kinds` names
+#: the decoder whose layers attend a window of the newest positions or
+#: the whole context, over a paged pool a kind, a dense MLP in its first
+#: layers and then a shared expert beside this chip's share of the routed
+#: experts (llm/window_moe.py)
+WINDOW_MOE = "window_moe"
+#: the kinds of layer `LMSpec.layer_kinds` names: the hybrid family's two,
+#: then the window family's two
 LINEAR, SPARSE = "linear", "sparse"
+WINDOW, FULL = "window", "full"
 
 
 @dataclass(frozen=True)
@@ -70,3 +78,27 @@ class LMSpec:
     emb_scale: float = 1.0
     residual_scale: float = 1.0
     logit_div: float = 1.0
+    # the expert layer's router beyond softmax scores: "sigmoid" scores
+    # each expert apart and chooses by score + the router's bias (which
+    # is in the choice, not in the weights); the renormalised weights are
+    # multiplied by route_scale
+    score_fn: str = "softmax"
+    route_scale: float = 1.0
+    # the share of the routed experts held here: experts_held of them from
+    # experts_first on (0 = all n_experts). The router scores all
+    # n_experts; a pair routed to an expert that is not held adds nothing
+    experts_first: int = 0
+    experts_held: int = 0
+    # the window family (layer_kinds of WINDOW and FULL). A WINDOW layer's
+    # query at t attends the positions t - window < s <= t and is roped;
+    # a FULL layer's attends all s <= t and is not. Both: q and k are
+    # normed a head, the attention's output is multiplied by sigmoid(u Wg)
+    # before Wo, each branch is normed before and after (ln1..ln4). The
+    # first dense_layers layers have a SwiGLU of dense_width, the others
+    # the expert layer beside a shared SwiGLU of shared_width every token
+    # passes. norm_eps is every RMSNorm's
+    window: int = 0
+    dense_layers: int = 0
+    dense_width: int = 0
+    shared_width: int = 0
+    norm_eps: float = 1e-6

@@ -572,8 +572,8 @@ def metrics_snapshot(tracer=None, admission: Optional[dict] = None,
             f"{ns}_llm_row_steps_total", "counter",
             "rows of max_batch over every decode launch, by what each "
             "did: decode, prefilling or retiring, or free and blocked "
-            "(short of KV blocks), blocked_state, unfed (nothing "
-            "queued) or other; the states' rates sum to max_batch x "
+            "(short of KV blocks), blocked_state, blocked_window, unfed "
+            "(nothing queued) or other; the states' rates sum to max_batch x "
             "the launch rate",
             [({"element": el, "state": k}, float(v))
              for el, st, _ in rows
@@ -600,6 +600,13 @@ def metrics_snapshot(tracer=None, admission: Optional[dict] = None,
             "the most KV blocks ever live at once (written context, not "
             "reserved lives)",
             [({"element": el}, float(c.get("blocks_live_high_water", 0)))
+             for el, c in caches]))
+        out.append(_series(
+            f"{ns}_llm_window_blocks_freed_total", "counter",
+            "blocks of the window layers' pools given back behind a "
+            "row's window as it advanced (0 for a family with one table "
+            "a sequence)",
+            [({"element": el}, float(c.get("window_blocks_freed", 0)))
              for el, c in caches]))
         out.append(_series(
             f"{ns}_llm_chunk_deferred_steps_total", "counter",

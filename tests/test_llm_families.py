@@ -173,5 +173,7 @@ def test_stats_hold_the_keys_the_runners_read(dense, family, extra):
     assert all(isinstance(ex[k], int) for k in EXECUTOR_KEYS + extra)
     assert st["cache"]["blocks_used"] == 0 and ex["decode_steps"] == 2
     assert ("family" in ex) == (family != "dense")
-    # the compiled window's five keys went with it
-    assert not [k for k in set(st) | set(ex) if "window" in k]
+    # the compiled window's five keys went with it (what a request waits
+    # for in a family with window pools is another window: PR 39)
+    assert not [k for k in set(st) | set(ex)
+                if "window" in k and k != "admission_blocked_window"]

@@ -166,7 +166,7 @@ def test_no_token_is_dropped_when_all_route_to_one_expert(params):
     g = jnp.asarray(np.random.default_rng(4).normal(size=(24, 64)),
                     jnp.float32).at[:, 0].set(8.0)
     live = jnp.arange(24) < 21                       # three padding rows
-    y, counts = sparse_moe._expert_layer(blk, g, live, SPEC, jnp.float32)
+    y, counts, _ = sparse_moe._expert_layer(blk, g, live, SPEC, jnp.float32)
     with jax.default_matmul_precision("highest"):
         dense = np.asarray(ref.moe_dense(g, blk, 2))
         sorted_ = np.asarray(ref.moe(g, blk, 2)[0])

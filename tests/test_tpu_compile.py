@@ -37,9 +37,14 @@ def one_chip():
 # heads of 128, chunks of 2,048, a context of 33 tiles) and a short bucket;
 # the SALA cell's (minicpm-sala-8l: a call a KV head of 16 query heads, the
 # keys one tile wide: `hybrid_lm.sparse_attend_walk`)
+# the Trinity cell's (trinity-large-preview-5l: 8 KV heads of 6 query heads,
+# chunks of 2,048 and a whole prompt of 1,024, the keys one tile wide:
+# `window_moe.attend_tiles`)
 @pytest.mark.parametrize("nkv,grp,c,s_pad", [
     (4, 8, 2048, 33792), (4, 8, 64, 33792), (1, 16, 2048, 1024),
-    (1, 16, 64, 1024)], ids=["keye-2048", "keye-64", "sala-2048", "sala-64"])
+    (1, 16, 64, 1024), (8, 6, 2048, 1024), (8, 6, 1024, 1024)],
+    ids=["keye-2048", "keye-64", "sala-2048", "sala-64", "trinity-2048",
+         "trinity-1024"])
 def test_selected_block_update_compiles_at_the_cells_sizes(one_chip, nkv,
                                                            grp, c, s_pad):
     hd, tile = 128, sparse_moe._CTX_TILE
@@ -107,3 +112,35 @@ def test_the_hybrid_chunks_walk_gathers_nothing_a_query_wide(one_chip,
     calls = [ln for ln in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in ln]
     assert len(calls) == spec.n_kv
+
+
+@pytest.mark.parametrize("rows", [1, 2, 16])
+def test_the_expert_layers_grouped_products_take_the_kernel(one_chip, rows):
+    """The Trinity cell's expert layer (trinity-large-preview-5l: 32 held
+    experts of 256, width 3,072, 4 a token) at a decode bucket's rows:
+    both grouped products go to the compiler's kernel, at one row too,
+    whose 4 pair rows it would expand to a dense product over every held
+    expert (read wrong in float32 at `highest` on the chip, PR 39)."""
+    from perfbench.runners.window_moe_llm import lm_spec as window_spec
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "trinity-large-preview-5l.json")) as f:
+        spec = window_spec(json.load(f))
+    d, f_, held, bf = 3072, spec.expert_width, spec.experts_held, jnp.bfloat16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = {"router": arg((d, spec.n_experts), bf),
+           "router_bias": arg((spec.n_experts,), jnp.float32),
+           "ewi": arg((held, d, 2 * f_), bf), "ewd": arg((held, f_, d), bf)}
+    text = jax.jit(lambda b, g, live: sparse_moe._expert_layer(
+        b, g, live, spec, bf)).lower(
+        blk, arg((rows, d), bf), arg((rows,), jnp.bool_)
+    ).compile().as_text()
+    # the kernel's calls: one that lays out the groups, one a product
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln
+             and "%ragged-dot-metadata" not in ln.split("=")[0]]
+    # the expansion is a convolution that dilates its input by the groups
+    assert len(calls) == 2 and "lhs_dilate" not in text
