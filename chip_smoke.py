@@ -226,6 +226,33 @@ def leg_kernels() -> dict:
               q, qpos, tab, span, 1, kp, vp, window=1536, fused=False,
               tile=tile, dtype=jnp.bfloat16), 3e-2)
 
+    # a chunk's expert layer through the grouped-product kernel (1,024
+    # tokens x 4 of 64 experts, 8 held: 4,096 pair rows of which an
+    # eighth is held, a row tile of 128), against the same layer with the
+    # compiler's own grouped product in its place
+    ex = LMSpec(family="window_moe", n_heads=8, n_kv=2, head_dim=128,
+                n_experts=64, experts_per_tok=4, expert_width=512,
+                score_fn="sigmoid", route_scale=2.448, experts_first=16,
+                experts_held=8)
+    assert sparse_moe.expert_row_tile(4096, 64) == 128
+    eblk = {"router": normal((1024, 64), jnp.bfloat16),
+            "router_bias": jnp.zeros((64,), jnp.float32),
+            "ewi": normal((8, 1024, 1024), jnp.bfloat16) * 0.03,
+            "ewd": normal((8, 512, 1024), jnp.bfloat16) * 0.03}
+    live = jnp.arange(1024) < 1000
+
+    def layer_by_ragged_dot(g):
+        tile, sparse_moe._XLA_ROW_TILE = sparse_moe._XLA_ROW_TILE, 1 << 30
+        try:
+            return sparse_moe._expert_layer(eblk, g, live, ex, jnp.bfloat16)
+        finally:
+            sparse_moe._XLA_ROW_TILE = tile
+
+    check("grouped_matmul",
+          lambda g: sparse_moe._expert_layer(eblk, g, live, ex,
+                                             jnp.bfloat16),
+          (normal((1024, 1024), jnp.bfloat16),), layer_by_ragged_dot, 3e-2)
+
     # paged kernels at the LLM legs' geometry, MHA and GQA
     hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64
     nh = LLM["n_heads"]

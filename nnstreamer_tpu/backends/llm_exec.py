@@ -537,13 +537,13 @@ class PagedLLMExecutor:
         chunk's own `req` and `clen` and what its `invoke` said of where
         it starts (`pos0` and the family's `note_chunk`)."""
         while self._chunk_beside:
-            req, clen, extra, dev = self._chunk_beside[0]
+            req, clen, bucket, extra, dev = self._chunk_beside[0]
             if not (wait or all(d.is_ready() for d in dev)):
                 return
             self._chunk_beside.pop(0)
             t0 = time.perf_counter()
             host = [np.asarray(d) for d in dev]  # nnlint: disable=NNL002 ready, or behind the caller's device_sync
-            said = self.programs.note_beside("chunk", host)
+            said = self.programs.note_beside("chunk", host, bucket)
             if self.tracer.active:
                 self.tracer.span(
                     "backend", self.name, "resolve", t0,
@@ -714,10 +714,10 @@ class PagedLLMExecutor:
             # reads from it; until then a `resolve` span will
             extra["pos0"] = int(pos0)
             if host:
-                extra.update(ps.note_beside("chunk", host))
+                extra.update(ps.note_beside("chunk", host, c_b))
             else:
                 self._drain_chunks()
-                self._chunk_beside.append((req, clen, extra, beside))
+                self._chunk_beside.append((req, clen, c_b, extra, beside))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_prefill_chunk",

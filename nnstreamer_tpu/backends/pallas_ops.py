@@ -549,6 +549,108 @@ def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
       t[:, None], cut[:, None], m, l, acc)
 
 
+# -- grouped matmul -----------------------------------------------------------
+
+def group_visits(group_sizes, m: int, tm: int):
+    """The grouped product's plan for rows sorted by group, in row tiles
+    of `tm`: one visit for every (row tile, group) pair that shares
+    rows, in row order. Returns (offsets (G + 1,): group g's rows are
+    ``offsets[g]`` to ``offsets[g + 1]``; group (V,) and row tile (V,) of
+    each visit, V = m / tm + G - 1 the most there can be; the visits
+    there are, () int32). A group of no rows is not visited."""
+    g = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    first = (ends - group_sizes) // tm
+    tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    done = jnp.cumsum(tiles)                  # visits up to each group's last
+    v = jnp.arange(m // tm + g - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(v[:, None] >= done[None, :], axis=1,
+                                dtype=jnp.int32), g - 1)
+    tile = first[group] + v - (done[group] - tiles[group])
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, group, jnp.clip(tile, 0, m // tm - 1), done[-1]
+
+
+def _grouped_matmul_kernel(tm: int, tk: int, tn: int, off_ref, group_ref,
+                           tile_ref, lhs_ref, rhs_ref, out_ref, acc_ref):
+    """One (visit, N tile, K tile) step: the visit's row tile, whole in
+    K, against a (tk, tn) tile of its group's matrix, summed over K
+    tiles in float32; after the last, the rows that are the group's go
+    to their N tile of the visit's output rows, which stay in fast
+    memory while consecutive visits share the row tile."""
+    v, n, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(
+        lhs_ref[:, pl.ds(pl.multiple_of(k * tk, tk), tk)], rhs_ref[...],
+        preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _store():
+        g = group_ref[v]
+        row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
+            jnp.int32, (tm, tn), 0)
+        mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
+        at = pl.ds(pl.multiple_of(n * tn, tn), tn)
+        out_ref[:, at] = jnp.where(
+            mine, acc_ref[...],
+            out_ref[:, at].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
+    """``lhs[rows of group g] @ rhs[g]`` for every group: lhs (M, K)
+    with its rows sorted by group, rhs (G, K, N), group_sizes (G,) int32
+    summing to at most M. What `jax.lax.ragged_dot` computes, on the grid
+    of the public megablox grouped product with the row tile the caller
+    chooses: `tiling` = (tm, tk, tn), tk dividing K and tn dividing N.
+    One visit for every (row tile, group) pair that shares rows
+    (`group_visits`; a run-time count: a group of no rows costs
+    nothing, and neither do the rows past the last group); products of
+    the operands' type summed in float32 over K tiles; the result in
+    lhs's type. **Rows past the last group belong to no group and are
+    never written: what they hold is not defined** (the interpreter
+    leaves NaN there), so the caller reads them behind a mask. M is
+    filled up to a whole number of row tiles here.
+
+    The visits are the outermost grid dimension: a visit's rows (tm, K)
+    and its output rows (tm, N) stay in fast memory while the group's
+    matrix streams through once, so the rows are read once a visit, not
+    once an N tile as in megablox's order."""
+    tm, tk, tn = tiling
+    m, kk = lhs.shape
+    groups, _, nn = rhs.shape
+    if kk % tk or nn % tn:
+        raise ValueError(f"grouped_matmul needs K={kk} divisible by tk={tk} "
+                         f"and N={nn} by tn={tn}")
+    if m % tm:
+        lhs = jnp.pad(lhs, ((0, -m % tm), (0, 0)))
+    rows = lhs.shape[0]
+    offsets, group, tile, visits = group_visits(group_sizes, rows, tm)
+    out = pl.pallas_call(
+        functools.partial(_grouped_matmul_kernel, tm, tk, tn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(visits, nn // tn, kk // tk),
+            in_specs=[
+                pl.BlockSpec((tm, kk), lambda v, n, k, o, g, t: (t[v], 0)),
+                pl.BlockSpec((None, tk, tn),
+                             lambda v, n, k, o, g, t: (g[v], k, n))],
+            out_specs=pl.BlockSpec((tm, nn),
+                                   lambda v, n, k, o, g, t: (t[v], 0)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, nn), lhs.dtype),
+        name="grouped_matmul",
+        # a row tile's output is revisited by the next visit: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=_interpret() if interpret is None else interpret,
+    )(offsets, group, tile, lhs, rhs)
+    return out[:m]
+
+
 def flash_carry_init(bh: int, sq: int, d: int):
     """Fresh (m, l, acc) carry for flash_block_update — m/l in the
     (BH, 8, Sq) sublane-replicated layout the kernel requires."""

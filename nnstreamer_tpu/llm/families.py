@@ -47,6 +47,36 @@ from nnstreamer_tpu.llm.spec import (
     DENSE, FULL, HYBRID, LINEAR, SPARSE, SPARSE_MOE, WINDOW, WINDOW_MOE)
 
 
+def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
+    """The visits a chunk's grouped products make, one of its two
+    products, over its expert layers: counts (layers, experts) are the
+    pair rows each expert got, sorted by expert in each layer; an
+    expert's rows span the row tiles of `tm` from its first row's to its
+    last row's, and each is a visit (`pallas_ops.group_visits`, reckoned
+    here on the host). An expert of no rows is not visited."""
+    counts = counts.astype(np.int64)
+    ends = np.cumsum(counts, axis=1)
+    tiles = (ends - 1) // tm - (ends - counts) // tm + 1
+    return int(tiles[counts > 0].sum())
+
+
+def _note_expert_tiles(ps, counts: np.ndarray, bucket: int) -> dict:
+    """How full the row tiles were that a chunk's grouped products
+    visited (`sparse_moe._expert_layer`), for the set `ps` of a family
+    with an expert layer: counts (layers, held experts) and the rows the
+    chunk was padded to give the pair rows, and the rule gives the tile
+    (`sparse_moe.expert_row_tile`). Counts them and returns the span's
+    part."""
+    from nnstreamer_tpu.llm.sparse_moe import expert_row_tile
+
+    tm = expert_row_tile(bucket * ps.spec.experts_per_tok, ps.spec.n_experts)
+    visits = expert_tile_visits(counts, tm)
+    ps.counters["expert_tile_visits"] += visits
+    ps.counters["expert_tile_rows"] += visits * tm
+    return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
+        100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
+
+
 class Program(NamedTuple):
     fn: Callable
     static: Tuple[str, ...]
@@ -199,9 +229,10 @@ class DenseSet:
         return its span's part."""
         return {}
 
-    def note_beside(self, kind: str, host: list) -> dict:
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """Account what a `chunk` or a `decode` returned beside its
-        logits, now on the host, and return its span's part."""
+        logits, now on the host, and return its span's part. `bucket`:
+        the rows a chunk was padded to (a decode step gives none)."""
         return {}
 
     def stats(self) -> dict:
@@ -235,6 +266,85 @@ class ChunkOnlySet(DenseSet):
         bs = self.block_size
         return dict(self.kw, fused=self._fused(bucket),
                     by_block=int(pos0) % bs == 0 and bucket % bs == 0)
+
+    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
+        """Refuse a prompt this family can never prefill."""
+
+    # -- accounting --------------------------------------------------------
+    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
+        """Count what one decode step from the bucket's positions
+        `pos_a` (`n` live rows first) attends and reads, and return its
+        span's part: kv_tokens, the live rows' context with the step's
+        own tokens; kv_slots, the pool slots one layer gathers, padding
+        rows and the walk's rounding included."""
+        if self._walk_slots is not None:
+            slots = self._walk_slots(pos_a, self.block_size, self.n_kv,
+                                     self.head_dim, self.max_blocks)
+        else:
+            slots = len(pos_a) * self.max_blocks * self.block_size
+        tokens = int(pos_a[:n].sum()) + n
+        self.counters["kv_tokens_attended"] += tokens
+        self.counters["kv_slots_read"] += slots
+        return {"kv_tokens": tokens, "kv_slots": slots}
+
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """Count what a chunk of `clen` tokens at `pos0`, padded to
+        `bucket`, reads that the host can tell from those three, and
+        return its span's part."""
+        return {}
+
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
+        """Account what a `chunk` or a `decode` returned beside its
+        logits, now on the host, and return its span's part. `bucket`:
+        the rows a chunk was padded to (a decode step gives none)."""
+        return {}
+
+    def stats(self) -> dict:
+        return dict(self.counters)
+
+
+class ChunkOnlySet(DenseSet):
+    """What the families share that have one prefill program, their
+    chunk: whole prompts go through it, up to a length."""
+
+    #: the longest prompt the one-chunk whole-prompt prefill takes: past
+    #: it a chunk's temporaries outgrow what the pool leaves free, and
+    #: the engine has to chunk (prefill_chunk)
+    WHOLE_PROMPT_MAX = 4096
+
+    def prefill_kind(self, params: dict) -> str:
+        return "chunk"
+
+    def _fused(self, bucket: int) -> bool:
+        """Whether the chunk's attention walk updates a context tile in
+        one kernel (`sparse_moe.fused_attend`)."""
+        from nnstreamer_tpu.llm import sparse_moe
+
+        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
+                                       self.head_dim)
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        """Whole blocks are written at once where the chunk lies on
+        them: every chunk of a prompt does when block_size divides
+        prefill_chunk, so the bucket stays one program."""
+        bs = self.block_size
+        return dict(self.kw, fused=self._fused(bucket),
+                    by_block=int(pos0) % bs == 0 and bucket % bs == 0)
+
+    def _note_expert_tiles(self, counts: np.ndarray, bucket: int) -> dict:
+        """How full the row tiles were that a chunk's grouped products
+        visited (`sparse_moe._expert_layer`): counts (layers, held
+        experts) and the rows the chunk was padded to give the pair rows,
+        and the rule gives the tile (`sparse_moe.expert_row_tile`)."""
+        from nnstreamer_tpu.llm.sparse_moe import expert_row_tile
+
+        tm = expert_row_tile(bucket * self.spec.experts_per_tok,
+                             self.spec.n_experts)
+        visits = expert_tile_visits(counts, tm)
+        self.counters["expert_tile_visits"] += visits
+        self.counters["expert_tile_rows"] += visits * tm
+        return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
+            100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
 
     def check_prompt(self, plen: int, prefill_chunk: int) -> None:
         """The family prefills through its chunk program only, and one
@@ -285,12 +395,15 @@ class SparseMoESet(ChunkOnlySet):
         # Chunks: tokens at the busiest expert, summed over the chunks
         # whose counts have been read back (expert_load_chunks); context
         # tiles a layer's walks covered, and those of them the attention
-        # walk updated in one kernel (`sparse_moe.fused_attend`).
+        # walk updated in one kernel (`sparse_moe.fused_attend`); the
+        # (row tile, expert) visits one of a chunk's grouped products
+        # made over its layers, and the rows of those tiles.
         self.counters.update(dict.fromkeys((
             "kv_tokens_scored", "kv_tokens_selected", "idx_slots_read",
             "expert_tokens", "expert_steps_layers", "experts_touched_sum",
             "expert_load_max_sum", "expert_load_chunks",
-            "chunk_tiles_attended", "chunk_tiles_fused"), 0))
+            "chunk_tiles_attended", "chunk_tiles_fused",
+            "expert_tile_visits", "expert_tile_rows"), 0))
 
     def program(self, kind: str) -> Program:
         from nnstreamer_tpu.llm import sparse_moe
@@ -346,10 +459,11 @@ class SparseMoESet(ChunkOnlySet):
         return {"attend": "fused" if fused else "plain",
                 "ctx_tiles": tiles}
 
-    def note_beside(self, kind: str, host: list) -> dict:
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """One call's (layers, experts) token counts: distinct experts
         with a token, summed over layers, and for a chunk the tokens at
-        the busiest expert, largest over layers."""
+        the busiest expert, largest over layers, and how full its
+        grouped products' row tiles were."""
         counts, = host
         touched = int((counts > 0).sum())
         c = self.counters
@@ -361,7 +475,8 @@ class SparseMoESet(ChunkOnlySet):
         load_max = int(counts.max())
         c["expert_load_max_sum"] += load_max
         c["expert_load_chunks"] += 1
-        return {"experts_touched": touched, "expert_load_max": load_max}
+        return {"experts_touched": touched, "expert_load_max": load_max,
+                **_note_expert_tiles(self, counts, bucket)}
 
 
 class HybridSet(ChunkOnlySet):
@@ -541,12 +656,15 @@ class WindowMoESet(ChunkOnlySet):
         # expert) pairs of real tokens routed to held experts and away.
         # Chunks: context tiles a FULL and a WINDOW layer's walk covered;
         # tokens at the busiest held expert, summed over the chunks whose
-        # counts have been read back (expert_load_chunks)
+        # counts have been read back (expert_load_chunks); the (row tile,
+        # expert) visits one of a chunk's grouped products made over its
+        # expert layers, and the rows of those tiles
         self.counters.update(dict.fromkeys((
             "kv_tokens_full", "kv_tokens_window", "expert_pairs_held",
             "expert_pairs_away", "expert_steps_layers",
             "experts_touched_sum", "expert_load_max_sum",
-            "expert_load_chunks", "ctx_tiles_full", "ctx_tiles_window"), 0))
+            "expert_load_chunks", "ctx_tiles_full", "ctx_tiles_window",
+            "expert_tile_visits", "expert_tile_rows"), 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         """The FULL layers' pools under the pool's geometry as given;
@@ -639,11 +757,12 @@ class WindowMoESet(ChunkOnlySet):
                 "ctx_tiles_full": int(full),
                 "ctx_tiles_window": int(end - first)}
 
-    def note_beside(self, kind: str, host: list) -> dict:
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """One call's (expert layers, held + 1) counts: the real tokens'
         pairs at held experts and away, the distinct held experts with a
         token summed over layers, and for a chunk the tokens at the
-        busiest held expert, largest over layers."""
+        busiest held expert, largest over layers, and how full its
+        grouped products' row tiles were."""
         load, = host
         counts, away = load[:, :-1], int(load[:, -1].sum())
         touched, held = int((counts > 0).sum()), int(counts.sum())
@@ -659,7 +778,8 @@ class WindowMoESet(ChunkOnlySet):
         c["expert_load_max_sum"] += load_max
         c["expert_load_chunks"] += 1
         return {"experts_touched": touched, "expert_load_max": load_max,
-                "expert_pairs_held": held, "expert_pairs_away": away}
+                "expert_pairs_held": held, "expert_pairs_away": away,
+                **_note_expert_tiles(self, counts, bucket)}
 
 
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:

@@ -64,12 +64,27 @@ How each program reads it.
   inside a tile. XLA still reads the pool through the table for both:
   ``paged_kernel`` stays ``xla``.
 - Expert layer (both): (token, expert) pairs sorted by expert, two
-  grouped products (`jax.lax.ragged_dot`) over the experts that have
-  tokens, combined by the renormalised weights in f32. Experts without a
-  token are not read. Padding rows are routed past the last expert and
-  count for nothing; the pair rows are filled up to a multiple of 8
-  (`_GROUPED_ROWS`) the same way. Returns the tokens each expert got,
-  which rides the step's read-back.
+  grouped products over the experts that have tokens, combined by the
+  renormalised weights in f32. Experts without a token are not read.
+  Padding rows are routed past the last expert and count for nothing.
+  Returns the tokens each expert got, which rides the step's read-back.
+  Which product (`_grouped`, from the static count of pair rows alone):
+  a grouped product visits every (row tile, expert) pair that shares
+  rows, a whole row tile against that expert's matrices each time, and
+  the TPU compiler gives `jax.lax.ragged_dot` a row tile of min(512,
+  pair rows). Up to 512 pair rows (`_XLA_ROW_TILE`: every decode bucket
+  of both families, a chunk bucket of up to 512 / `experts_per_tok`
+  tokens) that tile is all the rows and the call stays
+  `jax.lax.ragged_dot`, its rows filled up to a multiple of 8
+  (`_GROUPED_ROWS`) past the last group. Beyond (a chunk of 2,048
+  tokens: 8,192 or 16,384 pair rows, of which an expert gets tens to a
+  few hundred) the matrix unit would be paid 512 rows a visit, so the
+  call goes to `pallas_ops.grouped_matmul`, the same grid with the row
+  tile `expert_row_tile` reckons from (pair rows, `n_experts`): 128 or
+  256. Same operands, float32 sums, every pair at every held expert;
+  the two differ in the order float32 sums are added inside a K tile.
+  The kernel leaves the rows past the last group unwritten: the
+  combine's mask is their only reader.
 """
 
 from __future__ import annotations
@@ -101,6 +116,13 @@ _FUSED_Q_BLOCK = 128
 # over all groups (compiled for a described v5e, and read on the chip:
 # PERF.md, PR 39).
 _GROUPED_ROWS = 8
+
+# The row tile the TPU compiler gives `jax.lax.ragged_dot`'s kernel: all
+# the pair rows up to this many, and this many beyond (read from the
+# compiled text's `ragged_dot_tiling`: PERF.md, PR 40). A visit of the
+# kernel is a whole row tile against one expert's matrices, so past this
+# count an expert's few rows are paid for as 512.
+_XLA_ROW_TILE = 512
 
 _F32 = jnp.float32
 _U32 = jnp.uint32
@@ -225,6 +247,47 @@ def _route(blk, g, spec: LMSpec, dtype):
         jnp.sum(p, axis=-1, keepdims=True) + 1e-20), e
 
 
+def _fit(size: int, want: int) -> int:
+    """The widest tile of at most `want` that divides `size` in whole
+    lane tiles of 128; the whole of a `size` that has none."""
+    t = min(want, size) // 128 * 128
+    while t and size % t:
+        t -= 128
+    return t or size
+
+
+def expert_row_tile(rows: int, n_experts: int) -> int:
+    """The row tile of the expert layer's grouped products over `rows`
+    (token, expert) pair rows dealt over `n_experts`: from the shapes
+    alone. Up to `_XLA_ROW_TILE` rows it is the compiler's own, all the
+    rows (filled to `_GROUPED_ROWS`). Beyond, a visit is one row tile
+    against one expert's matrices, and on the v5e it is bound by reading
+    those matrices up to about 240 rows (197 TFLOP/s over 819 GB/s) and
+    by the matrix unit past that: the tile is a few times the mean rows
+    an expert gets, so that an expert's rows span one or two tiles,
+    within 128 to 256 (the sweep on the chip: PERF.md, PR 40)."""
+    if rows <= _XLA_ROW_TILE:
+        return -(-rows // _GROUPED_ROWS) * _GROUPED_ROWS
+    mean = max(1, rows // n_experts)
+    return min(256, max(128, 1 << (2 * mean - 1).bit_length()))
+
+
+def _grouped(xs, w, counts, n_experts: int):
+    """The grouped product ``xs[rows of expert e] @ w[e]`` for pair rows
+    xs (R, K) sorted by expert, w (E, K, N), counts (E,): the compiler's
+    kernel where its row tile is all the rows, the repo's kernel with
+    `expert_row_tile`'s beyond. Rows past the last expert's are the
+    caller's to leave unread."""
+    rows, (_, kk, nn) = xs.shape[0], w.shape
+    if rows <= _XLA_ROW_TILE:
+        return jax.lax.ragged_dot(xs, w, counts)
+    # a (tk, tn) tile of an expert's matrix is 2 MB whatever the type
+    tk = _fit(kk, 2048 // xs.dtype.itemsize)
+    return pallas_ops.grouped_matmul(
+        xs, w, counts,
+        tiling=(expert_row_tile(rows, n_experts), tk, _fit(nn, 1024)))
+
+
 def _expert_layer(blk, g, live, spec: LMSpec, dtype):
     """The dropless expert layer for tokens g (N, D), `live` (N,) bool
     marking the real ones. The router scores all `n_experts`; `ewi` and
@@ -255,10 +318,13 @@ def _expert_layer(blk, g, live, spec: LMSpec, dtype):
     # belong to no expert, take it to the kernel
     if n * k % _GROUPED_ROWS:
         xs = jnp.pad(xs, ((0, -(n * k) % _GROUPED_ROWS), (0, 0)))
-    gu = jax.lax.ragged_dot(xs, blk["ewi"].astype(dtype), counts)
+    gu = _grouped(xs, blk["ewi"].astype(dtype), counts, spec.n_experts)
     mid = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-    out = jax.lax.ragged_dot(mid, blk["ewd"].astype(dtype), counts)
+    out = _grouped(mid, blk["ewd"].astype(dtype), counts, spec.n_experts)
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    # the rows past the last group are read by no pair that is `mine`:
+    # this `where` is their only reader, and the kernel leaves them
+    # unwritten
     out = out[inv].reshape(n, k, d).astype(_F32)
     y = jnp.sum(jnp.where(mine[..., None], out * p[..., None], 0.0),
                 axis=1)

@@ -79,7 +79,7 @@ class EchoSet(families.DenseSet):
         logits, depth, *pools = out
         return logits, (depth,), pools
 
-    def note_beside(self, kind, host):
+    def note_beside(self, kind, host, bucket=0):
         depth = int(np.max(host[0]))
         self.counters["echo_values"] += 1
         self.counters["echo_depth_sum"] += depth

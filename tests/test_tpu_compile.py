@@ -144,3 +144,74 @@ def test_the_expert_layers_grouped_products_take_the_kernel(one_chip, rows):
              and "%ragged-dot-metadata" not in ln.split("=")[0]]
     # the expansion is a convolution that dilates its input by the groups
     assert len(calls) == 2 and "lhs_dilate" not in text
+
+
+def _cell(cell):
+    """(configuration, spec) of a sparse-expert cell, by its short name."""
+    from perfbench.runners.sparse_moe_llm import lm_spec as sparse_spec
+    from perfbench.runners.window_moe_llm import lm_spec as window_spec
+    config, runner_spec = {
+        "trinity": ("trinity-large-preview-5l.json", window_spec),
+        "keye": ("keye-vl-2.0-30b-a3b-6l.json", sparse_spec)}[cell]
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs", config)) as f:
+        cfg = json.load(f)
+    return cfg, runner_spec(cfg)
+
+
+def _expert_layer_text(one_chip, cfg, spec, rows):
+    """The compiled text of `sparse_moe._expert_layer` in bfloat16 at a
+    cell's widths for `rows` tokens."""
+    d, f_, bf = cfg["hidden_size"], spec.expert_width, jnp.bfloat16
+    held = spec.experts_held or spec.n_experts
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = {"router": arg((d, spec.n_experts), bf),
+           "router_bias": arg((spec.n_experts,), jnp.float32),
+           "ewi": arg((held, d, 2 * f_), bf), "ewd": arg((held, f_, d), bf)}
+    return jax.jit(lambda b, g, live: sparse_moe._expert_layer(
+        b, g, live, spec, bf)).lower(
+        blk, arg((rows, d), bf), arg((rows,), jnp.bool_)
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("cell,tile", [("trinity", 128), ("keye", 256)])
+def test_a_chunks_grouped_products_take_the_repos_kernel(one_chip,
+                                                         monkeypatch, cell,
+                                                         tile):
+    """The expert layer of a chunk of 2,048 tokens at the Trinity cell's
+    widths (8,192 pair rows over 32 held experts of 3,072 x 6,144 and
+    3,072 x 3,072) and the Keye cell's (16,384 over 128 of 2,048 x 1,536
+    and 768 x 2,048): Mosaic takes `pallas_ops.grouped_matmul` at the
+    rule's tiles, twice, and the compiler's own grouped product, whose
+    row tile there is 512 (`ragged_dot_tiling="512,...`), is gone."""
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    cfg, spec = _cell(cell)
+    text = _expert_layer_text(one_chip, cfg, spec, 2048)
+    assert sparse_moe.expert_row_tile(2048 * spec.experts_per_tok,
+                                      spec.n_experts) == tile
+    assert "ragged_dot_tiling" not in text
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 2
+    assert all("grouped_matmul" in ln for ln in calls)
+
+
+@pytest.mark.parametrize("cell,rows", [("trinity", 16), ("keye", 8),
+                                       ("keye", 64)])
+def test_a_decode_buckets_grouped_products_stay_the_compilers(one_chip,
+                                                              monkeypatch,
+                                                              cell, rows):
+    """At a decode bucket's rows (and Keye's chunk bucket of 64: 512
+    pair rows) the compiler's row tile is already all the pair rows:
+    the layer keeps `jax.lax.ragged_dot` and holds no call of the repo's
+    kernel, so the decode programs are the parent's."""
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    cfg, spec = _cell(cell)
+    text = _expert_layer_text(one_chip, cfg, spec, rows)
+    assert "grouped_matmul" not in text
+    tilings = {ln.split("ragged_dot_tiling=\"")[1].split(",")[0]
+               for ln in text.splitlines() if "ragged_dot_tiling=\"" in ln}
+    assert tilings == {str(rows * spec.experts_per_tok)}
