@@ -40,6 +40,16 @@ weights, frames and prompts:
                    tables a sequence through the same element on the chip
                    serve the tokens the same engine serves on this host's
                    CPU device, with window blocks given back and regranted;
+- ``llm_latent_moe`` the latent family (llm/latent_moe.py) at a tiny size
+                   (hidden 64, 4 heads of 8 + 4 | 8 through ranks 24 and 16,
+                   YaRN, blocks of 4, the first layer dense, then shared
+                   experts beside 2 held of 16 routed experts, 3 of 8 groups
+                   and 4 a token, float32): chunked prefill and decode over
+                   the pool of latents and roped keys through the same
+                   element on the chip serve the tokens the same engine
+                   serves on this host's CPU device, once with chunks of 8
+                   (the absorbed form) and once with chunks of 32 (the
+                   expanded form);
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -225,6 +235,32 @@ def leg_kernels() -> dict:
           lambda q, kp, vp: window_moe.attend_tiles(
               q, qpos, tab, span, 1, kp, vp, window=1536, fused=False,
               tile=tile, dtype=jnp.bfloat16), 3e-2)
+
+    # the latent family's chunk walk at the published head widths (16
+    # heads of 128 + 64 | 128 over latents of 512 and roped keys of 64, two
+    # a row, pool blocks of 64): the expanded form through the same kernel
+    # (a head's K filled up to 256, its V 128 wide) against the absorbed
+    # form through the plain update, tiles 0 to 2 of a table of 4
+    from nnstreamer_tpu.llm import latent_moe
+
+    la = LMSpec(family="latent_moe", n_heads=16, q_rank=256, kv_rank=512,
+                nope_dim=128, rope_dim=64, v_dim=128, yarn_factor=40.0,
+                yarn_orig_len=4096, yarn_mscale=0.707,
+                yarn_mscale_all_dim=0.707)
+    span = (0, 3)
+    wkvb = normal((512, 16, 256), jnp.bfloat16) * 0.05
+
+    def latent_walk(expanded, fused):
+        return lambda qn, qp, kp, ip: latent_moe.attend_tiles(
+            qn, qp, qpos, tab, span, 1, kp, ip, wkvb, expanded=expanded,
+            fused=fused, tile=tile, spec=la, dtype=jnp.bfloat16)
+
+    check("latent_walk_fused", latent_walk(True, True),
+          (normal((c, 16, 128), jnp.bfloat16),
+           normal((c, 16, 64), jnp.bfloat16),
+           normal((2, 96, 64, 1, 512), jnp.bfloat16),
+           normal((2, 96, 32, 128), jnp.bfloat16)),
+          latent_walk(False, False), 3e-2)
 
     # a chunk's expert layer through the grouped-product kernel (1,024
     # tokens x 4 of 64 experts, 8 held: 4,096 pair rows of which an
@@ -796,6 +832,118 @@ def leg_llm_window_moe() -> dict:
             "window_blocks_freed": cache["window_blocks_freed"]}
 
 
+LATENT = dict(d=64, heads=4, q_rank=24, kv_rank=16, nope=8, rope=4, v=8,
+              dense_width=160, width=32, experts=16, first=4, held=2,
+              groups=8, topk_group=3, per_tok=4, vocab=256, layers=3)
+
+
+def _latent_bundle(device):
+    """Seeded float32 weights of the tiny latent-family model on `device`,
+    in the family's hand-over layout, with its description."""
+    import jax
+    import numpy as np
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.llm.spec import LATENT_MOE, LMSpec
+
+    c = LATENT
+    rng = np.random.default_rng(23)
+
+    def w(*shape):
+        lim = (6.0 / (shape[-2] + shape[-1])) ** 0.5
+        return rng.uniform(-lim, lim, shape).astype(np.float32)
+
+    ones = lambda n: np.ones((n,), np.float32)          # noqa: E731
+    h = c["heads"]
+
+    def layer(dense):
+        out = dict(ln1=ones(c["d"]), ln2=ones(c["d"]),
+                   wqa=w(c["d"], c["q_rank"]), q_norm=ones(c["q_rank"]),
+                   wqb=w(c["q_rank"], h * (c["nope"] + c["rope"])),
+                   wkva=w(c["d"], c["kv_rank"] + c["rope"]),
+                   kv_norm=ones(c["kv_rank"]),
+                   wkvb=w(c["kv_rank"], h * (c["nope"] + c["v"])),
+                   wo=w(h * c["v"], c["d"]))
+        if dense:
+            out.update(wi=w(c["d"], 2 * c["dense_width"]),
+                       wd=w(c["dense_width"], c["d"]))
+            return out
+        out.update(router=w(c["d"], c["experts"]),
+                   ewi=w(c["held"], c["d"], 2 * c["width"]),
+                   ewd=w(c["held"], c["width"], c["d"]),
+                   swi=w(c["d"], 4 * c["width"]),
+                   swd=w(2 * c["width"], c["d"]))
+        return out
+
+    params = {"embed": w(c["vocab"], c["d"]),
+              "blocks": [layer(i == 0) for i in range(c["layers"])],
+              "ln_f": ones(c["d"]), "head": w(c["d"], c["vocab"])}
+    spec = LMSpec(family=LATENT_MOE, n_heads=h, q_rank=c["q_rank"],
+                  kv_rank=c["kv_rank"], nope_dim=c["nope"],
+                  rope_dim=c["rope"], v_dim=c["v"], yarn_factor=40.0,
+                  yarn_orig_len=16, yarn_mscale=0.707,
+                  yarn_mscale_all_dim=0.707, dense_layers=1,
+                  dense_width=c["dense_width"], shared_width=2 * c["width"],
+                  n_experts=c["experts"], experts_per_tok=c["per_tok"],
+                  expert_width=c["width"], n_group=c["groups"],
+                  topk_group=c["topk_group"], route_norm=False,
+                  route_scale=16.0, experts_first=c["first"],
+                  experts_held=c["held"])
+    return ModelBundle(fn=None, lm=spec, params=jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, device), params))
+
+
+def leg_llm_latent_moe() -> dict:
+    """As `leg_llm_sparse_moe`, for the family whose pool holds a latent
+    and a roped key a token and no values: once with chunks of 8, which
+    attend absorbed, once with chunks of 32, which attend expanded
+    (`latent_moe.expanded_attend`: these widths cross at 16 queries)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.llm.engine import LLMEngine
+    from nnstreamer_tpu.serving.store import get_store
+
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, LATENT["vocab"], size=n).astype(np.int32)
+               for n in (5, 12, 29, 41)]
+    cpu = jax.devices("cpu")[0]
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    out = {}
+    try:
+        get_store().register("chip_smoke_latent_moe",
+                             _latent_bundle(jax.devices()[0]))
+        for form, chunk in (("absorbed", 8), ("expanded", 32)):
+            serving = dict(block_size=4, num_blocks=96, max_len=64,
+                           prefill_chunk=chunk)
+            with jax.default_device(cpu):
+                eng = LLMEngine(_latent_bundle(cpu), dtype=jnp.float32,
+                                max_batch=8, **serving)
+                reqs = [eng.submit(p, req_id=f"req{i}",
+                                   max_new_tokens=LLM_NEW_TOKENS)
+                        for i, p in enumerate(prompts)]
+                eng.drain()
+                want = {r.req_id: list(r.tokens) for r in reqs}
+                eng.executor.close()
+            toks, stats = _run_llm("store://chip_smoke_latent_moe", prompts,
+                                   dtype="float32", paged_kernel="xla",
+                                   **serving)
+            ex, cache = stats["executor"], stats["cache"]
+            assert ex["family"] == "latent_moe", ex
+            assert ex["expert_pairs_held"] > 0 < ex["expert_pairs_away"], ex
+            assert (ex["latents_expanded"] > 0) == (form == "expanded"), ex
+            assert cache["pools"] == 2 and cache["blocks_used"] == 0, cache
+            assert toks == want, \
+                f"{form}: greedy tokens differ: chip {toks} vs cpu {want}"
+            out[f"chunk_prefills_{form}"] = ex["chunk_prefills"]
+            out["tokens"] = out.get("tokens", 0) + stats["tokens_out"]
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+    return out
+
+
 # -- four chips --------------------------------------------------------------
 
 def leg_multichip(model: str, ref) -> dict:
@@ -916,6 +1064,7 @@ def main() -> int:
     leg("llm_sparse_moe", leg_llm_sparse_moe)
     leg("llm_hybrid", leg_llm_hybrid)
     leg("llm_window_moe", leg_llm_window_moe)
+    leg("llm_latent_moe", leg_llm_latent_moe)
     if dev["count"] >= 4 and ref:
         leg("multichip", leg_multichip, model, ref)
     else:

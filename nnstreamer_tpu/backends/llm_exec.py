@@ -266,7 +266,9 @@ class PagedLLMExecutor:
         # the family keeps one a sequence (the engine passes max_batch)
         self.cache = PagedKVCache(
             num_blocks=int(num_blocks), block_size=bs,
-            head_dim=self.head_dim, idx_dim=self.programs.idx_dim,
+            # the width of a row of the `k` pool is the family's to say:
+            # a head's, or the latent's where no head has a row
+            head_dim=self.programs.head_dim, idx_dim=self.programs.idx_dim,
             dtype=self.dtype, placer=placer, state_slots=int(state_slots),
             **self.programs.cache_kw(self.n_layers))
         #: bytes of a value in the pools, which keep K, V and the indexer's

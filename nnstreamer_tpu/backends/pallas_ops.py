@@ -505,9 +505,11 @@ def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
     of Sk slots; t (C,) uint32 and cut (C,) int32: query c attends slot
     s where ``keys[c, s] > t[c]``, or ``keys[c, s] == t[c]`` and
     ``s <= cut[c]``. Updates the flash carry m, l (Hkv, G, C) f32 and
-    acc (Hkv, G, C, D) f32 in place; a query the tile selects nothing
+    acc (Hkv, G, C, Dv) f32 in place; a query the tile selects nothing
     for keeps its carry. Needs D a multiple of 128 (a KV head is a lane
-    tile of the (Sk, Hkv * D) view) unless Hkv is 1.
+    tile of the (Sk, Hkv * D) view) unless Hkv is 1. The values may be
+    of another width than the keys, v_blk (Sk, Hkv, Dv) under the same
+    rule (the latent family's expanded heads: llm/latent_moe.py).
 
     A program takes `block_q` queries against the whole tile: on the
     v5e, at Sk 1024 and 8 heads of 128 a group, 128 queries read 0.40 ms
@@ -520,18 +522,21 @@ def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
         raise ValueError(
             f"selected_block_update needs C={c} divisible by {bq} and "
             f"S={keys.shape[1]} by Sk={sk}")
+    dv = v_blk.shape[2]
     kern = functools.partial(_selected_block_kernel, d ** -0.5)
     blk_q = pl.BlockSpec((1, grp, bq, d), lambda g, i, j: (g, 0, i, 0))
-    blk_kv = pl.BlockSpec((sk, d), lambda g, i, j: (0, g))
+    blk_a = pl.BlockSpec((1, grp, bq, dv), lambda g, i, j: (g, 0, i, 0))
+    blk_k = pl.BlockSpec((sk, d), lambda g, i, j: (0, g))
+    blk_v = pl.BlockSpec((sk, dv), lambda g, i, j: (0, g))
     blk_c = pl.BlockSpec((bq, 1), lambda g, i, j: (i, 0))
     blk_m = pl.BlockSpec((1, grp, bq), lambda g, i, j: (g, 0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nkv, c // bq),
-        in_specs=[blk_q, blk_kv, blk_kv,
+        in_specs=[blk_q, blk_k, blk_v,
                   pl.BlockSpec((bq, sk), lambda g, i, j: (i, j[0])),
-                  blk_c, blk_c, blk_m, blk_m, blk_q],
-        out_specs=[blk_m, blk_m, blk_q])
+                  blk_c, blk_c, blk_m, blk_m, blk_a],
+        out_specs=[blk_m, blk_m, blk_a])
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -545,7 +550,7 @@ def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret() if interpret is None else interpret,
     )(jnp.asarray(tile, jnp.int32).reshape(1), q,
-      k_blk.reshape(sk, nkv * d), v_blk.reshape(sk, nkv * d), keys,
+      k_blk.reshape(sk, nkv * d), v_blk.reshape(sk, nkv * dv), keys,
       t[:, None], cut[:, None], m, l, acc)
 
 
@@ -629,6 +634,12 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
         lhs = jnp.pad(lhs, ((0, -m % tm), (0, 0)))
     rows = lhs.shape[0]
     offsets, group, tile, visits = group_visits(group_sizes, rows, tm)
+    # what a visit keeps in fast memory, its blocks buffered twice; past
+    # the compiler's own limit of 16 MB a kernel (float32 at a hidden size
+    # of 5,120: 22 MB) the call asks for what it needs
+    need = (2 * lhs.dtype.itemsize * (tm * kk + tm * nn + tk * tn)
+            + 4 * tm * tn)
+    limit = need + (4 << 20) if need > (14 << 20) else None
     out = pl.pallas_call(
         functools.partial(_grouped_matmul_kernel, tm, tk, tn),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -645,7 +656,8 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
         name="grouped_matmul",
         # a row tile's output is revisited by the next visit: in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=limit),
         interpret=_interpret() if interpret is None else interpret,
     )(offsets, group, tile, lhs, rhs)
     return out[:m]

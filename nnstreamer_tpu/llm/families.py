@@ -27,6 +27,9 @@ family, a kernel and a kind of call about:
   compressed keys a sequence, whose slot the executor hands `chunk_args`
   and `decode_args` as `slot` / `slots`), and the window layers' pools
   under a table a sequence of their own (handed as `window`, last);
+  `values: False` where the family keeps no V pool, and `head_dim`, the
+  width of a row of the `k` pool (a head's, or the latent family's one
+  compressed row a token);
 - its accounting: `note_decode`, `note_chunk` and `note_beside` count what
   a call attended and read and return what its span says of it; `stats()`
   is the family's part of the executor's.
@@ -44,7 +47,8 @@ import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
 from nnstreamer_tpu.llm.spec import (
-    DENSE, FULL, HYBRID, LINEAR, SPARSE, SPARSE_MOE, WINDOW, WINDOW_MOE)
+    DENSE, FULL, HYBRID, LATENT_MOE, LINEAR, SPARSE, SPARSE_MOE, WINDOW,
+    WINDOW_MOE)
 
 
 def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
@@ -75,6 +79,31 @@ def _note_expert_tiles(ps, counts: np.ndarray, bucket: int) -> dict:
     ps.counters["expert_tile_rows"] += visits * tm
     return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
         100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
+
+
+def _note_held_load(ps, kind: str, load: np.ndarray, bucket: int) -> dict:
+    """One call's (expert layers, held + 1) counts, for the set `ps` of a
+    family that holds a share of its experts: the real tokens' pairs at
+    held experts and away, the distinct held experts with a token summed
+    over layers, and for a chunk the tokens at the busiest held expert,
+    largest over layers, and how full its grouped products' row tiles
+    were. Counts them and returns the span's part."""
+    counts, away = load[:, :-1], int(load[:, -1].sum())
+    touched, held = int((counts > 0).sum()), int(counts.sum())
+    c = ps.counters
+    c["expert_pairs_held"] += held
+    c["expert_pairs_away"] += away
+    if kind == "decode":
+        c["expert_steps_layers"] += counts.shape[0]
+        c["experts_touched_sum"] += touched
+        return {"experts_touched": touched, "expert_pairs_held": held,
+                "expert_pairs_away": away}
+    load_max = int(counts.max())
+    c["expert_load_max_sum"] += load_max
+    c["expert_load_chunks"] += 1
+    return {"experts_touched": touched, "expert_load_max": load_max,
+            "expert_pairs_held": held, "expert_pairs_away": away,
+            **_note_expert_tiles(ps, counts, bucket)}
 
 
 class Program(NamedTuple):
@@ -266,85 +295,6 @@ class ChunkOnlySet(DenseSet):
         bs = self.block_size
         return dict(self.kw, fused=self._fused(bucket),
                     by_block=int(pos0) % bs == 0 and bucket % bs == 0)
-
-    def check_prompt(self, plen: int, prefill_chunk: int) -> None:
-        """Refuse a prompt this family can never prefill."""
-
-    # -- accounting --------------------------------------------------------
-    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
-        """Count what one decode step from the bucket's positions
-        `pos_a` (`n` live rows first) attends and reads, and return its
-        span's part: kv_tokens, the live rows' context with the step's
-        own tokens; kv_slots, the pool slots one layer gathers, padding
-        rows and the walk's rounding included."""
-        if self._walk_slots is not None:
-            slots = self._walk_slots(pos_a, self.block_size, self.n_kv,
-                                     self.head_dim, self.max_blocks)
-        else:
-            slots = len(pos_a) * self.max_blocks * self.block_size
-        tokens = int(pos_a[:n].sum()) + n
-        self.counters["kv_tokens_attended"] += tokens
-        self.counters["kv_slots_read"] += slots
-        return {"kv_tokens": tokens, "kv_slots": slots}
-
-    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
-        """Count what a chunk of `clen` tokens at `pos0`, padded to
-        `bucket`, reads that the host can tell from those three, and
-        return its span's part."""
-        return {}
-
-    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
-        """Account what a `chunk` or a `decode` returned beside its
-        logits, now on the host, and return its span's part. `bucket`:
-        the rows a chunk was padded to (a decode step gives none)."""
-        return {}
-
-    def stats(self) -> dict:
-        return dict(self.counters)
-
-
-class ChunkOnlySet(DenseSet):
-    """What the families share that have one prefill program, their
-    chunk: whole prompts go through it, up to a length."""
-
-    #: the longest prompt the one-chunk whole-prompt prefill takes: past
-    #: it a chunk's temporaries outgrow what the pool leaves free, and
-    #: the engine has to chunk (prefill_chunk)
-    WHOLE_PROMPT_MAX = 4096
-
-    def prefill_kind(self, params: dict) -> str:
-        return "chunk"
-
-    def _fused(self, bucket: int) -> bool:
-        """Whether the chunk's attention walk updates a context tile in
-        one kernel (`sparse_moe.fused_attend`)."""
-        from nnstreamer_tpu.llm import sparse_moe
-
-        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
-                                       self.head_dim)
-
-    def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        """Whole blocks are written at once where the chunk lies on
-        them: every chunk of a prompt does when block_size divides
-        prefill_chunk, so the bucket stays one program."""
-        bs = self.block_size
-        return dict(self.kw, fused=self._fused(bucket),
-                    by_block=int(pos0) % bs == 0 and bucket % bs == 0)
-
-    def _note_expert_tiles(self, counts: np.ndarray, bucket: int) -> dict:
-        """How full the row tiles were that a chunk's grouped products
-        visited (`sparse_moe._expert_layer`): counts (layers, held
-        experts) and the rows the chunk was padded to give the pair rows,
-        and the rule gives the tile (`sparse_moe.expert_row_tile`)."""
-        from nnstreamer_tpu.llm.sparse_moe import expert_row_tile
-
-        tm = expert_row_tile(bucket * self.spec.experts_per_tok,
-                             self.spec.n_experts)
-        visits = expert_tile_visits(counts, tm)
-        self.counters["expert_tile_visits"] += visits
-        self.counters["expert_tile_rows"] += visits * tm
-        return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
-            100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
 
     def check_prompt(self, plen: int, prefill_chunk: int) -> None:
         """The family prefills through its chunk program only, and one
@@ -763,23 +713,151 @@ class WindowMoESet(ChunkOnlySet):
         token summed over layers, and for a chunk the tokens at the
         busiest held expert, largest over layers, and how full its
         grouped products' row tiles were."""
-        load, = host
-        counts, away = load[:, :-1], int(load[:, -1].sum())
-        touched, held = int((counts > 0).sum()), int(counts.sum())
-        c = self.counters
-        c["expert_pairs_held"] += held
-        c["expert_pairs_away"] += away
-        if kind == "decode":
-            c["expert_steps_layers"] += counts.shape[0]
-            c["experts_touched_sum"] += touched
-            return {"experts_touched": touched, "expert_pairs_held": held,
-                    "expert_pairs_away": away}
-        load_max = int(counts.max())
-        c["expert_load_max_sum"] += load_max
-        c["expert_load_chunks"] += 1
-        return {"experts_touched": touched, "expert_load_max": load_max,
-                "expert_pairs_held": held, "expert_pairs_away": away,
-                **_note_expert_tiles(self, counts, bucket)}
+        return _note_held_load(self, kind, host[0], bucket)
+
+
+class LatentMoESet(ChunkOnlySet):
+    """The decoder whose attention is latent (llm/latent_moe.py): one
+    prefill program, its chunk; two pools under one table, a token's
+    compressed row (`k`: one row of `kv_rank` a slot, no head axis) and
+    its roped key (`idx`, packed as the indexer's keys), and no V pool;
+    the decode step attends in the latent (absorbed), a chunk in the form
+    `latent_moe.expanded_attend` picks from its bucket; each call
+    returns, an expert layer, the tokens each held expert got and the
+    pairs routed to experts that are not held, beside its logits."""
+
+    family = LATENT_MOE
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        layers = len(params["blocks"])
+        # what the family cannot yet be combined with (ROADMAP B4)
+        why = None
+        if self.shards > 0:
+            why = (f"shards={self.shards}: `kv_pool_placer` shards the "
+                   f"pools along their head axis, and a latent row has "
+                   f"none (ROADMAP B4)")
+        elif self.paged_kernel == "pallas":
+            why = ("paged_kernel=pallas: it has no Pallas walk in the "
+                   "latent yet (ROADMAP B4); set paged_kernel=xla")
+        elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
+            why = ("a W8A8 store version: its absorbed products and its "
+                   "grouped expert products are float only")
+        elif min(spec.q_rank, spec.kv_rank, spec.nope_dim, spec.v_dim) < 1 \
+                or spec.rope_dim < 2 or spec.rope_dim % 2:
+            why = (f"ranks {spec.q_rank} / {spec.kv_rank} and head widths "
+                   f"{spec.nope_dim} + {spec.rope_dim} / {spec.v_dim}: "
+                   f"every one has to be set, the roped width even")
+        elif not 0 <= spec.dense_layers < layers:
+            why = (f"dense_layers={spec.dense_layers} of {layers}: at "
+                   f"least one layer has to be an expert layer")
+        elif spec.n_group > 1 and (
+                spec.n_experts % spec.n_group
+                or not 0 < spec.topk_group <= spec.n_group
+                or spec.experts_per_tok > spec.topk_group
+                * (spec.n_experts // spec.n_group)):
+            why = (f"{spec.n_experts} experts in {spec.n_group} groups of "
+                   f"which {spec.topk_group} are chosen for "
+                   f"{spec.experts_per_tok} a token: the groups have to "
+                   f"be equal and the chosen ones hold a token's experts")
+        if why is not None:
+            raise BackendError(
+                f"llm {self.name}: the latent_moe family cannot be served "
+                f"with {why}")
+        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
+        # `note_decode` counts this family's walk: every layer attends each
+        # live row's whole context in the latent, in whole iterations of T
+        # chunks of C slots (`latent_moe.walk_plan`), every row of the
+        # bucket in it
+        from nnstreamer_tpu.llm.latent_moe import walk_slots
+
+        self._walk_slots = lambda pos, bs, n_kv, hd, mb: walk_slots(
+            pos, bs, mb)
+        # the pool's row: one latent a token and, beside it, its roped key
+        self.n_kv, self.head_dim = 1, int(spec.kv_rank)
+        self.idx_dim = int(spec.rope_dim)
+        self.expert_layers = layers - spec.dense_layers
+        # kept tracer on or off. Decode steps: live context the steps
+        # attended, pool slots a layer gathered for it (whole iterations
+        # of the walk, padding rows included; a slot is kv_rank + rope_dim
+        # values); (layer, step) pairs and the distinct held experts that
+        # got a token in them. Every call: (token, expert) pairs of real
+        # tokens routed to held experts and away. Chunks: context tiles a
+        # layer's walk covered, and the context tokens a layer put through
+        # Wkvb (the expanded form's; 0 for an absorbed chunk); tokens at
+        # the busiest held expert, summed over the chunks whose counts
+        # have been read back (expert_load_chunks); the (row tile,
+        # expert) visits one of a chunk's grouped products made over its
+        # expert layers, and the rows of those tiles
+        self.counters.update(dict.fromkeys((
+            "latents_expanded", "chunk_tiles_attended", "expert_pairs_held",
+            "expert_pairs_away", "expert_steps_layers",
+            "experts_touched_sum", "expert_load_max_sum",
+            "expert_load_chunks", "expert_tile_visits",
+            "expert_tile_rows"), 0))
+
+    def cache_kw(self, n_layers: int) -> dict:
+        return {"n_layers": n_layers, "n_kv": 1, "values": False}
+
+    def program(self, kind: str) -> Program:
+        from nnstreamer_tpu.llm import latent_moe
+
+        if kind == "chunk":
+            return Program(latent_moe.latent_moe_prefill_chunk,
+                           ("spec", "dtype", "by_block", "fused",
+                            "expanded", "tile"), (6, 7))
+        return Program(latent_moe.latent_moe_decode_step,
+                       ("spec", "dtype"), (5, 6))
+
+    def _expanded(self, bucket: int) -> bool:
+        from nnstreamer_tpu.llm.latent_moe import expanded_attend
+
+        return expanded_attend(bucket, self.spec)
+
+    def _fused(self, bucket: int) -> bool:
+        """The expanded form's tile update in one kernel, where a head's
+        values are whole lane tiles (its keys are filled up to that)."""
+        from nnstreamer_tpu.llm import sparse_moe
+
+        return self._expanded(bucket) and sparse_moe.fused_attend(
+            bucket, sparse_moe._CTX_TILE, self.spec.v_dim)
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+
+        return dict(super().chunk_kw(pos0, bucket),
+                    expanded=self._expanded(bucket), tile=_CTX_TILE)
+
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None, window=None) -> tuple:
+        # n live rows: a step's padding rows reach no expert
+        return (params, cur, tab, pos, np.int32(n), *pools)
+
+    def split(self, out: tuple) -> tuple:
+        logits, load, *pools = out
+        return logits, (load,), pools
+
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """The context tiles a layer's walk covers (the program's own
+        trip count, `window_moe.tile_span`), the form it attends them in
+        and, expanded, the context tokens a layer puts through Wkvb."""
+        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+        from nnstreamer_tpu.llm.window_moe import tile_span
+
+        _, tiles = tile_span(pos0, bucket, self.max_blocks * self.block_size,
+                             _CTX_TILE)
+        expanded = self._expanded(bucket)
+        through = int(tiles) * _CTX_TILE * expanded
+        self.counters["chunk_tiles_attended"] += int(tiles)
+        self.counters["latents_expanded"] += through
+        return {"pos0": pos0, "ctx_tiles": int(tiles),
+                "attend": "expanded" if expanded else "absorbed",
+                "latents_expanded": through}
+
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
+        """One call's (expert layers, held + 1) counts, as the window
+        family's."""
+        return _note_held_load(self, kind, host[0], bucket)
 
 
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
@@ -807,7 +885,8 @@ def _chunk_reads(spec, pos0: int, clen: int, slots: int) -> dict:
 
 #: `LMSpec.family` -> its program set
 FAMILIES: Dict[str, type] = {DENSE: DenseSet, SPARSE_MOE: SparseMoESet,
-                             HYBRID: HybridSet, WINDOW_MOE: WindowMoESet}
+                             HYBRID: HybridSet, WINDOW_MOE: WindowMoESet,
+                             LATENT_MOE: LatentMoESet}
 
 
 def program_set(spec, *, name: str, **given):

@@ -1,0 +1,477 @@
+"""The decoder whose attention is latent: a token keeps one compressed
+row and one roped key, all heads share both, and two programs read that
+one cache in two forms (pure jax, jitted by llm_exec as
+``jit_latent_moe_decode_step`` and ``jit_latent_moe_prefill_chunk``).
+
+What is new in this family lives here: the projections through the two
+ranks, YaRN's rope, the two forms of the attention and the rule that picks
+one. The norms (`rmsnorm`), the products (`_proj`), the dense SwiGLU
+(`_mlp_paged`) and the decode step's work list (`_live_items`) are the
+dense family's functions; the expert layer (`sparse_moe._expert_layer`,
+whose router chooses inside groups under this family's spec), the chunk's
+tile update (`sparse_moe.attend_plain`,
+`pallas_ops.selected_block_update`) and the writes of whole blocks the
+sparse-expert family's; the walk's bounds (`tile_span`) the window
+family's.
+
+The layer, for input x at position t, two RMSNorms (`norm_eps`):
+``h = x + Attn(N1(x))``, ``y = h + MLP(N2(h))``; after the last layer a
+final norm and the untied head. No biases.
+
+- ``Attn(u)``: ``cq = RMSNorm(u Wqa)`` (`q_rank`); ``q = cq Wqb``: H heads
+  of ``nope_dim + rope_dim`` = (q_nope | q_pe). ``u Wkva`` (`kv_rank` +
+  `rope_dim`) = (c | k_pe): ``c = RMSNorm(c)``, the latent; k_pe is one key
+  for all heads and is not normed. ``c Wkvb``: H heads of ``nope_dim +
+  v_dim`` = (k_nope | v). Rope on q_pe and k_pe: the pairs (2i, 2i + 1)
+  turned by ``t * f_i`` (`yarn_freqs`), written back as (the pairs' first
+  values | their second values), the same for q and k, so the dot product
+  is the interleaved one's. Scores in float32,
+  ``(q_nope . k_nope + q_pe . k_pe) * score_scale``, causal, softmax in
+  float32; the heads' sums of v, side by side, through ``Wo``.
+- ``MLP`` of the first `dense_layers` layers: a SwiGLU of `dense_width`.
+  Of the others: a shared SwiGLU of `shared_width` every token passes,
+  unweighted, plus the routed experts' part (`sparse_moe._route`: softmax
+  over all `n_experts`, `topk_group` of `n_group` groups by their best
+  expert, `experts_per_tok` among those, weights ``route_scale * s_e`` and
+  not renormalised), summed over the chosen experts held here.
+
+State: two pools under one table a sequence (`PagedKVCache` with
+``values=False``): the latents ``(layers, blocks, block_size, 1,
+kv_rank)`` and the roped keys ``(layers, blocks, block_size // pack, pack
+* rope_dim)``, `pack` neighbouring tokens side by side in a row of 128
+values (`paged_cache.idx_pack`, as the sparse-expert family's indexer
+keys). At the published widths 512 + 64 values a token a layer, 1,152
+bytes in bfloat16, both minor dimensions whole lane tiles, and no V pool:
+the values are a product of the latent.
+
+The two forms, over the same cache.
+
+- *Absorbed* (`absorbed_queries`): ``Wkvb`` a head is (W_UK | W_UV);
+  ``q_nope . k_nope = (q_nope W_UK^T) . c`` and a head's output is
+  ``(sum_s p_s c_s) W_UV``, so a query attends in the latent: 2 H (2
+  kv_rank + rope_dim) operations a (query, key), nothing expanded, every
+  head reading the same row. The decode step always (one query a row: a
+  work list of live chunks as the dense family's, merged by an online
+  softmax a row and head), and a chunk of few queries.
+- *Expanded* (`attend_tiles` with ``expanded``): a context tile's latents
+  go through ``Wkvb`` once for all the chunk's queries, 2 kv_rank H
+  (nope_dim + v_dim) a key, and a pair then costs 2 H (nope_dim + rope_dim
+  + v_dim). A chunk of many queries.
+
+`expanded_attend` picks from the static bucket alone, by the operations:
+at the published widths the forms cross at 171 queries a key. How a tile
+updates the softmax's carry is `sparse_moe.fused_attend`'s to say (the
+expanded form only: a head there has a K and a V of its own, which is the
+kernel's layout; its K is filled with zeros to a whole lane tile).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from nnstreamer_tpu.backends import pallas_ops
+from nnstreamer_tpu.llm import sparse_moe
+from nnstreamer_tpu.llm.paged_model import _live_items, _mlp_paged, _proj
+from nnstreamer_tpu.llm.spec import LMSpec
+from nnstreamer_tpu.llm.window_moe import _write_chunk, tile_span
+from nnstreamer_tpu.models.transformer import rmsnorm
+
+_F32 = jnp.float32
+
+# The decode walk's extents: slots a chunk of the work list holds (whole
+# blocks) and chunks an iteration gathers and attends. All heads read one
+# row a slot, so a chunk is a (heads, kv_rank + rope_dim) x (slots) product
+# and wants many slots; a row wastes half a chunk of masked slots.
+_DECODE_CHUNK = 512
+_DECODE_ITEMS = 16
+
+
+# -- YaRN -----------------------------------------------------------------------
+
+def _mscale(factor: float, a: float) -> float:
+    return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(spec: LMSpec) -> np.ndarray:
+    """The angle a position of pair i of the roped dims: ``e_i =
+    theta^(-2i / rope_dim)``, divided by `yarn_factor` past the ramp
+    between the pairs that make `yarn_beta_fast` and `yarn_beta_slow`
+    turns over `yarn_orig_len` positions. (rope_dim / 2,) float32."""
+    dim, theta = spec.rope_dim, float(spec.rope_theta)
+    e = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not spec.yarn_factor:
+        return e.astype(np.float32)
+
+    def corr(turns):
+        return dim * math.log(spec.yarn_orig_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(spec.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(spec.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (e * (1 - ramp) + e / spec.yarn_factor * ramp).astype(np.float32)
+
+
+def rope_gain(spec: LMSpec) -> float:
+    """What cos and sin are multiplied by."""
+    if not spec.yarn_factor:
+        return 1.0
+    return _mscale(spec.yarn_factor, spec.yarn_mscale) \
+        / _mscale(spec.yarn_factor, spec.yarn_mscale_all_dim)
+
+
+def score_scale(spec: LMSpec) -> float:
+    """What a score is multiplied by: the key's width and YaRN's
+    ``m(factor, mscale_all_dim)^2`` (0.114721 at the published values)."""
+    m = _mscale(spec.yarn_factor, spec.yarn_mscale_all_dim) \
+        if spec.yarn_factor else 1.0
+    return (spec.nope_dim + spec.rope_dim) ** -0.5 * m * m
+
+
+def _rope(x, pos, spec: LMSpec):
+    """x (N, ..., rope_dim) at positions pos (N,): the pairs (2i, 2i + 1)
+    turned by pos * f_i; (first values | second values) out."""
+    ang = pos.astype(_F32)[:, None] * jnp.asarray(yarn_freqs(spec))[None, :]
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    gain = rope_gain(spec)
+    cos, sin = jnp.cos(ang) * gain, jnp.sin(ang) * gain
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+# -- what both programs share ------------------------------------------------------
+
+def _norm(w, x, spec: LMSpec, dtype):
+    return rmsnorm(x, w.astype(dtype), spec.norm_eps)
+
+
+def _project(blk, x, pos, spec: LMSpec, dtype):
+    """x (N, 1, D) at positions pos (N,): q_nope (N, H, nope), q_pe (N,
+    H, rope) roped, the latent c (N, kv_rank) normed, k_pe (N, rope)
+    roped. Rows are independent: a decode batch and a chunk's tokens take
+    the same path."""
+    n = x.shape[0]
+    u = _norm(blk["ln1"], x, spec, dtype)
+    cq = _norm(blk["q_norm"], _proj(blk, "wqa", u, dtype), spec, dtype)
+    q = _proj(blk, "wqb", cq, dtype).reshape(
+        n, spec.n_heads, spec.nope_dim + spec.rope_dim)
+    kv = _proj(blk, "wkva", u, dtype)[:, 0]
+    c = _norm(blk["kv_norm"], kv[:, :spec.kv_rank], spec, dtype)
+    return (q[..., :spec.nope_dim], _rope(q[..., spec.nope_dim:], pos, spec),
+            c, _rope(kv[:, spec.kv_rank:], pos, spec))
+
+
+def _wkvb(blk, spec: LMSpec, dtype):
+    """``Wkvb`` as (kv_rank, H, nope_dim + v_dim): a head's (W_UK |
+    W_UV)."""
+    return blk["wkvb"].astype(dtype).reshape(
+        spec.kv_rank, spec.n_heads, spec.nope_dim + spec.v_dim)
+
+
+def absorbed_queries(q_nope, q_pe, w, spec: LMSpec):
+    """The queries of the absorbed form, (N, H, kv_rank + rope_dim):
+    ``q_nope W_UK^T`` against the latent, q_pe against the roped key."""
+    q_lat = jnp.einsum("nhd,rhd->nhr", q_nope, w[..., :spec.nope_dim],
+                       preferred_element_type=_F32).astype(q_nope.dtype)
+    return jnp.concatenate([q_lat, q_pe], axis=-1)
+
+
+def _absorbed_out(o_lat, w, spec: LMSpec, dtype):
+    """The heads' outputs from their sums of latents o_lat (N, H,
+    kv_rank) f32: ``o_lat W_UV``, side by side, (N, H * v_dim)."""
+    o = jnp.einsum("nhr,rhv->nhv", o_lat.astype(dtype),
+                   w[..., spec.nope_dim:], preferred_element_type=_F32)
+    return o.reshape(o.shape[0], -1).astype(dtype)
+
+
+def _mlp(blk, x, live, dense: bool, spec: LMSpec, dtype):
+    """x + MLP(N2(x)). Returns (x, the expert layer's counts with the
+    pairs routed away last (experts_held + 1,) int32, or None for a dense
+    layer)."""
+    u = _norm(blk["ln2"], x, spec, dtype)
+    if dense:
+        return x + _mlp_paged(blk, u, dtype), None
+    y, counts, away = sparse_moe._expert_layer(blk, u[:, 0], live, spec,
+                                               dtype)
+    # the shared experts are one SwiGLU, through the dense family's products
+    shared = _mlp_paged({"wi": blk["swi"], "wd": blk["swd"]}, u, dtype)
+    return (x + shared + y[:, None, :],
+            jnp.concatenate([counts, away[None]]))
+
+
+def _finish(params, x, spec: LMSpec, dtype):
+    x = _norm(params["ln_f"], x, spec, dtype)
+    return _proj(params, "head", x, dtype).astype(_F32)
+
+
+def _unpacked(rows, rope_dim: int):
+    """Gathered rows of the roped keys' pool (..., block_size // pack,
+    pack * rope_dim) as (..., slots, rope_dim) in slot order."""
+    return rows.reshape(rows.shape[:-3] + (-1, rope_dim))
+
+
+# -- decode -------------------------------------------------------------------
+
+def walk_plan(block_size: int, b: int, max_blocks: int):
+    """The decode walk's constants for a bucket of `b` rows: (blocks a
+    chunk, chunks a full table holds, items an iteration)."""
+    nb_c = max(1, min(max_blocks, _DECODE_CHUNK // block_size))
+    n_chunks = -(-max_blocks // nb_c)
+    return nb_c, n_chunks, max(1, min(b * n_chunks, _DECODE_ITEMS))
+
+
+def walk_slots(pos, block_size: int, max_blocks: int) -> int:
+    """Pool slots one layer of a decode step gathers for the bucket's
+    positions `pos` (padding rows included): whole iterations of T
+    chunks of C slots. Host arithmetic, for the family's counters."""
+    nb_c, _, t = walk_plan(block_size, len(pos), max_blocks)
+    c = nb_c * block_size
+    items = sum(int(p) // c + 1 for p in pos)
+    return -(-items // t) * t * c
+
+
+def attend_latent(q, k_pool, i_pool, li, items, t, scale: float):
+    """Layer `li`'s attention in the latent of the absorbed queries q (B,
+    H, kv_rank + rope_dim) over each row's work list `items`
+    (`paged_model._live_items`): T items an iteration, all heads of an
+    item's row against its chunk's latents and roped keys, the
+    online-softmax carry (m, l, acc) a row and head in f32, merged through
+    the (T, B) relation `own` as the dense family's. Returns the heads'
+    sums of latents (B, H, kv_rank) f32."""
+    row, blocks, last, n_iter = items
+    b, nh, _ = q.shape
+    rank = k_pool.shape[4]
+    c = blocks.shape[1] * k_pool.shape[2]
+    slot = jnp.arange(c)
+    hi = jax.lax.Precision.HIGHEST
+
+    def body(j, state):
+        m, l, acc = state
+        r = jax.lax.dynamic_slice_in_dim(row, j * t, t)
+        bl = jax.lax.dynamic_slice_in_dim(blocks, j * t, t)
+        la = jax.lax.dynamic_slice_in_dim(last, j * t, t)
+        ct = k_pool[li, bl].reshape(t, c, rank)
+        pe = _unpacked(i_pool[li, bl], q.shape[2] - rank).reshape(t, c, -1)
+        qr = q[r]
+        s = (jnp.einsum("thd,tcd->thc", qr[..., :rank], ct,
+                        preferred_element_type=_F32)
+             + jnp.einsum("thd,tcd->thc", qr[..., rank:], pe,
+                          preferred_element_type=_F32)) * scale
+        ok = (slot[None, :] <= la[:, None])[:, None, :]
+        s = jnp.where(ok, s, -1e30)
+        mi = jnp.max(s, axis=-1)                                  # (T, H)
+        p = jnp.where(ok, jnp.exp(s - mi[..., None]), 0.0)
+        ai = jnp.einsum("thc,tcr->thr", p.astype(ct.dtype), ct,
+                        preferred_element_type=_F32)
+        own = (r[:, None] == jnp.arange(b)[None, :]) & (la >= 0)[:, None]
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(own[:, :, None], mi[:, None, :], -1e30), axis=0))
+        w = jnp.exp(mi - m_new[r])
+        old = jnp.exp(m - m_new)
+        ownf = own.astype(_F32)
+        l = l * old + jnp.einsum(
+            "tb,th->bh", ownf, jnp.sum(p, axis=-1) * w, precision=hi)
+        acc = acc * old[..., None] + jnp.einsum(
+            "tb,thr->bhr", ownf, ai * w[..., None], precision=hi)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(0, n_iter, body, (
+        jnp.full((b, nh), -1e30, _F32), jnp.zeros((b, nh), _F32),
+        jnp.zeros((b, nh, rank), _F32)))
+    return acc / l[..., None]
+
+
+@functools.partial(jax.jit, static_argnames=("dense", "t", "spec", "dtype"))
+def _decode_layer(blk, x, li, pos, live, write_blk, write_off, items,
+                  k_pool, i_pool, *, dense, t, spec, dtype):
+    """Layer `li` of a decode step (jitted with `li` an argument, so a
+    step traces a layer of each shape once; XLA inlines the calls)."""
+    q_nope, q_pe, c, k_pe = _project(blk, x, pos, spec, dtype)
+    k_pool = k_pool.at[li, write_blk, write_off].set(
+        c[:, None, :].astype(k_pool.dtype))
+    i_pool = sparse_moe._idx_write(i_pool, li, write_blk, write_off, k_pe)
+    w = _wkvb(blk, spec, dtype)
+    o_lat = attend_latent(absorbed_queries(q_nope, q_pe, w, spec), k_pool,
+                          i_pool, li, items, t, score_scale(spec))
+    o = _absorbed_out(o_lat, w, spec, dtype)
+    x = x + _proj(blk, "wo", o[:, None, :], dtype)
+    x, load = _mlp(blk, x, live, dense, spec, dtype)
+    return x, load, k_pool, i_pool
+
+
+def latent_moe_decode_step(params, cur, tables, pos, n_live, k_pool, i_pool,
+                           *, spec: LMSpec, dtype=jnp.float32):
+    """One decode step for a bucketed batch, in the absorbed form. cur,
+    pos (B_b,) int32; tables (B_b, max_blocks) int32; n_live () int32,
+    the real rows (the first ones). Returns (logits (B_b, vocab) f32, the
+    expert layers' counts (layers, experts_held + 1) int32, k_pool,
+    i_pool)."""
+    b = cur.shape[0]
+    bs = k_pool.shape[2]
+    nb_c, n_chunks, t = walk_plan(bs, b, tables.shape[1])
+    write_blk = tables[jnp.arange(b), pos // bs]
+    write_off = pos % bs
+    live = jnp.arange(b) < n_live
+    # one work list a step, shared by every layer
+    items = _live_items(tables, pos, bs, nb_c, n_chunks, t)
+    x = params["embed"][cur][:, None, :].astype(dtype)
+    load = []
+    for li, blk in enumerate(params["blocks"]):
+        x, counts, k_pool, i_pool = _decode_layer(
+            blk, x, li, pos, live, write_blk, write_off, items, k_pool,
+            i_pool, dense=li < spec.dense_layers, t=t, spec=spec,
+            dtype=dtype)
+        if counts is not None:
+            load.append(counts)
+    return (_finish(params, x[:, 0], spec, dtype), jnp.stack(load), k_pool,
+            i_pool)
+
+
+# -- chunk prefill ------------------------------------------------------------
+
+def expanded_attend(c: int, spec: LMSpec) -> bool:
+    """Whether a chunk of `c` queries attends in the expanded form: from
+    the bucket and the widths alone, by the operations. A (query, key)
+    costs 2 H (2 kv_rank + rope_dim) absorbed and 2 H (nope_dim +
+    rope_dim + v_dim) expanded; a key's expansion, once for all the
+    queries, 2 kv_rank H (nope_dim + v_dim)."""
+    absorbed = 2 * spec.kv_rank + spec.rope_dim
+    expanded = spec.nope_dim + spec.rope_dim + spec.v_dim
+    return c * (absorbed - expanded) > spec.kv_rank * (spec.nope_dim
+                                                       + spec.v_dim)
+
+
+def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
+                 expanded: bool, fused: bool, tile: int, spec: LMSpec,
+                 dtype):
+    """Layer `li`'s attention of a whole chunk: queries (C, H, .) at
+    positions qpos (C,) of one sequence over the context tiles `span`
+    (first, end; traced) of its table `tab` (MB,), a tile's latents and
+    roped keys read once for all queries, under the causal edge.
+    `expanded`: the tile goes through `w` (`_wkvb`) to a K and a V a
+    head; else the queries go through it to the latent. Returns (C, H *
+    v_dim) in `dtype`."""
+    c, nh, _ = q_nope.shape
+    bs, rank = k_pool.shape[2], spec.kv_rank
+    nope, rope = spec.nope_dim, spec.rope_dim
+    if tile % bs:
+        raise ValueError(f"block_size {bs} does not divide the context "
+                         f"tile of {tile} slots")
+    nb_t = tile // bs
+    max_tiles = -(-tab.shape[0] // nb_t)
+    # the table's tail past max_blocks reads block 0: the scratch block
+    tab = jnp.pad(tab, (0, max_tiles * nb_t - tab.shape[0]))
+    if expanded:
+        # a head is a KV head of its own; the kernel takes a head's K as
+        # whole lane tiles, so its width is filled up with zeros
+        fill = -(nope + rope) % 128 if fused else 0
+        q = jnp.concatenate([q_nope, q_pe] + [jnp.zeros(
+            (c, nh, fill), q_pe.dtype)] * bool(fill), axis=-1)
+        heads, vw = (nh, 1), spec.v_dim
+    else:
+        fill, q = 0, absorbed_queries(q_nope, q_pe, w, spec)
+        heads, vw = (1, nh), rank
+    kw = q.shape[-1]
+    # the tile update divides a score by the root of the width it sees
+    qg = (q * (score_scale(spec) * kw ** 0.5)).astype(dtype).reshape(
+        (c,) + heads + (kw,))
+    # the kernel's layout, a head's queries side by side: made once
+    qh = qg.transpose(1, 2, 0, 3) if fused else None
+    slot = jnp.arange(tile)
+    # selection keys of 1 and 0 under a threshold of 0 with no tie taken
+    none, no_tie = jnp.zeros((c,), jnp.uint32), jnp.full((c,), -1, jnp.int32)
+
+    def attend_tile(j, state):
+        bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
+        ct = k_pool[li, bl].astype(dtype).reshape(tile, rank)
+        pe = _unpacked(i_pool[li, bl], rope).astype(dtype).reshape(tile, rope)
+        if expanded:
+            kv = jnp.einsum("sr,rhd->shd", ct, w,
+                            preferred_element_type=_F32).astype(dtype)
+            kt = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(pe[:, None, :], (tile, nh, rope))]
+                + [jnp.zeros((tile, nh, fill), dtype)] * bool(fill), axis=-1)
+            vt = kv[..., nope:]
+        else:
+            kt = jnp.concatenate([ct, pe], axis=-1)[:, None, :]
+            vt = ct[:, None, :]
+        keys = ((j * tile + slot)[None, :] <= qpos[:, None]).astype(
+            jnp.uint32)                                       # (C, tile)
+        if fused:
+            return pallas_ops.selected_block_update(
+                qh, kt, vt, keys, none, no_tie, 0, *state,
+                block_q=sparse_moe._FUSED_Q_BLOCK)
+        return sparse_moe.attend_plain(qg, kt, vt, keys, none, no_tie, 0,
+                                       state)
+
+    _, l, acc = jax.lax.fori_loop(*span, attend_tile, (
+        jnp.full(heads + (c,), -1e30, _F32), jnp.zeros(heads + (c,), _F32),
+        jnp.zeros(heads + (c, vw), _F32)))
+    # a padding query past the table's last tile attended nothing
+    att = (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(nh, c, vw)
+    att = att.transpose(1, 0, 2)
+    if expanded:
+        return att.reshape(c, nh * vw).astype(dtype)
+    return _absorbed_out(att, w, spec, dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "dense", "tile", "by_block", "fused", "expanded", "spec", "dtype"))
+def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, k_pool,
+                 i_pool, *, dense, tile, by_block, fused, expanded, spec,
+                 dtype):
+    """Layer `li` of a chunk: x (C, 1, D), the chunk's tokens as rows
+    (jitted with `li` an argument, as `_decode_layer`)."""
+    c = x.shape[0]
+    bs = k_pool.shape[2]
+    q_nope, q_pe, lat, k_pe = _project(blk, x, pos, spec, dtype)
+    k_pool = _write_chunk(k_pool, li, blk_idx, blk_off, lat[:, None, :],
+                          by_block)
+    if by_block:
+        i_pool = sparse_moe._put_blocks(
+            i_pool, li, blk_idx.reshape(c // bs, bs)[:, 0], k_pe)
+    else:
+        i_pool = sparse_moe._idx_write(i_pool, li, blk_idx, blk_off, k_pe)
+    span = tile_span(pos[0], c, tab.shape[0] * bs, tile)
+    o = attend_tiles(q_nope, q_pe, pos, tab, span, li, k_pool, i_pool,
+                     _wkvb(blk, spec, dtype), expanded=expanded, fused=fused,
+                     tile=tile, spec=spec, dtype=dtype)
+    x = x + _proj(blk, "wo", o[:, None, :], dtype)
+    x, load = _mlp(blk, x, live, dense, spec, dtype)
+    return x, load, k_pool, i_pool
+
+
+def latent_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
+                             k_pool, i_pool, last_idx, *, spec: LMSpec,
+                             dtype=jnp.float32, by_block: bool = False,
+                             fused: bool = False, expanded: bool = False,
+                             tile: int = sparse_moe._CTX_TILE):
+    """One prompt chunk of one sequence: the arguments of
+    `paged_prefill_chunk` with the roped keys' pool where V would be.
+    `by_block`, `fused` (static) as `sparse_moe_prefill_chunk`;
+    `expanded` (static): the attention's form, which the caller asks
+    `expanded_attend`; `tile` (static): the context slots a walk covers
+    an iteration. Returns (last real token's logits (vocab,) f32, the
+    expert layers' counts over the chunk's real tokens (layers,
+    experts_held + 1) int32, k_pool, i_pool)."""
+    c = ids.shape[1]
+    pos = pos0 + jnp.arange(c)
+    live = jnp.arange(c) <= last_idx
+    x = params["embed"][ids[0]][:, None, :].astype(dtype)
+    load = []
+    for li, blk in enumerate(params["blocks"]):
+        x, counts, k_pool, i_pool = _chunk_layer(
+            blk, x, li, pos, live, blk_idx, blk_off, table, k_pool, i_pool,
+            dense=li < spec.dense_layers, tile=tile, by_block=by_block,
+            fused=fused, expanded=expanded, spec=spec, dtype=dtype)
+        if counts is not None:
+            load.append(counts)
+    logits = _finish(params, x[last_idx, 0][None, :], spec, dtype)[0]
+    return logits, jnp.stack(load), k_pool, i_pool

@@ -47,6 +47,12 @@ therefore holds at most `window_cap(1)` window blocks while it decodes and
 `window_cap(chunk)` while a chunk of its prompt is prefilled, whatever its
 context, and `peak_demand` counts that cap. `reserve` admits against the
 peak in both pools and grants both tables or neither.
+
+A model whose attention is latent (llm/latent_moe.py) keeps no value a
+head: `values=False` makes no V pool. Its `k` pool holds one compressed
+row a token a layer (`n_kv` 1, `head_dim` the latent's width) and its
+`idx` pool the one roped key all heads share, two tokens a 128-lane row
+where the key is 64 wide (`idx_pack`), under the one table and allocator.
 """
 
 from __future__ import annotations
@@ -227,7 +233,7 @@ class PagedKVCache:
                  placer=None, state_shape: tuple = (),
                  ckey_shape: tuple = (), state_slots: int = 0,
                  window_layers: int = 0, window_blocks: int = 0,
-                 window: int = 0):
+                 window: int = 0, values: bool = True):
         import jax.numpy as jnp
 
         self.num_blocks = int(num_blocks)
@@ -239,7 +245,7 @@ class PagedKVCache:
         shape = (self.n_layers, self.num_blocks, self.block_size,
                  self.n_kv, self.head_dim)
         self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self.v = jnp.zeros(shape, self.dtype) if values else None
         self.idx_dim = int(idx_dim)
         self.idx_pack = idx_pack(self.block_size, self.idx_dim)
         self.idx = jnp.zeros(
@@ -281,8 +287,7 @@ class PagedKVCache:
             # sharded along the kv-head axis next to the projections —
             # serving/sharding.kv_pool_placer); allocator/table logic is
             # untouched, only where the bytes live changes
-            self.k = placer(self.k)
-            self.v = placer(self.v)
+            self.k, self.v = placer(self.k), placer(self.v)
         self.allocator = BlockAllocator(self.num_blocks)
         # growth grants, and the largest peak an admission was accepted at
         self.blocks_grown = 0
@@ -296,20 +301,19 @@ class PagedKVCache:
     def tokens_capacity(self) -> int:
         return self.allocator.total * self.block_size
 
-    _EXTRA = ("idx", "ck", "state", "wk", "wv")
+    _POOLS = ("k", "v", "idx", "ck", "state", "wk", "wv")
 
     def pools(self) -> tuple:
-        """The pools there are: (k, v), then those the model adds, in
-        the order indexer keys, compressed keys, state, the window
-        layers' K and V."""
-        return (self.k, self.v) + tuple(
-            p for p in (getattr(self, n) for n in self._EXTRA)
-            if p is not None)
+        """The pools there are: k, v (where the model keeps values),
+        then those the model adds, in the order indexer keys, compressed
+        keys, state, the window layers' K and V."""
+        return tuple(p for p in (getattr(self, n) for n in self._POOLS)
+                     if p is not None)
 
     def set_pools(self, pools) -> None:
         """Keep the pools a donating jit handed back, in `pools()` order."""
-        self.k, self.v, *rest = pools
-        for name in self._EXTRA:
+        rest = list(pools)
+        for name in self._POOLS:
             if getattr(self, name) is not None:
                 setattr(self, name, rest.pop(0))
 
@@ -404,7 +408,8 @@ class PagedKVCache:
         """Bytes one block holds over all layers and all pools that are
         paged under the one table (what a sequence holds by slot:
         `state_slot_bytes`; a window block: `window_block_bytes`)."""
-        per_slot = 2 * self.n_kv * self.head_dim + self.idx_dim
+        per_slot = ((1 if self.v is None else 2) * self.n_kv * self.head_dim
+                    + self.idx_dim)
         return (self.n_layers * self.block_size * per_slot
                 * self.dtype.itemsize)
 

@@ -231,14 +231,28 @@ def _idx_scores(qi, w, rows, di, shared: bool):
 def _route(blk, g, spec: LMSpec, dtype):
     """The router for tokens g (N, D): (weights (N, k) f32, experts
     (N, k) int32 among all `n_experts`). Softmax scores: the k largest,
-    renormalised. Sigmoid scores: the k of largest score + bias (ties to
-    the lower index), weighted by their scores alone, renormalised and
-    multiplied by `route_scale`."""
+    renormalised; under `n_group` > 1 the k largest inside the
+    `topk_group` groups whose best expert scores highest (ties to the
+    lower index, of groups and of experts), and where `route_norm` is
+    false the scores as they are, times `route_scale`. Sigmoid scores:
+    the k of largest score + bias (ties to the lower index), weighted by
+    their scores alone, renormalised and multiplied by `route_scale`."""
     k = spec.experts_per_tok
     logits = jnp.dot(g, blk["router"].astype(dtype),
                      preferred_element_type=_F32)
     if spec.score_fn == "softmax":
-        p, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        s = jax.nn.softmax(logits, axis=-1)
+        if spec.n_group > 1:
+            # the other groups' scores count as 0: below every real one
+            best = jnp.max(s.reshape(-1, spec.n_group,
+                                     s.shape[-1] // spec.n_group), axis=-1)
+            _, chosen = jax.lax.top_k(best, spec.topk_group)
+            group = jnp.arange(s.shape[-1]) // (s.shape[-1] // spec.n_group)
+            s = jnp.where(jnp.any(
+                group[None, None, :] == chosen[:, :, None], axis=1), s, 0.0)
+        p, e = jax.lax.top_k(s, k)
+        if not spec.route_norm:
+            return spec.route_scale * p, e
         return p / jnp.sum(p, axis=-1, keepdims=True), e
     s = jax.nn.sigmoid(logits)
     _, e = jax.lax.top_k(s + blk["router_bias"].astype(_F32), k)

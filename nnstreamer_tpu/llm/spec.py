@@ -32,6 +32,12 @@ HYBRID = "hybrid"
 #: layers and then a shared expert beside this chip's share of the routed
 #: experts (llm/window_moe.py)
 WINDOW_MOE = "window_moe"
+#: the decoder whose attention is latent: a token keeps one compressed
+#: row and one roped key that all heads share, in a pool with no head axis
+#: and no values; a dense MLP in its first layers and then shared experts
+#: beside this chip's share of routed experts chosen inside groups
+#: (llm/latent_moe.py)
+LATENT_MOE = "latent_moe"
 #: the kinds of layer `LMSpec.layer_kinds` names: the hybrid family's two,
 #: then the window family's two
 LINEAR, SPARSE = "linear", "sparse"
@@ -102,3 +108,34 @@ class LMSpec:
     dense_width: int = 0
     shared_width: int = 0
     norm_eps: float = 1e-6
+    # the expert layer's router, further: n_group > 1 divides the experts
+    # into groups of equal size, a group scores as its best expert, and
+    # only the experts of the topk_group best groups can be chosen;
+    # route_norm False leaves the chosen scores as they are (times
+    # route_scale) and does not renormalise them
+    n_group: int = 0
+    topk_group: int = 0
+    route_norm: bool = True
+    # the latent family. The query goes through q_rank values and a norm
+    # to n_heads heads of nope_dim + rope_dim; a token's key and value
+    # through kv_rank values and a norm (the latent the pool keeps) to
+    # n_heads heads of nope_dim + v_dim, beside one roped key of rope_dim
+    # that all heads share. `dense_layers`, `dense_width`, `shared_width`
+    # and `norm_eps` as above
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    # YaRN on the roped dims (rope_theta the base): positions past
+    # yarn_orig_len are reached by dividing the slow frequencies by
+    # yarn_factor (0 = plain rope), the ramp between yarn_beta_fast and
+    # yarn_beta_slow turns of the original length; cos and sin are
+    # multiplied by m(factor, mscale) / m(factor, mscale_all_dim) and the
+    # scores by m(factor, mscale_all_dim)^2, m(s, a) = 0.1 a ln s + 1
+    yarn_factor: float = 0.0
+    yarn_orig_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 0.0
+    yarn_mscale_all_dim: float = 0.0

@@ -10,6 +10,7 @@ guide), and every such compile lives in this one file.
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -215,3 +216,86 @@ def test_a_decode_buckets_grouped_products_stay_the_compilers(one_chip,
     tilings = {ln.split("ragged_dot_tiling=\"")[1].split(",")[0]
                for ln in text.splitlines() if "ragged_dot_tiling=\"" in ln}
     assert tilings == {str(rows * spec.experts_per_tok)}
+
+
+@pytest.mark.parametrize("c", [2048, 1024])
+def test_the_latent_chunks_expanded_walk_takes_the_kernel(one_chip,
+                                                          monkeypatch, c):
+    """One expert layer of the DeepSeek-V2 cell's chunk (deepseek-v2-6l:
+    128 heads of 128 + 64 | 128 over latents of 512, a chunk of 2,048 and
+    a whole prompt of 1,024, a table of 288 blocks of 64): the expanded
+    walk updates a tile in the kernel (a head's K filled up to 256, its V
+    128 wide), the two grouped products are the repo's kernel too, no
+    score over (heads, chunk, tile) is kept, and neither pool is copied
+    or converted."""
+    from nnstreamer_tpu.llm import latent_moe
+    from perfbench.references import latent_moe_lm
+    from perfbench.runners.latent_moe_llm import lm_spec as latent_spec
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "deepseek-v2-6l.json")) as f:
+        cfg = json.load(f)
+    spec = latent_spec(cfg)
+    assert latent_moe.expanded_attend(c, spec)
+    mb, bs, nblk, bf, i32 = 288, 64, 11000, jnp.bfloat16, jnp.int32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = jax.eval_shape(
+        lambda: latent_moe_lm.make_params(cfg, 1, dtype=bf))["blocks"][1]
+    blk = jax.tree.map(lambda x: arg(x.shape, x.dtype), blk)
+
+    def layer(*a):
+        return latent_moe._chunk_layer(
+            *a, dense=False, tile=sparse_moe._CTX_TILE, by_block=True,
+            fused=True, expanded=True, spec=spec, dtype=bf)
+
+    compiled = jax.jit(layer, donate_argnums=(8, 9)).lower(
+        blk, arg((c, 1, 5120), bf), arg((), i32), arg((c,), i32),
+        arg((c,), jnp.bool_), arg((c,), i32), arg((c,), i32),
+        arg((mb,), i32), arg((6, nblk, bs, 1, 512), bf),
+        arg((6, nblk, bs // 2, 128), bf)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 3
+    # a tile's float32 scores would be heads x chunk x tile x 4 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * 128 * c * sparse_moe._CTX_TILE
+    assert not re.search(
+        rf"= bf16\[6,{nblk},[^ ]* (copy|convert)\(", text)
+
+
+@pytest.mark.parametrize("dt,held", [(jnp.float32, 8), (jnp.bfloat16, 20)],
+                         ids=["float32", "bfloat16"])
+def test_the_latent_cells_grouped_products_fit_fast_memory(one_chip,
+                                                           monkeypatch, dt,
+                                                           held):
+    """A chunk's expert layer at the DeepSeek-V2 cell's widths (12,288
+    pair rows against experts of 5,120 x 3,072 and 1,536 x 5,120, a row
+    tile of 256): a visit's rows and output rows, buffered twice, are 13.6
+    MB in bfloat16 and 22 MB in float32, the type of the check against the
+    reference, which the compiler's 16 MB a kernel refused on the chip (PR
+    41) until `grouped_matmul` asked for what it needs."""
+    from perfbench.runners.latent_moe_llm import lm_spec as latent_spec
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "deepseek-v2-6l.json")) as f:
+        cfg = json.load(f)
+    cfg["n_routed_experts"] = held
+    spec = latent_spec(cfg)
+    d, f_ = cfg["hidden_size"], spec.expert_width
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = {"router": arg((d, spec.n_experts), dt),
+           "ewi": arg((held, d, 2 * f_), dt), "ewd": arg((held, f_, d), dt)}
+    text = jax.jit(lambda b, g, live: sparse_moe._expert_layer(
+        b, g, live, spec, dt)).lower(
+        blk, arg((2048, d), dt), arg((2048,), jnp.bool_)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
