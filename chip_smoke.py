@@ -215,9 +215,12 @@ def leg_kernels() -> dict:
               fused=False, tile=tile), 3e-2)
 
     # the window family's chunk walk at the Trinity cell's head geometry
-    # (8 KV heads of 6 query heads of 128, pool blocks of 64): the same
-    # kernel a tile behind the causal edge and a window of 1,536, tiles 1
-    # to 3 of a table of 4, against the walk by the plain update
+    # (8 KV heads of 6 query heads of 128, pool blocks of 64): the tile
+    # update's causal form (`pallas_ops.causal_block_update`: the mask from
+    # positions inside the kernel, 128 queries a program) behind the causal
+    # edge and a window of 1,536, tiles 1 to 3 of a table of 4 (blocks that
+    # see a tile whole, in part and not at all), against the walk by the
+    # plain update
     from nnstreamer_tpu.llm import window_moe
 
     c = 512
@@ -238,9 +241,10 @@ def leg_kernels() -> dict:
 
     # the latent family's chunk walk at the published head widths (16
     # heads of 128 + 64 | 128 over latents of 512 and roped keys of 64, two
-    # a row, pool blocks of 64): the expanded form through the same kernel
-    # (a head's K filled up to 256, its V 128 wide) against the absorbed
-    # form through the plain update, tiles 0 to 2 of a table of 4
+    # a row, pool blocks of 64): the expanded form through the causal
+    # kernel (a head's K filled up to 256, its V 128 wide, a head a group
+    # of one: 512 queries a program) against the absorbed form through the
+    # plain update, tiles 0 to 2 of a table of 4
     from nnstreamer_tpu.llm import latent_moe
 
     la = LMSpec(family="latent_moe", n_heads=16, q_rank=256, kv_rank=512,

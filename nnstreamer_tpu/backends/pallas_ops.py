@@ -554,6 +554,154 @@ def selected_block_update(q, k_blk, v_blk, keys, t, cut, tile, m, l, acc,
       t[:, None], cut[:, None], m, l, acc)
 
 
+def block_reach(q0, bq: int, s0, sk: int, window: int = 0):
+    """What the `bq` queries at positions from `q0` on see of the `sk`
+    context slots from `s0` on, query q seeing slot s where ``s <= q``
+    and, under a `window` > 0, ``s > q - window``. Returns (skip: no
+    query sees any slot; clear: every query sees every slot); neither is
+    the edge, where a mask decides. In arithmetic that the host's ints
+    (and numpy's arrays) and a kernel's traced scalars both take."""
+    q1, s1 = q0 + (bq - 1), s0 + (sk - 1)         # last query, last slot
+    skip, clear = s0 > q1, s1 <= q0
+    if window:
+        skip = skip | (s1 <= q0 - window)
+        clear = clear & (s0 > q1 - window)
+    return skip, clear
+
+
+#: rows of queries (heads of a group x queries) a program of the causal
+#: tile update takes: what `selected_block_update`'s 128 queries of 8 heads
+#: were read best at (PR 32), and the readings in `causal_block_update`
+_BLOCK_ROWS = 1024
+
+
+def causal_block_q(c: int, grp: int) -> int:
+    """Queries a program of `causal_block_update` takes of a chunk of
+    `c`, `grp` query heads a KV head: `_BLOCK_ROWS` rows a program or the
+    most under it, a power of two that divides `c`, never under 128 (a
+    shorter chunk whole)."""
+    bq = 128
+    while bq * 2 * grp <= _BLOCK_ROWS and c % (bq * 2) == 0:
+        bq *= 2
+    return min(bq, c)
+
+
+def _causal_block_kernel(scale: float, window: int, pos_ref,
+                         q_ref, k_ref, v_ref, m_ref, l_ref, a_ref,
+                         mo_ref, lo_ref, ao_ref):
+    """Causal tile update: `_selected_block_kernel`'s carry and layout
+    under a mask that positions alone decide, so no array says it. A
+    program's block of queries starts at position ``pos_ref[0] + i * bq``
+    and the tile at slot ``pos_ref[1]``; `block_reach` says from the two
+    which of three bodies runs: the update with no mask, the update under
+    the mask made here from two iotas, or the carry handed through."""
+    grp, bq = q_ref.shape[1], q_ref.shape[2]
+    sk = k_ref.shape[0]
+    q0 = pos_ref[0] + pl.program_id(1) * bq
+    s0 = pos_ref[1]
+    skip, clear = block_reach(q0, bq, s0, sk, window)
+
+    def update(mask):
+        for r in range(grp):
+            mo_ref[0, r], lo_ref[0, r], ao_ref[0, r] = _online_softmax_update(
+                q_ref[0, r], k_ref[...], v_ref[...],
+                m_ref[0, r], l_ref[0, r], a_ref[0, r], scale, mask)
+
+    @pl.when(clear)
+    def _clear():
+        update(None)
+
+    @pl.when(jnp.logical_not(clear | skip))
+    def _edge():
+        row = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 0)
+        col = s0 + jax.lax.broadcasted_iota(jnp.int32, (bq, sk), 1)
+        mask = col <= row
+        if window:
+            mask = mask & (col > row - window)
+        update(mask)
+
+    @pl.when(skip)
+    def _skip():
+        mo_ref[...] = m_ref[...]
+        lo_ref[...] = l_ref[...]
+        ao_ref[...] = a_ref[...]
+
+
+def causal_block_update(q, k_blk, v_blk, qpos0, slot0, m, l, acc, *,
+                        window: int = 0, block_q: int = 0, interpret=None):
+    """One context tile of a chunk's attention walk under the causal
+    edge and, where `window` > 0, a window (llm/window_moe.py,
+    llm/latent_moe.py) as a Pallas kernel: `selected_block_update` for a
+    mask that is a function of positions. q (Hkv, G, C, D): C queries at
+    the consecutive positions from `qpos0` on (a traced scalar); k_blk
+    (Sk, Hkv, D), v_blk (Sk, Hkv, Dv): the keys and values of the context
+    slots from `slot0` on (a traced scalar); query c attends slot s where
+    ``slot0 + s <= qpos0 + c`` and, under a window, ``slot0 + s > qpos0 +
+    c - window``. Updates the flash carry m, l (Hkv, G, C) f32 and acc
+    (Hkv, G, C, Dv) f32 in place; a query that sees no slot of the tile
+    keeps its carry to the bit. The same widths as the selected form: D
+    and Dv multiples of 128 unless Hkv is 1.
+
+    A program takes `block_q` queries (0: `causal_block_q`'s) with all
+    the heads of their group against the whole tile, in the one of three
+    bodies `block_reach` names from its first position and `slot0`: a
+    block that sees every slot runs the update with no mask (no iota,
+    compare or select), a block that sees none copies its carry through
+    and runs neither product, the blocks between make the mask from two
+    iotas. A row's result does not depend on the block it rides in.
+
+    On the v5e, a walk of 6 tiles of 1,024 slots under 2,048 queries at
+    position 4,096, ms a tile (PERF.md, PR 42; the selected form at 128
+    queries beside it): 128 heads of one, D 256 and Dv 128 (the latent
+    family's expanded heads), 3.12 selected, 2.92 at 128 queries, 2.32 at
+    256, 2.10 at 512, 2.01 at 1,024 (a chunk of 1,024 at position 0: 1.72,
+    1.71, -, 1.34, 1.28); 8 KV heads of 6 of 128 (the window family's),
+    0.565 selected, 0.515 at 128, 0.628 at 256, and under a window of
+    4,096 at position 8,192 0.562, 0.481, 0.568; 4 KV heads of 8, 0.369,
+    0.343, 0.410. The mask's array was a fifteenth of the time, the
+    programs' number most of it: the rule is rows, not bytes."""
+    nkv, grp, c, d = q.shape
+    sk, dv = k_blk.shape[0], v_blk.shape[2]
+    bq = min(block_q or causal_block_q(c, grp), c)
+    if c % bq:
+        raise ValueError(
+            f"causal_block_update needs C={c} divisible by {bq}")
+    kern = functools.partial(_causal_block_kernel, d ** -0.5, int(window))
+    blk_q = pl.BlockSpec((1, grp, bq, d), lambda g, i, p: (g, 0, i, 0))
+    blk_a = pl.BlockSpec((1, grp, bq, dv), lambda g, i, p: (g, 0, i, 0))
+    blk_k = pl.BlockSpec((sk, d), lambda g, i, p: (0, g))
+    blk_v = pl.BlockSpec((sk, dv), lambda g, i, p: (0, g))
+    blk_m = pl.BlockSpec((1, grp, bq), lambda g, i, p: (g, 0, i))
+    # what a program keeps in fast memory: its blocks buffered twice, the
+    # carry's in and out, and a head's scores, probabilities and mask;
+    # past the compiler's own limit of 16 MB a kernel the call asks for
+    # what it needs, as `grouped_matmul` does
+    item = q.dtype.itemsize
+    need = (2 * (item * (grp * bq * d + sk * (d + dv))
+                 + 8 * grp * bq * (dv + 2))
+            + bq * sk * (9 + item))
+    limit = need + (4 << 20) if need > (14 << 20) else None
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nkv, c // bq),
+            in_specs=[blk_q, blk_k, blk_v, blk_m, blk_m, blk_a],
+            out_specs=[blk_m, blk_m, blk_a]),
+        out_shape=[jax.ShapeDtypeStruct(m.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(l.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(acc.shape, jnp.float32)],
+        # the carry is updated in place, as flash_block_update's
+        input_output_aliases={4: 0, 5: 1, 6: 2},
+        name="causal_block_update",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=limit),
+        interpret=_interpret() if interpret is None else interpret,
+    )(jnp.asarray([qpos0, slot0], jnp.int32), q,
+      k_blk.reshape(sk, nkv * d), v_blk.reshape(sk, nkv * dv), m, l, acc)
+
+
 # -- grouped matmul -----------------------------------------------------------
 
 def group_visits(group_sizes, m: int, tm: int):

@@ -106,6 +106,41 @@ def _note_held_load(ps, kind: str, load: np.ndarray, bucket: int) -> dict:
             **_note_expert_tiles(ps, counts, bucket)}
 
 
+#: the three things a program of the causal tile update does with its
+#: block of queries (`pallas_ops.block_reach`), as the counters name them
+QBLOCK_KINDS = ("chunk_qblocks_clear", "chunk_qblocks_edge",
+                "chunk_qblocks_skipped")
+
+
+def _note_qblocks(ps, pos0: int, bucket: int, grp: int, tile: int,
+                  walks) -> dict:
+    """What the programs of `pallas_ops.causal_block_update` do over a
+    chunk of `bucket` queries at `pos0`, `grp` query heads a KV head, for
+    the set `ps`: `walks` are (layers, first tile, end tile, window), a
+    kind of layer each, and every (block of queries, tile) pair of a walk
+    is one program: the update with no mask (clear), under a mask made
+    from positions (edge), or the carry handed through (skipped); the
+    kernel's own arithmetic (`block_reach`, with its block,
+    `causal_block_q`), here over all pairs at once. Counts them, summed
+    over layers, and returns the span's part. No walk, where the update
+    is the plain one: zeros."""
+    from nnstreamer_tpu.backends.pallas_ops import block_reach, causal_block_q
+
+    bq = causal_block_q(bucket, grp)
+    q0 = pos0 + bq * np.arange(bucket // bq, dtype=np.int64)[:, None]
+    clear = pairs = skipped = 0
+    for layers, first, end, window in walks:
+        s0 = tile * np.arange(int(first), int(end), dtype=np.int64)[None, :]
+        skip, clr = block_reach(q0, bq, s0, tile, window)
+        clear += layers * int(clr.sum())
+        skipped += layers * int(skip.sum())
+        pairs += layers * skip.size
+    said = dict(zip(QBLOCK_KINDS, (clear, pairs - clear - skipped, skipped)))
+    for name, n in said.items():
+        ps.counters[name] += n
+    return said
+
+
 class Program(NamedTuple):
     fn: Callable
     static: Tuple[str, ...]
@@ -608,13 +643,15 @@ class WindowMoESet(ChunkOnlySet):
         # tokens at the busiest held expert, summed over the chunks whose
         # counts have been read back (expert_load_chunks); the (row tile,
         # expert) visits one of a chunk's grouped products made over its
-        # expert layers, and the rows of those tiles
+        # expert layers, and the rows of those tiles; the programs of the
+        # fused tile update, (block of queries, tile) pairs over all
+        # layers, by what `pallas_ops.block_reach` has each do
         self.counters.update(dict.fromkeys((
             "kv_tokens_full", "kv_tokens_window", "expert_pairs_held",
             "expert_pairs_away", "expert_steps_layers",
             "experts_touched_sum", "expert_load_max_sum",
             "expert_load_chunks", "ctx_tiles_full", "ctx_tiles_window",
-            "expert_tile_visits", "expert_tile_rows"), 0))
+            "expert_tile_visits", "expert_tile_rows") + QBLOCK_KINDS, 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         """The FULL layers' pools under the pool's geometry as given;
@@ -692,20 +729,24 @@ class WindowMoESet(ChunkOnlySet):
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a FULL and a WINDOW layer's walk covers (the
-        program's own trip counts, `window_moe.tile_span`)."""
+        program's own trip counts, `window_moe.tile_span`) and, where the
+        tile update is the fused one, what its programs do."""
         from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
         from nnstreamer_tpu.llm.window_moe import tile_span
 
         slots = self.max_blocks * self.block_size
+        spec, fused = self.spec, self._fused(bucket)
         _, full = tile_span(pos0, bucket, slots, _CTX_TILE)
-        first, end = tile_span(pos0, bucket, slots, _CTX_TILE,
-                               self.spec.window)
+        first, end = tile_span(pos0, bucket, slots, _CTX_TILE, spec.window)
         self.counters["ctx_tiles_full"] += full
         self.counters["ctx_tiles_window"] += end - first
-        return {"pos0": pos0,
-                "attend": "fused" if self._fused(bucket) else "plain",
+        walks = ((self.n_full, 0, full, 0),
+                 (self.n_window, first, end, spec.window))
+        return {"pos0": pos0, "attend": "fused" if fused else "plain",
                 "ctx_tiles_full": int(full),
-                "ctx_tiles_window": int(end - first)}
+                "ctx_tiles_window": int(end - first),
+                **_note_qblocks(self, pos0, bucket, spec.n_heads // spec.n_kv,
+                                _CTX_TILE, walks if fused else ())}
 
     def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """One call's (expert layers, held + 1) counts: the real tokens'
@@ -776,6 +817,7 @@ class LatentMoESet(ChunkOnlySet):
         # the pool's row: one latent a token and, beside it, its roped key
         self.n_kv, self.head_dim = 1, int(spec.kv_rank)
         self.idx_dim = int(spec.rope_dim)
+        self.layers = layers
         self.expert_layers = layers - spec.dense_layers
         # kept tracer on or off. Decode steps: live context the steps
         # attended, pool slots a layer gathered for it (whole iterations
@@ -788,13 +830,15 @@ class LatentMoESet(ChunkOnlySet):
         # the busiest held expert, summed over the chunks whose counts
         # have been read back (expert_load_chunks); the (row tile,
         # expert) visits one of a chunk's grouped products made over its
-        # expert layers, and the rows of those tiles
+        # expert layers, and the rows of those tiles; the programs of the
+        # fused tile update, (block of queries, tile) pairs over all
+        # layers, by what `pallas_ops.block_reach` has each do
         self.counters.update(dict.fromkeys((
             "latents_expanded", "chunk_tiles_attended", "expert_pairs_held",
             "expert_pairs_away", "expert_steps_layers",
             "experts_touched_sum", "expert_load_max_sum",
             "expert_load_chunks", "expert_tile_visits",
-            "expert_tile_rows"), 0))
+            "expert_tile_rows") + QBLOCK_KINDS, 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         return {"n_layers": n_layers, "n_kv": 1, "values": False}
@@ -839,8 +883,10 @@ class LatentMoESet(ChunkOnlySet):
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a layer's walk covers (the program's own
-        trip count, `window_moe.tile_span`), the form it attends them in
-        and, expanded, the context tokens a layer puts through Wkvb."""
+        trip count, `window_moe.tile_span`), the form it attends them in,
+        expanded, the context tokens a layer puts through Wkvb and, where
+        the tile update is the fused one (a head a group of one), what
+        its programs do."""
         from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
         from nnstreamer_tpu.llm.window_moe import tile_span
 
@@ -850,9 +896,11 @@ class LatentMoESet(ChunkOnlySet):
         through = int(tiles) * _CTX_TILE * expanded
         self.counters["chunk_tiles_attended"] += int(tiles)
         self.counters["latents_expanded"] += through
+        walks = ((self.layers, 0, tiles, 0),) if self._fused(bucket) else ()
         return {"pos0": pos0, "ctx_tiles": int(tiles),
                 "attend": "expanded" if expanded else "absorbed",
-                "latents_expanded": through}
+                "latents_expanded": through,
+                **_note_qblocks(self, pos0, bucket, 1, _CTX_TILE, walks)}
 
     def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """One call's (expert layers, held + 1) counts, as the window

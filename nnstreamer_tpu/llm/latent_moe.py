@@ -9,10 +9,10 @@ one. The norms (`rmsnorm`), the products (`_proj`), the dense SwiGLU
 (`_mlp_paged`) and the decode step's work list (`_live_items`) are the
 dense family's functions; the expert layer (`sparse_moe._expert_layer`,
 whose router chooses inside groups under this family's spec), the chunk's
-tile update (`sparse_moe.attend_plain`,
-`pallas_ops.selected_block_update`) and the writes of whole blocks the
-sparse-expert family's; the walk's bounds (`tile_span`) the window
-family's.
+plain tile update (`sparse_moe.attend_plain`) and the writes of whole
+blocks the sparse-expert family's; the walk's bounds (`tile_span`) and its
+causal tile update (`pallas_ops.causal_block_update`, whose mask the
+kernel makes from positions) the window family's.
 
 The layer, for input x at position t, two RMSNorms (`norm_eps`):
 ``h = x + Attn(N1(x))``, ``y = h + MLP(N2(h))``; after the last layer a
@@ -62,7 +62,8 @@ The two forms, over the same cache.
 at the published widths the forms cross at 171 queries a key. How a tile
 updates the softmax's carry is `sparse_moe.fused_attend`'s to say (the
 expanded form only: a head there has a K and a V of its own, which is the
-kernel's layout; its K is filled with zeros to a whole lane tile).
+kernel's layout; its K is filled with zeros to a whole lane tile, and a
+head being a group of one, a program of the kernel takes 1,024 queries).
 """
 
 from __future__ import annotations
@@ -78,7 +79,8 @@ from nnstreamer_tpu.backends import pallas_ops
 from nnstreamer_tpu.llm import sparse_moe
 from nnstreamer_tpu.llm.paged_model import _live_items, _mlp_paged, _proj
 from nnstreamer_tpu.llm.spec import LMSpec
-from nnstreamer_tpu.llm.window_moe import _write_chunk, tile_span
+from nnstreamer_tpu.llm.window_moe import (
+    _write_chunk, attend_tile_plain, tile_span)
 from nnstreamer_tpu.models.transformer import rmsnorm
 
 _F32 = jnp.float32
@@ -353,7 +355,8 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
     """Layer `li`'s attention of a whole chunk: queries (C, H, .) at
     positions qpos (C,) of one sequence over the context tiles `span`
     (first, end; traced) of its table `tab` (MB,), a tile's latents and
-    roped keys read once for all queries, under the causal edge.
+    roped keys read once for all queries, under the causal edge (qpos are
+    consecutive positions, which the fused update makes its mask from).
     `expanded`: the tile goes through `w` (`_wkvb`) to a K and a V a
     head; else the queries go through it to the latent. Returns (C, H *
     v_dim) in `dtype`."""
@@ -383,9 +386,6 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
         (c,) + heads + (kw,))
     # the kernel's layout, a head's queries side by side: made once
     qh = qg.transpose(1, 2, 0, 3) if fused else None
-    slot = jnp.arange(tile)
-    # selection keys of 1 and 0 under a threshold of 0 with no tie taken
-    none, no_tie = jnp.zeros((c,), jnp.uint32), jnp.full((c,), -1, jnp.int32)
 
     def attend_tile(j, state):
         bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
@@ -402,14 +402,11 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
         else:
             kt = jnp.concatenate([ct, pe], axis=-1)[:, None, :]
             vt = ct[:, None, :]
-        keys = ((j * tile + slot)[None, :] <= qpos[:, None]).astype(
-            jnp.uint32)                                       # (C, tile)
         if fused:
-            return pallas_ops.selected_block_update(
-                qh, kt, vt, keys, none, no_tie, 0, *state,
-                block_q=sparse_moe._FUSED_Q_BLOCK)
-        return sparse_moe.attend_plain(qg, kt, vt, keys, none, no_tie, 0,
-                                       state)
+            # the mask from the positions, inside the kernel
+            return pallas_ops.causal_block_update(
+                qh, kt, vt, qpos[0], j * tile, *state)
+        return attend_tile_plain(qg, kt, vt, qpos, j * tile, 0, state)
 
     _, l, acc = jax.lax.fori_loop(*span, attend_tile, (
         jnp.full(heads + (c,), -1e30, _F32), jnp.zeros(heads + (c,), _F32),

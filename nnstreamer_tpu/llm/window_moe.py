@@ -5,11 +5,13 @@ as ``jit_window_moe_decode_step`` and ``jit_window_moe_prefill_chunk``).
 `LMSpec.layer_kinds` says which kind each layer is, WINDOW or FULL. The
 projections (`_proj`), the norms (`rmsnorm`), the rope (`_rope_rows`) and
 the dense SwiGLU (`_mlp_paged`) are the dense family's functions, the
-expert layer (`sparse_moe._expert_layer`) and the chunk's tile update
-(`sparse_moe.fused_attend`, `attend_plain`,
-`pallas_ops.selected_block_update`) the sparse-expert family's, and the
-decode step's work list (`paged_model._live_items`) the dense family's
-with a lower bound.
+expert layer (`sparse_moe._expert_layer`), the choice of the chunk's tile
+update (`sparse_moe.fused_attend`) and the plain one (`attend_plain`) the
+sparse-expert family's, and the decode step's work list
+(`paged_model._live_items`) the dense family's with a lower bound. The
+fused tile update is this family's and the latent one's:
+`pallas_ops.causal_block_update`, the selected form's carry under a mask
+that positions alone decide.
 
 The layer, for input x at position t, with four RMSNorms (`norm_eps`):
 ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(MLP(N3(h)))``; the embedding is
@@ -55,7 +57,11 @@ How each program reads it.
   the tiles from ``(pos0 - window + 1) // tile`` on (at most
   ``(window + C) / tile + 1`` of them whatever the context). A tile's
   mask is the causal edge and the window's; the update is the one
-  `sparse_moe.fused_attend` chooses.
+  `sparse_moe.fused_attend` chooses: plain XLA under the mask as an
+  array, or the kernel, which is told the first query's position, the
+  tile's first slot and the window and runs, a block of queries, the
+  update with no mask (every query sees every slot), under a mask made
+  from two iotas (the edges), or not at all (no query sees any slot).
 
 Both return, beside the logits, ``(expert layers, experts_held + 1)``
 int32: the tokens each held expert got and, last, the real tokens' pairs
@@ -287,13 +293,29 @@ def _write_chunk(pool, li, blk_idx, blk_off, x, by_block: bool):
     return sparse_moe._put_blocks(pool, li, first, x)
 
 
+def attend_tile_plain(qg, kt, vt, qpos, first, window: int, state):
+    """One context tile in plain XLA (`sparse_moe.attend_plain`) under
+    the causal edge and, where `window` > 0, the window's: the queries at
+    positions qpos (C,) against the slots from `first` on, the mask as
+    selection keys of 1 and 0 under a threshold of 0 with no tie taken."""
+    c = qpos.shape[0]
+    s = (first + jnp.arange(kt.shape[0]))[None, :]
+    on = s <= qpos[:, None]
+    if window:
+        on = on & (s > qpos[:, None] - window)
+    return sparse_moe.attend_plain(
+        qg, kt, vt, on.astype(jnp.uint32), jnp.zeros((c,), jnp.uint32),
+        jnp.full((c,), -1, jnp.int32), 0, state)
+
+
 def attend_tiles(q, qpos, tab, span, li, k_pool, v_pool, *, window: int,
                  fused: bool, tile: int, dtype):
     """Layer `li`'s attention of a whole chunk: queries q (C, H, hd) at
     positions qpos (C,) of one sequence over the context tiles `span`
     (first, end; traced) of its table `tab` (MB,), a tile's K and V read
     once for all queries, under the causal edge and, where `window` > 0,
-    the window's. Returns (C, H * hd) f32."""
+    the window's; qpos are consecutive positions, which the fused update
+    makes its mask from. Returns (C, H * hd) f32."""
     c, nh, hd = q.shape
     bs, nkv = k_pool.shape[2], k_pool.shape[3]
     grp = nh // nkv
@@ -307,25 +329,16 @@ def attend_tiles(q, qpos, tab, span, li, k_pool, v_pool, *, window: int,
     qg = q.reshape(c, nkv, grp, hd)
     # the kernel's layout, a head's queries side by side: made once
     qh = qg.transpose(1, 2, 0, 3) if fused else None
-    slot = jnp.arange(tile)
-    # selection keys of 1 and 0 under a threshold of 0 with no tie taken
-    none, no_tie = jnp.zeros((c,), jnp.uint32), jnp.full((c,), -1, jnp.int32)
 
     def attend_tile(j, state):
         bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
         kt = k_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
         vt = v_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
-        s = (j * tile + slot)[None, :]
-        on = s <= qpos[:, None]
-        if window:
-            on = on & (s > qpos[:, None] - window)
-        keys = on.astype(jnp.uint32)                          # (C, tile)
         if fused:
-            return pallas_ops.selected_block_update(
-                qh, kt, vt, keys, none, no_tie, 0, *state,
-                block_q=sparse_moe._FUSED_Q_BLOCK)
-        return sparse_moe.attend_plain(qg, kt, vt, keys, none, no_tie, 0,
-                                       state)
+            # the mask from the positions, inside the kernel
+            return pallas_ops.causal_block_update(
+                qh, kt, vt, qpos[0], j * tile, *state, window=window)
+        return attend_tile_plain(qg, kt, vt, qpos, j * tile, window, state)
 
     _, l, acc = jax.lax.fori_loop(*span, attend_tile, (
         jnp.full((nkv, grp, c), -1e30, _F32),

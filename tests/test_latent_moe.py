@@ -18,9 +18,11 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "perfbench"))
 
+import tiny_hybrid                                              # noqa: E402
 import tiny_latent_moe as tiny                                  # noqa: E402
 import tiny_sparse_moe                                          # noqa: E402
 import tiny_window_moe                                          # noqa: E402
+from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
@@ -30,8 +32,10 @@ from nnstreamer_tpu.llm.paged_cache import PagedKVCache         # noqa: E402
 from nnstreamer_tpu.llm.spec import LMSpec                      # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import latent_moe_lm as ref           # noqa: E402
-from perfbench.references import sparse_moe_lm, window_moe_lm   # noqa: E402
-from perfbench.runners import sparse_moe_llm, window_moe_llm    # noqa: E402
+from perfbench.references import (                              # noqa: E402
+    hybrid_lm, sparse_moe_lm, window_moe_lm)
+from perfbench.runners import (                                 # noqa: E402
+    hybrid_llm, sparse_moe_llm, window_moe_llm)
 from perfbench.runners.latent_moe_llm import lm_spec            # noqa: E402
 
 CFG = tiny.CONFIG
@@ -179,6 +183,64 @@ def test_the_expanded_form_through_the_kernel_agrees_with_the_absorbed():
     assert float(jnp.abs(absorbed).max()) > 0.5
     assert np.abs(np.asarray(fused) - np.asarray(absorbed)).max() < TOL
     assert np.abs(np.asarray(plain) - np.asarray(absorbed)).max() < TOL
+
+
+@pytest.mark.parametrize("tile", [4, 16])
+def test_expanded_chunks_through_the_causal_kernel_say_what_it_did(
+        bundle, params, monkeypatch, tile):
+    """Every chunk expanded and through `pallas_ops.causal_block_update`
+    (interpreted; a program takes 2 queries of the bucket of 8): the
+    reference's logits, and the chunk's span says the three kinds of
+    (block of queries, tile) pair, which add up to the walk's trip count x
+    blocks x layers; a head is a group of one."""
+    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(latent_moe, "expanded_attend", lambda c, spec: True)
+    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(pallas_ops, "causal_block_q", lambda c, grp: 2)
+    ids = _prompt(40, seed=5)
+    tracer = Tracer(max_events=8192)
+    ex = _executor(bundle, tracer=tracer, name="llm")
+    kw = ex.programs.chunk_kw(0, CHUNK)
+    assert kw["fused"] is True and kw["expanded"] is True
+    got = _serve(ex, ids, 29)
+    want = np.asarray(ref.forward_logits(params, CFG, ids))[28:]
+    assert np.abs(got - want).max() < TOL
+    chunks = [a for ph, cat, _, label, _, _, a in tracer.events()
+              if ph == "X" and cat == "backend" and label == "invoke"
+              and a.get("what") == "llm_prefill_chunk"]
+    assert [a["pos0"] for a in chunks] == [8, 16, 24]   # the first compiled
+    kinds = families.QBLOCK_KINDS
+    assert kinds == ("chunk_qblocks_clear", "chunk_qblocks_edge",
+                     "chunk_qblocks_skipped")
+
+    def by_hand(pos0):
+        said = [0, 0, 0]
+        for j in range(min(-(-(pos0 + CHUNK) // tile), 64 // tile)):
+            for q0 in range(pos0, pos0 + CHUNK, 2):
+                pairs = [s <= q for q in (q0, q0 + 1)
+                         for s in range(j * tile, (j + 1) * tile)]
+                said[0 if all(pairs) else 2 if not any(pairs) else 1] += 3
+        return said                               # three layers
+
+    for a in chunks:
+        said = [a[k] for k in kinds]
+        assert said == by_hand(a["pos0"])
+        assert sum(said) == a["ctx_tiles"] * (CHUNK // 2) * 3
+    st = ex.programs.stats()
+    for i, k in enumerate(kinds):
+        assert st[k] == by_hand(0)[i] + sum(a[k] for a in chunks)
+    assert st["chunk_qblocks_clear"] > 0 and st["chunk_qblocks_edge"] > 0
+    # a chunk's first blocks lie under its second tile of 4, which starts
+    # past them
+    assert (st["chunk_qblocks_skipped"] > 0) == (tile == 4)
+
+
+def test_an_absorbed_or_plain_chunk_counts_no_program(bundle):
+    ex = _executor(bundle)
+    _serve(ex, _prompt(20, seed=2), 14)
+    st = ex.programs.stats()
+    assert st["chunk_tiles_attended"] > 0
+    assert all(st[k] == 0 for k in families.QBLOCK_KINDS)
 
 
 def test_the_form_follows_from_the_bucket_alone():
@@ -361,6 +423,51 @@ def test_the_other_expert_families_programs_are_as_before_the_groups():
     assert new == old and len(new[0]) > 1000 and "top_k" in new[0]
 
 
+def _chunk_jaxprs():
+    """The tiny chunk programs of the sparse-expert and the hybrid family
+    (Keye's and SALA's), the tile update the fused one."""
+    out = []
+    for mod, runner, lm in ((tiny_sparse_moe, sparse_moe_llm, sparse_moe_lm),
+                            (tiny_hybrid, hybrid_llm, hybrid_lm)):
+        cfg = mod.CONFIG
+        p = lm.make_params(cfg, SEED, dtype=jnp.float32)
+        ex = PagedLLMExecutor(
+            ModelBundle(fn=None, params=p, lm=runner.lm_spec(cfg)),
+            dtype=jnp.float32, state_slots=4, prefill_chunk=8, block_size=4,
+            num_blocks=48, max_len=64)
+        ps = ex.programs
+        kw = ps.chunk_kw(8, 8)
+        assert kw["fused"] is True
+        z = np.zeros((8,), np.int32)
+        tab = np.zeros((ex.max_blocks,), np.int32)
+        args = ps.chunk_args(p, np.zeros((1, 8), np.int32), np.int32(8), z,
+                             z, tab, np.int32(7), ex.cache.pools(),
+                             np.int32(0), None)
+        out.append(str(jax.make_jaxpr(
+            lambda *a, ps=ps, kw=kw: ps.program("chunk").fn(*a, **kw))(
+                *args)))
+    return out
+
+
+def test_keyes_and_salas_chunk_programs_do_not_reach_the_causal_form(
+        monkeypatch):
+    """The sparse-expert and the hybrid family's chunks, their tile
+    update the fused one, trace to the same program with the causal
+    form's three names in `pallas_ops` and without them: they call the
+    selected form, whose text this PR left as it was, and nothing of the
+    new entry."""
+    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    with_it = _chunk_jaxprs()
+    for name in ("causal_block_update", "causal_block_q", "block_reach",
+                 "_causal_block_kernel"):
+        monkeypatch.delattr(pallas_ops, name)
+    without = _chunk_jaxprs()
+    assert with_it == without
+    for text in with_it:
+        assert len(text) > 1000 and "selected_block_update" in text
+        assert "causal_block_update" not in text
+
+
 # -- the share of the experts -----------------------------------------------------
 
 def _uncut():
@@ -507,7 +614,8 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     for key in ("rows", "kv_tokens", "kv_slots", "kv_pool_itemsize",
                 "experts_touched", "expert_pairs_held", "expert_pairs_away"):
         assert key in decode[-1], key
-    for key in ("pos0", "clen", "ctx_tiles", "attend", "latents_expanded"):
+    for key in ("pos0", "clen", "ctx_tiles", "attend", "latents_expanded",
+                *families.QBLOCK_KINDS):
         assert key in chunks[-1], key
     assert chunks[-1]["attend"] == "absorbed"       # a bucket of 8
     resolved = [e[6] for e in tracer.events() if e[3] == "resolve"]

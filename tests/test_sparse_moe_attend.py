@@ -2,7 +2,10 @@
 tile update (`backends/pallas_ops.selected_block_update`, here in
 interpret mode) against the plain one (`sparse_moe.attend_plain`) on the
 same inputs, the whole chunk program with the fused update forced against
-the plain reference, and how the update is chosen and reported.
+the plain reference, and how the update is chosen and reported; and the
+tile update's causal form (`pallas_ops.causal_block_update`, the window
+and the latent family's) against both, with the arithmetic that names
+what a block of its queries does (`pallas_ops.block_reach`).
 
 Tolerances. Both updates compute in float32 here and differ only in the
 order float32 sums are added inside a tile: 1e-5 absolute on carries of
@@ -10,6 +13,7 @@ magnitude about 1 (measured 2.9e-6).
 The chunk program against the reference: `test_sparse_moe.TOL`.
 """
 
+import functools
 import os
 import sys
 
@@ -24,7 +28,7 @@ import tiny_sparse_moe as tiny                                  # noqa: E402
 from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
-from nnstreamer_tpu.llm import sparse_moe                       # noqa: E402
+from nnstreamer_tpu.llm import sparse_moe, window_moe           # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import sparse_moe_lm as ref           # noqa: E402
 from perfbench.runners.sparse_moe_llm import lm_spec            # noqa: E402
@@ -161,6 +165,201 @@ def test_fused_update_refuses_blocks_that_do_not_tile():
     with pytest.raises(ValueError, match="divisible"):
         _both(qg, kt, kt, keys, np.zeros((C,), np.uint32),
               np.zeros((C,), np.int32), 0, state, block_q=12)
+
+
+# -- the causal form: the mask from positions, inside the kernel --------------
+
+CC, CTILE = 32, 32          # queries and slots of the small cases
+
+
+@functools.lru_cache(maxsize=None)
+def _causal_fn(window, block_q):
+    return jax.jit(lambda qh, kt, vt, pos0, slot0, m, l, acc:
+                   pallas_ops.causal_block_update(
+                       qh, kt, vt, pos0, slot0, m, l, acc, window=window,
+                       block_q=block_q))
+
+
+@functools.lru_cache(maxsize=None)
+def _selected_fn(block_q):
+    return jax.jit(lambda qh, kt, vt, keys, m, l, acc:
+                   pallas_ops.selected_block_update(
+                       qh, kt, vt, keys, jnp.zeros((keys.shape[0],),
+                                                   jnp.uint32),
+                       jnp.full((keys.shape[0],), -1, jnp.int32), 0,
+                       m, l, acc, block_q=block_q))
+
+
+_plain_fn = jax.jit(window_moe.attend_tile_plain, static_argnums=(5,))
+
+
+def _seen(pos0, c, first, tile, window):
+    """(c, tile) bool: query pos0 + i sees slot first + s."""
+    q = pos0 + np.arange(c)[:, None]
+    s = first + np.arange(tile)[None, :]
+    return (s <= q) & ((s > q - window) if window else True)
+
+
+def _causal_case(name):
+    """-> (nkv, grp, c, tile, d, dv, pos0, window, block_q, dtype)"""
+    if name.startswith("rule"):
+        # the blocks the rule gives, at a head's real widths: a latent
+        # head (a group of one, K 256 and V 128 wide) 1,024, 512 and 256,
+        # a short chunk whole, Trinity's group of 6 and Keye's of 8 128
+        geo = {"rule1024": (1, 1, 2048, 256, 128, 1024),
+               "rule512": (2, 1, 512, 256, 128, 512),
+               "rule256": (2, 1, 256, 256, 128, 256),
+               "rule_short": (2, 1, 64, 256, 128, 64),
+               "rule_grp6": (1, 6, 256, 128, 128, 128),
+               "rule_grp8": (1, 8, 128, 128, 128, 128)}[name]
+        nkv, grp, c, d, dv, bq = geo
+        assert pallas_ops.causal_block_q(c, grp) == bq
+        return nkv, grp, c, 128, d, dv, 128 + c // 2, 0, 0, jnp.float32
+    at, win, group, dt = name.split("-")
+    pos0 = {"p0": 0, "p1024": CTILE, "p3072": 3 * CTILE, "podd": 45}[at]
+    window = {"full": 0, "wsmall": 20, "wwide": 80}[win]
+    nkv, grp, d, dv = {"grp1": (3, 1, 32, 16), "grp6": (1, 6, 16, 16),
+                       "grp8": (2, 8, 16, 16)}[group]
+    return (nkv, grp, CC, CTILE, d, dv, pos0, window, 8,
+            jnp.float32 if dt == "f32" else jnp.bfloat16)
+
+
+CAUSAL_CASES = [f"{at}-{win}-{group}-f32"
+                for at in ("p0", "p1024", "p3072", "podd")
+                for win in ("full", "wsmall", "wwide")
+                for group in ("grp1", "grp6", "grp8")] + [
+    f"podd-{win}-{group}-bf16" for win in ("full", "wsmall", "wwide")
+    for group in ("grp1", "grp6", "grp8")] + [
+    "rule1024", "rule512", "rule256", "rule_short", "rule_grp6",
+    "rule_grp8"]
+
+
+@pytest.mark.parametrize("case", CAUSAL_CASES)
+def test_causal_tile_update_equals_the_selected_and_the_plain_one(case):
+    """Every tile from the first to one past the last query's, so that
+    tiles no query reaches, tiles every query sees whole and both edges
+    come by; the queries past the chunk's real ones are positions like
+    any other. The selected form, given the same mask as keys of 1 and 0,
+    runs the masked body everywhere; the causal form runs it on the edge
+    blocks (bit for bit in float32), hands a block that sees nothing
+    through, bit for bit, and runs the body with no mask where a block
+    sees every slot: the same sums, which the interpreter's fusions round
+    a float32 ulp apart."""
+    nkv, grp, c, tile, d, dv, pos0, window, bq, dt = _causal_case(case)
+    rng = np.random.default_rng(CAUSAL_CASES.index(case))
+    bq_eff = bq or pallas_ops.causal_block_q(c, grp)
+    qg = jnp.asarray(rng.normal(size=(c, nkv, grp, d)), dt)
+    qh = qg.transpose(1, 2, 0, 3)
+    kinds = set()
+    for j in range(-(-(pos0 + c) // tile) + 1):
+        kt = jnp.asarray(rng.normal(size=(tile, nkv, d)), dt)
+        vt = jnp.asarray(rng.normal(size=(tile, nkv, dv)), dt)
+        state = (jnp.asarray(rng.normal(size=(nkv, grp, c)), jnp.float32),
+                 jnp.asarray(rng.uniform(1, 9, size=(nkv, grp, c)),
+                             jnp.float32),
+                 jnp.asarray(rng.normal(size=(nkv, grp, c, dv)),
+                             jnp.float32))
+        seen = _seen(pos0, c, j * tile, tile, window)
+        got = _causal_fn(window, bq)(qh, kt, vt, jnp.int32(pos0),
+                                     jnp.int32(j * tile), *state)
+        sel = _selected_fn(min(bq_eff, 128))(
+            qh, kt, vt, jnp.asarray(seen.astype(np.uint32)), *state)
+        plain = _plain_fn(qg, kt, vt, pos0 + jnp.arange(c), j * tile,
+                          window, state)
+        # bfloat16: an ulp of a probability may round its cast the other
+        # way, so only float32 is held to the bit
+        exact = dt == jnp.float32
+        tol = CARRY_TOL if exact else 2e-2
+        for g, w, pl_, st in zip(got, sel, plain, state):
+            g, w, st = np.asarray(g), np.asarray(w), np.asarray(st)
+            assert g.shape == w.shape and g.dtype == np.float32
+            assert np.abs(g - np.asarray(pl_)).max() < tol
+            for i in range(c // bq_eff):
+                rows = slice(i * bq_eff, (i + 1) * bq_eff)
+                block = seen[rows]
+                if not block.any():
+                    kinds.add("skipped")
+                    assert (g[:, :, rows] == st[:, :, rows]).all()
+                a, b = g[:, :, rows], w[:, :, rows]
+                if not block.all():
+                    kinds.add("edge" if block.any() else "skipped")
+                    assert (a == b).all() if exact else \
+                        np.abs(a - b).max() < tol
+                else:
+                    kinds.add("clear")
+                    assert np.abs(a - b).max() < tol
+            # a query that sees no slot of the tile keeps its carry
+            idle = ~seen.any(1)
+            assert (g[:, :, idle] == st[:, :, idle]).all()
+    assert "skipped" in kinds and ("edge" in kinds or "clear" in kinds)
+
+
+def test_causal_update_attends_exactly_the_slots_positions_allow():
+    """Queries of zero and one-hot values from an empty carry, as the
+    selected form's test: acc is the mask itself."""
+    c, tile, pos0, window = 32, 32, 45, 20
+    qh = jnp.zeros((1, 2, c, tile), jnp.float32)
+    kt = jnp.asarray(np.random.default_rng(3).normal(size=(tile, 1, tile)),
+                     jnp.float32)
+    vt = jnp.eye(tile)[:, None, :]
+    for j in range(4):
+        m, l, acc = pallas_ops.causal_block_update(
+            qh, kt, vt, pos0, j * tile, jnp.full((1, 2, c), -1e30),
+            jnp.zeros((1, 2, c)), jnp.zeros((1, 2, c, tile)), window=window,
+            block_q=8)
+        seen = _seen(pos0, c, j * tile, tile, window)
+        assert (np.asarray(acc) == seen[None, None].astype(np.float32)).all()
+        assert (np.asarray(l) == seen.sum(1)[None, None]).all()
+        assert (np.asarray(m) == np.where(seen.any(1), 0.0, -1e30).astype(
+            np.float32)).all()
+
+
+def test_causal_update_refuses_blocks_that_do_not_tile():
+    state = _carry(np.random.default_rng(0), 1, 1, False)
+    with pytest.raises(ValueError, match="divisible"):
+        pallas_ops.causal_block_update(
+            jnp.zeros((1, 1, C, HD)), jnp.zeros((TILE, 1, HD)),
+            jnp.zeros((TILE, 1, HD)), 0, 0, *state, block_q=12)
+
+
+@pytest.mark.parametrize("window", [0, 5, 16, 40])
+def test_block_reach_against_a_loop_over_every_pair(window):
+    """`block_reach` for blocks of 8 queries and tiles of 16 slots, every
+    first position up to 70: skip is "no (query, slot) pair is seen",
+    clear "every pair is", by a loop in plain Python; numpy's arrays
+    answer as the ints do."""
+    bq, sk = 8, 16
+    for q0 in range(0, 70):
+        for s0 in range(0, 96, sk):
+            pairs = [s <= q and (not window or s > q - window)
+                     for q in range(q0, q0 + bq) for s in range(s0, s0 + sk)]
+            skip, clear = pallas_ops.block_reach(q0, bq, s0, sk, window)
+            assert (bool(skip), bool(clear)) == (not any(pairs), all(pairs))
+    q0 = np.arange(0, 70)[:, None]
+    s0 = np.arange(0, 96, sk)[None, :]
+    skip, clear = pallas_ops.block_reach(q0, bq, s0, sk, window)
+    assert skip.shape == clear.shape == (70, 6)
+    assert not (skip & clear).any() and skip.any()
+    # a window narrower than a block and a tile together clears no pair
+    assert clear.any() == (window in (0, 40))
+    assert skip[5, 1] and clear[69, 0] == (window == 0)
+
+
+def test_a_programs_queries_follow_the_groups_size():
+    """`causal_block_q`: 1,024 rows of queries a program (heads of the
+    group x queries) or the most under it, a power of two that divides
+    the chunk, never under 128, a shorter chunk whole."""
+    q = pallas_ops.causal_block_q
+    assert q(2048, 1) == 1024 and q(1024, 1) == 1024      # a latent head
+    assert q(512, 1) == 512 and q(4096, 1) == 1024
+    assert q(2048, 6) == 128 and q(1024, 6) == 128        # Trinity's group
+    assert q(2048, 8) == 128 and q(2048, 16) == 128
+    assert q(2048, 2) == 512 and q(2048, 4) == 256
+    assert q(256, 1) == 256 and q(64, 1) == 64 and q(64, 6) == 64
+    assert q(384, 1) == 128 and q(768, 1) == 256
+    for c in (64, 128, 384, 1024, 2048):
+        for grp in (1, 2, 4, 6, 8, 16):
+            assert c % q(c, grp) == 0
 
 
 # -- the whole chunk program with the fused update forced ---------------------

@@ -73,6 +73,106 @@ def test_selected_block_update_compiles_at_the_cells_sizes(one_chip, nkv,
     assert mem.temp_size_in_bytes < nkv * grp * c * tile
 
 
+# the tile update's causal form (`window_moe.attend_tiles`,
+# `latent_moe.attend_tiles`): the Trinity cell's window and full layers (8
+# KV heads of 6 query heads of 128, a window of 4,096, chunks of 2,048 and
+# a whole prompt of 1,024) and the DeepSeek-V2 cell's expanded heads
+# (deepseek-v2-6l: 128 heads a group of one each, K filled up to 256, V
+# 128 wide)
+@pytest.mark.parametrize("nkv,grp,c,d,dv,window", [
+    (8, 6, 2048, 128, 128, 0), (8, 6, 2048, 128, 128, 4096),
+    (8, 6, 1024, 128, 128, 0), (8, 6, 1024, 128, 128, 4096),
+    (128, 1, 2048, 256, 128, 0), (128, 1, 1024, 256, 128, 0)],
+    ids=["trinity-2048-full", "trinity-2048-window", "trinity-1024-full",
+         "trinity-1024-window", "dsv2-2048", "dsv2-1024"])
+def test_causal_block_update_compiles_at_the_cells_sizes(
+        one_chip, monkeypatch, nkv, grp, c, d, dv, window):
+    """Mosaic takes the kernel at the block the rule gives: 128 queries
+    of Trinity's 6 heads, whose blocks and scores fit the compiler's own
+    16 MB of fast memory a kernel (the call asks for no more), and 1,024
+    of a latent head, whose scores alone are 4 MB in float32 three times
+    over: the call asks for what it reckons, under a quarter of the
+    chip's 128 MiB."""
+    tile, asked = sparse_moe._CTX_TILE, []
+    params = pallas_ops.pltpu.CompilerParams
+
+    def spy(**kw):
+        asked.append(kw.get("vmem_limit_bytes"))
+        return params(**kw)
+
+    monkeypatch.setattr(pallas_ops.pltpu, "CompilerParams", spy)
+    assert pallas_ops.causal_block_q(c, grp) == (128 if grp == 6 else 1024)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def update(q, k, v, qpos0, slot0, m, l, acc):
+        return pallas_ops.causal_block_update(
+            q, k, v, qpos0, slot0, m, l, acc, window=window,
+            interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(5, 6, 7)).lower(
+        arg((nkv, grp, c, d), jnp.bfloat16),
+        arg((tile, nkv, d), jnp.bfloat16),
+        arg((tile, nkv, dv), jnp.bfloat16),
+        arg((), jnp.int32), arg((), jnp.int32),
+        arg((nkv, grp, c), jnp.float32), arg((nkv, grp, c), jnp.float32),
+        arg((nkv, grp, c, dv), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "causal_block_update" in text
+    if grp == 6:
+        assert asked == [None]
+    else:
+        assert 16 << 20 < asked[0] < 32 << 20 and len(asked) == 1
+    # the carry is updated in place and nothing of a tile's scores' or
+    # mask's size, (heads, C, tile) or (C, tile), is kept outside
+    assert not re.search(rf"(u32|s32|pred)\[{c},{tile}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < nkv * grp * c * tile
+
+
+@pytest.mark.parametrize("layer,c", [(1, 2048), (2, 2048), (1, 1024)],
+                         ids=["window-2048", "full-2048", "window-1024"])
+def test_the_window_chunks_walk_keeps_no_mask_a_query_wide(one_chip,
+                                                           monkeypatch,
+                                                           layer, c):
+    """One expert layer of the Trinity cell's chunk (a window layer and
+    the full one; a table of 800 blocks of 64): the walk updates a tile
+    in the causal kernel, which makes its mask from positions, so the
+    program holds no (chunk, tile) array of integers or booleans (the
+    selected form was handed one a tile: 8.4 MB, read again a KV head)."""
+    from nnstreamer_tpu.llm import window_moe
+    from perfbench.references import window_moe_lm
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    cfg, spec = _cell("trinity")
+    kind = spec.layer_kinds[layer]
+    mb, bs, nblk, bf, i32 = 800, 64, 1200, jnp.bfloat16, jnp.int32
+    tile = sparse_moe._CTX_TILE
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = jax.eval_shape(
+        lambda: window_moe_lm.make_params(cfg, 1, dtype=bf))["blocks"][layer]
+    blk = jax.tree.map(lambda x: arg(x.shape, x.dtype), blk)
+    pool = arg((4, nblk, bs, 8, 128), bf)
+
+    def chunk_layer(*a):
+        return window_moe._chunk_layer(
+            *a, kind=kind, dense=False, tile=tile, by_block=True, fused=True,
+            spec=spec, dtype=bf)
+
+    text = jax.jit(chunk_layer, donate_argnums=(8, 9)).lower(
+        blk, arg((c, 1, 3072), bf), arg((), i32), arg((c,), i32),
+        arg((c,), jnp.bool_), arg((c,), i32), arg((c,), i32),
+        arg((mb,), i32), pool, pool).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sum("causal_block_update" in ln for ln in calls) == 1
+    assert not any("selected_block_update" in ln for ln in calls)
+    assert not re.search(rf"(u32|s32|pred)\[{c},{tile}\]", text)
+
+
 def test_the_hybrid_chunks_walk_gathers_nothing_a_query_wide(one_chip,
                                                              monkeypatch):
     """One sparse layer of the SALA cell's chunk (minicpm-sala-8l: 2,048
@@ -224,8 +324,8 @@ def test_the_latent_chunks_expanded_walk_takes_the_kernel(one_chip,
     """One expert layer of the DeepSeek-V2 cell's chunk (deepseek-v2-6l:
     128 heads of 128 + 64 | 128 over latents of 512, a chunk of 2,048 and
     a whole prompt of 1,024, a table of 288 blocks of 64): the expanded
-    walk updates a tile in the kernel (a head's K filled up to 256, its V
-    128 wide), the two grouped products are the repo's kernel too, no
+    walk updates a tile in the causal kernel (a head's K filled up to 256,
+    its V 128 wide), the two grouped products are the repo's kernel too, no
     score over (heads, chunk, tile) is kept, and neither pool is copied
     or converted."""
     from nnstreamer_tpu.llm import latent_moe
@@ -261,6 +361,10 @@ def test_the_latent_chunks_expanded_walk_takes_the_kernel(one_chip,
     calls = [ln for ln in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in ln]
     assert len(calls) == 3
+    # the tile update is the causal form: no mask a query wide is built
+    assert sum("causal_block_update" in ln for ln in calls) == 1
+    assert not re.search(
+        rf"(u32|s32|pred)\[{c},{sparse_moe._CTX_TILE}\]", text)
     # a tile's float32 scores would be heads x chunk x tile x 4 bytes
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 4 * 128 * c * sparse_moe._CTX_TILE

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "perfbench"))
 
 import tiny_sparse_moe                                          # noqa: E402
 import tiny_window_moe as tiny                                  # noqa: E402
+from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
@@ -114,6 +115,104 @@ def test_chunks_then_decode_give_the_references_logits(bundle, params,
     assert (got.argmax(-1) == want.argmax(-1)).all()
     assert held <= window_cap(WINDOW, BS, CHUNK)
     assert ex.cache.allocator.used == ex.cache.window_alloc.used == 0
+
+
+# -- the chunk's walk through the causal kernel ----------------------------------
+
+@pytest.fixture
+def forced(monkeypatch):
+    """The predicate says yes whatever the backend and the shapes, and a
+    program takes 2 queries of a chunk's bucket of 8, so that a walk has
+    blocks of every kind; the kernel then runs in interpret mode."""
+    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(pallas_ops, "causal_block_q", lambda c, grp: 2)
+
+
+def _qblocks_by_hand(pos0, bucket, tile, bq=2):
+    """(clear, edge, skipped) over the tiny model's layers (S S F) for a
+    chunk at `pos0`: every (block, tile) pair of each layer's walk, by a
+    loop over its (query, slot) pairs."""
+    said = [0, 0, 0]
+    for window in (WINDOW, WINDOW, 0):
+        first, end = window_moe.tile_span(pos0, bucket, 64, tile, window)
+        for j in range(first, end):
+            for q0 in range(pos0, pos0 + bucket, bq):
+                pairs = [s <= q and (not window or s > q - window)
+                         for q in range(q0, q0 + bq)
+                         for s in range(j * tile, (j + 1) * tile)]
+                said[0 if all(pairs) else 2 if not any(pairs) else 1] += 1
+    return said
+
+
+@pytest.mark.parametrize("tile", [4, 8])
+def test_the_walk_through_the_causal_kernel_gives_the_references_logits(
+        bundle, params, monkeypatch, forced, tile):
+    """The fused walk (`pallas_ops.causal_block_update`, interpreted) on
+    a context past the window, tiles smaller than the window and as wide:
+    the reference's logits, and the chunk's span says what the kernel's
+    programs did, which adds up to the walks' trip counts x blocks."""
+    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    ids = _prompt(45, seed=33)
+    tracer = Tracer(max_events=8192)
+    ex = _executor(bundle, tracer=tracer, name="llm")
+    assert ex.programs.chunk_kw(0, CHUNK)["fused"] is True
+    got, _ = _serve(ex, ids, 33)
+    want = np.asarray(ref.forward_logits(params, CFG, ids))[32:]
+    assert np.abs(got - want).max() < TOL
+    chunks = [a for ph, cat, _, label, _, _, a in tracer.events()
+              if ph == "X" and cat == "backend" and label == "invoke"
+              and a.get("what") == "llm_prefill_chunk"]
+    assert len(chunks) == 8         # 9 chunks, the first one compiled
+    kinds = ("chunk_qblocks_clear", "chunk_qblocks_edge",
+             "chunk_qblocks_skipped")
+    for a in chunks:
+        assert a["attend"] == "fused"
+        said = [a[k] for k in kinds]
+        # a chunk of 4 rides a bucket of 8: 4 blocks of 2 queries
+        assert a["bucket"] == 8
+        assert said == _qblocks_by_hand(a["pos0"], 8, tile)
+        assert sum(said) == (a["ctx_tiles_full"]
+                             + 2 * a["ctx_tiles_window"]) * 4
+    st = ex.stats()
+    first = _qblocks_by_hand(0, 8, tile)
+    for i, k in enumerate(kinds):
+        assert st[k] == first[i] + sum(a[k] for a in chunks)
+    # behind the window a window layer skips, under the diagonal it is clear
+    assert all(st[k] > 0 for k in kinds)
+
+
+def test_on_the_cpu_the_walk_is_plain_and_counts_no_program(bundle):
+    tracer = Tracer(max_events=4096)
+    ex = _executor(bundle, tracer=tracer, name="llm")
+    _serve(ex, _prompt(20, seed=1), 14)
+    chunks = [a for ph, cat, _, label, _, _, a in tracer.events()
+              if ph == "X" and label == "invoke" and a
+              and a.get("what") == "llm_prefill_chunk"]
+    assert chunks and all(a["attend"] == "plain" for a in chunks)
+    st = ex.stats()
+    for k in ("chunk_qblocks_clear", "chunk_qblocks_edge",
+              "chunk_qblocks_skipped"):
+        assert st[k] == 0 and all(a[k] == 0 for a in chunks)
+
+
+def test_padding_queries_past_the_tables_last_tile_attend_nothing():
+    """A bucket whose padding rows lie past the table: the walk ends at
+    the table's last tile, those queries see no slot of any tile, and
+    the fused walk returns zeros for them as the plain one does."""
+    rng = np.random.default_rng(2)
+    c, tile, nkv, grp, hd = 16, 8, 2, 3, 16
+    tab = jnp.asarray([3, 1, 4, 2], jnp.int32)         # 4 blocks of 4
+    q = jnp.asarray(rng.normal(size=(c, nkv * grp, hd)), jnp.float32)
+    pools = [jnp.asarray(rng.normal(size=(1, 6, 4, nkv, hd)), jnp.float32)
+             for _ in range(2)]
+    qpos = 8 + jnp.arange(c)                           # 8..23: 16.. past it
+    span = window_moe.tile_span(8, c, 16, tile)
+    assert span == (0, 2)
+    out = [window_moe.attend_tiles(q, qpos, tab, span, 0, *pools, window=0,
+                                   fused=fused, tile=tile, dtype=jnp.float32)
+           for fused in (False, True)]
+    assert np.abs(np.asarray(out[0]) - np.asarray(out[1])).max() < TOL
+    assert float(jnp.abs(out[1][:8]).max()) > 0.1
 
 
 def test_a_window_layer_alone_forgets_what_is_behind_its_window(params):
@@ -432,7 +531,8 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
                 "experts_touched", "expert_pairs_held", "expert_pairs_away"):
         assert key in decode[-1], key
     for key in ("pos0", "clen", "ctx_tiles_full", "ctx_tiles_window",
-                "attend"):
+                "attend", "chunk_qblocks_clear", "chunk_qblocks_edge",
+                "chunk_qblocks_skipped"):
         assert key in chunks[-1], key
     # a chunk is launched unsynced: what its read-back tells is on the
     # span that resolved it, under the chunk's own req, pos0 and clen
