@@ -266,6 +266,34 @@ def leg_kernels() -> dict:
            normal((2, 96, 32, 128), jnp.bfloat16)),
           latent_walk(False, False), 3e-2)
 
+    # the same family's decode walk as one kernel a row (the pools whole
+    # and in place, read through the tables; a bucket of 4 of which 3 rows
+    # are live, at position 0, on a step's last slot and 3 steps deep)
+    # against the plain walk's work list, layer 1 of 2
+    from nnstreamer_tpu.backends import pallas_paged
+    from nnstreamer_tpu.llm.paged_model import _live_items
+
+    assert latent_moe.fused_decode(64, la, jnp.bfloat16)
+    dpos = jnp.asarray([0, 1023, 2100, 0], jnp.int32)
+    dtab = np.zeros((4, 48), np.int32)
+    dtab[0, :1], dtab[1, :16], dtab[2, :33] = 1, np.arange(2, 18), \
+        18 + rng.permutation(60)[:33]
+    dtab = jnp.asarray(dtab)
+    walk = latent_moe.walk_plan(64, 4, 48)
+
+    def decode_plain(q, kp, ip):
+        items = _live_items(dtab, dpos, 64, *walk)
+        return latent_moe.attend_latent(q, kp, ip, 1, items, walk[2],
+                                        0.05)[:3]
+
+    check("latent_decode_fused",
+          lambda q, kp, ip: pallas_paged.latent_decode_attn(
+              q, kp, ip, jnp.int32(1), dtab, dpos, jnp.int32(3), scale=0.05,
+              step=latent_moe._DECODE_STEP)[:3],
+          (normal((4, 16, 576), jnp.bfloat16),
+           normal((2, 96, 64, 1, 512), jnp.bfloat16),
+           normal((2, 96, 32, 128), jnp.bfloat16)), decode_plain, 3e-2)
+
     # a chunk's expert layer through the grouped-product kernel (1,024
     # tokens x 4 of 64 experts, 8 held: 4,096 pair rows of which an
     # eighth is held, a row tile of 128), against the same layer with the

@@ -262,6 +262,210 @@ def paged_prefill_attn(q, k_pool_l, v_pool_l, table, pos0):
       v_pool_l.reshape(nb, bs, n_kv * hd))
 
 
+# -- latent decode: a row's whole context in one program ---------------------
+
+def _token_rows(ref, rope: int):
+    """The packed rows of roped keys in `ref` (S // 2, 2 * rope), tokens
+    2r and 2r + 1 side by side, as a row a token (S, 2 * rope) in slot
+    order: a token's key in the first `rope` values, its neighbour's
+    after it (which the queries meet with zeros). Row 2r is row r, row
+    2r + 1 row r with its halves exchanged. Four-byte values interleave
+    through two strided stores; bfloat16 in the 32-bit words fast memory
+    keeps two rows in, row 2r the low half and row 2r + 1 the high one,
+    each half a float32's upper bits."""
+    half, w = ref.shape
+    if ref.dtype.itemsize == 4:
+        def interleave(rows):
+            rows[pl.ds(0, half, stride=2), :] = ref[...]
+            rows[pl.ds(1, half, stride=2), :] = pltpu.roll(ref[...], rope, 1)
+            return rows[...]
+
+        return pl.run_scoped(interleave, pltpu.VMEM((2 * half, w), ref.dtype))
+    bits = jax.lax.bitcast_convert_type(
+        ref[...].astype(jnp.float32), jnp.uint32)
+    return pltpu.bitcast((bits >> 16) | pltpu.roll(bits, rope, 1), ref.dtype)
+
+
+def _latent_decode_kernel(scale: float, bs: int, nb: int, mb: int,
+                          meta_ref, tab_ref, pos_ref, q_ref, k_hbm, i_hbm,
+                          o_ref, k_buf, i_buf, sem, turn, m_scr, l_scr,
+                          acc_scr):
+    """One row of the bucket, its whole context: `nb` blocks a step,
+    copied through the row's table from the pools in HBM into one of two
+    buffers while the step before is attended. A row's first step is
+    started by the row before it, so the copies run ahead across rows;
+    `turn` holds the buffer the next step waits on. The carry (m, l, acc)
+    stays in fast memory for the row and o_ref is written once, at its
+    end. Rows past the live ones copy nothing and write zeros. q_ref (1,
+    H, rank + 2 rope) = (q_lat | q_pe | 0): `_token_rows` says why."""
+    b = pl.program_id(0)
+    li, n_live = meta_ref[0], meta_ref[1]
+    rank = k_buf.shape[-1]
+    half, step = bs // 2, nb * bs
+
+    def copies(blk, k, buf):
+        """Pool block `blk` to place k of buffer `buf`: its latents and
+        its packed roped keys, both on the buffer's semaphore."""
+        return (pltpu.make_async_copy(
+            k_hbm.at[li, blk],
+            k_buf.at[buf, pl.ds(pl.multiple_of(k * bs, bs), bs)],
+            sem.at[buf]),
+                pltpu.make_async_copy(
+            i_hbm.at[li, blk],
+            i_buf.at[buf, pl.ds(pl.multiple_of(k * half, half), half)],
+            sem.at[buf]))
+
+    def start(row, j, buf):
+        """Step j of `row` on its way: block k of it through the table,
+        the index held at the row's last live block (a step's tail past
+        it reads that block again, under the mask). A loop, not `nb`
+        copies written out: those read 8 % faster alone on the chip (0.66
+        against 0.73 ms a layer of the DeepSeek-V2 cell) and cost every
+        run's set-up 10 s, the kernel being traced and lowered for each
+        kind of layer of each decode bucket (PERF.md, PR 43)."""
+        last = pos_ref[row] // bs
+
+        def one(k, _):
+            blk = tab_ref[row * mb + jnp.minimum(j * nb + k, last)]
+            for c in copies(blk, k, buf):
+                c.start()
+
+        jax.lax.fori_loop(0, nb, one, None)
+
+    def wait(buf):
+        def one(k, _):
+            for c in copies(0, k, buf):
+                c.wait()
+
+        jax.lax.fori_loop(0, nb, one, None)
+
+    @pl.when((b == 0) & (n_live > 0))
+    def _first():
+        turn[0] = 0
+        start(0, 0, 0)
+
+    @pl.when(b < n_live)
+    def _row():
+        pos = pos_ref[b]
+        n_j = pos // step + 1
+        m_scr[...] = jnp.full_like(m_scr, -1e30)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        q_lat, q_pe = q_ref[0, :, :rank], q_ref[0, :, rank:]
+        slot = jax.lax.broadcasted_iota(jnp.int32, (1, step), 1)
+
+        def dot(x, y, dims):
+            return jax.lax.dot_general(x, y, (dims, ((), ())),
+                                       preferred_element_type=jnp.float32)
+
+        def attend(j, _):
+            buf = turn[0]
+            more = j + 1 < n_j
+
+            @pl.when(more | (b + 1 < n_live))
+            def _ahead():
+                start(jnp.where(more, b, b + 1), jnp.where(more, j + 1, 0),
+                      1 - buf)
+
+            wait(buf)
+            lat = k_buf[buf].reshape(step, rank)
+            pe = _token_rows(i_buf.at[buf], q_pe.shape[1] // 2)
+            ok = j * step + slot <= pos
+            s = jnp.where(ok, (dot(q_lat, lat, ((1,), (1,)))
+                               + dot(q_pe, pe, ((1,), (1,)))) * scale, -1e30)
+            m = m_scr[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            old = jnp.exp(m - m_new)
+            l_scr[...] = l_scr[...] * old + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * old + dot(
+                p.astype(lat.dtype), lat, ((1,), (0,)))
+            m_scr[...] = m_new
+            turn[0] = 1 - buf
+
+        jax.lax.fori_loop(0, n_j, attend, None)
+        o_ref[0] = acc_scr[...] / l_scr[...]
+
+    @pl.when(b >= n_live)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def latent_decode_attn(q, k_pool, i_pool, li, tables, pos, n_live, *,
+                       scale: float, step: int, interpret=None):
+    """Layer `li`'s decode attention of the latent family
+    (llm/latent_moe.py) in the latent, one program a row of the bucket.
+
+    q (B, H, rank + rope): the step's absorbed queries; k_pool (layers,
+    blocks, block_size, 1, rank): the latents, and i_pool (layers, blocks,
+    block_size // 2, 2 * rope): the roped keys, two tokens a row, both
+    whole and already holding the step's writes (`li` is traced: a
+    ``k_pool[li]`` in front of the call would slice a layer out);
+    tables (B, max_blocks) int32; pos (B,) int32; n_live () int32, the
+    real rows (the first ones). Row b attends its slots ``<= pos[b]`` in
+    steps of `step` slots (whole blocks). Returns the heads' sums of
+    latents (B, H, rank) f32, zeros for the rows past `n_live`.
+
+    The table, the positions, `li` and `n_live` ride scalar prefetch and
+    the pools stay in HBM: the kernel copies a step's blocks itself, two
+    buffers deep, so no gathered copy of the context exists, a step past a
+    row's context or a row past the live ones moves nothing, and the
+    online-softmax carry never leaves fast memory (no merge across rows).
+    Scores, softmax and sums in float32, the probabilities cast to the
+    pool's type for the sums, `scale` applied to the float32 scores:
+    `latent_moe.attend_latent`'s arithmetic, a row's steps added in order.
+    """
+    b, nh, qw = q.shape
+    nl, nblk, bs, _, rank = k_pool.shape
+    w = i_pool.shape[3]
+    rope = qw - rank
+    if i_pool.shape[2] * 2 != bs or w != 2 * rope or step % bs:
+        raise ValueError(
+            f"latent_decode_attn: roped keys {i_pool.shape} are not two "
+            f"tokens a row of blocks of {bs} slots, or a step of {step} "
+            f"slots is not whole blocks")
+    mb = tables.shape[1]
+    # each pool is handed over as XLA:TPU lays it out: 16-bit latents with
+    # their unit axis set aside (the view without it is the same bytes),
+    # 32-bit ones a row a tile (that view would copy the pool)
+    unit = (1,) * (k_pool.dtype.itemsize == 4)
+    # a token's row of roped keys holds its neighbour's after its own
+    q3 = jnp.pad(q, ((0, 0), (0, 0), (0, rope)))
+    kern = functools.partial(_latent_decode_kernel, float(scale), bs,
+                             step // bs, mb)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, nh, rank + w), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, nh, rank), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, step) + unit + (rank,), k_pool.dtype),
+            pltpu.VMEM((2, step // 2, w), i_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),             # the buffer in turn
+            pltpu.VMEM((nh, 1), jnp.float32),        # m
+            pltpu.VMEM((nh, 1), jnp.float32),        # l
+            pltpu.VMEM((nh, rank), jnp.float32),     # acc
+        ],
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, nh, rank), jnp.float32),
+        name="latent_decode_attn",
+        # a row's first copies are started by the row before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret() if interpret is None else interpret,
+    )(jnp.stack([li, n_live]).astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32), q3,
+      k_pool.reshape((nl, nblk, bs) + unit + (rank,)), i_pool)
+
+
 # -- full layer-stack twins (jitted by llm_exec) -----------------------------
 
 def paged_flash_decode_step(params, cur, tables, pos, k_pool, v_pool,

@@ -779,8 +779,10 @@ class LatentMoESet(ChunkOnlySet):
                    f"pools along their head axis, and a latent row has "
                    f"none (ROADMAP B4)")
         elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: it has no Pallas walk in the "
-                   "latent yet (ROADMAP B4); set paged_kernel=xla")
+            why = ("paged_kernel=pallas: that names the dense family's "
+                   "twin programs; this family's decode walk takes its "
+                   "kernel from the backend and the pools' widths alone "
+                   "(`latent_moe.fused_decode`); set paged_kernel=xla")
         elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
             why = ("a W8A8 store version: its absorbed products and its "
                    "grouped expert products are float only")
@@ -806,27 +808,20 @@ class LatentMoESet(ChunkOnlySet):
                 f"llm {self.name}: the latent_moe family cannot be served "
                 f"with {why}")
         self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
-        # `note_decode` counts this family's walk: every layer attends each
-        # live row's whole context in the latent, in whole iterations of T
-        # chunks of C slots (`latent_moe.walk_plan`), every row of the
-        # bucket in it
-        from nnstreamer_tpu.llm.latent_moe import walk_slots
-
-        self._walk_slots = lambda pos, bs, n_kv, hd, mb: walk_slots(
-            pos, bs, mb)
         # the pool's row: one latent a token and, beside it, its roped key
         self.n_kv, self.head_dim = 1, int(spec.kv_rank)
         self.idx_dim = int(spec.rope_dim)
         self.layers = layers
         self.expert_layers = layers - spec.dense_layers
         # kept tracer on or off. Decode steps: live context the steps
-        # attended, pool slots a layer gathered for it (whole iterations
-        # of the walk, padding rows included; a slot is kv_rank + rope_dim
-        # values); (layer, step) pairs and the distinct held experts that
-        # got a token in them. Every call: (token, expert) pairs of real
-        # tokens routed to held experts and away. Chunks: context tiles a
-        # layer's walk covered, and the context tokens a layer put through
-        # Wkvb (the expanded form's; 0 for an absorbed chunk); tokens at
+        # attended, pool slots a layer read for it (`note_decode` says
+        # which under each walk; a slot is kv_rank + rope_dim values), the
+        # steps by their walk (`latent_moe.fused_decode`); (layer, step)
+        # pairs and the distinct held experts that got a token in them.
+        # Every call: (token, expert) pairs of real tokens routed to held
+        # experts and away. Chunks: context tiles a layer's walk covered,
+        # and the context tokens a layer put through Wkvb (the expanded
+        # form's; 0 for an absorbed chunk); tokens at
         # the busiest held expert, summed over the chunks whose counts
         # have been read back (expert_load_chunks); the (row tile,
         # expert) visits one of a chunk's grouped products made over its
@@ -834,6 +829,7 @@ class LatentMoESet(ChunkOnlySet):
         # fused tile update, (block of queries, tile) pairs over all
         # layers, by what `pallas_ops.block_reach` has each do
         self.counters.update(dict.fromkeys((
+            "decode_steps_fused", "decode_steps_plain",
             "latents_expanded", "chunk_tiles_attended", "expert_pairs_held",
             "expert_pairs_away", "expert_steps_layers",
             "experts_touched_sum", "expert_load_max_sum",
@@ -880,6 +876,30 @@ class LatentMoESet(ChunkOnlySet):
     def split(self, out: tuple) -> tuple:
         logits, load, *pools = out
         return logits, (load,), pools
+
+    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
+        """Every layer attends each live row's whole context in the
+        latent (kv_tokens). kv_slots, what a layer reads of the pool for
+        it: under the fused walk the live rows' blocks up to whole steps
+        of the kernel, which copies nothing for a padding row
+        (`latent_moe.fused_slots`); under the plain walk whole iterations
+        of T chunks of C slots, every row of the bucket in them
+        (`latent_moe.walk_slots`). `attend` says which, by the program's
+        own rule."""
+        from nnstreamer_tpu.llm import latent_moe
+
+        fused = latent_moe.fused_decode(self.block_size, self.spec,
+                                        self.kw["dtype"])
+        slots = latent_moe.fused_slots(pos_a, n) if fused \
+            else latent_moe.walk_slots(pos_a, self.block_size,
+                                       self.max_blocks)
+        tokens = int(pos_a[:n].sum()) + n
+        self.counters["kv_tokens_attended"] += tokens
+        self.counters["kv_slots_read"] += slots
+        self.counters["decode_steps_fused" if fused
+                      else "decode_steps_plain"] += 1
+        return {"kv_tokens": tokens, "kv_slots": slots,
+                "attend": "fused" if fused else "plain"}
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a layer's walk covers (the program's own

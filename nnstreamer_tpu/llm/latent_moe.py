@@ -6,8 +6,9 @@ one cache in two forms (pure jax, jitted by llm_exec as
 What is new in this family lives here: the projections through the two
 ranks, YaRN's rope, the two forms of the attention and the rule that picks
 one. The norms (`rmsnorm`), the products (`_proj`), the dense SwiGLU
-(`_mlp_paged`) and the decode step's work list (`_live_items`) are the
-dense family's functions; the expert layer (`sparse_moe._expert_layer`,
+(`_mlp_paged`) and the plain decode walk's work list (`_live_items`) are
+the dense family's functions; the fused decode walk's kernel is
+`pallas_paged.latent_decode_attn`; the expert layer (`sparse_moe._expert_layer`,
 whose router chooses inside groups under this family's spec), the chunk's
 plain tile update (`sparse_moe.attend_plain`) and the writes of whole
 blocks the sparse-expert family's; the walk's bounds (`tile_span`) and its
@@ -50,9 +51,13 @@ The two forms, over the same cache.
   ``q_nope . k_nope = (q_nope W_UK^T) . c`` and a head's output is
   ``(sum_s p_s c_s) W_UV``, so a query attends in the latent: 2 H (2
   kv_rank + rope_dim) operations a (query, key), nothing expanded, every
-  head reading the same row. The decode step always (one query a row: a
-  work list of live chunks as the dense family's, merged by an online
-  softmax a row and head), and a chunk of few queries.
+  head reading the same row. The decode step always (one query a row),
+  and a chunk of few queries. The step walks a row's context in one of
+  two ways, which `fused_decode` picks from the backend and the pools'
+  widths alone: one kernel a layer whose programs are the rows, a row's
+  carry kept on the chip and the pools read in place through the table;
+  or a work list of live chunks as the dense family's, the items' sums
+  merged into every row's carry an iteration (`attend_latent`).
 - *Expanded* (`attend_tiles` with ``expanded``): a context tile's latents
   go through ``Wkvb`` once for all the chunk's queries, 2 kv_rank H
   (nope_dim + v_dim) a key, and a pair then costs 2 H (nope_dim + rope_dim
@@ -75,8 +80,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nnstreamer_tpu.backends import pallas_ops
+from nnstreamer_tpu.backends import pallas_ops, pallas_paged
 from nnstreamer_tpu.llm import sparse_moe
+from nnstreamer_tpu.llm.paged_cache import idx_pack
 from nnstreamer_tpu.llm.paged_model import _live_items, _mlp_paged, _proj
 from nnstreamer_tpu.llm.spec import LMSpec
 from nnstreamer_tpu.llm.window_moe import (
@@ -85,12 +91,18 @@ from nnstreamer_tpu.models.transformer import rmsnorm
 
 _F32 = jnp.float32
 
-# The decode walk's extents: slots a chunk of the work list holds (whole
-# blocks) and chunks an iteration gathers and attends. All heads read one
-# row a slot, so a chunk is a (heads, kv_rank + rope_dim) x (slots) product
-# and wants many slots; a row wastes half a chunk of masked slots.
+# The plain decode walk's extents: slots a chunk of the work list holds
+# (whole blocks) and chunks an iteration gathers and attends. All heads read
+# one row a slot, so a chunk is a (heads, kv_rank + rope_dim) x (slots)
+# product and wants many slots; a row wastes half a chunk of masked slots.
 _DECODE_CHUNK = 512
 _DECODE_ITEMS = 16
+# Slots a step of the fused walk's kernel copies and attends (whole blocks).
+# One layer alone on the v5e, 31 rows of a bucket of 32 at 6.5 k positions
+# each (PERF.md, PR 43): 0.97 ms at 256, 0.77 at 512, 0.66 at 1,024, 0.67 at
+# 2,048 (the plain walk 1.36): a step's fixed cost outweighs the half step a
+# row wastes, up to 1,024.
+_DECODE_STEP = 1024
 
 
 # -- YaRN -----------------------------------------------------------------------
@@ -238,6 +250,28 @@ def walk_slots(pos, block_size: int, max_blocks: int) -> int:
     return -(-items // t) * t * c
 
 
+def fused_decode(block_size: int, spec: LMSpec, dtype) -> bool:
+    """Whether the decode step walks a row's context in one kernel a
+    layer (`pallas_paged.latent_decode_attn`) or by the work list
+    (`attend_latent`): from the backend and the pools' widths alone. The
+    kernel takes the latent and a packed row of two roped keys as whole
+    lane tiles, whole blocks a step whose packed rows fill a 16-bit tile,
+    and a pool of bfloat16 or float32."""
+    return (jax.default_backend() == "tpu" and spec.kv_rank % 128 == 0
+            and idx_pack(block_size, spec.rope_dim) == 2
+            and (2 * spec.rope_dim) % 128 == 0 and block_size % 32 == 0
+            and _DECODE_STEP % block_size == 0
+            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
+
+
+def fused_slots(pos, n: int) -> int:
+    """Pool slots one layer of a decode step copies under the fused walk,
+    for the bucket's positions `pos` of which the first `n` are live: each
+    live row's context in whole steps of `_DECODE_STEP` slots, nothing
+    for the padding rows. Host arithmetic, the kernel's own trip counts."""
+    return sum(int(p) // _DECODE_STEP + 1 for p in pos[:n]) * _DECODE_STEP
+
+
 def attend_latent(q, k_pool, i_pool, li, items, t, scale: float):
     """Layer `li`'s attention in the latent of the absorbed queries q (B,
     H, kv_rank + rope_dim) over each row's work list `items`
@@ -290,17 +324,27 @@ def attend_latent(q, k_pool, i_pool, li, items, t, scale: float):
 
 
 @functools.partial(jax.jit, static_argnames=("dense", "t", "spec", "dtype"))
-def _decode_layer(blk, x, li, pos, live, write_blk, write_off, items,
+def _decode_layer(blk, x, li, pos, live, write_blk, write_off, walk,
                   k_pool, i_pool, *, dense, t, spec, dtype):
     """Layer `li` of a decode step (jitted with `li` an argument, so a
-    step traces a layer of each shape once; XLA inlines the calls)."""
+    step traces a layer of each shape once; XLA inlines the calls).
+    `walk`: the plain walk's work list, `t` (static) items an iteration;
+    under the fused walk (`t` 0) the tables and the live rows' number."""
     q_nope, q_pe, c, k_pe = _project(blk, x, pos, spec, dtype)
     k_pool = k_pool.at[li, write_blk, write_off].set(
         c[:, None, :].astype(k_pool.dtype))
     i_pool = sparse_moe._idx_write(i_pool, li, write_blk, write_off, k_pe)
     w = _wkvb(blk, spec, dtype)
-    o_lat = attend_latent(absorbed_queries(q_nope, q_pe, w, spec), k_pool,
-                          i_pool, li, items, t, score_scale(spec))
+    q = absorbed_queries(q_nope, q_pe, w, spec)
+    if t:
+        o_lat = attend_latent(q, k_pool, i_pool, li, walk, t,
+                              score_scale(spec))
+    else:
+        # the pools go in whole: `li` is the kernel's to index with
+        tables, n_live = walk
+        o_lat = pallas_paged.latent_decode_attn(
+            q, k_pool, i_pool, li, tables, pos, n_live,
+            scale=score_scale(spec), step=_DECODE_STEP)
     o = _absorbed_out(o_lat, w, spec, dtype)
     x = x + _proj(blk, "wo", o[:, None, :], dtype)
     x, load = _mlp(blk, x, live, dense, spec, dtype)
@@ -316,17 +360,20 @@ def latent_moe_decode_step(params, cur, tables, pos, n_live, k_pool, i_pool,
     i_pool)."""
     b = cur.shape[0]
     bs = k_pool.shape[2]
-    nb_c, n_chunks, t = walk_plan(bs, b, tables.shape[1])
     write_blk = tables[jnp.arange(b), pos // bs]
     write_off = pos % bs
     live = jnp.arange(b) < n_live
-    # one work list a step, shared by every layer
-    items = _live_items(tables, pos, bs, nb_c, n_chunks, t)
+    if fused_decode(bs, spec, k_pool.dtype):
+        walk, t = (tables, n_live), 0
+    else:
+        # one work list a step, shared by every layer
+        nb_c, n_chunks, t = walk_plan(bs, b, tables.shape[1])
+        walk = _live_items(tables, pos, bs, nb_c, n_chunks, t)
     x = params["embed"][cur][:, None, :].astype(dtype)
     load = []
     for li, blk in enumerate(params["blocks"]):
         x, counts, k_pool, i_pool = _decode_layer(
-            blk, x, li, pos, live, write_blk, write_off, items, k_pool,
+            blk, x, li, pos, live, write_blk, write_off, walk, k_pool,
             i_pool, dense=li < spec.dense_layers, t=t, spec=spec,
             dtype=dtype)
         if counts is not None:

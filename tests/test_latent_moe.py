@@ -22,13 +22,14 @@ import tiny_hybrid                                              # noqa: E402
 import tiny_latent_moe as tiny                                  # noqa: E402
 import tiny_sparse_moe                                          # noqa: E402
 import tiny_window_moe                                          # noqa: E402
-from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
+from nnstreamer_tpu.backends import pallas_ops, pallas_paged    # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
 from nnstreamer_tpu.llm import families, latent_moe, sparse_moe  # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.paged_cache import PagedKVCache         # noqa: E402
+from nnstreamer_tpu.llm.paged_model import _live_items          # noqa: E402
 from nnstreamer_tpu.llm.spec import LMSpec                      # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import latent_moe_lm as ref           # noqa: E402
@@ -611,9 +612,10 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     chunks = [e[6] for e in tracer.events() if e[3] == "invoke"
               and e[6].get("what") == "llm_prefill_chunk"]
     assert decode and chunks
-    for key in ("rows", "kv_tokens", "kv_slots", "kv_pool_itemsize",
+    for key in ("rows", "kv_tokens", "kv_slots", "kv_pool_itemsize", "attend",
                 "experts_touched", "expert_pairs_held", "expert_pairs_away"):
         assert key in decode[-1], key
+    assert decode[-1]["attend"] == "plain"          # the CPU's walk
     for key in ("pos0", "clen", "ctx_tiles", "attend", "latents_expanded",
                 *families.QBLOCK_KINDS):
         assert key in chunks[-1], key
@@ -628,6 +630,218 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
         == last["rows"] * 4 * 2                # 4 a token, 2 expert layers
     assert last["kv_slots"] % (BS * latent_moe.walk_plan(
         BS, 2, 16)[0]) == 0
+
+
+# -- the decode walk as one kernel a row ------------------------------------------
+
+STEP = 8            # slots a step of the kernel here: two blocks of 4
+
+# (bucket, the live rows' positions, blocks taken in order)
+WALKS = {
+    "a-row-at-position-0": (1, [0], False),
+    "a-context-that-ends-on-a-steps-last-slot": (2, [STEP - 1, 3 * STEP - 1],
+                                                 False),
+    "a-context-one-past-a-steps-last-slot": (2, [STEP, 3 * STEP], False),
+    "a-bucket-of-32-with-5-live-rows": (32, [3, 40, 23, 8, 31], False),
+    "a-bucket-of-1": (1, [37], False),
+    "tables-in-order": (4, [0, 15, 16, 45], True),
+}
+
+
+def _walk_case(dtype, b, pos, in_order, seed=0, rank=16, rope=4, nh=4,
+               nblk=80, mb=12, layers=2):
+    """Pools of random values, a table a live row (distinct blocks, out of
+    order unless `in_order`; the padding rows' the scratch block) and the
+    absorbed queries of a bucket of `b`."""
+    rng = np.random.default_rng(seed)
+    k_pool = jnp.asarray(rng.standard_normal((layers, nblk, BS, 1, rank)),
+                         dtype)
+    i_pool = jnp.asarray(
+        rng.standard_normal((layers, nblk, BS // 2, 2 * rope)), dtype)
+    q = jnp.asarray(rng.standard_normal((b, nh, rank + rope)), dtype)
+    free = np.arange(1, nblk) if in_order else rng.permutation(
+        np.arange(1, nblk))
+    tables, pos_a, at = np.zeros((b, mb), np.int32), np.zeros((b,), np.int32), 0
+    pos_a[:len(pos)] = pos
+    for r, p in enumerate(pos):
+        n = p // BS + 1
+        tables[r, :n], at = free[at:at + n], at + n
+    return q, k_pool, i_pool, jnp.asarray(tables), jnp.asarray(pos_a)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 6e-3)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_fused_walks_kernel_agrees_with_the_plain_walk(case, dtype, tol):
+    """`pallas_paged.latent_decode_attn`, interpreted, against
+    `attend_latent` on the same pools and tables: float32 to rounding
+    (only the order of a row's sums differs), bfloat16 within the
+    probabilities' rounding to it (the plain walk rounds them against a
+    chunk's own maximum, the kernel against the running one)."""
+    b, pos, in_order = WALKS[case]
+    q, k_pool, i_pool, tables, pos_a = _walk_case(dtype, b, pos, in_order,
+                                                  seed=len(case))
+    n, li, scale = len(pos), 1, 0.3
+    got = pallas_paged.latent_decode_attn(
+        q, k_pool, i_pool, jnp.int32(li), tables, pos_a, jnp.int32(n),
+        scale=scale, step=STEP, interpret=True)
+    nb_c, n_chunks, t = latent_moe.walk_plan(BS, b, tables.shape[1])
+    items = _live_items(tables, pos_a, BS, nb_c, n_chunks, t)
+    want = latent_moe.attend_latent(q, k_pool, i_pool, li, items, t, scale)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    assert np.abs(np.asarray(got)[:n] - np.asarray(want)[:n]).max() < tol
+    # a padding row copies nothing and writes zeros
+    assert not np.asarray(got)[n:].any()
+
+
+def test_the_kernel_reads_the_layer_and_the_blocks_it_is_told():
+    """Another layer's rows, or a block the table does not name, change
+    nothing; the row's own last block does."""
+    q, k_pool, i_pool, tables, pos_a = _walk_case(jnp.float32, 2, [9, 21],
+                                                  False)
+
+    def run(k, i):
+        return np.asarray(pallas_paged.latent_decode_attn(
+            q, k, i, jnp.int32(1), tables, pos_a, jnp.int32(2), scale=0.3,
+            step=STEP, interpret=True))
+
+    want = run(k_pool, i_pool)
+    named = set(np.asarray(tables)[0, :3]) | set(np.asarray(tables)[1, :6])
+    other = next(b for b in range(1, 80) if b not in named)
+    assert (run(k_pool.at[0].set(7.0).at[1, other].set(7.0),
+                i_pool.at[0].set(7.0).at[1, other].set(7.0)) == want).all()
+    last = int(np.asarray(tables)[1, 5])
+    moved = run(k_pool.at[1, last, 1].set(7.0), i_pool)
+    assert (moved[0] == want[0]).all() and (moved[1] != want[1]).any()
+    # a slot past the row's position is not attended: 21 is slot 1 of `last`
+    assert (run(k_pool.at[1, last, 2:].set(7.0),
+                i_pool.at[1, last, 1:].set(7.0)) == want).all()
+    with pytest.raises(ValueError, match="two tokens a row"):
+        pallas_paged.latent_decode_attn(
+            q, k_pool, i_pool.reshape(2, 80, 1, 16), jnp.int32(1), tables,
+            pos_a, jnp.int32(2), scale=0.3, step=STEP, interpret=True)
+
+
+def _pairs_cfg():
+    """The tiny configuration with a roped key of 64: two a packed row of
+    128, the layout the kernel reads."""
+    return dict(CFG, qk_rope_head_dim=64)
+
+
+@pytest.fixture
+def fused_walk(monkeypatch):
+    """The rule forced (the backend here is the CPU, where the kernel is
+    interpreted) and a step of two blocks."""
+    monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
+    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+
+
+def test_a_decode_steps_logits_through_both_walks(monkeypatch):
+    cfg = _pairs_cfg()
+    spec = lm_spec(cfg)
+    params = ref.make_params(cfg, SEED, dtype=jnp.float32)
+    ex = PagedLLMExecutor(ModelBundle(fn=None, params=params, lm=spec),
+                          dtype=jnp.float32, state_slots=4,
+                          prefill_chunk=CHUNK, **POOL)
+    assert ex.cache.pools()[1].shape[2:] == (BS // 2, 128)
+    cache, tables, pos = ex.cache, np.zeros((4, 16), np.int32), [21, 9, 16]
+    for r, p in enumerate(pos):
+        blocks, _ = cache.reserve(cache.blocks_for(p + 1))
+        ids = _prompt(p, seed=r)
+        for at in range(0, p, CHUNK):
+            ex.prefill_chunk(ids[at:at + CHUNK], at, blocks, bucket=CHUNK)
+        tables[r, :len(blocks)] = blocks
+    args = (jax.tree.map(jnp.asarray, params), jnp.asarray([5, 6, 7, 0]),
+            jnp.asarray(tables), jnp.asarray(pos + [0], jnp.int32),
+            jnp.int32(3), *cache.pools())
+    kw = dict(spec=spec, dtype=jnp.float32)
+    want = latent_moe.latent_moe_decode_step(*args, **kw)
+    monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
+    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+    calls = []
+    monkeypatch.setattr(
+        pallas_paged, "latent_decode_attn",
+        lambda *a, _f=pallas_paged.latent_decode_attn, **k: (
+            calls.append(k["step"]), _f(*a, **k))[1])
+    got = latent_moe.latent_moe_decode_step(*args, **kw)
+    assert calls == [STEP] * 2          # a trace a kind of layer
+    assert np.abs(np.asarray(got[0])[:3] - np.asarray(want[0])[:3]).max() \
+        < TOL
+    assert (np.asarray(got[0])[:3].argmax(-1)
+            == np.asarray(want[0])[:3].argmax(-1)).all()
+    # the expert layers' counts are the same, both pools' writes the
+    # first layer's to the bit and the later ones' to rounding (but the
+    # padding row's, which go to the scratch block)
+    assert (np.asarray(got[1]) == np.asarray(want[1])).all()
+    for g, w in zip(got[2:], want[2:]):
+        assert (np.asarray(g[0]) == np.asarray(w[0])).all()
+        assert np.abs(np.asarray(g[:, 1:]) - np.asarray(w[:, 1:])).max() \
+            < TOL
+
+
+def test_the_engine_serves_the_same_tokens_through_the_fused_walk(
+        fused_walk):
+    cfg = _pairs_cfg()
+    params = ref.make_params(cfg, SEED, dtype=jnp.float32)
+    tracer = Tracer()
+    eng = LLMEngine(ModelBundle(fn=None, params=params, lm=lm_spec(cfg)),
+                    dtype=jnp.float32, max_batch=4, prefill_chunk=CHUNK,
+                    tracer=tracer, **POOL)
+    prompts = [_prompt(p, seed=i) for i, p in enumerate((20, 33, 9))]
+    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    eng.drain()
+    for p, r in zip(prompts, reqs):
+        ids = np.concatenate([p, np.asarray(r.tokens[:-1], np.int32)])
+        want = np.asarray(ref.forward_logits(params, cfg, ids))[len(p) - 1:]
+        assert list(want.argmax(-1)) == list(r.tokens)
+    st = eng.executor.stats()
+    assert st["decode_steps_fused"] == st["decode_steps"] > 0
+    assert st["decode_steps_plain"] == 0
+    decode = [e[6] for e in tracer.events() if e[3] == "invoke"
+              and e[6].get("what") == "llm_decode"]
+    assert decode and all(d["attend"] == "fused" for d in decode)
+    assert all(d["kv_slots"] % STEP == 0 for d in decode)
+
+
+def test_the_rule_reads_the_backend_and_the_pools_widths(monkeypatch):
+    bf = jnp.bfloat16
+    assert not latent_moe.fused_decode(64, PUBLISHED, bf)     # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert latent_moe.fused_decode(64, PUBLISHED, bf)
+    assert latent_moe.fused_decode(64, PUBLISHED, jnp.float32)
+    assert latent_moe.fused_decode(32, PUBLISHED, bf)
+    assert not latent_moe.fused_decode(64, PUBLISHED, jnp.float16)
+    # packed rows of a block that fill no 16-bit tile; a step no whole blocks
+    assert not latent_moe.fused_decode(16, PUBLISHED, bf)
+    assert not latent_moe.fused_decode(96, PUBLISHED, bf)
+    for narrow in (dict(kv_rank=192), dict(rope_dim=32), dict(rope_dim=128)):
+        assert not latent_moe.fused_decode(
+            64, dataclasses.replace(PUBLISHED, **narrow), bf)
+    assert not latent_moe.fused_decode(BS, SPEC, jnp.float32)   # the tiny one
+
+
+def test_each_walk_counts_the_slots_it_moves(bundle, monkeypatch):
+    """`fused_slots`: each live row's context in whole steps, nothing for
+    a padding row, which is what the kernel's trip counts copy (a step is
+    `_DECODE_STEP` slots: `n_j` of `_latent_decode_kernel`); `walk_slots`
+    whole iterations of the work list. `note_decode` picks by the rule."""
+    pos = np.asarray([0, 1023, 1024, 7000, 0, 0, 0, 0])
+    assert latent_moe.fused_slots(pos, 4) == (1 + 1 + 2 + 7) * 1024
+    assert latent_moe.fused_slots(pos, 1) == 1024
+    ps = _executor(bundle).programs
+    plain = latent_moe.walk_slots([9, 20, 0, 0], BS, 16)
+    assert plain == 256             # one iteration of 16 chunks of 16 slots
+    said = ps.note_decode(np.asarray([9, 20, 0, 0]), 2)
+    assert said == {"kv_tokens": 31, "attend": "plain", "kv_slots": plain}
+    monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
+    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+    said = ps.note_decode(np.asarray([9, 20, 0, 0]), 2)
+    assert said == {"kv_tokens": 31, "attend": "fused",
+                    "kv_slots": (2 + 3) * STEP}
+    st = ps.stats()
+    assert (st["decode_steps_plain"], st["decode_steps_fused"]) == (1, 1)
+    assert st["kv_slots_read"] == (2 + 3) * STEP + plain
 
 
 # -- what the family refuses, and the module's classes ------------------------------

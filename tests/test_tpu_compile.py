@@ -403,3 +403,103 @@ def test_the_latent_cells_grouped_products_fit_fast_memory(one_chip,
         blk, arg((2048, d), dt), arg((2048,), jnp.bool_)
     ).compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+
+
+def _dsv2():
+    """(configuration, spec) of the DeepSeek-V2 cell."""
+    from perfbench.runners.latent_moe_llm import lm_spec as latent_spec
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "deepseek-v2-6l.json")) as f:
+        cfg = json.load(f)
+    return cfg, latent_spec(cfg)
+
+
+@pytest.mark.parametrize("dt,b,nblk", [
+    (jnp.bfloat16, 32, 13438), (jnp.bfloat16, 1, 13438),
+    (jnp.float32, 4, 1200)], ids=["bucket-32", "bucket-1", "float32"])
+def test_the_latent_decode_walks_kernel_compiles_at_the_cells_sizes(
+        one_chip, dt, b, nblk):
+    """`pallas_paged.latent_decode_attn` at the DeepSeek-V2 cell's sizes
+    (deepseek-v2-6l: 128 heads over latents of 512 and roped keys of 64, a
+    table of 288 blocks of 64, a pool of 13,438 blocks, 6 layers, steps of
+    1,024 slots): Mosaic takes it, both pools go in as they lie (a float32
+    pool, the check's against the reference, keeps its unit axis: the view
+    without it would copy the pool) and nothing the kernel needs is made
+    outside it but the queries filled up to the packed row's width."""
+    from nnstreamer_tpu.backends import pallas_paged
+    from nnstreamer_tpu.llm import latent_moe
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, k_pool, i_pool, li, tables, pos, n_live):
+        return pallas_paged.latent_decode_attn(
+            q, k_pool, i_pool, li, tables, pos, n_live, scale=0.1147,
+            step=latent_moe._DECODE_STEP, interpret=False)
+
+    i32 = jnp.int32
+    compiled = jax.jit(attend).lower(
+        arg((b, 128, 576), dt), arg((6, nblk, 64, 1, 512), dt),
+        arg((6, nblk, 32, 128), dt), arg((), i32), arg((b, 288), i32),
+        arg((b,), i32), arg((), i32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 1 and "latent_decode_attn" in calls[0]
+    assert not re.search(rf"\[6,{nblk},[^ ]* (copy|convert|fusion)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("b", [32, 1])
+def test_the_latent_decode_layer_reads_the_pools_where_they_lie(
+        one_chip, monkeypatch, b):
+    """One expert layer of the DeepSeek-V2 cell's decode step under the
+    fused walk: the text holds no array of a layer's pool shape (a
+    ``k_pool[li]`` in front of the kernel would be one: 880 MB a layer)
+    and none of the pools' own shape but the pools, their views without
+    the unit axis and the step's writes into them, in place."""
+    from nnstreamer_tpu.backends import pallas_paged
+    from nnstreamer_tpu.llm import latent_moe
+    from perfbench.references import latent_moe_lm
+    cfg, spec = _dsv2()
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        assert latent_moe.fused_decode(64, spec, jnp.bfloat16)
+    monkeypatch.setattr(pallas_paged, "_interpret", lambda: False)
+    mb, bs, nblk, bf, i32 = 288, 64, 13438, jnp.bfloat16, jnp.int32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = jax.eval_shape(
+        lambda: latent_moe_lm.make_params(cfg, 1, dtype=bf))["blocks"][1]
+    blk = jax.tree.map(lambda x: arg(x.shape, x.dtype), blk)
+
+    def layer(*a):
+        return latent_moe._decode_layer(*a, dense=False, t=0, spec=spec,
+                                        dtype=bf)
+
+    compiled = jax.jit(layer, donate_argnums=(8, 9)).lower(
+        blk, arg((b, 1, 5120), bf), arg((), i32), arg((b,), i32),
+        arg((b,), jnp.bool_), arg((b,), i32), arg((b,), i32),
+        (arg((b, mb), i32), arg((), i32)),
+        arg((6, nblk, bs, 1, 512), bf), arg((6, nblk, bs // 2, 128), bf)
+    ).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sum("latent_decode_attn" in ln for ln in calls) == 1
+    # the plain walk's loop is gone, whose carry was every row's sums
+    assert not any(f"f32[{b},128,512]" in ln for ln in text.splitlines()
+                   if " while(" in ln)
+    assert not re.search(rf"bf16\[(1,)?{nblk},", text)
+    made = set(re.findall(rf"= bf16\[6,{nblk},\S* ([a-z-]+)\(", text))
+    assert made <= {"parameter", "bitcast", "scatter", "fusion",
+                    "get-tuple-element", "dynamic-update-slice", "while"}, \
+        made
+    fusions = [ln for ln in text.splitlines()
+               if re.search(rf"= bf16\[6,{nblk},[^ ]* fusion\(", ln)]
+    assert all("scatter" in ln for ln in fusions)
+    # both pools are written in place: nothing a pool wide is kept beside
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
