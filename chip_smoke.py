@@ -94,7 +94,8 @@ def leg_kernels() -> dict:
     from nnstreamer_tpu.backends import pallas_paged as pp
     from nnstreamer_tpu.parallel.ring_attention import ring_attention
 
-    assert not po._interpret(), "Pallas kernels would run in interpret mode"
+    assert jax.default_backend() == "tpu", \
+        "Pallas kernels would run in interpret mode"
     rng = np.random.default_rng(0)
     errs = {}
 
@@ -132,7 +133,7 @@ def leg_kernels() -> dict:
           (xf,), lambda a: np.clip(a, -1, 1) * 2 + 0.5, 1e-6)
     for m in (16, 4):                       # 4: the zero-padded-rows path
         check(f"quantize_rows_m{m}", po.quantize_rows,
-              (normal((m, 1024), jnp.bfloat16),), po._quantize_rows_xla, 1.0)
+              (normal((m, 1024), jnp.bfloat16),), po.quantize_rows_xla, 1.0)
 
     def attn_ref(q, k, v, causal, q0=0):
         s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -171,7 +172,7 @@ def leg_kernels() -> dict:
     # geometry (8 query heads a KV head of 128), against its plain twin:
     # tile 1 of 2, a tie at every fourth slot cut at a query's own place,
     # a carry that arrives filled
-    from nnstreamer_tpu.llm import sparse_moe
+    from nnstreamer_tpu.llm import experts, parts
 
     c, tile, nkv, grp = 512, 1024, 2, 8
     keys = jnp.asarray(rng.integers(1, 9, (c, 2 * tile)), jnp.uint32) << 28
@@ -186,7 +187,7 @@ def leg_kernels() -> dict:
           (normal((c, nkv, grp, 128), jnp.bfloat16),
            normal((tile, nkv, 128), jnp.bfloat16),
            normal((tile, nkv, 128), jnp.bfloat16), *carry),
-          lambda qg, kt, vt, m, l, a: sparse_moe.attend_plain(
+          lambda qg, kt, vt, m, l, a: parts.attend_plain(
               qg, kt, vt, keys[:, tile:], t, cut, tile, (m, l, a)), 3e-2)
 
     # the hybrid chunk's walk at the SALA cell's head geometry (2 KV heads
@@ -226,7 +227,7 @@ def leg_kernels() -> dict:
     c = 512
     tab = jnp.asarray(1 + rng.permutation(95)[:4 * tile // 64], jnp.int32)
     qpos = jnp.arange(3 * tile - c, 3 * tile)
-    span = window_moe.tile_span(3 * tile - c, c, 4 * tile, tile, 1536)
+    span = parts.tile_span(3 * tile - c, c, 4 * tile, tile, 1536)
     assert span == (1, 3), span
     check("window_walk_fused",
           lambda q, kp, vp: window_moe.attend_tiles(
@@ -271,7 +272,6 @@ def leg_kernels() -> dict:
     # are live, at position 0, on a step's last slot and 3 steps deep)
     # against the plain walk's work list, layer 1 of 2
     from nnstreamer_tpu.backends import pallas_paged
-    from nnstreamer_tpu.llm.paged_model import _live_items
 
     assert latent_moe.fused_decode(64, la, jnp.bfloat16)
     dpos = jnp.asarray([0, 1023, 2100, 0], jnp.int32)
@@ -282,14 +282,14 @@ def leg_kernels() -> dict:
     walk = latent_moe.walk_plan(64, 4, 48)
 
     def decode_plain(q, kp, ip):
-        items = _live_items(dtab, dpos, 64, *walk)
+        items = parts.live_items(dtab, dpos, 64, *walk)
         return latent_moe.attend_latent(q, kp, ip, 1, items, walk[2],
                                         0.05)[:3]
 
     check("latent_decode_fused",
           lambda q, kp, ip: pallas_paged.latent_decode_attn(
               q, kp, ip, jnp.int32(1), dtab, dpos, jnp.int32(3), scale=0.05,
-              step=latent_moe._DECODE_STEP)[:3],
+              step=latent_moe.DECODE_STEP)[:3],
           (normal((4, 16, 576), jnp.bfloat16),
            normal((2, 96, 64, 1, 512), jnp.bfloat16),
            normal((2, 96, 32, 128), jnp.bfloat16)), decode_plain, 3e-2)
@@ -302,7 +302,7 @@ def leg_kernels() -> dict:
                 n_experts=64, experts_per_tok=4, expert_width=512,
                 score_fn="sigmoid", route_scale=2.448, experts_first=16,
                 experts_held=8)
-    assert sparse_moe.expert_row_tile(4096, 64) == 128
+    assert experts.expert_row_tile(4096, 64) == 128
     eblk = {"router": normal((1024, 64), jnp.bfloat16),
             "router_bias": jnp.zeros((64,), jnp.float32),
             "ewi": normal((8, 1024, 1024), jnp.bfloat16) * 0.03,
@@ -310,15 +310,14 @@ def leg_kernels() -> dict:
     live = jnp.arange(1024) < 1000
 
     def layer_by_ragged_dot(g):
-        tile, sparse_moe._XLA_ROW_TILE = sparse_moe._XLA_ROW_TILE, 1 << 30
+        tile, experts.XLA_ROW_TILE = experts.XLA_ROW_TILE, 1 << 30
         try:
-            return sparse_moe._expert_layer(eblk, g, live, ex, jnp.bfloat16)
+            return experts.expert_layer(eblk, g, live, ex, jnp.bfloat16)
         finally:
-            sparse_moe._XLA_ROW_TILE = tile
+            experts.XLA_ROW_TILE = tile
 
     check("grouped_matmul",
-          lambda g: sparse_moe._expert_layer(eblk, g, live, ex,
-                                             jnp.bfloat16),
+          lambda g: experts.expert_layer(eblk, g, live, ex, jnp.bfloat16),
           (normal((1024, 1024), jnp.bfloat16),), layer_by_ragged_dot, 3e-2)
 
     # paged kernels at the LLM legs' geometry, MHA and GQA

@@ -255,7 +255,7 @@ class PagedLLMExecutor:
                     f"chips leased: {chips}")
             self._shard_chips = chips
             self._shard_devs = shg.shard_devices(chips)
-            self._mesh = shg._tp_mesh(self._shard_devs)
+            self._mesh = shg.tp_mesh(self._shard_devs)
             # raises the typed float-only / 8-divisibility errors up
             # front, before any pool or jit exists
             placed, self._sspecs = shg.shard_llm_params(
@@ -599,7 +599,7 @@ class PagedLLMExecutor:
         `state_slot` is the sequence's slot of the state pool, where the
         family keeps a state a sequence; `window_table` its table of the
         window layers' pools, where it keeps those."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
         plen = int(prompt.shape[0])
@@ -607,13 +607,13 @@ class PagedLLMExecutor:
         if ps.prefill_kind(self.params) == "chunk":
             ps.check_prompt(plen, 0)
             return self.prefill_chunk(
-                prompt, 0, block_table, bucket=_next_pow2(plen, 8),
+                prompt, 0, block_table, bucket=next_pow2(plen, 8),
                 sync=sync, req=req, state_slot=state_slot,
                 window_table=window_table)
         kind = "prefill"
         if self.shards and 0 < self.ring_prefill_min <= plen:
             kind = "ring"    # sequence-parallel long-context cutover
-        s_b = _next_pow2(plen, 8)
+        s_b = next_pow2(plen, 8)
         bs = self.cache.block_size
         ids = np.zeros((1, s_b), np.int32)
         ids[0, :plen] = prompt
@@ -664,11 +664,11 @@ class PagedLLMExecutor:
         the final chunk's value is meaningful to sampling. A family
         with window pools writes the chunk through `window_table` too,
         whose entries behind the window read the scratch block."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
 
         t_in = time.perf_counter() if self.tracer.active else 0.0
         clen = int(chunk.shape[0])
-        c_b = max(int(bucket) or 0, _next_pow2(clen, 8))
+        c_b = max(int(bucket) or 0, next_pow2(clen, 8))
         bs = self.cache.block_size
         ids = np.zeros((1, c_b), np.int32)
         ids[0, :clen] = chunk
@@ -758,7 +758,7 @@ class PagedLLMExecutor:
         layers' pools, where it keeps those."""
         import jax
 
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
         from nnstreamer_tpu.llm import next_ids
 
         if self.shards and not sync:
@@ -767,7 +767,7 @@ class PagedLLMExecutor:
                 f"one chip; shards={self.shards} decodes with sync=True")
         t_in = time.perf_counter() if self.tracer.active else 0.0
         n = len(cur)
-        b_b = _next_pow2(n, 1)
+        b_b = next_pow2(n, 1)
         cur_a = np.zeros((b_b,), np.int32)
         # -1: on the device, at the first block of the row's table
         cur_a[:n] = [-1 if c is None else c for c in cur]
@@ -975,9 +975,9 @@ class PagedLLMExecutor:
     def warm_decode(self, rows: int) -> bool:
         """Build the decode bucket that holds `rows` rows, unless it is
         built. Returns whether it was built now."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
 
-        return self._warm_compile("decode", _next_pow2(rows, 1))
+        return self._warm_compile("decode", next_pow2(rows, 1))
 
     def prewarm_buckets(self, *, max_batch: int, max_prompt: int,
                         chunk: int = 0) -> int:
@@ -986,32 +986,32 @@ class PagedLLMExecutor:
         `max_prompt`, and — when the engine runs chunked prefill — the
         one chunk bucket. Start-time cost, zero hot-path compiles
         after."""
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
 
         compiled = 0
-        b, top_b = 1, _next_pow2(max(1, max_batch), 1)
+        b, top_b = 1, next_pow2(max(1, max_batch), 1)
         while b <= top_b:
             compiled += int(self._warm_compile("decode", b))
             b *= 2
         if chunk > 0:
             compiled += int(self._warm_compile(
-                "chunk", _next_pow2(chunk, 8)))
+                "chunk", next_pow2(chunk, 8)))
         if self.programs.prefill_kind(self.params) == "chunk":
             # whole-prompt prefills route through the chunk family too
-            s, top_s = 8, _next_pow2(
+            s, top_s = 8, next_pow2(
                 min(max(1, max_prompt), self.max_len), 8)
             while s <= top_s:
                 compiled += int(self._warm_compile("chunk", s))
                 s *= 2
             return compiled
-        s, top_s = 8, _next_pow2(
+        s, top_s = 8, next_pow2(
             min(max(1, max_prompt), self.max_len), 8)
         while s <= top_s:
             compiled += int(self._warm_compile("prefill", s))
             s *= 2
         if self.shards and self.ring_prefill_min > 0:
             # buckets a ring-cutover prompt can land in
-            s = _next_pow2(max(8, self.ring_prefill_min), 8)
+            s = next_pow2(max(8, self.ring_prefill_min), 8)
             while s <= top_s:
                 compiled += int(self._warm_compile("ring", s))
                 s *= 2

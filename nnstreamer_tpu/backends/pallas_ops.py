@@ -63,7 +63,7 @@ def _quantize_rows_kernel(x_ref, q_ref, s_ref):
     s_ref[...] = scale
 
 
-def _quantize_rows_xla(x):
+def quantize_rows_xla(x):
     """Plain-XLA twin of _quantize_rows_kernel — the one place the
     quantization formula lives outside the kernel, used for row counts
     the 8-row Mosaic sublane can't tile."""
@@ -89,7 +89,7 @@ def quantize_rows(x, block_rows: int = 256):
     independently (per-row scales; amax 0 → scale 1 → q 0) so they
     never touch real rows, and the kernel keeps the single-HBM-trip
     property for ragged M (decode steps, tail microbatches) instead of
-    falling back to the ~3-trip XLA path. `_quantize_rows_xla` remains
+    falling back to the ~3-trip XLA path. `quantize_rows_xla` remains
     as the formula's plain-XLA twin for reference/testing."""
     m, k = x.shape
     m_pad = (-m) % 8

@@ -1,6 +1,6 @@
 """Pallas paged attention: the flash kernels taught block tables.
 
-The serving gap this closes (ROADMAP item 2, bench round r05): the
+The serving gap this closes (bench round r05): the
 continuous-batching LLM path computed attention as plain-XLA block-table
 gathers + full einsums + ``-1e30``-mask softmax (`llm/paged_model.py`),
 materializing the whole ``(B, max_blocks*block_size, n_kv, hd)``
@@ -473,11 +473,9 @@ def paged_flash_decode_step(params, cur, tables, pos, k_pool, v_pool,
     """Drop-in twin of `paged_model.paged_decode_step` with the
     attention einsums replaced by `paged_decode_attn`. Everything else
     — rope, pool write-through, residual/MLP structure, quant-aware
-    projections — is shared with the reference via paged_model's
-    helpers, so the two paths can only diverge in the attention kernel
+    projections — is shared with the reference via `llm/parts.py`, so the two paths can only diverge in the attention kernel
     itself (the thing the parity tests pin)."""
-    from nnstreamer_tpu.llm.paged_model import (
-        _mlp_paged, _proj, _rope_rows)
+    from nnstreamer_tpu.llm.parts import mlp_paged, proj, rope_rows
     from nnstreamer_tpu.models.transformer import rmsnorm
 
     b = cur.shape[0]
@@ -490,25 +488,25 @@ def paged_flash_decode_step(params, cur, tables, pos, k_pool, v_pool,
         h = rmsnorm(x, blk["ln1"].astype(dtype))
         d = x.shape[-1]
         hd = d // n_heads
-        qkv = _proj(blk, "wqkv", h, dtype)
+        qkv = proj(blk, "wqkv", h, dtype)
         kv_dim = (qkv.shape[-1] - d) // 2
         n_kv = kv_dim // hd
         q = qkv[..., :d].reshape(b, 1, n_heads, hd)
         k = qkv[..., d:d + kv_dim].reshape(b, 1, n_kv, hd)
         v = qkv[..., d + kv_dim:].reshape(b, 1, n_kv, hd)
-        q, k = _rope_rows(q, pos), _rope_rows(k, pos)
+        q, k = rope_rows(q, pos), rope_rows(k, pos)
         k_pool = k_pool.at[li, write_blk, write_off].set(
             k[:, 0].astype(k_pool.dtype))
         v_pool = v_pool.at[li, write_blk, write_off].set(
             v[:, 0].astype(v_pool.dtype))
         attn = paged_decode_attn(q[:, 0], k_pool[li], v_pool[li],
                                  tables, pos)
-        x = x + _proj(blk, "wo", attn.reshape(b, 1, -1).astype(dtype),
+        x = x + proj(blk, "wo", attn.reshape(b, 1, -1).astype(dtype),
                       dtype)
         h = rmsnorm(x, blk["ln2"].astype(dtype))
-        x = x + _mlp_paged(blk, h, dtype)
+        x = x + mlp_paged(blk, h, dtype)
     x = rmsnorm(x, params["ln_f"].astype(dtype))
-    logits = _proj(params, "head", x[:, 0], dtype).astype(jnp.float32)
+    logits = proj(params, "head", x[:, 0], dtype).astype(jnp.float32)
     return logits, k_pool, v_pool
 
 
@@ -520,7 +518,7 @@ def paged_flash_prefill_chunk(params, ids, pos0, blk_idx, blk_off,
     writes its K/V into the pool and attends the whole prefix (earlier
     chunks included) straight through the block table, one pool block
     per DMA."""
-    from nnstreamer_tpu.llm.paged_model import _mlp_paged, _proj
+    from nnstreamer_tpu.llm.parts import mlp_paged, proj
     from nnstreamer_tpu.models.transformer import rmsnorm, rope
 
     _, c = ids.shape
@@ -530,7 +528,7 @@ def paged_flash_prefill_chunk(params, ids, pos0, blk_idx, blk_off,
         h = rmsnorm(x, blk["ln1"].astype(dtype))
         d = x.shape[-1]
         hd = d // n_heads
-        qkv = _proj(blk, "wqkv", h, dtype)
+        qkv = proj(blk, "wqkv", h, dtype)
         kv_dim = (qkv.shape[-1] - d) // 2
         n_kv = kv_dim // hd
         q = qkv[..., :d].reshape(1, c, n_heads, hd)
@@ -544,10 +542,10 @@ def paged_flash_prefill_chunk(params, ids, pos0, blk_idx, blk_off,
         attn = paged_prefill_attn(q[0].transpose(1, 0, 2), k_pool[li],
                                   v_pool[li], table, pos0)
         attn = attn.transpose(1, 0, 2).reshape(1, c, -1).astype(dtype)
-        x = x + _proj(blk, "wo", attn, dtype)
+        x = x + proj(blk, "wo", attn, dtype)
         h = rmsnorm(x, blk["ln2"].astype(dtype))
-        x = x + _mlp_paged(blk, h, dtype)
+        x = x + mlp_paged(blk, h, dtype)
     x = rmsnorm(x, params["ln_f"].astype(dtype))
-    logits = _proj(params, "head", x[0, last_idx][None, :],
+    logits = proj(params, "head", x[0, last_idx][None, :],
                    dtype).astype(jnp.float32)
     return logits[0], k_pool, v_pool

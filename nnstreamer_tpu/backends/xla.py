@@ -99,7 +99,7 @@ class _VState:
     device_params: Any = None
 
 
-def _next_pow2(n: int, floor: int = 1) -> int:
+def next_pow2(n: int, floor: int = 1) -> int:
     v = max(n, floor)
     return 1 << (v - 1).bit_length()
 
@@ -1130,7 +1130,7 @@ class XLABackend(FilterBackend):
                 pads = []
                 padded_shape = list(shape)
                 for ax in (len(shape) - 3, len(shape) - 2):
-                    b = _next_pow2(shape[ax], 16)
+                    b = next_pow2(shape[ax], 16)
                     pads.append((ax, b - shape[ax]))
                     padded_shape[ax] = b
                 if any(p for _, p in pads):
@@ -1160,7 +1160,7 @@ class XLABackend(FilterBackend):
         import jax
         import numpy as np_
 
-        nb = _next_pow2(n)
+        nb = next_pow2(n)
         if shape[0] == 1:
             batched_shape = (nb,) + shape[1:]
             stacked = False
@@ -1208,7 +1208,7 @@ class XLABackend(FilterBackend):
         if self._bundle.host_pre is not None:
             # host_pre parses per-frame bytes; it has no batched form
             return super().invoke_batched(tensors, n, keepdims)
-        nb = _next_pow2(n)
+        nb = next_pow2(n)
         # keep device-resident micro-batches as-is (asarray would force
         # a D2H readback just to re-upload them a few lines down)
         arrs = [t if hasattr(t, "shape") else np_.asarray(t)
@@ -1306,7 +1306,7 @@ class XLABackend(FilterBackend):
         vs = self._vstates[ver]
         if vs.bundle.host_pre is not None:
             return super().invoke_batched(tensors, n, keepdims)
-        nb = _next_pow2(n)
+        nb = next_pow2(n)
         arrs = [t if hasattr(t, "shape") else np_.asarray(t)
                 for t in tensors]
         pairs = tuple(((nb,) + tuple(a.shape[1:]), str(a.dtype))
@@ -1415,7 +1415,7 @@ class XLABackend(FilterBackend):
 
         from nnstreamer_tpu.runtime.sync import device_sync
 
-        nb = _next_pow2(int(nb))
+        nb = next_pow2(int(nb))
         batched = tuple(((nb,) + tuple(s), d) for s, d in pairs)
         ver = None
         if self._store_entry is not None:

@@ -1,4 +1,4 @@
-"""Continuous-batching LLM serving (ROADMAP open item 3).
+"""Continuous-batching LLM serving (docs/llm_serving.md).
 
 The prefill/decode split and the streaming transformer
 (models/transformer.py) become a first-class serving workload:
@@ -6,7 +6,10 @@ The prefill/decode split and the streaming transformer
 - `paged_cache`  — fixed-size-block KV pool + free-list allocator, so
   slot count (not max_len × batch) bounds HBM.
 - `paged_model`  — prefill/decode math over the paged pool, formulated
-  for token-for-token parity with `transformer.generate`.
+  for token-for-token parity with `transformer.generate`: the dense
+  family; `sparse_moe`, `hybrid_lm`, `window_moe`, `latent_moe` the others.
+- `parts`, `experts` — what two or more families use: no family module
+  imports another.
 - `families`     — one program set a model family (`spec.LMSpec.family`):
   which programs serve it, their arguments, refusals and counters.
 - `engine`       — the continuous-batching scheduler loop: admit,

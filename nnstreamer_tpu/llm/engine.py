@@ -603,7 +603,7 @@ class LLMEngine:
         if req.pos == 0:
             self._first_launch(req, time.perf_counter())
         chunk = req.prompt[req.pos:req.pos + self.prefill_chunk]
-        from nnstreamer_tpu.backends.xla import _next_pow2
+        from nnstreamer_tpu.backends.xla import next_pow2
 
         if self._windowed:
             # the window table grows into the chunk (`_grow` sees to the
@@ -614,7 +614,7 @@ class LLMEngine:
                                 window=True)
         logits = self.executor.prefill_chunk(
             chunk, req.pos, req.block_table,
-            bucket=_next_pow2(self.prefill_chunk, 8), sync=False,
+            bucket=next_pow2(self.prefill_chunk, 8), sync=False,
             req=req.req_id, state_slot=req.state_slot,
             **self._window_of(req))
         req.pos += int(chunk.shape[0])

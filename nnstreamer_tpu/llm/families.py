@@ -66,12 +66,12 @@ def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
 
 def _note_expert_tiles(ps, counts: np.ndarray, bucket: int) -> dict:
     """How full the row tiles were that a chunk's grouped products
-    visited (`sparse_moe._expert_layer`), for the set `ps` of a family
-    with an expert layer: counts (layers, held experts) and the rows the
+    visited (`experts.expert_layer`), for the set `ps` of a family with
+    an expert layer: counts (layers, held experts) and the rows the
     chunk was padded to give the pair rows, and the rule gives the tile
-    (`sparse_moe.expert_row_tile`). Counts them and returns the span's
+    (`experts.expert_row_tile`). Counts them and returns the span's
     part."""
-    from nnstreamer_tpu.llm.sparse_moe import expert_row_tile
+    from nnstreamer_tpu.llm.experts import expert_row_tile
 
     tm = expert_row_tile(bucket * ps.spec.experts_per_tok, ps.spec.n_experts)
     visits = expert_tile_visits(counts, tm)
@@ -79,31 +79,6 @@ def _note_expert_tiles(ps, counts: np.ndarray, bucket: int) -> dict:
     ps.counters["expert_tile_rows"] += visits * tm
     return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
         100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
-
-
-def _note_held_load(ps, kind: str, load: np.ndarray, bucket: int) -> dict:
-    """One call's (expert layers, held + 1) counts, for the set `ps` of a
-    family that holds a share of its experts: the real tokens' pairs at
-    held experts and away, the distinct held experts with a token summed
-    over layers, and for a chunk the tokens at the busiest held expert,
-    largest over layers, and how full its grouped products' row tiles
-    were. Counts them and returns the span's part."""
-    counts, away = load[:, :-1], int(load[:, -1].sum())
-    touched, held = int((counts > 0).sum()), int(counts.sum())
-    c = ps.counters
-    c["expert_pairs_held"] += held
-    c["expert_pairs_away"] += away
-    if kind == "decode":
-        c["expert_steps_layers"] += counts.shape[0]
-        c["experts_touched_sum"] += touched
-        return {"experts_touched": touched, "expert_pairs_held": held,
-                "expert_pairs_away": away}
-    load_max = int(counts.max())
-    c["expert_load_max_sum"] += load_max
-    c["expert_load_chunks"] += 1
-    return {"experts_touched": touched, "expert_load_max": load_max,
-            "expert_pairs_held": held, "expert_pairs_away": away,
-            **_note_expert_tiles(ps, counts, bucket)}
 
 
 #: the three things a program of the causal tile update does with its
@@ -178,11 +153,7 @@ class DenseSet:
                                          "kv_slots_read": 0}
         # only the XLA single-chip step walks live blocks; the Pallas
         # grid and the sharded step cover every table entry
-        self._walk_slots = None
-        if not shards and kernel == "xla":
-            from nnstreamer_tpu.llm.paged_model import walk_slots
-
-            self._walk_slots = walk_slots
+        self._walks = not shards and kernel == "xla"
 
     # -- which program -----------------------------------------------------
     def kernel(self, kind: str) -> str:
@@ -260,11 +231,14 @@ class DenseSet:
         every layer keeps K and V, and there is no other state."""
         return {"n_layers": n_layers, "n_kv": self.n_kv}
 
+    #: the device values a chunk or a decode step returns beside its logits
+    beside = 0
+
     def split(self, out: tuple) -> tuple:
         """A program's result as (logits, the device values it returns
         beside them, the pools)."""
-        logits, *pools = out
-        return logits, (), pools
+        logits, *rest = out
+        return logits, tuple(rest[:self.beside]), rest[self.beside:]
 
     # -- what it refuses at submission -------------------------------------
     def check_prompt(self, plen: int, prefill_chunk: int) -> None:
@@ -277,9 +251,13 @@ class DenseSet:
         span's part: kv_tokens, the live rows' context with the step's
         own tokens; kv_slots, the pool slots one layer gathers, padding
         rows and the walk's rounding included."""
-        if self._walk_slots is not None:
-            slots = self._walk_slots(pos_a, self.block_size, self.n_kv,
-                                     self.head_dim, self.max_blocks)
+        if self._walks:
+            from nnstreamer_tpu.llm import parts
+
+            nb_c, _, t = parts.walk_plan(self.block_size, self.n_kv,
+                                         self.head_dim, len(pos_a),
+                                         self.max_blocks)
+            slots = parts.walk_slots(pos_a, self.block_size, nb_c, t)
         else:
             slots = len(pos_a) * self.max_blocks * self.block_size
         tokens = int(pos_a[:n].sum()) + n
@@ -312,24 +290,69 @@ class ChunkOnlySet(DenseSet):
     #: the engine has to chunk (prefill_chunk)
     WHOLE_PROMPT_MAX = 4096
 
+    #: what none of them is served with yet, why in the family's own words
+    NO_SHARDS = NO_W8A8 = ""
+    NO_PALLAS = "it has no Pallas twin yet"
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        if self.shards > 0:
+            why = f"shards={self.shards}: {self.NO_SHARDS}"
+        elif self.paged_kernel == "pallas":
+            why = (f"paged_kernel=pallas: {self.NO_PALLAS}; set "
+                   f"paged_kernel=xla")
+        elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
+            why = f"a W8A8 store version: {self.NO_W8A8}"
+        else:
+            why = self.refusal(params)
+        if why is not None:
+            raise BackendError(
+                f"llm {self.name}: the {self.family} family cannot be "
+                f"served with {why}")
+        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
+
+    def refusal(self, params: dict):
+        """What of the spec, the bundle or the pool's geometry the family
+        cannot serve, in words, or None: the checks that are its own."""
+        return None
+
     def prefill_kind(self, params: dict) -> str:
         return "chunk"
 
     def _fused(self, bucket: int) -> bool:
         """Whether the chunk's attention walk updates a context tile in
-        one kernel (`sparse_moe.fused_attend`)."""
-        from nnstreamer_tpu.llm import sparse_moe
+        one kernel (`parts.fused_attend`)."""
+        from nnstreamer_tpu.llm import parts
 
-        return sparse_moe.fused_attend(bucket, sparse_moe._CTX_TILE,
-                                       self.head_dim)
+        return parts.fused_attend(bucket, parts.CTX_TILE, self.head_dim)
 
     def chunk_kw(self, pos0: int, bucket: int) -> dict:
         """Whole blocks are written at once where the chunk lies on
         them: every chunk of a prompt does when block_size divides
         prefill_chunk, so the bucket stays one program."""
+        from nnstreamer_tpu.llm.parts import CTX_TILE
+
         bs = self.block_size
-        return dict(self.kw, fused=self._fused(bucket),
-                    by_block=int(pos0) % bs == 0 and bucket % bs == 0)
+        kw = dict(self.kw, fused=self._fused(bucket),
+                  by_block=int(pos0) % bs == 0 and bucket % bs == 0)
+        # the walk's tile, for a program that takes it as a static argument
+        if "tile" in self.program("chunk").static:
+            kw["tile"] = CTX_TILE
+        return kw
+
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None, window=None) -> tuple:
+        # n live rows: a step's padding rows reach no expert
+        return (params, cur, tab, pos, np.int32(n), *pools)
+
+    def tiles(self, pos0: int, bucket: int, window: int = 0):
+        """(first, end) of the context tiles a chunk's walk covers: the
+        program's own trip count (`parts.tile_span`), on the host."""
+        from nnstreamer_tpu.llm import parts
+
+        return parts.tile_span(pos0, bucket,
+                               self.max_blocks * self.block_size,
+                               parts.CTX_TILE, window)
 
     def check_prompt(self, plen: int, prefill_chunk: int) -> None:
         """The family prefills through its chunk program only, and one
@@ -352,26 +375,14 @@ class SparseMoESet(ChunkOnlySet):
     (layers, experts), beside its logits."""
 
     family = SPARSE_MOE
+    beside = 1
+
+    NO_SHARDS = "its experts and indexer pool have no sharding rule yet"
+    NO_W8A8 = "its grouped expert products are float only"
 
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
-        # what the family cannot yet be combined with (ROADMAP C2)
-        why = None
-        if self.shards > 0:
-            why = (f"shards={self.shards}: its experts and indexer pool "
-                   f"have no sharding rule yet (ROADMAP B2)")
-        elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: it has no Pallas twin yet "
-                   "(ROADMAP B2); set paged_kernel=xla")
-        elif any(k.endswith("_scale") for k in params["blocks"][0]):
-            why = ("a W8A8 store version: its grouped expert products "
-                   "are float only")
-        if why is not None:
-            raise BackendError(
-                f"llm {self.name}: the sparse_moe family cannot be served "
-                f"with {why}")
         self.idx_dim = int(spec.idx_dim)
-        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
         # kept tracer on or off. Decode steps: context slots the indexer
         # scored / slots selected and attended / indexer-pool slots a
         # layer read (kv_slots_read then counts the selected slots'
@@ -380,7 +391,7 @@ class SparseMoESet(ChunkOnlySet):
         # Chunks: tokens at the busiest expert, summed over the chunks
         # whose counts have been read back (expert_load_chunks); context
         # tiles a layer's walks covered, and those of them the attention
-        # walk updated in one kernel (`sparse_moe.fused_attend`); the
+        # walk updated in one kernel (`parts.fused_attend`); the
         # (row tile, expert) visits one of a chunk's grouped products
         # made over its layers, and the rows of those tiles.
         self.counters.update(dict.fromkeys((
@@ -399,15 +410,6 @@ class SparseMoESet(ChunkOnlySet):
                            (6, 7, 8))
         return Program(sparse_moe.sparse_moe_decode_step,
                        ("spec", "dtype"), (5, 6, 7))
-
-    def decode_args(self, params, cur, tab, pos, n: int, pools,
-                    slots=None, window=None) -> tuple:
-        # n live rows: a step's padding rows reach no expert
-        return (params, cur, tab, pos, np.int32(n), *pools)
-
-    def split(self, out: tuple) -> tuple:
-        logits, counts, *pools = out
-        return logits, (counts,), pools
 
     def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
         """The indexer scores each live row's context (kv_tokens_scored)
@@ -432,12 +434,9 @@ class SparseMoESet(ChunkOnlySet):
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles each of a layer's three walks covers (the
-        program's own count, `sparse_moe_prefill_chunk`'s `n_tiles`),
-        and which update the attention walk made them with."""
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-
-        tiles = min(-(-(pos0 + bucket) // _CTX_TILE),
-                    -(-(self.max_blocks * self.block_size) // _CTX_TILE))
+        program's own count, `parts.tile_span`), and which update the
+        attention walk made them with."""
+        _, tiles = self.tiles(pos0, bucket)
         fused = self._fused(bucket)
         self.counters["chunk_tiles_attended"] += tiles
         self.counters["chunk_tiles_fused"] += tiles * fused
@@ -473,31 +472,23 @@ class HybridSet(ChunkOnlySet):
 
     family = HYBRID
 
+    NO_SHARDS = ("its state and compressed-key pools have no sharding rule "
+                 "yet")
+    NO_W8A8 = "its state update and gathered attention are float only"
+
+    def refusal(self, params: dict):
+        spec = self.spec
+        if self.block_size != spec.ck_stride:
+            return (f"block_size={self.block_size}: a sequence keeps one "
+                    f"compressed key a block of its table, so block_size "
+                    f"has to be the keys' stride, {spec.ck_stride}")
+        if len(spec.layer_kinds) != len(params["blocks"]):
+            return (f"a bundle of {len(params['blocks'])} layers under a "
+                    f"spec that names {len(spec.layer_kinds)}")
+        return None
+
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
-        # what the family cannot yet be combined with (ROADMAP C2)
-        why = None
-        if self.shards > 0:
-            why = (f"shards={self.shards}: its state and compressed-key "
-                   f"pools have no sharding rule yet (ROADMAP B5)")
-        elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: it has no Pallas twin yet "
-                   "(ROADMAP B5); set paged_kernel=xla")
-        elif any(k.endswith("_scale") for k in params["blocks"][0]):
-            why = ("a W8A8 store version: its state update and gathered "
-                   "attention are float only")
-        elif self.block_size != spec.ck_stride:
-            why = (f"block_size={self.block_size}: a sequence keeps one "
-                   f"compressed key a block of its table, so block_size "
-                   f"has to be the keys' stride, {spec.ck_stride}")
-        elif len(spec.layer_kinds) != len(params["blocks"]):
-            why = (f"a bundle of {len(params['blocks'])} layers under a "
-                   f"spec that names {len(spec.layer_kinds)}")
-        if why is not None:
-            raise BackendError(
-                f"llm {self.name}: the hybrid family cannot be served "
-                f"with {why}")
-        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
         self.n_linear = spec.layer_kinds.count(LINEAR)
         self.n_sparse = spec.layer_kinds.count(SPARSE)
         #: bytes of one sequence's state, all linear layers
@@ -566,28 +557,72 @@ class HybridSet(ChunkOnlySet):
                 **self._count(_sparse_reads(
                     s, pos_a[:n].astype(np.int64), slots))}
 
-    def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-
-        return dict(super().chunk_kw(pos0, bucket), tile=_CTX_TILE)
-
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a sparse layer's walk covers (the program's
-        own trip count, `hybrid_lm.live_tiles` of the tile `chunk_kw`
-        hands it) and the slots it gathers for them, a KV head."""
-        from nnstreamer_tpu.llm import hybrid_lm
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
+        own trip count, `parts.tile_span` of the tile `chunk_kw` hands
+        it) and the slots it gathers for them, a KV head."""
+        from nnstreamer_tpu.llm.parts import CTX_TILE
 
-        tiles = hybrid_lm.live_tiles(
-            pos0, bucket, self.max_blocks * self.block_size, _CTX_TILE)
+        _, tiles = self.tiles(pos0, bucket)
         self.counters["state_bytes_rw"] += 2 * self.state_bytes
         self.counters["chunk_tiles_attended"] += tiles
         return {"pos0": pos0, "state_rows": 1, "ctx_tiles": tiles,
                 **self._count(_chunk_reads(self.spec, pos0, clen,
-                                           tiles * _CTX_TILE))}
+                                           tiles * CTX_TILE))}
 
 
-class WindowMoESet(ChunkOnlySet):
+class HeldExpertsSet(ChunkOnlySet):
+    """What the families share whose expert layers hold a share of the
+    routed experts: each call returns, an expert layer, the tokens each
+    held expert got and the pairs routed to experts that are not held,
+    beside its logits."""
+
+    beside = 1
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        # kept tracer on or off. Decode steps: (layer, step) pairs and
+        # the distinct held experts that got a token in them. Every call:
+        # (token, expert) pairs of real tokens routed to held experts and
+        # away. Chunks: tokens at the busiest held expert, summed over
+        # the chunks whose counts have been read back
+        # (expert_load_chunks); the (row tile, expert) visits one of a
+        # chunk's grouped products made over its expert layers, and the
+        # rows of those tiles; the programs of the fused tile update,
+        # (block of queries, tile) pairs over all layers, by what
+        # `pallas_ops.block_reach` has each do
+        self.counters.update(dict.fromkeys((
+            "expert_pairs_held", "expert_pairs_away", "expert_steps_layers",
+            "experts_touched_sum", "expert_load_max_sum",
+            "expert_load_chunks", "expert_tile_visits",
+            "expert_tile_rows") + QBLOCK_KINDS, 0))
+
+    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
+        """One call's (expert layers, held + 1) counts: the real tokens'
+        pairs at held experts and away, the distinct held experts with a
+        token summed over layers, and for a chunk the tokens at the
+        busiest held expert, largest over layers, and how full its
+        grouped products' row tiles were."""
+        load, = host
+        counts, away = load[:, :-1], int(load[:, -1].sum())
+        touched, held = int((counts > 0).sum()), int(counts.sum())
+        c = self.counters
+        c["expert_pairs_held"] += held
+        c["expert_pairs_away"] += away
+        if kind == "decode":
+            c["expert_steps_layers"] += counts.shape[0]
+            c["experts_touched_sum"] += touched
+            return {"experts_touched": touched, "expert_pairs_held": held,
+                    "expert_pairs_away": away}
+        load_max = int(counts.max())
+        c["expert_load_max_sum"] += load_max
+        c["expert_load_chunks"] += 1
+        return {"experts_touched": touched, "expert_load_max": load_max,
+                "expert_pairs_held": held, "expert_pairs_away": away,
+                **_note_expert_tiles(self, counts, bucket)}
+
+
+class WindowMoESet(HeldExpertsSet):
     """The decoder whose layers attend a window of the newest positions
     or the whole context (llm/window_moe.py): one prefill program, its
     chunk; a pair of K and V pools a layer kind, each under a table a
@@ -598,60 +633,38 @@ class WindowMoESet(ChunkOnlySet):
 
     family = WINDOW_MOE
 
+    NO_SHARDS = ("its two pairs of pools and its share of the experts have "
+                 "no sharding rule yet")
+    NO_PALLAS = "it has no windowed Pallas twin yet"
+    NO_W8A8 = "its grouped expert products are float only"
+
+    def refusal(self, params: dict):
+        spec, kinds = self.spec, self.spec.layer_kinds
+        if len(kinds) != len(params["blocks"]):
+            return (f"a bundle of {len(params['blocks'])} layers under a "
+                    f"spec that names {len(kinds)}")
+        if set(kinds) != {WINDOW, FULL} or spec.window < 1:
+            return (f"layer kinds {sorted(set(kinds))} and a window of "
+                    f"{spec.window}: it serves layers of both kinds, "
+                    f"'{WINDOW}' and '{FULL}', and a window >= 1")
+        if not 0 <= spec.dense_layers < len(kinds):
+            return (f"dense_layers={spec.dense_layers} of {len(kinds)}: at "
+                    f"least one layer has to be an expert layer")
+        return None
+
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
         kinds = spec.layer_kinds
-        # what the family cannot yet be combined with (ROADMAP B3)
-        why = None
-        if self.shards > 0:
-            why = (f"shards={self.shards}: its two pairs of pools and its "
-                   f"share of the experts have no sharding rule yet "
-                   f"(ROADMAP B3)")
-        elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: it has no windowed Pallas twin yet "
-                   "(ROADMAP B3); set paged_kernel=xla")
-        elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
-            why = ("a W8A8 store version: its grouped expert products "
-                   "are float only")
-        elif len(kinds) != len(params["blocks"]):
-            why = (f"a bundle of {len(params['blocks'])} layers under a "
-                   f"spec that names {len(kinds)}")
-        elif set(kinds) != {WINDOW, FULL} or spec.window < 1:
-            why = (f"layer kinds {sorted(set(kinds))} and a window of "
-                   f"{spec.window}: it serves layers of both kinds, "
-                   f"'{WINDOW}' and '{FULL}', and a window >= 1")
-        elif not 0 <= spec.dense_layers < len(kinds):
-            why = (f"dense_layers={spec.dense_layers} of {len(kinds)}: at "
-                   f"least one layer has to be an expert layer")
-        if why is not None:
-            raise BackendError(
-                f"llm {self.name}: the window_moe family cannot be served "
-                f"with {why}")
-        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
         self.n_window, self.n_full = kinds.count(WINDOW), kinds.count(FULL)
         self.held = spec.experts_held or spec.n_experts
-        from nnstreamer_tpu.llm.paged_model import _walk_plan
-
-        self._walk_plan = _walk_plan
         # kept tracer on or off. Decode steps: live context a FULL layer
         # and a WINDOW layer attended, pool slots all layers gathered for
         # it (whole iterations, padding rows included; a slot is n_kv x
-        # head_dim values, K and V); (layer, step) pairs and the distinct
-        # held experts that got a token in them. Every call: (token,
-        # expert) pairs of real tokens routed to held experts and away.
-        # Chunks: context tiles a FULL and a WINDOW layer's walk covered;
-        # tokens at the busiest held expert, summed over the chunks whose
-        # counts have been read back (expert_load_chunks); the (row tile,
-        # expert) visits one of a chunk's grouped products made over its
-        # expert layers, and the rows of those tiles; the programs of the
-        # fused tile update, (block of queries, tile) pairs over all
-        # layers, by what `pallas_ops.block_reach` has each do
+        # head_dim values, K and V). Chunks: context tiles a FULL and a
+        # WINDOW layer's walk covered
         self.counters.update(dict.fromkeys((
-            "kv_tokens_full", "kv_tokens_window", "expert_pairs_held",
-            "expert_pairs_away", "expert_steps_layers",
-            "experts_touched_sum", "expert_load_max_sum",
-            "expert_load_chunks", "ctx_tiles_full", "ctx_tiles_window",
-            "expert_tile_visits", "expert_tile_rows") + QBLOCK_KINDS, 0))
+            "kv_tokens_full", "kv_tokens_window", "ctx_tiles_full",
+            "ctx_tiles_window"), 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         """The FULL layers' pools under the pool's geometry as given;
@@ -679,11 +692,6 @@ class WindowMoESet(ChunkOnlySet):
         return Program(window_moe.window_moe_decode_step,
                        ("spec", "dtype"), (6, 7, 8, 9))
 
-    def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-
-        return dict(super().chunk_kw(pos0, bucket), tile=_CTX_TILE)
-
     def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
                    pools, slot=None, window=None) -> tuple:
         wblk_idx, wtab = window
@@ -695,18 +703,15 @@ class WindowMoESet(ChunkOnlySet):
         # n live rows: a step's padding rows reach no expert
         return (params, cur, tab, window, pos, np.int32(n), *pools)
 
-    def split(self, out: tuple) -> tuple:
-        logits, load, *pools = out
-        return logits, (load,), pools
-
     def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
         """A FULL layer attends each live row's whole context, a WINDOW
         layer its newest `window` positions; each kind's work list is
         walked in whole iterations of T chunks of C slots
-        (`paged_model._walk_plan`), every row of the bucket in it."""
-        nb_c, _, t = self._walk_plan(self.block_size, self.n_kv,
-                                     self.head_dim, len(pos_a),
-                                     self.max_blocks)
+        (`parts.walk_plan`), every row of the bucket in it."""
+        from nnstreamer_tpu.llm.parts import walk_plan
+
+        nb_c, _, t = walk_plan(self.block_size, self.n_kv, self.head_dim,
+                               len(pos_a), self.max_blocks)
         c = nb_c * self.block_size
         pos = pos_a.astype(np.int64)
         lo = np.maximum(pos - (self.spec.window - 1), 0)
@@ -729,15 +734,13 @@ class WindowMoESet(ChunkOnlySet):
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a FULL and a WINDOW layer's walk covers (the
-        program's own trip counts, `window_moe.tile_span`) and, where the
-        tile update is the fused one, what its programs do."""
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-        from nnstreamer_tpu.llm.window_moe import tile_span
+        program's own trip counts, `parts.tile_span`) and, where the tile
+        update is the fused one, what its programs do."""
+        from nnstreamer_tpu.llm.parts import CTX_TILE
 
-        slots = self.max_blocks * self.block_size
         spec, fused = self.spec, self._fused(bucket)
-        _, full = tile_span(pos0, bucket, slots, _CTX_TILE)
-        first, end = tile_span(pos0, bucket, slots, _CTX_TILE, spec.window)
+        _, full = self.tiles(pos0, bucket)
+        first, end = self.tiles(pos0, bucket, spec.window)
         self.counters["ctx_tiles_full"] += full
         self.counters["ctx_tiles_window"] += end - first
         walks = ((self.n_full, 0, full, 0),
@@ -746,18 +749,10 @@ class WindowMoESet(ChunkOnlySet):
                 "ctx_tiles_full": int(full),
                 "ctx_tiles_window": int(end - first),
                 **_note_qblocks(self, pos0, bucket, spec.n_heads // spec.n_kv,
-                                _CTX_TILE, walks if fused else ())}
-
-    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
-        """One call's (expert layers, held + 1) counts: the real tokens'
-        pairs at held experts and away, the distinct held experts with a
-        token summed over layers, and for a chunk the tokens at the
-        busiest held expert, largest over layers, and how full its
-        grouped products' row tiles were."""
-        return _note_held_load(self, kind, host[0], bucket)
+                                CTX_TILE, walks if fused else ())}
 
 
-class LatentMoESet(ChunkOnlySet):
+class LatentMoESet(HeldExpertsSet):
     """The decoder whose attention is latent (llm/latent_moe.py): one
     prefill program, its chunk; two pools under one table, a token's
     compressed row (`k`: one row of `kv_rank` a slot, no head axis) and
@@ -769,45 +764,38 @@ class LatentMoESet(ChunkOnlySet):
 
     family = LATENT_MOE
 
-    def __init__(self, spec, *, params: dict, **given):
-        super().__init__(spec, params=params, **given)
-        layers = len(params["blocks"])
-        # what the family cannot yet be combined with (ROADMAP B4)
-        why = None
-        if self.shards > 0:
-            why = (f"shards={self.shards}: `kv_pool_placer` shards the "
-                   f"pools along their head axis, and a latent row has "
-                   f"none (ROADMAP B4)")
-        elif self.paged_kernel == "pallas":
-            why = ("paged_kernel=pallas: that names the dense family's "
-                   "twin programs; this family's decode walk takes its "
-                   "kernel from the backend and the pools' widths alone "
-                   "(`latent_moe.fused_decode`); set paged_kernel=xla")
-        elif any(k.endswith("_scale") for b in params["blocks"] for k in b):
-            why = ("a W8A8 store version: its absorbed products and its "
-                   "grouped expert products are float only")
-        elif min(spec.q_rank, spec.kv_rank, spec.nope_dim, spec.v_dim) < 1 \
+    NO_SHARDS = ("`kv_pool_placer` shards the pools along their head axis, "
+                 "and a latent row has none")
+    NO_PALLAS = ("that names the dense family's twin programs; this family's "
+                 "decode walk takes its kernel from the backend and the "
+                 "pools' widths alone (`latent_moe.fused_decode`)")
+    NO_W8A8 = ("its absorbed products and its grouped expert products are "
+               "float only")
+
+    def refusal(self, params: dict):
+        spec, layers = self.spec, len(params["blocks"])
+        if min(spec.q_rank, spec.kv_rank, spec.nope_dim, spec.v_dim) < 1 \
                 or spec.rope_dim < 2 or spec.rope_dim % 2:
-            why = (f"ranks {spec.q_rank} / {spec.kv_rank} and head widths "
-                   f"{spec.nope_dim} + {spec.rope_dim} / {spec.v_dim}: "
-                   f"every one has to be set, the roped width even")
-        elif not 0 <= spec.dense_layers < layers:
-            why = (f"dense_layers={spec.dense_layers} of {layers}: at "
-                   f"least one layer has to be an expert layer")
-        elif spec.n_group > 1 and (
+            return (f"ranks {spec.q_rank} / {spec.kv_rank} and head widths "
+                    f"{spec.nope_dim} + {spec.rope_dim} / {spec.v_dim}: "
+                    f"every one has to be set, the roped width even")
+        if not 0 <= spec.dense_layers < layers:
+            return (f"dense_layers={spec.dense_layers} of {layers}: at "
+                    f"least one layer has to be an expert layer")
+        if spec.n_group > 1 and (
                 spec.n_experts % spec.n_group
                 or not 0 < spec.topk_group <= spec.n_group
                 or spec.experts_per_tok > spec.topk_group
                 * (spec.n_experts // spec.n_group)):
-            why = (f"{spec.n_experts} experts in {spec.n_group} groups of "
-                   f"which {spec.topk_group} are chosen for "
-                   f"{spec.experts_per_tok} a token: the groups have to "
-                   f"be equal and the chosen ones hold a token's experts")
-        if why is not None:
-            raise BackendError(
-                f"llm {self.name}: the latent_moe family cannot be served "
-                f"with {why}")
-        self.kw = {"spec": spec, "dtype": self.kw["dtype"]}
+            return (f"{spec.n_experts} experts in {spec.n_group} groups of "
+                    f"which {spec.topk_group} are chosen for "
+                    f"{spec.experts_per_tok} a token: the groups have to "
+                    f"be equal and the chosen ones hold a token's experts")
+        return None
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        layers = len(params["blocks"])
         # the pool's row: one latent a token and, beside it, its roped key
         self.n_kv, self.head_dim = 1, int(spec.kv_rank)
         self.idx_dim = int(spec.rope_dim)
@@ -816,25 +804,12 @@ class LatentMoESet(ChunkOnlySet):
         # kept tracer on or off. Decode steps: live context the steps
         # attended, pool slots a layer read for it (`note_decode` says
         # which under each walk; a slot is kv_rank + rope_dim values), the
-        # steps by their walk (`latent_moe.fused_decode`); (layer, step)
-        # pairs and the distinct held experts that got a token in them.
-        # Every call: (token, expert) pairs of real tokens routed to held
-        # experts and away. Chunks: context tiles a layer's walk covered,
-        # and the context tokens a layer put through Wkvb (the expanded
-        # form's; 0 for an absorbed chunk); tokens at
-        # the busiest held expert, summed over the chunks whose counts
-        # have been read back (expert_load_chunks); the (row tile,
-        # expert) visits one of a chunk's grouped products made over its
-        # expert layers, and the rows of those tiles; the programs of the
-        # fused tile update, (block of queries, tile) pairs over all
-        # layers, by what `pallas_ops.block_reach` has each do
+        # steps by their walk (`latent_moe.fused_decode`). Chunks: context
+        # tiles a layer's walk covered, and the context tokens a layer
+        # put through Wkvb (the expanded form's; 0 for an absorbed chunk)
         self.counters.update(dict.fromkeys((
-            "decode_steps_fused", "decode_steps_plain",
-            "latents_expanded", "chunk_tiles_attended", "expert_pairs_held",
-            "expert_pairs_away", "expert_steps_layers",
-            "experts_touched_sum", "expert_load_max_sum",
-            "expert_load_chunks", "expert_tile_visits",
-            "expert_tile_rows") + QBLOCK_KINDS, 0))
+            "decode_steps_fused", "decode_steps_plain", "latents_expanded",
+            "chunk_tiles_attended"), 0))
 
     def cache_kw(self, n_layers: int) -> dict:
         return {"n_layers": n_layers, "n_kv": 1, "values": False}
@@ -857,25 +832,14 @@ class LatentMoESet(ChunkOnlySet):
     def _fused(self, bucket: int) -> bool:
         """The expanded form's tile update in one kernel, where a head's
         values are whole lane tiles (its keys are filled up to that)."""
-        from nnstreamer_tpu.llm import sparse_moe
+        from nnstreamer_tpu.llm import parts
 
-        return self._expanded(bucket) and sparse_moe.fused_attend(
-            bucket, sparse_moe._CTX_TILE, self.spec.v_dim)
+        return self._expanded(bucket) and parts.fused_attend(
+            bucket, parts.CTX_TILE, self.spec.v_dim)
 
     def chunk_kw(self, pos0: int, bucket: int) -> dict:
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-
         return dict(super().chunk_kw(pos0, bucket),
-                    expanded=self._expanded(bucket), tile=_CTX_TILE)
-
-    def decode_args(self, params, cur, tab, pos, n: int, pools,
-                    slots=None, window=None) -> tuple:
-        # n live rows: a step's padding rows reach no expert
-        return (params, cur, tab, pos, np.int32(n), *pools)
-
-    def split(self, out: tuple) -> tuple:
-        logits, load, *pools = out
-        return logits, (load,), pools
+                    expanded=self._expanded(bucket))
 
     def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
         """Every layer attends each live row's whole context in the
@@ -884,15 +848,18 @@ class LatentMoESet(ChunkOnlySet):
         of the kernel, which copies nothing for a padding row
         (`latent_moe.fused_slots`); under the plain walk whole iterations
         of T chunks of C slots, every row of the bucket in them
-        (`latent_moe.walk_slots`). `attend` says which, by the program's
-        own rule."""
-        from nnstreamer_tpu.llm import latent_moe
+        (`parts.walk_slots` under `latent_moe.walk_plan`). `attend` says
+        which, by the program's own rule."""
+        from nnstreamer_tpu.llm import latent_moe, parts
 
         fused = latent_moe.fused_decode(self.block_size, self.spec,
                                         self.kw["dtype"])
-        slots = latent_moe.fused_slots(pos_a, n) if fused \
-            else latent_moe.walk_slots(pos_a, self.block_size,
-                                       self.max_blocks)
+        if fused:
+            slots = latent_moe.fused_slots(pos_a, n)
+        else:
+            nb_c, _, t = latent_moe.walk_plan(self.block_size, len(pos_a),
+                                              self.max_blocks)
+            slots = parts.walk_slots(pos_a, self.block_size, nb_c, t)
         tokens = int(pos_a[:n].sum()) + n
         self.counters["kv_tokens_attended"] += tokens
         self.counters["kv_slots_read"] += slots
@@ -903,29 +870,22 @@ class LatentMoESet(ChunkOnlySet):
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """The context tiles a layer's walk covers (the program's own
-        trip count, `window_moe.tile_span`), the form it attends them in,
+        trip count, `parts.tile_span`), the form it attends them in,
         expanded, the context tokens a layer puts through Wkvb and, where
         the tile update is the fused one (a head a group of one), what
         its programs do."""
-        from nnstreamer_tpu.llm.sparse_moe import _CTX_TILE
-        from nnstreamer_tpu.llm.window_moe import tile_span
+        from nnstreamer_tpu.llm.parts import CTX_TILE
 
-        _, tiles = tile_span(pos0, bucket, self.max_blocks * self.block_size,
-                             _CTX_TILE)
+        _, tiles = self.tiles(pos0, bucket)
         expanded = self._expanded(bucket)
-        through = int(tiles) * _CTX_TILE * expanded
+        through = int(tiles) * CTX_TILE * expanded
         self.counters["chunk_tiles_attended"] += int(tiles)
         self.counters["latents_expanded"] += through
         walks = ((self.layers, 0, tiles, 0),) if self._fused(bucket) else ()
         return {"pos0": pos0, "ctx_tiles": int(tiles),
                 "attend": "expanded" if expanded else "absorbed",
                 "latents_expanded": through,
-                **_note_qblocks(self, pos0, bucket, 1, _CTX_TILE, walks)}
-
-    def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
-        """One call's (expert layers, held + 1) counts, as the window
-        family's."""
-        return _note_held_load(self, kind, host[0], bucket)
+                **_note_qblocks(self, pos0, bucket, 1, CTX_TILE, walks)}
 
 
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:

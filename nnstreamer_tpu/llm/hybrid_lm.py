@@ -3,11 +3,13 @@ block-sparse attention over paged KV (pure jax, jitted by llm_exec as
 ``jit_hybrid_decode_step`` and ``jit_hybrid_prefill_chunk``).
 
 `LMSpec.layer_kinds` says which kind each layer is. The projections
-(`_proj`), the norms (`rmsnorm`) and the rope (`_rope_rows`) are the dense
-family's functions; every layer's MLP is the dense family's SwiGLU. Three
-scalings ride the residual stream: the embedding is multiplied by
-``spec.emb_scale``, each branch by ``spec.residual_scale`` before it is
-added, and the final norm's output is divided by ``spec.logit_div``.
+(`proj`), the rope (`rope_rows`), every layer's SwiGLU (`mlp_paged`), the
+head (`finish`, with this family's `logit_div`) and a layer's index among
+its kind (`layer_index`) are `llm/parts.py`'s, as are the pieces of the
+chunk's walk named below. Three scalings ride the residual stream: the
+embedding is multiplied by ``spec.emb_scale``, each branch by
+``spec.residual_scale`` before it is added, and the final norm's output
+is divided by ``spec.logit_div``.
 
 A LINEAR layer (``spec.lin_heads`` heads of ``head_dim``) keeps no keys:
 per head j a state S (hd x hd, float32) that forgets by
@@ -56,15 +58,17 @@ compressed keys are ``(heads, queries, max_len / stride)``, a tile of
 - Chunk: the selection of every query is kept as a mask over blocks,
   forced and chosen as one set (`attended_mask`; the chosen ones found
   without a sort, `chosen_mask`), and attention walks the live context a
-  tile of `sparse_moe._CTX_TILE` slots at a time, a loop whose trip count
-  comes from ``pos0`` (`sparse_attend_walk`): a tile's K and V are read
-  once through the table for all of the chunk's queries, a query's block
-  bits are spread over the tile's slots behind its position, and an
-  online softmax's carry is updated under that mask, on a TPU in one
-  kernel a tile and KV head (`pallas_ops.selected_block_update`, the
-  sparse-expert family's; elsewhere `sparse_moe.attend_plain`). Nothing
-  a query wide is gathered: a context of C tokens is read once, not once
-  for each of the queries that chose from it. The walk's time grows with
+  tile of `parts.CTX_TILE` slots at a time, a loop whose trip count
+  comes from ``pos0`` (`sparse_attend_walk`, `parts.tile_span`): a tile's
+  K and V are read once through the table for all of the chunk's queries,
+  a query's block bits are spread over the tile's slots behind its
+  position, and an online softmax's carry is updated under that mask, on
+  a TPU in one kernel a tile and KV head
+  (`pallas_ops.selected_block_update`; elsewhere `parts.attend_plain`).
+  The loop is this family's own (`parts.walk_tiles` has one carry, this
+  one a KV head, since heads select apart). Nothing a query wide is
+  gathered: a context of C tokens is read once, not once for each of the
+  queries that chose from it. The walk's time grows with
   the context, 0.39 ms a tile and layer on the v5e (PERF.md, PR 36).
 
 ``y = Wo (sigmoid(h Wg) * o)``.
@@ -81,8 +85,9 @@ import jax
 import jax.numpy as jnp
 
 from nnstreamer_tpu.backends import pallas_ops
-from nnstreamer_tpu.llm import sparse_moe
-from nnstreamer_tpu.llm.paged_model import _mlp_paged, _proj, _rope_rows
+from nnstreamer_tpu.llm import parts
+from nnstreamer_tpu.llm.parts import (
+    finish, layer_index, mlp_paged, proj, rope_rows)
 from nnstreamer_tpu.llm.spec import LINEAR, LMSpec
 from nnstreamer_tpu.models.transformer import rmsnorm
 
@@ -108,11 +113,11 @@ def _linear_qkv(blk, h, pos, spec: LMSpec, dtype):
     (N, H, hd), q and k normed and roped."""
     n = h.shape[0]
     nh, hd = spec.lin_heads, spec.head_dim
-    qkv = _proj(blk, "wqkv", h, dtype).reshape(n, 1, 3, nh, hd)
+    qkv = proj(blk, "wqkv", h, dtype).reshape(n, 1, 3, nh, hd)
     q = rmsnorm(qkv[:, :, 0], blk["q_norm"].astype(dtype))
     k = rmsnorm(qkv[:, :, 1], blk["k_norm"].astype(dtype))
-    q = _rope_rows(q, pos, spec.rope_theta)
-    k = _rope_rows(k, pos, spec.rope_theta)
+    q = rope_rows(q, pos, spec.rope_theta)
+    k = rope_rows(k, pos, spec.rope_theta)
     return q[:, 0], k[:, 0], qkv[:, 0, 2]
 
 
@@ -163,8 +168,8 @@ def _gated_out(blk, x, h, o, spec: LMSpec, dtype):
     o = o.reshape(x.shape[0], 1, -1).astype(dtype)
     if "o_norm" in blk:
         o = rmsnorm(o, blk["o_norm"].astype(dtype))
-    gate = jax.nn.sigmoid(_proj(blk, "wg", h, dtype))
-    return x + spec.residual_scale * _proj(blk, "wo", gate * o, dtype)
+    gate = jax.nn.sigmoid(proj(blk, "wg", h, dtype))
+    return x + spec.residual_scale * proj(blk, "wo", gate * o, dtype)
 
 
 # -- the sparse layer ---------------------------------------------------------
@@ -174,7 +179,7 @@ def _sparse_qkv(blk, h, spec: LMSpec, dtype):
     no rope."""
     n = h.shape[0]
     nh, g, hd = spec.n_heads, spec.n_kv, spec.head_dim
-    qkv = _proj(blk, "wqkv", h, dtype)
+    qkv = proj(blk, "wqkv", h, dtype)
     qw, kw = nh * hd, g * hd
     q = rmsnorm(qkv[..., :qw].reshape(n, nh, hd), blk["q_norm"].astype(dtype))
     k = rmsnorm(qkv[..., qw:qw + kw].reshape(n, g, hd),
@@ -231,17 +236,17 @@ def chosen_mask(score, qpos, spec: LMSpec):
     sel_topk - sel_init - window blocks of largest score, ties to the
     lower index. Returns (chosen (N, G, NB) bool, take (N,): how many a
     query chose). No sort: the J-th largest score of each row is found
-    by the sparse-expert family's search over the bits of order-
-    preserving keys (`select_cut`)."""
+    by the search over the bits of order-preserving keys
+    (`parts.select_cut`)."""
     n, g, nb = score.shape
     forced, own = _forced(nb, qpos, spec)
     free = ~forced & (jnp.arange(nb)[None, :] <= own)            # (N, NB)
     take = jnp.minimum(_n_chosen(spec), jnp.sum(free, axis=1))   # (N,)
-    keys = jnp.where(free[:, None, :], sparse_moe._sort_keys(score),
+    keys = jnp.where(free[:, None, :], parts.sort_keys(score),
                      jnp.uint32(0))
     keys = keys.reshape(n * g, nb)
     rows = jnp.repeat(take, g)
-    t, cut = sparse_moe.select_cut(keys, 1, nb, rows)
+    t, cut = parts.select_cut(keys, 1, nb, rows)
     sel = (keys > t[:, None]) | ((keys == t[:, None]) & (
         jnp.arange(nb)[None, :] <= cut[:, None]))
     sel = sel & (rows > 0)[:, None]
@@ -325,15 +330,6 @@ def sparse_attend_rows(q, qpos, tables, slots, li, k_pool, v_pool, c_pool,
     return o.reshape(n, nh, hd).astype(dtype)
 
 
-def live_tiles(pos0, c: int, slots: int, tile: int):
-    """Context tiles of `tile` slots that hold what a chunk of `c`
-    queries at `pos0` may attend, under a table of `slots` slots: the
-    walk's trip count, the smaller of the two in arithmetic that the
-    host's ints and the program's traced `pos0` both take."""
-    n, cap = -(-(pos0 + c) // tile), -(-slots // tile)
-    return n - (n > cap) * (n - cap)
-
-
 def sparse_attend_walk(q, qpos, mask, tab, n_tiles, li, k_pool, v_pool,
                        *, spec: LMSpec, dtype, fused: bool, tile: int):
     """Layer `li`'s attention of a whole chunk: queries q (C, H, hd) at
@@ -345,20 +341,19 @@ def sparse_attend_walk(q, qpos, mask, tab, n_tiles, li, k_pool, v_pool,
     own pool layer, the tile's block bits of a query are spread over its
     slots behind the query's position, and an online softmax's carry is
     updated under that mask: by `pallas_ops.selected_block_update` where
-    `fused` (`sparse_moe.fused_attend`), a call a KV head since heads
-    select apart, else by `sparse_moe.attend_plain`. Nothing a query
+    `fused` (`parts.fused_attend`), a call a KV head since heads
+    select apart, else by `parts.attend_plain`. Nothing a query
     wide is gathered. Returns o (C, H, hd) in `dtype`."""
     c, nh, hd = q.shape
     g, sb = spec.n_kv, spec.sel_block
     grp = nh // g
     bs = k_pool.shape[2]
-    if tile % bs or tile % sb:
-        raise ValueError(f"block_size {bs} and sel_block {sb} have to "
-                         f"divide the context tile of {tile} slots")
+    if tile % sb:
+        raise ValueError(f"sel_block {sb} does not divide the context tile "
+                         f"of {tile} slots")
+    tab = parts.whole_tiles(tab, tile, bs)
     nb_t, sb_t = tile // bs, tile // sb
-    max_tiles = -(-tab.shape[0] // nb_t)
-    # the table's tail past max_blocks reads block 0: the scratch block
-    tab = jnp.pad(tab, (0, max_tiles * nb_t - tab.shape[0]))
+    max_tiles = tab.shape[0] // nb_t
     mask = jnp.pad(mask.transpose(1, 0, 2),
                    ((0, 0), (0, 0), (0, max_tiles * sb_t - mask.shape[2])))
     head = _head_layers(li, spec)
@@ -382,8 +377,8 @@ def sparse_attend_walk(q, qpos, mask, tab, n_tiles, li, k_pool, v_pool,
         if fused:
             return tuple(pallas_ops.selected_block_update(
                 qs[i], kt[i], vt[i], keys[i], none, no_tie, 0, *state[i],
-                block_q=sparse_moe._FUSED_Q_BLOCK) for i in range(g))
-        return tuple(sparse_moe.attend_plain(
+                block_q=parts.FUSED_Q_BLOCK) for i in range(g))
+        return tuple(parts.attend_plain(
             qs[i], kt[i], vt[i], keys[i], none, no_tie, 0, state[i])
             for i in range(g))
 
@@ -396,23 +391,7 @@ def sparse_attend_walk(q, qpos, mask, tab, n_tiles, li, k_pool, v_pool,
 
 def _mlp(blk, x, spec: LMSpec, dtype):
     h = rmsnorm(x, blk["ln2"].astype(dtype))
-    return x + spec.residual_scale * _mlp_paged(blk, h, dtype)
-
-
-def _finish(params, x, spec: LMSpec, dtype):
-    x = rmsnorm(x, params["ln_f"].astype(dtype)) / spec.logit_div
-    return _proj(params, "head", x.astype(dtype), dtype).astype(_F32)
-
-
-def _layer_index(spec: LMSpec):
-    """For each layer its index among the layers of its own kind: where
-    its state or its KV lives in the pools."""
-    seen = {}
-    out = []
-    for kind in spec.layer_kinds:
-        out.append(seen.get(kind, 0))
-        seen[kind] = out[-1] + 1
-    return out
+    return x + spec.residual_scale * mlp_paged(blk, h, dtype)
 
 
 # -- decode -------------------------------------------------------------------
@@ -472,7 +451,7 @@ def hybrid_decode_step(params, cur, tables, pos, slots, k_pool, v_pool,
     write_blk = tables[jnp.arange(b), pos // bs]
     write_off = pos % bs
     x = (params["embed"][cur][:, None, :] * spec.emb_scale).astype(dtype)
-    for kind, li, blk in zip(spec.layer_kinds, _layer_index(spec),
+    for kind, li, blk in zip(spec.layer_kinds, layer_index(spec.layer_kinds),
                              params["blocks"]):
         if kind == LINEAR:
             x, s_pool = _decode_linear(blk, x, li, pos, slots, s_pool,
@@ -481,8 +460,8 @@ def hybrid_decode_step(params, cur, tables, pos, slots, k_pool, v_pool,
             x, k_pool, v_pool, c_pool = _decode_sparse(
                 blk, x, li, pos, write_blk, write_off, tables, slots, k_pool,
                 v_pool, c_pool, spec=spec, dtype=dtype)
-    return (_finish(params, x[:, 0], spec, dtype), k_pool, v_pool, c_pool,
-            s_pool)
+    return (finish(params, x[:, 0], dtype, logit_div=spec.logit_div), k_pool,
+            v_pool, c_pool, s_pool)
 
 
 # -- chunk prefill ------------------------------------------------------------
@@ -501,12 +480,14 @@ def _chunk_linear(blk, x, li, pos, live, fresh, slot, s_pool, *, spec,
 
 def _write_chunk(pool, head, blk_idx, blk_off, x, by_block: bool):
     """A chunk's keys (or values) x (C, G, hd), consecutive positions,
-    into the pool layers `head` (G,). `by_block`: the chunk starts on a
-    block's first slot and is a whole number of blocks long, so each
-    block of each head is written whole, in a loop of in-place updates
-    (a scatter runs its C x G updates one after another). A block the
-    prompt ends in takes its padding rows' values in the slots past the
-    end, which are written again before any query may read them."""
+    into the pool layers `head` (G,): this family's own write, a KV head
+    a pool layer, where `parts.write_chunk` writes one layer. `by_block`:
+    the chunk starts on a block's first slot and is a whole number of
+    blocks long, so each block of each head is written whole, in a loop
+    of in-place updates (a scatter runs its C x G updates one after
+    another). A block the prompt ends in takes its padding rows' values
+    in the slots past the end, which are written again before any query
+    may read them."""
     x = x.astype(pool.dtype)
     if not by_block:
         return pool.at[head[None, :], blk_idx[:, None], blk_off[:, None],
@@ -564,8 +545,8 @@ def _chunk_sparse(blk, x, li, pos, last_pos, blk_idx, blk_off, tab, slot,
                                 pos.reshape(c // n_q, n_q)))
     o = sparse_attend_walk(
         q, pos, mask.reshape((c,) + mask.shape[2:]), tab,
-        live_tiles(pos[0], c, tab.shape[0] * bs, tile), li, k_pool, v_pool,
-        spec=spec, dtype=dtype, fused=fused, tile=tile)
+        parts.tile_span(pos[0], c, tab.shape[0] * bs, tile)[1], li, k_pool,
+        v_pool,         spec=spec, dtype=dtype, fused=fused, tile=tile)
     x = _gated_out(blk, x, h, o, spec, dtype)
     return _mlp(blk, x, spec, dtype), k_pool, v_pool, c_pool
 
@@ -574,7 +555,7 @@ def hybrid_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table, slot,
                          k_pool, v_pool, c_pool, s_pool, last_idx,
                          *, spec: LMSpec, dtype=jnp.float32,
                          by_block: bool = False, fused: bool = False,
-                         tile: int = sparse_moe._CTX_TILE):
+                         tile: int = parts.CTX_TILE):
     """One prompt chunk of one sequence: the arguments of
     `paged_prefill_chunk` with the sequence's state slot after its table
     and the compressed-key and state pools after K and V. A chunk at
@@ -583,13 +564,13 @@ def hybrid_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table, slot,
     multiples of the block size (`_write_chunk`). `tile` (static): the
     context slots the sparse layers' walk covers an iteration; `fused`
     (static): it updates a tile in one kernel, and the caller asks
-    `sparse_moe.fused_attend` whether it may. Returns (last real
+    `parts.fused_attend` whether it may. Returns (last real
     token's logits (vocab,) f32, k_pool, v_pool, c_pool, s_pool)."""
     c = ids.shape[1]
     pos = pos0 + jnp.arange(c)
     live = jnp.arange(c) <= last_idx
     x = (params["embed"][ids[0]][:, None, :] * spec.emb_scale).astype(dtype)
-    for kind, li, blk in zip(spec.layer_kinds, _layer_index(spec),
+    for kind, li, blk in zip(spec.layer_kinds, layer_index(spec.layer_kinds),
                              params["blocks"]):
         if kind == LINEAR:
             x, s_pool = _chunk_linear(blk, x, li, pos, live, pos0 == 0,
@@ -599,5 +580,6 @@ def hybrid_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table, slot,
                 blk, x, li, pos, pos0 + last_idx, blk_idx, blk_off, table,
                 slot, k_pool, v_pool, c_pool, spec=spec, dtype=dtype,
                 by_block=by_block, fused=fused, tile=tile)
-    logits = _finish(params, x[last_idx, 0][None, :], spec, dtype)[0]
+    logits = finish(params, x[last_idx, 0][None, :], dtype,
+                    logit_div=spec.logit_div)[0]
     return logits, k_pool, v_pool, c_pool, s_pool

@@ -3,17 +3,19 @@ row and one roped key, all heads share both, and two programs read that
 one cache in two forms (pure jax, jitted by llm_exec as
 ``jit_latent_moe_decode_step`` and ``jit_latent_moe_prefill_chunk``).
 
-What is new in this family lives here: the projections through the two
-ranks, YaRN's rope, the two forms of the attention and the rule that picks
-one. The norms (`rmsnorm`), the products (`_proj`), the dense SwiGLU
-(`_mlp_paged`) and the plain decode walk's work list (`_live_items`) are
-the dense family's functions; the fused decode walk's kernel is
-`pallas_paged.latent_decode_attn`; the expert layer (`sparse_moe._expert_layer`,
-whose router chooses inside groups under this family's spec), the chunk's
-plain tile update (`sparse_moe.attend_plain`) and the writes of whole
-blocks the sparse-expert family's; the walk's bounds (`tile_span`) and its
-causal tile update (`pallas_ops.causal_block_update`, whose mask the
-kernel makes from positions) the window family's.
+What is this family's own lives here: the projections through the two
+ranks, YaRN's rope, the two forms of the attention, the rule that picks
+one, and the decode walk in the latent (the plain one over a work list,
+the fused one's kernel `pallas_paged.latent_decode_attn`). From
+`llm/parts.py`: the norms (`norm`), the products (`proj`), the head
+(`finish`), a chunk's writes into both pools (`write_chunk`), the plain
+decode walk's work list (`live_items`) and the chunk's walk over context
+tiles (`tile_span`, `walk_tiles` around this family's own read of a tile
+in either form, and `causal_update`: `pallas_ops.causal_block_update`,
+whose mask the kernel makes from positions, or the plain update). From
+`llm/experts.py`: the MLP of shared experts beside the routed ones
+(`shared_mlp`), whose router chooses inside groups under this family's
+spec.
 
 The layer, for input x at position t, two RMSNorms (`norm_eps`):
 ``h = x + Attn(N1(x))``, ``y = h + MLP(N2(h))``; after the last layer a
@@ -31,7 +33,7 @@ final norm and the untied head. No biases.
   float32; the heads' sums of v, side by side, through ``Wo``.
 - ``MLP`` of the first `dense_layers` layers: a SwiGLU of `dense_width`.
   Of the others: a shared SwiGLU of `shared_width` every token passes,
-  unweighted, plus the routed experts' part (`sparse_moe._route`: softmax
+  unweighted, plus the routed experts' part (`experts.route`: softmax
   over all `n_experts`, `topk_group` of `n_group` groups by their best
   expert, `experts_per_tok` among those, weights ``route_scale * s_e`` and
   not renormalised), summed over the chosen experts held here.
@@ -65,7 +67,7 @@ The two forms, over the same cache.
 
 `expanded_attend` picks from the static bucket alone, by the operations:
 at the published widths the forms cross at 171 queries a key. How a tile
-updates the softmax's carry is `sparse_moe.fused_attend`'s to say (the
+updates the softmax's carry is `parts.fused_attend`'s to say (the
 expanded form only: a head there has a K and a V of its own, which is the
 kernel's layout; its K is filled with zeros to a whole lane tile, and a
 head being a group of one, a program of the kernel takes 1,024 queries).
@@ -80,14 +82,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nnstreamer_tpu.backends import pallas_ops, pallas_paged
-from nnstreamer_tpu.llm import sparse_moe
+from nnstreamer_tpu.backends import pallas_paged
+from nnstreamer_tpu.llm import parts
+from nnstreamer_tpu.llm.experts import shared_mlp
 from nnstreamer_tpu.llm.paged_cache import idx_pack
-from nnstreamer_tpu.llm.paged_model import _live_items, _mlp_paged, _proj
+from nnstreamer_tpu.llm.parts import finish, idx_write, norm, proj, write_chunk
 from nnstreamer_tpu.llm.spec import LMSpec
-from nnstreamer_tpu.llm.window_moe import (
-    _write_chunk, attend_tile_plain, tile_span)
-from nnstreamer_tpu.models.transformer import rmsnorm
 
 _F32 = jnp.float32
 
@@ -95,14 +95,14 @@ _F32 = jnp.float32
 # (whole blocks) and chunks an iteration gathers and attends. All heads read
 # one row a slot, so a chunk is a (heads, kv_rank + rope_dim) x (slots)
 # product and wants many slots; a row wastes half a chunk of masked slots.
-_DECODE_CHUNK = 512
-_DECODE_ITEMS = 16
+DECODE_CHUNK = 512
+DECODE_ITEMS = 16
 # Slots a step of the fused walk's kernel copies and attends (whole blocks).
 # One layer alone on the v5e, 31 rows of a bucket of 32 at 6.5 k positions
 # each (PERF.md, PR 43): 0.97 ms at 256, 0.77 at 512, 0.66 at 1,024, 0.67 at
 # 2,048 (the plain walk 1.36): a step's fixed cost outweighs the half step a
 # row wastes, up to 1,024.
-_DECODE_STEP = 1024
+DECODE_STEP = 1024
 
 
 # -- YaRN -----------------------------------------------------------------------
@@ -161,22 +161,18 @@ def _rope(x, pos, spec: LMSpec):
 
 # -- what both programs share ------------------------------------------------------
 
-def _norm(w, x, spec: LMSpec, dtype):
-    return rmsnorm(x, w.astype(dtype), spec.norm_eps)
-
-
 def _project(blk, x, pos, spec: LMSpec, dtype):
     """x (N, 1, D) at positions pos (N,): q_nope (N, H, nope), q_pe (N,
     H, rope) roped, the latent c (N, kv_rank) normed, k_pe (N, rope)
     roped. Rows are independent: a decode batch and a chunk's tokens take
     the same path."""
     n = x.shape[0]
-    u = _norm(blk["ln1"], x, spec, dtype)
-    cq = _norm(blk["q_norm"], _proj(blk, "wqa", u, dtype), spec, dtype)
-    q = _proj(blk, "wqb", cq, dtype).reshape(
+    u = norm(blk["ln1"], x, spec, dtype)
+    cq = norm(blk["q_norm"], proj(blk, "wqa", u, dtype), spec, dtype)
+    q = proj(blk, "wqb", cq, dtype).reshape(
         n, spec.n_heads, spec.nope_dim + spec.rope_dim)
-    kv = _proj(blk, "wkva", u, dtype)[:, 0]
-    c = _norm(blk["kv_norm"], kv[:, :spec.kv_rank], spec, dtype)
+    kv = proj(blk, "wkva", u, dtype)[:, 0]
+    c = norm(blk["kv_norm"], kv[:, :spec.kv_rank], spec, dtype)
     return (q[..., :spec.nope_dim], _rope(q[..., spec.nope_dim:], pos, spec),
             c, _rope(kv[:, spec.kv_rank:], pos, spec))
 
@@ -204,26 +200,6 @@ def _absorbed_out(o_lat, w, spec: LMSpec, dtype):
     return o.reshape(o.shape[0], -1).astype(dtype)
 
 
-def _mlp(blk, x, live, dense: bool, spec: LMSpec, dtype):
-    """x + MLP(N2(x)). Returns (x, the expert layer's counts with the
-    pairs routed away last (experts_held + 1,) int32, or None for a dense
-    layer)."""
-    u = _norm(blk["ln2"], x, spec, dtype)
-    if dense:
-        return x + _mlp_paged(blk, u, dtype), None
-    y, counts, away = sparse_moe._expert_layer(blk, u[:, 0], live, spec,
-                                               dtype)
-    # the shared experts are one SwiGLU, through the dense family's products
-    shared = _mlp_paged({"wi": blk["swi"], "wd": blk["swd"]}, u, dtype)
-    return (x + shared + y[:, None, :],
-            jnp.concatenate([counts, away[None]]))
-
-
-def _finish(params, x, spec: LMSpec, dtype):
-    x = _norm(params["ln_f"], x, spec, dtype)
-    return _proj(params, "head", x, dtype).astype(_F32)
-
-
 def _unpacked(rows, rope_dim: int):
     """Gathered rows of the roped keys' pool (..., block_size // pack,
     pack * rope_dim) as (..., slots, rope_dim) in slot order."""
@@ -235,19 +211,9 @@ def _unpacked(rows, rope_dim: int):
 def walk_plan(block_size: int, b: int, max_blocks: int):
     """The decode walk's constants for a bucket of `b` rows: (blocks a
     chunk, chunks a full table holds, items an iteration)."""
-    nb_c = max(1, min(max_blocks, _DECODE_CHUNK // block_size))
+    nb_c = max(1, min(max_blocks, DECODE_CHUNK // block_size))
     n_chunks = -(-max_blocks // nb_c)
-    return nb_c, n_chunks, max(1, min(b * n_chunks, _DECODE_ITEMS))
-
-
-def walk_slots(pos, block_size: int, max_blocks: int) -> int:
-    """Pool slots one layer of a decode step gathers for the bucket's
-    positions `pos` (padding rows included): whole iterations of T
-    chunks of C slots. Host arithmetic, for the family's counters."""
-    nb_c, _, t = walk_plan(block_size, len(pos), max_blocks)
-    c = nb_c * block_size
-    items = sum(int(p) // c + 1 for p in pos)
-    return -(-items // t) * t * c
+    return nb_c, n_chunks, max(1, min(b * n_chunks, DECODE_ITEMS))
 
 
 def fused_decode(block_size: int, spec: LMSpec, dtype) -> bool:
@@ -260,22 +226,22 @@ def fused_decode(block_size: int, spec: LMSpec, dtype) -> bool:
     return (jax.default_backend() == "tpu" and spec.kv_rank % 128 == 0
             and idx_pack(block_size, spec.rope_dim) == 2
             and (2 * spec.rope_dim) % 128 == 0 and block_size % 32 == 0
-            and _DECODE_STEP % block_size == 0
+            and DECODE_STEP % block_size == 0
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
 
 
 def fused_slots(pos, n: int) -> int:
     """Pool slots one layer of a decode step copies under the fused walk,
     for the bucket's positions `pos` of which the first `n` are live: each
-    live row's context in whole steps of `_DECODE_STEP` slots, nothing
+    live row's context in whole steps of `DECODE_STEP` slots, nothing
     for the padding rows. Host arithmetic, the kernel's own trip counts."""
-    return sum(int(p) // _DECODE_STEP + 1 for p in pos[:n]) * _DECODE_STEP
+    return sum(int(p) // DECODE_STEP + 1 for p in pos[:n]) * DECODE_STEP
 
 
 def attend_latent(q, k_pool, i_pool, li, items, t, scale: float):
     """Layer `li`'s attention in the latent of the absorbed queries q (B,
     H, kv_rank + rope_dim) over each row's work list `items`
-    (`paged_model._live_items`): T items an iteration, all heads of an
+    (`parts.live_items`): T items an iteration, all heads of an
     item's row against its chunk's latents and roped keys, the
     online-softmax carry (m, l, acc) a row and head in f32, merged through
     the (T, B) relation `own` as the dense family's. Returns the heads'
@@ -333,7 +299,7 @@ def _decode_layer(blk, x, li, pos, live, write_blk, write_off, walk,
     q_nope, q_pe, c, k_pe = _project(blk, x, pos, spec, dtype)
     k_pool = k_pool.at[li, write_blk, write_off].set(
         c[:, None, :].astype(k_pool.dtype))
-    i_pool = sparse_moe._idx_write(i_pool, li, write_blk, write_off, k_pe)
+    i_pool = idx_write(i_pool, li, write_blk, write_off, k_pe)
     w = _wkvb(blk, spec, dtype)
     q = absorbed_queries(q_nope, q_pe, w, spec)
     if t:
@@ -344,10 +310,11 @@ def _decode_layer(blk, x, li, pos, live, write_blk, write_off, walk,
         tables, n_live = walk
         o_lat = pallas_paged.latent_decode_attn(
             q, k_pool, i_pool, li, tables, pos, n_live,
-            scale=score_scale(spec), step=_DECODE_STEP)
+            scale=score_scale(spec), step=DECODE_STEP)
     o = _absorbed_out(o_lat, w, spec, dtype)
-    x = x + _proj(blk, "wo", o[:, None, :], dtype)
-    x, load = _mlp(blk, x, live, dense, spec, dtype)
+    x = x + proj(blk, "wo", o[:, None, :], dtype)
+    x, load = shared_mlp(blk, norm(blk["ln2"], x, spec, dtype), live, dense,
+                         spec, dtype, onto=x)
     return x, load, k_pool, i_pool
 
 
@@ -368,7 +335,7 @@ def latent_moe_decode_step(params, cur, tables, pos, n_live, k_pool, i_pool,
     else:
         # one work list a step, shared by every layer
         nb_c, n_chunks, t = walk_plan(bs, b, tables.shape[1])
-        walk = _live_items(tables, pos, bs, nb_c, n_chunks, t)
+        walk = parts.live_items(tables, pos, bs, nb_c, n_chunks, t)
     x = params["embed"][cur][:, None, :].astype(dtype)
     load = []
     for li, blk in enumerate(params["blocks"]):
@@ -378,8 +345,8 @@ def latent_moe_decode_step(params, cur, tables, pos, n_live, k_pool, i_pool,
             dtype=dtype)
         if counts is not None:
             load.append(counts)
-    return (_finish(params, x[:, 0], spec, dtype), jnp.stack(load), k_pool,
-            i_pool)
+    return (finish(params, x[:, 0], dtype, spec.norm_eps), jnp.stack(load),
+            k_pool, i_pool)
 
 
 # -- chunk prefill ------------------------------------------------------------
@@ -410,13 +377,7 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
     c, nh, _ = q_nope.shape
     bs, rank = k_pool.shape[2], spec.kv_rank
     nope, rope = spec.nope_dim, spec.rope_dim
-    if tile % bs:
-        raise ValueError(f"block_size {bs} does not divide the context "
-                         f"tile of {tile} slots")
-    nb_t = tile // bs
-    max_tiles = -(-tab.shape[0] // nb_t)
-    # the table's tail past max_blocks reads block 0: the scratch block
-    tab = jnp.pad(tab, (0, max_tiles * nb_t - tab.shape[0]))
+    tab = parts.whole_tiles(tab, tile, bs)
     if expanded:
         # a head is a KV head of its own; the kernel takes a head's K as
         # whole lane tiles, so its width is filled up with zeros
@@ -434,8 +395,7 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
     # the kernel's layout, a head's queries side by side: made once
     qh = qg.transpose(1, 2, 0, 3) if fused else None
 
-    def attend_tile(j, state):
-        bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
+    def read(bl):
         ct = k_pool[li, bl].astype(dtype).reshape(tile, rank)
         pe = _unpacked(i_pool[li, bl], rope).astype(dtype).reshape(tile, rope)
         if expanded:
@@ -449,17 +409,15 @@ def attend_tiles(q_nope, q_pe, qpos, tab, span, li, k_pool, i_pool, w, *,
         else:
             kt = jnp.concatenate([ct, pe], axis=-1)[:, None, :]
             vt = ct[:, None, :]
-        if fused:
-            # the mask from the positions, inside the kernel
-            return pallas_ops.causal_block_update(
-                qh, kt, vt, qpos[0], j * tile, *state)
-        return attend_tile_plain(qg, kt, vt, qpos, j * tile, 0, state)
+        return kt, vt
 
-    _, l, acc = jax.lax.fori_loop(*span, attend_tile, (
-        jnp.full(heads + (c,), -1e30, _F32), jnp.zeros(heads + (c,), _F32),
-        jnp.zeros(heads + (c, vw), _F32)))
+    def update(j, kt, vt, state):
+        return parts.causal_update(qg, qh, kt, vt, qpos, j * tile, state,
+                                   fused=fused)
+
     # a padding query past the table's last tile attended nothing
-    att = (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(nh, c, vw)
+    att = parts.walk_tiles(tab, span, tile // bs, read, update, heads, c,
+                           vw, l_floor=1e-30).reshape(nh, c, vw)
     att = att.transpose(1, 0, 2)
     if expanded:
         return att.reshape(c, nh * vw).astype(dtype)
@@ -476,19 +434,16 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, k_pool,
     c = x.shape[0]
     bs = k_pool.shape[2]
     q_nope, q_pe, lat, k_pe = _project(blk, x, pos, spec, dtype)
-    k_pool = _write_chunk(k_pool, li, blk_idx, blk_off, lat[:, None, :],
-                          by_block)
-    if by_block:
-        i_pool = sparse_moe._put_blocks(
-            i_pool, li, blk_idx.reshape(c // bs, bs)[:, 0], k_pe)
-    else:
-        i_pool = sparse_moe._idx_write(i_pool, li, blk_idx, blk_off, k_pe)
-    span = tile_span(pos[0], c, tab.shape[0] * bs, tile)
+    k_pool = write_chunk(k_pool, li, blk_idx, blk_off, lat[:, None, :],
+                         by_block)
+    i_pool = write_chunk(i_pool, li, blk_idx, blk_off, k_pe, by_block)
+    span = parts.tile_span(pos[0], c, tab.shape[0] * bs, tile)
     o = attend_tiles(q_nope, q_pe, pos, tab, span, li, k_pool, i_pool,
                      _wkvb(blk, spec, dtype), expanded=expanded, fused=fused,
                      tile=tile, spec=spec, dtype=dtype)
-    x = x + _proj(blk, "wo", o[:, None, :], dtype)
-    x, load = _mlp(blk, x, live, dense, spec, dtype)
+    x = x + proj(blk, "wo", o[:, None, :], dtype)
+    x, load = shared_mlp(blk, norm(blk["ln2"], x, spec, dtype), live, dense,
+                         spec, dtype, onto=x)
     return x, load, k_pool, i_pool
 
 
@@ -496,15 +451,17 @@ def latent_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
                              k_pool, i_pool, last_idx, *, spec: LMSpec,
                              dtype=jnp.float32, by_block: bool = False,
                              fused: bool = False, expanded: bool = False,
-                             tile: int = sparse_moe._CTX_TILE):
+                             tile: int = parts.CTX_TILE):
     """One prompt chunk of one sequence: the arguments of
     `paged_prefill_chunk` with the roped keys' pool where V would be.
-    `by_block`, `fused` (static) as `sparse_moe_prefill_chunk`;
-    `expanded` (static): the attention's form, which the caller asks
-    `expanded_attend`; `tile` (static): the context slots a walk covers
-    an iteration. Returns (last real token's logits (vocab,) f32, the
-    expert layers' counts over the chunk's real tokens (layers,
-    experts_held + 1) int32, k_pool, i_pool)."""
+    `by_block` (static): the caller vouches that `pos0` and the chunk's
+    width are multiples of the block size (`parts.write_chunk`); `fused`
+    (static): the walk updates a tile in one kernel, and the caller asks
+    `parts.fused_attend` whether it may; `expanded` (static): the
+    attention's form, which the caller asks `expanded_attend`; `tile`
+    (static): the context slots a walk covers an iteration. Returns (last
+    real token's logits (vocab,) f32, the expert layers' counts over the
+    chunk's real tokens (layers, experts_held + 1) int32, k_pool, i_pool)."""
     c = ids.shape[1]
     pos = pos0 + jnp.arange(c)
     live = jnp.arange(c) <= last_idx
@@ -517,5 +474,6 @@ def latent_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
             fused=fused, expanded=expanded, spec=spec, dtype=dtype)
         if counts is not None:
             load.append(counts)
-    logits = _finish(params, x[last_idx, 0][None, :], spec, dtype)[0]
+    logits = finish(params, x[last_idx, 0][None, :], dtype,
+                    spec.norm_eps)[0]
     return logits, jnp.stack(load), k_pool, i_pool

@@ -45,47 +45,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.llm import parts
+from nnstreamer_tpu.llm.parts import mlp_paged, proj, rope_rows
 from nnstreamer_tpu.models.transformer import (
-    _expand_kv, apply_seq_kv, rmsnorm)
-
-
-def _proj(store, name, x, dtype):
-    """One projection matmul, quant-aware: a store version whose params
-    carry ``<name>_scale`` (models/quant.quantize_transformer) routes
-    through the W8A8 int8 path; float params take the dense matmul the
-    reference always took — for float weights this is bit-identical to
-    the inline ``x @ w`` it replaced, so the parity contract is
-    untouched."""
-    if f"{name}_scale" in store:
-        from nnstreamer_tpu.models.quant import w8a8_matmul
-
-        return w8a8_matmul(x, store[name],
-                           store[f"{name}_scale"]).astype(dtype)
-    return x @ store[name].astype(dtype)
-
-
-def _mlp_paged(blk, x, dtype):
-    """SwiGLU MLP through `_proj` — the quant-aware twin of
-    `transformer._mlp` (identical math for float params)."""
-    gate_up = _proj(blk, "wi", x, dtype)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    return _proj(blk, "wd", jax.nn.silu(gate) * up, dtype)
-
-
-def _rope_rows(x, pos, base=10000.0):
-    """Rotary embedding with a PER-ROW position: x (B, 1, H, D),
-    pos (B,). Same f32 angle math as `transformer.rope`, broadcast over
-    the batch instead of the sequence axis — row b's values are bit-
-    identical to rope(x[b:b+1], pos[b:b+1], base)."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]   # (B, half)
-    cos = jnp.cos(ang)[:, None, None, :]
-    sin = jnp.sin(ang)[:, None, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+    expand_kv, apply_seq_kv, rmsnorm)
 
 
 def paged_prefill(params, ids, blk_idx, blk_off, k_pool, v_pool, last_idx,
@@ -109,79 +72,6 @@ def paged_prefill(params, ids, blk_idx, blk_off, k_pool, v_pool, last_idx,
     return logits[0, last_idx], k_pool, v_pool
 
 
-# The extents of `_walk_plan`, set from chip runs (PERF.md section 6,
-# PR 26 and PR 30). They are bytes of the float32 tile the products
-# read, not of the pool: a narrower pool is widened after its gather,
-# inside the loop, and it is the widened K and V tiles of an iteration
-# that have to stay in fast memory (at twice these slots an iteration a
-# bfloat16 pool's step was slower than a float32 pool's at these). One K
-# (or V) chunk is at most `_CHUNK_BYTES` of it, one iteration's chunks
-# together at most `_ITER_BYTES`.
-_CHUNK_BYTES = 128 << 10
-_ITER_BYTES = 16 << 20
-
-
-def _walk_plan(block_size, n_kv, hd, b, max_blocks):
-    """The decode walk's constants for one pool geometry and bucket:
-    (blocks a chunk, chunks a full table holds, items an iteration).
-    A chunk is a whole number of blocks, so C = blocks * block_size
-    slots; T items of C slots are gathered and attended at a time."""
-    block_bytes = block_size * n_kv * hd * 4     # attended as float32
-    nb_c = max(1, min(max_blocks, _CHUNK_BYTES // block_bytes))
-    n_chunks = -(-max_blocks // nb_c)
-    t = max(1, min(b * n_chunks, _ITER_BYTES // (nb_c * block_bytes)))
-    return nb_c, n_chunks, t
-
-
-def walk_slots(pos, block_size, n_kv, hd, max_blocks):
-    """Pool slots one layer of a decode step gathers for the bucket's
-    positions `pos` (padding rows included): whole iterations of T
-    chunks of C slots. Host arithmetic, for the executor's counters."""
-    nb_c, _, t = _walk_plan(block_size, n_kv, hd, len(pos), max_blocks)
-    c = nb_c * block_size
-    items = sum(int(p) // c + 1 for p in pos)
-    return -(-items // t) * t * c
-
-
-def _live_items(tables, pos, block_size, nb_c, n_chunks, t, lo=None):
-    """The step's work list, made on the device from `pos` and
-    `tables`: item i is one chunk of one row's live blocks, rows in
-    order, row r holding pos[r] // C + 1 of them. Returns per item its
-    row, its pool blocks (nb_c,), the last live slot inside its chunk
-    (-1 for the items past the total, which read the scratch block and
-    count for nothing) and the number of T-item iterations.
-
-    `lo` (B,), where given, is each row's first live position (a window's
-    lower edge): row r then holds the chunks from lo[r] // C on, and a
-    fifth value is returned, the first live slot inside each item's
-    chunk (at or below 0: the whole chunk is behind the edge)."""
-    b, max_blocks = tables.shape
-    c = nb_c * block_size
-    n_items = -(-(b * n_chunks) // t) * t
-    chunks = pos // c + 1                                    # (B,)
-    if lo is not None:
-        chunk0 = lo // c
-        chunks = chunks - chunk0
-    ends = jnp.cumsum(chunks)
-    i = jnp.arange(n_items, dtype=pos.dtype)
-    valid = i < ends[-1]
-    row = jnp.minimum(
-        jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
-    chunk = jnp.where(valid, i - (ends - chunks)[row], 0)
-    if lo is not None:
-        chunk = chunk + jnp.where(valid, chunk0[row], 0)
-    # a table's tail past max_blocks, like an item past the total,
-    # reads block 0: the scratch block
-    tab = jnp.pad(tables, ((0, 0), (0, n_chunks * nb_c - max_blocks)))
-    blocks = tab[row[:, None], chunk[:, None] * nb_c + jnp.arange(nb_c)]
-    blocks = jnp.where(valid[:, None], blocks, 0)
-    last = jnp.where(valid, pos[row] - chunk * c, -1)
-    n_iter = (ends[-1] + t - 1) // t
-    if lo is None:
-        return row, blocks, last, n_iter
-    return row, blocks, last, n_iter, lo[row] - chunk * c
-
-
 def _attend_live(q, k_pool, v_pool, li, items, t, n_heads):
     """Layer `li`'s attention of q (B, H, hd) f32 over each row's live
     slots: a loop over the work list, T items at a time, with the
@@ -201,14 +91,14 @@ def _attend_live(q, k_pool, v_pool, li, items, t, n_heads):
         la = jax.lax.dynamic_slice_in_dim(last, j * t, t)
         kc = k_pool[li, bl].reshape(t, c, n_kv, hd)
         vc = v_pool[li, bl].reshape(t, c, n_kv, hd)
-        kcx = _expand_kv(kc, n_heads).astype(jnp.float32)
+        kcx = expand_kv(kc, n_heads).astype(jnp.float32)
         s = jnp.einsum("thd,tchd->thc", q[r], kcx) * hd ** -0.5
         # the same inclusive window as _step_impl's `<= p`
         live = (jnp.arange(c)[None, :] <= la[:, None])[:, None, :]
         s = jnp.where(live, s, -1e30)
         mi = jnp.max(s, axis=-1)                             # (T, H)
         p = jnp.where(live, jnp.exp(s - mi[..., None]), 0.0)
-        vcx = _expand_kv(vc, n_heads).astype(jnp.float32)
+        vcx = expand_kv(vc, n_heads).astype(jnp.float32)
         ai = jnp.einsum("thc,tchd->thd", p, vcx)
         own = (r[:, None] == jnp.arange(b)[None, :]) & (la >= 0)[:, None]
         m_new = jnp.maximum(m, jnp.max(
@@ -240,20 +130,20 @@ def _decode_layer(blk, x, li, pos, write_blk, write_off, items,
     n_kv, hd = k_pool.shape[3], k_pool.shape[4]
     kv_dim = n_kv * hd
     h = rmsnorm(x, blk["ln1"].astype(dtype))
-    qkv = _proj(blk, "wqkv", h, dtype)
+    qkv = proj(blk, "wqkv", h, dtype)
     q = qkv[..., :d].reshape(b, 1, n_heads, hd)
     k = qkv[..., d:d + kv_dim].reshape(b, 1, n_kv, hd)
     v = qkv[..., d + kv_dim:].reshape(b, 1, n_kv, hd)
-    q, k = _rope_rows(q, pos), _rope_rows(k, pos)
+    q, k = rope_rows(q, pos), rope_rows(k, pos)
     k_pool = k_pool.at[li, write_blk, write_off].set(
         k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[li, write_blk, write_off].set(
         v[:, 0].astype(v_pool.dtype))
     attn = _attend_live(q[:, 0].astype(jnp.float32), k_pool, v_pool,
                         li, items, t, n_heads).astype(dtype)
-    x = x + _proj(blk, "wo", attn.reshape(b, 1, -1), dtype)
+    x = x + proj(blk, "wo", attn.reshape(b, 1, -1), dtype)
     h = rmsnorm(x, blk["ln2"].astype(dtype))
-    return x + _mlp_paged(blk, h, dtype), k_pool, v_pool
+    return x + mlp_paged(blk, h, dtype), k_pool, v_pool
 
 
 def paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
@@ -272,18 +162,18 @@ def paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
     """
     b = cur.shape[0]
     _, _, block_size, n_kv, hd = k_pool.shape
-    nb_c, n_chunks, t = _walk_plan(block_size, n_kv, hd, b,
+    nb_c, n_chunks, t = parts.walk_plan(block_size, n_kv, hd, b,
                                    tables.shape[1])
     write_blk = tables[jnp.arange(b), pos // block_size]      # (B,)
     write_off = pos % block_size
-    items = _live_items(tables, pos, block_size, nb_c, n_chunks, t)
+    items = parts.live_items(tables, pos, block_size, nb_c, n_chunks, t)
     x = params["embed"][cur][:, None, :].astype(dtype)   # (B,1,D)
     for li, blk in enumerate(params["blocks"]):
         x, k_pool, v_pool = _decode_layer(
             blk, x, li, pos, write_blk, write_off, items, k_pool, v_pool,
             t=t, n_heads=n_heads, dtype=dtype)
     x = rmsnorm(x, params["ln_f"].astype(dtype))
-    logits = _proj(params, "head", x[:, 0], dtype).astype(jnp.float32)
+    logits = proj(params, "head", x[:, 0], dtype).astype(jnp.float32)
     return logits, k_pool, v_pool
 
 
@@ -326,31 +216,31 @@ def paged_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
         h = rmsnorm(x, blk["ln1"].astype(dtype))
         d = x.shape[-1]
         hd = d // n_heads
-        qkv = _proj(blk, "wqkv", h, dtype)
+        qkv = proj(blk, "wqkv", h, dtype)
         kv_dim = (qkv.shape[-1] - d) // 2
         n_kv = kv_dim // hd
         q = qkv[..., :d].reshape(1, c, n_heads, hd)
         k = qkv[..., d:d + kv_dim].reshape(1, c, n_kv, hd)
         v = qkv[..., d + kv_dim:].reshape(1, c, n_kv, hd)
-        q = _rope_rows(q.transpose(1, 0, 2, 3), pos).transpose(1, 0, 2, 3)
-        k = _rope_rows(k.transpose(1, 0, 2, 3), pos).transpose(1, 0, 2, 3)
+        q = rope_rows(q.transpose(1, 0, 2, 3), pos).transpose(1, 0, 2, 3)
+        k = rope_rows(k.transpose(1, 0, 2, 3), pos).transpose(1, 0, 2, 3)
         k_pool = k_pool.at[li, blk_idx, blk_off].set(
             k[0].astype(k_pool.dtype))
         v_pool = v_pool.at[li, blk_idx, blk_off].set(
             v[0].astype(v_pool.dtype))
         kc = k_pool[li][table].reshape(1, kv_len, n_kv, hd)
         vc = v_pool[li][table].reshape(1, kv_len, n_kv, hd)
-        kcx = _expand_kv(kc, n_heads).astype(jnp.float32)
+        kcx = expand_kv(kc, n_heads).astype(jnp.float32)
         s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                        kcx) * hd ** -0.5               # (1,H,C,kv_len)
         s = jnp.where(mask, s, -1e30)
         pattn = jax.nn.softmax(s, axis=-1)
-        vcx = _expand_kv(vc, n_heads).astype(jnp.float32)
+        vcx = expand_kv(vc, n_heads).astype(jnp.float32)
         attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vcx).astype(dtype)
-        x = x + _proj(blk, "wo", attn.reshape(1, c, -1), dtype)
+        x = x + proj(blk, "wo", attn.reshape(1, c, -1), dtype)
         h = rmsnorm(x, blk["ln2"].astype(dtype))
-        x = x + _mlp_paged(blk, h, dtype)
+        x = x + mlp_paged(blk, h, dtype)
     x = rmsnorm(x, params["ln_f"].astype(dtype))
-    logits = _proj(params, "head", x[0, last_idx][None, :],
+    logits = proj(params, "head", x[0, last_idx][None, :],
                    dtype).astype(jnp.float32)
     return logits[0], k_pool, v_pool

@@ -2,16 +2,18 @@
 the whole context, over a paged pool a kind (pure jax, jitted by llm_exec
 as ``jit_window_moe_decode_step`` and ``jit_window_moe_prefill_chunk``).
 
-`LMSpec.layer_kinds` says which kind each layer is, WINDOW or FULL. The
-projections (`_proj`), the norms (`rmsnorm`), the rope (`_rope_rows`) and
-the dense SwiGLU (`_mlp_paged`) are the dense family's functions, the
-expert layer (`sparse_moe._expert_layer`), the choice of the chunk's tile
-update (`sparse_moe.fused_attend`) and the plain one (`attend_plain`) the
-sparse-expert family's, and the decode step's work list
-(`paged_model._live_items`) the dense family's with a lower bound. The
-fused tile update is this family's and the latent one's:
-`pallas_ops.causal_block_update`, the selected form's carry under a mask
-that positions alone decide.
+`LMSpec.layer_kinds` says which kind each layer is, WINDOW or FULL. What
+is this family's own lives here: the layer's four norms and gated output,
+rope on the window layers alone, and the decode walk over a work list
+with a lower bound, grouped by KV head. From `llm/parts.py`: the
+projections (`proj`), the norms (`norm`), the rope (`rope_rows`), the
+head (`finish`), a layer's index among its kind (`layer_index`), a
+chunk's writes (`write_chunk`), the decode step's work list (`walk_plan`,
+`live_items`) and the chunk's walk over context tiles (`tile_span`,
+`walk_tiles`, and `causal_update`: `pallas_ops.causal_block_update`, the
+selected form's carry under a mask that positions alone decide, or the
+plain update). From `llm/experts.py`: the MLP of a shared expert beside
+the routed ones (`shared_mlp`).
 
 The layer, for input x at position t, with four RMSNorms (`norm_eps`):
 ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(MLP(N3(h)))``; the embedding is
@@ -52,12 +54,12 @@ How each program reads it.
   a row and head merges T chunks an iteration, grouped by KV head (no
   key is repeated for the query heads that share it).
 - Chunk prefill (C queries of one sequence): the context is walked a
-  tile of `sparse_moe._CTX_TILE` slots at a time, a loop whose bounds
-  come from ``pos0``: all live tiles on a FULL layer, on a WINDOW layer
-  the tiles from ``(pos0 - window + 1) // tile`` on (at most
+  tile of `parts.CTX_TILE` slots at a time, a loop whose bounds come
+  from ``pos0`` (`parts.tile_span`): all live tiles on a FULL layer, on a
+  WINDOW layer the tiles from ``(pos0 - window + 1) // tile`` on (at most
   ``(window + C) / tile + 1`` of them whatever the context). A tile's
   mask is the causal edge and the window's; the update is the one
-  `sparse_moe.fused_attend` chooses: plain XLA under the mask as an
+  `parts.fused_attend` chooses: plain XLA under the mask as an
   array, or the kernel, which is told the first query's position, the
   tile's first slot and the window and runs, a block of queries, the
   update with no mask (every query sees every slot), under a mask made
@@ -75,18 +77,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from nnstreamer_tpu.backends import pallas_ops
-from nnstreamer_tpu.llm import sparse_moe
-from nnstreamer_tpu.llm.paged_model import (
-    _live_items, _mlp_paged, _proj, _rope_rows, _walk_plan)
+from nnstreamer_tpu.llm import parts
+from nnstreamer_tpu.llm.experts import shared_mlp
+from nnstreamer_tpu.llm.parts import (
+    finish, layer_index, norm, proj, rope_rows, write_chunk)
 from nnstreamer_tpu.llm.spec import WINDOW, LMSpec
-from nnstreamer_tpu.models.transformer import rmsnorm
 
 _F32 = jnp.float32
-
-
-def _norm(blk, name, x, spec: LMSpec, dtype):
-    return rmsnorm(x, blk[name].astype(dtype), spec.norm_eps)
 
 
 def _qkv(blk, x, pos, kind: str, spec: LMSpec, dtype):
@@ -95,57 +92,43 @@ def _qkv(blk, x, pos, kind: str, spec: LMSpec, dtype):
     WINDOW layer."""
     n = x.shape[0]
     nh, nkv, hd = spec.n_heads, spec.n_kv, spec.head_dim
-    u = _norm(blk, "ln1", x, spec, dtype)
-    qkv = _proj(blk, "wqkv", u, dtype)
+    u = norm(blk["ln1"], x, spec, dtype)
+    qkv = proj(blk, "wqkv", u, dtype)
     qw, kw = nh * hd, nkv * hd
     q = qkv[..., :qw].reshape(n, 1, nh, hd)
     k = qkv[..., qw:qw + kw].reshape(n, 1, nkv, hd)
     v = qkv[..., qw + kw:].reshape(n, nkv, hd)
-    q = _norm(blk, "q_norm", q, spec, dtype)
-    k = _norm(blk, "k_norm", k, spec, dtype)
+    q = norm(blk["q_norm"], q, spec, dtype)
+    k = norm(blk["k_norm"], k, spec, dtype)
     if kind == WINDOW:
-        q = _rope_rows(q, pos, spec.rope_theta)
-        k = _rope_rows(k, pos, spec.rope_theta)
+        q = rope_rows(q, pos, spec.rope_theta)
+        k = rope_rows(k, pos, spec.rope_theta)
     return u, q[:, 0], k[:, 0], v
 
 
 def _attn_out(blk, x, u, o, spec: LMSpec, dtype):
     """x + N2((o * sigmoid(u Wg)) Wo) for the attention's o (N, H * hd)."""
     o = o.reshape(x.shape[0], 1, -1).astype(dtype)
-    o = o * jax.nn.sigmoid(_proj(blk, "wg", u, dtype))
-    return x + _norm(blk, "ln2", _proj(blk, "wo", o, dtype), spec, dtype)
+    o = o * jax.nn.sigmoid(proj(blk, "wg", u, dtype))
+    return x + norm(blk["ln2"], proj(blk, "wo", o, dtype), spec, dtype)
 
 
 def _mlp(blk, x, live, dense: bool, spec: LMSpec, dtype):
     """x + N4(MLP(N3(x))). Returns (x, the expert layer's counts with
     the pairs routed away last (experts_held + 1,) int32, or None for a
     dense layer)."""
-    u = _norm(blk, "ln3", x, spec, dtype)
-    if dense:
-        y, load = _mlp_paged(blk, u, dtype), None
-    else:
-        y, counts, away = sparse_moe._expert_layer(blk, u[:, 0], live, spec,
-                                                   dtype)
-        # the shared expert through the dense family's products
-        shared = _mlp_paged({"wi": blk["swi"], "wd": blk["swd"]}, u, dtype)
-        y = shared + y[:, None, :]
-        load = jnp.concatenate([counts, away[None]])
-    return x + _norm(blk, "ln4", y, spec, dtype), load
-
-
-def _finish(params, x, spec: LMSpec, dtype):
-    x = rmsnorm(x, params["ln_f"].astype(dtype), spec.norm_eps)
-    return _proj(params, "head", x, dtype).astype(_F32)
+    u = norm(blk["ln3"], x, spec, dtype)
+    y, load = shared_mlp(blk, u, live, dense, spec, dtype)
+    return x + norm(blk["ln4"], y, spec, dtype), load
 
 
 def _layers(params, spec: LMSpec):
     """Each layer as (kind, its index among the layers of its kind: where
     its K and V live in that kind's pools, whether its MLP is dense, its
     parameters)."""
-    seen = {}
-    for i, (kind, blk) in enumerate(zip(spec.layer_kinds, params["blocks"])):
-        li = seen.get(kind, 0)
-        seen[kind] = li + 1
+    kinds = spec.layer_kinds
+    for i, (kind, li, blk) in enumerate(zip(kinds, layer_index(kinds),
+                                            params["blocks"])):
         yield kind, li, i < spec.dense_layers, blk
 
 
@@ -158,7 +141,7 @@ def window_floor(pos, window: int):
 
 def _attend_items(q, k_pool, v_pool, li, items, t):
     """Layer `li`'s attention of q (B, H, hd) over each row's work list
-    `items` (`paged_model._live_items`; with a fifth value, the first
+    `items` (`parts.live_items`; with a fifth value, the first
     live slot of each item's chunk): a loop over the list, T items at a
     time, the online-softmax carry (m, l, acc) a row, KV head and query
     head of its group in f32. Several items of one iteration may belong
@@ -238,13 +221,13 @@ def window_moe_decode_step(params, cur, tables, wtables, pos, n_live,
     v_pool, wk_pool, wv_pool)."""
     b = cur.shape[0]
     bs, nkv, hd = k_pool.shape[2], k_pool.shape[3], k_pool.shape[4]
-    nb_c, n_chunks, t = _walk_plan(bs, nkv, hd, b, tables.shape[1])
+    nb_c, n_chunks, t = parts.walk_plan(bs, nkv, hd, b, tables.shape[1])
     at = jnp.arange(b), pos // bs
     write_off = pos % bs
     live = jnp.arange(b) < n_live
     # one work list a kind, shared by every layer of the kind
-    full = (tables[at], _live_items(tables, pos, bs, nb_c, n_chunks, t))
-    win = (wtables[at], _live_items(
+    full = (tables[at], parts.live_items(tables, pos, bs, nb_c, n_chunks, t))
+    win = (wtables[at], parts.live_items(
         wtables, pos, bs, nb_c, n_chunks, t,
         lo=window_floor(pos, spec.window)))
     x = (params["embed"][cur][:, None, :] * spec.emb_scale).astype(dtype)
@@ -262,51 +245,11 @@ def window_moe_decode_step(params, cur, tables, wtables, pos, n_live,
             k_pool, v_pool = pools
         if counts is not None:
             load.append(counts)
-    return (_finish(params, x[:, 0], spec, dtype), jnp.stack(load),
+    return (finish(params, x[:, 0], dtype, spec.norm_eps), jnp.stack(load),
             k_pool, v_pool, wk_pool, wv_pool)
 
 
 # -- chunk prefill ------------------------------------------------------------
-
-def tile_span(pos0, c: int, slots: int, tile: int, window: int = 0):
-    """(first, end) of the context tiles of `tile` slots a chunk of `c`
-    queries at `pos0` walks under a table of `slots` slots: up to the
-    chunk's own last tile, and on a WINDOW layer (`window` > 0) from the
-    tile that holds the first query's window floor. In arithmetic that
-    the host's ints and the program's traced `pos0` both take."""
-    n, cap = -(-(pos0 + c) // tile), -(-slots // tile)
-    end = n - (n > cap) * (n - cap)
-    if not window:
-        return 0 * end, end
-    lo = pos0 - (window - 1)
-    return (lo > 0) * (lo // tile), end
-
-
-def _write_chunk(pool, li, blk_idx, blk_off, x, by_block: bool):
-    """A chunk's keys (or values) x (C, Hkv, hd), consecutive positions,
-    into layer `li` of `pool`; `by_block` as
-    `sparse_moe._write_chunk`: each block written whole."""
-    if not by_block:
-        return pool.at[li, blk_idx, blk_off].set(x.astype(pool.dtype))
-    bs = pool.shape[2]
-    first = blk_idx.reshape(x.shape[0] // bs, bs)[:, 0]
-    return sparse_moe._put_blocks(pool, li, first, x)
-
-
-def attend_tile_plain(qg, kt, vt, qpos, first, window: int, state):
-    """One context tile in plain XLA (`sparse_moe.attend_plain`) under
-    the causal edge and, where `window` > 0, the window's: the queries at
-    positions qpos (C,) against the slots from `first` on, the mask as
-    selection keys of 1 and 0 under a threshold of 0 with no tie taken."""
-    c = qpos.shape[0]
-    s = (first + jnp.arange(kt.shape[0]))[None, :]
-    on = s <= qpos[:, None]
-    if window:
-        on = on & (s > qpos[:, None] - window)
-    return sparse_moe.attend_plain(
-        qg, kt, vt, on.astype(jnp.uint32), jnp.zeros((c,), jnp.uint32),
-        jnp.full((c,), -1, jnp.int32), 0, state)
-
 
 def attend_tiles(q, qpos, tab, span, li, k_pool, v_pool, *, window: int,
                  fused: bool, tile: int, dtype):
@@ -319,33 +262,22 @@ def attend_tiles(q, qpos, tab, span, li, k_pool, v_pool, *, window: int,
     c, nh, hd = q.shape
     bs, nkv = k_pool.shape[2], k_pool.shape[3]
     grp = nh // nkv
-    if tile % bs:
-        raise ValueError(f"block_size {bs} does not divide the context "
-                         f"tile of {tile} slots")
-    nb_t = tile // bs
-    max_tiles = -(-tab.shape[0] // nb_t)
-    # the table's tail past max_blocks reads block 0: the scratch block
-    tab = jnp.pad(tab, (0, max_tiles * nb_t - tab.shape[0]))
+    tab = parts.whole_tiles(tab, tile, bs)
     qg = q.reshape(c, nkv, grp, hd)
     # the kernel's layout, a head's queries side by side: made once
     qh = qg.transpose(1, 2, 0, 3) if fused else None
 
-    def attend_tile(j, state):
-        bl = jax.lax.dynamic_slice_in_dim(tab, j * nb_t, nb_t)
-        kt = k_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
-        vt = v_pool[li, bl].astype(dtype).reshape(tile, nkv, hd)
-        if fused:
-            # the mask from the positions, inside the kernel
-            return pallas_ops.causal_block_update(
-                qh, kt, vt, qpos[0], j * tile, *state, window=window)
-        return attend_tile_plain(qg, kt, vt, qpos, j * tile, window, state)
+    def read(bl):
+        return (k_pool[li, bl].astype(dtype).reshape(tile, nkv, hd),
+                v_pool[li, bl].astype(dtype).reshape(tile, nkv, hd))
 
-    _, l, acc = jax.lax.fori_loop(*span, attend_tile, (
-        jnp.full((nkv, grp, c), -1e30, _F32),
-        jnp.zeros((nkv, grp, c), _F32),
-        jnp.zeros((nkv, grp, c, hd), _F32)))
+    def update(j, kt, vt, state):
+        return parts.causal_update(qg, qh, kt, vt, qpos, j * tile, state,
+                                   window=window, fused=fused)
+
     # a padding query past the table's last tile attended nothing
-    att = acc / jnp.maximum(l, 1e-30)[..., None]
+    att = parts.walk_tiles(tab, span, tile // bs, read, update, (nkv, grp),
+                           c, hd, l_floor=1e-30)
     return att.transpose(2, 0, 1, 3).reshape(c, nh * hd)
 
 
@@ -357,10 +289,11 @@ def _chunk_layer(blk, x, li, pos, live, blk_idx, blk_off, tab, k_pool,
     (jitted with `li` an argument, as `_decode_layer`)."""
     c = x.shape[0]
     u, q, k, v = _qkv(blk, x, pos, kind, spec, dtype)
-    k_pool = _write_chunk(k_pool, li, blk_idx, blk_off, k, by_block)
-    v_pool = _write_chunk(v_pool, li, blk_idx, blk_off, v, by_block)
+    k_pool = write_chunk(k_pool, li, blk_idx, blk_off, k, by_block)
+    v_pool = write_chunk(v_pool, li, blk_idx, blk_off, v, by_block)
     window = spec.window if kind == WINDOW else 0
-    span = tile_span(pos[0], c, tab.shape[0] * k_pool.shape[2], tile, window)
+    span = parts.tile_span(pos[0], c, tab.shape[0] * k_pool.shape[2], tile,
+                           window)
     o = attend_tiles(q, pos, tab, span, li, k_pool, v_pool, window=window,
                      fused=fused, tile=tile, dtype=dtype)
     x = _attn_out(blk, x, u, o, spec, dtype)
@@ -373,13 +306,16 @@ def window_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
                              wv_pool, last_idx, *, spec: LMSpec,
                              dtype=jnp.float32, by_block: bool = False,
                              fused: bool = False,
-                             tile: int = sparse_moe._CTX_TILE):
+                             tile: int = parts.CTX_TILE):
     """One prompt chunk of one sequence: the arguments of
     `paged_prefill_chunk` with the WINDOW layers' write targets
     `wblk_idx` (C_b,) and table `wtable` (max_blocks,) after the FULL
-    layers' and their pools after K and V. `by_block`, `fused` (static)
-    as `sparse_moe_prefill_chunk`; `tile` (static): the context slots a
-    walk covers an iteration. Returns (last real token's logits (vocab,)
+    layers' and their pools after K and V. `by_block` (static): the
+    caller vouches that `pos0` and the chunk's width are multiples of the
+    block size (`parts.write_chunk`); `fused` (static): a walk updates a
+    tile in one kernel, and the caller asks `parts.fused_attend` whether
+    it may; `tile` (static): the context slots a walk covers an
+    iteration. Returns (last real token's logits (vocab,)
     f32, the expert layers' counts over the chunk's real tokens (layers,
     experts_held + 1) int32, k_pool, v_pool, wk_pool, wv_pool)."""
     c = ids.shape[1]
@@ -402,5 +338,6 @@ def window_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
             k_pool, v_pool = pools
         if counts is not None:
             load.append(counts)
-    logits = _finish(params, x[last_idx, 0][None, :], spec, dtype)[0]
+    logits = finish(params, x[last_idx, 0][None, :], dtype,
+                    spec.norm_eps)[0]
     return (logits, jnp.stack(load), k_pool, v_pool, wk_pool, wv_pool)

@@ -269,7 +269,7 @@ def decode_wav_bytes(data: bytes, desired_samples: int = -1,
 
 # -- audio frontend (jax twins of the TF kernels) --------------------------
 
-def _next_pow2(n: int) -> int:
+def next_pow2(n: int) -> int:
     v = 1
     while v < n:
         v *= 2
@@ -282,7 +282,7 @@ def audio_spectrogram(jnp, audio, window_size: int, stride: int,
     spectrogram.cc semantics (periodic Hann, next-pow-2 FFT, full
     windows only)."""
     n = audio.shape[0]
-    fft_len = _next_pow2(window_size)
+    fft_len = next_pow2(window_size)
     frames = 1 + (n - window_size) // stride if n >= window_size else 0
     idx = (np.arange(frames)[:, None] * stride
            + np.arange(window_size)[None, :])          # (frames, win)

@@ -123,7 +123,7 @@ def apply_seq_w8a8(params_q, ids, *, n_heads=4, attn: str = "auto",
         k = qkv[..., d:d + kv_dim].reshape(b, s, n_kv, hd)
         v = qkv[..., d + kv_dim:].reshape(b, s, n_kv, hd)
         q, k = T.rope(q, pos), T.rope(k, pos)
-        k, v = T._expand_kv(k, n_heads), T._expand_kv(v, n_heads)
+        k, v = T.expand_kv(k, n_heads), T.expand_kv(v, n_heads)
         if use_pallas:
             from nnstreamer_tpu.backends.pallas_ops import flash_attention
 

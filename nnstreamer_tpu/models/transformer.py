@@ -103,7 +103,7 @@ def _qkv(blk, x, n_heads, dtype):
     return q, k, v
 
 
-def _expand_kv(k, n_heads):
+def expand_kv(k, n_heads):
     """(B, S, n_kv, D) → (B, S, n_heads, D) by group repetition."""
     n_kv = k.shape[2]
     if n_kv == n_heads:
@@ -137,7 +137,7 @@ def apply_seq(params, ids, *, n_heads=4, dtype=jnp.float32,
         h = rmsnorm(x, blk["ln1"].astype(dtype))
         q, k, v = _qkv(blk, h, n_heads, dtype)
         q, k = rope(q, pos), rope(k, pos)
-        k, v = _expand_kv(k, n_heads), _expand_kv(v, n_heads)
+        k, v = expand_kv(k, n_heads), expand_kv(v, n_heads)
         if mesh is not None:
             attn = ring_attention(q, k, v, mesh=mesh, axis=sp_axis,
                                   causal=True)
@@ -188,12 +188,12 @@ def apply_seq_kv(params, ids, *, n_heads=4, dtype=jnp.float32):
         ks.append(k)
         vs.append(v)
         hd = x.shape[-1] // n_heads
-        kcx = _expand_kv(k, n_heads).astype(jnp.float32)
+        kcx = expand_kv(k, n_heads).astype(jnp.float32)
         sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         kcx) * hd ** -0.5
         sc = jnp.where(causal, sc, -1e30)
         pattn = jax.nn.softmax(sc, axis=-1)
-        vcx = _expand_kv(v, n_heads).astype(jnp.float32)
+        vcx = expand_kv(v, n_heads).astype(jnp.float32)
         attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vcx).astype(dtype)
         x = x + attn.reshape(b, s, -1) @ blk["wo"].astype(dtype)
         h = rmsnorm(x, blk["ln2"].astype(dtype))
@@ -263,14 +263,14 @@ def _step_impl(params, ids, k_cache, v_cache, pos, n_heads, dtype, proj):
         # cache layout is (B, max_len, n_kv, D): expand KV groups to
         # full heads for the attention einsum; scores/softmax in f32
         # regardless of the cache storage dtype
-        kcx = _expand_kv(kc, n_heads).astype(jnp.float32)
+        kcx = expand_kv(kc, n_heads).astype(jnp.float32)
         s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                        kcx) * scale                 # (B,H,1,max_len)
         mask = (jnp.arange(max_len) <=
                 jnp.minimum(p, max_len - 1))[None, None, None, :]
         s = jnp.where(mask, s, -1e30)
         pattn = jax.nn.softmax(s, axis=-1)
-        vcx = _expand_kv(vc, n_heads).astype(jnp.float32)
+        vcx = expand_kv(vc, n_heads).astype(jnp.float32)
         attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vcx).astype(dtype)
         x = x + proj(blk, "wo", attn.reshape(b, 1, -1))
         h = rmsnorm(x, blk["ln2"].astype(dtype))

@@ -71,7 +71,7 @@ OUTCOMES = ("applied", "dry_run", "proposed", "hysteresis", "cooldown",
 LITTLE_MARGIN = 0.5
 
 
-def _next_pow2(n: int) -> int:
+def next_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (int(n) - 1).bit_length()
 
 
@@ -425,7 +425,7 @@ class AutoTuner:
         if total < 8:
             return []                  # not enough signal to refine on
         p95 = _hist_percentile(hist, 95.0)
-        target_bucket = _next_pow2(p95)
+        target_bucket = next_pow2(p95)
         out = []
         for el in self.batch_elements:
             cur = float(el.props["max_batch"])

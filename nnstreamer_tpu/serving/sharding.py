@@ -80,7 +80,7 @@ FIXED_BLOCKS = 8
 SUPPORTED_SHARDS = (1, 2, 4, 8)
 
 
-def _tp_mesh(devices):
+def tp_mesh(devices):
     """A 1-axis ("tp",) mesh over exactly these devices."""
     from jax.sharding import Mesh
 
@@ -177,7 +177,7 @@ class ShardedBackend:
         self.name = name
         self.device_indices = tuple(int(i) for i in device_indices)
         self.shards = validate_shards(len(self.device_indices))
-        self.mesh = _tp_mesh(shard_devices(self.device_indices))
+        self.mesh = tp_mesh(shard_devices(self.device_indices))
         self.compile_count = 0
         self.invokes = 0
         self.invoke_failures = 0
@@ -551,7 +551,7 @@ def sharded_paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
     x = params["embed"][cur][:, None, :].astype(dtype)
     mask = (jnp.arange(kv_len)[None, None, None, :] <=
             pos[:, None, None, None])
-    from nnstreamer_tpu.llm.paged_model import _rope_rows
+    from nnstreamer_tpu.llm.parts import rope_rows
     from nnstreamer_tpu.models.transformer import rmsnorm
 
     for li, blk in enumerate(params["blocks"]):
@@ -564,7 +564,7 @@ def sharded_paged_decode_step(params, cur, tables, pos, k_pool, v_pool,
                 b, 1, kv_per_blk, hd)
             v = (h @ blk["wv"][j].astype(dtype)).reshape(
                 b, 1, kv_per_blk, hd)
-            q, k = _rope_rows(q, pos), _rope_rows(k, pos)
+            q, k = rope_rows(q, pos), rope_rows(k, pos)
             kvs = slice(j * kv_per_blk, (j + 1) * kv_per_blk)
             k_pool = k_pool.at[li, write_blk, write_off, kvs].set(
                 k[:, 0].astype(k_pool.dtype))
@@ -751,7 +751,7 @@ def ring_prefill(params, ids, mesh_devices, *, n_heads=4, dtype=None):
     import jax.numpy as jnp
 
     from nnstreamer_tpu.models.transformer import (
-        _expand_kv, _qkv, _mlp, rmsnorm, rope)
+        expand_kv, _qkv, _mlp, rmsnorm, rope)
     from nnstreamer_tpu.parallel.ring_attention import ring_attention
 
     dtype = dtype or jnp.float32
@@ -770,8 +770,8 @@ def ring_prefill(params, ids, mesh_devices, *, n_heads=4, dtype=None):
         q, k = rope(q, pos), rope(k, pos)
         ks.append(k)
         vs.append(v)
-        attn = ring_attention(q, _expand_kv(k, n_heads),
-                              _expand_kv(v, n_heads), mesh=mesh,
+        attn = ring_attention(q, expand_kv(k, n_heads),
+                              expand_kv(v, n_heads), mesh=mesh,
                               axis="sp", causal=True)
         x = x + attn.reshape(b, s, -1) @ blk["wo"].astype(dtype)
         h = rmsnorm(x, blk["ln2"].astype(dtype))

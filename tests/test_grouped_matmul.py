@@ -19,7 +19,7 @@ import tiny_window_moe                                          # noqa: E402
 from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
-from nnstreamer_tpu.llm import families, sparse_moe             # noqa: E402
+from nnstreamer_tpu.llm import experts, families                # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import sparse_moe_lm, window_moe_lm   # noqa: E402
 from perfbench.runners import sparse_moe_llm, window_moe_llm    # noqa: E402
@@ -118,7 +118,7 @@ def test_a_tile_that_does_not_divide_is_refused():
     (64, 256, 64), (128, 128, 128),        # decode steps of 16 rows
     (4, 256, 8)])          # one row of Trinity, filled to 8
 def test_the_row_tile_comes_from_the_shapes(rows, n_experts, tile):
-    assert sparse_moe.expert_row_tile(rows, n_experts) == tile
+    assert experts.expert_row_tile(rows, n_experts) == tile
 
 
 @pytest.mark.parametrize("size,want,tile", [
@@ -126,7 +126,7 @@ def test_the_row_tile_comes_from_the_shapes(rows, n_experts, tile):
     (1536, 1024, 768), (768, 1024, 768), (768, 512, 384), (64, 1024, 64),
     (200, 128, 200)])
 def test_k_and_n_tiles_divide_their_dimension(size, want, tile):
-    assert sparse_moe._fit(size, want) == tile
+    assert experts.fit(size, want) == tile
 
 
 # -- the layer on both sides of the switch-over ------------------------------------
@@ -158,7 +158,7 @@ def test_the_sparse_expert_layer_is_the_references_either_side(
     g = jnp.asarray(np.random.default_rng(n).normal(size=(n, 64)),
                     jnp.float32)
     live = jnp.arange(n) < real
-    y, counts, away = sparse_moe._expert_layer(blk, g, live, spec,
+    y, counts, away = experts.expert_layer(blk, g, live, spec,
                                                jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(sparse_moe_lm.moe_dense(g, blk, 2))
@@ -188,7 +188,7 @@ def test_the_window_familys_share_is_the_references_either_side(
     share = dict(whole, ewi=whole["ewi"][first:first + 2],
                  ewd=whole["ewd"][first:first + 2])
     spec = dataclasses.replace(spec, experts_first=first, experts_held=2)
-    y, counts, away = sparse_moe._expert_layer(
+    y, counts, away = experts.expert_layer(
         share, u, jnp.ones((n,), bool), spec, jnp.float32)
     with jax.default_matmul_precision("highest"):
         want, _ = window_moe_lm.routed_part(u, share, first=first, k=2,
@@ -262,7 +262,7 @@ def test_the_window_set_counts_held_experts_visits():
     load[0, 0], load[0, -1] = 32, 900
     load[1, :2] = 100, 60
     said = ps.note_beside("chunk", [load], 512)   # 1,024 pair rows: 128
-    tm = sparse_moe.expert_row_tile(512 * 2, ps.spec.n_experts)
+    tm = experts.expert_row_tile(512 * 2, ps.spec.n_experts)
     want = 1 + families.expert_tile_visits(load[1:, :-1], tm)
     assert said["expert_tile_visits"] == want
     assert said["expert_tile_fill_pct"] == round(100 * 192 / (want * tm), 2)

@@ -16,7 +16,7 @@ import tiny_hybrid as tiny                                      # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
-from nnstreamer_tpu.llm import hybrid_lm, sparse_moe            # noqa: E402
+from nnstreamer_tpu.llm import hybrid_lm, parts                 # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.paged_cache import (                    # noqa: E402
     BlockAllocator, PagedKVCache)
@@ -384,7 +384,7 @@ def test_counts_of_a_step_are_the_references(bundle, params):
     # the one live context tile, read once for all eight queries (the
     # table's 64 slots and the scratch block past them)
     assert chunk["ctx_tiles"] == 1
-    assert chunk["kv_slots"] == sparse_moe._CTX_TILE
+    assert chunk["kv_slots"] == parts.CTX_TILE
     assert ps.counters["kv_slots_read"] == read + chunk["kv_slots"]
     assert ps.note_chunk(16, 8, 8) == chunk            # reckoned once
 

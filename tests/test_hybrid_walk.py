@@ -24,7 +24,7 @@ import tiny_hybrid as tiny                                      # noqa: E402
 from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
-from nnstreamer_tpu.llm import hybrid_lm, sparse_moe            # noqa: E402
+from nnstreamer_tpu.llm import hybrid_lm, parts                 # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import hybrid_lm as ref               # noqa: E402
 from perfbench.runners.hybrid_llm import lm_spec                # noqa: E402
@@ -109,7 +109,7 @@ def test_the_walk_equals_a_softmax_over_the_whole_table(case, fused):
     li = 1
     mask = _mask(q, qpos, ck, spec)
     want = _attend_whole_table(q, qpos, mask, tab, li, k_pool, v_pool, spec)
-    n_tiles = hybrid_lm.live_tiles(pos0, n, MB * BS, TILE)
+    n_tiles = parts.tile_span(pos0, n, MB * BS, TILE)[1]
     assert n_tiles == -(-(pos0 + n) // TILE)
     got = hybrid_lm.sparse_attend_walk(
         q, qpos, mask, tab, n_tiles, li, k_pool, v_pool, spec=spec,
@@ -173,7 +173,7 @@ def test_the_kernel_equals_the_plain_update_at_16_heads_a_group():
     state = (jnp.asarray(rng.normal(size=(1, grp, c)), jnp.float32),
              jnp.asarray(rng.uniform(1, 9, size=(1, grp, c)), jnp.float32),
              jnp.asarray(rng.normal(size=(1, grp, c, HD)), jnp.float32))
-    want = sparse_moe.attend_plain(qg, kt, vt, keys, t, cut, 0, state)
+    want = parts.attend_plain(qg, kt, vt, keys, t, cut, 0, state)
     got = pallas_ops.selected_block_update(
         qg.transpose(1, 2, 0, 3), kt, vt, keys, t, cut, 0, *state, block_q=8)
     for w, g_, s in zip(want, got, state):
@@ -206,9 +206,9 @@ def test_prefill_then_decode_equals_one_forward_pass(
         params, monkeypatch, cfg, fused, tile, tiles):
     # the tile is the program's argument (`HybridSet.chunk_kw`), so a
     # program traced under another is not met again
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     if fused:
-        monkeypatch.setattr(sparse_moe, "fused_attend",
+        monkeypatch.setattr(parts, "fused_attend",
                             lambda c, tile, hd: True)
     ids = np.random.default_rng(41).integers(0, 256, 49).astype(np.int32)
     want = np.asarray(ref.forward_logits(params, cfg, ids, q_block=8))
@@ -252,20 +252,20 @@ def test_the_update_is_chosen_from_backend_and_shapes_alone(params,
     assert ps.chunk_kw(0, 64)["fused"] is True      # a short bucket
     assert ps.chunk_kw(0, 8) == dict(
         spec=ps.spec, dtype=jnp.float32, by_block=True, fused=True,
-        tile=sparse_moe._CTX_TILE)
+        tile=parts.CTX_TILE)
 
 
 def test_ctx_tiles_is_the_programs_own_trip_count(params, monkeypatch):
     """`note_chunk` and `_chunk_sparse` ask one function: tiles up to the
     bucket's last padded row, capped at the table's; on the host's ints
     and on the program's traced position."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", 16)
+    monkeypatch.setattr(parts, "CTX_TILE", 16)
     ps = _executor(params).programs               # max_len 64: 4 tiles
     assert ps.chunk_kw(0, 8)["tile"] == 16
     cases = ((0, 8), (8, 5), (9, 8), (40, 8), (60, 4), (62, 2))
     said = [ps.note_chunk(pos0, clen, 8) for pos0, clen in cases]
     assert [s["ctx_tiles"] for s in said] == [1, 1, 2, 3, 4, 4]
-    traced = jax.jit(lambda p: hybrid_lm.live_tiles(p, 8, 64, 16))
+    traced = jax.jit(lambda p: parts.tile_span(p, 8, 64, 16)[1])
     for (pos0, _), s in zip(cases, said):
         assert int(traced(jnp.int32(pos0))) == s["ctx_tiles"]
     assert ps.counters["chunk_tiles_attended"] == 15

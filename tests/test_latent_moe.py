@@ -26,10 +26,10 @@ from nnstreamer_tpu.backends import pallas_ops, pallas_paged    # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
-from nnstreamer_tpu.llm import families, latent_moe, sparse_moe  # noqa: E402
+from nnstreamer_tpu.llm import (                                # noqa: E402
+    experts, families, latent_moe, parts)
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.paged_cache import PagedKVCache         # noqa: E402
-from nnstreamer_tpu.llm.paged_model import _live_items          # noqa: E402
 from nnstreamer_tpu.llm.spec import LMSpec                      # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import latent_moe_lm as ref           # noqa: E402
@@ -112,7 +112,7 @@ def test_prompts_chunks_and_decode_give_the_references_logits(
         bundle, params, monkeypatch, plen, total, chunk, tile):
     # the tile is a static argument of the chunk program: a small one
     # makes the walk's trip count do the work
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     ids = _prompt(total, seed=plen)
     ex = _executor(bundle)
     got = _serve(ex, ids, plen, chunk)
@@ -128,7 +128,7 @@ def test_the_two_forms_agree_on_the_same_cache(bundle, params, monkeypatch,
     """The absorbed and the expanded chunk write the same pools and give
     the same logits, the reference's; the decode step (absorbed) reads
     what either wrote."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     ids = _prompt(40, seed=5)
     want = np.asarray(ref.forward_logits(params, CFG, ids))[28:]
     got, pools, said = {}, {}, {}
@@ -194,9 +194,9 @@ def test_expanded_chunks_through_the_causal_kernel_say_what_it_did(
     reference's logits, and the chunk's span says the three kinds of
     (block of queries, tile) pair, which add up to the walk's trip count x
     blocks x layers; a head is a group of one."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     monkeypatch.setattr(latent_moe, "expanded_attend", lambda c, spec: True)
-    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(parts, "fused_attend", lambda c, tile, hd: True)
     monkeypatch.setattr(pallas_ops, "causal_block_q", lambda c, grp: 2)
     ids = _prompt(40, seed=5)
     tracer = Tracer(max_events=8192)
@@ -330,7 +330,7 @@ def test_router_against_a_loop_in_plain_python(params):
     blk = params["blocks"][1]
     u = jnp.asarray(np.random.default_rng(3).normal(size=(40, 64)),
                     jnp.float32)
-    p, e = sparse_moe._route(blk, u, SPEC, jnp.float32)
+    p, e = experts.route(blk, u, SPEC, jnp.float32)
     rp, re = ref.route(u, blk["router"], M)
     s = np.asarray(jax.nn.softmax(jnp.matmul(
         u, blk["router"], precision=jax.lax.Precision.HIGHEST), axis=-1))
@@ -357,7 +357,7 @@ def test_router_ties_go_to_the_lower_index():
     first four."""
     blk = {"router": jnp.zeros((64, 16), jnp.float32)}
     u = jnp.ones((2, 64), jnp.float32)
-    p, e = sparse_moe._route(blk, u, SPEC, jnp.float32)
+    p, e = experts.route(blk, u, SPEC, jnp.float32)
     assert np.asarray(e).tolist() == [[0, 1, 2, 3]] * 2
     assert np.allclose(np.asarray(p), 16.0 / 16)
     rp, re = ref.route(u, blk["router"], M)
@@ -366,7 +366,7 @@ def test_router_ties_go_to_the_lower_index():
     # expert comes with it before any expert of a group left out
     w = np.zeros((64, 16), np.float32)
     w[0, 13] = 1.0
-    p, e = sparse_moe._route({"router": jnp.asarray(w)}, u, SPEC,
+    p, e = experts.route({"router": jnp.asarray(w)}, u, SPEC,
                              jnp.float32)
     assert np.asarray(e)[0].tolist() == [13, 0, 1, 2]
     assert _route_by_hand([1.0] * 13 + [2.0] + [1.0] * 2, 8, 3, 4, 1.0) \
@@ -376,7 +376,7 @@ def test_router_ties_go_to_the_lower_index():
 def _jaxprs(route):
     """The tiny decode steps of the sparse-expert and the window family,
     traced with `route` as the router."""
-    sparse_moe._route = route
+    experts.route = route
     jax.clear_caches()
     out = []
     for mod, runner, lm in ((tiny_sparse_moe, sparse_moe_llm, sparse_moe_lm),
@@ -415,11 +415,11 @@ def test_the_other_expert_families_programs_are_as_before_the_groups():
         return spec.route_scale * p / (
             jnp.sum(p, axis=-1, keepdims=True) + 1e-20), e
 
-    new_route = sparse_moe._route
+    new_route = experts.route
     try:
         new, old = _jaxprs(new_route), _jaxprs(old_route)
     finally:
-        sparse_moe._route = new_route
+        experts.route = new_route
         jax.clear_caches()
     assert new == old and len(new[0]) > 1000 and "top_k" in new[0]
 
@@ -457,7 +457,7 @@ def test_keyes_and_salas_chunk_programs_do_not_reach_the_causal_form(
     form's three names in `pallas_ops` and without them: they call the
     selected form, whose text this PR left as it was, and nothing of the
     new entry."""
-    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(parts, "fused_attend", lambda c, tile, hd: True)
     with_it = _chunk_jaxprs()
     for name in ("causal_block_update", "causal_block_q", "block_reach",
                  "_causal_block_kernel"):
@@ -493,7 +493,7 @@ def test_the_eight_groups_parts_add_up_to_the_uncut_layer():
         parts.append(ref.routed_part(u, share, dict(M, first=first))[0])
         # the program's layer, told the same share
         spec = dataclasses.replace(SPEC, experts_first=first)
-        y, counts, away = sparse_moe._expert_layer(
+        y, counts, away = experts.expert_layer(
             share, u, jnp.ones((24,), bool), spec, jnp.float32)
         assert np.abs(np.asarray(y) - np.asarray(parts[-1])).max() < TOL
         assert int(counts.sum()) + int(away) == 24 * 4
@@ -687,7 +687,7 @@ def test_the_fused_walks_kernel_agrees_with_the_plain_walk(case, dtype, tol):
         q, k_pool, i_pool, jnp.int32(li), tables, pos_a, jnp.int32(n),
         scale=scale, step=STEP, interpret=True)
     nb_c, n_chunks, t = latent_moe.walk_plan(BS, b, tables.shape[1])
-    items = _live_items(tables, pos_a, BS, nb_c, n_chunks, t)
+    items = parts.live_items(tables, pos_a, BS, nb_c, n_chunks, t)
     want = latent_moe.attend_latent(q, k_pool, i_pool, li, items, t, scale)
     assert got.dtype == jnp.float32 and got.shape == want.shape
     assert np.abs(np.asarray(got)[:n] - np.asarray(want)[:n]).max() < tol
@@ -734,7 +734,7 @@ def fused_walk(monkeypatch):
     """The rule forced (the backend here is the CPU, where the kernel is
     interpreted) and a step of two blocks."""
     monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
-    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+    monkeypatch.setattr(latent_moe, "DECODE_STEP", STEP)
 
 
 def test_a_decode_steps_logits_through_both_walks(monkeypatch):
@@ -758,7 +758,7 @@ def test_a_decode_steps_logits_through_both_walks(monkeypatch):
     kw = dict(spec=spec, dtype=jnp.float32)
     want = latent_moe.latent_moe_decode_step(*args, **kw)
     monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
-    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+    monkeypatch.setattr(latent_moe, "DECODE_STEP", STEP)
     calls = []
     monkeypatch.setattr(
         pallas_paged, "latent_decode_attn",
@@ -824,18 +824,19 @@ def test_the_rule_reads_the_backend_and_the_pools_widths(monkeypatch):
 def test_each_walk_counts_the_slots_it_moves(bundle, monkeypatch):
     """`fused_slots`: each live row's context in whole steps, nothing for
     a padding row, which is what the kernel's trip counts copy (a step is
-    `_DECODE_STEP` slots: `n_j` of `_latent_decode_kernel`); `walk_slots`
+    `DECODE_STEP` slots: `n_j` of `_latent_decode_kernel`); `walk_slots`
     whole iterations of the work list. `note_decode` picks by the rule."""
     pos = np.asarray([0, 1023, 1024, 7000, 0, 0, 0, 0])
     assert latent_moe.fused_slots(pos, 4) == (1 + 1 + 2 + 7) * 1024
     assert latent_moe.fused_slots(pos, 1) == 1024
     ps = _executor(bundle).programs
-    plain = latent_moe.walk_slots([9, 20, 0, 0], BS, 16)
+    nb_c, _, t = latent_moe.walk_plan(BS, 4, 16)
+    plain = parts.walk_slots([9, 20, 0, 0], BS, nb_c, t)
     assert plain == 256             # one iteration of 16 chunks of 16 slots
     said = ps.note_decode(np.asarray([9, 20, 0, 0]), 2)
     assert said == {"kv_tokens": 31, "attend": "plain", "kv_slots": plain}
     monkeypatch.setattr(latent_moe, "fused_decode", lambda *a: True)
-    monkeypatch.setattr(latent_moe, "_DECODE_STEP", STEP)
+    monkeypatch.setattr(latent_moe, "DECODE_STEP", STEP)
     said = ps.note_decode(np.asarray([9, 20, 0, 0]), 2)
     assert said == {"kv_tokens": 31, "attend": "fused",
                     "kv_slots": (2 + 3) * STEP}
@@ -880,9 +881,10 @@ def test_each_program_sets_class_is_defined_once():
     assert len(names) == len(set(names))
     sets = [n for n in names if n.endswith("Set")]
     assert sets == ["DenseSet", "ChunkOnlySet", "SparseMoESet", "HybridSet",
-                    "WindowMoESet", "LatentMoESet"]
+                    "HeldExpertsSet", "WindowMoESet", "LatentMoESet"]
     assert set(families.FAMILIES.values()) == {
-        getattr(families, n) for n in sets} - {families.ChunkOnlySet}
+        getattr(families, n) for n in sets} - {families.ChunkOnlySet,
+                                               families.HeldExpertsSet}
     methods = [m.name for c in tree.body if isinstance(c, ast.ClassDef)
                for m in c.body if isinstance(m, ast.FunctionDef)
                and c.name == "ChunkOnlySet"]

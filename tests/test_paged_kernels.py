@@ -128,9 +128,8 @@ def _dense_decode_step(params, cur, tables, pos, k_pool, v_pool, *,
     every slot of every table behind a -1e30 mask, float32."""
     import jax
 
-    from nnstreamer_tpu.llm.paged_model import (
-        _mlp_paged, _proj, _rope_rows)
-    from nnstreamer_tpu.models.transformer import _expand_kv, rmsnorm
+    from nnstreamer_tpu.llm.parts import mlp_paged, proj, rope_rows
+    from nnstreamer_tpu.models.transformer import expand_kv, rmsnorm
 
     b, f32 = cur.shape[0], jnp.float32
     bs, n_kv, hd = k_pool.shape[2:]
@@ -140,37 +139,37 @@ def _dense_decode_step(params, cur, tables, pos, k_pool, v_pool, *,
     mask = jnp.arange(kv_len)[None, None, None, :] \
         <= pos[:, None, None, None]
     for li, blk in enumerate(params["blocks"]):
-        qkv = _proj(blk, "wqkv", rmsnorm(x, blk["ln1"]), f32)
+        qkv = proj(blk, "wqkv", rmsnorm(x, blk["ln1"]), f32)
         d = x.shape[-1]
         kvd = n_kv * hd
-        q = _rope_rows(qkv[..., :d].reshape(b, 1, n_heads, hd), pos)
-        k = _rope_rows(qkv[..., d:d + kvd].reshape(b, 1, n_kv, hd), pos)
+        q = rope_rows(qkv[..., :d].reshape(b, 1, n_heads, hd), pos)
+        k = rope_rows(qkv[..., d:d + kvd].reshape(b, 1, n_kv, hd), pos)
         v = qkv[..., d + kvd:].reshape(b, 1, n_kv, hd)
         k_pool = k_pool.at[li, wb, wo].set(k[:, 0])
         v_pool = v_pool.at[li, wb, wo].set(v[:, 0])
-        kc = _expand_kv(k_pool[li][tables].reshape(b, kv_len, n_kv, hd),
+        kc = expand_kv(k_pool[li][tables].reshape(b, kv_len, n_kv, hd),
                         n_heads)
-        vc = _expand_kv(v_pool[li][tables].reshape(b, kv_len, n_kv, hd),
+        vc = expand_kv(v_pool[li][tables].reshape(b, kv_len, n_kv, hd),
                         n_heads)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kc) * hd ** -0.5
         pattn = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
         attn = jnp.einsum("bhqk,bkhd->bqhd", pattn, vc)
-        x = x + _proj(blk, "wo", attn.reshape(b, 1, -1), f32)
-        x = x + _mlp_paged(blk, rmsnorm(x, blk["ln2"]), f32)
+        x = x + proj(blk, "wo", attn.reshape(b, 1, -1), f32)
+        x = x + mlp_paged(blk, rmsnorm(x, blk["ln2"]), f32)
     x = rmsnorm(x, params["ln_f"])
-    return _proj(params, "head", x[:, 0], f32), k_pool, v_pool
+    return proj(params, "head", x[:, 0], f32), k_pool, v_pool
 
 
 def _walk_consts(monkeypatch, chunk_blocks, items):
     """Steer the walk's constants from the test: C = chunk_blocks
     blocks a chunk, T = items an iteration (None: as shipped, where a
     table of this size is one chunk)."""
-    from nnstreamer_tpu.llm import paged_model as pm
+    from nnstreamer_tpu.llm import parts
 
     if chunk_blocks is not None:
-        monkeypatch.setattr(pm, "_CHUNK_BYTES",
+        monkeypatch.setattr(parts, "CHUNK_BYTES",
                             chunk_blocks * WBLOCK_BYTES)
-        monkeypatch.setattr(pm, "_ITER_BYTES",
+        monkeypatch.setattr(parts, "ITER_BYTES",
                             items * chunk_blocks * WBLOCK_BYTES)
 
 
@@ -680,18 +679,18 @@ def test_walk_slots_equal_what_the_device_loop_gathers(params, monkeypatch,
     those of the float32 tile the products read, so the pool's
     itemsize does not move them."""
     from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor
-    from nnstreamer_tpu.llm import paged_model as pm
+    from nnstreamer_tpu.llm import parts
 
-    monkeypatch.setattr(pm, "_CHUNK_BYTES", 2 * WBLOCK_BYTES)
-    monkeypatch.setattr(pm, "_ITER_BYTES", 6 * WBLOCK_BYTES)
+    monkeypatch.setattr(parts, "CHUNK_BYTES", 2 * WBLOCK_BYTES)
+    monkeypatch.setattr(parts, "ITER_BYTES", 6 * WBLOCK_BYTES)
     plans = []
-    live_items = pm._live_items
+    live_items = parts.live_items
 
     def recording(tables, pos, block_size, nb_c, n_chunks, t):
         plans.append((nb_c, n_chunks, t))
         return live_items(tables, pos, block_size, nb_c, n_chunks, t)
 
-    monkeypatch.setattr(pm, "_live_items", recording)
+    monkeypatch.setattr(parts, "live_items", recording)
     ex = PagedLLMExecutor(dict(params), n_heads=4, dtype=dtype,
                           block_size=WBS, num_blocks=WNB, max_len=WMB * WBS,
                           name="walk")
@@ -708,6 +707,6 @@ def test_walk_slots_equal_what_the_device_loop_gathers(params, monkeypatch,
         n_iter = live_items(jnp.zeros((4, WMB), jnp.int32), jnp.asarray(pos),
                             WBS, nb_c, n_chunks, t)[3]
         assert int(n_iter) * t * nb_c * WBS == ex.stats()["kv_slots_read"] \
-            == pm.walk_slots(pos, WBS, NKV, HD, WMB)
+            == parts.walk_slots(pos, WBS, nb_c, t)
     finally:
         ex.close()

@@ -22,7 +22,7 @@ import tiny_sparse_moe as tiny                                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
-from nnstreamer_tpu.llm import sparse_moe                       # noqa: E402
+from nnstreamer_tpu.llm import experts, parts, sparse_moe       # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.families import SparseMoESet            # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
@@ -103,7 +103,7 @@ def test_chunked_equals_unchunked_and_a_tiled_context(params, ids, want,
     table = whole.cache.allocator.alloc(8)
     one = whole.prefill(ids[:29], table)
     chunked = _serve(_executor(params), ids, 29)
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", 16)
+    monkeypatch.setattr(parts, "CTX_TILE", 16)
     tiled = _serve(_executor(params), ids, 29)
     assert np.abs(one - chunked[0]).max() < TOL
     assert np.abs(tiled - chunked).max() < TOL
@@ -140,8 +140,8 @@ def test_selection_is_exact_and_ties_go_to_the_lower_position():
     assert (want.sum(1) == np.minimum(TOPK, qpos + 1)).all()
     # the chunk program's: integer keys, a threshold and a position cut
     may = np.arange(s)[None, :] <= qpos[:, None]
-    keys = jnp.where(may, sparse_moe._sort_keys(jnp.asarray(scores)), 0)
-    t, p = sparse_moe.select_cut(keys, jnp.int32(3), tile,
+    keys = jnp.where(may, parts.sort_keys(jnp.asarray(scores)), 0)
+    t, p = parts.select_cut(keys, jnp.int32(3), tile,
                                  jnp.minimum(TOPK, jnp.asarray(qpos) + 1))
     assert (_selected(np.asarray(keys), np.asarray(t), np.asarray(p))
             == want).all()
@@ -166,7 +166,7 @@ def test_no_token_is_dropped_when_all_route_to_one_expert(params):
     g = jnp.asarray(np.random.default_rng(4).normal(size=(24, 64)),
                     jnp.float32).at[:, 0].set(8.0)
     live = jnp.arange(24) < 21                       # three padding rows
-    y, counts, _ = sparse_moe._expert_layer(blk, g, live, SPEC, jnp.float32)
+    y, counts, _ = experts.expert_layer(blk, g, live, SPEC, jnp.float32)
     with jax.default_matmul_precision("highest"):
         dense = np.asarray(ref.moe_dense(g, blk, 2))
         sorted_ = np.asarray(ref.moe(g, blk, 2)[0])

@@ -1,6 +1,6 @@
 """The sparse-expert chunk's attention walk (llm/sparse_moe.py): the fused
 tile update (`backends/pallas_ops.selected_block_update`, here in
-interpret mode) against the plain one (`sparse_moe.attend_plain`) on the
+interpret mode) against the plain one (`parts.attend_plain`) on the
 same inputs, the whole chunk program with the fused update forced against
 the plain reference, and how the update is chosen and reported; and the
 tile update's causal form (`pallas_ops.causal_block_update`, the window
@@ -28,7 +28,7 @@ import tiny_sparse_moe as tiny                                  # noqa: E402
 from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
-from nnstreamer_tpu.llm import sparse_moe, window_moe           # noqa: E402
+from nnstreamer_tpu.llm import parts, sparse_moe, window_moe    # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import sparse_moe_lm as ref           # noqa: E402
 from perfbench.runners.sparse_moe_llm import lm_spec            # noqa: E402
@@ -91,7 +91,7 @@ def _carry(rng, nkv, grp, filled, hd=HD):
 def _both(qg, kt, vt, keys, t, cut, j, state, **blocks):
     tile = kt.shape[0]
     keys, t, cut = jnp.asarray(keys), jnp.asarray(t), jnp.asarray(cut)
-    want = sparse_moe.attend_plain(
+    want = parts.attend_plain(
         qg, kt, vt, keys[:, j * tile:(j + 1) * tile], t, cut, j * tile,
         state)
     got = pallas_ops.selected_block_update(
@@ -190,7 +190,7 @@ def _selected_fn(block_q):
                        m, l, acc, block_q=block_q))
 
 
-_plain_fn = jax.jit(window_moe.attend_tile_plain, static_argnums=(5,))
+_plain_fn = jax.jit(parts.attend_tile_plain, static_argnums=(5,))
 
 
 def _seen(pos0, c, first, tile, window):
@@ -384,7 +384,7 @@ def want(params, ids):
 def forced(monkeypatch):
     """The predicate says yes whatever the backend and the shapes; the
     kernel then runs in interpret mode."""
-    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(parts, "fused_attend", lambda c, tile, hd: True)
 
 
 def _executor(params, **kw):
@@ -421,7 +421,7 @@ def test_fused_update_over_a_tiled_context(params, ids, want, forced,
     """The context walked in four tiles of 16 slots, so that the carry
     passes from call to call and tiles past a query's position come
     last: chunked equals unchunked equals the reference."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", 16)
+    monkeypatch.setattr(parts, "CTX_TILE", 16)
     one, _ = _prefill(_executor(params), ids, 29, 32)
     ex = _executor(params)
     chunked, _ = _prefill(ex, ids, 29, 8)
@@ -435,14 +435,14 @@ def test_fused_update_over_a_tiled_context(params, ids, want, forced,
 
 def test_the_choice_is_made_from_backend_and_shapes_alone(monkeypatch):
     assert jax.default_backend() == "cpu"
-    assert not sparse_moe.fused_attend(2048, 1024, 128)
+    assert not parts.fused_attend(2048, 1024, 128)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert sparse_moe.fused_attend(2048, 1024, 128)
-    assert sparse_moe.fused_attend(64, 1024, 128)       # a short bucket
-    assert not sparse_moe.fused_attend(2048, 1024, 64)  # half a lane tile
-    assert not sparse_moe.fused_attend(2048, 1000, 128)   # nor a tile
-    assert not sparse_moe.fused_attend(
-        sparse_moe._FUSED_Q_BLOCK + 8, 1024, 128)
+    assert parts.fused_attend(2048, 1024, 128)
+    assert parts.fused_attend(64, 1024, 128)       # a short bucket
+    assert not parts.fused_attend(2048, 1024, 64)  # half a lane tile
+    assert not parts.fused_attend(2048, 1000, 128)   # nor a tile
+    assert not parts.fused_attend(
+        parts.FUSED_Q_BLOCK + 8, 1024, 128)
 
 
 def _chunk_spans(tracer, req):
@@ -486,7 +486,7 @@ def test_with_the_predicate_forced_they_say_fused(params, ids, forced):
 def test_ctx_tiles_is_the_programs_own_count(params, monkeypatch):
     """`note_chunk` against `sparse_moe_prefill_chunk`'s `n_tiles`: tiles
     up to the chunk's last padded row, capped at the table's."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", 16)
+    monkeypatch.setattr(parts, "CTX_TILE", 16)
     ps = _executor(params).programs               # max_len 64: 4 tiles
     said = [ps.note_chunk(pos0, clen, 8)["ctx_tiles"]
             for pos0, clen in ((0, 8), (8, 5), (9, 8), (40, 8), (60, 4))]

@@ -554,29 +554,68 @@ class TestEdgeTracing:
         """ISSUE 11 regression: a client BUSY-retry re-sends the SAME
         buffer, so the trace context (and its id) must survive — a new
         client_send hop is appended, never a fresh id. A fresh id per
-        attempt would shatter one request into unjoinable timelines."""
-        srv = EchoServer(service_ms=40.0, max_pending=16, max_inflight=1)
+        attempt would shatter one request into unjoinable timelines.
+
+        No clock decides the BUSY: the server (`max_inflight=1`) holds
+        another client's frame at a gate, so the traced client's first
+        offer is refused for as long as the gate is shut, and the test
+        opens it once it has seen the refusal. The traced client keeps
+        one frame in flight, where a retry is exact: the refused frame
+        is the one sent again."""
+        from nnstreamer_tpu.backends.custom import (
+            register_custom_easy, unregister_custom_easy)
+
+        gate, held = threading.Event(), threading.Event()
+
+        def serve(ts):
+            held.set()
+            assert gate.wait(60), "the test never opened the gate"
+            return ts
+
+        register_custom_easy("traffic_gated_echo", serve)
+        srv = nns.parse_launch(
+            "tensor_query_serversrc name=src id=9911 port=0 dims=8:1 "
+            "types=float32 max_pending=16 max_inflight=1 ! "
+            "tensor_filter framework=custom model=traffic_gated_echo ! "
+            "tensor_query_serversink id=9911")
+        gate.set()              # negotiation probes the model once
+        srv_rn = nns.PipelineRunner(srv).start()
+        runners = [srv_rn]
         try:
+            gate.clear(), held.clear()
+            port = srv.get("src").port
+            # the frame that fills the server: admitted, held at the gate
+            _, blocker = _client_pipe(port, None, n=1, max_in_flight=1,
+                                      timeout=60)
+            runners.append(blocker)
+            assert held.wait(30), "the blocking frame never reached service"
             pipe = nns.parse_launch(
                 f"appsrc name=src dims=8:1 types=float32 ! "
-                f"tensor_query_client name=qc port={srv.port} "
-                f"timeout=30 max_in_flight=2 error_policy=retry:10:30 "
+                f"tensor_query_client name=qc port={port} timeout=60 "
+                f"max_in_flight=1 error_policy=retry:12:20 "
                 f"! tensor_sink name=sink")
             rn = nns.PipelineRunner(pipe).start()
+            runners.append(rn)
             sent_ids = {}
-            for i in range(6):
+            for i in range(3):
                 buf = TensorBuffer.of(
                     np.full((8, 1), float(i), np.float32), pts=i)
                 sent_ids[i] = ensure_trace_ctx(buf.meta)["id"]
                 pipe.get("src").push(buf)
             pipe.get("src").end()
+            # retry:12:20 keeps offering for 80 s; the gate opens as
+            # soon as one offer has been refused
+            deadline = time.monotonic() + 30
+            while rn.stats()["qc"]["query_busy"] < 1:
+                assert time.monotonic() < deadline, "no offer was refused"
+                time.sleep(0.01)
+            gate.set()
             rn.wait(60)
+            blocker.wait(60)
             st = rn.stats()
-            rn.stop()
             res = pipe.get("sink").results
-            assert [r.pts for r in res] == list(range(6))
-            assert st["qc"]["query_busy"] >= 1   # else test is vacuous
-            retried_frames = 0
+            assert [r.pts for r in res] == list(range(3))
+            assert st["qc"]["query_busy"] >= 1 and st["qc"]["retries"] >= 1
             for r in res:
                 ctx = get_trace_ctx(r.meta)
                 assert ctx is not None, f"pts={r.pts} lost its context"
@@ -585,15 +624,18 @@ class TestEdgeTracing:
                 hop_names = [h["hop"] for h in ctx["hops"]]
                 assert hop_names.count("client_send") >= 1
                 assert "reply" in hop_names
-                spans = hop_spans(ctx["hops"])
-                if spans.get("retries"):
-                    retried_frames += 1
-            # at least one frame was BUSY-retried and its timeline
-            # shows it as extra client_send hops on ONE id
-            assert retried_frames >= 1
-            assert not srv.crashed()
+            # the refused frame's timeline shows the retry as extra
+            # client_send hops on ONE id
+            first = get_trace_ctx(res[0].meta)
+            assert hop_spans(first["hops"]).get("retries")
+            assert [h["hop"] for h in first["hops"]].count(
+                "client_send") >= 2
+            assert srv_rn._error is None
         finally:
-            srv.stop()
+            gate.set()
+            for r in reversed(runners):
+                r.stop()
+            unregister_custom_easy("traffic_gated_echo")
 
     def test_open_loop_trace_reports_hop_breakdown(self):
         r = run_against_echo(pattern="poisson", load_x=0.5, n=30,

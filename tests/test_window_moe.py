@@ -21,11 +21,10 @@ from nnstreamer_tpu.backends import pallas_ops                  # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
-from nnstreamer_tpu.llm import sparse_moe, window_moe           # noqa: E402
+from nnstreamer_tpu.llm import experts, parts, window_moe        # noqa: E402
 from nnstreamer_tpu.llm.engine import LLMEngine                 # noqa: E402
 from nnstreamer_tpu.llm.paged_cache import (                    # noqa: E402
     PagedKVCache, peak_demand, window_cap)
-from nnstreamer_tpu.llm.paged_model import _live_items          # noqa: E402
 from nnstreamer_tpu.runtime.tracing import Tracer               # noqa: E402
 from perfbench.references import sparse_moe_lm                  # noqa: E402
 from perfbench.references import window_moe_lm as ref           # noqa: E402
@@ -106,7 +105,7 @@ def test_chunks_then_decode_give_the_references_logits(bundle, params,
                                                        total, tile):
     # the tile is a static argument of the chunk program: a small one
     # makes the walks' bounds (first tile, end) do the work
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     ids = _prompt(total, seed=plen)
     ex = _executor(bundle)
     got, held = _serve(ex, ids, plen)
@@ -124,7 +123,7 @@ def forced(monkeypatch):
     """The predicate says yes whatever the backend and the shapes, and a
     program takes 2 queries of a chunk's bucket of 8, so that a walk has
     blocks of every kind; the kernel then runs in interpret mode."""
-    monkeypatch.setattr(sparse_moe, "fused_attend", lambda c, tile, hd: True)
+    monkeypatch.setattr(parts, "fused_attend", lambda c, tile, hd: True)
     monkeypatch.setattr(pallas_ops, "causal_block_q", lambda c, grp: 2)
 
 
@@ -134,7 +133,7 @@ def _qblocks_by_hand(pos0, bucket, tile, bq=2):
     loop over its (query, slot) pairs."""
     said = [0, 0, 0]
     for window in (WINDOW, WINDOW, 0):
-        first, end = window_moe.tile_span(pos0, bucket, 64, tile, window)
+        first, end = parts.tile_span(pos0, bucket, 64, tile, window)
         for j in range(first, end):
             for q0 in range(pos0, pos0 + bucket, bq):
                 pairs = [s <= q and (not window or s > q - window)
@@ -151,7 +150,7 @@ def test_the_walk_through_the_causal_kernel_gives_the_references_logits(
     a context past the window, tiles smaller than the window and as wide:
     the reference's logits, and the chunk's span says what the kernel's
     programs did, which adds up to the walks' trip counts x blocks."""
-    monkeypatch.setattr(sparse_moe, "_CTX_TILE", tile)
+    monkeypatch.setattr(parts, "CTX_TILE", tile)
     ids = _prompt(45, seed=33)
     tracer = Tracer(max_events=8192)
     ex = _executor(bundle, tracer=tracer, name="llm")
@@ -206,7 +205,7 @@ def test_padding_queries_past_the_tables_last_tile_attend_nothing():
     pools = [jnp.asarray(rng.normal(size=(1, 6, 4, nkv, hd)), jnp.float32)
              for _ in range(2)]
     qpos = 8 + jnp.arange(c)                           # 8..23: 16.. past it
-    span = window_moe.tile_span(8, c, 16, tile)
+    span = parts.tile_span(8, c, 16, tile)
     assert span == (0, 2)
     out = [window_moe.attend_tiles(q, qpos, tab, span, 0, *pools, window=0,
                                    fused=fused, tile=tile, dtype=jnp.float32)
@@ -234,12 +233,12 @@ def test_a_window_layer_alone_forgets_what_is_behind_its_window(params):
 
 
 def test_work_list_with_a_lower_bound():
-    """`_live_items` with `lo`: a row holds the chunks from lo // C on,
+    """`parts.live_items` with `lo`: a row holds the chunks from lo // C on,
     and each item says where its chunk's live slots begin."""
     tables = jnp.asarray(np.arange(24).reshape(2, 12) + 1, jnp.int32)
     pos = jnp.asarray([21, 6], jnp.int32)
     lo = jnp.asarray([14, 0], jnp.int32)
-    row, blocks, last, n_iter, first = _live_items(
+    row, blocks, last, n_iter, first = parts.live_items(
         tables, pos, 4, 1, 12, 4, lo=lo)
     # row 0: chunks 3, 4, 5 (positions 12-23); row 1: chunks 0, 1
     assert row[:5].tolist() == [0, 0, 0, 1, 1]
@@ -247,7 +246,7 @@ def test_work_list_with_a_lower_bound():
     assert last[:5].tolist() == [9, 5, 1, 6, 2]
     assert first[:5].tolist() == [2, -2, -6, 0, -4]
     assert int(n_iter) == 2 and (np.asarray(last[5:]) == -1).all()
-    plain = _live_items(tables, pos, 4, 1, 12, 4)
+    plain = parts.live_items(tables, pos, 4, 1, 12, 4)
     assert len(plain) == 4 and int(plain[3]) == 2       # 6 + 2 chunks
 
 
@@ -276,7 +275,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         # the program's layer, told the same share
         spec = dataclasses.replace(SPEC, experts_first=first,
                                    experts_held=2)
-        y, counts, away = sparse_moe._expert_layer(
+        y, counts, away = experts.expert_layer(
             share, u, jnp.ones((24,), bool), spec, jnp.float32)
         assert np.abs(np.asarray(y) - np.asarray(parts[-1])).max() < TOL
         assert int(counts.sum()) + int(away) == 24 * 2
@@ -301,7 +300,7 @@ def test_router_bias_is_in_the_choice_not_in_the_weights():
 
 
 def _old_expert_layer(blk, g, live, spec, dtype):
-    """`sparse_moe._expert_layer` as it was before it learned of shares
+    """`experts.expert_layer` as it was before it learned of shares
     and sigmoid scores (PR 38)."""
     n, d = g.shape
     ne, k, f = spec.n_experts, spec.experts_per_tok, spec.expert_width
@@ -334,7 +333,7 @@ def test_the_sparse_expert_familys_layer_is_bit_for_bit_as_it_was(n, real):
     g = jnp.asarray(np.random.default_rng(n).normal(size=(n, 64)),
                     jnp.float32)
     live = jnp.arange(n) < real
-    new = jax.jit(lambda b, x, lv: sparse_moe._expert_layer(
+    new = jax.jit(lambda b, x, lv: experts.expert_layer(
         b, x, lv, spec, jnp.float32))(blk, g, live)
     old = jax.jit(lambda b, x, lv: _old_expert_layer(
         b, x, lv, spec, jnp.float32))(blk, g, live)
