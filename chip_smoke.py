@@ -50,6 +50,19 @@ weights, frames and prompts:
                    serves on this host's CPU device, once with chunks of 8
                    (the absorbed form) and once with chunks of 32 (the
                    expanded form);
+- ``llm_delta_moe`` the delta family (llm/delta_moe.py) at a tiny size
+                   (hidden 64, layers K K L K L: KDA of 2 heads of 8 with a
+                   convolution over 4 tokens, latent attention of 4 heads of
+                   8 + 4 | 8 through a latent of 16 with no query rank and
+                   no rope, blocks of 4, the first layer dense, then a
+                   shared expert beside 4 held of 16 routed experts, 4 a
+                   token, sigmoid scores, float32): chunked prefill (runs of
+                   the closed form, states and tails handed chunk to chunk
+                   by slot) and decode over both kinds of cache through the
+                   same element on the chip serve the tokens the same engine
+                   serves on this host's CPU device, once with chunks of 8
+                   and once with chunks of 32 (runs of 16, the latent
+                   layers' expanded form);
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -975,6 +988,137 @@ def leg_llm_latent_moe() -> dict:
     return out
 
 
+DELTA = dict(d=64, kinds="KKLKL", kda_heads=2, kda_dim=8, conv=4, heads=4,
+             kv_rank=16, nope=8, rope=4, v=8, dense_width=160, width=32,
+             experts=16, first=4, held=4, per_tok=4, vocab=256)
+
+
+def _delta_bundle(device):
+    """Seeded float32 weights of the tiny delta-family model on `device`,
+    in the family's hand-over layout, with its description."""
+    import jax
+    import numpy as np
+
+    from nnstreamer_tpu.backends.xla import ModelBundle
+    from nnstreamer_tpu.llm.spec import DELTA_MOE, KDA, LATENT, LMSpec
+
+    c = DELTA
+    rng = np.random.default_rng(29)
+
+    def w(*shape):
+        lim = (6.0 / (shape[-2] + shape[-1])) ** 0.5
+        return rng.uniform(-lim, lim, shape).astype(np.float32)
+
+    ones = lambda n: np.ones((n,), np.float32)          # noqa: E731
+    h, hd, r = c["heads"], c["kda_heads"] * c["kda_dim"], c["kda_dim"]
+
+    def layer(kind, dense):
+        out = dict(ln1=ones(c["d"]), ln2=ones(c["d"]))
+        if kind == "K":
+            out.update(
+                wqkv=w(c["d"], 3 * hd),
+                conv=rng.uniform(-0.5, 0.5, (c["conv"], 3 * hd))
+                .astype(np.float32),
+                wfa=w(c["d"], r), wfb=w(r, hd),
+                dt_bias=rng.uniform(-4.0, -1.0, (hd,)).astype(np.float32),
+                a_log=np.log(rng.uniform(1.0, 16.0, (c["kda_heads"],)))
+                .astype(np.float32),
+                wb=w(c["d"], c["kda_heads"]), wga=w(c["d"], r),
+                wgb=w(r, hd), o_norm=ones(c["kda_dim"]), wo=w(hd, c["d"]))
+        else:
+            out.update(wq=w(c["d"], h * (c["nope"] + c["rope"])),
+                       wkva=w(c["d"], c["kv_rank"] + c["rope"]),
+                       kv_norm=ones(c["kv_rank"]),
+                       wkvb=w(c["kv_rank"], h * (c["nope"] + c["v"])),
+                       wo=w(h * c["v"], c["d"]))
+        if dense:
+            out.update(wi=w(c["d"], 2 * c["dense_width"]),
+                       wd=w(c["dense_width"], c["d"]))
+            return out
+        out.update(router=w(c["d"], c["experts"]),
+                   router_bias=rng.uniform(-0.02, 0.02, (c["experts"],))
+                   .astype(np.float32),
+                   ewi=w(c["held"], c["d"], 2 * c["width"]),
+                   ewd=w(c["held"], c["width"], c["d"]),
+                   swi=w(c["d"], 2 * c["width"]), swd=w(c["width"], c["d"]))
+        return out
+
+    params = {"embed": w(c["vocab"], c["d"]),
+              "blocks": [layer(k, i == 0) for i, k in enumerate(c["kinds"])],
+              "ln_f": ones(c["d"]), "head": w(c["d"], c["vocab"])}
+    spec = LMSpec(family=DELTA_MOE, n_heads=h, head_dim=c["kda_dim"],
+                  lin_heads=c["kda_heads"], conv_kernel=c["conv"],
+                  q_rank=0, roped=False,
+                  layer_kinds=tuple(KDA if k == "K" else LATENT
+                                    for k in c["kinds"]),
+                  kv_rank=c["kv_rank"], nope_dim=c["nope"],
+                  rope_dim=c["rope"], v_dim=c["v"], dense_layers=1,
+                  dense_width=c["dense_width"], shared_width=c["width"],
+                  n_experts=c["experts"], experts_per_tok=c["per_tok"],
+                  expert_width=c["width"], score_fn="sigmoid",
+                  route_scale=2.446, experts_first=c["first"],
+                  experts_held=c["held"], norm_eps=1e-5)
+    return ModelBundle(fn=None, lm=spec, params=jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, device), params))
+
+
+def leg_llm_delta_moe() -> dict:
+    """As `leg_llm_latent_moe`, for the family that keeps two kinds of
+    cache: once with chunks of 8 (one run of the closed form a chunk, the
+    latent layers absorbed), once with chunks of 32 (runs of 16, the
+    latent layers expanded); decode through the states and tails by slot."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.llm import delta_moe
+    from nnstreamer_tpu.llm.engine import LLMEngine
+    from nnstreamer_tpu.serving.store import get_store
+
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, DELTA["vocab"], size=n).astype(np.int32)
+               for n in (5, 12, 29, 41)]
+    cpu = jax.devices("cpu")[0]
+    was, run = jax.config.jax_default_matmul_precision, delta_moe.RUN
+    jax.config.update("jax_default_matmul_precision", "highest")
+    delta_moe.RUN = 16
+    out = {}
+    try:
+        get_store().register("chip_smoke_delta_moe",
+                             _delta_bundle(jax.devices()[0]))
+        for form, chunk in (("absorbed", 8), ("expanded", 32)):
+            serving = dict(block_size=4, num_blocks=96, max_len=64,
+                           prefill_chunk=chunk)
+            with jax.default_device(cpu):
+                eng = LLMEngine(_delta_bundle(cpu), dtype=jnp.float32,
+                                max_batch=8, **serving)
+                reqs = [eng.submit(p, req_id=f"req{i}",
+                                   max_new_tokens=LLM_NEW_TOKENS)
+                        for i, p in enumerate(prompts)]
+                eng.drain()
+                want = {r.req_id: list(r.tokens) for r in reqs}
+                eng.executor.close()
+            toks, stats = _run_llm("store://chip_smoke_delta_moe", prompts,
+                                   dtype="float32", paged_kernel="xla",
+                                   **serving)
+            ex, cache = stats["executor"], stats["cache"]
+            assert ex["family"] == "delta_moe", ex
+            assert ex["expert_pairs_held"] > 0 < ex["expert_pairs_away"], ex
+            assert (ex["latents_expanded"] > 0) == (form == "expanded"), ex
+            assert ex["state_bytes_rw"] > 0 < ex["tail_bytes_rw"], ex
+            assert cache["pools"] == 4 and cache["blocks_used"] == 0, cache
+            assert cache["state_slots_used"] == 0, cache
+            assert toks == want, \
+                f"{form}: greedy tokens differ: chip {toks} vs cpu {want}"
+            out[f"chunk_prefills_{form}"] = ex["chunk_prefills"]
+            out[f"delta_runs_{form}"] = ex["delta_runs"]
+            out["tokens"] = out.get("tokens", 0) + stats["tokens_out"]
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+        delta_moe.RUN = run
+    return out
+
+
 # -- four chips --------------------------------------------------------------
 
 def leg_multichip(model: str, ref) -> dict:
@@ -1096,6 +1240,7 @@ def main() -> int:
     leg("llm_hybrid", leg_llm_hybrid)
     leg("llm_window_moe", leg_llm_window_moe)
     leg("llm_latent_moe", leg_llm_latent_moe)
+    leg("llm_delta_moe", leg_llm_delta_moe)
     if dev["count"] >= 4 and ref:
         leg("multichip", leg_multichip, model, ref)
     else:

@@ -272,9 +272,9 @@ class TensorLLM(Element):
         return []
 
     def _window_s(self) -> float:
-        # a non-positive window would starve the input channel (an
-        # always-past deadline makes the scheduler fire timers forever
-        # without reading input) — clamp to one scheduler-visible tick
+        # a non-positive window would leave every deadline past (the
+        # scheduler then reads what is queued, and no more, between two
+        # fires) — clamp to one scheduler-visible tick
         return max(0.05, float(self.props["admit_window_ms"])) * 1e-3
 
     def next_deadline(self) -> Optional[float]:

@@ -7,9 +7,11 @@ The prefill/decode split and the streaming transformer
   slot count (not max_len × batch) bounds HBM.
 - `paged_model`  — prefill/decode math over the paged pool, formulated
   for token-for-token parity with `transformer.generate`: the dense
-  family; `sparse_moe`, `hybrid_lm`, `window_moe`, `latent_moe` the others.
+  family; `sparse_moe`, `hybrid_lm`, `window_moe`, `latent_moe`,
+  `delta_moe` the others.
 - `parts`, `experts` — what two or more families use: no family module
-  imports another.
+  imports another (but `delta_moe`, whose latent layers are `latent_moe`'s
+  whole layers under public names).
 - `families`     — one program set a model family (`spec.LMSpec.family`):
   which programs serve it, their arguments, refusals and counters.
 - `engine`       — the continuous-batching scheduler loop: admit,

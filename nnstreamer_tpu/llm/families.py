@@ -23,9 +23,10 @@ family, a kernel and a kind of call about:
   (`check_prompt`);
 - `idx_dim`: the width a token needs of the pool's blocks beyond K and V,
   and `cache_kw(n_layers)`: which layers keep K and V, the pools the
-  family adds to `PagedKVCache` by slot (a fixed-size state and
-  compressed keys a sequence, whose slot the executor hands `chunk_args`
-  and `decode_args` as `slot` / `slots`), and the window layers' pools
+  family adds to `PagedKVCache` by slot (a fixed-size state and rows of
+  compressed keys or of convolutions' tails a sequence, whose slot the
+  executor hands `chunk_args` and `decode_args` as `slot` / `slots`), and
+  the window layers' pools
   under a table a sequence of their own (handed as `window`, last);
   `values: False` where the family keeps no V pool, and `head_dim`, the
   width of a row of the `k` pool (a head's, or the latent family's one
@@ -47,8 +48,8 @@ import numpy as np
 
 from nnstreamer_tpu.core.errors import BackendError
 from nnstreamer_tpu.llm.spec import (
-    DENSE, FULL, HYBRID, LATENT_MOE, LINEAR, SPARSE, SPARSE_MOE, WINDOW,
-    WINDOW_MOE)
+    DELTA_MOE, DENSE, FULL, HYBRID, KDA, LATENT, LATENT_MOE, LINEAR, SPARSE,
+    SPARSE_MOE, WINDOW, WINDOW_MOE)
 
 
 def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
@@ -513,7 +514,7 @@ class HybridSet(ChunkOnlySet):
         return {"n_layers": heads, "n_kv": 1,
                 "state_shape": (self.n_linear, s.lin_heads, s.head_dim,
                                 s.head_dim),
-                "ckey_shape": (heads, self.max_blocks, s.head_dim)}
+                "row_shape": (heads, self.max_blocks, s.head_dim)}
 
     def program(self, kind: str) -> Program:
         from nnstreamer_tpu.llm import hybrid_lm
@@ -774,11 +775,22 @@ class LatentMoESet(HeldExpertsSet):
 
     def refusal(self, params: dict):
         spec, layers = self.spec, len(params["blocks"])
-        if min(spec.q_rank, spec.kv_rank, spec.nope_dim, spec.v_dim) < 1 \
-                or spec.rope_dim < 2 or spec.rope_dim % 2:
+        if min(spec.kv_rank, spec.nope_dim, spec.v_dim) < 1 \
+                or spec.q_rank < 0 or spec.rope_dim < 2 or spec.rope_dim % 2:
             return (f"ranks {spec.q_rank} / {spec.kv_rank} and head widths "
                     f"{spec.nope_dim} + {spec.rope_dim} / {spec.v_dim}: "
-                    f"every one has to be set, the roped width even")
+                    f"every one has to be set (a q_rank of 0 is a query "
+                    f"with no low-rank step), the roped width even")
+        # a query with no rank goes through one matrix, `wq`, where a
+        # ranked one goes through `wqa`, a norm and `wqb`
+        need = ("wqa", "q_norm", "wqb") if spec.q_rank else ("wq",)
+        short = sorted({k for b in self._latent_blocks(params)
+                        for k in need if k not in b})
+        if short:
+            return (f"q_rank={spec.q_rank}: a latent layer's query needs "
+                    f"{' and '.join(need)} (D -> "
+                    f"{'q_rank -> ' if spec.q_rank else ''}heads x (nope_dim "
+                    f"+ rope_dim)), and the bundle has no {', '.join(short)}")
         if not 0 <= spec.dense_layers < layers:
             return (f"dense_layers={spec.dense_layers} of {layers}: at "
                     f"least one layer has to be an expert layer")
@@ -793,14 +805,18 @@ class LatentMoESet(HeldExpertsSet):
                     f"be equal and the chosen ones hold a token's experts")
         return None
 
+    def _latent_blocks(self, params: dict) -> list:
+        """The layers that attend in the latent: all of this family's."""
+        return params["blocks"]
+
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
-        layers = len(params["blocks"])
         # the pool's row: one latent a token and, beside it, its roped key
         self.n_kv, self.head_dim = 1, int(spec.kv_rank)
         self.idx_dim = int(spec.rope_dim)
-        self.layers = layers
-        self.expert_layers = layers - spec.dense_layers
+        #: the layers that keep a row of the pool a token
+        self.layers = len(self._latent_blocks(params))
+        self.expert_layers = len(params["blocks"]) - spec.dense_layers
         # kept tracer on or off. Decode steps: live context the steps
         # attended, pool slots a layer read for it (`note_decode` says
         # which under each walk; a slot is kv_rank + rope_dim values), the
@@ -888,6 +904,130 @@ class LatentMoESet(HeldExpertsSet):
                 **_note_qblocks(self, pos0, bucket, 1, CTX_TILE, walks)}
 
 
+class DeltaMoESet(LatentMoESet):
+    """The decoder whose layers are gated delta-rule linear attention or
+    latent attention (llm/delta_moe.py): one prefill program, its chunk;
+    two kinds of cache in one model: by block, the latent layers' two
+    pools (`LatentMoESet`'s, read by its layer programs in its two
+    forms); by slot, a float32 state a KDA layer and the tails of its
+    three convolutions; each call returns, an expert layer, the tokens
+    each held expert got and the pairs routed to experts that are not
+    held, beside its logits."""
+
+    family = DELTA_MOE
+
+    NO_SHARDS = ("a sequence's state and tails live by slot on one chip and "
+                 "the latent pool has no head axis to shard along: neither "
+                 "has a sharding rule yet")
+    NO_PALLAS = ("that names the dense family's twin programs; the delta "
+                 "rule is plain XLA and the latent layers take their decode "
+                 "kernel from the backend and the pools' widths alone "
+                 "(`latent_moe.fused_decode`)")
+    NO_W8A8 = ("the delta rule's state and the products that feed it are "
+               "float32, and its grouped expert products are float only")
+
+    def _latent_blocks(self, params: dict) -> list:
+        return [b for b, kind in zip(params["blocks"], self.spec.layer_kinds)
+                if kind == LATENT]
+
+    def refusal(self, params: dict):
+        spec, kinds = self.spec, self.spec.layer_kinds
+        if len(kinds) != len(params["blocks"]):
+            return (f"a bundle of {len(params['blocks'])} layers under a "
+                    f"spec that names {len(kinds)}")
+        if set(kinds) != {KDA, LATENT}:
+            return (f"layer kinds {sorted(set(kinds))}: it serves layers of "
+                    f"both kinds, '{KDA}' and '{LATENT}'")
+        if min(spec.lin_heads, spec.head_dim) < 1 or spec.conv_kernel < 2:
+            return (f"{spec.lin_heads} KDA heads of {spec.head_dim} and a "
+                    f"convolution over {spec.conv_kernel} tokens: every one "
+                    f"has to be set, the convolution over at least 2 (it "
+                    f"carries a tail)")
+        return super().refusal(params)
+
+    def __init__(self, spec, *, params: dict, **given):
+        super().__init__(spec, params=params, **given)
+        self.n_kda = spec.layer_kinds.count(KDA)
+        width = 3 * spec.lin_heads * spec.head_dim
+        #: bytes of one sequence's state, and of its tails, all KDA layers
+        self.state_bytes = (self.n_kda * spec.lin_heads * spec.head_dim
+                            * spec.head_dim * 4)
+        self.tail_bytes = (self.n_kda * (spec.conv_kernel - 1) * width
+                           * np.dtype(self.kw["dtype"]).itemsize)
+        # kept tracer on or off, beside the latent set's. Decode steps and
+        # chunks: state and tail bytes read and written (all KDA layers),
+        # the rows whose state a call advanced. Chunks: those that started
+        # from zero, and the runs of the closed form a KDA layer took
+        self.counters.update(dict.fromkeys((
+            "state_rows", "state_bytes_rw", "tail_bytes_rw", "chunks_fresh",
+            "delta_runs"), 0))
+
+    def cache_kw(self, n_layers: int) -> dict:
+        # by block, the latent layers' rows alone; by slot, the KDA
+        # layers' states and the last inputs of their convolutions,
+        s = self.spec
+        return {"n_layers": self.layers, "n_kv": 1, "values": False,
+                "state_shape": (self.n_kda, s.lin_heads, s.head_dim,
+                                s.head_dim),
+                # one row a slot: the K - 1 inputs side by side
+                "row_shape": (self.n_kda, 1, (s.conv_kernel - 1)
+                              * 3 * s.lin_heads * s.head_dim)}
+
+    def program(self, kind: str) -> Program:
+        from nnstreamer_tpu.llm import delta_moe
+
+        if kind == "chunk":
+            return Program(delta_moe.delta_moe_prefill_chunk,
+                           ("spec", "dtype", "by_block", "fused",
+                            "expanded", "tile", "run"), (7, 8, 9, 10))
+        return Program(delta_moe.delta_moe_decode_step,
+                       ("spec", "dtype"), (6, 7, 8, 9))
+
+    def chunk_args(self, params, ids, pos0, blk_idx, blk_off, tab, last,
+                   pools, slot=None, window=None) -> tuple:
+        return (params, ids, pos0, blk_idx, blk_off, tab, slot, *pools,
+                last)
+
+    def decode_args(self, params, cur, tab, pos, n: int, pools,
+                    slots=None, window=None) -> tuple:
+        return (params, cur, tab, pos, np.int32(n), slots, *pools)
+
+    def chunk_kw(self, pos0: int, bucket: int) -> dict:
+        # the closed form's run, as the walk's tile: a static argument
+        from nnstreamer_tpu.llm.delta_moe import RUN
+
+        return dict(super().chunk_kw(pos0, bucket), run=RUN)
+
+    def _note_state(self, rows: int) -> dict:
+        said = {"state_rows": rows,
+                "state_bytes_rw": 2 * rows * self.state_bytes,
+                "tail_bytes_rw": 2 * rows * self.tail_bytes}
+        for name, n in said.items():
+            self.counters[name] += n
+        return said
+
+    def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
+        """Each live row's state and tails are read and written once a
+        KDA layer (state_rows, state_bytes_rw, tail_bytes_rw); a latent
+        layer attends its whole context (`LatentMoESet.note_decode`)."""
+        return {**self._note_state(n), **super().note_decode(pos_a, n)}
+
+    def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
+        """One sequence's state and tails read and written once a KDA
+        layer (counted so for a `fresh` chunk too, which starts from zero
+        and reads neither), in `delta_runs` runs of the closed form a
+        layer (the program's own count, from the run `chunk_kw` hands
+        it); the latent layers' walk as `LatentMoESet.note_chunk`."""
+        from nnstreamer_tpu.llm.delta_moe import RUN
+
+        fresh = int(pos0) == 0
+        runs = -(-bucket // min(RUN, bucket))
+        self.counters["chunks_fresh"] += fresh
+        self.counters["delta_runs"] += runs
+        return {**self._note_state(1), "fresh": fresh, "delta_runs": runs,
+                **super().note_chunk(pos0, clen, bucket)}
+
+
 def _sparse_reads(spec, qpos: np.ndarray, slots: int) -> dict:
     """What the queries at positions `qpos` score, select and attend in
     one sparse layer of the hybrid family, a KV head; `slots` pool slots
@@ -914,7 +1054,8 @@ def _chunk_reads(spec, pos0: int, clen: int, slots: int) -> dict:
 #: `LMSpec.family` -> its program set
 FAMILIES: Dict[str, type] = {DENSE: DenseSet, SPARSE_MOE: SparseMoESet,
                              HYBRID: HybridSet, WINDOW_MOE: WindowMoESet,
-                             LATENT_MOE: LatentMoESet}
+                             LATENT_MOE: LatentMoESet,
+                             DELTA_MOE: DeltaMoESet}
 
 
 def program_set(spec, *, name: str, **given):

@@ -164,17 +164,17 @@ def _rope(x, pos, spec: LMSpec):
 def _project(blk, x, pos, spec: LMSpec, dtype):
     """x (N, 1, D) at positions pos (N,): q_nope (N, H, nope), q_pe (N,
     H, rope) roped, the latent c (N, kv_rank) normed, k_pe (N, rope)
-    roped. Rows are independent: a decode batch and a chunk's tokens take
-    the same path."""
+    roped (`_ranked`, `_turn`: or neither). Rows are independent: a
+    decode batch and a chunk's tokens take the same path."""
     n = x.shape[0]
     u = norm(blk["ln1"], x, spec, dtype)
-    cq = norm(blk["q_norm"], proj(blk, "wqa", u, dtype), spec, dtype)
-    q = proj(blk, "wqb", cq, dtype).reshape(
+    cq = _ranked(blk, u, spec, dtype)
+    q = proj(blk, "wqb" if spec.q_rank else "wq", cq, dtype).reshape(
         n, spec.n_heads, spec.nope_dim + spec.rope_dim)
     kv = proj(blk, "wkva", u, dtype)[:, 0]
     c = norm(blk["kv_norm"], kv[:, :spec.kv_rank], spec, dtype)
-    return (q[..., :spec.nope_dim], _rope(q[..., spec.nope_dim:], pos, spec),
-            c, _rope(kv[:, spec.kv_rank:], pos, spec))
+    return (q[..., :spec.nope_dim], _turn(q[..., spec.nope_dim:], pos, spec),
+            c, _turn(kv[:, spec.kv_rank:], pos, spec))
 
 
 def _wkvb(blk, spec: LMSpec, dtype):
@@ -477,3 +477,30 @@ def latent_moe_prefill_chunk(params, ids, pos0, blk_idx, blk_off, table,
     logits = finish(params, x[last_idx, 0][None, :], dtype,
                     spec.norm_eps)[0]
     return logits, jnp.stack(load), k_pool, i_pool
+
+
+# -- what the spec may leave out, and the layers under public names ------------
+# Below every kernel's call site: a kernel's serialized module carries the
+# lines of its call sites, so a line added above them costs the cells that
+# hold the kernel one compile (PERF.md section 6, PR 44).
+
+def _ranked(blk, u, spec: LMSpec, dtype):
+    """What the query's heads are made from: the normed `q_rank` values
+    ``RMSNorm(u Wqa)`` (through ``Wqb``), or under `q_rank` 0 the layer's
+    input itself (through ``Wq``: no low-rank step and no norm)."""
+    if not spec.q_rank:
+        return u
+    return norm(blk["q_norm"], proj(blk, "wqa", u, dtype), spec, dtype)
+
+
+def _turn(x, pos, spec: LMSpec):
+    """`_rope`, or x as it is where the model turns nothing (`roped`
+    false: the `rope_dim` values ride beside the others unturned)."""
+    return _rope(x, pos, spec) if spec.roped else x
+
+
+#: a whole latent layer of a decode step and of a chunk (attention, then the
+#: MLP `experts.shared_mlp` makes of `dense`), for a family that has such
+#: layers among others (llm/delta_moe.py); jitted with the layer's index
+#: in the pools an argument, as this family's own programs call them
+decode_layer, chunk_layer = _decode_layer, _chunk_layer

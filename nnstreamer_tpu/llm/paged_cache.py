@@ -27,12 +27,15 @@ pow2 padding never corrupts a live sequence's cache.
 
 A model may keep a second kind of state, which is not paged: a *slot*
 a sequence, under an allocator of its own, of a pool of fixed-size
-states (llm/hybrid_lm.py's linear layers) and of a pool of compressed
-keys, one a block of the sequence's table (its sparse layers; read
-whole and in order by every query, so kept in a row, not gathered by
-block). A sequence is admitted with its blocks and its slot or with
-neither (`PagedKVCache.reserve`); slot 0 is the scratch slot, as block 0
-is the scratch block.
+float32 states (llm/hybrid_lm.py's linear layers, llm/delta_moe.py's
+delta-rule layers) and of a pool of rows in the compute type, read whole
+and in order and so kept by slot, not gathered by block: compressed
+keys, one a block of the sequence's table (llm/hybrid_lm.py's sparse
+layers), or the tails of short convolutions, the last few inputs of
+each (llm/delta_moe.py, which keeps its latent layers' rows by block
+beside them: two kinds of cache in one model). A sequence is admitted
+with its blocks and its slot or with neither (`PagedKVCache.reserve`);
+slot 0 is the scratch slot, as block 0 is the scratch block.
 
 A model whose layers are of two kinds, some attending a window of the
 newest positions and some the whole context (llm/window_moe.py), keeps a
@@ -201,15 +204,17 @@ class PagedKVCache:
     onward). `idx_dim=0` (every dense model) makes no third pool.
 
     `n_layers` counts the layers that keep K and V (all of a dense
-    model's; the sparse layers' KV heads of llm/hybrid_lm.py). Two more
-    pools serve that family, both indexed by a sequence's *slot*, one of
-    `state_slots` from `state_alloc` (slot 0 is scratch: padding rows
-    and warm-up calls write there). `state_shape` (layers, heads, dk,
-    dv): the state pool (layers, state_slots + 1, heads, dk, dv),
-    float32 whatever `dtype` is. `ckey_shape` (layers, entries, width):
-    the compressed keys `ck` (layers, state_slots + 1, entries, width)
-    in `dtype`, entry m of a slot belonging to block m of its
-    sequence's table.
+    model's; the sparse layers' KV heads of llm/hybrid_lm.py; the latent
+    layers of llm/delta_moe.py). Two more pools serve those two
+    families, both indexed by a sequence's *slot*, one of `state_slots`
+    from `state_alloc` (slot 0 is scratch: padding rows and warm-up
+    calls write there). `state_shape` (layers, heads, dk, dv): the state
+    pool (layers, state_slots + 1, heads, dk, dv), float32 whatever
+    `dtype` is. `row_shape` (layers, entries, width): the by-slot rows
+    `slot_rows` (layers, state_slots + 1, entries, width) in `dtype`:
+    compressed keys, entry m of a slot belonging to block m of its
+    sequence's table, or convolutions' tails, the sequence's last
+    `entries` inputs, oldest first.
 
     `window_layers` > 0 (llm/window_moe.py): a second pair of pools, `wk`
     and `wv`, (window_layers, window_blocks, block_size, n_kv, head_dim),
@@ -231,7 +236,7 @@ class PagedKVCache:
     def __init__(self, *, num_blocks: int, block_size: int, n_layers: int,
                  n_kv: int, head_dim: int, idx_dim: int = 0, dtype=None,
                  placer=None, state_shape: tuple = (),
-                 ckey_shape: tuple = (), state_slots: int = 0,
+                 row_shape: tuple = (), state_slots: int = 0,
                  window_layers: int = 0, window_blocks: int = 0,
                  window: int = 0, values: bool = True):
         import jax.numpy as jnp
@@ -253,8 +258,8 @@ class PagedKVCache:
              self.block_size // self.idx_pack,
              self.idx_pack * self.idx_dim), self.dtype) \
             if self.idx_dim else None
-        self.ck = self.state = self.state_alloc = None
-        if state_shape or ckey_shape:
+        self.slot_rows = self.state = self.state_alloc = None
+        if state_shape or row_shape:
             self.state_alloc = BlockAllocator(int(state_slots) + 1)
 
         def by_slot(shape, dtype):
@@ -263,8 +268,8 @@ class PagedKVCache:
 
         if state_shape:
             self.state = by_slot(state_shape, jnp.float32)
-        if ckey_shape:
-            self.ck = by_slot(ckey_shape, self.dtype)
+        if row_shape:
+            self.slot_rows = by_slot(row_shape, self.dtype)
         self.wk = self.wv = self.window_alloc = None
         self.window = int(window)
         if window_layers:
@@ -301,12 +306,12 @@ class PagedKVCache:
     def tokens_capacity(self) -> int:
         return self.allocator.total * self.block_size
 
-    _POOLS = ("k", "v", "idx", "ck", "state", "wk", "wv")
+    _POOLS = ("k", "v", "idx", "slot_rows", "state", "wk", "wv")
 
     def pools(self) -> tuple:
         """The pools there are: k, v (where the model keeps values),
-        then those the model adds, in the order indexer keys, compressed
-        keys, state, the window layers' K and V."""
+        then those the model adds, in the order indexer keys, the rows
+        kept by slot, state, the window layers' K and V."""
         return tuple(p for p in (getattr(self, n) for n in self._POOLS)
                      if p is not None)
 
@@ -424,9 +429,9 @@ class PagedKVCache:
     @property
     def state_slot_bytes(self) -> int:
         """Bytes one slot holds: a sequence's state over all layers that
-        keep one, and its compressed keys."""
+        keep one, and its rows (compressed keys, or convolutions' tails)."""
         return sum(int(p.nbytes) // p.shape[1]
-                   for p in (self.state, self.ck) if p is not None)
+                   for p in (self.state, self.slot_rows) if p is not None)
 
     def resident_bytes(self) -> int:
         return sum(int(p.nbytes) for p in self.pools())
@@ -446,6 +451,9 @@ class PagedKVCache:
             out["state_bytes"] = self.state_slot_bytes * (
                 self.state_alloc.total + 1)
             out["state_slot_bytes"] = self.state_slot_bytes
+            # of which the rows kept by slot (compressed keys or tails)
+            out["slot_row_bytes"] = 0 if self.slot_rows is None else (
+                int(self.slot_rows.nbytes) // self.slot_rows.shape[1])
         if self.window_alloc is not None:
             w = self.window_alloc
             out["window"] = {

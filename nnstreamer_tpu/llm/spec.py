@@ -38,10 +38,18 @@ WINDOW_MOE = "window_moe"
 #: beside this chip's share of routed experts chosen inside groups
 #: (llm/latent_moe.py)
 LATENT_MOE = "latent_moe"
+#: the decoder whose layers are gated delta-rule linear attention (a
+#: float32 state and the tails of three short convolutions a sequence, by
+#: slot) or latent attention with no rope and no query rank (a pool of
+#: one compressed row a token, by block); a dense MLP in its first layers
+#: and then a shared expert beside this chip's share of the routed experts
+#: (llm/delta_moe.py)
+DELTA_MOE = "delta_moe"
 #: the kinds of layer `LMSpec.layer_kinds` names: the hybrid family's two,
-#: then the window family's two
+#: the window family's two, the delta family's two
 LINEAR, SPARSE = "linear", "sparse"
 WINDOW, FULL = "window", "full"
+KDA, LATENT = "kda", "latent"
 
 
 @dataclass(frozen=True)
@@ -139,3 +147,16 @@ class LMSpec:
     yarn_beta_slow: float = 1.0
     yarn_mscale: float = 0.0
     yarn_mscale_all_dim: float = 0.0
+    # q_rank 0: the query has no low-rank step, u Wq straight to the heads
+    # (no norm). roped False: the rope_dim values of a query and of the
+    # shared key ride unturned (no rotation anywhere)
+    roped: bool = True
+    # the delta family (layer_kinds of KDA and LATENT). A KDA layer:
+    # lin_heads heads of head_dim; q, k and v each through a causal
+    # depthwise convolution over conv_kernel tokens and SiLU (a sequence
+    # carries the last conv_kernel - 1 inputs of each: its tails); q and k
+    # L2-normed a head; a decay a channel and an output gate, each
+    # through a low-rank pair whose rank the weights say; a state of
+    # head_dim x head_dim a head, float32, updated by the delta rule. A
+    # LATENT layer is the latent family's, with the fields above
+    conv_kernel: int = 0

@@ -24,7 +24,10 @@ Host-path design (docs/performance.md):
   `queue.Queue`s: consumers wake on enqueue, producers on dequeue —
   no 100 ms poll floor, no idle CPU, and teardown (`Channel.close()`)
   wakes every waiter unconditionally. Timer elements (`next_deadline()`)
-  get a deadline-bounded wait instead of a fixed 0.1 s tick.
+  get a deadline-bounded wait instead of a fixed 0.1 s tick; a timer
+  found already due fires after what the channel held at that instant
+  is read, and no more (an element whose step outlasts its own window,
+  `tensor_llm` at many rows, would else never read its input).
 - **Chain fusion** ([runtime] chain_fusion, default on): maximal linear
   runs of cheap single-in/single-out elements with `error-policy=fail`
   (converter→transform→decoder chains) execute in ONE worker thread
@@ -1300,6 +1303,9 @@ class PipelineRunner:
         # consumed, in order, before the channel is touched again, and
         # never re-enter a window — ordering is preserved by construction
         pending: deque = deque()
+        # messages still to read before a timer found due may fire (None:
+        # no timer is due)
+        due_reads: Optional[int] = None
         try:
             while not self._stop_evt.is_set():
                 # deadline-aware wait: an element holding half-assembled
@@ -1309,17 +1315,29 @@ class PipelineRunner:
                 # even when no further buffer ever arrives, and an idle
                 # element sleeps until woken by an enqueue or teardown
                 deadline = elem.next_deadline()
-                if deadline is not None:
-                    now = time.perf_counter()
-                    if now >= deadline:
+                now = time.perf_counter() if deadline is not None else 0.0
+                if deadline is None or now < deadline:
+                    due_reads = None
+                else:
+                    # a timer already due: what the channel holds at this
+                    # instant is read first (every deadline of an element
+                    # whose step's emissions outlast its window is past,
+                    # and its input must still be read), and no more than
+                    # that, so a steady stream cannot hold the timer back
+                    if due_reads is None:
+                        due_reads = ch.qsize()
+                    if due_reads:
+                        due_reads -= 1
+                    else:
+                        due_reads = None
                         stats.timer_fires += 1
                         if not tr.active:
                             for sp, b in elem.on_timer():
                                 self._emit(elem, sp, b)
                             continue
                         # input_depth: messages this fire leaves unread
-                        # (a deadline already past fires again before
-                        # the channel is read); len() needs no lock
+                        # (those that came after the deadline was found
+                        # past); len() needs no lock
                         depth = ch.qsize()
                         out = elem.on_timer()
                         t_ret = time.perf_counter()

@@ -216,11 +216,11 @@ def test_the_cache_builds_the_family_its_pools(bundle):
     # the two sparse layers' two KV heads, a pool layer each
     assert c.k.shape == c.v.shape == (4, 80, 4, 1, 16)
     # by slot: a compressed key a block of the longest table, a state
-    assert c.ck.shape == (4, 5, 16, 16) and c.idx is None
+    assert c.slot_rows.shape == (4, 5, 16, 16) and c.idx is None
     assert c.state.shape == (2, 5, 4, 16, 16)
     assert c.state.dtype == jnp.float32
     assert [p.shape for p in c.pools()] == [c.k.shape, c.v.shape,
-                                            c.ck.shape, c.state.shape]
+                                            c.slot_rows.shape, c.state.shape]
     st = c.stats()
     assert st["pools"] == 4 and st["state_slots"] == 4
     assert st["state_slots_used"] == 0

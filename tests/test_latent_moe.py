@@ -881,7 +881,8 @@ def test_each_program_sets_class_is_defined_once():
     assert len(names) == len(set(names))
     sets = [n for n in names if n.endswith("Set")]
     assert sets == ["DenseSet", "ChunkOnlySet", "SparseMoESet", "HybridSet",
-                    "HeldExpertsSet", "WindowMoESet", "LatentMoESet"]
+                    "HeldExpertsSet", "WindowMoESet", "LatentMoESet",
+                    "DeltaMoESet"]
     assert set(families.FAMILIES.values()) == {
         getattr(families, n) for n in sets} - {families.ChunkOnlySet,
                                                families.HeldExpertsSet}

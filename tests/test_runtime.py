@@ -347,3 +347,79 @@ def test_stats_report_queue_wait():
     assert "queue_wait_avg_us" in tr and "queue_wait_max_us" in tr
     assert tr["queue_wait_max_us"] >= tr["queue_wait_avg_us"] >= 0.0
     assert tr["proctime_avg_us"] > 0.0
+
+
+def _late_timer(fires: int):
+    from nnstreamer_tpu.graph.pipeline import Element
+
+    class LateTimer(Element):
+        """A serving element whose step outlasts its own window: every
+        deadline it arms is already past when the scheduler reads it."""
+
+        ELEMENT_NAME = "test_late_timer"
+        CHAIN_FUSABLE = False
+
+        def __init__(self, name=None, **props):
+            super().__init__(name, **props)
+            self.seen = 0
+            self.seen_at_fire = []
+            self._deadline = None
+
+        def negotiate(self, in_specs):
+            return [in_specs[0]]
+
+        def process(self, pad, buf):
+            self.seen += 1
+            if self._deadline is None and len(self.seen_at_fire) < fires:
+                self._deadline = time.perf_counter() - 1.0
+            return []
+
+        def next_deadline(self):
+            return self._deadline
+
+        def on_timer(self):
+            self.seen_at_fire.append(self.seen)
+            time.sleep(0.005)             # the producer refills the queue
+            self._deadline = (time.perf_counter() - 1.0
+                              if len(self.seen_at_fire) < fires else None)
+            return []
+
+    return LateTimer(name="late")
+
+
+def test_a_timer_already_due_waits_for_what_is_queued_and_no_more():
+    """An element whose every deadline is past (`tensor_llm` at many
+    rows: a step's emissions outlast `admit_window_ms`) still has its
+    input read: what the channel held when the timer was found due,
+    and no more than that, so the timer is not held back either."""
+    import nnstreamer_tpu as nns
+    from nnstreamer_tpu.elements.sinks import TensorSink
+    from nnstreamer_tpu.tensor.dtypes import DType
+    from nnstreamer_tpu.tensor.info import TensorInfo
+
+    cap, n, fires = 4, 14, 3
+    pipe = nns.Pipeline("late_timer")
+    src = AppSrc(spec=TensorsSpec.of(TensorInfo((1, 4), DType.FLOAT32)),
+                 name="src")
+    late = _late_timer(fires)
+    sink = TensorSink(name="out")
+    for e in (src, late, sink):
+        pipe.add(e)
+    pipe.link(src, late)
+    pipe.link(late, sink)
+    for i in range(n):
+        src.push(TensorBuffer.of(np.ones((1, 4), np.float32), pts=i))
+    src.end()
+    r = nns.PipelineRunner(pipe, queue_capacity=cap)
+    r.start()
+    r.wait(30)
+    r.stop()
+    at = late.seen_at_fire
+    assert late.seen == n and len(at) == fires
+    # the input was read between the fires (it was not before: every
+    # fire saw the one message that armed the first deadline)
+    assert all(b > a for a, b in zip(at, at[1:]))
+    # and a fire waits for no more than the channel holds
+    assert all(b - a <= cap for a, b in zip(at, at[1:]))
+    assert at[0] <= 1 + cap
+    assert r.stats()["late"]["timer_fires"] == fires

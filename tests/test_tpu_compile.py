@@ -507,3 +507,144 @@ def test_the_latent_decode_layer_reads_the_pools_where_they_lie(
     assert all("scatter" in ln for ln in fusions)
     # both pools are written in place: nothing a pool wide is kept beside
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# -- the delta family's cell (kimi-linear-48b-a3b-8l) -----------------------------
+
+def _kimi():
+    from perfbench.runners import delta_moe_llm
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "perfbench",
+                           "configs", "kimi-linear-48b-a3b-8l.json")) as f:
+        cfg = json.load(f)
+    return cfg, delta_moe_llm.lm_spec(cfg)
+
+
+def _kimi_layer(cfg, one_chip, layer: int):
+    """The shapes of published layer `layer` + 1's weights, on the chip."""
+    from perfbench.references import delta_moe_lm
+    blk = jax.eval_shape(lambda: delta_moe_lm.make_params(
+        cfg, 1, dtype=jnp.bfloat16))["blocks"][layer]
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), blk)
+
+
+@pytest.mark.parametrize("b", [64, 1])
+def test_the_kda_decode_layer_updates_states_and_tails_in_place(one_chip, b):
+    """One KDA expert layer of the Kimi cell's decode step: 64 rows'
+    states (134 MB float32) are gathered by slot, advanced and scattered
+    back into the pool where it lies: both by-slot pools are aliased to
+    the outputs, nothing a pool wide (818 MB of states) is kept beside
+    them, and the state stays float32."""
+    from nnstreamer_tpu.llm import delta_moe
+    cfg, spec = _kimi()
+    bf, i32, slots = jnp.bfloat16, jnp.int32, 65
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(*a):
+        return delta_moe._decode_kda(*a, dense=False, spec=spec, dtype=bf)
+
+    tails, states = (6, slots, 1, 9 * 4096), (6, slots, 32, 128, 128)
+    compiled = jax.jit(layer, donate_argnums=(5, 6)).lower(
+        _kimi_layer(cfg, one_chip, 1), arg((b, 1, 2304), bf), arg((), i32),
+        arg((b,), jnp.bool_), arg((b,), i32), arg(tails, bf),
+        arg(states, jnp.float32)).compile()
+    mem = compiled.memory_analysis()
+    pools = 6 * slots * (3 * 12288 * 2 + 32 * 128 * 128 * 4)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 2
+    text = compiled.as_text()
+    assert f"f32[6,{slots},32,128,128]" in text
+    assert not re.search(rf"bf16\[6,{slots},32,128,128\]", text)
+
+
+@pytest.mark.parametrize("c", [2048, 256])
+def test_the_kda_chunk_layer_fits_beside_the_pools(one_chip, monkeypatch, c):
+    """One KDA expert layer of the Kimi cell's chunk: the closed form over
+    runs of 64 at `highest`, the systems solved for all runs at once; its
+    temporaries (the chunk's float32 q, k, v, g, the runs' A, B and T, a
+    run's pairwise decays) stay under 1 GB, the pools written in place."""
+    from nnstreamer_tpu.llm import delta_moe
+    cfg, spec = _kimi()
+    bf, i32, slots = jnp.bfloat16, jnp.int32, 65
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(*a):
+        return delta_moe._chunk_kda(*a, dense=False, run=delta_moe.RUN,
+                                    spec=spec, dtype=bf)
+
+    compiled = jax.jit(layer, donate_argnums=(7, 8)).lower(
+        _kimi_layer(cfg, one_chip, 1), arg((c, 1, 2304), bf), arg((), i32),
+        arg((c,), jnp.bool_), arg((), i32), arg((), jnp.bool_),
+        arg((), i32), arg((6, slots, 1, 9 * 4096), bf),
+        arg((6, slots, 32, 128, 128), jnp.float32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * slots * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 30
+    # the grouped products of 8 x c pair rows: the repo's kernel past 512
+    assert ("grouped_matmul" in compiled.as_text()) == (8 * c > 512)
+
+
+@pytest.mark.parametrize("what", ["decode-64", "chunk-2048", "chunk-256"])
+def test_the_kimi_cells_latent_layers_take_the_latent_familys_kernels(
+        one_chip, monkeypatch, what):
+    """A latent expert layer of the Kimi cell (32 heads of 128 + 64 | 128
+    over latents of 512, no query rank, nothing turned; a pool of its 2
+    latent layers) through `latent_moe.decode_layer` / `chunk_layer`: the
+    decode walk's kernel and the expanded chunk's causal tile update
+    compile at this second shape, the query goes through one matrix, and
+    neither pool is copied or converted."""
+    from nnstreamer_tpu.backends import pallas_paged
+    from nnstreamer_tpu.llm import latent_moe
+    cfg, spec = _kimi()
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_paged, "_interpret", lambda: False)
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        assert latent_moe.fused_decode(64, spec, jnp.bfloat16)
+    mb, bs, nblk, bf, i32 = 288, 64, 39000, jnp.bfloat16, jnp.int32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blk = _kimi_layer(cfg, one_chip, 3)              # published layer 4
+    assert "wq" in blk and "wqa" not in blk
+    pools = (arg((2, nblk, bs, 1, 512), bf), arg((2, nblk, bs // 2, 128), bf))
+    if what == "decode-64":
+        b = 64
+
+        def layer(*a):
+            return latent_moe.decode_layer(*a, dense=False, t=0, spec=spec,
+                                           dtype=bf)
+
+        compiled = jax.jit(layer, donate_argnums=(8, 9)).lower(
+            blk, arg((b, 1, 2304), bf), arg((), i32), arg((b,), i32),
+            arg((b,), jnp.bool_), arg((b,), i32), arg((b,), i32),
+            (arg((b, mb), i32), arg((), i32)), *pools).compile()
+        kernel, limit = "latent_decode_attn", 256 << 20
+    else:
+        c = int(what.split("-")[1])
+        assert latent_moe.expanded_attend(c, spec)
+
+        def layer(*a):
+            return latent_moe.chunk_layer(
+                *a, dense=False, tile=parts.CTX_TILE, by_block=True,
+                fused=True, expanded=True, spec=spec, dtype=bf)
+
+        compiled = jax.jit(layer, donate_argnums=(8, 9)).lower(
+            blk, arg((c, 1, 2304), bf), arg((), i32), arg((c,), i32),
+            arg((c,), jnp.bool_), arg((c,), i32), arg((c,), i32),
+            arg((mb,), i32), *pools).compile()
+        # a tile's float32 scores would be heads x chunk x tile x 4 bytes
+        kernel, limit = "causal_block_update", 4 * 32 * c * parts.CTX_TILE
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sum(kernel in ln for ln in calls) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+    assert not re.search(
+        rf"= bf16\[2,{nblk},[^ ]* (copy|convert)\(", text)
