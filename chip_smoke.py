@@ -307,31 +307,41 @@ def leg_kernels() -> dict:
            normal((2, 96, 64, 1, 512), jnp.bfloat16),
            normal((2, 96, 32, 128), jnp.bfloat16)), decode_plain, 3e-2)
 
-    # a chunk's expert layer through the grouped-product kernel (1,024
+    # the expert layer through the grouped-product kernel, a chunk's (1,024
     # tokens x 4 of 64 experts, 8 held: 4,096 pair rows of which an
-    # eighth is held, a row tile of 128), against the same layer with the
-    # compiler's own grouped product in its place
+    # eighth is held, a row tile of 128) and a decode bucket's (64 rows x
+    # 4: 256 pair rows, a row tile of 64), each
+    # against the same layer with the compiler's grouped product in its
+    # place
     ex = LMSpec(family="window_moe", n_heads=8, n_kv=2, head_dim=128,
                 n_experts=64, experts_per_tok=4, expert_width=512,
                 score_fn="sigmoid", route_scale=2.448, experts_first=16,
                 experts_held=8)
     assert experts.expert_row_tile(4096, 64) == 128
+    assert experts.expert_row_tile(256, 64) == 64
     eblk = {"router": normal((1024, 64), jnp.bfloat16),
             "router_bias": jnp.zeros((64,), jnp.float32),
             "ewi": normal((8, 1024, 1024), jnp.bfloat16) * 0.03,
             "ewd": normal((8, 512, 1024), jnp.bfloat16) * 0.03}
-    live = jnp.arange(1024) < 1000
 
-    def layer_by_ragged_dot(g):
-        tile, experts.XLA_ROW_TILE = experts.XLA_ROW_TILE, 1 << 30
+    def layer(g, live):
+        return experts.expert_layer(eblk, g, live, ex, jnp.bfloat16)
+
+    def layer_by_ragged_dot(g, live):
+        kernel = experts.grouped
+        experts.grouped = lambda xs, w, counts, n: jax.lax.ragged_dot(
+            xs, w, counts)
         try:
-            return experts.expert_layer(eblk, g, live, ex, jnp.bfloat16)
+            return layer(g, live)
         finally:
-            experts.XLA_ROW_TILE = tile
+            experts.grouped = kernel
 
-    check("grouped_matmul",
-          lambda g: experts.expert_layer(eblk, g, live, ex, jnp.bfloat16),
-          (normal((1024, 1024), jnp.bfloat16),), layer_by_ragged_dot, 3e-2)
+    check("grouped_matmul", layer,
+          (normal((1024, 1024), jnp.bfloat16), jnp.arange(1024) < 1000),
+          layer_by_ragged_dot, 3e-2)
+    check("grouped_matmul_decode", layer,
+          (normal((64, 1024), jnp.bfloat16), jnp.arange(64) < 63),
+          layer_by_ragged_dot, 3e-2)
 
     # paged kernels at the LLM legs' geometry, MHA and GQA
     hd, bs, nb = LLM["d_model"] // LLM["n_heads"], 16, 64

@@ -825,7 +825,7 @@ class PagedLLMExecutor:
                     kv_pool_itemsize=self.kv_pool_itemsize,
                     **ps.note_decode(pos_a, n))
         if host:
-            span.update(ps.note_beside("decode", host))
+            span.update(ps.note_beside("decode", host, b_b))
         if fresh:
             self.compile_count += 1
             self._span("compile", t0, t1, what="llm_decode", bucket=b_b,
@@ -891,7 +891,9 @@ class PagedLLMExecutor:
             return None, first_ids
         span = launch.span
         if launch.beside:
-            said = self.programs.note_beside("decode", host[nf + 1:])
+            # the launch's ids are a row a row of its bucket
+            said = self.programs.note_beside("decode", host[nf + 1:],
+                                             len(host[nf]))
             # the chunks launched before it are done too
             self._drain_chunks()
             if span is not None:

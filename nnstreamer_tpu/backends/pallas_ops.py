@@ -26,7 +26,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Whether a kernel traced now is interpreted: everywhere but on the
+    TPU. Where a default device is set (`jax.default_device`: an engine
+    kept on the host's CPU device beside the chip, as `chip_smoke.py`'s
+    references are) it is that device's platform that decides, not the
+    process's default backend."""
+    device = jax.config.jax_default_device
+    if device is None:
+        return jax.default_backend() != "tpu"
+    return getattr(device, "platform", device) != "tpu"
 
 
 # -- normalize: uint8 → (x - mean) / std ------------------------------------
@@ -737,9 +745,13 @@ def _grouped_matmul_kernel(tm: int, tk: int, tn: int, off_ref, group_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        lhs_ref[:, pl.ds(pl.multiple_of(k * tk, tk), tk)], rhs_ref[...],
-        preferred_element_type=jnp.float32)
+    # a K or an N that is no whole number of lane tiles is one tile (the
+    # caller's `tiling`) and is taken whole: Mosaic has no index to prove
+    # aligned
+    lhs = lhs_ref[...] if tk % 128 else lhs_ref[
+        :, pl.ds(pl.multiple_of(k * tk, tk), tk)]
+    acc_ref[...] += jnp.dot(lhs, rhs_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _store():
@@ -747,13 +759,15 @@ def _grouped_matmul_kernel(tm: int, tk: int, tn: int, off_ref, group_ref,
         row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, tn), 0)
         mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
-        at = pl.ds(pl.multiple_of(n * tn, tn), tn)
+        at = slice(None) if tn % 128 else pl.ds(
+            pl.multiple_of(n * tn, tn), tn)
         out_ref[:, at] = jnp.where(
             mine, acc_ref[...],
             out_ref[:, at].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
+def grouped_matmul(lhs, rhs, group_sizes, *, tiling, reckoned=False,
+                   interpret=None):
     """``lhs[rows of group g] @ rhs[g]`` for every group: lhs (M, K)
     with its rows sorted by group, rhs (G, K, N), group_sizes (G,) int32
     summing to at most M. What `jax.lax.ragged_dot` computes, on the grid
@@ -771,13 +785,24 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
     The visits are the outermost grid dimension: a visit's rows (tm, K)
     and its output rows (tm, N) stay in fast memory while the group's
     matrix streams through once, so the rows are read once a visit, not
-    once an N tile as in megablox's order."""
+    once an N tile as in megablox's order.
+
+    `reckoned`: hand the compiler the call's cost (every row against a
+    matrix, and the bytes of as many matrices as there are groups, or
+    rows if those are fewer: the most a call can visit). A kernel's
+    time is otherwise nothing to the compiler, which then starts few of
+    its own copies ahead of the operations around the call (a decode
+    step of DeepSeek-V2 compiled for a described v5e: 137 with its own
+    grouped product, 54 around this kernel, 101 around it reckoned:
+    PERF.md, PR 46)."""
     tm, tk, tn = tiling
     m, kk = lhs.shape
     groups, _, nn = rhs.shape
-    if kk % tk or nn % tn:
+    if kk % tk or nn % tn or (tk % 128 and tk != kk) or (tn % 128
+                                                            and tn != nn):
         raise ValueError(f"grouped_matmul needs K={kk} divisible by tk={tk} "
-                         f"and N={nn} by tn={tn}")
+                         f"and N={nn} by tn={tn}, in whole lane tiles of "
+                         f"128 or as one tile")
     if m % tm:
         lhs = jnp.pad(lhs, ((0, -m % tm), (0, 0)))
     rows = lhs.shape[0]
@@ -788,8 +813,14 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling, interpret=None):
     need = (2 * lhs.dtype.itemsize * (tm * kk + tm * nn + tk * tn)
             + 4 * tm * tn)
     limit = need + (4 << 20) if need > (14 << 20) else None
+    cost = pl.CostEstimate(
+        flops=2 * rows * kk * nn, transcendentals=0,
+        bytes_accessed=lhs.dtype.itemsize * (
+            min(groups, rows) * kk * nn + rows * (kk + nn))
+    ) if reckoned else None
     out = pl.pallas_call(
         functools.partial(_grouped_matmul_kernel, tm, tk, tn),
+        cost_estimate=cost,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(visits, nn // tn, kk // tk),

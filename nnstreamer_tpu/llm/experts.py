@@ -1,16 +1,17 @@
-"""The dropless expert layer, and which grouped product it takes (pure
-jax; `pallas_ops.grouped_matmul` past the compiler's row tile).
+"""The dropless expert layer and its grouped products (pure jax around
+`pallas_ops.grouped_matmul`).
 
-Three families' programs run this module, so three cells of the
-benchmark move with a line here: the sparse-expert family
-(`llm/sparse_moe.py`: `keye_longctx_backlog`), the window family
-(`llm/window_moe.py`: `trinity_mixed_backlog`) and the latent family
-(`llm/latent_moe.py`: `dsv2_code_backlog`). The choice of the grouped
-product's kernel and of its row tile is made here and nowhere else
-(`grouped`, `expert_row_tile`: from the static count of pair rows and
-`n_experts` alone); `llm/families.py` reads the same rule for its
+Four families' programs run this module, so four cells of the benchmark
+move with a line here: the sparse-expert family (`llm/sparse_moe.py`:
+`keye_longctx_backlog`), the window family (`llm/window_moe.py`:
+`trinity_mixed_backlog`), the latent family (`llm/latent_moe.py`:
+`dsv2_code_backlog`) and the delta family (`llm/delta_moe.py`:
+`kimi_reason_backlog`). The grouped products' row tile and their (tk,
+tn) tile are chosen here and nowhere else (`expert_row_tile`,
+`grouped`: from the static count of pair rows, `n_experts` and the
+operands' type alone); `llm/families.py` asks the same rule for its
 counters. `shared_mlp` is the MLP of a layer that keeps a shared expert
-beside the routed ones (the window and the latent family's).
+beside the routed ones (the window, the latent and the delta family's).
 """
 
 from __future__ import annotations
@@ -22,18 +23,12 @@ from nnstreamer_tpu.backends import pallas_ops
 from nnstreamer_tpu.llm.parts import mlp_paged
 from nnstreamer_tpu.llm.spec import LMSpec
 
-# Pair rows of a grouped product the TPU compiler hands to its kernel:
-# a multiple of this. Any other count it expands to one dense product
-# over all groups (compiled for a described v5e, and read on the chip:
-# PERF.md, PR 39).
-GROUPED_ROWS = 8
-
-# The row tile the TPU compiler gives `jax.lax.ragged_dot`'s kernel: all
-# the pair rows up to this many, and this many beyond (read from the
-# compiled text's `ragged_dot_tiling`: PERF.md, PR 40). A visit of the
-# kernel is a whole row tile against one expert's matrices, so past this
-# count an expert's few rows are paid for as 512.
-XLA_ROW_TILE = 512
+# The pair rows up to which an expert of a layer gets a few rows at most
+# (every decode bucket of the benchmark's cells, the chunks' small
+# buckets): a visit of the grouped product there does nothing but stream
+# the expert's matrices, and its row tile is chosen for that (PERF.md,
+# PR 46). Beyond, a chunk's thousands of pair rows, the tile is PR 40's.
+FEW_ROWS = 512
 
 _F32 = jnp.float32
 
@@ -83,33 +78,42 @@ def fit(size: int, want: int) -> int:
 def expert_row_tile(rows: int, n_experts: int) -> int:
     """The row tile of the expert layer's grouped products over `rows`
     (token, expert) pair rows dealt over `n_experts`: from the shapes
-    alone. Up to `XLA_ROW_TILE` rows it is the compiler's own, all the
-    rows (filled to `GROUPED_ROWS`). Beyond, a visit is one row tile
-    against one expert's matrices, and on the v5e it is bound by reading
-    those matrices up to about 240 rows (197 TFLOP/s over 819 GB/s) and
-    by the matrix unit past that: the tile is a few times the mean rows
-    an expert gets, so that an expert's rows span one or two tiles,
-    within 128 to 256 (the sweep on the chip: PERF.md, PR 40)."""
-    if rows <= XLA_ROW_TILE:
-        return -(-rows // GROUPED_ROWS) * GROUPED_ROWS
+    alone. A visit is one row tile against one expert's matrices, and on
+    the v5e it is bound by reading those matrices up to about 240
+    bfloat16 rows (197 TFLOP/s over 819 GB/s) and by the matrix unit
+    past that. Up to `FEW_ROWS` the tile is 64: a visit costs the same
+    from 8 rows to 64 (the matrix unit loads an expert's blocks whatever
+    the rows that pass them), and the wider tile has the fewer experts
+    whose rows straddle an edge and are read twice. Beyond, the tile is
+    a few times the mean rows an expert gets, so that an expert's rows
+    span one or two tiles, within 128 to 256 (the sweeps on the chip:
+    PERF.md, PR 40 and PR 46)."""
+    if rows <= FEW_ROWS:
+        return 64
     mean = max(1, rows // n_experts)
     return min(256, max(128, 1 << (2 * mean - 1).bit_length()))
 
 
 def grouped(xs, w, counts, n_experts: int):
     """The grouped product ``xs[rows of expert e] @ w[e]`` for pair rows
-    xs (R, K) sorted by expert, w (E, K, N), counts (E,): the compiler's
-    kernel where its row tile is all the rows, the repo's kernel with
-    `expert_row_tile`'s beyond. Rows past the last expert's are the
-    caller's to leave unread."""
+    xs (R, K) sorted by expert, w (E, K, N), counts (E,), through
+    `pallas_ops.grouped_matmul` at `expert_row_tile`'s row tile: every
+    count of rows, every type (the compiler's `jax.lax.ragged_dot` takes
+    all the rows of a decode bucket as one row tile and pays every
+    touched expert a visit of it, 2.5 x the kernel's time at 512 pair
+    rows and nowhere under it; and an expert's matrix in tiles wider
+    than 2 MB, faster alone on the chip, slowed DeepSeek-V2's step:
+    PERF.md, PR 46). Rows past the last expert's are the caller's to
+    leave unread."""
     rows, (_, kk, nn) = xs.shape[0], w.shape
-    if rows <= XLA_ROW_TILE:
-        return jax.lax.ragged_dot(xs, w, counts)
-    # a (tk, tn) tile of an expert's matrix is 2 MB whatever the type
+    # a (tk, tn) tile of an expert's matrix is 2 MB whatever the type; a
+    # decode step is many short operations around these calls, so there
+    # the compiler is told what they cost and schedules its copies by it
     tk = fit(kk, 2048 // xs.dtype.itemsize)
     return pallas_ops.grouped_matmul(
         xs, w, counts,
-        tiling=(expert_row_tile(rows, n_experts), tk, fit(nn, 1024)))
+        tiling=(expert_row_tile(rows, n_experts), tk, fit(nn, 1024)),
+        reckoned=rows <= FEW_ROWS)
 
 
 def expert_layer(blk, g, live, spec: LMSpec, dtype):
@@ -136,12 +140,6 @@ def expert_layer(blk, g, live, spec: LMSpec, dtype):
     counts = jnp.sum(e[:, None] == jnp.arange(ne)[None, :], axis=0,
                      dtype=jnp.int32)
     xs = g[order // k]
-    # a count of pair rows the compiler would expand (one decode row of
-    # 4 a token) reads every held expert, and reads wrong in float32 at
-    # `highest` on the chip: rows of zeros past the last group, which
-    # belong to no expert, take it to the kernel
-    if n * k % GROUPED_ROWS:
-        xs = jnp.pad(xs, ((0, -(n * k) % GROUPED_ROWS), (0, 0)))
     gu = grouped(xs, blk["ewi"].astype(dtype), counts, spec.n_experts)
     mid = jax.nn.silu(gu[:, :f]) * gu[:, f:]
     out = grouped(mid, blk["ewd"].astype(dtype), counts, spec.n_experts)

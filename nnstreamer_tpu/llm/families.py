@@ -53,7 +53,7 @@ from nnstreamer_tpu.llm.spec import (
 
 
 def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
-    """The visits a chunk's grouped products make, one of its two
+    """The visits a call's grouped products make, one of its two
     products, over its expert layers: counts (layers, experts) are the
     pair rows each expert got, sorted by expert in each layer; an
     expert's rows span the row tiles of `tm` from its first row's to its
@@ -65,21 +65,46 @@ def expert_tile_visits(counts: np.ndarray, tm: int) -> int:
     return int(tiles[counts > 0].sum())
 
 
-def _note_expert_tiles(ps, counts: np.ndarray, bucket: int) -> dict:
-    """How full the row tiles were that a chunk's grouped products
-    visited (`experts.expert_layer`), for the set `ps` of a family with
-    an expert layer: counts (layers, held experts) and the rows the
-    chunk was padded to give the pair rows, and the rule gives the tile
-    (`experts.expert_row_tile`). Counts them and returns the span's
-    part."""
+def _note_experts(ps, counts: np.ndarray, bucket: int, kind: str) -> dict:
+    """What a call's expert layers did (`experts.expert_layer`), for the
+    set `ps` of a family that has them: counts (layers, held experts)
+    are the pairs each expert got, and the rows the call was padded to
+    give the pair rows and, by the rule, the row tile of its grouped
+    products (`experts.expert_row_tile`, asked again here, on the host).
+    Counts `EXPERT_COUNTERS` and returns the span's part: the distinct
+    experts with a pair and the (row tile, expert) visits of one grouped
+    product, over the layers (visits over experts touched is what the
+    experts cost whose rows straddle a tile's edge and are read twice);
+    a decode step's says the tile, a chunk's the pairs at the busiest
+    expert and how full the visited tiles were."""
     from nnstreamer_tpu.llm.experts import expert_row_tile
 
     tm = expert_row_tile(bucket * ps.spec.experts_per_tok, ps.spec.n_experts)
-    visits = expert_tile_visits(counts, tm)
-    ps.counters["expert_tile_visits"] += visits
-    ps.counters["expert_tile_rows"] += visits * tm
-    return {"expert_tile_visits": visits, "expert_tile_fill_pct": round(
+    touched, visits = int((counts > 0).sum()), expert_tile_visits(counts, tm)
+    c = ps.counters
+    c["expert_tile_visits"] += visits
+    c["expert_tile_rows"] += visits * tm
+    said = {"experts_touched": touched, "expert_tile_visits": visits}
+    if kind == "decode":
+        c["expert_steps_layers"] += counts.shape[0]
+        c["experts_touched_sum"] += touched
+        return {**said, "expert_row_tile": tm}
+    load_max = int(counts.max())
+    c["expert_load_max_sum"] += load_max
+    c["expert_load_chunks"] += 1
+    return {**said, "expert_load_max": load_max, "expert_tile_fill_pct": round(
         100.0 * int(counts.sum()) / max(visits * tm, 1), 2)}
+
+
+#: what `_note_experts` counts, tracer on or off. Decode steps: (layer,
+#: step) pairs and the distinct experts that got a pair in them. Chunks
+#: whose counts have been read back (`expert_load_chunks`): the pairs at
+#: the busiest expert, largest over layers. Every call: the (row tile,
+#: expert) visits one of its two grouped products made over its expert
+#: layers, and the rows of those tiles
+EXPERT_COUNTERS = ("expert_steps_layers", "experts_touched_sum",
+                   "expert_load_max_sum", "expert_load_chunks",
+                   "expert_tile_visits", "expert_tile_rows")
 
 
 #: the three things a program of the causal tile update does with its
@@ -275,7 +300,7 @@ class DenseSet:
     def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """Account what a `chunk` or a `decode` returned beside its
         logits, now on the host, and return its span's part. `bucket`:
-        the rows a chunk was padded to (a decode step gives none)."""
+        the rows the call was padded to."""
         return {}
 
     def stats(self) -> dict:
@@ -384,23 +409,17 @@ class SparseMoESet(ChunkOnlySet):
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
         self.idx_dim = int(spec.idx_dim)
-        # kept tracer on or off. Decode steps: context slots the indexer
-        # scored / slots selected and attended / indexer-pool slots a
-        # layer read (kv_slots_read then counts the selected slots'
-        # gathers); (layer, step) pairs and the distinct experts that
-        # got a token in them. Every call: (token, expert) pairs routed.
-        # Chunks: tokens at the busiest expert, summed over the chunks
-        # whose counts have been read back (expert_load_chunks); context
-        # tiles a layer's walks covered, and those of them the attention
-        # walk updated in one kernel (`parts.fused_attend`); the
-        # (row tile, expert) visits one of a chunk's grouped products
-        # made over its layers, and the rows of those tiles.
+        # kept tracer on or off, beside `EXPERT_COUNTERS`. Decode steps:
+        # context slots the indexer scored / slots selected and attended
+        # / indexer-pool slots a layer read (kv_slots_read then counts
+        # the selected slots' gathers). Every call: (token, expert) pairs
+        # routed. Chunks: context tiles a layer's walks covered, and
+        # those of them the attention walk updated in one kernel
+        # (`parts.fused_attend`).
         self.counters.update(dict.fromkeys((
             "kv_tokens_scored", "kv_tokens_selected", "idx_slots_read",
-            "expert_tokens", "expert_steps_layers", "experts_touched_sum",
-            "expert_load_max_sum", "expert_load_chunks",
-            "chunk_tiles_attended", "chunk_tiles_fused",
-            "expert_tile_visits", "expert_tile_rows"), 0))
+            "expert_tokens", "chunk_tiles_attended", "chunk_tiles_fused")
+            + EXPERT_COUNTERS, 0))
 
     def program(self, kind: str) -> Program:
         from nnstreamer_tpu.llm import sparse_moe
@@ -445,23 +464,11 @@ class SparseMoESet(ChunkOnlySet):
                 "ctx_tiles": tiles}
 
     def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
-        """One call's (layers, experts) token counts: distinct experts
-        with a token, summed over layers, and for a chunk the tokens at
-        the busiest expert, largest over layers, and how full its
-        grouped products' row tiles were."""
+        """One call's (layers, experts) token counts: the pairs routed,
+        and what the expert layers did with them (`_note_experts`)."""
         counts, = host
-        touched = int((counts > 0).sum())
-        c = self.counters
-        c["expert_tokens"] += int(counts.sum())
-        if kind == "decode":
-            c["expert_steps_layers"] += counts.shape[0]
-            c["experts_touched_sum"] += touched
-            return {"experts_touched": touched}
-        load_max = int(counts.max())
-        c["expert_load_max_sum"] += load_max
-        c["expert_load_chunks"] += 1
-        return {"experts_touched": touched, "expert_load_max": load_max,
-                **_note_expert_tiles(self, counts, bucket)}
+        self.counters["expert_tokens"] += int(counts.sum())
+        return _note_experts(self, counts, bucket, kind)
 
 
 class HybridSet(ChunkOnlySet):
@@ -582,45 +589,26 @@ class HeldExpertsSet(ChunkOnlySet):
 
     def __init__(self, spec, *, params: dict, **given):
         super().__init__(spec, params=params, **given)
-        # kept tracer on or off. Decode steps: (layer, step) pairs and
-        # the distinct held experts that got a token in them. Every call:
-        # (token, expert) pairs of real tokens routed to held experts and
-        # away. Chunks: tokens at the busiest held expert, summed over
-        # the chunks whose counts have been read back
-        # (expert_load_chunks); the (row tile, expert) visits one of a
-        # chunk's grouped products made over its expert layers, and the
-        # rows of those tiles; the programs of the fused tile update,
-        # (block of queries, tile) pairs over all layers, by what
-        # `pallas_ops.block_reach` has each do
+        # kept tracer on or off, beside `EXPERT_COUNTERS` (of the held
+        # experts). Every call: (token, expert) pairs of real tokens
+        # routed to held experts and away. Chunks: the programs of the
+        # fused tile update, (block of queries, tile) pairs over all
+        # layers, by what `pallas_ops.block_reach` has each do
         self.counters.update(dict.fromkeys((
-            "expert_pairs_held", "expert_pairs_away", "expert_steps_layers",
-            "experts_touched_sum", "expert_load_max_sum",
-            "expert_load_chunks", "expert_tile_visits",
-            "expert_tile_rows") + QBLOCK_KINDS, 0))
+            "expert_pairs_held", "expert_pairs_away")
+            + EXPERT_COUNTERS + QBLOCK_KINDS, 0))
 
     def note_beside(self, kind: str, host: list, bucket: int = 0) -> dict:
         """One call's (expert layers, held + 1) counts: the real tokens'
-        pairs at held experts and away, the distinct held experts with a
-        token summed over layers, and for a chunk the tokens at the
-        busiest held expert, largest over layers, and how full its
-        grouped products' row tiles were."""
+        pairs at held experts and away, and what the expert layers did
+        with those held (`_note_experts`)."""
         load, = host
         counts, away = load[:, :-1], int(load[:, -1].sum())
-        touched, held = int((counts > 0).sum()), int(counts.sum())
-        c = self.counters
-        c["expert_pairs_held"] += held
-        c["expert_pairs_away"] += away
-        if kind == "decode":
-            c["expert_steps_layers"] += counts.shape[0]
-            c["experts_touched_sum"] += touched
-            return {"experts_touched": touched, "expert_pairs_held": held,
-                    "expert_pairs_away": away}
-        load_max = int(counts.max())
-        c["expert_load_max_sum"] += load_max
-        c["expert_load_chunks"] += 1
-        return {"experts_touched": touched, "expert_load_max": load_max,
-                "expert_pairs_held": held, "expert_pairs_away": away,
-                **_note_expert_tiles(self, counts, bucket)}
+        held = int(counts.sum())
+        self.counters["expert_pairs_held"] += held
+        self.counters["expert_pairs_away"] += away
+        return {**_note_experts(self, counts, bucket, kind),
+                "expert_pairs_held": held, "expert_pairs_away": away}
 
 
 class WindowMoESet(HeldExpertsSet):

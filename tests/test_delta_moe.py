@@ -394,6 +394,10 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     assert last["tail_bytes_rw"] == 2 * last["rows"] * TAILS
     assert last["expert_pairs_held"] + last["expert_pairs_away"] \
         == last["rows"] * 4 * 4                # 4 a token, 4 expert layers
+    # a bucket of at most 4 rows x 4 lies inside one row tile of 64: a
+    # visit a touched expert
+    assert last["expert_row_tile"] == 64
+    assert last["expert_tile_visits"] == last["experts_touched"]
     for key in ("pos0", "clen", "fresh", "delta_runs", "ctx_tiles", "attend",
                 "latents_expanded", *families.QBLOCK_KINDS):
         assert key in chunks[-1], key
@@ -410,8 +414,11 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     for key in ("state_rows", "state_bytes_rw", "tail_bytes_rw",
                 "chunks_fresh", "delta_runs", "decode_steps_fused",
                 "decode_steps_plain", "chunk_prefills", "latents_expanded",
-                "kv_tokens_attended", "kv_slots_read", *families.QBLOCK_KINDS):
+                "kv_tokens_attended", "kv_slots_read", *families.QBLOCK_KINDS,
+                *families.EXPERT_COUNTERS):
         assert key in counters, key
+    assert counters["expert_tile_rows"] \
+        == 64 * counters["expert_tile_visits"] > 0
     cache = eng.stats()["cache"]
     assert {"state_slots_used", "state_slot_bytes", "block_bytes"} <= set(
         cache)

@@ -628,6 +628,13 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     last = decode[-1]
     assert last["expert_pairs_held"] + last["expert_pairs_away"] \
         == last["rows"] * 4 * 2                # 4 a token, 2 expert layers
+    # a bucket of at most 4 rows x 4 lies inside one row tile of 64: a
+    # visit a touched expert
+    assert last["expert_row_tile"] == 64
+    assert last["expert_tile_visits"] == last["experts_touched"]
+    counters = eng.stats()["executor"]
+    assert counters["expert_tile_rows"] \
+        == 64 * counters["expert_tile_visits"] > 0
     assert last["kv_slots"] % (BS * latent_moe.walk_plan(
         BS, 2, 16)[0]) == 0
 
@@ -889,5 +896,5 @@ def test_each_program_sets_class_is_defined_once():
     methods = [m.name for c in tree.body if isinstance(c, ast.ClassDef)
                for m in c.body if isinstance(m, ast.FunctionDef)
                and c.name == "ChunkOnlySet"]
-    assert "_note_expert_tiles" not in methods
+    assert "_note_experts" not in methods
     assert len(methods) == len(set(methods))

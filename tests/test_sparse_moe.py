@@ -215,11 +215,17 @@ def test_span_counts_equal_the_references_routing_and_selection(params, ids,
             == int(taps["attended"][0, t])
         assert a["experts_touched"] == int((hist > 0).sum())
         assert a["idx_slots"] == 64 and a["kv_slots"] == TOPK
+        # one row x 2 lies inside one row tile of 64: a visit a touched
+        # expert
+        assert a["expert_row_tile"] == 64
+        assert a["expert_tile_visits"] == a["experts_touched"]
     after = ex.stats()
     assert after["kv_tokens_selected"] - before["kv_tokens_selected"] == 32
     assert after["kv_tokens_scored"] - before["kv_tokens_scored"] \
         == 30 + 31 + 32 + 33
     assert after["expert_steps_layers"] - before["expert_steps_layers"] == 8
+    assert after["expert_tile_visits"] - before["expert_tile_visits"] \
+        == after["experts_touched_sum"] - before["experts_touched_sum"] > 0
     assert after["expert_tokens"] - before["expert_tokens"] == 4 * 2 * 2
 
 

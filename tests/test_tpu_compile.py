@@ -215,45 +215,18 @@ def test_the_hybrid_chunks_walk_gathers_nothing_a_query_wide(one_chip,
     assert len(calls) == spec.n_kv
 
 
-@pytest.mark.parametrize("rows", [1, 2, 16])
-def test_the_expert_layers_grouped_products_take_the_kernel(one_chip, rows):
-    """The Trinity cell's expert layer (trinity-large-preview-5l: 32 held
-    experts of 256, width 3,072, 4 a token) at a decode bucket's rows:
-    both grouped products go to the compiler's kernel, at one row too,
-    whose 4 pair rows it would expand to a dense product over every held
-    expert (read wrong in float32 at `highest` on the chip, PR 39)."""
-    from perfbench.runners.window_moe_llm import lm_spec as window_spec
-    root = os.path.dirname(os.path.dirname(__file__))
-    with open(os.path.join(root, "perfbench", "configs",
-                           "trinity-large-preview-5l.json")) as f:
-        spec = window_spec(json.load(f))
-    d, f_, held, bf = 3072, spec.expert_width, spec.experts_held, jnp.bfloat16
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    blk = {"router": arg((d, spec.n_experts), bf),
-           "router_bias": arg((spec.n_experts,), jnp.float32),
-           "ewi": arg((held, d, 2 * f_), bf), "ewd": arg((held, f_, d), bf)}
-    text = jax.jit(lambda b, g, live: experts.expert_layer(
-        b, g, live, spec, bf)).lower(
-        blk, arg((rows, d), bf), arg((rows,), jnp.bool_)
-    ).compile().as_text()
-    # the kernel's calls: one that lays out the groups, one a product
-    calls = [ln for ln in text.splitlines()
-             if "custom_call_target=\"tpu_custom_call\"" in ln
-             and "%ragged-dot-metadata" not in ln.split("=")[0]]
-    # the expansion is a convolution that dilates its input by the groups
-    assert len(calls) == 2 and "lhs_dilate" not in text
-
-
 def _cell(cell):
-    """(configuration, spec) of a sparse-expert cell, by its short name."""
+    """(configuration, spec) of a cell with an expert layer, by its short
+    name."""
+    from perfbench.runners.delta_moe_llm import lm_spec as delta_spec
+    from perfbench.runners.latent_moe_llm import lm_spec as latent_spec
     from perfbench.runners.sparse_moe_llm import lm_spec as sparse_spec
     from perfbench.runners.window_moe_llm import lm_spec as window_spec
     config, runner_spec = {
         "trinity": ("trinity-large-preview-5l.json", window_spec),
-        "keye": ("keye-vl-2.0-30b-a3b-6l.json", sparse_spec)}[cell]
+        "keye": ("keye-vl-2.0-30b-a3b-6l.json", sparse_spec),
+        "dsv2": ("deepseek-v2-6l.json", latent_spec),
+        "kimi": ("kimi-linear-48b-a3b-8l.json", delta_spec)}[cell]
     root = os.path.dirname(os.path.dirname(__file__))
     with open(os.path.join(root, "perfbench", "configs", config)) as f:
         cfg = json.load(f)
@@ -278,48 +251,100 @@ def _expert_layer_text(one_chip, cfg, spec, rows):
     ).compile().as_text()
 
 
-@pytest.mark.parametrize("cell,tile", [("trinity", 128), ("keye", 256)])
+def _tiles_asked(monkeypatch):
+    """The (tm, tk, tn) `pallas_ops.grouped_matmul` is called with, as
+    they come."""
+    seen, real = [], pallas_ops.grouped_matmul
+
+    def spy(lhs, rhs, counts, *, tiling, **kw):
+        seen.append(tuple(tiling))
+        return real(lhs, rhs, counts, tiling=tiling, **kw)
+
+    monkeypatch.setattr(pallas_ops, "grouped_matmul", spy)
+    return seen
+
+
+@pytest.mark.parametrize("cell,tiles", [
+    ("trinity", [(128, 1024, 1024), (128, 1024, 1024)]),
+    ("keye", [(256, 1024, 768), (256, 768, 1024)])])
 def test_a_chunks_grouped_products_take_the_repos_kernel(one_chip,
                                                          monkeypatch, cell,
-                                                         tile):
+                                                         tiles):
     """The expert layer of a chunk of 2,048 tokens at the Trinity cell's
     widths (8,192 pair rows over 32 held experts of 3,072 x 6,144 and
     3,072 x 3,072) and the Keye cell's (16,384 over 128 of 2,048 x 1,536
-    and 768 x 2,048): Mosaic takes `pallas_ops.grouped_matmul` at the
-    rule's tiles, twice, and the compiler's own grouped product, whose
-    row tile there is 512 (`ragged_dot_tiling="512,...`), is gone."""
+    and 768 x 2,048): Mosaic takes `pallas_ops.grouped_matmul` at PR 40's
+    tiles, a row tile of 128 or 256 and 2 MB of an expert's matrix,
+    twice, as before the decode buckets took the kernel too (PR 46), and
+    the compiler's own grouped product, whose row tile there is 512
+    (`ragged_dot_tiling="512,...`), is gone."""
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    asked = _tiles_asked(monkeypatch)
     cfg, spec = _cell(cell)
     text = _expert_layer_text(one_chip, cfg, spec, 2048)
-    assert experts.expert_row_tile(2048 * spec.experts_per_tok,
-                                      spec.n_experts) == tile
+    assert asked == tiles
     assert "ragged_dot_tiling" not in text
     calls = [ln for ln in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in ln]
     assert len(calls) == 2
     assert all("grouped_matmul" in ln for ln in calls)
+    assert not any("cost_estimate" in ln for ln in calls)   # as the parent's
 
 
-@pytest.mark.parametrize("cell,rows", [("trinity", 16), ("keye", 8),
-                                       ("keye", 64)])
-def test_a_decode_buckets_grouped_products_stay_the_compilers(one_chip,
-                                                              monkeypatch,
-                                                              cell, rows):
-    """At a decode bucket's rows (and Keye's chunk bucket of 64: 512
-    pair rows) the compiler's row tile is already all the pair rows:
-    the layer keeps `jax.lax.ragged_dot` and holds no call of the repo's
-    kernel, so the decode programs are the parent's."""
+@pytest.mark.parametrize("kk,nn", [(64, 64), (64, 128), (96, 192)])
+def test_grouped_matmul_compiles_at_widths_of_no_whole_lane_tile(one_chip,
+                                                                  kk, nn):
+    """The smoke's tiny models (`chip_smoke.py`: a hidden size of 64) run
+    the kernel on the chip since PR 46: a K or an N of no whole number of
+    lane tiles is one tile, taken whole; Mosaic refused the kernel's
+    dynamic index into it ("cannot statically prove that index in
+    dimension 1 is a multiple of 128")."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda a, b, c: pallas_ops.grouped_matmul(
+        a, b, c, tiling=(64, kk, nn), reckoned=True,
+        interpret=False)).lower(
+        arg((16, kk), jnp.float32), arg((8, kk, nn), jnp.float32),
+        arg((8,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# the decode buckets of the four cells with an expert layer, full, half
+# full and of one row, and Keye's chunk bucket of 64 tokens
+DECODE_BUCKETS = [("keye", 8), ("keye", 1), ("keye", 64), ("trinity", 16),
+                  ("trinity", 1), ("dsv2", 32), ("dsv2", 1), ("kimi", 32),
+                  ("kimi", 64)]
+
+
+@pytest.mark.parametrize("cell,rows", DECODE_BUCKETS,
+                         ids=[f"{c}-{r}" for c, r in DECODE_BUCKETS])
+def test_a_decode_buckets_grouped_products_take_the_repos_kernel(
+        one_chip, monkeypatch, cell, rows):
+    """`experts.grouped` at a decode bucket's pair rows (4 of Trinity's one
+    row to Kimi-Linear's 512; no count is filled up: the kernel fills its
+    rows to its tile): both products are `pallas_ops.grouped_matmul` at
+    the row tile of 64 and 2 MB of an expert's matrix a tile, Mosaic
+    takes them, and the compiler's grouped product, which paid every touched expert
+    a visit of all the pair rows, and its expansion to a dense product
+    over every group (a convolution that dilates its input by the
+    groups: PR 39) are gone. The calls say what they cost."""
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    asked = _tiles_asked(monkeypatch)
     cfg, spec = _cell(cell)
     text = _expert_layer_text(one_chip, cfg, spec, rows)
-    # in the instructions, not in the text's table of source files (a
-    # function first traced under tests/test_grouped_matmul.py, where a
-    # worker ran that file before this one, is named there)
-    assert not any("grouped_matmul" in ln for ln in text.splitlines()
-                   if " = " in ln)
-    tilings = {ln.split("ragged_dot_tiling=\"")[1].split(",")[0]
-               for ln in text.splitlines() if "ragged_dot_tiling=\"" in ln}
-    assert tilings == {str(rows * spec.experts_per_tok)}
+    assert [t[0] for t in asked] == [64, 64]
+    assert experts.expert_row_tile(rows * spec.experts_per_tok,
+                                   spec.n_experts) == 64
+    assert "ragged_dot_tiling" not in text and "lhs_dilate" not in text
+    # in the instructions, not in the text's table of source files
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 2
+    assert all("grouped_matmul" in ln.split(" = ")[0] for ln in calls)
+    # the compiler is told what the calls cost (`reckoned`): it schedules
+    # its own copies around a decode step's operations by that
+    assert all("cost_estimate" in ln for ln in calls)
 
 
 @pytest.mark.parametrize("c", [2048, 1024])
@@ -471,6 +496,8 @@ def test_the_latent_decode_layer_reads_the_pools_where_they_lie(
         on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
         assert latent_moe.fused_decode(64, spec, jnp.bfloat16)
     monkeypatch.setattr(pallas_paged, "_interpret", lambda: False)
+    # a bucket of 32 rows takes its grouped products through the kernel
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     mb, bs, nblk, bf, i32 = 288, 64, 13438, jnp.bfloat16, jnp.int32
 
     def arg(shape, dtype):
@@ -529,7 +556,8 @@ def _kimi_layer(cfg, one_chip, layer: int):
 
 
 @pytest.mark.parametrize("b", [64, 1])
-def test_the_kda_decode_layer_updates_states_and_tails_in_place(one_chip, b):
+def test_the_kda_decode_layer_updates_states_and_tails_in_place(
+        one_chip, monkeypatch, b):
     """One KDA expert layer of the Kimi cell's decode step: 64 rows'
     states (134 MB float32) are gathered by slot, advanced and scattered
     back into the pool where it lies: both by-slot pools are aliased to
@@ -538,6 +566,8 @@ def test_the_kda_decode_layer_updates_states_and_tails_in_place(one_chip, b):
     from nnstreamer_tpu.llm import delta_moe
     cfg, spec = _kimi()
     bf, i32, slots = jnp.bfloat16, jnp.int32, 65
+    # a bucket of 64 rows takes its grouped products through the kernel
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

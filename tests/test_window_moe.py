@@ -546,6 +546,13 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
     assert last["kv_tokens_window"] <= last["rows"] * WINDOW
     assert last["expert_pairs_held"] + last["expert_pairs_away"] \
         == last["rows"] * 2 * 2                # 2 a token, 2 expert layers
+    # a bucket of at most 4 rows x 2 lies inside one row tile of 64: a
+    # visit a touched expert
+    assert last["expert_row_tile"] == 64
+    assert last["expert_tile_visits"] == last["experts_touched"]
+    counters = eng.stats()["executor"]
+    assert counters["expert_tile_rows"] \
+        == 64 * counters["expert_tile_visits"] > 0
     admit = [e[6] for e in tracer.events() if e[1] == "llm"
              and e[3].startswith("admit")]
     assert "window_free" in admit[0]
