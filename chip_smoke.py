@@ -62,7 +62,10 @@ weights, frames and prompts:
                    same element on the chip serve the tokens the same engine
                    serves on this host's CPU device, once with chunks of 8
                    and once with chunks of 32 (runs of 16, the latent
-                   layers' expanded form);
+                   layers' expanded form), the rows' states gathered by
+                   slot; and once with KDA heads of 128, where the decode
+                   step moves a row's state through its slot in one kernel
+                   (`pallas_state.delta_decode_update`);
 - ``multichip``    with four or more devices: ``tensor_filter devices=4`` on
                    four distinct chips, ``tensor_llm shards=4`` equal to
                    ``shards=1``, ring prefill through the Pallas block kernel.
@@ -1003,16 +1006,17 @@ DELTA = dict(d=64, kinds="KKLKL", kda_heads=2, kda_dim=8, conv=4, heads=4,
              experts=16, first=4, held=4, per_tok=4, vocab=256)
 
 
-def _delta_bundle(device):
+def _delta_bundle(device, kda_dim: int = DELTA["kda_dim"]):
     """Seeded float32 weights of the tiny delta-family model on `device`,
-    in the family's hand-over layout, with its description."""
+    in the family's hand-over layout, with its description; KDA heads of
+    `kda_dim`."""
     import jax
     import numpy as np
 
     from nnstreamer_tpu.backends.xla import ModelBundle
     from nnstreamer_tpu.llm.spec import DELTA_MOE, KDA, LATENT, LMSpec
 
-    c = DELTA
+    c = dict(DELTA, kda_dim=kda_dim)
     rng = np.random.default_rng(29)
 
     def w(*shape):
@@ -1076,7 +1080,12 @@ def leg_llm_delta_moe() -> dict:
     """As `leg_llm_latent_moe`, for the family that keeps two kinds of
     cache: once with chunks of 8 (one run of the closed form a chunk, the
     latent layers absorbed), once with chunks of 32 (runs of 16, the
-    latent layers expanded); decode through the states and tails by slot."""
+    latent layers expanded); decode through the states and tails by slot,
+    the states gathered (heads of 8). Then once more with KDA heads of 128,
+    the width at which the decode step moves a row's state through its
+    slot in one kernel (`pallas_state.delta_decode_update`, by
+    `delta_moe.fused_state`'s rule): lowered on the chip, interpreted in
+    the engine on the host's CPU device."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1096,26 +1105,35 @@ def leg_llm_delta_moe() -> dict:
     try:
         get_store().register("chip_smoke_delta_moe",
                              _delta_bundle(jax.devices()[0]))
-        for form, chunk in (("absorbed", 8), ("expanded", 32)):
+        get_store().register("chip_smoke_delta_moe_wide",
+                             _delta_bundle(jax.devices()[0], 128))
+        for form, chunk, kda_dim in (("absorbed", 8, DELTA["kda_dim"]),
+                                     ("expanded", 32, DELTA["kda_dim"]),
+                                     ("fused_state", 8, 128)):
             serving = dict(block_size=4, num_blocks=96, max_len=64,
                            prefill_chunk=chunk)
+            fused = form == "fused_state"
             with jax.default_device(cpu):
-                eng = LLMEngine(_delta_bundle(cpu), dtype=jnp.float32,
-                                max_batch=8, **serving)
+                eng = LLMEngine(_delta_bundle(cpu, kda_dim),
+                                dtype=jnp.float32, max_batch=8, **serving)
                 reqs = [eng.submit(p, req_id=f"req{i}",
                                    max_new_tokens=LLM_NEW_TOKENS)
                         for i, p in enumerate(prompts)]
                 eng.drain()
                 want = {r.req_id: list(r.tokens) for r in reqs}
                 eng.executor.close()
-            toks, stats = _run_llm("store://chip_smoke_delta_moe", prompts,
-                                   dtype="float32", paged_kernel="xla",
-                                   **serving)
+            toks, stats = _run_llm(
+                "store://chip_smoke_delta_moe" + "_wide" * fused, prompts,
+                dtype="float32", paged_kernel="xla", **serving)
             ex, cache = stats["executor"], stats["cache"]
             assert ex["family"] == "delta_moe", ex
             assert ex["expert_pairs_held"] > 0 < ex["expert_pairs_away"], ex
             assert (ex["latents_expanded"] > 0) == (form == "expanded"), ex
             assert ex["state_bytes_rw"] > 0 < ex["tail_bytes_rw"], ex
+            took, other = (("fused", "gathered") if fused
+                           else ("gathered", "fused"))
+            assert ex[f"state_steps_{took}"] == ex["decode_steps"], ex
+            assert ex[f"state_steps_{other}"] == 0, ex
             assert cache["pools"] == 4 and cache["blocks_used"] == 0, cache
             assert cache["state_slots_used"] == 0, cache
             assert toks == want, \

@@ -47,8 +47,21 @@ A KDA layer (H = `lin_heads` heads of d = `head_dim`; u the normed input):
 - ``y = Wo (RMSNorm(o_t; o_norm) * sigmoid((u Wga) Wgb))``, the norm a head
   with one weight of d.
 
-Decode: a row's tail and state are read from its slot, advanced by one
-token and written back in place (`_decode_kda`).
+Decode (`_decode_kda`): a row's tail is gathered from its slot, advanced
+by one token and written back. Its state goes one of two ways, by
+`fused_state`'s rule from the backend and the head's width alone: through
+one kernel a layer (`pallas_state.delta_decode_update`: a program a row
+and group of heads reads the state through the row's slot, advances it in
+fast memory and writes it back where it lay, the pool aliased input to
+output, so the states cross the memory once each way), or, for every
+other shape, gathered by slot into a copy, advanced by `delta_step` and
+scattered back (about nine passes over the rows' states), which stays the
+definition the closed form, the kernel and the tests are held to. (A
+third way, each slot of the pool advanced where it lies by XLA, the
+slots without a row by g = 0 and beta = 0, was 8 % faster than the
+gathered one at 17 rows and served tokens that were not the reference's
+at a full bucket of 64, though 8 rows of 9 slots agreed to the last bit
+on the chip: PERF.md section 6, PR 45. It is out of the program.)
 
 Chunk (`delta_chunk`): the chunk's tokens in runs of `RUN` or fewer,
 through the recurrence's closed form. With ``G_i`` the running sum of g
@@ -74,6 +87,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.backends import pallas_state
 from nnstreamer_tpu.llm import latent_moe, parts
 from nnstreamer_tpu.llm.experts import shared_mlp
 from nnstreamer_tpu.llm.parts import finish, layer_index, norm, proj
@@ -226,6 +240,15 @@ def delta_chunk(q, k, v, g, beta, state, run: int = RUN):
 
 # -- decode -------------------------------------------------------------------
 
+def fused_state(spec: LMSpec) -> bool:
+    """Whether a decode step moves its rows' states through their slots in
+    one kernel a layer (`pallas_state.delta_decode_update`) or gathers,
+    advances (`delta_step`) and scatters them: from the backend and the
+    head's width alone. The kernel takes a head's state as whole (8, 128)
+    tiles with its rows down the sublanes."""
+    return jax.default_backend() == "tpu" and spec.head_dim % 128 == 0
+
+
 @functools.partial(jax.jit, static_argnames=("dense", "spec", "dtype"))
 def _decode_kda(blk, x, li, live, slots, t_pool, s_pool, *, dense, spec,
                 dtype):
@@ -239,15 +262,18 @@ def _decode_kda(blk, x, li, live, slots, t_pool, s_pool, *, dense, spec,
     t_pool = t_pool.at[li, slots].set(
         seq[:, 1:].reshape(b, 1, -1).astype(t_pool.dtype))
     qkv = conv_act(seq, blk["conv"], 1, dtype)[:, 0]
-    # the rows' states gathered by slot, advanced, scattered back. (Each
-    # slot of the pool advanced where it lies, the slots without a row by
-    # g = 0 and beta = 0, is fewer passes over the states and was 8 %
-    # faster at 17 rows, but a full bucket of 64 served tokens that were
-    # not the reference's on the chip, though 8 rows of 9 slots agreed to
-    # the last bit there: PERF.md section 6, PR 45.)
-    o, state = delta_step(*kda_inputs(blk, u, qkv, None, spec, dtype),
-                          s_pool[li, slots])
-    s_pool = s_pool.at[li, slots].set(state)
+    q, k, v, g, beta = kda_inputs(blk, u, qkv, None, spec, dtype)
+    if fused_state(spec):
+        # each live row's state through its slot, once each way (the
+        # live rows are the bucket's first: their count is the mask's)
+        o, s_pool = pallas_state.delta_decode_update(
+            q, k, v, jnp.exp(g), beta, s_pool, li, slots,
+            jnp.sum(live, dtype=jnp.int32))
+    else:
+        # the rows' states gathered by slot, advanced, scattered back
+        # (the module docstring has the record of the third way)
+        o, state = delta_step(q, k, v, g, beta, s_pool[li, slots])
+        s_pool = s_pool.at[li, slots].set(state)
     x = _kda_out(blk, x, u, o, spec, dtype)
     x, load = shared_mlp(blk, norm(blk["ln2"], x, spec, dtype), live, dense,
                          spec, dtype, onto=x)

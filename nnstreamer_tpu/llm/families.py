@@ -908,9 +908,9 @@ class DeltaMoESet(LatentMoESet):
                  "the latent pool has no head axis to shard along: neither "
                  "has a sharding rule yet")
     NO_PALLAS = ("that names the dense family's twin programs; the delta "
-                 "rule is plain XLA and the latent layers take their decode "
-                 "kernel from the backend and the pools' widths alone "
-                 "(`latent_moe.fused_decode`)")
+                 "rule's decode update and the latent layers' decode walk "
+                 "take their kernels from the backend and the widths alone "
+                 "(`delta_moe.fused_state`, `latent_moe.fused_decode`)")
     NO_W8A8 = ("the delta rule's state and the products that feed it are "
                "float32, and its grouped expert products are float only")
 
@@ -944,10 +944,12 @@ class DeltaMoESet(LatentMoESet):
                            * np.dtype(self.kw["dtype"]).itemsize)
         # kept tracer on or off, beside the latent set's. Decode steps and
         # chunks: state and tail bytes read and written (all KDA layers),
-        # the rows whose state a call advanced. Chunks: those that started
+        # the rows whose state a call advanced; decode steps by how their
+        # states moved (`delta_moe.fused_state`). Chunks: those that started
         # from zero, and the runs of the closed form a KDA layer took
         self.counters.update(dict.fromkeys((
-            "state_rows", "state_bytes_rw", "tail_bytes_rw", "chunks_fresh",
+            "state_rows", "state_bytes_rw", "tail_bytes_rw",
+            "state_steps_fused", "state_steps_gathered", "chunks_fresh",
             "delta_runs"), 0))
 
     def cache_kw(self, n_layers: int) -> dict:
@@ -996,9 +998,19 @@ class DeltaMoESet(LatentMoESet):
 
     def note_decode(self, pos_a: np.ndarray, n: int) -> dict:
         """Each live row's state and tails are read and written once a
-        KDA layer (state_rows, state_bytes_rw, tail_bytes_rw); a latent
-        layer attends its whole context (`LatentMoESet.note_decode`)."""
-        return {**self._note_state(n), **super().note_decode(pos_a, n)}
+        KDA layer (state_rows, state_bytes_rw, tail_bytes_rw: what the
+        rule needs, however the states moved); `state_update` says how
+        they moved: `fused`, through their slots in one kernel a layer
+        (`pallas_state.delta_decode_update`), or `gathered` by slot,
+        advanced and scattered back (`delta_moe.fused_state`'s choice,
+        asked again on the host). A latent layer attends its whole
+        context (`LatentMoESet.note_decode`)."""
+        from nnstreamer_tpu.llm.delta_moe import fused_state
+
+        how = "fused" if fused_state(self.spec) else "gathered"
+        self.counters[f"state_steps_{how}"] += 1
+        return {**self._note_state(n), "state_update": how,
+                **super().note_decode(pos_a, n)}
 
     def note_chunk(self, pos0: int, clen: int, bucket: int) -> dict:
         """One sequence's state and tails read and written once a KDA

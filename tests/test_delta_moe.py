@@ -7,6 +7,7 @@ executor and the engine."""
 
 import ast
 import dataclasses
+import functools
 import os
 import sys
 
@@ -19,6 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "perfbench"))
 
 import tiny_delta_moe as tiny                                   # noqa: E402
 import tiny_latent_moe                                          # noqa: E402
+from nnstreamer_tpu.backends import pallas_state                # noqa: E402
 from nnstreamer_tpu.backends.llm_exec import PagedLLMExecutor   # noqa: E402
 from nnstreamer_tpu.backends.xla import ModelBundle             # noqa: E402
 from nnstreamer_tpu.core.errors import BackendError             # noqa: E402
@@ -142,6 +144,154 @@ def test_a_strong_decay_neither_overflows_nor_divides():
     assert np.isfinite(np.asarray(got_o)).all()
     assert np.abs(np.asarray(got_o - want_o)).max() < 1e-5
     assert np.abs(np.asarray(got_s - want_s)).max() < 1e-5
+
+
+# -- the decode update's kernel: a row's state through its slot -------------------
+
+def _pool_inputs(b, h, d, layers, slots, seed):
+    """A bucket's q, k, v, g, beta (`_rule_inputs`, a token a row) and a
+    state pool with nothing zero in it."""
+    q, k, v, g, beta, _ = _rule_inputs(b, h=h, d=d, seed=seed)
+    pool = np.random.default_rng(seed + 1).standard_normal(
+        (layers, slots, h, d, d)).astype(np.float32)
+    return (q, k, v, g, beta), jnp.asarray(pool)
+
+
+def _rel(got, want):
+    return np.abs(np.asarray(got) - np.asarray(want)).max() \
+        / np.abs(np.asarray(want)).max()
+
+
+# buckets of 1, 8 and 64 rows, the live rows fewer than the bucket (the
+# padding rows on the scratch slot 0), the slots out of order, a layer in
+# the middle of the pool, heads of 128 x 128 in groups of 4, 2 and 1
+@pytest.mark.parametrize("b,n,hb", [
+    (1, 1, 4), (8, 5, 2), (8, 8, 4), (64, 41, 2), (64, 64, 1)])
+def test_the_state_kernel_is_the_gathered_rule(b, n, hb):
+    """`pallas_state.delta_decode_update` (interpreted) against
+    `delta_step` through a gather and a scatter by slot: the live rows'
+    outputs and states to 1e-6 of the largest, zeros for the padding
+    rows, and every slot without a live row and every other layer equal
+    to what went in to the last bit."""
+    layers, h, d, li = 3, 4, 128, 1
+    n_slots = b + 2
+    x, pool = _pool_inputs(b, h, d, layers, n_slots, seed=b + n)
+    slots = np.zeros(b, np.int32)
+    slots[:n] = np.random.default_rng(b).permutation(
+        np.arange(1, n_slots))[:n]
+    want_o, want_s = delta_moe.delta_step(*x, pool[li, slots])
+    q, k, v, g, beta = x
+    got_o, got = jax.jit(functools.partial(
+        pallas_state.delta_decode_update, heads=hb))(
+        q, k, v, jnp.exp(g), beta, pool, jnp.int32(li), jnp.asarray(slots),
+        jnp.int32(n))
+    assert got_o.shape == want_o.shape and got.shape == pool.shape
+    assert _rel(got_o[:n], want_o[:n]) < 1e-6
+    assert not np.asarray(got_o[n:]).any()
+    got, pool = np.asarray(got), np.asarray(pool)
+    assert _rel(got[li, slots[:n]], want_s[:n]) < 1e-6
+    assert np.abs(got[li, slots[:n]] - pool[li, slots[:n]]).max() > 0.01
+    idle = np.setdiff1d(np.arange(n_slots), slots[:n])
+    assert 0 in idle
+    assert np.array_equal(got[li, idle], pool[li, idle])
+    assert np.array_equal(got[[0, 2]], pool[[0, 2]])
+
+
+@pytest.mark.parametrize("d,hb", [(8, 2), (128, 1)])
+def test_32_steps_through_the_kernel_are_the_recurrence(d, hb):
+    """Two rows' sequences of 32 tokens, a kernel call a token, each on
+    the pool the call before left, against `delta_step` under a scan from
+    the states the slots held."""
+    c, h, layers, li = 32, 2, 2, 1
+    rows = [_rule_inputs(c, h=h, d=d, seed=70 + r) for r in range(2)]
+    slots = jnp.asarray([2, 1], jnp.int32)
+    pool = jnp.zeros((layers, 3, h, d, d), jnp.float32).at[li, slots].set(
+        jnp.stack([r[5] for r in rows]))
+    step = jax.jit(functools.partial(pallas_state.delta_decode_update,
+                                     heads=hb))
+    outs = []
+    for t in range(c):
+        q, k, v, g, beta = (jnp.stack([r[i][t] for r in rows])
+                            for i in range(5))
+        o, pool = step(q, k, v, jnp.exp(g), beta, pool, jnp.int32(li), slots,
+                       jnp.int32(2))
+        outs.append(o)
+    for r, row in enumerate(rows):
+        want_o, want_s = _recurrence(*row)
+        assert np.abs(np.asarray(jnp.stack(outs)[:, r] - want_o)).max() < 1e-5
+        assert np.abs(np.asarray(pool[li, slots[r]] - want_s)).max() < 1e-5
+    assert not np.asarray(pool[0]).any() and not np.asarray(pool[li, 0]).any()
+
+
+def test_the_state_kernel_refuses_a_pool_of_another_shape():
+    (q, k, v, g, beta), pool = _pool_inputs(2, 4, 8, 2, 3, seed=5)
+    args = (jnp.int32(0), jnp.asarray([1, 2], jnp.int32), jnp.int32(2))
+    with pytest.raises(ValueError, match="groups of 3"):
+        pallas_state.delta_decode_update(q, k, v, jnp.exp(g), beta, pool,
+                                         *args, heads=3)
+    with pytest.raises(ValueError, match="bfloat16"):
+        pallas_state.delta_decode_update(
+            q, k, v, jnp.exp(g), beta, pool.astype(jnp.bfloat16), *args)
+
+
+def _wide_cfg():
+    """The tiny configuration with KDA heads of 128, the width the rule
+    takes (and the rank of the decay's and the gate's pairs with it)."""
+    return dict(CFG, linear_attn_config=dict(CFG["linear_attn_config"],
+                                             head_dim=128))
+
+
+def test_the_state_rule_reads_the_backend_and_the_heads_width(monkeypatch):
+    wide = lm_spec(_wide_cfg())
+    assert not delta_moe.fused_state(wide)                  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert delta_moe.fused_state(wide)
+    assert delta_moe.fused_state(dataclasses.replace(wide, head_dim=256))
+    assert not delta_moe.fused_state(dataclasses.replace(wide, head_dim=64))
+    assert not delta_moe.fused_state(SPEC)                  # heads of 8
+
+
+@pytest.mark.parametrize("how", ["fused", "gathered"])
+def test_a_step_says_how_its_states_moved(monkeypatch, how):
+    """The engine at KDA heads of 128, the rule forced either way (the
+    kernel interpreted here): the reference's tokens both ways, every
+    decode step counted under the way it took (`state_steps_fused` /
+    `state_steps_gathered`) and saying it on its `invoke` span
+    (`state_update`), `state_bytes_rw` what the rule needs either way."""
+    cfg = _wide_cfg()
+    params = ref.make_params(cfg, SEED, dtype=jnp.float32)
+    monkeypatch.setattr(delta_moe, "fused_state", lambda spec: how == "fused")
+    # a layer's trace does not know the rule it was made under
+    delta_moe._decode_kda.clear_cache()
+    calls = []
+    monkeypatch.setattr(
+        pallas_state, "delta_decode_update",
+        lambda *a, _f=pallas_state.delta_decode_update, **k: (
+            calls.append(a[5].shape), _f(*a, **k))[1])
+    tracer = Tracer()
+    eng = LLMEngine(ModelBundle(fn=None, params=params, lm=lm_spec(cfg)),
+                    dtype=jnp.float32, max_batch=4, prefill_chunk=CHUNK,
+                    tracer=tracer, **POOL)
+    prompts = [_prompt(p, seed=i) for i, p in enumerate((20, 9, 13))]
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    eng.drain()
+    delta_moe._decode_kda.clear_cache()
+    for p, r in zip(prompts, reqs):
+        ids = np.concatenate([p, np.asarray(r.tokens[:-1], np.int32)])
+        want = np.asarray(ref.forward_logits(params, cfg, ids))[len(p) - 1:]
+        assert list(want.argmax(-1)) == list(r.tokens)
+    # a trace a bucket: the whole pool handed to the kernel, never a layer
+    assert (len(calls) > 0) == (how == "fused")
+    assert all(shape == (3, 5, 2, 128, 128) for shape in calls)
+    st = eng.executor.stats()
+    other = {"fused": "gathered", "gathered": "fused"}[how]
+    assert st[f"state_steps_{how}"] == st["decode_steps"] > 0
+    assert st[f"state_steps_{other}"] == 0
+    decode = [e[6] for e in tracer.events() if e[3] == "invoke"
+              and e[6].get("what") == "llm_decode"]
+    assert decode and all(d["state_update"] == how for d in decode)
+    assert all(d["state_bytes_rw"] == 2 * d["rows"] * 3 * 2 * 128 * 128 * 4
+               for d in decode)
 
 
 # -- the convolutions and their tails ---------------------------------------------
@@ -384,11 +534,13 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
               and e[6].get("what") == "llm_prefill_chunk"]
     assert decode and chunks
     for key in ("rows", "state_rows", "state_bytes_rw", "tail_bytes_rw",
-                "kv_tokens", "kv_slots", "attend", "experts_touched",
+                "state_update", "kv_tokens", "kv_slots", "attend",
+                "experts_touched",
                 "expert_pairs_held", "expert_pairs_away"):
         assert key in decode[-1], key
     last = decode[-1]
     assert last["attend"] == "plain"                # the CPU's walk
+    assert last["state_update"] == "gathered"       # and heads of 8
     assert last["state_rows"] == last["rows"]
     assert last["state_bytes_rw"] == 2 * last["rows"] * STATE
     assert last["tail_bytes_rw"] == 2 * last["rows"] * TAILS
@@ -412,7 +564,8 @@ def test_spans_say_what_a_step_and_a_chunk_read(bundle):
         assert key in resolved[-1], key
     counters = eng.stats()["executor"]
     for key in ("state_rows", "state_bytes_rw", "tail_bytes_rw",
-                "chunks_fresh", "delta_runs", "decode_steps_fused",
+                "chunks_fresh", "delta_runs", "state_steps_fused",
+                "state_steps_gathered", "decode_steps_fused",
                 "decode_steps_plain", "chunk_prefills", "latents_expanded",
                 "kv_tokens_attended", "kv_slots_read", *families.QBLOCK_KINDS,
                 *families.EXPERT_COUNTERS):
@@ -550,7 +703,7 @@ def test_the_latent_familys_decode_step_traces_as_the_parents(monkeypatch):
 # -- what the family refuses -------------------------------------------------------
 
 def test_refusals(bundle, params):
-    with pytest.raises(BackendError, match="paged_kernel=pallas.*plain XLA"):
+    with pytest.raises(BackendError, match="paged_kernel=pallas.*fused_state"):
         _executor(bundle, paged_kernel="pallas")
     with pytest.raises(BackendError, match="shards=2.*by slot on one chip"):
         LLMEngine(bundle, dtype=jnp.float32, shards=2, **POOL)

@@ -558,16 +558,24 @@ def _kimi_layer(cfg, one_chip, layer: int):
 @pytest.mark.parametrize("b", [64, 1])
 def test_the_kda_decode_layer_updates_states_and_tails_in_place(
         one_chip, monkeypatch, b):
-    """One KDA expert layer of the Kimi cell's decode step: 64 rows'
-    states (134 MB float32) are gathered by slot, advanced and scattered
-    back into the pool where it lies: both by-slot pools are aliased to
-    the outputs, nothing a pool wide (818 MB of states) is kept beside
-    them, and the state stays float32."""
+    """One KDA expert layer of the Kimi cell's decode step: the rule takes
+    the kernel at the cell's widths on the chip, and the rows' states go
+    through their slots inside it (`pallas_state.delta_decode_update`):
+    both by-slot pools are aliased to the outputs, the program keeps less
+    beside them than one gathered copy of 64 rows' states (134 MB), the
+    text holds no array of the gathered states' shape and neither a
+    scatter nor a `dynamic-update-slice` into the state pool, which stays
+    float32 and is made by nothing but the kernel."""
+    from nnstreamer_tpu.backends import pallas_state
     from nnstreamer_tpu.llm import delta_moe
     cfg, spec = _kimi()
     bf, i32, slots = jnp.bfloat16, jnp.int32, 65
-    # a bucket of 64 rows takes its grouped products through the kernel
+    # on the chip: the rule says so, and no kernel is interpreted (a bucket
+    # of 64 rows takes its grouped products through the repo's too)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert delta_moe.fused_state(spec)
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_state, "_interpret", lambda: False)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -583,10 +591,18 @@ def test_the_kda_decode_layer_updates_states_and_tails_in_place(
     mem = compiled.memory_analysis()
     pools = 6 * slots * (3 * 12288 * 2 + 32 * 128 * 128 * 4)
     assert mem.alias_size_in_bytes >= pools
-    assert mem.temp_size_in_bytes < pools // 2
+    assert mem.temp_size_in_bytes < 64 * 32 * 128 * 128 * 4
     text = compiled.as_text()
-    assert f"f32[6,{slots},32,128,128]" in text
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sum("delta_decode_update" in ln for ln in calls) == 1
+    assert not re.search(rf"f32\[{b},32,128,128\]", text)
+    pool = rf"f32\[6,{slots},32,128,128\]"
     assert not re.search(rf"bf16\[6,{slots},32,128,128\]", text)
+    made = set(re.findall(rf"= {pool}\S* ([a-z-]+)\(", text))
+    assert made <= {"parameter", "get-tuple-element", "bitcast"}, made
+    assert not any(re.search(pool, ln) for ln in text.splitlines()
+                   if " scatter(" in ln or " dynamic-update-slice(" in ln)
 
 
 @pytest.mark.parametrize("c", [2048, 256])
